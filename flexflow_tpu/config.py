@@ -44,7 +44,9 @@ class FFConfig:
     # FFModel::compile unconditionally; here it is opt-in so explicit
     # dp/tp degrees remain the default path).
     auto_parallel: bool = False
-    tpu_chip: str = "cpu-sim"           # cost-model chip: v5e|v5p|v4|cpu-sim
+    # cost-model chip: v5e|v5p|v4|cpu-sim. None = identify the device at
+    # compile (search/machine_model.chip_for_device; unknown kinds raise)
+    tpu_chip: Optional[str] = None
     only_data_parallel: bool = False
     search_budget: int = -1
     search_alpha: float = 1.2

@@ -9,7 +9,9 @@ reference path used on CPU (tests) and as a numerics oracle.
 
 Dispatch: ``use_pallas(config)`` returns True on a real TPU backend (or when
 FF_PALLAS_INTERPRET=1 forces interpreter-mode kernels on CPU, which the
-kernel unit tests use to exercise the Pallas code path everywhere).
+kernel unit tests use to exercise the Pallas code path everywhere). The
+variable is a CPU-only switch: set on a TPU backend it raises, because the
+interpreter would silently stand in for the compiled kernels.
 """
 
 from __future__ import annotations
@@ -60,11 +62,17 @@ def use_pallas(config=None) -> bool:
     """Should serving ops run their Pallas kernels?"""
     if config is not None and not getattr(config, "use_pallas", True):
         return False
-    if pallas_interpret_forced():
-        return True
     import jax
 
-    return jax.default_backend() == "tpu"
+    on_tpu = jax.default_backend() == "tpu"
+    if pallas_interpret_forced():
+        if on_tpu:
+            raise RuntimeError(
+                "FF_PALLAS_INTERPRET is set on a TPU backend: the Pallas "
+                "kernels would run interpreted instead of compiled. Unset "
+                "it (it exists for CPU tests only).")
+        return True
+    return on_tpu
 
 
 from flexflow_tpu.kernels.attention import flash_attend  # noqa: E402,F401

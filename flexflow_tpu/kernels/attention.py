@@ -34,12 +34,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax 0.4.37 spells the Mosaic compiler-params dataclass TPUCompilerParams
-# (renamed to CompilerParams when the API stabilized); same fields either
-# way, so alias rather than fork the call site.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-
 NEG_INF = -1e30  # finite "minus infinity": keeps online softmax NaN-free
 
 # Mosaic tiling: DMA slices need the sublane (second-minor) dim 8-aligned
@@ -423,7 +417,7 @@ def flash_attend(q, k_cache, v_cache, lengths, qpos, bias=None,
 
     cache_dt = k_cache.dtype
     kv_bytes = 2 * 2 * SB * KH * DL * cache_dt.itemsize
-    compiler_params = _CompilerParams(
+    compiler_params = pltpu.CompilerParams(
         vmem_limit_bytes=int(min(
             128 * 1024 * 1024,
             8 * (KH * GQ * (DL + 2) * 4 + PACK * KH * GQ * DL * 2

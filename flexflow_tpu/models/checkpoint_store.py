@@ -392,9 +392,6 @@ def main(argv=None) -> int:
     ip.add_argument("dir")
     args = ap.parse_args(argv)
     if args.cmd == "save":
-        import jax
-
-        jax.config.update("jax_platforms", "cpu")
         man = save_tiny_checkpoint(args.family, args.out, fmt=args.format,
                                    seed=args.seed)
         print(json.dumps({"dir": args.out, **man}))

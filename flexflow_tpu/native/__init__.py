@@ -14,6 +14,7 @@ fallback so the framework works even without a toolchain.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -24,6 +25,9 @@ _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 _NATIVE_DIR = os.path.join(_REPO_ROOT, "native")
 _BUILD_DIR = os.path.join(_NATIVE_DIR, "build")
 _LIB_PATH = os.path.join(_BUILD_DIR, "libflexflow_tpu_native.so")
+# hash of the sources the library at _LIB_PATH was built from; written
+# after a successful build, compared before every load
+_STAMP_PATH = _LIB_PATH + ".srchash"
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
@@ -37,25 +41,44 @@ def _sources():
              "sp_tokenizer.cpp", "graph_builder.cpp")]
 
 
-def _needs_build() -> bool:
-    if not os.path.exists(_LIB_PATH):
-        return True
-    lib_mtime = os.path.getmtime(_LIB_PATH)
+def _source_hash() -> str:
+    h = hashlib.sha256()
     hdr = os.path.join(_NATIVE_DIR, "include", "flexflow_tpu_c.h")
-    return any(os.path.getmtime(p) > lib_mtime
-               for p in _sources() + [hdr] if os.path.exists(p))
+    for path in _sources() + [hdr]:
+        h.update(os.path.basename(path).encode() + b"\0")
+        with open(path, "rb") as f:
+            h.update(f.read())
+    return h.hexdigest()
+
+
+def _needs_build() -> bool:
+    """True unless the library was built from exactly these sources.
+
+    Content, not mtimes: a checkout or a copy flattens mtimes, and
+    ``native/build`` is ignored by git, so a library left on disk by an
+    older tree (or built outside ``_build``, with no stamp) must never be
+    loaded in place of the current ``native/src``."""
+    try:
+        with open(_STAMP_PATH) as f:
+            stamp = f.read().strip()
+    except OSError:
+        return True
+    return not os.path.exists(_LIB_PATH) or stamp != _source_hash()
 
 
 def _build() -> bool:
     os.makedirs(_BUILD_DIR, exist_ok=True)
+    src_hash = _source_hash()
     cmd = ["g++", "-O2", "-std=c++17", "-fPIC", "-Wall", "-shared",
            "-I", os.path.join(_NATIVE_DIR, "include"),
            "-o", _LIB_PATH] + _sources()
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        return True
-    except Exception:
+    except (OSError, subprocess.SubprocessError):
         return False
+    with open(_STAMP_PATH, "w") as f:
+        f.write(src_hash + "\n")
+    return True
 
 
 def _declare(lib: ctypes.CDLL):
@@ -100,12 +123,8 @@ def _declare(lib: ctypes.CDLL):
     lib.ffs_done_tokens.argtypes = [c.c_void_p, c.c_int64, i32p, c.c_int]
     lib.ffs_prompt_len.restype = c.c_int
     lib.ffs_prompt_len.argtypes = [c.c_void_p, c.c_int64]
-    if hasattr(lib, "ffs_cancel"):
-        # absent in libraries built before cancellation support; callers
-        # probe NativeBatchScheduler.supports_cancel and fall back to the
-        # host-side python loop when missing
-        lib.ffs_cancel.restype = c.c_int
-        lib.ffs_cancel.argtypes = [c.c_void_p, c.c_int64]
+    lib.ffs_cancel.restype = c.c_int
+    lib.ffs_cancel.argtypes = [c.c_void_p, c.c_int64]
 
     ip = c.POINTER(c.c_int)
     lib.ffgb_create.restype = c.c_void_p
@@ -164,21 +183,9 @@ def load_native() -> Optional[ctypes.CDLL]:
             return None
         try:
             lib = ctypes.CDLL(_LIB_PATH)
-        except Exception:
-            # a stale/foreign-platform .so (equal checkout mtimes defeat
-            # _needs_build): rebuild from source once before giving up
-            try:
-                os.remove(_LIB_PATH)
-            except OSError:
-                pass
-            if not _build():
-                _build_failed = True
-                return None
-            try:
-                lib = ctypes.CDLL(_LIB_PATH)
-            except Exception:
-                _build_failed = True
-                return None
+        except OSError:
+            _build_failed = True
+            return None
         _declare(lib)
         _lib = lib
         return lib

@@ -88,18 +88,9 @@ class NativeBatchScheduler:
         return self._lib.ffs_append_block(self._h, _i32p(toks),
                                           toks.shape[1])
 
-    @property
-    def supports_cancel(self) -> bool:
-        """True when the loaded library exposes ``ffs_cancel`` (older
-        builds predate cancellation; the RequestManager keeps deadline/
-        cancel traffic on the python loop when this is False)."""
-        return getattr(self._lib, "ffs_cancel", None) is not None
-
     def cancel(self, guid: int) -> bool:
         """Cancel a pending or active request; its partial tokens drain
-        through ``pop_done``. False if unknown/finished/unsupported."""
-        if not self.supports_cancel:
-            return False
+        through ``pop_done``. False if unknown/finished."""
         return bool(self._lib.ffs_cancel(self._h, guid))
 
     def pop_done(self) -> Optional[Tuple[int, List[int], int]]:

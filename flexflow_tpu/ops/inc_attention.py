@@ -21,11 +21,13 @@ inside the kernel.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from flexflow_tpu.core.layer import WeightSpec
 from flexflow_tpu.core.initializer import default_kernel_initializer, ZeroInitializer
@@ -210,8 +212,16 @@ def _attend(attrs, q, k_cache, v_cache, lengths, qpos, out_dtype, ctx,
     cfg = ctx.config if ctx is not None else None
     from flexflow_tpu.kernels.attention import supports_shapes
     Q = q.shape[1]
+    mesh = getattr(ctx, "mesh", None) if ctx is not None else None
+    seq_deg = (mesh.shape["seq"] if mesh is not None
+               and "seq" in getattr(mesh, "axis_names", ()) else 1)
     if not ffk.use_pallas(cfg):
         pass                        # CPU/tests: jnp is the intended path
+    elif seq_deg > 1 and S % seq_deg == 0:
+        # the cache's S dim lives sharded over the mesh: the kernel streams
+        # one device's HBM, so this plan attends through the jnp
+        # seq_sharded_attend below
+        ffk.record_fallback("cache sharded over the mesh's 'seq' axis")
     elif not supports_shapes(S, Dp):
         ffk.record_fallback(f"cache shape S={S} D={Dp} not tileable")
     elif Q > 256:
@@ -228,11 +238,16 @@ def _attend(attrs, q, k_cache, v_cache, lengths, qpos, out_dtype, ctx,
         if append_kv is not None:
             k_new, v_new, appos = append_kv           # [R, 1, KH, D] each
             fkv = (_pad_d(k_new, Dp), _pad_d(v_new, Dp), appos)
-        res = flash_attend(
-            _pad_d(q, Dp), k_cache, v_cache, lengths, qpos, bias=bias,
-            alibi=alibi, append_kv=fkv, causal=causal, qk_scale=scale,
+        attend = functools.partial(
+            flash_attend, causal=causal, qk_scale=scale,
             out_dtype=out_dtype, layer_idx=layer_idx,
             interpret=ffk.pallas_interpret_forced())
+        args = (_pad_d(q, Dp), k_cache, v_cache, lengths, qpos, bias, alibi,
+                fkv)
+        if (mesh is not None and mesh.devices.size > 1
+                and getattr(ctx, "kv_override", None) is None):
+            attend = _attend_on_mesh(attend, mesh, *args)
+        res = attend(*args)
         out, caches = (res, ()) if append_kv is None else (res[0], res[1:])
         if Dp != D:                 # drop the per-head lane padding
             out = out.reshape(R, Q, H, Dp)[..., :D].reshape(R, Q, H * D)
@@ -256,9 +271,6 @@ def _attend(attrs, q, k_cache, v_cache, lengths, qpos, out_dtype, ctx,
     kc, vc = k_cache, v_cache
     if layer_idx is not None:
         kc, vc = k_cache[layer_idx], v_cache[layer_idx]
-    mesh = getattr(ctx, "mesh", None) if ctx is not None else None
-    seq_deg = (mesh.shape["seq"] if mesh is not None
-               and "seq" in getattr(mesh, "axis_names", ()) else 1)
     if seq_deg > 1 and S % seq_deg == 0:
         # searched sequence-parallel plan: the cache S dim is sharded over
         # the mesh's "seq" axis — score local slices, reconcile the softmax
@@ -272,6 +284,36 @@ def _attend(attrs, q, k_cache, v_cache, lengths, qpos, out_dtype, ctx,
         q, kc[..., :D], vc[..., :D], lengths, qpos, bias=bias,
         alibi=alibi, causal=causal, qk_scale=scale, out_dtype=out_dtype)
     return out if append_kv is None else (out,) + new_caches
+
+
+def _attend_on_mesh(attend, mesh, q, k_cache, v_cache, lengths, qpos, bias,
+                    alibi, append_kv):
+    """``flash_attend`` as a manual region over the whole mesh.
+
+    XLA refuses to partition a Mosaic custom call ("cannot be
+    automatically partitioned"), so on a multi-chip mesh the kernel runs
+    inside ``jax.shard_map``. Under tensor parallelism every chip attends
+    its own heads over its own slice of the cache: q, the cache and the
+    output split on the head dim over 'model' exactly as wq/wk/wv/wo and
+    parallel/spec.kv_cache_sharding place them, so no operand moves. A KV
+    head count the degree does not divide keeps the cache replicated and
+    every chip then attends all heads. Other mesh axes replicate the call.
+    (The pipeline segment, ``kv_override``, is already manual over "pipe"
+    and does not come through here.)"""
+    tp = mesh.shape["model"] if "model" in mesh.axis_names else 1
+    m = "model" if tp > 1 and k_cache.shape[-3] % tp == 0 else None
+    heads = P(None, None, m, None)                  # [R, Q, heads, D]
+    cache = P(*([None] * (k_cache.ndim - 3)), m, None, None)
+    out = P(None, None, m)                          # [R, Q, H*D]
+    # None operands are empty pytrees: their spec is None too
+    in_specs = (heads, cache, cache, P(), P(),
+                None if bias is None else P(),
+                None if alibi is None else P(m),
+                None if append_kv is None else (heads, heads, P()))
+    return jax.shard_map(
+        attend, mesh=mesh, in_specs=in_specs,
+        out_specs=out if append_kv is None else (out, cache, cache),
+        check_vma=False)
 
 
 def _weight_specs(attrs, input_specs):
@@ -377,8 +419,7 @@ def _project_out(attrs, params, ctx, attn_out):
 #  * stacked (consolidated by FFModel.compile when all serving-attention
 #    layers share one cache shape): op_state["kv_cache"] = {"k": [L, ...],
 #    "v": [L, ...]} and each layer carries attrs["cache_layer_idx"].
-# Stacking cuts the donated-arg count from 2*L to 2 — under a remote/tunnel
-# runtime every buffer costs a round trip per call, and it lets tree-commit
+# Stacking cuts the donated-arg count from 2*L to 2 and lets tree-commit
 # run vectorized over layers.
 # ----------------------------------------------------------------------
 def read_kv(ctx, attrs):

@@ -53,6 +53,14 @@ def make_mesh(config, devices: Optional[Sequence] = None) -> Mesh:
     if devices is None:
         devices = jax.devices()
     devices = list(devices)
+    if config.num_devices is not None:
+        # the same device count the search prices: a model asked to use
+        # fewer chips than the host has (one serving replica on a four-chip
+        # host) must not absorb the rest into data parallelism below
+        if config.num_devices > len(devices):
+            raise ValueError(f"num_devices={config.num_devices} but only "
+                             f"{len(devices)} devices are visible")
+        devices = devices[:config.num_devices]
     n = len(devices)
 
     if config.mesh_shape is not None:

@@ -32,7 +32,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from flexflow_tpu.ffconst import OpType
 from flexflow_tpu.ops.base import OpImpl, register_op, register_op_as
-from flexflow_tpu.utils.shard_map_compat import shard_map
 
 UNC = P.UNCONSTRAINED
 
@@ -184,8 +183,8 @@ def branch_parallel_apply(mesh, axis, branch_fns, out_channels, x,
         y = jax.lax.switch(bi, fns, xl)          # [B, Cmax, ...]
         return jax.lax.all_gather(y, axis)       # [d, B, Cmax, ...]
 
-    out = shard_map(local, mesh=mesh, in_specs=P(), out_specs=P(),
-                    check_vma=False)(x)
+    out = jax.shard_map(local, mesh=mesh, in_specs=P(), out_specs=P(),
+                        check_vma=False)(x)
     return [out[int(starts[i]), :, :c] for i, c in enumerate(out_channels)]
 
 
@@ -243,6 +242,7 @@ def branch_data_parallel_apply(mesh, axis, branch_fns, branch_params,
         # leading [d, mb] axes reshape to per-branch full batches
         return g.reshape((nb, k * mb) + g.shape[2:])
 
-    out = shard_map(local, mesh=mesh, in_specs=(P(), P()),
-                    out_specs=P(), check_vma=False)(x, tuple(branch_params))
+    out = jax.shard_map(local, mesh=mesh, in_specs=(P(), P()),
+                        out_specs=P(),
+                        check_vma=False)(x, tuple(branch_params))
     return [out[i, :, :c] for i, c in enumerate(out_channels)]

@@ -27,7 +27,6 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from flexflow_tpu.parallel.collectives import ppermute_shift
-from flexflow_tpu.utils.shard_map_compat import shard_map
 
 
 def stack_stage_params(per_layer_params: list):
@@ -109,7 +108,7 @@ def pipeline_spmd(block_fn: Callable, mesh, num_microbatches: int,
     def fn(stacked_params, x):
         param_specs = jax.tree.map(
             lambda l: P(P_axis, *([None] * (l.ndim - 1))), stacked_params)
-        return shard_map(
+        return jax.shard_map(
             run, mesh=mesh,
             in_specs=(param_specs, P()),     # x replicated across stages
             out_specs=P(),

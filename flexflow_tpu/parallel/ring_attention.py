@@ -27,7 +27,6 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from flexflow_tpu.parallel.collectives import axis_size
-from flexflow_tpu.utils.shard_map_compat import shard_map
 
 
 def _repeat_kv_heads(k, num_q_heads):
@@ -121,8 +120,8 @@ def ring_attention(q, k, v, mesh: Mesh, seq_axis: str = "seq",
     spec = P(ba, seq_axis, None, None)
     fn = partial(ring_attention_local, axis_name=seq_axis, causal=causal,
                  scale=scale)
-    return shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
-                     out_specs=spec, check_vma=False)(q, k, v)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
 
 
 _NEG_INF = -1e30   # finite "minus infinity", matches kernels/attention.py
@@ -206,5 +205,5 @@ def seq_sharded_attend(q, k_cache, v_cache, lengths, qpos, mesh: Mesh,
     if has_alibi:
         args.append(alibi)
         in_specs.append(P())
-    return shard_map(local_fn, mesh=mesh, in_specs=tuple(in_specs),
-                     out_specs=P(), check_vma=False)(*args)
+    return jax.shard_map(local_fn, mesh=mesh, in_specs=tuple(in_specs),
+                         out_specs=P(), check_vma=False)(*args)

@@ -76,14 +76,25 @@ class ShardingPolicy:
         return NamedSharding(self.mesh, P(*spec))
 
     def kv_cache_sharding(self, shape: Tuple[int, ...]) -> NamedSharding:
-        """KV-cache buffers [R, KH, S, D] (or stacked [L, R, KH, S, D]):
-        shard the sequence dim (dim -2) over 'seq' when the mesh has one
+        """KV-cache buffers [R, KH, S, D] (or stacked [L, R, KH, S, D]).
+
+        Under tensor parallelism the KV-head dim (dim -3) splits over
+        'model' when it divides — the same whole-head split as wk/wv
+        (ops/inc_attention._weight_specs), so each chip holds and attends
+        its own heads' cache. That is also where GSPMD moves a replicated
+        cache after the first sharded append, recompiling every program
+        for the new placement; committing it up front avoids both.
+
+        The sequence dim (dim -2) splits over 'seq' when the mesh has one
         and it divides — the storage layout consumed by
         parallel.ring_attention.seq_sharded_attend, so a searched
         sequence-parallel plan holds S/deg cache rows per device instead
-        of the whole context. Falls back to replication otherwise."""
+        of the whole context. Dims that do not divide stay replicated."""
         shape = tuple(shape)
         spec = [None] * len(shape)
+        if (len(shape) >= 3 and self._axis("model")
+                and shape[-3] % self.mesh.shape["model"] == 0):
+            spec[-3] = "model"
         if (len(shape) >= 2 and self._axis("seq")
                 and shape[-2] % self.mesh.shape["seq"] == 0):
             spec[-2] = "seq"

@@ -489,7 +489,7 @@ def mcmc_optimize(pcg: PCG, cost_model: CostModel,
     return best
 
 
-def _machine_for(config, chip: str, n: int) -> MachineModel:
+def _machine_for(config, chip: Optional[str], n: int) -> MachineModel:
     """Machine model with the config's multi-node geometry: num_nodes
     splits the devices into slices (mesh-axis groups larger than a slice
     pay DCN, optionally through a routed dcn_topology's bottleneck)."""
@@ -504,7 +504,7 @@ def _machine_for(config, chip: str, n: int) -> MachineModel:
                                   dcn_model=dcn_model)
 
 
-def optimize_model(model, chip: str = "cpu-sim",
+def optimize_model(model, chip: Optional[str] = None,
                    num_devices: Optional[int] = None,
                    training: bool = True,
                    mcmc_budget: Optional[int] = None,
@@ -513,7 +513,9 @@ def optimize_model(model, chip: str = "cpu-sim",
     """Entry point — reference FFModel::graph_optimize via
     GRAPH_OPTIMIZE_TASK (model.cc:3327). Reads parallelism axes from the
     model's config, builds PCG + cost model, runs DP+beam then MCMC, and
-    re-searches with growing memory λ if HBM oversubscribes.
+    re-searches with growing memory λ if HBM oversubscribes. ``chip=None``
+    prices the search with the running device's peaks
+    (machine_model.chip_for_device: ``cpu-sim`` on CPU).
 
     ``search_mesh`` (default ``config.search_mesh``): also search the
     MESH FACTORIZATION — every (data x model) split of the device count
@@ -655,7 +657,7 @@ def optimize_model(model, chip: str = "cpu-sim",
     return strategy
 
 
-def data_parallel_model_strategy(model, chip: str = "cpu-sim",
+def data_parallel_model_strategy(model, chip: Optional[str] = None,
                                  num_devices: Optional[int] = None,
                                  training: bool = True) -> Optional[Strategy]:
     """The canonical pure-DP strategy for ``model``, scored (not searched)

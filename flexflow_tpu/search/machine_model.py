@@ -64,6 +64,38 @@ TPU_CHIPS: Dict[str, ChipSpec] = {
 }
 
 
+# jax ``device_kind`` strings -> TPU_CHIPS key (v5e reports "TPU v5 lite").
+_DEVICE_KIND_CHIPS = {
+    "tpu v5 lite": "v5e", "tpu v5e": "v5e",
+    "tpu v5": "v5p", "tpu v5p": "v5p",
+    "tpu v4": "v4",
+}
+
+
+def chip_for_device(device=None) -> str:
+    """The TPU_CHIPS key for a JAX device (default ``jax.devices()[0]``).
+
+    The one place a device is identified for peak numbers: the search's
+    cost model (``FFConfig.tpu_chip=None``), bench_train's MFU and
+    bench.py's roofline all resolve through it. CPU maps to ``cpu-sim``;
+    a device that is not in the table raises rather than borrow another
+    chip's peaks."""
+    if device is None:
+        import jax
+
+        device = jax.devices()[0]
+    if device.platform == "cpu":
+        return "cpu-sim"
+    kind = str(device.device_kind)
+    chip = _DEVICE_KIND_CHIPS.get(kind.strip().lower())
+    if device.platform != "tpu" or chip is None:
+        raise ValueError(
+            f"no chip spec for platform={device.platform!r} "
+            f"device_kind={kind!r}; add its public peak numbers to "
+            "TPU_CHIPS/_DEVICE_KIND_CHIPS in search/machine_model.py")
+    return chip
+
+
 @dataclasses.dataclass
 class MachineModel:
     """Slice geometry + chip spec → collective/time/memory primitives.
@@ -82,11 +114,12 @@ class MachineModel:
     dcn_model: Optional[object] = None        # network.NetworkedMachineModel
 
     @classmethod
-    def from_name(cls, chip_name: str, num_devices: int,
+    def from_name(cls, chip_name: Optional[str], num_devices: int,
                   devices_per_slice: Optional[int] = None,
                   dcn_model=None) -> "MachineModel":
-        return cls(TPU_CHIPS[chip_name], num_devices, devices_per_slice,
-                   dcn_model)
+        """``chip_name=None`` identifies the running device."""
+        return cls(TPU_CHIPS[chip_name or chip_for_device()], num_devices,
+                   devices_per_slice, dcn_model)
 
     @property
     def num_slices(self) -> int:

@@ -94,4 +94,8 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    # here, not in main(): tests call main() and must not arm the cache
+    from flexflow_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     raise SystemExit(main())

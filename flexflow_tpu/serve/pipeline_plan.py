@@ -46,7 +46,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from flexflow_tpu.utils.shard_map_compat import shard_map
 
 PP_PARAMS_KEY = "__pp_blocks__"
 
@@ -438,7 +437,7 @@ def _pp_segment(model, plan):
 
     pipe_spec = jax.tree.map(lambda _: P("pipe"),
                              model.params[PP_PARAMS_KEY])
-    fn = shard_map(
+    fn = jax.shard_map(
         seg, mesh=mesh,
         in_specs=(pipe_spec, P("pipe"), P("pipe"), P(), P(), P()),
         out_specs=(P(), P("pipe"), P("pipe")),

@@ -40,7 +40,9 @@ class Optimizer:
         m = self.ffmodel
         if m is not None and getattr(m, "opt_state", None) is not None \
                 and "lr" in m.opt_state:
-            m.opt_state = dict(m.opt_state, lr=jnp.asarray(lr, jnp.float32))
+            # placed like the value it replaces, or the next step retraces
+            m.opt_state = dict(m.opt_state, lr=jax.device_put(
+                jnp.asarray(lr, jnp.float32), m.opt_state["lr"].sharding))
 
 
 class SGDOptimizer(Optimizer):

@@ -103,15 +103,6 @@ int ffsv_init(const char *repo_root) {
     set_error_from_python();
     return -1;
   }
-  /* Embedded-host-only setup (JAX_PLATFORMS override) runs HERE, not at
-   * module import: ordinary Python importers of capi_host must not have
-   * their session's backend mutated as a side effect. */
-  PyObject *r = call("host_init", nullptr);
-  if (!r) {
-    Py_CLEAR(g_host);
-    return -1;
-  }
-  Py_DECREF(r);
   return 0;
 }
 

@@ -1,0 +1,197 @@
+"""What the chip bring-up (chip_smoke.py) leans on, checked on the CPU.
+
+Lean by design (tier-1 budget): lowering-only kernel exports at the
+smoke's shapes, and the small decisions that keep a later number from
+lying about where it ran — which chip's peaks, which devices, which
+compile cache, which native library, interpreted or compiled kernels.
+The smoke's own tiny CPU rehearsal is ``slow``.
+"""
+
+import json
+import os
+import subprocess
+import sys
+import types
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+# ---------------------------------------------------------------------------
+# the Pallas kernel lowers to Mosaic at the shapes the smoke reaches
+# ---------------------------------------------------------------------------
+
+def _export_tpu(fn, *avals):
+    exported = jax.export.export(jax.jit(fn), platforms=["tpu"])(*avals)
+    assert "tpu_custom_call" in exported.mlir_module()
+
+
+def test_flash_attend_exports_for_tpu_at_smoke_shapes():
+    from flexflow_tpu.kernels.attention import flash_attend
+
+    # LLaMA-2-7B widths; 2 slots x 2 layers keep the lowering light
+    L, R, H, KH, S, D, W, chunk = 2, 2, 32, 32, 1024, 128, 8, 128
+    bf, i32 = jnp.bfloat16, jnp.int32
+    sds = jax.ShapeDtypeStruct
+    stack = sds((L, R, KH, S, D), bf)
+    _export_tpu(                      # width-8 decode, fused in-place append
+        lambda q, k, v, n, qp, kn, vn, ap: flash_attend(
+            q, k, v, n, qp, append_kv=(kn, vn, ap), causal=True,
+            layer_idx=1),
+        sds((R, W, H, D), bf), stack, stack, sds((R,), i32),
+        sds((R, W), i32), sds((R, 1, KH, D), bf), sds((R, 1, KH, D), bf),
+        sds((R,), i32))
+    _export_tpu(                      # width-8 tree verify with a bias
+        lambda q, k, v, n, qp, b: flash_attend(
+            q, k, v, n, qp, bias=b, causal=False, layer_idx=1),
+        sds((R, W, H, D), bf), stack, stack, sds((R,), i32),
+        sds((R, W), i32), sds((R, W, S), jnp.float32))
+    _export_tpu(                      # one prefill chunk
+        lambda q, k, v, n, qp: flash_attend(q, k, v, n, qp, causal=True,
+                                            layer_idx=0),
+        sds((R, chunk, H, D), bf), stack, stack, sds((R,), i32),
+        sds((R, chunk), i32))
+
+
+def test_interpret_switch_on_a_tpu_backend_raises(monkeypatch):
+    from flexflow_tpu import kernels as ffk
+
+    monkeypatch.setenv("FF_PALLAS_INTERPRET", "1")
+    assert ffk.use_pallas()           # CPU: the tests' interpreter switch
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="FF_PALLAS_INTERPRET"):
+        ffk.use_pallas()
+    monkeypatch.delenv("FF_PALLAS_INTERPRET")
+    assert ffk.use_pallas()           # TPU without the switch: compiled
+
+
+# ---------------------------------------------------------------------------
+# which chip, which devices
+# ---------------------------------------------------------------------------
+
+def test_chip_for_device(monkeypatch):
+    from flexflow_tpu.search.machine_model import (MachineModel,
+                                                   chip_for_device)
+
+    assert chip_for_device() == "cpu-sim"         # the test backend
+    dev = lambda kind: types.SimpleNamespace(platform="tpu",
+                                             device_kind=kind)
+    assert chip_for_device(dev("TPU v5 lite")) == "v5e"
+    assert chip_for_device(dev("TPU v4")) == "v4"
+    with pytest.raises(ValueError, match="TPU v9"):
+        chip_for_device(dev("TPU v9"))
+    # tpu_chip=None reaches the search and the cost export through here
+    monkeypatch.setattr(jax, "devices", lambda *a: [dev("TPU v9")])
+    with pytest.raises(ValueError, match="TPU v9"):
+        MachineModel.from_name(None, 1)
+
+
+def test_make_mesh_uses_num_devices():
+    import flexflow_tpu as ff
+    from flexflow_tpu.parallel.mesh import make_mesh
+
+    n = len(jax.devices())
+    assert n > 1                                  # conftest's virtual mesh
+    assert make_mesh(ff.FFConfig()).devices.size == n    # absorbed into dp
+    one = make_mesh(ff.FFConfig(num_devices=1))
+    assert one.devices.size == 1
+    assert one.devices.flat[0] == jax.devices()[0]
+    tp = make_mesh(ff.FFConfig(num_devices=2, tensor_parallelism_degree=2))
+    assert dict(tp.shape) == {"model": 2}
+    with pytest.raises(ValueError, match="num_devices"):
+        make_mesh(ff.FFConfig(num_devices=n + 1))
+
+
+# ---------------------------------------------------------------------------
+# compile cache: placeable, fixed, never set by the package or the tests
+# ---------------------------------------------------------------------------
+
+def test_compile_cache_dir(monkeypatch):
+    from flexflow_tpu.utils import compile_cache
+
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: updates.append((k, v)))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/else")
+    assert compile_cache.enable_compile_cache() == "/somewhere/else"
+    # JAX reads the variable itself: no directory is set in code
+    assert "jax_compilation_cache_dir" not in dict(updates)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    fixed = os.path.join(REPO, ".jax_cache")
+    assert compile_cache.enable_compile_cache() == fixed
+    assert dict(updates)["jax_compilation_cache_dir"] == fixed
+
+
+# ---------------------------------------------------------------------------
+# native library: keyed on what it was built from, not on mtimes
+# ---------------------------------------------------------------------------
+
+def test_native_build_is_keyed_on_source_content(tmp_path, monkeypatch):
+    from flexflow_tpu import native
+
+    if not native.native_available():
+        pytest.skip("no native toolchain")
+    assert not native._needs_build()
+    stamp = tmp_path / "stamp"
+    monkeypatch.setattr(native, "_STAMP_PATH", str(stamp))
+    assert native._needs_build()                  # no stamp: unknown origin
+    stamp.write_text("0" * 64 + "\n")
+    assert native._needs_build()                  # built from other sources
+    stamp.write_text(native._source_hash() + "\n")
+    # a newer source mtime alone changes nothing; content decides
+    assert not native._needs_build()
+
+
+# ---------------------------------------------------------------------------
+# chip_smoke.py itself
+# ---------------------------------------------------------------------------
+
+def _run_smoke(*args, timeout):
+    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
+    env.pop("XLA_FLAGS", None)
+    return subprocess.run([sys.executable, os.path.join(REPO,
+                                                        "chip_smoke.py"),
+                           *args], capture_output=True, text=True, env=env,
+                          timeout=timeout)
+
+
+def test_chip_smoke_refuses_without_a_tpu(monkeypatch, capsys):
+    monkeypatch.syspath_prepend(REPO)
+    import chip_smoke
+
+    assert chip_smoke.main([]) != 0               # in-process: conftest's CPU
+    out = capsys.readouterr()
+    assert out.out == ""                          # no result of any kind
+    assert "no TPU" in out.err
+
+
+def test_chip_smoke_result_line_has_exactly_the_contract_keys(monkeypatch):
+    monkeypatch.syspath_prepend(REPO)
+    import chip_smoke
+
+    out = json.loads(chip_smoke.result_line(
+        True, {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}))
+    assert out == {"ok": True, "device": {"platform": "tpu",
+                                          "kind": "TPU v5 lite", "count": 1}}
+    assert list(out) == ["ok", "device"]
+    assert list(out["device"]) == ["platform", "kind", "count"]
+
+
+@pytest.mark.slow
+def test_chip_smoke_cpu_rehearsal():
+    r = _run_smoke("--rehearse-cpu", timeout=900)
+    assert r.returncode == 0, r.stderr[-2000:]
+    summary, result = map(json.loads, r.stdout.strip().splitlines()[-2:])
+    # the last line is the result alone; the summary is the line before it
+    assert result == {"ok": True, "device": summary["device"]}
+    assert set(result["device"]) == {"platform", "kind", "count"}
+    out = summary
+    assert out["ok"] and out["rehearsal"] and out["claim"] is None
+    assert list(out)[-1] == "claim"
+    assert out["device"]["platform"] == "cpu"
+    assert out["phases"]["serving"]["specinfer"]["match_first30"] == "4/4"
+    assert out["phases"]["serving"]["attention"]["fallback_traces"] == {}

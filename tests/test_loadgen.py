@@ -6,8 +6,9 @@ reproducible, goodput/deadline accounting is exact on a hand-built
 record set, sliding-window percentiles match the exact-histogram values
 on retained samples, the end-to-end runner drives the background-server
 submission queue and yields the queue-wait/service decomposition, and
-tools/bench_trend.py passes the committed r01-r05 trajectory while
-flagging a synthetic 10% throughput regression (the gate's own smoke)."""
+tools/bench_trend.py passes a steady synthetic trajectory (skipping its
+failed round) while flagging a synthetic 10% throughput regression (the
+gate's own smoke; histories are built from tests/bench_round_fixture.json)."""
 
 import json
 import os
@@ -189,22 +190,30 @@ def _trend():
     return bench_trend
 
 
-def test_bench_trend_passes_committed_history():
+def test_bench_trend_passes_steady_history(tmp_path, bench_round):
     bt = _trend()
-    rounds = bt.load_rounds(REPO)
-    assert len(rounds) >= 5                      # r01..r05 committed
-    assert not rounds[1]["ok"]                   # r02 tunnel flake skipped
+    for n in (1, 3, 4, 5):                       # a steady trajectory ...
+        (tmp_path / f"BENCH_r{n:02d}.json").write_text(
+            json.dumps({**bench_round, "n": n}))
+    (tmp_path / "BENCH_r02.json").write_text(    # ... with one failed round
+        json.dumps({"n": 2, "rc": 1, "parsed": None}))
+    rounds = bt.load_rounds(str(tmp_path))
+    assert len(rounds) == 5
+    assert not rounds[1]["ok"]                   # failed round is skipped
     regressions, lines = bt.check_trajectory(rounds)
     assert regressions == [], "\n".join(lines)
-    # CLI --check smoke: exit code 0 on the real trajectory
-    assert bt.main(["--check", "--dir", REPO]) == 0
+    # CLI --check smoke: exit code 0 on the steady trajectory
+    assert bt.main(["--check", "--dir", str(tmp_path)]) == 0
 
 
-def test_bench_trend_flags_synthetic_regression(tmp_path, capsys):
+def test_bench_trend_flags_synthetic_regression(tmp_path, capsys,
+                                                bench_round):
     bt = _trend()
-    for name in ("BENCH_r03.json", "BENCH_r04.json", "BENCH_r05.json"):
-        (tmp_path / name).write_text(open(os.path.join(REPO, name)).read())
-    bad = json.load(open(os.path.join(REPO, "BENCH_r05.json")))
+    good = bench_round
+    for n in (3, 4, 5):
+        (tmp_path / f"BENCH_r{n:02d}.json").write_text(
+            json.dumps({**good, "n": n}))
+    bad = dict(good)
     bad["n"] = 6
     bad["parsed"] = dict(bad["parsed"])
     bad["parsed"]["value"] = round(bad["parsed"]["value"] * 0.9, 2)
@@ -215,7 +224,6 @@ def test_bench_trend_flags_synthetic_regression(tmp_path, capsys):
     out = capsys.readouterr()
     assert "BENCH TREND GATE FAILED" in out.err
     # a serving_load regression is gated the same way once present
-    good = json.load(open(os.path.join(REPO, "BENCH_r05.json")))
     g5, g6 = dict(good), dict(good)
     g5["parsed"] = dict(good["parsed"])
     g5["parsed"]["serving_load"] = {"peak_tokens_per_s": 100.0}

@@ -181,9 +181,9 @@ def _trend():
     return bench_trend
 
 
-def test_bench_trend_serving_overload_floor(tmp_path):
+def test_bench_trend_serving_overload_floor(tmp_path, bench_round):
     bt = _trend()
-    good = json.load(open(os.path.join(REPO, "BENCH_r05.json")))
+    good = bench_round
     (tmp_path / "BENCH_r05.json").write_text(json.dumps(good))
     bad = dict(good)
     bad["n"] = 6

@@ -19,7 +19,6 @@ from flexflow_tpu.parallel.collectives import (
     psum,
     reduce_scatter,
 )
-from flexflow_tpu.utils.shard_map_compat import shard_map
 
 
 def make_data(n=256, d=32, classes=10, seed=0):
@@ -111,8 +110,8 @@ def test_collectives_shard_map():
 
         # all_gather output is vma-varying under shard_map, so emit it with
         # P("x") (each shard's identical copy concatenated) rather than P().
-        return shard_map(body, mesh=mesh, in_specs=P("x"),
-                         out_specs=(P(), P("x"), P("x"), P("x")))(v)
+        return jax.shard_map(body, mesh=mesh, in_specs=P("x"),
+                             out_specs=(P(), P("x"), P("x"), P("x")))(v)
 
     v = jnp.arange(8.0)
     s, g, rs, shifted = run(v)

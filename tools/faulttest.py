@@ -62,14 +62,8 @@ def main(argv=None):
                     help="wall-clock bound every future must resolve in")
     ap.add_argument("--no-restart", action="store_true",
                     help="do not restart the server after a fault")
-    ap.add_argument("--platform", choices=("cpu", "default"), default="cpu")
     ap.add_argument("--json", default=None, metavar="PATH")
     args = ap.parse_args(argv)
-
-    if args.platform == "cpu":
-        import jax
-
-        jax.config.update("jax_platforms", "cpu")
 
     from flexflow_tpu.serve.admission import AdmissionPolicy
     from flexflow_tpu.serve.faultinject import FaultInjector, run_chaos

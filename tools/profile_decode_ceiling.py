@@ -213,6 +213,9 @@ def run(name, scanned):
 
 
 if __name__ == "__main__":
+    from flexflow_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     print(f"geometry: {LAYERS}L x {HIDDEN} int8, R={R} W={W} S={S}, "
           f"T={STEPS}")
     run("unrolled", scanned=False)

@@ -128,6 +128,9 @@ def run_decode(trace_dir, fusion=True):
 
 
 if __name__ == "__main__":
+    from flexflow_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     what = sys.argv[1] if len(sys.argv) > 1 else "resnet"
     modes = ("resnet", "decode", "decode-nofuse")
     if what not in modes:
