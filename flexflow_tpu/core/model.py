@@ -703,7 +703,10 @@ class FFModel:
             lp = fetch_layer_params(lp, offloaded[layer.name])
         if not impl.quant_aware:
             lp = dequantize_layer_params(lp, ctx.compute_dtype)
-        outs = impl.forward(layer.attrs, lp, ins, ctx)
+        # compile-time metadata only: the layer's operations carry
+        # "<op_type>/<layer name>" in a device trace (README "Telemetry")
+        with jax.named_scope(f"{layer.op_type.name.lower()}/{layer.name}"):
+            outs = impl.forward(layer.attrs, lp, ins, ctx)
         if self.strategy is not None and self.policy is not None:
             strat_op = self.strategy.ops.get(layer.name)
             if strat_op is not None and outs:

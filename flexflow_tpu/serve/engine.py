@@ -45,6 +45,42 @@ def _resolve_tel(explicit):
     return explicit if explicit is not None else get_telemetry()
 
 
+def _open_block(tel):
+    """A fused block's ``spec_block`` span and its first leaf,
+    ``call_stage`` (both None with telemetry off)."""
+    if tel is None:
+        return None, None
+    return (tel.tracer.begin("spec_block"),
+            tel.call_phase(None, "call_stage", "spec_block"))
+
+
+def _report_block(engine, tel, span, wait, seconds, packed, n_rounds,
+                  trace):
+    """Telemetry after one fused block's read-back (the device fence):
+    closes the call's ``call_wait`` leaf and its ``spec_block`` span,
+    feeds the speculation metrics, and reports compiles the call made.
+    ``trace`` is the scheduler round's RoundTrace, if a loop drives the
+    engine: its ``sched_commit`` phase opens as soon as the spans are
+    closed, so the bookkeeping here counts as the round's."""
+    tel.call_phase(wait, None)
+    name = type(engine).__name__
+    ran = packed[:, :, -2] >= 0
+    tel.tracer.end(span, rounds_asked=n_rounds,
+                   rounds=int(ran.any(axis=0).sum()),
+                   rows=int(ran.any(axis=1).sum()),
+                   committed=int((packed[:, :, -2][ran] + 1).sum()),
+                   engine=name)
+    if trace is not None:
+        trace.phase("sched_commit")
+    tel.record_spec_block(seconds, packed[:, :, -2],
+                          depths=packed[:, :, -1])
+    if engine._trace_count != engine._traces_reported:
+        tel.note_retrace(name,
+                         engine._trace_count - engine._traces_reported,
+                         engine._trace_count)
+        engine._traces_reported = engine._trace_count
+
+
 def build_feeds(model, meta):
     """The ONE place feed construction / position offsets live — used by
     the jitted serving body below and the eager debug-dump path
@@ -414,69 +450,73 @@ class MultiSpecEngine:
         d_run = jnp.max(jnp.where(active, depth_r, 1))
 
         chains = []
-        for j in range(B):
-            ssm_states[j], chain = self._draft(
-                j, ssm_ps[j], ssm_states[j], tks, nblk, base, active,
-                jax.random.fold_in(rng, 100 + j), d_run)
-            chains.append(chain)
+        with jax.named_scope("draft"):
+            for j in range(B):
+                ssm_states[j], chain = self._draft(
+                    j, ssm_ps[j], ssm_states[j], tks, nblk, base, active,
+                    jax.random.fold_in(rng, 100 + j), d_run)
+                chains.append(chain)
 
         # --- verify: root + B chains as a constant-topology tree ---
         from flexflow_tpu.serve.batch_config import TreeBatchMeta
 
-        root = jnp.take_along_axis(
-            tks, jnp.maximum(nblk - 1, 0)[:, None], axis=1)[:, 0]
-        tokens = jnp.concatenate([root[:, None]] + chains, axis=1)  # [R,T]
-        Tp = self.tree_width
-        tokens = jnp.pad(tokens, ((0, 0), (0, Tp - T)))
-        parent, depth_of, anc = self._tree_constants(R)
-        positions = r_pos[:, None] + depth_of[None, :]
-        meta = TreeBatchMeta(
-            tokens=tokens, positions=positions, parent=parent,
-            ancestor=anc, start_pos=r_pos,
-            num_nodes=jnp.where(active, T, 0).astype(jnp.int32),
-            active=active)
-        out, llm_state = forward_with_meta(
-            self.llm, llm_params, llm_state, meta,
-            jax.random.fold_in(rng, 7), self._compute_dtype,
-            kv_contiguous=True)
-        o = out.astype(jnp.int32)                   # [R, T]
+        with jax.named_scope("verify"):
+            root = jnp.take_along_axis(
+                tks, jnp.maximum(nblk - 1, 0)[:, None], axis=1)[:, 0]
+            tokens = jnp.concatenate([root[:, None]] + chains, axis=1)  # [R,T]
+            Tp = self.tree_width
+            tokens = jnp.pad(tokens, ((0, 0), (0, Tp - T)))
+            parent, depth_of, anc = self._tree_constants(R)
+            positions = r_pos[:, None] + depth_of[None, :]
+            meta = TreeBatchMeta(
+                tokens=tokens, positions=positions, parent=parent,
+                ancestor=anc, start_pos=r_pos,
+                num_nodes=jnp.where(active, T, 0).astype(jnp.int32),
+                active=active)
+            out, llm_state = forward_with_meta(
+                self.llm, llm_params, llm_state, meta,
+                jax.random.fold_in(rng, 7), self._compute_dtype,
+                kv_contiguous=True)
+            o = out.astype(jnp.int32)                   # [R, T]
 
         # --- per-branch greedy acceptance, best branch wins ---
-        n_js = []
-        for j in range(B):
-            pred = jnp.concatenate(
-                [o[:, :1], o[:, 1 + j * d: j * d + d]], axis=1)  # [R, d]
-            # longest matching prefix = index of the first mismatch
-            # (argmin of [match, 0] — cumprod lowers to a slow O(d^2)
-            # reduce-window on some backends); positions past the row's
-            # controller depth count as mismatches, so n_acc <= depth_r
-            match = ((chains[j] == pred)
-                     & (jnp.arange(d)[None, :] < depth_r[:, None])
-                     ).astype(jnp.int32)
-            n_js.append(jnp.argmin(
-                jnp.pad(match, ((0, 0), (0, 1))), axis=1).astype(jnp.int32))
-        n_mat = jnp.stack(n_js, axis=1)             # [R, B]
-        best_j = jnp.argmax(n_mat, axis=1).astype(jnp.int32)
-        n_acc = jnp.max(n_mat, axis=1)
-        bonus_idx = jnp.where(n_acc == 0, 0, 1 + best_j * d + n_acc - 1)
-        bonus = jnp.take_along_axis(o, bonus_idx[:, None], axis=1)[:, 0]
-        best_chain = jnp.take_along_axis(
-            jnp.stack(chains, axis=1), best_j[:, None, None], axis=1)[:, 0]
+        with jax.named_scope("commit"):
+            n_js = []
+            for j in range(B):
+                pred = jnp.concatenate(
+                    [o[:, :1], o[:, 1 + j * d: j * d + d]], axis=1)  # [R, d]
+                # longest matching prefix = index of the first mismatch
+                # (argmin of [match, 0] — cumprod lowers to a slow O(d^2)
+                # reduce-window on some backends); positions past the row's
+                # controller depth count as mismatches, so n_acc <= depth_r
+                match = ((chains[j] == pred)
+                         & (jnp.arange(d)[None, :] < depth_r[:, None])
+                         ).astype(jnp.int32)
+                n_js.append(jnp.argmin(
+                    jnp.pad(match, ((0, 0), (0, 1))),
+                    axis=1).astype(jnp.int32))
+            n_mat = jnp.stack(n_js, axis=1)             # [R, B]
+            best_j = jnp.argmax(n_mat, axis=1).astype(jnp.int32)
+            n_acc = jnp.max(n_mat, axis=1)
+            bonus_idx = jnp.where(n_acc == 0, 0, 1 + best_j * d + n_acc - 1)
+            bonus = jnp.take_along_axis(o, bonus_idx[:, None], axis=1)[:, 0]
+            best_chain = jnp.take_along_axis(
+                jnp.stack(chains, axis=1), best_j[:, None, None], axis=1)[:, 0]
 
-        if B > 1:
-            # single-branch trees are already contiguous (branch 0's slots
-            # ARE the committed region) — no compaction needed
-            llm_state = self._commit(llm_state, best_j, n_acc, r_pos,
-                                     active)
+            if B > 1:
+                # single-branch trees are already contiguous (branch 0's slots
+                # ARE the committed region) — no compaction needed
+                llm_state = self._commit(llm_state, best_j, n_acc, r_pos,
+                                         active)
 
-        # next round's accepted block: [accepted chain prefix, bonus]
-        blk = jnp.zeros((R, d + 1), jnp.int32)
-        idx = jnp.arange(d + 1)[None, :]
-        blk = jnp.where(idx < n_acc[:, None],
-                        jnp.pad(best_chain, ((0, 0), (0, 1))), blk)
-        blk = jnp.where(idx == n_acc[:, None], bonus[:, None], blk)
-        new_nblk = n_acc + 1
-        new_base = r_pos + 1
+            # next round's accepted block: [accepted chain prefix, bonus]
+            blk = jnp.zeros((R, d + 1), jnp.int32)
+            idx = jnp.arange(d + 1)[None, :]
+            blk = jnp.where(idx < n_acc[:, None],
+                            jnp.pad(best_chain, ((0, 0), (0, 1))), blk)
+            blk = jnp.where(idx == n_acc[:, None], bonus[:, None], blk)
+            new_nblk = n_acc + 1
+            new_base = r_pos + 1
         return (llm_state, ssm_states, blk, new_nblk, new_base, best_chain,
                 n_acc, bonus)
 
@@ -549,7 +589,7 @@ class MultiSpecEngine:
     def run_block(self, tok: np.ndarray, pos: np.ndarray, active: np.ndarray,
                   n_rounds: int, remaining: Optional[np.ndarray] = None,
                   depth: Optional[np.ndarray] = None,
-                  min_depth: int = 1
+                  min_depth: int = 1, trace=None
                   ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
         """Run up to ``n_rounds`` fused tree rounds. Returns
         (toks, n_acc, depth_used): toks[r, k] holds round k's [chain
@@ -560,8 +600,11 @@ class MultiSpecEngine:
         SpecChainEngine.run_block contract (per-row effective depth +
         give-up, no retrace; the tree topology and verify width stay
         static — only draft-chain steps early-exit and acceptance caps
-        per row; depth=None = static legacy behavior)."""
+        per row; depth=None = static legacy behavior). ``trace``: see
+        SpecChainEngine.run_block."""
         n_rounds = min(int(n_rounds), self.max_rounds)
+        tel = _resolve_tel(self.telemetry)
+        span, ph = _open_block(tel)
         if remaining is None:
             remaining = np.full(tok.shape, np.iinfo(np.int32).max // 2,
                                 np.int32)
@@ -577,22 +620,19 @@ class MultiSpecEngine:
                  jnp.asarray(depth),
                  jnp.int32(max(1, min(int(min_depth), self.depth))),
                  jnp.int32(int(adaptive))]
-        tel = _resolve_tel(self.telemetry)
+        if tel is not None:
+            ph = tel.call_phase(ph, "call_launch", "spec_block")
         t0 = time.perf_counter()
         llm_state, ssm_states, packed = self._block(*args)
         self.llm.op_state = llm_state
         for s, st in zip(self.ssms, ssm_states):
             s.op_state = st
+        if tel is not None:
+            ph = tel.call_phase(ph, "call_wait", "spec_block")
         packed = np.asarray(packed)
         if tel is not None:     # the np readback above is the device fence
-            tel.record_spec_block(time.perf_counter() - t0,
-                                  packed[:, :, -2], self.depth,
-                                  self.tree_width, depths=packed[:, :, -1])
-            if self._trace_count != self._traces_reported:
-                tel.note_retrace("MultiSpecEngine",
-                                 self._trace_count - self._traces_reported,
-                                 self._trace_count)
-                self._traces_reported = self._trace_count
+            _report_block(self, tel, span, ph, time.perf_counter() - t0,
+                          packed, n_rounds, trace)
         return packed[:, :, :-2], packed[:, :, -2], packed[:, :, -1]
 
 
@@ -649,35 +689,39 @@ class SpecChainEngine:
             chain = jax.lax.dynamic_update_slice(chain, nxt[:, None], (0, i))
             return i + 1, state, nxt, p + 1, chain
 
-        (_, ssm_state, _, _, chain) = jax.lax.while_loop(
-            draft_cond, draft_body,
-            (jnp.int32(0), ssm_state, tok, pos,
-             jnp.zeros((R, d + 1), jnp.int32)))
-        chain = chain[:, :d]                                    # [R, d]
+        with jax.named_scope("draft"):
+            (_, ssm_state, _, _, chain) = jax.lax.while_loop(
+                draft_cond, draft_body,
+                (jnp.int32(0), ssm_state, tok, pos,
+                 jnp.zeros((R, d + 1), jnp.int32)))
+            chain = chain[:, :d]                                    # [R, d]
 
         # --- verify: one causal pass over [pending, chain...] ---
         # (static width d+1: undrafted tail columns hold zeros whose
         # staged KV is overwritten by later rounds, exactly like padding)
-        vtokens = jnp.concatenate([tok[:, None], chain], axis=1)  # [R, d+1]
-        vpos = pos[:, None] + jnp.arange(d + 1)[None, :]
-        out, llm_state = _forward_tokens(
-            self.llm, llm_params, llm_state, vtokens, vpos, pos,
-            num * (d + 1), active, jax.random.fold_in(rng, d + 1),
-            self._compute_dtype)
-        a = out.astype(jnp.int32)                               # [R, d+1]
+        with jax.named_scope("verify"):
+            vtokens = jnp.concatenate([tok[:, None], chain],
+                                      axis=1)                   # [R, d+1]
+            vpos = pos[:, None] + jnp.arange(d + 1)[None, :]
+            out, llm_state = _forward_tokens(
+                self.llm, llm_params, llm_state, vtokens, vpos, pos,
+                num * (d + 1), active, jax.random.fold_in(rng, d + 1),
+                self._compute_dtype)
+            a = out.astype(jnp.int32)                               # [R, d+1]
 
         # --- greedy acceptance: longest prefix where chain matches ---
         # (= index of the first mismatch; see MultiSpecEngine on cumprod)
         # capped per row at the controller depth: positions past depth_r
         # count as mismatches, so n_acc <= depth_r
-        match = ((chain == a[:, :d])
-                 & (jnp.arange(d)[None, :] < depth_r[:, None])
-                 ).astype(jnp.int32)
-        n_acc = jnp.argmin(jnp.pad(match, ((0, 0), (0, 1))),
-                           axis=1).astype(jnp.int32)            # [R] in [0,d]
-        bonus = jnp.take_along_axis(a, n_acc[:, None], axis=1)[:, 0]
-        new_tok = bonus.astype(jnp.int32)
-        new_pos = pos + n_acc + 1
+        with jax.named_scope("commit"):
+            match = ((chain == a[:, :d])
+                     & (jnp.arange(d)[None, :] < depth_r[:, None])
+                     ).astype(jnp.int32)
+            n_acc = jnp.argmin(jnp.pad(match, ((0, 0), (0, 1))),
+                               axis=1).astype(jnp.int32)    # [R] in [0,d]
+            bonus = jnp.take_along_axis(a, n_acc[:, None], axis=1)[:, 0]
+            new_tok = bonus.astype(jnp.int32)
+            new_pos = pos + n_acc + 1
         return llm_state, ssm_state, new_tok, new_pos, a, n_acc
 
     def _block_impl(self, llm_params, llm_state, ssm_params, ssm_state, tok,
@@ -739,7 +783,7 @@ class SpecChainEngine:
                   n_rounds: int,
                   remaining: Optional[np.ndarray] = None,
                   depth: Optional[np.ndarray] = None,
-                  min_depth: int = 1
+                  min_depth: int = 1, trace=None
                   ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
         """Run up to ``n_rounds`` (<= max_rounds) rounds; returns
         (a, n_acc, depth_used).
@@ -764,8 +808,15 @@ class SpecChainEngine:
         depth_used[r, k] reports the bound each round actually ran under
         (-1 on idle rounds) so the host can attribute its acceptance
         observations.
+
+        ``trace`` is the calling scheduler loop's RoundTrace when
+        telemetry is on (None otherwise, and for direct drivers): the
+        block then hands the round its ``sched_commit`` phase the moment
+        its own spans close.
         """
         n_rounds = min(int(n_rounds), self.max_rounds)
+        tel = _resolve_tel(self.telemetry)
+        span, ph = _open_block(tel)
         if remaining is None:
             remaining = np.full(tok.shape, np.iinfo(np.int32).max // 2,
                                 np.int32)
@@ -774,25 +825,23 @@ class SpecChainEngine:
             depth = np.full(tok.shape, self.depth, np.int32)
         depth = np.clip(np.asarray(depth, np.int32), 1, self.depth)
         min_depth = max(1, min(int(min_depth), self.depth))
-        tel = _resolve_tel(self.telemetry)
+        staged = (jnp.asarray(tok), jnp.asarray(pos), jnp.asarray(active),
+                  jnp.int32(n_rounds),
+                  jnp.asarray(remaining, dtype=jnp.int32),
+                  jnp.asarray(depth), jnp.int32(min_depth),
+                  jnp.int32(int(adaptive)))
+        if tel is not None:
+            ph = tel.call_phase(ph, "call_launch", "spec_block")
         t0 = time.perf_counter()
         (self.llm.op_state, self.ssm.op_state, packed) = self._block(
             self.llm.params, self.llm.op_state, self.ssm.params,
-            self.ssm.op_state, jnp.asarray(tok), jnp.asarray(pos),
-            jnp.asarray(active), jnp.int32(n_rounds),
-            jnp.asarray(remaining, dtype=jnp.int32),
-            jnp.asarray(depth), jnp.int32(min_depth),
-            jnp.int32(int(adaptive)))
+            self.ssm.op_state, *staged)
+        if tel is not None:
+            ph = tel.call_phase(ph, "call_wait", "spec_block")
         packed = np.asarray(packed)
         if tel is not None:     # the np readback above is the device fence
-            tel.record_spec_block(time.perf_counter() - t0,
-                                  packed[:, :, -2], self.depth,
-                                  self.depth + 1, depths=packed[:, :, -1])
-            if self._trace_count != self._traces_reported:
-                tel.note_retrace("SpecChainEngine",
-                                 self._trace_count - self._traces_reported,
-                                 self._trace_count)
-                self._traces_reported = self._trace_count
+            _report_block(self, tel, span, ph, time.perf_counter() - t0,
+                          packed, n_rounds, trace)
         return packed[:, :, :-2], packed[:, :, -2], packed[:, :, -1]
 
 
@@ -879,12 +928,13 @@ class BeamSpecEngine:
         # --- catch-up + root expansion (one causal pass, width d+1) ---
         pos = base[:, None] + jnp.arange(d + 1)[None, :]
         num = jnp.where(active, nblk, 0)
-        out0, ssm_state = forward_with_meta(
-            self.ssm, ssm_params, ssm_state,
-            BatchMeta(tokens=tks, positions=pos, start_pos=base,
-                      num_tokens=num, active=active),
-            jax.random.fold_in(rng, 0), self._compute_dtype,
-            kv_contiguous=True)                       # [R, d+1, 2W]
+        with jax.named_scope("draft"):
+            out0, ssm_state = forward_with_meta(
+                self.ssm, ssm_params, ssm_state,
+                BatchMeta(tokens=tks, positions=pos, start_pos=base,
+                          num_tokens=num, active=active),
+                jax.random.fold_in(rng, 0), self._compute_dtype,
+                kv_contiguous=True)                       # [R, d+1, 2W]
         root_out = jnp.take_along_axis(
             out0, jnp.maximum(nblk - 1, 0)[:, None, None], axis=1)[:, 0]
         root = jnp.take_along_axis(
@@ -942,67 +992,70 @@ class BeamSpecEngine:
             return place_level(t, (ssm_state, tokens, parent, anc, cum),
                                cand, ids_flat, par_flat)
 
-        cum = jnp.zeros((R, W), jnp.float32)
-        carry = (ssm_state, tokens, parent, anc, cum)
-        # level 0 always runs (d_run >= 1): candidates come straight from
-        # the catch-up pass's packed root expansion
-        carry = place_level(
-            0, carry,
-            jnp.log(jnp.maximum(root_out[:, :W].astype(jnp.float32),
-                                1e-20)),
-            root_out[:, W:2 * W], jnp.zeros((R, W), jnp.int32))
-        for t in range(1, d):
-            # controller early-exit: levels past the round's deepest
-            # active row skip their tree forward entirely (their static
-            # node slots keep zeros, which the capped acceptance walk
-            # below never reaches)
-            carry = jax.lax.cond(d_run > t,
-                                 lambda c, t=t: expand_level(t, c),
-                                 lambda c: c, carry)
-        (ssm_state, tokens, parent, anc, cum) = carry
+        with jax.named_scope("draft"):
+            cum = jnp.zeros((R, W), jnp.float32)
+            carry = (ssm_state, tokens, parent, anc, cum)
+            # level 0 always runs (d_run >= 1): candidates come straight from
+            # the catch-up pass's packed root expansion
+            carry = place_level(
+                0, carry,
+                jnp.log(jnp.maximum(root_out[:, :W].astype(jnp.float32),
+                                    1e-20)),
+                root_out[:, W:2 * W], jnp.zeros((R, W), jnp.int32))
+            for t in range(1, d):
+                # controller early-exit: levels past the round's deepest
+                # active row skip their tree forward entirely (their static
+                # node slots keep zeros, which the capped acceptance walk
+                # below never reaches)
+                carry = jax.lax.cond(d_run > t,
+                                     lambda c, t=t: expand_level(t, c),
+                                     lambda c: c, carry)
+            (ssm_state, tokens, parent, anc, cum) = carry
 
         # --- verify the whole tree on the LLM ---
-        meta_v = TreeBatchMeta(
-            tokens=tokens, positions=positions, parent=parent, ancestor=anc,
-            start_pos=r_pos,
-            num_nodes=jnp.where(active, T, 0).astype(jnp.int32),
-            active=active)
-        out_v, llm_state = forward_with_meta(
-            self.llm, llm_params, llm_state, meta_v,
-            jax.random.fold_in(rng, 7), self._compute_dtype,
-            kv_contiguous=True)
-        o = out_v.astype(jnp.int32)                   # [R, Tp]
+        with jax.named_scope("verify"):
+            meta_v = TreeBatchMeta(
+                tokens=tokens, positions=positions, parent=parent,
+                ancestor=anc, start_pos=r_pos,
+                num_nodes=jnp.where(active, T, 0).astype(jnp.int32),
+                active=active)
+            out_v, llm_state = forward_with_meta(
+                self.llm, llm_params, llm_state, meta_v,
+                jax.random.fold_in(rng, 7), self._compute_dtype,
+                kv_contiguous=True)
+            o = out_v.astype(jnp.int32)                   # [R, Tp]
 
         # --- greedy acceptance walk over the levels ---
-        cur = jnp.zeros((R,), jnp.int32)
-        alive = active
-        n_acc = jnp.zeros((R,), jnp.int32)
-        path = jnp.zeros((R, d), jnp.int32)
-        for t in range(d):
-            lvl0 = 1 + t * W
-            tok_lvl = jax.lax.dynamic_slice(tokens, (0, lvl0), (R, W))
-            par_lvl = jax.lax.dynamic_slice(parent, (0, lvl0), (R, W))
-            want = jnp.take_along_axis(o, cur[:, None], axis=1)[:, 0]
-            # depth_r caps the accepted path per row (controller contract)
-            ok = ((par_lvl == cur[:, None]) & (tok_lvl == want[:, None])
-                  & alive[:, None] & (depth_r > t)[:, None])
-            has = jnp.any(ok, axis=1)
-            nxt = lvl0 + jnp.argmax(ok, axis=1).astype(jnp.int32)
-            path = path.at[:, t].set(jnp.where(has, nxt, 0))
-            cur = jnp.where(has, nxt, cur)
-            n_acc = n_acc + has.astype(jnp.int32)
-            alive = alive & has
-        bonus = jnp.take_along_axis(o, cur[:, None], axis=1)[:, 0]
+        with jax.named_scope("commit"):
+            cur = jnp.zeros((R,), jnp.int32)
+            alive = active
+            n_acc = jnp.zeros((R,), jnp.int32)
+            path = jnp.zeros((R, d), jnp.int32)
+            for t in range(d):
+                lvl0 = 1 + t * W
+                tok_lvl = jax.lax.dynamic_slice(tokens, (0, lvl0), (R, W))
+                par_lvl = jax.lax.dynamic_slice(parent, (0, lvl0), (R, W))
+                want = jnp.take_along_axis(o, cur[:, None], axis=1)[:, 0]
+                # depth_r caps the accepted path per row (controller contract)
+                ok = ((par_lvl == cur[:, None]) & (tok_lvl == want[:, None])
+                      & alive[:, None] & (depth_r > t)[:, None])
+                has = jnp.any(ok, axis=1)
+                nxt = lvl0 + jnp.argmax(ok, axis=1).astype(jnp.int32)
+                path = path.at[:, t].set(jnp.where(has, nxt, 0))
+                cur = jnp.where(has, nxt, cur)
+                n_acc = n_acc + has.astype(jnp.int32)
+                alive = alive & has
+            bonus = jnp.take_along_axis(o, cur[:, None], axis=1)[:, 0]
 
-        # --- KV commit: staged slot r_pos+path[t] -> r_pos+1+t ---
-        llm_state = self._commit(llm_state, path, n_acc, r_pos, active)
+            # --- KV commit: staged slot r_pos+path[t] -> r_pos+1+t ---
+            llm_state = self._commit(llm_state, path, n_acc, r_pos, active)
 
-        chain = jnp.take_along_axis(tokens, path, axis=1)   # [R, d]
-        blk = jnp.zeros((R, d + 1), jnp.int32)
-        idx = jnp.arange(d + 1)[None, :]
-        blk = jnp.where(idx < n_acc[:, None],
-                        jnp.pad(chain, ((0, 0), (0, 1))), blk)
-        blk = jnp.where(idx == n_acc[:, None], bonus[:, None], blk)
+            chain = jnp.take_along_axis(tokens, path, axis=1)   # [R, d]
+            blk = jnp.zeros((R, d + 1), jnp.int32)
+            idx = jnp.arange(d + 1)[None, :]
+            blk = jnp.where(idx < n_acc[:, None],
+                            jnp.pad(chain, ((0, 0), (0, 1))), blk)
+            blk = jnp.where(idx == n_acc[:, None], bonus[:, None], blk)
         return (llm_state, ssm_state, blk, n_acc + 1, r_pos + 1, chain,
                 n_acc, bonus)
 
@@ -1097,15 +1150,18 @@ class BeamSpecEngine:
                   active: np.ndarray, n_rounds: int,
                   remaining: Optional[np.ndarray] = None,
                   depth: Optional[np.ndarray] = None,
-                  min_depth: int = 1
+                  min_depth: int = 1, trace=None
                   ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
         """Same packed contract as SpecChainEngine.run_block: the committed
         tokens for slot r in round k are ``a[r, k, :n_acc[r, k] + 1]``
         (accepted path + bonus); n_acc == -1 marks an idle round;
         depth_used reports each round's per-row depth bound (beam levels
         past the round's deepest bound skip their staged tree forward via
-        lax.cond — static layout, no retrace)."""
+        lax.cond — static layout, no retrace). ``trace``: see
+        SpecChainEngine.run_block."""
         n_rounds = min(int(n_rounds), self.max_rounds)
+        tel = _resolve_tel(self.telemetry)
+        span, ph = _open_block(tel)
         if remaining is None:
             remaining = np.full(tok.shape, np.iinfo(np.int32).max // 2,
                                 np.int32)
@@ -1113,23 +1169,21 @@ class BeamSpecEngine:
         if depth is None:
             depth = np.full(tok.shape, self.depth, np.int32)
         depth = np.clip(np.asarray(depth, np.int32), 1, self.depth)
-        tel = _resolve_tel(self.telemetry)
+        staged = (jnp.asarray(tok), jnp.asarray(pos), jnp.asarray(active),
+                  jnp.int32(n_rounds), jnp.asarray(remaining, jnp.int32),
+                  jnp.asarray(depth),
+                  jnp.int32(max(1, min(int(min_depth), self.depth))),
+                  jnp.int32(int(adaptive)))
+        if tel is not None:
+            ph = tel.call_phase(ph, "call_launch", "spec_block")
         t0 = time.perf_counter()
         (self.llm.op_state, self.ssm.op_state, packed) = self._block(
             self.llm.params, self.llm.op_state, self.ssm.params,
-            self.ssm.op_state, jnp.asarray(tok), jnp.asarray(pos),
-            jnp.asarray(active), jnp.int32(n_rounds),
-            jnp.asarray(remaining, jnp.int32), jnp.asarray(depth),
-            jnp.int32(max(1, min(int(min_depth), self.depth))),
-            jnp.int32(int(adaptive)))
+            self.ssm.op_state, *staged)
+        if tel is not None:
+            ph = tel.call_phase(ph, "call_wait", "spec_block")
         packed = np.asarray(packed)
         if tel is not None:     # the np readback above is the device fence
-            tel.record_spec_block(time.perf_counter() - t0,
-                                  packed[:, :, -2], self.depth,
-                                  self.tree_width, depths=packed[:, :, -1])
-            if self._trace_count != self._traces_reported:
-                tel.note_retrace("BeamSpecEngine",
-                                 self._trace_count - self._traces_reported,
-                                 self._trace_count)
-                self._traces_reported = self._trace_count
+            _report_block(self, tel, span, ph, time.perf_counter() - t0,
+                          packed, n_rounds, trace)
         return packed[:, :, :-2], packed[:, :, -2], packed[:, :, -1]

@@ -82,7 +82,7 @@ class InferenceManager:
         return forward_with_meta(self.model, params, op_state, meta, rng,
                                  self._compute_dtype)
 
-    def step(self, meta, want_output: bool = True):
+    def step(self, meta, want_output: bool = True, tel=None):
         """Run one serving step; threads the model's KV caches through.
 
         Returns the op outputs (token ids [R, Q] for graphs ending in
@@ -90,8 +90,14 @@ class InferenceManager:
         donated to the device program). ``want_output=False`` skips the
         blocking device->host readback — prefill chunks whose outputs are
         discarded dispatch asynchronously and overlap with the host
-        building the next batch.
+        building the next batch. ``tel`` (a ServingTelemetry; None: no
+        spans) records the call's ``call_stage`` / ``call_launch`` /
+        ``call_wait`` leaves; an output-free step is program ``prefill``
+        and its caller fences.
         """
+        ph, prog = None, "step" if want_output else "prefill"
+        if tel is not None:
+            ph = tel.call_phase(None, "call_stage", prog)
         self._rng, step_rng = jax.random.split(self._rng)
         if self.model.config.inference_debugging:
             # reference inference_debugging mode: dump every op's
@@ -102,15 +108,25 @@ class InferenceManager:
             dump_serving_step(self.model, meta, "./inference_tensors",
                               self._debug_step, rng=step_rng)
             self._debug_step += 1
+        if tel is not None:
+            ph = tel.call_phase(ph, "call_launch", prog)
         out, new_state = self._step(self.model.params, self.model.op_state,
                                     meta, step_rng)
         self.model.op_state = new_state
         if not want_output:
+            if tel is not None:
+                tel.call_phase(ph, None)
             return None
-        return np.asarray(out)
+        if tel is not None:
+            ph = tel.call_phase(ph, "call_wait", prog)
+        out = np.asarray(out)
+        if tel is not None:
+            tel.call_phase(ph, None)
+        return out
 
     def decode_block(self, tok: np.ndarray, pos: np.ndarray,
-                     active: np.ndarray, n_steps: int) -> np.ndarray:
+                     active: np.ndarray, n_steps: int,
+                     tel=None) -> np.ndarray:
         """Run ``n_steps`` fused decode steps in ONE device program.
 
         The TPU answer to the reference's depth-4 in-flight Legion batch
@@ -118,6 +134,7 @@ class InferenceManager:
         batches, the whole token-feedback loop runs on device via a
         dynamic-trip while_loop — one host round-trip AND one compiled
         program for every block size. Returns int32 [R, n_steps].
+        ``tel``: as in ``step`` (program ``decode_block``).
         """
         from flexflow_tpu.serve.engine import make_decode_block
 
@@ -170,13 +187,23 @@ class InferenceManager:
                     cfg.decode_block_steps,
                     width=self.decode_width)
         n_steps = min(int(n_steps), self.model.config.decode_block_steps)
+        ph = None
+        if tel is not None:
+            ph = tel.call_phase(None, "call_stage", "decode_block")
         self._rng, step_rng = jax.random.split(self._rng)
+        args = (jnp.asarray(tok), jnp.asarray(pos), jnp.asarray(active),
+                step_rng, jnp.int32(n_steps))
+        if tel is not None:
+            ph = tel.call_phase(ph, "call_launch", "decode_block")
         toks, new_state, _last = self._decode_block(
-            self.model.params, self.model.op_state, jnp.asarray(tok),
-            jnp.asarray(pos), jnp.asarray(active), step_rng,
-            jnp.int32(n_steps))
+            self.model.params, self.model.op_state, *args)
         self.model.op_state = new_state
-        return np.asarray(toks)[:, :n_steps]
+        if tel is not None:
+            ph = tel.call_phase(ph, "call_wait", "decode_block")
+        toks = np.asarray(toks)[:, :n_steps]
+        if tel is not None:
+            tel.call_phase(ph, None)
+        return toks
 
     def _decode_block_debug(self, tok, pos, active, n_steps: int):
         from flexflow_tpu.serve.batch_config import BatchMeta

@@ -606,7 +606,7 @@ class RequestManager:
             req.first_token_s = time.perf_counter()
 
     def _timed_prefill(self, ifm, meta, tel, rows=(), active=None,
-                       n_tokens=None):
+                       n_tokens=None, rnd=None):
         """One prefill step, optionally wall-clocked. The step's outputs
         are discarded (want_output=False dispatches asynchronously), so
         honest timing needs an explicit fence on the new op_state
@@ -615,21 +615,35 @@ class RequestManager:
 
         ``rows``/``active`` feed per-request prefill spans; paths whose
         slot->request mapping lives elsewhere (the native scheduler)
-        pass ``n_tokens`` alone and get metrics without spans."""
+        pass ``n_tokens`` alone and get metrics without spans. ``rnd`` is
+        the round's RoundTrace in the loops that have one: the call's
+        ``call_*`` leaves take over from the open phase, and
+        ``sched_build`` resumes after the fence."""
         if tel is None:
             ifm.step(meta, want_output=False)
             return
         from flexflow_tpu.utils.profiling import device_fence
 
-        t0 = time.perf_counter()
-        ifm.step(meta, want_output=False)
-        device_fence(ifm.model.op_state)
+        if rnd is None:
+            t0 = time.perf_counter()
+            ifm.step(meta, want_output=False)
+            device_fence(ifm.model.op_state)
+            dt = time.perf_counter() - t0
+        else:
+            rnd.phase(None)
+            t0 = time.perf_counter()
+            ifm.step(meta, want_output=False, tel=tel)
+            wait = tel.call_phase(None, "call_wait", "prefill")
+            device_fence(ifm.model.op_state)
+            tel.call_phase(wait, None)
+            dt = time.perf_counter() - t0
+            rnd.phase("sched_build")
         if n_tokens is None:
             n_tokens = sum(len(chunk) for _, chunk, _ in rows)
-        tel.record_prefill(time.perf_counter() - t0, n_tokens,
+        tel.record_prefill(dt, n_tokens,
                            [(active[slot].guid, sp, len(chunk))
                             for slot, chunk, sp in rows]
-                           if active is not None else ())
+                           if active is not None else (), t0)
 
     def _tel_tick(self, tel, live, slots: int, max_seq: int):
         """Once per scheduling tick that dispatches decode/spec work:
@@ -720,9 +734,12 @@ class RequestManager:
 
         while self.pending or any(a is not None for a in active):
             tel = self._tel()
+            rnd = tel.begin_round("incr", R) if tel is not None else None
             self._reap_expired(active, max_seq, done)
             self._fill_slots(active, max_seq, done)
             self._prefix_install(active, (("llm", ifm),))
+            if rnd is not None:
+                rnd.admitted(R - active.count(None), len(self.pending))
             # decode-interleaved chunked prefill (ISSUE 19): each engine
             # round dispatches at most ONE bounded prefill chunk AND the
             # decode block for already-caught-up slots — a queued short
@@ -734,7 +751,7 @@ class RequestManager:
             if rows:
                 meta = self._meta_from_rows(R, chunk, rows)
                 # non-final chunk outputs unused
-                self._timed_prefill(ifm, meta, tel, rows, active)
+                self._timed_prefill(ifm, meta, tel, rows, active, rnd=rnd)
                 for slot, chunk_toks, sp in rows:
                     active[slot].cache_depth = sp + len(chunk_toks)
             # decode: every caught-up slot feeds its pending token; the
@@ -769,12 +786,15 @@ class RequestManager:
                 block = max(1, min(block,
                                    max_seq - 1 - int(pos[act].max())))
                 self._tel_tick(tel, live, R, max_seq)
+                if rnd is not None:
+                    rnd.phase(None)
                 t0 = time.perf_counter()
-                toks = ifm.decode_block(tok, pos, act, block)
+                toks = ifm.decode_block(tok, pos, act, block, tel=tel)
                 if tel is not None:   # decode_block's np readback = fence
-                    tel.record_decode_block(time.perf_counter() - t0,
-                                            block, len(live),
-                                            [r.guid for r in live])
+                    dt = time.perf_counter() - t0
+                    rnd.phase("sched_commit", live)
+                    tel.record_decode_block(dt, block, len(live),
+                                            [r.guid for r in live], t0)
                 for req in live:
                     for j in range(block):
                         req.tokens.append(int(toks[req.slot, j]))
@@ -788,6 +808,8 @@ class RequestManager:
                     self._prefix_store(req, (("llm", ifm),))
                     done.append(self._collect(req))
                     active[slot] = None
+            if rnd is not None:
+                rnd.end()
         return done
 
     def _generate_incr_native(self, model, ifm, cfg,
@@ -933,30 +955,45 @@ class RequestManager:
         tel.note_spec_controller(stats["ewma_mean"], stats["n_fallback"],
                                  ctrl.take_new_fallbacks())
 
-    def _partition_spec(self, ctrl, tel, live, roomy, rounds):
+    def _partition_spec(self, ctrl, rnd, live, roomy, rounds):
         """Controller partition shared by the two fused scheduler loops
         (which must stay in sync — see _generate_spec_tree_fused): split
         the roomy requests into (draftable, parked), feed the controller
         telemetry gauges, and shrink a pure-probe tick to ONE round (one
         acceptance sample — minimal probe tax on parked traffic).
+        ``rnd`` is the round's RoundTrace (None: telemetry off).
         Returns (draftable, parked, rounds)."""
-        self._tick_controller(ctrl, tel, live)
+        self._tick_controller(ctrl, None if rnd is None else rnd.tel, live)
         if ctrl is None:
             return roomy, [], rounds
         draftable = [req for req in roomy if ctrl.wants_draft(req.guid)]
         draft_guids = {req.guid for req in draftable}
         parked = [req for req in roomy if req.guid not in draft_guids]
         if draftable and all(ctrl.in_fallback(r.guid) for r in draftable):
+            if rnd is not None and rounds > 1:
+                rnd.note_cut("probe")
             rounds = 1
         return draftable, parked, rounds
 
-    def _fallback_decode(self, llm_ifm, reqs, R, max_seq, cfg, tel) -> int:
+    @staticmethod
+    def _prefill_kind(active, rows) -> str:
+        """What a draft model's prefill rows are: ``catch_up`` when every
+        row only feeds tokens a speculation block committed (the gap the
+        block leaves in the draft's cache), ``prefill`` when a prompt is
+        still going in."""
+        return ("catch_up" if all(active[slot].num_generated > 0
+                                  for slot, _, _ in rows) else "prefill")
+
+    def _fallback_decode(self, llm_ifm, reqs, R, max_seq, cfg, tel,
+                         rnd) -> int:
         """Fused incremental decode for requests the adaptive speculation
         controller parked in fallback: the same decode-block program
         generate_incr_decoding drives (verify-consistent width), so a
         parked request pays exactly the incremental cost and emits the
         identical greedy tokens. Draft caches are left stale; the prefill
         cycle heals them if/when the request probes back into drafting."""
+        if rnd is not None:
+            rnd.phase("sched_build")
         block = min(max(self._remaining_budget(r, max_seq) for r in reqs),
                     cfg.decode_block_steps)
         R_tok = np.zeros((R,), np.int32)
@@ -968,11 +1005,15 @@ class RequestManager:
             act[req.slot] = True
         block = max(1, min(block, max_seq - 1 - int(pos[act].max())))
         self._tel_tick(tel, reqs, R, max_seq)
+        if rnd is not None:
+            rnd.phase(None)
         t0 = time.perf_counter()
-        toks = llm_ifm.decode_block(R_tok, pos, act, block)
+        toks = llm_ifm.decode_block(R_tok, pos, act, block, tel=tel)
         if tel is not None:     # decode_block's np readback = fence
-            tel.record_decode_block(time.perf_counter() - t0, block,
-                                    len(reqs), [r.guid for r in reqs])
+            dt = time.perf_counter() - t0
+            rnd.phase("sched_commit", reqs)
+            tel.record_decode_block(dt, block, len(reqs),
+                                    [r.guid for r in reqs], t0)
         for req in reqs:
             for j in range(block):
                 req.tokens.append(int(toks[req.slot, j]))
@@ -1136,9 +1177,6 @@ class RequestManager:
             live = [req for req in active if req is not None and not req.finished]
             if live:
                 self._tel_tick(tel, live, R, max_seq)
-                if tel is not None:
-                    tel.draft_depth.set(depth)
-                    tel.tree_width.set(T)
                 # ---- draft phase: each SSM proposes chains (or beams) ----
                 chains: List[Dict[int, List[int]]] = []  # per branch: slot->toks
                 for i, ifm in enumerate(ssm_ifms):
@@ -1256,6 +1294,8 @@ class RequestManager:
 
         while self.pending or any(a is not None for a in active):
             tel = self._tel()
+            rnd = (tel.begin_round("spec_chain", R) if tel is not None
+                   else None)
             self._reap_expired(active, max_seq, done, ctrl)
             parked_guids = ({req.guid for req in active if req is not None
                              and ctrl.in_fallback(req.guid)}
@@ -1263,6 +1303,8 @@ class RequestManager:
             self._fill_slots(active, max_seq, done, parked_guids)
             self._prefix_install(active, (("llm", llm_ifm),
                                           ("ssm0", ssm_ifm)))
+            if rnd is not None:
+                rnd.admitted(R - active.count(None), len(self.pending))
             # prompt prefill for both models (same path as incremental);
             # one bounded chunk per model per round — caught-up slots
             # draft/decode below in the SAME round (decode-interleaved
@@ -1287,13 +1329,16 @@ class RequestManager:
                                  or ctrl.wants_draft(active[slot].guid))]
                 if rows:
                     meta = self._meta_from_rows(R, chunk, rows)
-                    self._timed_prefill(ifm, meta, tel, rows, active)
+                    self._timed_prefill(ifm, meta, tel, rows, active,
+                                        rnd=rnd)
                     for slot, toks, sp in rows:
                         if ifm is llm_ifm:
                             active[slot].cache_depth = sp + len(toks)
                         else:
                             active[slot].ssm_cache_depth[0] = sp + len(toks)
                     prefilled = True
+                    if rnd is not None:
+                        rnd.note_cut(self._prefill_kind(active, rows))
             live = [req for req in active
                     if req is not None and not req.finished]
             # decode-interleaved chunked prefill: only slots whose
@@ -1318,7 +1363,7 @@ class RequestManager:
                 # fused incremental block (same cost/tokens as plain
                 # incremental) until their probe round recovers them
                 draftable, parked, rounds = self._partition_spec(
-                    ctrl, tel, live, roomy,
+                    ctrl, rnd, live, roomy,
                     min(cfg.spec_rounds_per_call, engine.max_rounds))
                 if prefilled:
                     # prefill still pending somewhere: one spec round,
@@ -1336,12 +1381,16 @@ class RequestManager:
                     rows = [(req.slot, req.tokens[-1:], len(req.tokens) - 1)
                             for req in cramped]
                     meta = self._meta_from_rows(R, 1, rows)
+                    if rnd is not None:
+                        rnd.phase(None)
                     t0 = time.perf_counter()
-                    out = llm_ifm.step(meta)
+                    out = llm_ifm.step(meta, tel=tel)
                     if tel is not None:   # step's np readback = fence
+                        dt = time.perf_counter() - t0
+                        rnd.phase("sched_commit", cramped)
                         tel.record_decode_block(
-                            time.perf_counter() - t0, 1, len(cramped),
-                            [req.guid for req in cramped])
+                            dt, 1, len(cramped),
+                            [req.guid for req in cramped], t0)
                     for slot, _t, sp in rows:
                         req = active[slot]
                         req.tokens.append(int(out[slot, 0]))
@@ -1352,10 +1401,12 @@ class RequestManager:
                         self._finish_if_done(req, max_seq)
                 if parked:
                     self._fallback_decode(llm_ifm, parked, R, max_seq, cfg,
-                                          tel)
+                                          tel, rnd)
                     for req in parked:
                         ctrl.note_fallback_block(req.guid)
                 if draftable:
+                    if rnd is not None:
+                        rnd.phase("sched_build")
                     tok = np.zeros((R,), np.int32)
                     pos = np.zeros((R,), np.int32)
                     act = np.zeros((R,), bool)
@@ -1378,11 +1429,15 @@ class RequestManager:
                     # hand THIS manager's explicit telemetry through (a
                     # None keeps the engine on the process-global one)
                     engine.telemetry = self.telemetry
+                    if rnd is not None:
+                        rnd.phase(None)
                     t0 = time.perf_counter()
                     a, n_acc, d_used = engine.run_block(
                         tok, pos, act, rounds, remaining, depth=depth_vec,
-                        min_depth=gc.min_spec_depth)
+                        min_depth=gc.min_spec_depth, trace=rnd)
                     block_dt = time.perf_counter() - t0
+                    if rnd is not None:
+                        rnd.phase("sched_commit", draftable)
                     for req in draftable:
                         round_events = []
                         observed = []
@@ -1424,6 +1479,8 @@ class RequestManager:
                                              ("ssm0", ssm_ifm)))
                     done.append(self._collect(req))
                     active[slot] = None
+            if rnd is not None:
+                rnd.end()
         return done
 
     def _generate_spec_tree_fused(self, llm, ssms: List[Any],
@@ -1479,6 +1536,8 @@ class RequestManager:
 
         while self.pending or any(a is not None for a in active):
             tel = self._tel()
+            rnd = (tel.begin_round("spec_tree", R) if tel is not None
+                   else None)
             self._reap_expired(active, max_seq, done, ctrl)
             parked_guids = ({req.guid for req in active if req is not None
                              and ctrl.in_fallback(req.guid)}
@@ -1488,6 +1547,8 @@ class RequestManager:
                 active, (("llm", llm_ifm),
                          *((f"ssm{i}", m)
                            for i, m in enumerate(ssm_ifms))))
+            if rnd is not None:
+                rnd.admitted(R - active.count(None), len(self.pending))
             # one bounded prefill chunk per model per round; caught-up
             # slots spec/decode below in the SAME round (ISSUE 19)
             prefilled = False
@@ -1495,10 +1556,13 @@ class RequestManager:
                                       cfg.max_tokens_per_batch)
             if rows:
                 meta = self._meta_from_rows(R, chunk, rows)
-                self._timed_prefill(llm_ifm, meta, tel, rows, active)
+                self._timed_prefill(llm_ifm, meta, tel, rows, active,
+                                    rnd=rnd)
                 for slot, toks, sp in rows:
                     active[slot].cache_depth = sp + len(toks)
                 prefilled = True
+                if rnd is not None:
+                    rnd.note_cut("prefill")
             for i, ifm in enumerate(ssm_ifms):
                 rows = self._prefill_rows(
                     active, chunk, lambda r, i=i: r.ssm_cache_depth.get(i, 0),
@@ -1510,10 +1574,13 @@ class RequestManager:
                              or ctrl.wants_draft(active[slot].guid))]
                 if rows:
                     meta = self._meta_from_rows(R, chunk, rows)
-                    self._timed_prefill(ifm, meta, tel, rows, active)
+                    self._timed_prefill(ifm, meta, tel, rows, active,
+                                        rnd=rnd)
                     for slot, toks, sp in rows:
                         active[slot].ssm_cache_depth[i] = sp + len(toks)
                     prefilled = True
+                    if rnd is not None:
+                        rnd.note_cut(self._prefill_kind(active, rows))
             live = [req for req in active
                     if req is not None and not req.finished]
             # decode-interleaved chunked prefill: mid-prefill slots sit
@@ -1521,13 +1588,15 @@ class RequestManager:
             ready = [req for req in live
                      if req.cache_depth == len(req.tokens) - 1]
             if not ready:
+                if rnd is not None:
+                    rnd.end()
                 continue
             roomy = [req for req in ready
                      if max_seq - len(req.tokens) >= room_needed]
             cramped = [req for req in ready
                        if max_seq - len(req.tokens) < room_needed]
             draftable, parked, rounds = self._partition_spec(
-                ctrl, tel, live, roomy,
+                ctrl, rnd, live, roomy,
                 min(cfg.spec_rounds_per_call, engine.max_rounds))
             if prefilled:
                 rounds = 1      # see chain-path note
@@ -1541,12 +1610,16 @@ class RequestManager:
                 rows = [(req.slot, req.tokens[-1:], len(req.tokens) - 1)
                         for req in cramped]
                 meta = self._meta_from_rows(R, 1, rows)
+                if rnd is not None:
+                    rnd.phase(None)
                 t0 = time.perf_counter()
-                out = llm_ifm.step(meta)
+                out = llm_ifm.step(meta, tel=tel)
                 if tel is not None:       # step's np readback = fence
-                    tel.record_decode_block(time.perf_counter() - t0, 1,
-                                            len(cramped),
-                                            [req.guid for req in cramped])
+                    dt = time.perf_counter() - t0
+                    rnd.phase("sched_commit", cramped)
+                    tel.record_decode_block(dt, 1, len(cramped),
+                                            [req.guid for req in cramped],
+                                            t0)
                 for slot, _t, sp in rows:
                     req = active[slot]
                     req.tokens.append(int(out[slot, 0]))
@@ -1557,10 +1630,13 @@ class RequestManager:
                     self._note_first_token(req)
                     self._finish_if_done(req, max_seq)
             if parked:
-                self._fallback_decode(llm_ifm, parked, R, max_seq, cfg, tel)
+                self._fallback_decode(llm_ifm, parked, R, max_seq, cfg, tel,
+                                      rnd)
                 for req in parked:
                     ctrl.note_fallback_block(req.guid)
             if draftable:
+                if rnd is not None:
+                    rnd.phase("sched_build")
                 tok = np.zeros((R,), np.int32)
                 pos = np.zeros((R,), np.int32)
                 act = np.zeros((R,), bool)
@@ -1581,11 +1657,15 @@ class RequestManager:
                         depth_vec[req.slot] = ctrl.depth_for(req.guid)
                 self._tel_tick(tel, draftable, R, max_seq)
                 engine.telemetry = self.telemetry   # see chain-path note
+                if rnd is not None:
+                    rnd.phase(None)
                 t0 = time.perf_counter()
                 toks, n_acc, d_used = engine.run_block(
                     tok, pos, act, rounds, remaining, depth=depth_vec,
-                    min_depth=gc.min_spec_depth)
+                    min_depth=gc.min_spec_depth, trace=rnd)
                 block_dt = time.perf_counter() - t0
+                if rnd is not None:
+                    rnd.phase("sched_commit", draftable)
                 for req in draftable:
                     last_rpos = len(req.tokens) - 1
                     round_events = []
@@ -1635,6 +1715,8 @@ class RequestManager:
                                 for i, m in enumerate(ssm_ifms))))
                     done.append(self._collect(req))
                     active[slot] = None
+            if rnd is not None:
+                rnd.end()
         return done
 
     def _draft_chains(self, ifm, ssm_idx, live, R, depth):

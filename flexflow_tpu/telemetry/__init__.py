@@ -33,7 +33,6 @@ ffsv_acceptance_length           histogram  accepted draft tokens per round
 ffsv_tokens_per_round            histogram  committed tokens per round (+bonus)
 ffsv_batch_occupancy             histogram  live slots / max slots per tick
 ffsv_kv_cache_utilization        histogram  mean seq_len / max_seq over live
-ffsv_prefill_queue_depth         gauge      pending (unadmitted) requests
 ffsv_prefill_step_seconds        histogram  device-fenced prefill step time
 ffsv_decode_block_seconds        histogram  device-fenced decode block time
 ffsv_spec_block_seconds          histogram  device-fenced speculation block
@@ -42,8 +41,6 @@ ffsv_request_ttft_seconds        histogram  admission -> first token
 ffsv_request_queue_wait_seconds  histogram  admission -> batch-slot grant
 ffsv_request_prefill_seconds     histogram  slot grant -> first token
 ffsv_per_token_latency_seconds   histogram  latency / output tokens
-ffsv_draft_depth                 gauge      compiled speculation chain depth
-ffsv_tree_width                  gauge      verify-pass token-tree width
 ffsv_spec_effective_depth        histogram  controller depth per spec round
 ffsv_spec_fallback_total         counter    requests parked on incremental
 ffsv_spec_fallback_active        gauge      requests currently parked
@@ -57,6 +54,13 @@ ffsv_prefix_cache_evictions_total counter   pooled prefixes LRU-evicted
 ffsv_prefix_shared_tokens_total  counter    prompt tokens served from the pool
 ffsv_prefix_pool_tokens          gauge      tokens held by the prefix pool
 ===============================  =========  =================================
+
+Batch-level spans (``tracing.SpanTracer.begin``/``end``, ``tid`` 0): the
+Python scheduler loops open a ``RoundTrace`` per iteration (``sched_round``
+with the leaves ``sched_admit`` / ``sched_build`` / ``sched_commit``), and
+every device call records ``call_stage`` / ``call_launch`` / ``call_wait``
+through ``ServingTelemetry.call_phase`` (inside a ``spec_block`` span for
+the fused engines); README "Telemetry" has the table.
 
 Fleet layer (this package's distributed half): ``fleet.FleetTelemetry``
 keeps one ServingTelemetry per replica (distinct Chrome-trace ``pid``
@@ -106,6 +110,68 @@ from flexflow_tpu.telemetry.tracing import (SpanTracer, load_jsonl,
                                             stitch_chrome_trace)
 
 
+class RoundTrace:
+    """The phase spans of one scheduler-loop iteration (``sched_round``
+    on ``tid`` 0). Exactly one leaf is open at a time: ``sched_admit``
+    from the start, then whatever ``phase`` names; ``phase(None)`` closes
+    the open leaf before a device call, whose own ``call_*`` leaves
+    (``ServingTelemetry.call_phase``) take over until the loop names the
+    next phase. Built only with telemetry on (``begin_round``)."""
+
+    __slots__ = ("tel", "round", "leaf", "args", "grants0", "reqs",
+                 "tokens0")
+
+    def __init__(self, tel: "ServingTelemetry", loop: str, slots: int):
+        self.tel = tel
+        self.args = {"loop": loop, "slots": slots}
+        self.grants0 = tel.n_grants
+        self.reqs = None
+        self.tokens0 = 0
+        self.round = tel.tracer.begin("sched_round")
+        self.leaf = tel.tracer.begin("sched_admit")
+
+    def phase(self, name: Optional[str], reqs=None):
+        """Close the open leaf and open ``name`` (a leaf of that name
+        already open stays open). ``reqs``, given with ``sched_commit``,
+        are the requests whose tokens the phase appends: the span
+        reports how many it committed."""
+        tr = self.tel.tracer
+        leaf = self.leaf
+        if leaf is not None and leaf[0] != name:
+            if self.reqs is not None:
+                tr.end(leaf, committed=sum(len(r.tokens) for r in self.reqs)
+                       - self.tokens0)
+                self.reqs = None
+            else:
+                tr.end(leaf)
+            leaf = None
+        if reqs is not None:
+            self.reqs = reqs
+            self.tokens0 = sum(len(r.tokens) for r in reqs)
+        if leaf is None:
+            self.leaf = tr.begin(name) if name else None
+
+    def admitted(self, live: int, pending: int):
+        """Admission is over: ``sched_admit`` closes with the slots it
+        granted, the round notes its live and pending requests, and
+        ``sched_build`` opens."""
+        self.tel.tracer.end(self.leaf,
+                            granted=self.tel.n_grants - self.grants0)
+        self.args["live"] = live
+        self.args["pending"] = pending
+        self.leaf = self.tel.tracer.begin("sched_build")
+
+    def note_cut(self, why: str):
+        """Why this round's speculation block was held to one round
+        (``prefill``, ``catch_up`` or ``probe``); the first cause
+        stands."""
+        self.args.setdefault("cut", why)
+
+    def end(self):
+        self.phase(None)
+        self.tel.tracer.end(self.round, **self.args)
+
+
 class ServingTelemetry:
     """One registry + tracer pair with the serving hook vocabulary.
 
@@ -128,6 +194,9 @@ class ServingTelemetry:
         self.flight = FlightRecorder(
             self.FLIGHT_CAPACITY if flight_capacity is None
             else flight_capacity)
+        # slot grants so far: a scheduler round's ``sched_admit`` span
+        # reports the difference over its admission phase
+        self.n_grants = 0
         win = self.SLO_WINDOW_S if slo_window_s is None else slo_window_s
         r = self.registry
         self.requests_total = r.counter(
@@ -176,8 +245,6 @@ class ServingTelemetry:
             "ffsv_kv_cache_utilization",
             "mean sequence length / max_seq over live requests",
             buckets=FRACTION_BUCKETS)
-        self.queue_depth = r.gauge(
-            "ffsv_prefill_queue_depth", "pending (unadmitted) requests")
         self.prefill_seconds = r.histogram(
             "ffsv_prefill_step_seconds", "device-fenced prefill step time")
         self.decode_block_seconds = r.histogram(
@@ -201,10 +268,6 @@ class ServingTelemetry:
         self.per_token_latency = r.histogram(
             "ffsv_per_token_latency_seconds",
             "request latency / output tokens", window_s=win)
-        self.draft_depth = r.gauge(
-            "ffsv_draft_depth", "compiled speculation chain depth")
-        self.tree_width = r.gauge(
-            "ffsv_tree_width", "verify-pass token-tree width")
         # adaptive speculation controller (serve/spec_controller.py)
         self.spec_effective_depth = r.histogram(
             "ffsv_spec_effective_depth",
@@ -265,7 +328,6 @@ class ServingTelemetry:
     def note_batch(self, pending: int, live: int, slots: int,
                    kv_fraction: Optional[float]):
         """Once per host scheduling tick that dispatched device work."""
-        self.queue_depth.set(pending)
         self.submit_queue_depth.set(pending)
         self.batch_occupancy.observe(live / max(1, slots))
         if kv_fraction is not None:
@@ -315,6 +377,7 @@ class ServingTelemetry:
         -> service boundary, recorded for crash forensics — "what was
         scheduled right before the crash" is the first question an
         incident report answers."""
+        self.n_grants += 1
         self.flight.record("slot_grant", guid=guid, slot=slot)
 
     def note_retrace(self, engine: str, new_traces: int,
@@ -342,25 +405,31 @@ class ServingTelemetry:
         self.flight.record("failover", guid=guid, replica=replica,
                            target=target, trace_id=trace_id)
 
-    def record_prefill(self, seconds: float, n_tokens: int, rows=()):
+    def record_prefill(self, seconds: float, n_tokens: int, rows=(),
+                       t0: Optional[float] = None):
+        """``t0``: the step's start on ``perf_counter`` (None: it ended
+        just now)."""
         self.prefill_seconds.observe(seconds)
         self.prefill_tokens.inc(n_tokens)
-        t0 = time.perf_counter() - seconds
+        if t0 is None:
+            t0 = time.perf_counter() - seconds
         for guid, start_pos, n in rows:
             self.tracer.prefill(guid, start_pos, n, t0, seconds)
 
     def record_decode_block(self, seconds: float, steps: int, n_live: int,
-                            guids=()):
+                            guids=(), t0: Optional[float] = None):
+        """``t0``: as in ``record_prefill``."""
         self.decode_block_seconds.observe(seconds)
         self.decode_steps.inc(steps * n_live)
-        t0 = time.perf_counter() - seconds
+        if t0 is None:
+            t0 = time.perf_counter() - seconds
         for g in guids:
             self.tracer.decode_block(g, steps, t0, seconds)
         self.flight.record("decode_block", seconds=round(seconds, 6),
                            steps=int(steps), n_live=int(n_live))
 
     def record_spec_block(self, seconds: float, n_acc: np.ndarray,
-                          depth: int, tree_width: int, depths=None):
+                          depths=None):
         """After one fused speculation block (all engines): ``n_acc`` is
         the packed [R, rounds] accepted-length matrix, -1 marking idle
         rounds. Called from engine.run_block, so bench/direct engine
@@ -368,8 +437,6 @@ class ServingTelemetry:
         ``depths`` (same shape, optional) is the per-round EFFECTIVE
         draft depth the adaptive controller ran each row under."""
         self.spec_block_seconds.observe(seconds)
-        self.draft_depth.set(depth)
-        self.tree_width.set(tree_width)
         valid = np.asarray(n_acc).ravel()
         mask = valid >= 0
         valid = valid[mask]
@@ -387,7 +454,22 @@ class ServingTelemetry:
             rounds=int(valid.size), committed=int((valid + 1).sum()),
             mean_acc=(round(float(valid.mean()), 3) if valid.size else 0.0),
             depths=(sorted(set(int(d) for d in dv[dv > 0]))
-                    if dv is not None else [int(depth)]))
+                    if dv is not None else []))
+
+    def call_phase(self, prev, name: Optional[str], program: str = ""):
+        """One device call's leaf spans, in order: ``call_stage`` (build
+        and transfer the inputs), ``call_launch`` (the jitted call until
+        it returns its futures), ``call_wait`` (the blocking read-back
+        or fence). Closes ``prev`` (None: nothing open) and opens
+        ``name`` (None: the call is over); returns the new token."""
+        if prev is not None:
+            self.tracer.end(prev)
+        return self.tracer.begin(name, program=program) if name else None
+
+    def begin_round(self, loop: str, slots: int) -> "RoundTrace":
+        """Open one scheduler-loop iteration's ``sched_round`` span and
+        its first phase, ``sched_admit``."""
+        return RoundTrace(self, loop, slots)
 
     def note_spec_controller(self, ewma_mean, n_fallback: int,
                              new_fallbacks: int):
@@ -530,6 +612,7 @@ __all__ = [
     "Histogram",
     "MetricsHTTPServer",
     "MetricsRegistry",
+    "RoundTrace",
     "SLOMonitor",
     "SLOPolicy",
     "ServingTelemetry",

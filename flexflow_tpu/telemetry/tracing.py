@@ -12,15 +12,26 @@ buffered events into a ``{"traceEvents": [...]}`` file that Perfetto /
 chrome://tracing load directly (the raw JSONL is for programmatic
 consumption: one ``json.loads`` per line).
 
-Correlation with device traces: the first event is a ``clock_sync``
-metadata record holding both ``time.time()`` (wall clock) and the
-``perf_counter`` origin all span timestamps are relative to. A
-``jax.profiler`` trace taken around the same run
-(``utils/profiling.profiler_trace``) timestamps its XLA events on the
-same wall clock, so the recipe is: load both files in Perfetto and align
-on the wall-clock epoch (README "Telemetry" section). Span events also
-carry the guid in ``args`` so a device-trace step can be matched to the
-request(s) it served.
+Batch-level spans (``begin``/``end``, ``tid`` 0): what the scheduler
+loop and one device call do once per occurrence, whatever the number of
+requests in the batch (``sched_round`` > ``sched_admit`` / ``sched_build``
+/ ``sched_commit``; ``spec_block`` > ``call_stage`` / ``call_launch`` /
+``call_wait``; README "Telemetry" has the table). Each is also entered as
+a ``jax.profiler.TraceAnnotation`` under the same name, so a
+``jax.profiler`` trace taken around the run shows it on the host plane,
+on the profiler's own clock, beside the device plane.
+
+Correlation with device traces: spans written after the fact (the
+per-request tracks) carry ``perf_counter`` times only. ``profiler_mark``
+drops a named annotation into the running profiler session and records
+its ``perf_counter`` time as an instant event under the same name; the
+difference between the two is the offset between the clocks
+(``tools/profile_trace.mark_offset_ns``;
+``utils/profiling.profiler_trace`` writes a mark at both ends of its
+session when telemetry is on). The ``clock_sync`` metadata record holds
+the ``perf_counter`` origin all span timestamps are relative to. Span
+events also carry the guid in ``args`` so a device-trace step can be
+matched to the request(s) it served.
 
 Round-granularity caveat: speculation/decode rounds execute INSIDE one
 fused device program (serve/engine.py), so the host only observes the
@@ -37,12 +48,19 @@ import json
 import time
 from typing import IO, Iterable, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 # Process-wide trace-id mint (serve/api.py front door + serve/replica.py
 # pool). A counter, not a UUID: runs replay deterministically, and the
 # id only needs to be unique within one serving process/trace file. The
 # hex digits keep grep-ability ("t-0000002a") without dragging in
 # entropy the tests would have to mock out.
 _trace_counter = itertools.count(1)
+
+# name prefix of the marks ``SpanTracer.profiler_mark`` writes into a
+# profiler session (tools/profile_trace.py finds them by it)
+MARK_PREFIX = "ffsv_mark_"
+_mark_counter = itertools.count()
 
 
 def mint_trace_id() -> str:
@@ -84,7 +102,7 @@ class SpanTracer:
         if path:
             self._file = open(path, "w")
         self.emit("clock_sync", "M", ts_s=self._t0,
-                  wall_time_s=time.time(), perf_counter_origin=self._t0)
+                  perf_counter_origin=self._t0)
         if process_name:
             # Chrome-trace process_name metadata: Perfetto labels this
             # pid's row group (one group per replica in a stitched trace)
@@ -151,6 +169,35 @@ class SpanTracer:
             self._n_written += 1
             if self._n_written % self.FLUSH_EVERY == 0:
                 self._file.flush()
+
+    # -- batch-level spans, live on the profiler's clock too --------------
+    def begin(self, name: str, **args):
+        """Open a batch-level span (``tid`` 0): enters a profiler
+        annotation under ``name`` and returns the token ``end`` closes.
+        Tokens close in the reverse order they opened."""
+        ann = TraceAnnotation(name, **args)
+        ann.__enter__()
+        return (name, ann, args, time.perf_counter())
+
+    def end(self, span, **args):
+        """Close ``span`` and record it as one complete event; ``args``
+        join those given to ``begin``."""
+        name, ann, args0, t0 = span
+        dur = time.perf_counter() - t0
+        ann.__exit__(None, None, None)
+        self.emit(name, "X", ts_s=t0, dur_s=dur, **args0, **args)
+
+    def profiler_mark(self) -> str:
+        """Tie this tracer's clock to a running ``jax.profiler`` session:
+        an empty annotation goes into the session and an instant event of
+        the same name records the ``perf_counter`` time it was written
+        at. Returns the mark's name."""
+        name = f"{MARK_PREFIX}{next(_mark_counter)}"
+        t = time.perf_counter()
+        with TraceAnnotation(name):
+            pass
+        self.emit(name, "i", ts_s=t, perf_counter_s=t)
+        return name
 
     # -- span vocabulary (the JSONL schema documented in README) ----------
     def admission(self, guid: int, prompt_tokens: int, max_new_tokens: int,
@@ -232,8 +279,7 @@ def stitch_chrome_trace(tracers: Iterable["SpanTracer"],
     replicas spawned minutes apart at t=0. Correction: the EARLIEST
     origin becomes the fleet epoch and each tracer's events shift by
     ``(origin_i - origin_base) * 1e6`` µs — all tracers live in one
-    process, so perf_counter deltas ARE the true skew (for cross-host
-    stitching the clock_sync wall_time_s field would anchor instead).
+    process, so perf_counter deltas ARE the true skew.
     Per-tracer pids keep replica rows separate; a failed-over request's
     spans appear under BOTH pids sharing one ``args.trace_id``.
 

@@ -137,12 +137,25 @@ class StepTimer:
                         for k, v in self.summary().items())
 
 
+def _mark_telemetry():
+    from flexflow_tpu.telemetry import get_telemetry
+
+    tel = get_telemetry()
+    if tel is not None:
+        tel.tracer.profiler_mark()
+
+
 @contextlib.contextmanager
 def profiler_trace(logdir: str):
     """XLA device trace (the Legion Prof equivalent): view with
-    TensorBoard's profile plugin or Perfetto."""
+    TensorBoard's profile plugin or Perfetto. With telemetry on, the
+    session holds the program's batch-level spans on its host plane and
+    a clock mark at both ends (``SpanTracer.profiler_mark``), by which
+    ``tools/profile_trace.spans_on_profiler_clock`` aligns the rest."""
     jax.profiler.start_trace(logdir)
     try:
+        _mark_telemetry()
         yield
     finally:
+        _mark_telemetry()
         jax.profiler.stop_trace()

@@ -172,10 +172,10 @@ def test_scheduler_matches_python_request_manager():
     class FakeIFM:
         """Deterministic 'model': next token = (last + position) % 50 + 1."""
 
-        def step(self, meta, want_output=True):
+        def step(self, meta, want_output=True, tel=None):
             pass
 
-        def decode_block(self, tok, pos, act, block):
+        def decode_block(self, tok, pos, act, block, tel=None):
             R = tok.shape[0]
             out = np.zeros((R, block), np.int32)
             cur = tok.copy()

@@ -459,13 +459,19 @@ def test_fleet_observability_acceptance(llama_ckpt, tmp_path):
             assert all("kind" in e and "t_s" in e for e in events)
         assert pool.stats()["incident_reports"] == arts["incidents"]
 
-        # clock-sync emitter: one record per replica pid, for aligning a
-        # jax.profiler device trace with the fleet span trace
-        cs = pt.emit_clock_sync(fleet, str(tmp_path / "clock_sync.jsonl"))
-        recs = [json.loads(ln) for ln in open(cs)]
+        # clock marks: one per replica pid, for aligning a jax.profiler
+        # device trace with the fleet span trace (each tracer ties ITS
+        # perf_counter origin to the profiler session by a named mark;
+        # tools/profile_trace.mark_offset_ns reads the pair back)
+        tracers = [t.tracer for t in fleet.replica_telemetries()]
+        names = [trc.profiler_mark() for trc in tracers]
+        assert len(set(names)) == 2
+        recs = [next(e for e in trc.events if e["name"] == n)
+                for trc, n in zip(tracers, names)]
         assert [r["pid"] for r in recs] == [1, 2]
-        assert all(r["name"] == "clock_sync"
-                   and "wall_time_s" in r["args"] for r in recs)
+        assert all(r["ph"] == "i" and r["name"].startswith("ffsv_mark_")
+                   and r["args"]["perf_counter_s"] > 0 for r in recs)
+        assert pt.mark_offset_ns is not None    # the reader ships with it
 
         # steady-state control: same pool, same policy, no crash -> the
         # pager stays silent (crash_after beyond the run's engine calls)

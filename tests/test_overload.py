@@ -451,10 +451,21 @@ def test_stop_server_flush_timeout_cancels_stragglers(tiny_incr_model):
     handle.start_server()
     srv = handle._server
     guids, ev = srv.submit([[7, 3]], 56, 0)
-    time.sleep(0.05)                      # let the loop take the request
+    # wait for the slot grant: the loop has taken the request (a fixed
+    # sleep here lost the race whenever the workers were busy)
+    deadline = time.monotonic() + 60
+    while time.monotonic() < deadline:
+        req = handle.rm.inflight.get(guids[0])
+        if req is None or req.prefill_start_s:
+            break
+        time.sleep(0.002)
     handle.stop_server(flush_timeout_s=0.01)   # well under 56 tokens
     # the waiter resolved (flush cancels stragglers rather than hanging)
     assert ev.is_set()
+    # the cancelled round ends within a block; its result is there once
+    # the loop's thread has returned, however slow the machine
+    srv._thread.join(60)
+    assert not srv._thread.is_alive()
     res = handle.rm.results.get(guids[0])
     assert res is not None
     assert res.status in ("cancelled", "ok")   # ok only if absurdly fast

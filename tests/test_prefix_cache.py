@@ -389,10 +389,11 @@ def test_decode_interleaves_with_chunked_prefill(tiny_incr_model):
     events = []
     orig_prefill = rm._timed_prefill
 
-    def spy_prefill(ifm, meta, tel, rows=(), active=None, n_tokens=None):
+    def spy_prefill(ifm, meta, tel, rows=(), active=None, n_tokens=None,
+                    rnd=None):
         events.append("prefill")
         return orig_prefill(ifm, meta, tel, rows=rows, active=active,
-                            n_tokens=n_tokens)
+                            n_tokens=n_tokens, rnd=rnd)
 
     rm._timed_prefill = spy_prefill
     from flexflow_tpu.serve.request_manager import InferenceManager
@@ -402,9 +403,9 @@ def test_decode_interleaves_with_chunked_prefill(tiny_incr_model):
         ifm = model._inference_manager = InferenceManager(model)
     orig_decode = ifm.decode_block
 
-    def spy_decode(tok, pos, act, block):
+    def spy_decode(tok, pos, act, block, tel=None):
         events.append("decode")
-        return orig_decode(tok, pos, act, block)
+        return orig_decode(tok, pos, act, block, tel=tel)
 
     ifm.decode_block = spy_decode
     try:

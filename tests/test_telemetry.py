@@ -5,10 +5,15 @@ proven TINY config from test_serving and generation lengths stay small).
 Covers: counter/histogram math + exact percentiles, Prometheus/JSON
 export format, span lifecycle + JSONL/Chrome trace output, the /metrics
 HTTP endpoint, a 2-round speculative decode recording the expected
-acceptance-length events, and the disabled path recording nothing.
+acceptance-length events, the disabled path recording nothing, the
+batch-level span vocabulary of the three Python scheduler loops (in the
+tracer, and in a jax.profiler session with its clock marks), and the
+benchmark's readers of those spans on a hand-built trace.
 """
 
 import json
+import os
+import sys
 import urllib.request
 
 import pytest
@@ -215,3 +220,297 @@ def test_disabled_path_records_no_events(tiny_spec_pair):
         assert len(tel.tracer.events) == 1  # clock_sync only
     finally:
         disable_telemetry()
+
+
+# ---------------------------------------------------------------------------
+# the anatomy of a scheduler round: batch-level spans on tid 0 (ISSUE 24)
+# ---------------------------------------------------------------------------
+
+LEAVES = ("sched_admit", "sched_build", "sched_commit",
+          "call_stage", "call_launch", "call_wait")
+VOCABULARY = LEAVES + ("sched_round", "spec_block")
+LOOPS = ("incr", "spec_chain", "spec_tree")
+
+
+def _serve(loop, llm, ssm, rm):
+    """Three requests through two slots on one of the Python scheduler
+    loops: a refill mid-batch, so rounds with and without a prefill."""
+    from flexflow_tpu.serve.batch_config import GenerationConfig
+
+    for p in [[5, 9, 23, 44], [7, 3, 11], [9, 9, 4, 1, 2]]:
+        rm.register_new_request(p, max_new_tokens=8)
+    if loop == "incr":
+        saved = getattr(llm.config, "use_native_scheduler", True)
+        llm.config.use_native_scheduler = False
+        try:
+            return rm.generate_incr_decoding(llm)
+        finally:
+            llm.config.use_native_scheduler = saved
+    gc = GenerationConfig(adaptive_spec=False)
+    if loop == "spec_chain":
+        return rm._generate_spec_chain(llm, ssm, spec_depth=2,
+                                       generation_config=gc)
+    return rm._generate_spec_tree_fused(llm, [ssm], spec_depth=2,
+                                        generation_config=gc)
+
+
+@pytest.mark.parametrize("loop", LOOPS)
+def test_scheduler_round_span_vocabulary(loop, tiny_spec_pair, monkeypatch):
+    """A served batch emits each vocabulary span once per occurrence on
+    tid 0; the leaves inside a sched_round do not overlap and cover it;
+    spec_block.rounds is what the device ran (the executed columns of
+    n_acc) and never more than the rounds asked for."""
+    from flexflow_tpu.serve import engine as eng
+
+    llm, ssm = tiny_spec_pair
+    blocks = []                       # (rounds asked, n_acc) per run_block
+    for cls in (eng.SpecChainEngine, eng.MultiSpecEngine):
+        def spy(self, tok, pos, active, n_rounds, *a, _orig=cls.run_block,
+                **kw):
+            out = _orig(self, tok, pos, active, n_rounds, *a, **kw)
+            blocks.append((int(n_rounds), out[1].copy()))
+            return out
+        monkeypatch.setattr(cls, "run_block", spy)
+
+    tel = enable_telemetry()
+    try:
+        results = _serve(loop, llm, ssm, RequestManager())
+        events = tel.tracer.events
+        reg = tel.registry
+        n_decode_calls = reg.get("ffsv_decode_block_seconds").count
+        n_prefill_calls = reg.get("ffsv_prefill_step_seconds").count
+        n_spec_calls = reg.get("ffsv_spec_block_seconds").count
+    finally:
+        disable_telemetry()
+    assert sorted(len(r.output_tokens) for r in results) == [8, 8, 8]
+
+    batch = [e for e in events if e["ph"] == "X" and e["tid"] == 0]
+    assert batch and {e["name"] for e in batch} <= set(VOCABULARY)
+    # the per-request tracks are untouched: never on tid 0
+    per_request = [e for e in events if e["ph"] == "X" and e["tid"] != 0]
+    assert {e["name"] for e in per_request} <= {"prefill", "decode_block",
+                                                "decode_round"}
+    by = {n: [e for e in batch if e["name"] == n] for n in VOCABULARY}
+
+    # once per occurrence: one sched_round an iteration, one sched_admit
+    # in each, one stage/launch per device call, one wait per fenced call
+    rounds = by["sched_round"]
+    assert len(rounds) >= 2
+    assert all(r["args"]["loop"] == loop and r["args"]["slots"] == 2
+               and 0 <= r["args"]["live"] <= 2 for r in rounds)
+    assert len(by["sched_admit"]) == len(rounds)
+    assert sum(e["args"]["granted"] for e in by["sched_admit"]) == 3
+    n_calls = n_decode_calls + n_prefill_calls + n_spec_calls
+    for leaf in ("call_stage", "call_launch", "call_wait"):
+        assert len(by[leaf]) == n_calls, leaf
+        programs = [e["args"]["program"] for e in by[leaf]]
+        assert programs.count("prefill") == n_prefill_calls
+        assert programs.count("spec_block") == n_spec_calls
+    committed = sum(e["args"].get("committed", 0)
+                    for e in by["sched_commit"])
+    assert committed == sum(len(r.output_tokens) for r in results)
+
+    # spec_block: what the device ran, against what was asked
+    assert len(by["spec_block"]) == n_spec_calls == len(blocks)
+    assert (n_spec_calls > 0) == (loop != "incr")
+    for ev, (asked, n_acc) in zip(by["spec_block"], blocks):
+        ran = int((n_acc >= 0).any(axis=0).sum())
+        assert ev["args"]["rounds"] == ran <= asked
+        assert ev["args"]["rounds_asked"] == asked
+        assert ev["args"]["rows"] == int((n_acc >= 0).any(axis=1).sum())
+        assert ev["args"]["engine"] == ("SpecChainEngine"
+                                        if loop == "spec_chain"
+                                        else "MultiSpecEngine")
+    if loop == "spec_tree":
+        # the draft's cache owes the last block's accepted tokens: the
+        # catch-up chunk holds every later block to one round
+        assert "catch_up" in {r["args"].get("cut") for r in rounds}
+
+    # leaves: inside their round, in order, no overlap, and they cover it
+    eps = 0.25                       # two roundings to 0.1 us
+    leaves = sorted((e for e in batch if e["name"] in LEAVES),
+                    key=lambda e: e["ts"])
+    covered = 0.0
+    for r in rounds:
+        r0, r1 = r["ts"], r["ts"] + r["dur"]
+        inside = [e for e in leaves if r0 - eps <= e["ts"] < r1]
+        assert inside and inside[0]["name"] == "sched_admit"
+        for a, b in zip(inside, inside[1:]):
+            assert a["ts"] + a["dur"] <= b["ts"] + eps, (a, b)
+        assert inside[-1]["ts"] + inside[-1]["dur"] <= r1 + eps
+        covered += sum(e["dur"] for e in inside)
+    assert len(leaves) == sum(
+        1 for e in leaves
+        if any(r["ts"] - eps <= e["ts"] < r["ts"] + r["dur"]
+               for r in rounds))                 # no leaf outside a round
+    assert covered >= 0.9 * sum(r["dur"] for r in rounds)
+
+
+def test_disabled_path_enters_no_annotation(tiny_spec_pair, monkeypatch):
+    """Telemetry off: a batch served on each of the three loops records
+    no event and enters no profiler annotation."""
+    from flexflow_tpu.telemetry import tracing
+
+    llm, ssm = tiny_spec_pair
+    entered = []
+
+    class Counting(tracing.TraceAnnotation):
+        def __enter__(self):
+            entered.append(1)
+            return super().__enter__()
+
+    monkeypatch.setattr(tracing, "TraceAnnotation", Counting)
+    disable_telemetry()
+    for loop in LOOPS:
+        results = _serve(loop, llm, ssm, RequestManager())
+        assert len(results) == 3
+    assert get_telemetry() is None and not entered
+    tel = enable_telemetry()
+    try:
+        assert len(tel.tracer.events) == 1      # clock_sync only
+        tel.tracer.end(tel.tracer.begin("sched_round"))
+        assert entered                          # the spy does see one
+    finally:
+        disable_telemetry()
+
+
+def _tools_profile_trace():
+    tools = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "tools")
+    sys.path.insert(0, tools)
+    try:
+        import profile_trace
+    finally:
+        sys.path.pop(0)
+    return profile_trace
+
+
+def test_profiler_session_holds_spans_and_clock_mark(tiny_spec_pair,
+                                                     tmp_path):
+    """A CPU jax.profiler session around a served batch holds the
+    program's batch-level spans on its host plane under their names, and
+    the clock marks by which the rest of the tracer's spans are put on
+    the profiler's clock."""
+    from flexflow_tpu.telemetry.tracing import MARK_PREFIX
+    from flexflow_tpu.utils.profiling import profiler_trace
+
+    pt = _tools_profile_trace()
+    llm, ssm = tiny_spec_pair
+    logdir = str(tmp_path / "prof")
+    tel = enable_telemetry()
+    try:
+        with profiler_trace(logdir):
+            _serve("spec_chain", llm, ssm, RequestManager())
+        events = tel.tracer.events
+    finally:
+        disable_telemetry()
+
+    marks = [e for e in events if e["name"].startswith(MARK_PREFIX)]
+    assert len(marks) == 2 and all(e["ph"] == "i" for e in marks)
+    on_plane = pt.host_annotations(logdir)
+    names = {n for n, _, _ in on_plane}
+    assert set(VOCABULARY) <= names
+    assert {e["name"] for e in marks} <= names
+    offset = pt.mark_offset_ns(logdir, events)
+    assert offset is not None
+    # aligned by marks, the tracer's sched_round spans fall on the
+    # annotations of the same name (same count, starts within 0.5 ms)
+    mine = sorted(s[1] for s in pt.spans_on_profiler_clock(logdir, events)
+                  if s[0] == "sched_round")
+    theirs = sorted(st for n, st, _ in on_plane if n == "sched_round")
+    assert len(mine) == len(theirs) >= 2
+    assert max(abs(a - b) for a, b in zip(mine, theirs)) < 5e5
+    # the per-request tracks are written after the fact: only the marks
+    # put them beside the device plane
+    assert "decode_round" not in names
+    assert any(s[0] == "decode_round"
+               for s in pt.spans_on_profiler_clock(logdir, events))
+    assert pt.mark_offset_ns(logdir, [e for e in events
+                                      if e not in marks]) is None
+
+
+# the benchmark's readers of the new spans, on a hand-built traced stretch
+# of 100 ms (times below in microseconds after its start)
+_ROUNDS = [     # (name, start, end, args)
+    ("sched_round", 1000, 41000, {"loop": "spec_tree"}),
+    ("sched_admit", 1000, 1500, {"granted": 1}),
+    ("sched_build", 1500, 2000, {}),
+    ("prefill", 2000, 12000, {"n_tokens": 8}),
+    ("prefill", 2000, 12000, {"n_tokens": 8}),          # second request
+    ("call_stage", 2000, 3000, {"program": "prefill"}),
+    ("call_launch", 3000, 4000, {"program": "prefill"}),
+    ("call_wait", 4000, 12000, {"program": "prefill"}),
+    ("sched_build", 12000, 13000, {}),
+    ("spec_block", 13000, 38000, {"rounds_asked": 4, "rounds": 2,
+                                  "rows": 2}),
+    ("call_stage", 13000, 16000, {"program": "spec_block"}),
+    ("call_launch", 16000, 17000, {"program": "spec_block"}),
+    ("call_wait", 17000, 38000, {"program": "spec_block"}),
+    ("sched_commit", 38000, 41000, {"committed": 7}),
+    ("sched_round", 50000, 90000, {"loop": "spec_tree", "cut": "catch_up"}),
+    ("sched_admit", 50000, 50500, {"granted": 0}),
+    ("sched_build", 50500, 52000, {}),
+    ("spec_block", 52000, 84000, {"rounds_asked": 1, "rounds": 1,
+                                  "rows": 2}),
+    ("call_stage", 52000, 53000, {"program": "spec_block"}),
+    ("call_launch", 53000, 54000, {"program": "spec_block"}),
+    ("call_wait", 54000, 84000, {"program": "spec_block"}),
+    ("sched_commit", 84000, 88000, {"committed": 4}),
+    # a round that runs over the stretch's end: not counted
+    ("sched_round", 95000, 105000, {"loop": "spec_tree"}),
+    ("spec_block", 96000, 104000, {"rounds_asked": 1, "rounds": 1,
+                                   "rows": 1}),
+]
+_BUSY = [(4500, 11500), (17500, 37500), (55000, 83000), (96500, 99500)]
+_EXPECTED = {
+    "spec_round_ms": (20 + 28) / 3,         # busy in the two blocks / rounds
+    "spec_rounds_per_block": 3 / 2,
+    "call_idle_ms": (3 + 5 + 4) / 3,        # prefill, block, block
+    "call_stage_ms": (1 + 3 + 1) / 3,
+    "sched_host_ms": ((40 - 35) + (40 - 32)) / 2,
+    "idle_attributed": 100 * 23 / 42,       # 42 ms idle, 23 inside leaves
+}
+
+
+def _hand_ctx(spans):
+    from benchmark.lib import trace as TR
+
+    off, t0_s = 1000.0, 100.0              # profiler = perf_counter + 1 us
+    t0 = t0_s * 1e9 + off
+    raw = {"planes": {"/device:TPU:0": [[f"fusion.{i}", t0 + a * 1e3,
+                                         (b - a) * 1e3]
+                                        for i, (a, b) in enumerate(_BUSY)]},
+           "marks": {"bench_mark_0": t0, "bench_mark_1": t0 + 100e6}}
+    origin = 99.0
+    events = [{"name": "clock_sync", "ph": "M", "ts": 0.0,
+               "args": {"perf_counter_origin": origin}}]
+    for name, a, b, args in spans:
+        events.append({"name": name, "ph": "X", "tid": 0,
+                       "ts": (t0_s - origin) * 1e6 + a,
+                       "dur": float(b - a), "args": dict(args)})
+    red = TR.reduce_trace(raw, {"bench_mark_0": t0_s,
+                                "bench_mark_1": t0_s + 0.1}, events)
+    assert red["busy_s"] == pytest.approx(0.058)
+    return {"trace": red}
+
+
+@pytest.mark.parametrize("metric", sorted(_EXPECTED))
+def test_phase_span_readers_on_a_hand_built_trace(metric, capsys):
+    """Known spans and busy intervals give known numbers; a trace without
+    the spans (any program before ISSUE 24) gives None."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, root)
+    try:
+        from benchmark.run import load_module
+
+        read = load_module("layer_metrics", metric).read
+        assert read(_hand_ctx(_ROUNDS)) == pytest.approx(_EXPECTED[metric])
+        old = [s for s in _ROUNDS if s[0] == "prefill"]   # the parent's
+        assert read(_hand_ctx(old)) is None
+        assert read({"trace": None}) is None
+    finally:
+        sys.path.remove(root)
+    out = capsys.readouterr().out
+    assert all(line.startswith("# ") for line in out.splitlines())
+    if metric == "spec_rounds_per_block":
+        assert "rounds asked 2.500" in out and "'catch_up': 1" in out

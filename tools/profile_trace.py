@@ -7,6 +7,11 @@ total duration. Answers "where do the ms go" without guessing from
 ablations.
 
 Usage: python tools/profile_trace.py [resnet|decode]
+
+Serving: a profiler session taken with telemetry on
+(``utils/profiling.profiler_trace``) holds the program's batch-level spans
+on its host plane (``host_annotations``); ``spans_on_profiler_clock`` puts
+the tracer's other spans on the same clock by the marks both hold.
 """
 
 import glob
@@ -21,28 +26,60 @@ sys.path.insert(0, ".")
 sys.path.insert(0, "tools")
 
 
-def emit_clock_sync(telemetry, path):
-    """Write the replica-pool tracers' ``clock_sync`` records (one per
-    replica pid) as JSONL, so a ``jax.profiler`` device trace captured
-    around a pool run can be aligned with the fleet span trace: each
-    record carries the tracer's wall-clock epoch plus the perf_counter
-    origin its span timestamps are relative to (the recipe in the README
-    "Telemetry" section, extended to one record per replica thread).
+def _xplane(trace_dir):
+    import jax.profiler as jp
 
-    ``telemetry`` is a FleetTelemetry (or anything with
-    ``replica_telemetries()``) or an iterable of SpanTracers."""
-    import json
+    files = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    assert files, f"no xplane under {trace_dir}"
+    return jp.ProfileData.from_file(max(files, key=os.path.getmtime))
 
-    if hasattr(telemetry, "replica_telemetries"):
-        tracers = [t.tracer for t in telemetry.replica_telemetries()]
-    else:
-        tracers = list(telemetry)
-    with open(path, "w") as f:
-        for tr in tracers:
-            sync = dict(tr._sync or {})
-            sync["pid"] = tr.pid
-            f.write(json.dumps(sync) + "\n")
-    return path
+
+def host_annotations(trace_dir, prefix=""):
+    """[(name, start_ns, duration_ns)] of the host planes' events whose
+    name starts with ``prefix``: the program's batch-level spans
+    (``sched_round``, ``call_stage``, ...) and its clock marks are
+    there under their own names, on the profiler's clock."""
+    return [(ev.name, float(ev.start_ns), float(ev.duration_ns))
+            for plane in _xplane(trace_dir).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events
+            if ev.name.startswith(prefix)]
+
+
+def mark_offset_ns(trace_dir, events):
+    """profiler_ns - perf_counter_ns, the mean over the marks that
+    ``SpanTracer.profiler_mark`` wrote both into the profiler session
+    under ``trace_dir`` and into ``events`` (the tracer's events, or its
+    JSONL loaded back). None when the two share no mark."""
+    from flexflow_tpu.telemetry.tracing import MARK_PREFIX
+
+    at = {name: start for name, start, _ in
+          host_annotations(trace_dir, MARK_PREFIX)}
+    diffs = [at[ev["name"]] - ev["args"]["perf_counter_s"] * 1e9
+             for ev in events
+             if ev.get("name", "").startswith(MARK_PREFIX)
+             and ev["name"] in at]
+    return sum(diffs) / len(diffs) if diffs else None
+
+
+def spans_on_profiler_clock(trace_dir, events):
+    """The tracer's complete spans as (name, start_ns, end_ns, args) on
+    the clock of the profiler session under ``trace_dir``, aligned by
+    marks: what puts the per-request tracks (written after the fact, so
+    absent from the profiler's host plane) beside the device plane."""
+    offset = mark_offset_ns(trace_dir, events)
+    origin = next((ev["args"]["perf_counter_origin"] for ev in events
+                   if ev.get("name") == "clock_sync"), None)
+    if offset is None or origin is None:
+        return []
+    out = []
+    for ev in events:
+        if ev.get("ph") == "X":
+            start = (origin + ev["ts"] / 1e6) * 1e9 + offset
+            out.append((ev["name"], start, start + ev["dur"] * 1e3,
+                        ev.get("args", {})))
+    return out
 
 
 def aggregate(trace_dir, steps=3, min_pct=0.5):
@@ -50,12 +87,7 @@ def aggregate(trace_dir, steps=3, min_pct=0.5):
     (fusion-name prefixes) + top individual ops, per step."""
     import re
 
-    import jax.profiler as jp
-
-    files = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
-                      recursive=True)
-    assert files, f"no xplane under {trace_dir}"
-    pd = jp.ProfileData.from_file(max(files, key=os.path.getmtime))
+    pd = _xplane(trace_dir)
     totals = defaultdict(float)
     counts = defaultdict(int)
     kinds = defaultdict(float)
