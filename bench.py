@@ -308,10 +308,8 @@ class AcceptanceMeter:
         for cls in (MultiSpecEngine, SpecChainEngine):
             orig = cls.run_block
 
-            def patched(eng, tok, pos, act, n, remaining=None, _orig=orig,
-                        **kw):
-                a, n_acc, d_used = _orig(eng, tok, pos, act, n, remaining,
-                                         **kw)
+            def patched(eng, *args, _orig=orig, **kw):
+                a, n_acc, d_used = _orig(eng, *args, **kw)
                 meter.n_acc.append(np.asarray(n_acc))
                 return a, n_acc, d_used
 
@@ -909,7 +907,12 @@ def main():
     def warmup():
         # one compile each: the block programs take a dynamic trip count
         ifm.decode_block(tok0, pos0, act0, 1)
-        eng.run_block(tok0, pos0, act0, 1)
+        if isinstance(eng, MultiSpecEngine):
+            # the one-token accepted block: (tks, nblk, base)
+            eng.run_block(np.zeros((NUM_REQUESTS, SPEC_DEPTH + 1), np.int32),
+                          np.ones_like(pos0), pos0, act0, 1)
+        else:
+            eng.run_block(tok0, pos0, act0, 1)
         run_requests(lambda rm: rm.generate_incr_decoding(llm), warm, 4)
         run_requests(lambda rm: rm.generate_spec_infer(
             llm, ssms, spec_depth=SPEC_DEPTH, generation_config=gen_cfg()),

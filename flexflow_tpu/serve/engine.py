@@ -297,7 +297,9 @@ class MultiSpecEngine:
       width-(d+1) and doubles as the CATCH-UP over last round's accepted
       block, so a draft cache whose chain lost the previous round gets the
       accepted tokens' KV rewritten before drafting (the unfused path did
-      this via prefill calls);
+      this via prefill calls). The block crosses the call boundary: the
+      host hands the last call's final accepted block to the next
+      run_block, so no prefill call sits between two blocks;
     * the chains verify as one token tree with B branches off the root —
       chains are NOT merged (the host path dedups shared prefixes; here
       duplicate nodes just cost verify slots), so the tree topology, its
@@ -525,20 +527,21 @@ class MultiSpecEngine:
         B = len(self.ssms)
         ssm_ps = [rest[2 * i] for i in range(B)]
         ssm_states = [rest[2 * i + 1] for i in range(B)]
-        (tok, pos, active, n_rounds, remaining, depth0, min_depth,
+        (tks0, nblk0, base0, active, n_rounds, remaining, depth0, min_depth,
          adaptive) = rest[2 * B:]
-        R = tok.shape[0]
+        R = tks0.shape[0]
         d = self.depth
         max_seq = self.llm.config.max_sequence_length
-        rng0 = jax.random.fold_in(self._rng_const, pos.sum())
+        rng0 = jax.random.fold_in(self._rng_const,
+                                  (base0 + nblk0 - 1).sum())
         # packed [R, max_rounds, d+3]: chain ++ bonus ++ n_acc ++ depth
         packed0 = jnp.full((R, self.max_rounds, d + 3), 0, jnp.int32)
         packed0 = packed0.at[:, :, d + 1].set(-1)
         packed0 = packed0.at[:, :, d + 2].set(-1)
-        # call-boundary invariant: accepted block = just the pending root
-        tks0 = jnp.zeros((R, d + 1), jnp.int32).at[:, 0].set(tok)
-        nblk0 = jnp.ones((R,), jnp.int32)
-        base0 = pos
+        # a call starts where a round inside it starts: from each row's
+        # accepted block (tks0, nblk0, base0), handed over by the host, so
+        # round 0's width-(d+1) draft step is the catch-up over the LAST
+        # call's final round too (see run_block)
         adapt = adaptive > 0
 
         Tp = self.tree_width
@@ -586,12 +589,19 @@ class MultiSpecEngine:
                  base0, remaining, active, depth0, active, packed0))
         return (llm_state, tuple(ssm_states), packed)
 
-    def run_block(self, tok: np.ndarray, pos: np.ndarray, active: np.ndarray,
-                  n_rounds: int, remaining: Optional[np.ndarray] = None,
+    def run_block(self, tks: np.ndarray, nblk: np.ndarray, base: np.ndarray,
+                  active: np.ndarray, n_rounds: int,
+                  remaining: Optional[np.ndarray] = None,
                   depth: Optional[np.ndarray] = None,
                   min_depth: int = 1, trace=None
                   ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-        """Run up to ``n_rounds`` fused tree rounds. Returns
+        """Run up to ``n_rounds`` fused tree rounds. Each row enters with
+        its accepted block: ``tks[r, :nblk[r]]`` are the committed tokens
+        the drafts' caches still lack (1 <= nblk <= depth+1, the first at
+        sequence position ``base[r]``); the last of them is the pending
+        token, whose KV the verifier's cache lacks too. A row the drafts
+        are level with enters with the one-token block ``nblk == 1``.
+        Returns
         (toks, n_acc, depth_used): toks[r, k] holds round k's [chain
         tokens (depth), bonus]; the committed tokens are
         ``toks[r, k, :n_acc[r, k]]`` plus the bonus at the FIXED index
@@ -606,16 +616,17 @@ class MultiSpecEngine:
         tel = _resolve_tel(self.telemetry)
         span, ph = _open_block(tel)
         if remaining is None:
-            remaining = np.full(tok.shape, np.iinfo(np.int32).max // 2,
+            remaining = np.full(nblk.shape, np.iinfo(np.int32).max // 2,
                                 np.int32)
         adaptive = depth is not None
         if depth is None:
-            depth = np.full(tok.shape, self.depth, np.int32)
+            depth = np.full(nblk.shape, self.depth, np.int32)
         depth = np.clip(np.asarray(depth, np.int32), 1, self.depth)
         args = [self.llm.params, self.llm.op_state]
         for s in self.ssms:
             args += [s.params, s.op_state]
-        args += [jnp.asarray(tok), jnp.asarray(pos), jnp.asarray(active),
+        args += [jnp.asarray(tks, jnp.int32), jnp.asarray(nblk, jnp.int32),
+                 jnp.asarray(base, jnp.int32), jnp.asarray(active),
                  jnp.int32(n_rounds), jnp.asarray(remaining, jnp.int32),
                  jnp.asarray(depth),
                  jnp.int32(max(1, min(int(min_depth), self.depth))),

@@ -978,9 +978,9 @@ class RequestManager:
     @staticmethod
     def _prefill_kind(active, rows) -> str:
         """What a draft model's prefill rows are: ``catch_up`` when every
-        row only feeds tokens a speculation block committed (the gap the
-        block leaves in the draft's cache), ``prefill`` when a prompt is
-        still going in."""
+        row has generated already (its drafts fell more than one accepted
+        block behind: parked by the controller and probing back,
+        re-queued), ``prefill`` when a prompt is still going in."""
         return ("catch_up" if all(active[slot].num_generated > 0
                                   for slot, _, _ in rows) else "prefill")
 
@@ -1534,6 +1534,14 @@ class RequestManager:
         # round, hanging the loop)
         room_needed = engine.tree_width
 
+        def carries_block(req):
+            """The drafts all stand at one depth and owe at most one
+            accepted block (1..depth+1 tokens): what run_block takes."""
+            sd = req.ssm_cache_depth.get(0, 0)
+            return (1 <= len(req.tokens) - sd <= depth + 1
+                    and all(req.ssm_cache_depth.get(i, 0) == sd
+                            for i in range(1, B)))
+
         while self.pending or any(a is not None for a in active):
             tel = self._tel()
             rnd = (tel.begin_round("spec_tree", R) if tel is not None
@@ -1563,9 +1571,14 @@ class RequestManager:
                 prefilled = True
                 if rnd is not None:
                     rnd.note_cut("prefill")
+            # a row whose drafts owe no more than one accepted block goes
+            # to the engine as it is (run_block's first draft step is the
+            # catch-up); only a row that owes more is fed in chunks here
+            owing = [None if req is None or carries_block(req) else req
+                     for req in active]
             for i, ifm in enumerate(ssm_ifms):
                 rows = self._prefill_rows(
-                    active, chunk, lambda r, i=i: r.ssm_cache_depth.get(i, 0),
+                    owing, chunk, lambda r, i=i: r.ssm_cache_depth.get(i, 0),
                     cfg.max_tokens_per_batch)
                 rows = [(slot, toks, sp) for slot, toks, sp in rows
                         if max_seq - len(active[slot].tokens)
@@ -1600,10 +1613,7 @@ class RequestManager:
                 min(cfg.spec_rounds_per_call, engine.max_rounds))
             if prefilled:
                 rounds = 1      # see chain-path note
-            draftable = [req for req in draftable
-                         if all(req.ssm_cache_depth.get(i, 0)
-                                == len(req.tokens) - 1
-                                for i in range(B))]
+            draftable = [req for req in draftable if carries_block(req)]
             if cramped:
                 # cache nearly full: finish token by token (chain-path
                 # parity; the fused tree needs B*depth+1 staging slots)
@@ -1637,8 +1647,9 @@ class RequestManager:
             if draftable:
                 if rnd is not None:
                     rnd.phase("sched_build")
-                tok = np.zeros((R,), np.int32)
-                pos = np.zeros((R,), np.int32)
+                tks = np.zeros((R, depth + 1), np.int32)
+                nblk = np.ones((R,), np.int32)
+                base = np.zeros((R,), np.int32)
                 act = np.zeros((R,), bool)
                 remaining = np.zeros((R,), np.int32)
                 depth_vec = None
@@ -1646,11 +1657,9 @@ class RequestManager:
                     depth_vec = np.full((R,), depth, np.int32)
                 for req in draftable:
                     assert req.cache_depth == len(req.tokens) - 1
-                    for i in range(B):
-                        assert req.ssm_cache_depth.get(i, 0) \
-                            == len(req.tokens) - 1, (i, req.ssm_cache_depth)
-                    tok[req.slot] = req.tokens[-1]
-                    pos[req.slot] = len(req.tokens) - 1
+                    base[req.slot] = sd = req.ssm_cache_depth.get(0, 0)
+                    nblk[req.slot] = len(req.tokens) - sd
+                    tks[req.slot, :nblk[req.slot]] = req.tokens[sd:]
                     act[req.slot] = True
                     remaining[req.slot] = self._remaining_budget(req, max_seq)
                     if ctrl is not None:
@@ -1661,7 +1670,7 @@ class RequestManager:
                     rnd.phase(None)
                 t0 = time.perf_counter()
                 toks, n_acc, d_used = engine.run_block(
-                    tok, pos, act, rounds, remaining, depth=depth_vec,
+                    tks, nblk, base, act, rounds, remaining, depth=depth_vec,
                     min_depth=gc.min_spec_depth, trace=rnd)
                 block_dt = time.perf_counter() - t0
                 if rnd is not None:
@@ -1702,7 +1711,8 @@ class RequestManager:
                         # draft caches are only guaranteed correct through
                         # the last round's catch-up position: a losing
                         # branch's cache holds ITS chain, not the committed
-                        # tokens — the next prefill cycle feeds the gap
+                        # tokens — the next block is handed the gap as its
+                        # accepted block (carries_block)
                         req.ssm_cache_depth[i] = min(last_rpos + 1, d)
             for slot in range(R):
                 req = active[slot]

@@ -10,6 +10,7 @@ deterministic, (b) spec-infer output must token-match incremental decoding
 import os
 import warnings
 
+import jax
 import numpy as np
 import pytest
 
@@ -370,7 +371,9 @@ def test_multi_ssm_spec_host_calls_bounded():
     assert sum(len(r.output_tokens) for r in res) >= 4 * 40
     # 160 generated tokens over ~45 tree rounds; the unfused path paid
     # ~rounds*(n_ssm*depth+1) ~ 300+ host dispatches. Fused: blocks of
-    # spec_rounds_per_call (default 4) rounds + a few prefill/heal steps.
+    # spec_rounds_per_call (default 4) rounds + the prompts' prefill steps
+    # (these random drafts accept nothing; an accepting draft is
+    # test_fused_tree_high_acceptance_blocks_carry).
     assert calls["block"] <= 14, calls
     assert calls["step"] <= 16, calls
 
@@ -568,6 +571,149 @@ def test_single_ssm_fused_tree_path_matches_chain():
     assert len(spec) == 2
     for r in spec:
         assert incr[tuple(r.input_tokens)][:12] == r.output_tokens[:12]
+
+
+@pytest.mark.parametrize("adaptive", [False, True],
+                         ids=["static", "adaptive"])
+@pytest.mark.parametrize("n_ssm", [1, 2])
+def test_fused_tree_high_acceptance_blocks_carry(n_ssm, adaptive,
+                                                 monkeypatch):
+    """A draft that accepts (the verifier's own weights) must keep the fused
+    tree loop in whole blocks: the accepted block of a call's last round is
+    handed to the next call, so once a prompt is in no draft ever runs the
+    prefill program again and nothing but a prompt prefill holds a block to
+    one round. With two drafts the first is divergent: it loses every
+    round, and the carried block is what heals its cache."""
+    from flexflow_tpu.serve.batch_config import GenerationConfig
+    from flexflow_tpu.serve.engine import MultiSpecEngine
+    from flexflow_tpu.serve.inference_manager import InferenceManager
+
+    depth, plen, new = 4, 11, 96
+    prompts = [[(7 * i + 3 * j) % 120 + 1 for j in range(plen)]
+               for i in range(5)]                   # 5 requests, 4 slots
+    rm = RequestManager()
+    for p in prompts:
+        rm.register_new_request(p, max_new_tokens=new)
+    incr = {tuple(r.input_tokens): r.output_tokens
+            for r in rm.generate_incr_decoding(make_model(seed=0, max_seq=128))}
+
+    llm = make_model(mode=InferenceMode.TREE_VERIFY_MODE, seed=0, max_seq=128)
+    ssms = [make_model(mode=InferenceMode.BEAM_SEARCH_MODE, seed=s,
+                       max_seq=128) for s in ([0] if n_ssm == 1 else [7, 0])]
+    per_call = llm.config.spec_rounds_per_call
+    draft_ends, llm_prefills, blocks = [], [], []
+    orig_step, orig_block = InferenceManager.step, MultiSpecEngine.run_block
+
+    def step_spy(self, meta, *a, **k):
+        ends = (meta.start_pos + meta.num_tokens)[meta.active]
+        if self.model in ssms:
+            draft_ends.extend(int(e) for e in ends)
+        elif meta.num_tokens.max() > 1:
+            llm_prefills.append(1)
+        return orig_step(self, meta, *a, **k)
+
+    def block_spy(self, tks, nblk, base, active, n_rounds, *a, **k):
+        out = orig_block(self, tks, nblk, base, active, n_rounds, *a, **k)
+        blocks.append((int(n_rounds), int((out[1] >= 0).any(axis=0).sum()),
+                       int(nblk[active].max())))
+        return out
+
+    monkeypatch.setattr(InferenceManager, "step", step_spy)
+    monkeypatch.setattr(MultiSpecEngine, "run_block", block_spy)
+    rm2 = RequestManager()
+    for p in prompts:
+        rm2.register_new_request(p, max_new_tokens=new)
+    spec = rm2._generate_spec_tree_fused(
+        llm, ssms, spec_depth=depth,
+        # (a cheap draft, as a real one is: the controller's cost model
+        # would park a draft the size of its verifier from token one)
+        generation_config=GenerationConfig(adaptive_spec=adaptive,
+                                           spec_draft_cost_ratio=0.1))
+    assert len(spec) == len(prompts)
+    for r in spec:
+        assert r.output_tokens == incr[tuple(r.input_tokens)]
+    # a draft's prefill program only ever fed prompt tokens (a chunk
+    # leaves at least one token pending, so it ends inside the prompt)
+    assert draft_ends and max(draft_ends) <= plen - 1, draft_ends
+    # blocks entered with what the last one accepted, not one token
+    assert max(nb for _, _, nb in blocks) == depth + 1
+    # only a round with a prompt prefill asks for a single round
+    asked = [a for a, _, _ in blocks]
+    assert set(asked) <= {1, per_call}, asked
+    assert asked.count(1) <= len(llm_prefills), (asked, llm_prefills)
+    rounds_run = sum(ran for _, ran, _ in blocks)
+    assert 3 * len(blocks) <= rounds_run, blocks
+
+
+def test_multi_engine_carried_block_equals_prefilled_gap():
+    """run_block entered with a carried block (nblk 2..depth+1, beside rows
+    with nblk 1) returns the tokens and leaves the caches that feeding the
+    gap through the drafts' prefill program and entering with the pending
+    token alone gives from the same state."""
+    from flexflow_tpu.serve.engine import MultiSpecEngine
+    from flexflow_tpu.serve.inference_manager import InferenceManager
+
+    depth, R = 4, 4
+    llm = make_model(mode=InferenceMode.TREE_VERIFY_MODE, seed=0)
+    ssms = [make_model(mode=InferenceMode.BEAM_SEARCH_MODE, seed=s)
+            for s in (7, 0)]
+    models = [llm] + ssms
+    ifms = [InferenceManager(m) for m in models]
+    eng = MultiSpecEngine(llm, ssms, depth, max_rounds=4)
+    seqs = [[(5 * r + 3 * j) % 120 + 1 for j in range(n)]
+            for r, n in enumerate((9, 12, 7, 10))]
+    owed = [1, depth + 1, 2, 3]          # tokens each row's drafts lack
+    act = np.ones((R,), bool)
+    remaining = np.full((R,), 30, np.int32)
+
+    def prefill(ifm, upto):
+        rows = [(r, seqs[r][:upto[r]], 0) for r in range(R)]
+        ifm.step(RequestManager._meta_from_rows(R, 16, rows),
+                 want_output=False)
+
+    def snapshot():
+        return [jax.tree.map(np.asarray, m.op_state["kv_cache"])
+                for m in models]
+
+    def run(carried):
+        # verifier: all but the pending token; drafts: ``owed`` behind
+        prefill(ifms[0], [len(s) - 1 for s in seqs])
+        for ifm in ifms[1:]:
+            prefill(ifm, [len(s) - o for s, o in zip(seqs, owed)])
+        if carried:
+            nblk = np.array(owed, np.int32)
+        else:
+            for ifm in ifms[1:]:
+                rows = [(r, seqs[r][len(seqs[r]) - o:-1], len(seqs[r]) - o)
+                        for r, o in enumerate(owed) if o > 1]
+                ifm.step(RequestManager._meta_from_rows(R, 16, rows),
+                         want_output=False)
+            nblk = np.ones((R,), np.int32)
+        tks = np.zeros((R, depth + 1), np.int32)
+        for r, n in enumerate(nblk):
+            tks[r, :n] = seqs[r][len(seqs[r]) - n:]
+        base = np.array([len(s) for s in seqs], np.int32) - nblk
+        toks, n_acc, _ = eng.run_block(tks, nblk, base, act, 3, remaining)
+        return toks[:, :3].copy(), n_acc[:, :3].copy(), snapshot()
+
+    toks_a, n_acc_a, caches_a = run(carried=True)
+    toks_b, n_acc_b, caches_b = run(carried=False)
+    np.testing.assert_array_equal(n_acc_a, n_acc_b)
+    assert (n_acc_a >= 0).all() and n_acc_a.max() == depth
+    keep = np.arange(depth + 1)[None, None, :] < n_acc_a[:, :, None]
+    keep[:, :, depth] = True                        # the bonus token
+    np.testing.assert_array_equal(toks_a[keep], toks_b[keep])
+    # caches agree wherever they are committed: the verifier's through the
+    # last round's root, the drafts' through the last round's catch-up
+    final = np.array([len(s) for s in seqs]) + (n_acc_a + 1).sum(axis=1) - 1
+    last_root = final - (n_acc_a[:, -1] + 1)
+    for ca, cb, upto in zip(caches_a, caches_b,
+                            [final] + [last_root + 1] * len(ssms)):
+        for name in ("k", "v"):
+            for r in range(R):
+                np.testing.assert_allclose(
+                    ca[name][:, r, :, :upto[r]], cb[name][:, r, :, :upto[r]],
+                    rtol=1e-5, atol=1e-5)
 
 
 def test_long_context_serving():

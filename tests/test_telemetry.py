@@ -11,6 +11,7 @@ tracer, and in a jax.profiler session with its clock marks), and the
 benchmark's readers of those spans on a hand-built trace.
 """
 
+import inspect
 import json
 import os
 import sys
@@ -265,10 +266,11 @@ def test_scheduler_round_span_vocabulary(loop, tiny_spec_pair, monkeypatch):
     llm, ssm = tiny_spec_pair
     blocks = []                       # (rounds asked, n_acc) per run_block
     for cls in (eng.SpecChainEngine, eng.MultiSpecEngine):
-        def spy(self, tok, pos, active, n_rounds, *a, _orig=cls.run_block,
-                **kw):
-            out = _orig(self, tok, pos, active, n_rounds, *a, **kw)
-            blocks.append((int(n_rounds), out[1].copy()))
+        def spy(self, *a, _orig=cls.run_block,
+                _sig=inspect.signature(cls.run_block), **kw):
+            out = _orig(self, *a, **kw)
+            asked = _sig.bind(self, *a, **kw).arguments["n_rounds"]
+            blocks.append((int(asked), out[1].copy()))
             return out
         monkeypatch.setattr(cls, "run_block", spy)
 
@@ -322,9 +324,11 @@ def test_scheduler_round_span_vocabulary(loop, tiny_spec_pair, monkeypatch):
                                         if loop == "spec_chain"
                                         else "MultiSpecEngine")
     if loop == "spec_tree":
-        # the draft's cache owes the last block's accepted tokens: the
-        # catch-up chunk holds every later block to one round
-        assert "catch_up" in {r["args"].get("cut") for r in rounds}
+        # the next block is handed the last block's accepted tokens: no
+        # catch-up chunk, so only a prompt going in cuts a block short
+        assert {r["args"].get("cut") for r in rounds} == {None, "prefill"}
+        assert (max(asked for asked, _ in blocks)
+                == llm.config.spec_rounds_per_call)
 
     # leaves: inside their round, in order, no overlap, and they cover it
     eps = 0.25                       # two roundings to 0.1 us
