@@ -297,7 +297,8 @@ class FFModel:
                            apply_rotary_embedding: bool, scaling_query: bool,
                            scaling_factor: float, qk_prod_scaling: bool,
                            position_bias: bool, rope_theta: float,
-                           name) -> Tensor:
+                           name, qk_norm_eps: Optional[float] = None
+                           ) -> Tensor:
         if add_bias_kv or add_zero_attn:
             raise NotImplementedError(
                 "add_bias_kv/add_zero_attn are not supported by the serving "
@@ -317,7 +318,11 @@ class FFModel:
             max_requests=self.config.max_requests_per_batch,
             max_seq_length=self.config.max_sequence_length,
             use_pallas=self.config.use_pallas,
-            cache_dtype=self.config.kv_cache_dtype), name)
+            cache_dtype=self.config.kv_cache_dtype,
+            # only a model that has the step carries the key, so every
+            # other model's attrs (and program) stay what they were
+            **({} if qk_norm_eps is None else {"qk_norm_eps": qk_norm_eps})),
+            name)
 
     def inc_multihead_self_attention(self, input: Tensor, embed_dim: int,
                                      num_heads: int, **kw) -> Tensor:
@@ -333,13 +338,14 @@ class FFModel:
             apply_rotary_embedding: bool = False, scaling_query: bool = False,
             scaling_factor: float = 1.0, qk_prod_scaling: bool = True,
             position_bias: bool = False, rope_theta: float = 10000.0,
-            name: Optional[str] = None) -> Tensor:
+            name: Optional[str] = None,
+            qk_norm_eps: Optional[float] = None) -> Tensor:
         return self._serving_attention(
             OpType.INC_MULTIHEAD_SELF_ATTENTION, input, embed_dim, num_q_heads,
             num_kv_heads, kdim, vdim, dropout, bias, add_bias_kv,
             add_zero_attn, data_type, kernel_initializer,
             apply_rotary_embedding, scaling_query, scaling_factor,
-            qk_prod_scaling, position_bias, rope_theta, name)
+            qk_prod_scaling, position_bias, rope_theta, name, qk_norm_eps)
 
     def spec_inc_multihead_self_attention(self, input: Tensor, embed_dim: int,
                                           num_heads: int, **kw) -> Tensor:
@@ -355,13 +361,14 @@ class FFModel:
             apply_rotary_embedding: bool = False, scaling_query: bool = False,
             scaling_factor: float = 1.0, qk_prod_scaling: bool = True,
             position_bias: bool = False, rope_theta: float = 10000.0,
-            name: Optional[str] = None) -> Tensor:
+            name: Optional[str] = None,
+            qk_norm_eps: Optional[float] = None) -> Tensor:
         return self._serving_attention(
             OpType.SPEC_INC_MULTIHEAD_SELF_ATTENTION, input, embed_dim,
             num_q_heads, num_kv_heads, kdim, vdim, dropout, bias, add_bias_kv,
             add_zero_attn, data_type, kernel_initializer,
             apply_rotary_embedding, scaling_query, scaling_factor,
-            qk_prod_scaling, position_bias, rope_theta, name)
+            qk_prod_scaling, position_bias, rope_theta, name, qk_norm_eps)
 
     def tree_inc_multihead_self_attention(self, input: Tensor, embed_dim: int,
                                           num_heads: int, **kw) -> Tensor:
@@ -377,13 +384,14 @@ class FFModel:
             apply_rotary_embedding: bool = False, scaling_query: bool = False,
             scaling_factor: float = 1.0, qk_prod_scaling: bool = True,
             position_bias: bool = False, rope_theta: float = 10000.0,
-            name: Optional[str] = None) -> Tensor:
+            name: Optional[str] = None,
+            qk_norm_eps: Optional[float] = None) -> Tensor:
         return self._serving_attention(
             OpType.TREE_INC_MULTIHEAD_SELF_ATTENTION, input, embed_dim,
             num_q_heads, num_kv_heads, kdim, vdim, dropout, bias, add_bias_kv,
             add_zero_attn, data_type, kernel_initializer,
             apply_rotary_embedding, scaling_query, scaling_factor,
-            qk_prod_scaling, position_bias, rope_theta, name)
+            qk_prod_scaling, position_bias, rope_theta, name, qk_norm_eps)
 
     # --- elementwise binary ---
     def add(self, x, y, name=None):
@@ -620,6 +628,17 @@ class FFModel:
                                     experts_internal_dim_size=experts_internal_dim_size,
                                     activation=activation, use_bias=use_bias), name)
 
+    def moe_experts(self, input: Tensor, indices: Tensor, weights: Tensor,
+                    num_experts: int, expert_width: int,
+                    data_type: Optional[DataType] = None, name=None):
+        """The serving path's routed SwiGLU experts (ops/moe.MoeExperts):
+        dropless, over the step's real tokens, through the grouped kernel.
+        ``indices``/``weights`` are the router's top-k."""
+        return self._add_layer(OpType.MOE_EXPERTS, [input, indices, weights],
+                               dict(num_experts=num_experts,
+                                    expert_width=expert_width,
+                                    data_type=data_type), name)
+
     def cache(self, input: Tensor, num_batches: int = 1, name=None):
         """Cross-batch activation cache with staleness score (reference
         src/ops/cache.cc; pairs with RecompileState for adaptive MoE)."""
@@ -851,6 +870,9 @@ class FFModel:
                 self.op_state[layer.name] = impl.init_state(layer.attrs,
                                                             input_specs)
         self._consolidate_kv_caches()
+        from flexflow_tpu.ops.moe import init_counters
+
+        init_counters(self)     # routed-expert layers, telemetry on
         # --- pipeline-parallel serving plan (reference
         # inference_manager.cc:91-132 layer->stage placement); built after
         # KV consolidation so blocks carry their cache_layer_idx ---

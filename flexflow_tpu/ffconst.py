@@ -182,6 +182,7 @@ class OpType(enum.Enum):
     AGGREGATE = enum.auto()
     AGG_SPEC = enum.auto()
     EXPERTS = enum.auto()
+    MOE_EXPERTS = enum.auto()
     CACHE = enum.auto()
     # parallel ops (PCG nodes in the reference; sharding boundaries here)
     REPARTITION = enum.auto()

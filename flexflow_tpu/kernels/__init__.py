@@ -56,6 +56,9 @@ def reset_dispatch_stats():
     fallback_counts.clear()
     _warned.clear()
     fast_path_count = 0
+    from flexflow_tpu.kernels import moe
+
+    moe.reset_dispatch_stats()
 
 
 def use_pallas(config=None) -> bool:

@@ -2,7 +2,7 @@
 
 Capability parity with the reference model zoo (reference inference/models/
 llama.cc, opt.cc, falcon.cc, mpt.cc, starcoder.cc and their Python twins in
-python/flexflow/serve/models/): each model family is a builder that records
+python/flexflow/serve/models/; OLMoE, a sparse-expert family, is beyond it): each model family is a builder that records
 the decoder graph through the FFModel op-builder surface, plus a HuggingFace
 state-dict name mapping so real checkpoints load. ``FAMILIES`` maps the HF
 ``model_type`` to the family (the reference's ModelType enum +
@@ -15,12 +15,14 @@ from typing import Callable, Optional
 from flexflow_tpu.models import falcon as _falcon
 from flexflow_tpu.models import llama as _llama
 from flexflow_tpu.models import mpt as _mpt
+from flexflow_tpu.models import olmoe as _olmoe
 from flexflow_tpu.models import opt as _opt
 from flexflow_tpu.models import starcoder as _starcoder
 from flexflow_tpu.models.falcon import FalconConfig, create_falcon_model
 from flexflow_tpu.models.hf_utils import load_hf_state_dict
 from flexflow_tpu.models.llama import LLAMAConfig, create_llama_model
 from flexflow_tpu.models.mpt import MPTConfig, create_mpt_model
+from flexflow_tpu.models.olmoe import OLMoEConfig, create_olmoe_model
 from flexflow_tpu.models.opt import OPTConfig, create_opt_model
 from flexflow_tpu.models.starcoder import (STARCODERConfig,
                                            create_starcoder_model)
@@ -55,6 +57,9 @@ FAMILIES = {
                           _falcon.preprocess_hf_state_dict),
     "mpt": ModelFamily("mpt", MPTConfig, create_mpt_model,
                        _mpt.hf_weight_map, _mpt.preprocess_hf_state_dict),
+    "olmoe": ModelFamily("olmoe", OLMoEConfig, create_olmoe_model,
+                         _olmoe.hf_weight_map,
+                         _olmoe.preprocess_hf_state_dict),
     "gpt_bigcode": ModelFamily("gpt_bigcode", STARCODERConfig,
                                create_starcoder_model,
                                _starcoder.hf_weight_map,
@@ -82,11 +87,13 @@ __all__ = [
     "LLAMAConfig",
     "MPTConfig",
     "ModelFamily",
+    "OLMoEConfig",
     "OPTConfig",
     "STARCODERConfig",
     "create_falcon_model",
     "create_llama_model",
     "create_mpt_model",
+    "create_olmoe_model",
     "create_opt_model",
     "create_starcoder_model",
     "family_for_hf_config",

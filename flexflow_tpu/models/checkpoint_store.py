@@ -17,7 +17,8 @@ The write side walks ``hf_weight_map(config)`` backwards — every mapped
 param is read through ``get_parameter_by_key`` (which already dequantizes
 and un-fuses gemm/PP-stacked leaves), un-transposed back to HF orientation,
 then re-fused into the genuine HF key layout (falcon's three
-``query_key_value`` layouts, MPT ``Wqkv``, StarCoder ``c_attn``).
+``query_key_value`` layouts, MPT ``Wqkv``, StarCoder ``c_attn``; OLMoE's
+stacked ``[E, in, out]`` expert tensors back into one Linear per expert).
 
 CLI one-liners (see README "Checkpoints")::
 
@@ -63,6 +64,10 @@ TINY_CONFIGS: Dict[str, Dict[str, Any]] = {
                    num_attention_heads=4, num_kv_heads=1),
     "mpt": dict(vocab_size=128, hidden_size=64, n_heads=4, n_layers=2,
                 max_seq_len=64),
+    "olmoe": dict(vocab_size=128, hidden_size=64, intermediate_size=32,
+                  num_hidden_layers=2, num_attention_heads=4,
+                  num_key_value_heads=4, num_experts=8,
+                  num_experts_per_tok=2, max_position_embeddings=128),
     "gpt_bigcode": dict(vocab_size=128, hidden_size=64,
                         intermediate_size=128, num_hidden_layers=2,
                         num_attention_heads=4, max_position_embeddings=64),
@@ -161,6 +166,8 @@ def hf_config_dict(family_name: str, config) -> Dict[str, Any]:
     c = config
     if family_name == "llama":
         d = dataclasses.asdict(c)
+    elif family_name == "olmoe":
+        d = dict(dataclasses.asdict(c), norm_topk_prob=False, clip_qkv=None)
     elif family_name == "opt":
         d = dataclasses.asdict(c)
     elif family_name == "falcon":
@@ -263,8 +270,15 @@ def _refuse_starcoder(sd: Dict[str, np.ndarray], c) -> None:
             sd[f"{base}.c_attn.{suffix}"] = np.ascontiguousarray(fused)
 
 
+def _unstack_olmoe(sd: Dict[str, np.ndarray], c) -> None:
+    from flexflow_tpu.models.olmoe import unstack_hf_experts
+
+    unstack_hf_experts(sd, c)
+
+
 _REFUSE = {"falcon": _refuse_falcon, "mpt": _refuse_mpt,
-           "gpt_bigcode": _refuse_starcoder, "starcoder": _refuse_starcoder}
+           "gpt_bigcode": _refuse_starcoder, "starcoder": _refuse_starcoder,
+           "olmoe": _unstack_olmoe}
 
 
 # --------------------------------------------------------------- save/load

@@ -133,7 +133,11 @@ def _qkv(attrs, params, x, compute_dtype):
     With a fused "wqkv" weight (serve/gemm_fusion.py — the reference's
     --fusion/FusedOp analog) the three projections run as ONE gemm and
     slice: at decode widths each gemm pass is weight-load bound, so two
-    fewer passes is ~2/7 less per-gemm fixed cost per layer."""
+    fewer passes is ~2/7 less per-gemm fixed cost per layer.
+
+    With ``attrs["qk_norm_eps"]`` (OLMoE; absent = no such step) q and k
+    are RMS-normalised over their WHOLE projection, before the split into
+    heads and before the rotary embedding."""
     from flexflow_tpu.quant import qmatmul
 
     H = attrs["num_q_heads"]
@@ -158,6 +162,12 @@ def _qkv(attrs, params, x, compute_dtype):
             raise ValueError(
                 "attention qkv bias set must be all-present or all-absent; "
                 f"got {sorted(k_ for k_ in ('bq', 'bk', 'bv') if k_ in params)}")
+    eps = attrs.get("qk_norm_eps")
+    if eps is not None:
+        from flexflow_tpu.ops.norm import _rms_norm
+
+        q = _rms_norm(q, params["q_norm"], eps)
+        k = _rms_norm(k, params["k_norm"], eps)
     R, Q = x.shape[0], x.shape[1]
     return (q.reshape(R, Q, H, D), k.reshape(R, Q, KH, D),
             v.reshape(R, Q, KH, D))
@@ -348,6 +358,12 @@ def _weight_specs(attrs, input_specs):
                        shard_multiples=(D,)),
             WeightSpec("bo", (E,), dt, zero),
         ]
+    if attrs.get("qk_norm_eps") is not None:
+        from flexflow_tpu.core.initializer import ConstantInitializer
+
+        one = ConstantInitializer(1.0)
+        specs += [WeightSpec("q_norm", (H * D,), dt, one),
+                  WeightSpec("k_norm", (KH * D,), dt, one)]
     return specs
 
 

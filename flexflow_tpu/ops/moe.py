@@ -6,9 +6,16 @@ the TPU-idiomatic formulation is dense one-hot dispatch/combine einsums
 (GShard-style), which keep shapes static for XLA and put the FLOPs on the MXU.
 Expert parallelism shards the expert axis over the mesh "expert" axis
 (see flexflow_tpu/parallel).
+
+Those four are the reference's training formulation: a capacity that drops
+tokens, and every token times every expert. ``MoeExperts`` below is the
+serving path's: dropless SwiGLU experts over the step's real tokens only,
+through the grouped kernel of kernels/moe.py.
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -158,3 +165,150 @@ class Experts(OpImpl):
         per_expert = apply_activation(per_expert, act)
         out = jnp.einsum("teo,te->to", per_expert, weighted)
         return [out]
+
+
+# The expert layers' counters: ONE uint32 array in the model's op state,
+# a row a layer: routed pairs per expert, then, for each of MOE_FIELDS, one
+# count per phase a serving program is traced for. One leaf and not a dict
+# per layer: the serving loop donates the op state to every call and its
+# traced prefill fences it leaf by leaf (seen on the chip: 80 leaves more
+# idled the device 131 ms a prefill step).
+MOE_COUNTERS = "moe_counters"
+MOE_PHASES = ("decode", "prefill", "verify")
+MOE_FIELDS = ("calls", "tokens", "routed", "touched")
+
+
+def init_counters(model):
+    """Give ``model``'s expert layers their counters, if it has such layers
+    and ``FFConfig.telemetry`` is on (FFModel.compile calls this; without
+    either, nothing is built and the op counts nothing)."""
+    layers = [ly for ly in model.layers if ly.op_type == OpType.MOE_EXPERTS]
+    for ly in layers:
+        ly.attrs.pop("counter_row", None)
+    if not layers or not model.config.telemetry:
+        return
+    (E,) = {ly.attrs["num_experts"] for ly in layers}
+    for i, ly in enumerate(layers):
+        ly.attrs["counter_row"] = i
+    model.op_state[MOE_COUNTERS] = jnp.zeros(
+        (len(layers), E + len(MOE_FIELDS) * len(MOE_PHASES)), jnp.uint32)
+
+
+def _step_tokens(ctx, x):
+    """(columns that can hold a real token, valid [R, q], phase) of a
+    serving step, from the batch descriptor: a padded position or an
+    inactive slot is not a token."""
+    meta = ctx.batch_config
+    R, Q = x.shape[0], x.shape[1]
+    tree = hasattr(meta, "ancestor")
+    n = meta.num_nodes if tree else meta.num_tokens
+    # verify-consistent wide decode: only the first kv_append_q columns of
+    # a row are real (serve/engine.forward_with_meta)
+    append_q = getattr(ctx, "kv_append_q", None)
+    q = append_q if (append_q is not None and Q > append_q) else Q
+    valid = (jnp.arange(q)[None, :] < n[:, None]) & meta.active[:, None]
+    phase = "verify" if tree else ("decode" if q == 1 else "prefill")
+    return q, valid, phase
+
+
+def _in_chunks(x, idx, w, valid, cap: int, run, num_experts: int):
+    """``run`` over the real tokens of a wide step, ``cap`` at a time: the
+    real tokens are moved to the front, and a step that holds no more than
+    the scheduler's token budget is one pass (a step may hold more: tree
+    verification fills every row)."""
+    T, H = x.shape
+    order = jnp.argsort(~valid, stable=True).astype(jnp.int32)
+    order = jnp.pad(order, (0, (-T) % cap), constant_values=T)
+    n_real = jnp.sum(valid, dtype=jnp.int32)
+
+    def body(c, carry):
+        y, sizes = carry
+        tok = jax.lax.dynamic_slice(order, (c * cap,), (cap,))
+        ok = c * cap + jnp.arange(cap, dtype=jnp.int32) < n_real
+        at = jnp.minimum(tok, T - 1)
+        yc, sc = run(x[at], idx[at], w[at], ok)
+        return y.at[jnp.where(ok, tok, T)].set(yc, mode="drop"), sizes + sc
+
+    return jax.lax.fori_loop(
+        0, (n_real + cap - 1) // cap, body,
+        (jnp.zeros((T, H), x.dtype), jnp.zeros((num_experts,), jnp.int32)))
+
+
+@register_op
+class MoeExperts(OpImpl):
+    """Routed SwiGLU experts of a serving model, dropless.
+
+    Inputs: x [R, Q, d], indices [R, Q, k], gate weights [R, Q, k] (the
+    router's top-k, graph values). Output [R, Q, d]:
+    ``sum_j w_j * W_down[e_j] (silu(W_gate[e_j] x) * W_up[e_j] x)`` at the
+    step's real tokens (``ctx.batch_config``), zero elsewhere. The weights
+    are three stacks ``[E, in, out]``, int8 or not (quant.py). With
+    ``FFConfig.telemetry`` the op keeps cumulative counters on the device,
+    in its row of the op state's ``MOE_COUNTERS``: routed pairs per expert
+    and, per phase of ``MOE_PHASES``, ``calls``, ``tokens``, ``routed``
+    (pairs) and ``touched`` (distinct experts, summed over calls).
+    ``ServingTelemetry`` reads them at a snapshot and nowhere else."""
+
+    op_type = OpType.MOE_EXPERTS
+    quant_aware = True
+
+    @staticmethod
+    def infer_output_specs(attrs, input_specs):
+        return [input_specs[0]]
+
+    @staticmethod
+    def weight_specs(attrs, input_specs):
+        (sx, dx) = input_specs[0]
+        E, inter, d = attrs["num_experts"], attrs["expert_width"], sx[-1]
+        dt = attrs.get("data_type") or dx
+        init = attrs.get("kernel_initializer") or default_kernel_initializer()
+        shard = ("expert", None, None)
+        return [WeightSpec("gate", (E, d, inter), dt, init, shard),
+                WeightSpec("up", (E, d, inter), dt, init, shard),
+                WeightSpec("down", (E, inter, d), dt, init, shard)]
+
+    @staticmethod
+    def forward(attrs, params, inputs, ctx):
+        from flexflow_tpu import kernels as ffk
+        from flexflow_tpu.kernels import moe as K
+
+        x, idx, w = inputs
+        assert ctx.batch_config is not None, "serving ops need ctx.batch_config"
+        R, Q, H = x.shape
+        E = attrs["num_experts"]
+        q, valid, phase = _step_tokens(ctx, x)
+        mesh = ctx.mesh
+        pallas = ffk.use_pallas(ctx.config)
+        if pallas and mesh is not None and mesh.devices.size > 1:
+            pallas = False
+            K.record_fallback("a mesh of several chips")
+        elif not pallas:
+            K.record_fallback("backend without Mosaic")
+        else:
+            K.record_fast_path()
+        run = functools.partial(
+            K.moe_experts, gate=params["gate"], up=params["up"],
+            down=params["down"], pallas=pallas,
+            interpret=ffk.pallas_interpret_forced())
+        T = R * q
+        flat = (x[:, :q].reshape(T, H), idx[:, :q].reshape(T, -1),
+                w[:, :q].reshape(T, -1), valid.reshape(T))
+        cap = max(R, getattr(ctx.config, "max_tokens_per_batch", T))
+        if T <= cap:
+            y, sizes = run(*flat)
+        else:
+            y, sizes = _in_chunks(*flat, cap, run, E)
+        y = jnp.pad(y.reshape(R, q, H), ((0, 0), (0, Q - q), (0, 0)))
+        row = attrs.get("counter_row")
+        if row is not None:
+            u32, n = jnp.uint32, len(MOE_PHASES)
+            step = jnp.stack([jnp.uint32(1), jnp.sum(valid, dtype=u32),
+                              jnp.sum(sizes, dtype=u32),
+                              jnp.sum(sizes > 0, dtype=u32)])
+            at = E + n * jnp.arange(len(MOE_FIELDS)) + MOE_PHASES.index(phase)
+            st = ctx.state_out.get(MOE_COUNTERS)
+            if st is None:
+                st = ctx.state_in[MOE_COUNTERS]
+            ctx.state_out[MOE_COUNTERS] = st.at[row, :E].add(
+                sizes.astype(u32)).at[row, at].add(step)
+        return [y]

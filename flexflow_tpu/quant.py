@@ -25,7 +25,8 @@ import jax.numpy as jnp
 class QuantizedWeight:
     """Pytree leaf-pair: int8 payload + per-column scale, with static
     metadata (qtype, original rows, original dtype) so it passes through
-    jit boundaries."""
+    jit boundaries. A stacked ``[E, in, out]`` weight (the routed experts,
+    ops/moe.py) keeps one scale per (expert, output column): ``[E, out]``."""
 
     def __init__(self, qtype: str, q, scale, rows: int, dtype: str):
         self.qtype = qtype
@@ -47,7 +48,7 @@ class QuantizedWeight:
 
     @property
     def shape(self):
-        return (self.rows, self.q.shape[1])
+        return tuple(self.q.shape[:-2]) + (self.rows, self.q.shape[-1])
 
     def __repr__(self):
         return (f"QuantizedWeight({self.qtype}, shape={self.shape}, "
@@ -74,14 +75,20 @@ def normalize_qtype(qtype) -> Optional[str]:
 
 
 def quantize_array(w, qtype: str) -> QuantizedWeight:
-    """Quantize a 2-D float array (int4 packs two rows per byte)."""
+    """Quantize a 2-D ``[in, out]`` float array (int4 packs two rows per
+    byte) or, int8 only, a stack ``[E, in, out]`` of them."""
     w = jnp.asarray(w)
-    assert w.ndim == 2, w.shape
+    assert w.ndim in (2, 3), w.shape
+    if w.ndim == 3 and qtype != "int8":
+        raise NotImplementedError(
+            f"{qtype} for a stacked [E, in, out] weight {w.shape}: only int8 "
+            "is implemented (int4's row packing is 2-D)")
     qmax = 127.0 if qtype == "int8" else 7.0
-    scale = jnp.max(jnp.abs(w), axis=0) / qmax            # [out]
+    scale = jnp.max(jnp.abs(w), axis=-2) / qmax           # [out] | [E, out]
     scale = jnp.where(scale == 0, 1.0, scale).astype(jnp.float32)
-    q = jnp.clip(jnp.round(w / scale[None, :]), -qmax, qmax).astype(jnp.int8)
-    rows = int(w.shape[0])
+    q = jnp.clip(jnp.round(w / scale[..., None, :]), -qmax,
+                 qmax).astype(jnp.int8)
+    rows = int(w.shape[-2])
     if qtype == "int4":
         if q.shape[0] % 2:
             q = jnp.pad(q, ((0, 1), (0, 0)))
@@ -103,7 +110,8 @@ def dequantize_array(leaf: QuantizedWeight, dtype=None):
     if leaf.qtype == "int4":
         q = _unpack_int4(q, leaf.rows)
     out_dtype = dtype or jnp.dtype(leaf.dtype)
-    return (q.astype(jnp.float32) * leaf.scale[None, :]).astype(out_dtype)
+    return (q.astype(jnp.float32)
+            * leaf.scale[..., None, :]).astype(out_dtype)
 
 
 def is_quantized(leaf) -> bool:
@@ -114,19 +122,24 @@ def is_quantized(leaf) -> bool:
 # ("wqkv" = the gemm-fusion concat, serve/gemm_fusion.py)
 _QUANT_NAMES = {"kernel", "wq", "wk", "wv", "wo", "wqkv", "weight",
                 "w1", "w2", "w3", "gate", "up", "down"}
+# ... and of those, the ones that may be a stack [E, in, out] (ops/moe.py)
+_STACKED_NAMES = {"gate", "up", "down"}
 
 
 def quantize_params(params: Dict[str, Dict[str, Any]], qtype: str,
                     min_dim: int = 64) -> Dict[str, Dict[str, Any]]:
-    """Quantize every eligible 2-D weight in a model params tree."""
+    """Quantize every eligible 2-D weight in a model params tree, and the
+    stacked expert weights ``[E, in, out]`` (per expert and column)."""
     assert qtype in ("int8", "int4"), qtype
     out: Dict[str, Dict[str, Any]] = {}
     for layer, ws in params.items():
         new_ws = {}
         for name, w in ws.items():
             arr = jnp.asarray(w) if not is_quantized(w) else None
-            if (arr is not None and name in _QUANT_NAMES and arr.ndim == 2
-                    and min(arr.shape) >= min_dim
+            if (arr is not None and name in _QUANT_NAMES
+                    and (arr.ndim == 2
+                         or arr.ndim == 3 and name in _STACKED_NAMES)
+                    and min(arr.shape[-2:]) >= min_dim
                     and jnp.issubdtype(arr.dtype, jnp.floating)):
                 new_ws[name] = quantize_array(arr, qtype)
             else:

@@ -97,6 +97,7 @@ class InferenceManager:
         """
         ph, prog = None, "step" if want_output else "prefill"
         if tel is not None:
+            tel.watch_model(self.model)     # its on-device counters
             ph = tel.call_phase(None, "call_stage", prog)
         self._rng, step_rng = jax.random.split(self._rng)
         if self.model.config.inference_debugging:

@@ -53,7 +53,19 @@ ffsv_prefix_cache_misses_total   counter    admission lookups with no match
 ffsv_prefix_cache_evictions_total counter   pooled prefixes LRU-evicted
 ffsv_prefix_shared_tokens_total  counter    prompt tokens served from the pool
 ffsv_prefix_pool_tokens          gauge      tokens held by the prefix pool
+ffsv_moe_routed_pairs_total      counter    {phase} (token, expert) pairs run
+ffsv_moe_tokens_total            counter    {phase} real tokens the experts saw
+ffsv_moe_experts_touched         summary    {phase} distinct experts a call read
+ffsv_moe_expert_pairs_total      counter    {expert} routed pairs of one expert
 ===============================  =========  =================================
+
+The ``ffsv_moe_*`` series are the routed-expert op's (ops/moe.py), which
+counts ON THE DEVICE, in its op state, as the steps run; ``watch_model``
+makes the registry fetch those counters when a snapshot or a scrape is
+taken (one small device-to-host read) and at no other time. They are sums
+over the model's expert layers: a token counts once per layer, and
+``ffsv_moe_experts_touched``'s count is layer-steps. ``phase`` is
+``decode``, ``prefill`` or ``verify``, fixed when a program is traced.
 
 Batch-level spans (``tracing.SpanTracer.begin``/``end``, ``tid`` 0): the
 Python scheduler loops open a ``RoundTrace`` per iteration (``sched_round``
@@ -87,6 +99,7 @@ enqueue alone (utils/profiling.py protocol).
 from __future__ import annotations
 
 import time
+import weakref
 from typing import Optional
 
 import numpy as np
@@ -100,6 +113,7 @@ from flexflow_tpu.telemetry.metrics import (
     Histogram,
     MetricsHTTPServer,
     MetricsRegistry,
+    Summary,
     percentile,
 )
 from flexflow_tpu.telemetry.flight_recorder import (FlightRecorder,
@@ -313,6 +327,75 @@ class ServingTelemetry:
         self.prefix_pool_tokens = r.gauge(
             "ffsv_prefix_pool_tokens",
             "tokens currently held by the shared-prefix pool")
+
+        # models whose on-device counters a snapshot reads (watch_model):
+        # id -> [weak reference, the counters' values at the last snapshot]
+        self._watched = {}
+        r.add_collector(self._collect_moe)
+
+    # -- on-device counters (ops/moe.py) ----------------------------------
+    def watch_model(self, model):
+        """From now on every snapshot of the registry also reads
+        ``model``'s routed-expert counters off the device. Called once a
+        device call (serve/inference_manager.py); a model without such
+        counters costs two dict lookups."""
+        from flexflow_tpu.ops.moe import MOE_COUNTERS
+
+        if (id(model) not in self._watched
+                and MOE_COUNTERS in (model.op_state or {})):
+            self._watched[id(model)] = [weakref.ref(model), 0]
+
+    @staticmethod
+    def _read_counters(model):
+        """The counters as numpy, or None. The serving thread donates the
+        op state to every device call and puts the new one in its place:
+        a reader on another thread can catch the old array deleted, and
+        looks again."""
+        from flexflow_tpu.ops.moe import MOE_COUNTERS
+
+        for _ in range(8):
+            try:
+                return np.asarray(model.op_state[MOE_COUNTERS])
+            except (RuntimeError, KeyError):
+                time.sleep(0.002)
+        return None
+
+    def _collect_moe(self):
+        from flexflow_tpu.ops.moe import MOE_FIELDS, MOE_PHASES
+
+        r = self.registry
+        for key, watched in list(self._watched.items()):
+            model = watched[0]()
+            if model is None:
+                del self._watched[key]
+                continue
+            raw = self._read_counters(model)
+            if raw is None:
+                continue
+            # the device counts in uint32 and wraps; what a snapshot adds
+            # is the difference since the last one, modulo 2**32, summed
+            # over the layers (the rows)
+            gained = (raw - np.uint32(watched[1])).astype(np.int64).sum(0)
+            watched[1] = raw
+            n = len(MOE_PHASES)
+            pairs = gained[:-len(MOE_FIELDS) * n]
+            calls, tokens, routed, touched = gained[len(pairs):].reshape(
+                len(MOE_FIELDS), n)
+            for i, ph in enumerate(MOE_PHASES):
+                lab = f'{{phase="{ph}"}}'
+                r.counter("ffsv_moe_routed_pairs_total" + lab,
+                          "(token, expert) pairs the routed experts ran"
+                          ).inc(int(routed[i]))
+                r.counter("ffsv_moe_tokens_total" + lab,
+                          "real tokens the routed experts saw, per layer"
+                          ).inc(int(tokens[i]))
+                r.summary("ffsv_moe_experts_touched" + lab,
+                          "distinct experts one expert-layer call read"
+                          ).add(int(calls[i]), float(touched[i]))
+            for e, n_pairs in enumerate(pairs):
+                r.counter(f'ffsv_moe_expert_pairs_total{{expert="{e}"}}',
+                          "routed pairs of one expert, over layers"
+                          ).inc(int(n_pairs))
 
     # -- hooks (serve/request_manager.py, serve/engine.py) ---------------
     def note_admission(self, guid: int, prompt_tokens: int,

@@ -38,7 +38,8 @@ import threading
 from typing import Dict, List, Optional
 
 from flexflow_tpu.telemetry.metrics import (Counter, Gauge, Histogram,
-                                            MetricsRegistry, _fmt)
+                                            MetricsRegistry, Summary, _fmt,
+                                            series)
 from flexflow_tpu.telemetry.tracing import stitch_chrome_trace
 
 __all__ = ["FleetTelemetry"]
@@ -117,14 +118,13 @@ class FleetTelemetry:
             items = sorted(self._replicas.items())
         for rid, tel in items:
             for name, m in sorted(tel.registry._metrics.items()):
+                rep = f'replica="{rid}"'
                 if isinstance(m, (Counter, Gauge)):
+                    lines.append(f"{series(name, '', rep)} {_fmt(m.value)}")
+                elif isinstance(m, (Histogram, Summary)):
+                    lines.append(f"{series(name, '_count', rep)} {m.count}")
                     lines.append(
-                        f'{name}{{replica="{rid}"}} {_fmt(m.value)}')
-                elif isinstance(m, Histogram):
-                    lines.append(
-                        f'{name}_count{{replica="{rid}"}} {m.count}')
-                    lines.append(
-                        f'{name}_sum{{replica="{rid}"}} {_fmt(m.sum)}')
+                        f"{series(name, '_sum', rep)} {_fmt(m.sum)}")
         return "\n".join(ln for ln in lines if ln) + "\n"
 
     # -- traces -----------------------------------------------------------

@@ -105,6 +105,40 @@ class Gauge:
         return {"type": "gauge", "value": self._value}
 
 
+class Summary:
+    """Count and sum of observations that were added up elsewhere: the
+    routed-expert op counts on the device (ops/moe.py), and a snapshot
+    brings over the totals, never the single observations. Exports as a
+    Prometheus summary without quantiles; ``sum / count`` is the mean."""
+
+    __slots__ = ("name", "help", "_n", "_sum")
+
+    def __init__(self, name: str, help: str = ""):
+        self.name = name
+        self.help = help
+        self._n = 0
+        self._sum = 0.0
+
+    def add(self, count: int, total: float):
+        self._n += int(count)
+        self._sum += total
+
+    def reset(self):
+        self._n = 0
+        self._sum = 0.0
+
+    @property
+    def count(self) -> int:
+        return self._n
+
+    @property
+    def sum(self) -> float:
+        return self._sum
+
+    def snapshot(self) -> dict:
+        return {"type": "summary", "count": self._n, "sum": self._sum}
+
+
 class Histogram:
     """Bucketed histogram that ALSO retains raw samples for exact
     percentiles.
@@ -253,10 +287,24 @@ class MetricsRegistry:
     ``counter``/``gauge``/``histogram`` return the existing instrument
     when the name is already registered (mismatched kinds raise), so
     instrumentation sites never need to coordinate creation order.
+
+    A name may carry Prometheus labels (``base{phase="decode"}``): each
+    labelled name is a series of its own here, and the exposition puts
+    the labels where the format wants them. ``add_collector`` registers a
+    function that every export runs first: the place for values that are
+    kept elsewhere and fetched only when somebody looks.
     """
 
     def __init__(self):
         self._metrics: Dict[str, object] = {}
+        self._collectors: List = []
+
+    def add_collector(self, fn):
+        self._collectors.append(fn)
+
+    def collect(self):
+        for fn in self._collectors:
+            fn()
 
     def _get_or_create(self, cls, name, help, **kw):
         m = self._metrics.get(name)
@@ -278,6 +326,9 @@ class MetricsRegistry:
                   window_s: Optional[float] = None) -> Histogram:
         return self._get_or_create(Histogram, name, help, buckets=buckets,
                                    window_s=window_s)
+
+    def summary(self, name: str, help: str = "") -> Summary:
+        return self._get_or_create(Summary, name, help)
 
     def get(self, name: str):
         return self._metrics.get(name)
@@ -306,11 +357,14 @@ class MetricsRegistry:
         """
         out = cls()
         for reg in registries:
+            reg.collect()
             for name, m in reg._metrics.items():
                 if isinstance(m, Counter):
                     out.counter(name, m.help).inc(m.value)
                 elif isinstance(m, Gauge):
                     out.gauge(name, m.help).inc(m.value)
+                elif isinstance(m, Summary):
+                    out.summary(name, m.help).add(m.count, m.sum)
                 elif isinstance(m, Histogram):
                     t = out._get_or_create(Histogram, name, m.help,
                                            buckets=m.buckets,
@@ -353,6 +407,7 @@ class MetricsRegistry:
 
     # -- export -----------------------------------------------------------
     def snapshot(self) -> Dict[str, dict]:
+        self.collect()
         return {name: m.snapshot() for name, m in sorted(self._metrics.items())}
 
     def to_json(self, indent: Optional[int] = None) -> str:
@@ -360,26 +415,32 @@ class MetricsRegistry:
 
     def to_prometheus(self) -> str:
         """Prometheus text exposition format v0.0.4."""
+        self.collect()
         lines: List[str] = []
+        described = set()
         for name, m in sorted(self._metrics.items()):
-            if m.help:
-                lines.append(f"# HELP {name} {m.help}")
-            if isinstance(m, Counter):
-                lines.append(f"# TYPE {name} counter")
+            base = name.partition("{")[0]
+            if base not in described:       # once for a labelled family
+                described.add(base)
+                if m.help:
+                    lines.append(f"# HELP {base} {m.help}")
+                lines.append(f"# TYPE {base} {type(m).__name__.lower()}")
+            if isinstance(m, (Counter, Gauge)):
                 lines.append(f"{name} {_fmt(m.value)}")
-            elif isinstance(m, Gauge):
-                lines.append(f"# TYPE {name} gauge")
-                lines.append(f"{name} {_fmt(m.value)}")
+            elif isinstance(m, Summary):
+                lines.append(f"{series(name, '_sum')} {_fmt(m.sum)}")
+                lines.append(f"{series(name, '_count')} {m.count}")
             elif isinstance(m, Histogram):
-                lines.append(f"# TYPE {name} histogram")
                 cum = 0
                 for b, c in zip(m.buckets, m._counts):
                     cum += c
-                    lines.append(f'{name}_bucket{{le="{_fmt(b)}"}} {cum}')
+                    le = f'le="{_fmt(b)}"'
+                    lines.append(f"{series(name, '_bucket', le)} {cum}")
                 cum += m._counts[-1]
-                lines.append(f'{name}_bucket{{le="+Inf"}} {cum}')
-                lines.append(f"{name}_sum {_fmt(m.sum)}")
-                lines.append(f"{name}_count {m.count}")
+                le = 'le="+Inf"'
+                lines.append(f"{series(name, '_bucket', le)} {cum}")
+                lines.append(f"{series(name, '_sum')} {_fmt(m.sum)}")
+                lines.append(f"{series(name, '_count')} {m.count}")
                 if m.window_s:
                     # live SLO view: exact quantiles over the trailing
                     # window, exported as a Prometheus summary so
@@ -397,6 +458,15 @@ class MetricsRegistry:
                     lines.append(f"{name}_window_sum {_fmt(w['sum'])}")
                     lines.append(f"{name}_window_count {w['count']}")
         return "\n".join(lines) + ("\n" if lines else "")
+
+
+def series(name: str, suffix: str = "", label: str = "") -> str:
+    """``name`` (which may carry labels) with ``suffix`` on its base and
+    one more label: ``series('a{x="1"}', '_sum', 'r="2"')`` is
+    ``a_sum{x="1",r="2"}``."""
+    base, _, own = name.partition("{")
+    labels = ",".join(x for x in (own.rstrip("}"), label) if x)
+    return f"{base}{suffix}" + (f"{{{labels}}}" if labels else "")
 
 
 def _fmt(v: float) -> str:

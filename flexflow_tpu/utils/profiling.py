@@ -34,7 +34,10 @@ def device_fence(out):
     """
     import jax.numpy as jnp
 
-    scalars = [jnp.ravel(leaf)[0].astype(jnp.float32)
+    # index the first element; flattening first (jnp.ravel) copies the whole
+    # leaf on a TPU, where a reshape of a tiled array is a relayout: 2.1 GB
+    # for each of a KV cache's two leaves, alive together until the readback
+    scalars = [jnp.asarray(leaf[(0,) * leaf.ndim], jnp.float32)
                for leaf in jax.tree_util.tree_leaves(out)
                if hasattr(leaf, "dtype") and getattr(leaf, "size", 0)]
     if scalars:

@@ -57,6 +57,30 @@ def test_flash_attend_exports_for_tpu_at_smoke_shapes():
         sds((R, chunk), i32))
 
 
+@pytest.mark.parametrize("tokens", [32, 512],
+                         ids=["decode_32_rows", "prefill_512_tokens"])
+def test_moe_experts_exports_for_tpu_at_the_cells_shapes(tokens):
+    """The routed-expert kernel at OLMoE-1B-7B's widths (64 int8 experts of
+    2048 x 1024, top-8): a decode step of 32 rows and a prefill step of 512
+    real tokens. A Mosaic rejection shows here and not on the chip."""
+    from flexflow_tpu.kernels import moe as K
+    from flexflow_tpu.quant import QuantizedWeight
+
+    E, H, inter, k = 64, 2048, 1024, 8
+    sds = jax.ShapeDtypeStruct
+
+    def q(rows, cols):
+        return QuantizedWeight("int8", sds((E, rows, cols), jnp.int8),
+                               sds((E, cols), jnp.float32), rows, "bfloat16")
+
+    _export_tpu(
+        lambda x, idx, w, valid, g, u, d: K.moe_experts(
+            x, idx, w, valid, g, u, d, pallas=True)[0],
+        sds((tokens, H), jnp.bfloat16), sds((tokens, k), jnp.int32),
+        sds((tokens, k), jnp.float32), sds((tokens,), jnp.bool_),
+        q(H, inter), q(H, inter), q(inter, H))
+
+
 def test_interpret_switch_on_a_tpu_backend_raises(monkeypatch):
     from flexflow_tpu import kernels as ffk
 

@@ -68,12 +68,22 @@ def _hf_starcoder_mha():
         n_positions=128, multi_query=False))
 
 
+def _hf_olmoe():
+    # sparse experts: 8 experts, top-2, not renormalised; q/k RMSNorm
+    return transformers.OlmoeForCausalLM(transformers.OlmoeConfig(
+        vocab_size=256, hidden_size=64, intermediate_size=32,
+        num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=4,
+        num_experts=8, num_experts_per_tok=2, norm_topk_prob=False,
+        max_position_embeddings=128, tie_word_embeddings=False))
+
+
 CASES = {
     "llama": _hf_llama,
     "opt": _hf_opt,
     "falcon": _hf_falcon,
     "falcon-new-arch": _hf_falcon40b_style,
     "mpt": _hf_mpt,
+    "olmoe": _hf_olmoe,
     "starcoder": _hf_starcoder,
     "starcoder-mha": _hf_starcoder_mha,
 }
