@@ -107,6 +107,20 @@ def _kernel(len_ref,                       # scalar prefetch: [R] int32
                    layer_idx=layer_idx, PACK=PACK, D=D)
 
 
+def _rows_kernel(len_ref, rows_ref,        # scalar prefetch: [R] int32 each
+                 q_ref, qp_ref, slopes_ref, bias_hbm, k_hbm, v_hbm,
+                 o_ref,
+                 acc, m, l, kbuf, vbuf, bbuf, sem, **static):
+    """Row-mapped variant (the compact prefill batch): grid program ``r``
+    streams cache row ``rows[r]`` instead of row ``r``. The caches stay in
+    HBM and are only ever indexed by DMA, so reading another row is a
+    different DMA source and nothing is gathered; two programs may read
+    the same row (two segments of one slot)."""
+    _stream_attend(len_ref, None, q_ref, qp_ref, slopes_ref, None, None,
+                   bias_hbm, k_hbm, v_hbm, o_ref, acc, m, l, kbuf, vbuf,
+                   bbuf, sem, None, rows_ref=rows_ref, **static)
+
+
 def _append_kernel(len_ref, appos_ref,     # scalar prefetch: [R] int32 each
                    q_ref, qp_ref, slopes_ref, knew_ref, vnew_ref, bias_hbm,
                    k_hbm, v_hbm,
@@ -140,7 +154,7 @@ def _stream_attend(len_ref, appos_ref, q_ref, qp_ref, slopes_ref, knew_ref,
                    acc, m, l, kbuf, vbuf, bbuf, sem, asem,
                    *, BS: int, causal: bool, has_bias: bool,
                    has_alibi: bool, qk_scale: float, G: int, Q: int,
-                   layer_idx, PACK: int, D: int):
+                   layer_idx, PACK: int, D: int, rows_ref=None):
     """Shared stream-attend body.
 
     PACK == 1: one position per 128-lane cache row (D % 128 == 0).
@@ -160,6 +174,9 @@ def _stream_attend(len_ref, appos_ref, q_ref, qp_ref, slopes_ref, knew_ref,
 
     def nb_of(j):
         return (len_ref[j] + jnp.asarray(BS - 1, jnp.int32)) // BS
+
+    def row_of(j):                        # the cache row program j streams
+        return j if rows_ref is None else rows_ref[j]
 
     nb = nb_of(r)
     acc[:] = jnp.zeros_like(acc)
@@ -222,7 +239,7 @@ def _stream_attend(len_ref, appos_ref, q_ref, qp_ref, slopes_ref, knew_ref,
 
     @pl.when((nb > 0) & jnp.logical_not(prev_live))
     def _():                              # first live program self-starts
-        start_dmas(r, g0 % 2, 0)
+        start_dmas(row_of(r), g0 % 2, 0)
 
     GQ = q_ref.shape[-2]
     qp = qp_ref[r]                                  # [GQ] absolute positions
@@ -237,13 +254,13 @@ def _stream_attend(len_ref, appos_ref, q_ref, qp_ref, slopes_ref, knew_ref,
 
         @pl.when(i + 1 < nb)
         def _():
-            start_dmas(r, nxt_slot, i + 1)
+            start_dmas(row_of(r), nxt_slot, i + 1)
 
         @pl.when((i + 1 == nb) & (r_next < R))
         def _():                          # hand off to the next live row
-            start_dmas(r_next, nxt_slot, 0)
+            start_dmas(row_of(r_next), nxt_slot, 0)
 
-        wait_dmas(r, slot, i)
+        wait_dmas(row_of(r), slot, i)
         if has_append:
             @pl.when(i == bp)
             def _():
@@ -347,8 +364,9 @@ def _stream_attend(len_ref, appos_ref, q_ref, qp_ref, slopes_ref, knew_ref,
     static_argnames=("causal", "qk_scale", "interpret", "out_dtype",
                      "layer_idx"))
 def flash_attend(q, k_cache, v_cache, lengths, qpos, bias=None,
-                 alibi=None, append_kv=None, *, causal=True, qk_scale=None,
-                 out_dtype=None, layer_idx=None, interpret=False):
+                 alibi=None, append_kv=None, rows=None, *, causal=True,
+                 qk_scale=None, out_dtype=None, layer_idx=None,
+                 interpret=False):
     """Batched KV-cache attention.
 
     q        [R, Q, H, D]   new-token queries (rotary already applied)
@@ -366,8 +384,14 @@ def flash_attend(q, k_cache, v_cache, lengths, qpos, bias=None,
                             aliased in/out and the call returns
                             (out, k_cache, v_cache); callers must treat the
                             passed caches as consumed (donated)
+    rows     [R] int32      optional row map: batch row r attends cache row
+                            rows[r] (the cache may then hold any number of
+                            rows, and a row may be named twice); without it
+                            the kernel is the unmapped one, argument for
+                            argument. Not with append_kv.
     returns  [R, Q, H*D], or (out, k_cache, v_cache) with append_kv
     """
+    assert rows is None or append_kv is None, "no fused append by row map"
     R, Q, H, D = q.shape
     KH, S = k_cache.shape[-3], k_cache.shape[-2]
     G = H // KH
@@ -466,12 +490,15 @@ def flash_attend(q, k_cache, v_cache, lengths, qpos, bias=None,
             R, Q, H * D)
 
     if append_kv is None:
+        prefetch = [lengths.astype(jnp.int32)]
+        if rows is not None:
+            prefetch.append(rows.astype(jnp.int32))
         kern = functools.partial(
-            _kernel, BS=BS, causal=causal, has_bias=has_bias,
-            has_alibi=has_alibi, qk_scale=float(qk_scale), G=G, Q=Q,
-            layer_idx=layer_idx, PACK=PACK, D=D)
+            _kernel if rows is None else _rows_kernel, BS=BS, causal=causal,
+            has_bias=has_bias, has_alibi=has_alibi, qk_scale=float(qk_scale),
+            G=G, Q=Q, layer_idx=layer_idx, PACK=PACK, D=D)
         grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(R,),
+            num_scalar_prefetch=len(prefetch), grid=(R,),
             in_specs=qkv_in_specs + tail_in_specs,
             out_specs=o_spec, scratch_shapes=scratch)
         out = pl.pallas_call(
@@ -481,7 +508,7 @@ def flash_attend(q, k_cache, v_cache, lengths, qpos, bias=None,
                 jnp.float32 if PACK > 1 else out_dtype),
             compiler_params=compiler_params, cost_estimate=cost_estimate,
             interpret=interpret,
-        )(lengths.astype(jnp.int32), qt, qp_gq, slopes_gq,
+        )(*prefetch, qt, qp_gq, slopes_gq,
           bias.astype(jnp.float32), k_cache, v_cache)
         return post(out)
 

@@ -93,6 +93,9 @@ def plan_routes(idx, valid, num_experts: int, tm: int):
         ks < E,
         (tile_ends - tiles)[kc] * tm + jnp.arange(P, dtype=i32) - starts[kc],
         M)
+    # computed once: fused into both scatters below, the two table lookups
+    # of a prefill chunk's pairs cost XLA's fusion pass seconds a layer
+    dest = jax.lax.optimization_barrier(dest)
     row_token = jnp.zeros((M,), i32).at[dest].set(order // k, mode="drop")
     pair_row = jnp.zeros((P,), i32).at[order].set(dest).reshape(T, k)
     # tiles past the last active one repeat its expert, so nothing is

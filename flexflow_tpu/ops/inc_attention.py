@@ -186,8 +186,13 @@ def alibi_slopes(num_heads: int) -> jnp.ndarray:
 
 
 def _attend(attrs, q, k_cache, v_cache, lengths, qpos, out_dtype, ctx,
-            bias=None, causal=True, layer_idx=None, append_kv=None):
+            bias=None, causal=True, layer_idx=None, append_kv=None,
+            rows=None):
     """q [R,Q,H,D] x cache [R,KH,S,D] -> [R, Q, H*D].
+
+    ``rows`` [R] (the compact prefill batch's ``BatchMeta.slots``): batch
+    row r attends cache row rows[r]. The Pallas kernel takes it as a DMA
+    row map; the jnp paths gather those rows.
 
     With ``layer_idx`` the caches are the full stacked [L, R, KH, S, D]
     buffers and only that layer is read — the Pallas kernel DMAs straight
@@ -253,7 +258,7 @@ def _attend(attrs, q, k_cache, v_cache, lengths, qpos, out_dtype, ctx,
             out_dtype=out_dtype, layer_idx=layer_idx,
             interpret=ffk.pallas_interpret_forced())
         args = (_pad_d(q, Dp), k_cache, v_cache, lengths, qpos, bias, alibi,
-                fkv)
+                fkv, rows)
         if (mesh is not None and mesh.devices.size > 1
                 and getattr(ctx, "kv_override", None) is None):
             attend = _attend_on_mesh(attend, mesh, *args)
@@ -281,6 +286,8 @@ def _attend(attrs, q, k_cache, v_cache, lengths, qpos, out_dtype, ctx,
     kc, vc = k_cache, v_cache
     if layer_idx is not None:
         kc, vc = k_cache[layer_idx], v_cache[layer_idx]
+    if rows is not None:
+        kc, vc = kc[rows], vc[rows]
     if seq_deg > 1 and S % seq_deg == 0:
         # searched sequence-parallel plan: the cache S dim is sharded over
         # the mesh's "seq" axis — score local slices, reconcile the softmax
@@ -297,7 +304,7 @@ def _attend(attrs, q, k_cache, v_cache, lengths, qpos, out_dtype, ctx,
 
 
 def _attend_on_mesh(attend, mesh, q, k_cache, v_cache, lengths, qpos, bias,
-                    alibi, append_kv):
+                    alibi, append_kv, rows):
     """``flash_attend`` as a manual region over the whole mesh.
 
     XLA refuses to partition a Mosaic custom call ("cannot be
@@ -319,7 +326,8 @@ def _attend_on_mesh(attend, mesh, q, k_cache, v_cache, lengths, qpos, bias,
     in_specs = (heads, cache, cache, P(), P(),
                 None if bias is None else P(),
                 None if alibi is None else P(m),
-                None if append_kv is None else (heads, heads, P()))
+                None if append_kv is None else (heads, heads, P()),
+                None if rows is None else P())
     return jax.shard_map(
         attend, mesh=mesh, in_specs=in_specs,
         out_specs=out if append_kv is None else (out, cache, cache),
@@ -464,17 +472,26 @@ def write_kv(ctx, attrs, k_cache, v_cache):
                                  "v": st["v"].at[idx].set(v_cache)}
 
 
-def append_kv_contiguous(cache, layer_idx, new, start_pos, active):
+def append_kv_contiguous(cache, layer_idx, new, start_pos, active,
+                         slots=None, num_tokens=None):
     """In-place contiguous append: per-request dynamic_update_slice of the
     [KH, Q, D] run at start_pos[r] — no scatter at all.
 
-    Callable ONLY under the engines' guarantee that every ACTIVE row has
-    start_pos + Q <= S (their live_masks enforce it). Inactive rows
-    re-write their current region unchanged (a slot can be live but
-    sitting out of an engine block — e.g. cramped near the cache end —
-    so its KV must not be touched). Padding tokens beyond num_tokens
-    write garbage BEYOND the valid extent, masked by lengths until
-    overwritten by the next real append.
+    Without ``slots`` (the fused engines): callable ONLY under their
+    guarantee that every ACTIVE row has start_pos + Q <= S (their
+    live_masks enforce it). Inactive rows re-write their current region
+    unchanged (a slot can be live but sitting out of an engine block —
+    e.g. cramped near the cache end — so its KV must not be touched).
+    Padding tokens beyond num_tokens write garbage BEYOND the valid
+    extent, masked by lengths until overwritten by the next real append.
+
+    With ``slots`` (the compact prefill batch; ``num_tokens`` is read
+    only then): batch row r's run lands in cache row slots[r], and the
+    append is exact, as the windowed scatter's drop is: a padding position
+    keeps what the cache held (a later row may be the same slot's next
+    chunk), and a run that starts within Q of the cache's end is written
+    through the window [S - Q, S) with its tokens shifted to their own
+    positions (the whole row, where Q > S).
 
     This beats both scatter forms: the windowed scatter forces a permuted
     layout + full per-layer cache copies (~134MB/layer/step at 7B), and
@@ -485,28 +502,59 @@ def append_kv_contiguous(cache, layer_idx, new, start_pos, active):
     S = cache.shape[-2]
     KH, D = cache.shape[-3], cache.shape[-1]
     newT = jnp.swapaxes(new.astype(cache.dtype), 1, 2)    # [R, KH, Q, D]
+    lead = () if layer_idx is None else (layer_idx,)
+    # the eager debug dump (utils/debugging) hands numpy descriptors over
+    start_pos, active = jnp.asarray(start_pos), jnp.asarray(active)
+    lead1 = (1,) * (len(lead) + 1)
+    if slots is not None:
+        slots, num_tokens = jnp.asarray(slots), jnp.asarray(num_tokens)
+        if Q > S:
+            # a chunk wider than the cache (tiny max_sequence_length): no
+            # position past S exists, so the run's first S columns hold
+            # every token that can land
+            newT, Q = newT[:, :, :S], S
+        s = jnp.clip(start_pos, 0, S - Q)
+        shift = start_pos - s             # > 0 only within Q of the end
+        t = jnp.arange(Q)[None] - shift[:, None]    # a window column's token
+        keep = active[:, None] & (t >= 0) & (t < num_tokens[:, None])
+        # a handful of rows (the step's segments), in order and unrolled:
+        # as a device loop each row read its scalars one operation apiece,
+        # dozens a row, in every layer of every prefill step
+        for r in range(R):
+            at = lead + (slots[r], 0, s[r], 0)
+            run = jnp.roll(newT[r], shift[r], axis=1)
+            cur = jax.lax.dynamic_slice(cache, at, lead1 + (KH, Q, D))
+            upd = jnp.where(keep[r][None, :, None],
+                            run[(None,) * len(lead1)], cur)
+            cache = jax.lax.dynamic_update_slice(cache, upd, at)
+        return cache
 
     def body(r, c):
-        s = jnp.clip(start_pos[r], 0, S - Q)
-        if layer_idx is None:
-            cur = jax.lax.dynamic_slice(c, (r, 0, s, 0), (1, KH, Q, D))
-            upd = jnp.where(active[r], newT[r][None], cur)
-            return jax.lax.dynamic_update_slice(c, upd, (r, 0, s, 0))
-        cur = jax.lax.dynamic_slice(c, (layer_idx, r, 0, s, 0),
-                                    (1, 1, KH, Q, D))
-        upd = jnp.where(active[r], newT[r][None, None], cur)
-        return jax.lax.dynamic_update_slice(c, upd,
-                                            (layer_idx, r, 0, s, 0))
+        at = lead + (r, 0, jnp.clip(start_pos[r], 0, S - Q), 0)
+        cur = jax.lax.dynamic_slice(c, at, lead1 + (KH, Q, D))
+        upd = jnp.where(active[r], newT[r][(None,) * len(lead1)], cur)
+        return jax.lax.dynamic_update_slice(c, upd, at)
 
     return jax.lax.fori_loop(0, R, body, cache)
 
 
-def append_and_ref(ctx, attrs, k, v, start_pos, num_tokens, active):
+# One trace serves every layer's K and V append of a compact prefill step:
+# the layer index is an operand here, where the engines' calls bake it in
+# (tracing the loop 2 x layers times cost seconds of set-up at 32 layers).
+_append_by_slot = jax.jit(append_kv_contiguous)
+
+
+def append_and_ref(ctx, attrs, k, v, start_pos, num_tokens, active,
+                   slots=None):
     """Append this step's KV and return (k_ref, v_ref, layer_idx) to attend
     over: layer_idx is None when the refs are this layer's own [R,KH,S,D]
     caches, or the layer's index when they are the full [L,...] stack
     (stacked caches append in place — see append_kv_stacked). New k/v pad
     to the cache's (128-lane-tiled) head dim first.
+
+    ``slots`` (the compact prefill batch): row r's run goes to cache row
+    slots[r] by the exact in-place append of append_kv_contiguous, so a
+    prefill step neither scatters nor slices a layer of the stack out.
 
     The row-granular stacked path is chosen whenever its scalar-unit cost
     (~R*KH*Q index rows) beats the per-layer slice-out/write-back HBM
@@ -517,10 +565,18 @@ def append_and_ref(ctx, attrs, k, v, start_pos, num_tokens, active):
     ov = getattr(ctx, "kv_override", None)
     idx = attrs.get("cache_layer_idx")
     contiguous = getattr(ctx, "kv_contiguous", False)
+    # a pipeline stage's microbatch holds a slice of the cache's rows: its
+    # loops keep the slot grid (RequestManager._compact_prefill)
+    assert ov is None or slots is None, "no row map inside a pipeline stage"
     if ov is not None or idx is None:
         k0, v0 = read_kv(ctx, attrs)
         k, v = _pad_d(k, k0.shape[-1]), _pad_d(v, v0.shape[-1])
-        if contiguous and k.shape[1] != 1:
+        if slots is not None:
+            kc = _append_by_slot(k0, None, k, start_pos, active, slots,
+                                 num_tokens)
+            vc = _append_by_slot(v0, None, v, start_pos, active, slots,
+                                 num_tokens)
+        elif contiguous and k.shape[1] != 1:
             kc = append_kv_contiguous(k0, None, k, start_pos, active)
             vc = append_kv_contiguous(v0, None, v, start_pos, active)
         else:
@@ -530,7 +586,12 @@ def append_and_ref(ctx, attrs, k, v, start_pos, num_tokens, active):
         return kc, vc, None
     st = ctx.state_out.get("kv_cache") or ctx.state_in["kv_cache"]
     k, v = _pad_d(k, st["k"].shape[-1]), _pad_d(v, st["v"].shape[-1])
-    if contiguous and k.shape[1] != 1:
+    if slots is not None:
+        ks = _append_by_slot(st["k"], jnp.int32(idx), k, start_pos, active,
+                             slots, num_tokens)
+        vs = _append_by_slot(st["v"], jnp.int32(idx), v, start_pos, active,
+                             slots, num_tokens)
+    elif contiguous and k.shape[1] != 1:
         # wide contiguous appends (engine verify/catch-up): scatter-free
         # DUS; decode (Q == 1) stays on the per-(r,kh) row scatter — at 7B
         # the stacked 5D DUS read-modify loop defeats XLA's in-place
@@ -602,7 +663,9 @@ class IncMultiHeadSelfAttention(OpImpl):
         lengths = jnp.where(meta.active, meta.start_pos + meta.num_tokens, 0)
         append_q = getattr(ctx, "kv_append_q", None)
         eff_q = append_q if (append_q is not None and Q > append_q) else Q
-        if eff_q == 1 and getattr(ctx, "kv_override", None) is None:
+        slots = meta.slots
+        if (eff_q == 1 and slots is None
+                and getattr(ctx, "kv_override", None) is None):
             # single new real token per row (decode; verify-consistent
             # wide decode has 1 real + padding tokens): fuse the KV append
             # into the attention kernel instead of an XLA row scatter
@@ -626,9 +689,10 @@ class IncMultiHeadSelfAttention(OpImpl):
                 ctx.state_out["kv_cache"] = {"k": knew, "v": vnew}
             return [_project_out(attrs, params, ctx, out)]
         k_ref, v_ref, layer_idx = append_and_ref(
-            ctx, attrs, k, v, meta.start_pos, meta.num_tokens, meta.active)
+            ctx, attrs, k, v, meta.start_pos, meta.num_tokens, meta.active,
+            slots)
         out = _attend(attrs, q, k_ref, v_ref, lengths, q_abs, x.dtype,
-                      ctx, causal=True, layer_idx=layer_idx)
+                      ctx, causal=True, layer_idx=layer_idx, rows=slots)
         return [_project_out(attrs, params, ctx, out)]
 
 

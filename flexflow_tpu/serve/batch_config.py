@@ -91,6 +91,13 @@ class BatchMeta:
     start_pos: int32[R]     KV-cache depth of each slot before this step
     num_tokens:int32[R]     how many of the Q tokens are real (rest padding)
     active:    bool[R]      slot currently holds a request
+    slots:     int32[R]     the cache row each batch row reads and appends
+                            to; None: row i is slot i. With it the batch is
+                            segment major (the compact prefill batch of
+                            RequestManager._meta_from_segments): R is the
+                            number of segments, two rows may be consecutive
+                            chunks of one slot, and every row's K/V is
+                            appended before any row attends
     """
 
     tokens: jnp.ndarray
@@ -98,6 +105,7 @@ class BatchMeta:
     start_pos: jnp.ndarray
     num_tokens: jnp.ndarray
     active: jnp.ndarray
+    slots: Optional[jnp.ndarray] = None
 
     @property
     def q_width(self) -> int:

@@ -643,7 +643,8 @@ class RequestManager:
         tel.record_prefill(dt, n_tokens,
                            [(active[slot].guid, sp, len(chunk))
                             for slot, chunk, sp in rows]
-                           if active is not None else (), t0)
+                           if active is not None else (), t0,
+                           positions=meta.tokens.size)
 
     def _tel_tick(self, tel, live, slots: int, max_seq: int):
         """Once per scheduling tick that dispatches decode/spec work:
@@ -673,21 +674,75 @@ class RequestManager:
         return BatchMeta(tokens=tokens, positions=positions, start_pos=start,
                          num_tokens=num, active=act)
 
-    def _prefill_rows(self, active, chunk: int, depth_of, max_batch_tokens):
-        """Slots whose pending tokens exceed 1 → next chunk each (leaving at
-        least one token pending so the final chunk emits the next token)."""
-        rows, budget = [], max_batch_tokens
-        for req in active:
-            if req is None or req.finished:
-                continue
-            d = depth_of(req)
-            npend = len(req.tokens) - d
-            if npend > 1:
-                take = min(npend - 1, chunk, budget)
-                if take <= 0:
-                    continue
+    @staticmethod
+    def _meta_from_segments(P: int, Q: int, rows) -> BatchMeta:
+        """The compact prefill batch: batch row i is rows[i], a segment
+        (slot, tokens_chunk, start_pos) whose cache row goes in ``slots``.
+        The rows left over are inactive and point at slot 0."""
+        meta = RequestManager._meta_from_rows(
+            P, Q, [(i, chunk, sp) for i, (_, chunk, sp) in enumerate(rows)])
+        slots = np.zeros((P,), np.int32)
+        slots[:len(rows)] = [slot for slot, _, _ in rows]
+        return dataclasses.replace(meta, slots=slots)
+
+    @staticmethod
+    def _prefill_shape(cfg):
+        """(chunk, segments) of a prefill step: the batch's token budget
+        over at most four rows, and the rows that budget allows."""
+        chunk = max(1, cfg.max_tokens_per_batch
+                    // max(1, min(cfg.max_requests_per_batch, 4)))
+        return chunk, max(1, cfg.max_tokens_per_batch // chunk)
+
+    @staticmethod
+    def _prefill_rows(active, chunk: int, depth_of, segments: int,
+                      consecutive: bool = True):
+        """At most ``segments`` segments (slot, tokens, start_pos) of at
+        most ``chunk`` tokens for one prefill step. The requests whose
+        pending tokens exceed 1 get one each, oldest admission first
+        (leaving at least one token pending so the final chunk emits the
+        next token); with ``consecutive`` the spare segments go, in the
+        same order, to those with more still pending, as their next chunks.
+        A slot's segments come in ascending order of start_pos."""
+        rows, taken = [], {}
+
+        def pending(req):
+            return len(req.tokens) - depth_of(req) - taken.get(req.slot, 0)
+
+        filling = sorted((req for req in active if req is not None
+                          and not req.finished and pending(req) > 1),
+                         key=lambda req: req.prefill_start_s)
+        while filling and len(rows) < segments:
+            for req in filling[:segments - len(rows)]:
+                d = len(req.tokens) - pending(req)
+                take = min(pending(req) - 1, chunk)
                 rows.append((req.slot, req.tokens[d:d + take], d))
-                budget -= take
+                taken[req.slot] = taken.get(req.slot, 0) + take
+            filling = [req for req in filling
+                       if consecutive and pending(req) > 1]
+        return rows
+
+    @staticmethod
+    def _compact_prefill(ifm) -> bool:
+        """Whether ``ifm``'s model takes the compact prefill batch. A
+        pipeline stage streams microbatches of batch rows and of cache rows
+        together (serve/pipeline_plan.py), so a batch row there cannot
+        reach another slot's cache: a pipelined model keeps the slot grid."""
+        return getattr(getattr(ifm, "model", None), "_pp_plan", None) is None
+
+    def _prefill(self, ifm, active, shape, depth_of, tel, rnd=None):
+        """One round's prefill for ``ifm``'s model: choose the segments
+        among ``active`` (None: not a candidate), run them in one
+        output-free step, return them. The one prefill path of the Python
+        loops; the caller moves its depth marks by the rows returned."""
+        chunk, segments = shape
+        compact = self._compact_prefill(ifm)
+        rows = self._prefill_rows(active, chunk, depth_of, segments,
+                                  consecutive=compact)
+        if rows:
+            meta = (self._meta_from_segments(segments, chunk, rows)
+                    if compact else
+                    self._meta_from_rows(len(active), chunk, rows))
+            self._timed_prefill(ifm, meta, tel, rows, active, rnd=rnd)
         return rows
 
     # =====================================================================
@@ -728,7 +783,7 @@ class RequestManager:
         self.scheduler_loop = "python"
         R = cfg.max_requests_per_batch
         max_seq = cfg.max_sequence_length
-        chunk = max(1, cfg.max_tokens_per_batch // max(1, min(R, 4)))
+        shape = chunk, _ = self._prefill_shape(cfg)
         active: List[Optional[Request]] = [None] * R
         done: List[GenerationResult] = []
 
@@ -741,19 +796,14 @@ class RequestManager:
             if rnd is not None:
                 rnd.admitted(R - active.count(None), len(self.pending))
             # decode-interleaved chunked prefill (ISSUE 19): each engine
-            # round dispatches at most ONE bounded prefill chunk AND the
-            # decode block for already-caught-up slots — a queued short
-            # request's TTFT no longer tracks the longest resident
-            # prompt's full prefill.
-            rows = self._prefill_rows(active, chunk,
-                                      lambda r: r.cache_depth,
-                                      cfg.max_tokens_per_batch)
-            if rows:
-                meta = self._meta_from_rows(R, chunk, rows)
-                # non-final chunk outputs unused
-                self._timed_prefill(ifm, meta, tel, rows, active, rnd=rnd)
-                for slot, chunk_toks, sp in rows:
-                    active[slot].cache_depth = sp + len(chunk_toks)
+            # round dispatches at most ONE bounded prefill step (its
+            # outputs unused) AND the decode block for already-caught-up
+            # slots — a queued short request's TTFT no longer tracks the
+            # longest resident prompt's full prefill.
+            rows = self._prefill(ifm, active, shape,
+                                 lambda r: r.cache_depth, tel, rnd)
+            for slot, chunk_toks, sp in rows:
+                active[slot].cache_depth = sp + len(chunk_toks)
             # decode: every caught-up slot feeds its pending token; the
             # token-feedback loop runs fused on device (DECODE_BLOCK steps
             # per call); EOS/length overshoot is reconciled host-side.
@@ -1140,7 +1190,7 @@ class RequestManager:
         R = cfg.max_requests_per_batch
         max_seq = cfg.max_sequence_length
         depth = min(spec_depth or self.max_spec_depth, self.max_spec_depth)
-        chunk = max(1, cfg.max_tokens_per_batch // max(1, min(R, 4)))
+        shape = self._prefill_shape(cfg)
         # tree capacity: root + depth nodes per surviving branch
         T = 1 + depth * len(ssms) * beam_width
         active: List[Optional[Request]] = [None] * R
@@ -1154,24 +1204,17 @@ class RequestManager:
             self._reap_expired(active, max_seq, done)
             self._fill_slots(active, max_seq, done)
             # ---- prompt prefill: verifier + every SSM ----
-            prefilled = False
-            rows = self._prefill_rows(active, chunk, lambda r: r.cache_depth,
-                                      cfg.max_tokens_per_batch)
-            if rows:
-                meta = self._meta_from_rows(R, chunk, rows)
-                self._timed_prefill(llm_ifm, meta, tel, rows, active)
-                for slot, toks, sp in rows:
-                    active[slot].cache_depth = sp + len(toks)
-                prefilled = True
+            rows = self._prefill(llm_ifm, active, shape,
+                                 lambda r: r.cache_depth, tel)
+            for slot, toks, sp in rows:
+                active[slot].cache_depth = sp + len(toks)
+            prefilled = bool(rows)
             for i, ifm in enumerate(ssm_ifms):
-                rows = self._prefill_rows(active, chunk, ssm_depth_of(i),
-                                          cfg.max_tokens_per_batch)
-                if rows:
-                    meta = self._meta_from_rows(R, chunk, rows)
-                    self._timed_prefill(ifm, meta, tel, rows, active)
-                    for slot, toks, sp in rows:
-                        active[slot].ssm_cache_depth[i] = sp + len(toks)
-                    prefilled = True
+                rows = self._prefill(ifm, active, shape, ssm_depth_of(i),
+                                     tel)
+                for slot, toks, sp in rows:
+                    active[slot].ssm_cache_depth[i] = sp + len(toks)
+                prefilled = prefilled or bool(rows)
             if prefilled:
                 continue
             live = [req for req in active if req is not None and not req.finished]
@@ -1288,7 +1331,7 @@ class RequestManager:
                 engine = llm._chain_engine = SpecChainEngine(
                     llm, ssm, depth, max_rounds=cfg.spec_rounds_per_call)
             room_needed = depth + 1
-        chunk = max(1, cfg.max_tokens_per_batch // max(1, min(R, 4)))
+        shape = self._prefill_shape(cfg)
         active: List[Optional[Request]] = [None] * R
         done: List[GenerationResult] = []
 
@@ -1309,36 +1352,29 @@ class RequestManager:
             # one bounded chunk per model per round — caught-up slots
             # draft/decode below in the SAME round (decode-interleaved
             # chunked prefill, ISSUE 19)
-            prefilled = False
-            for ifm, depth_of in ((llm_ifm, lambda r: r.cache_depth),
-                                  (ssm_ifm,
-                                   lambda r: r.ssm_cache_depth.get(0, 0))):
-                rows = self._prefill_rows(active, chunk, depth_of,
-                                          cfg.max_tokens_per_batch)
-                if ifm is ssm_ifm:
-                    # Catching the SSM cache up is only useful if the request
-                    # can still draft (a full round of depth+1 KV slots left
-                    # AND the controller hasn't parked it on incremental —
-                    # healing a parked request's draft cache would be pure
-                    # waste until its probe comes due);
-                    # tail tokens go through the single-step fallback anyway.
-                    rows = [(slot, toks, sp) for slot, toks, sp in rows
-                            if max_seq - len(active[slot].tokens) - 1
-                            >= room_needed
-                            and (ctrl is None
-                                 or ctrl.wants_draft(active[slot].guid))]
-                if rows:
-                    meta = self._meta_from_rows(R, chunk, rows)
-                    self._timed_prefill(ifm, meta, tel, rows, active,
-                                        rnd=rnd)
-                    for slot, toks, sp in rows:
-                        if ifm is llm_ifm:
-                            active[slot].cache_depth = sp + len(toks)
-                        else:
-                            active[slot].ssm_cache_depth[0] = sp + len(toks)
-                    prefilled = True
-                    if rnd is not None:
-                        rnd.note_cut(self._prefill_kind(active, rows))
+            rows = self._prefill(llm_ifm, active, shape,
+                                 lambda r: r.cache_depth, tel, rnd)
+            for slot, toks, sp in rows:
+                active[slot].cache_depth = sp + len(toks)
+            # Catching the SSM cache up is only useful if the request can
+            # still draft (a full round of depth+1 KV slots left AND the
+            # controller hasn't parked it on incremental — healing a parked
+            # request's draft cache would be pure waste until its probe
+            # comes due); tail tokens go through the single-step fallback
+            # anyway.
+            drafting = [req if req is not None
+                        and max_seq - len(req.tokens) - 1 >= room_needed
+                        and (ctrl is None or ctrl.wants_draft(req.guid))
+                        else None for req in active]
+            ssm_rows = self._prefill(ssm_ifm, drafting, shape,
+                                     lambda r: r.ssm_cache_depth.get(0, 0),
+                                     tel, rnd)
+            for slot, toks, sp in ssm_rows:
+                active[slot].ssm_cache_depth[0] = sp + len(toks)
+            prefilled = bool(rows or ssm_rows)
+            if rnd is not None:
+                for part in filter(None, (rows, ssm_rows)):
+                    rnd.note_cut(self._prefill_kind(active, part))
             live = [req for req in active
                     if req is not None and not req.finished]
             # decode-interleaved chunked prefill: only slots whose
@@ -1524,7 +1560,7 @@ class RequestManager:
                 or engine.depth != depth):
             engine = llm._multi_engine = MultiSpecEngine(
                 llm, ssms, depth, max_rounds=cfg.spec_rounds_per_call)
-        chunk = max(1, cfg.max_tokens_per_batch // max(1, min(R, 4)))
+        shape = self._prefill_shape(cfg)
         active: List[Optional[Request]] = [None] * R
         done: List[GenerationResult] = []
         # a request can draft only with the engine's FULL staging window of
@@ -1559,38 +1595,28 @@ class RequestManager:
                 rnd.admitted(R - active.count(None), len(self.pending))
             # one bounded prefill chunk per model per round; caught-up
             # slots spec/decode below in the SAME round (ISSUE 19)
-            prefilled = False
-            rows = self._prefill_rows(active, chunk, lambda r: r.cache_depth,
-                                      cfg.max_tokens_per_batch)
-            if rows:
-                meta = self._meta_from_rows(R, chunk, rows)
-                self._timed_prefill(llm_ifm, meta, tel, rows, active,
-                                    rnd=rnd)
-                for slot, toks, sp in rows:
-                    active[slot].cache_depth = sp + len(toks)
-                prefilled = True
-                if rnd is not None:
-                    rnd.note_cut("prefill")
+            rows = self._prefill(llm_ifm, active, shape,
+                                 lambda r: r.cache_depth, tel, rnd)
+            for slot, toks, sp in rows:
+                active[slot].cache_depth = sp + len(toks)
+            prefilled = bool(rows)
+            if rows and rnd is not None:
+                rnd.note_cut("prefill")
             # a row whose drafts owe no more than one accepted block goes
             # to the engine as it is (run_block's first draft step is the
-            # catch-up); only a row that owes more is fed in chunks here
-            owing = [None if req is None or carries_block(req) else req
-                     for req in active]
+            # catch-up); only a row that owes more, and can still draft, is
+            # fed in chunks here
+            owing = [req if req is not None and not carries_block(req)
+                     and max_seq - len(req.tokens) >= room_needed
+                     and (ctrl is None or ctrl.wants_draft(req.guid))
+                     else None for req in active]
             for i, ifm in enumerate(ssm_ifms):
-                rows = self._prefill_rows(
-                    owing, chunk, lambda r, i=i: r.ssm_cache_depth.get(i, 0),
-                    cfg.max_tokens_per_batch)
-                rows = [(slot, toks, sp) for slot, toks, sp in rows
-                        if max_seq - len(active[slot].tokens)
-                        >= room_needed
-                        and (ctrl is None
-                             or ctrl.wants_draft(active[slot].guid))]
+                rows = self._prefill(
+                    ifm, owing, shape,
+                    lambda r, i=i: r.ssm_cache_depth.get(i, 0), tel, rnd)
+                for slot, toks, sp in rows:
+                    active[slot].ssm_cache_depth[i] = sp + len(toks)
                 if rows:
-                    meta = self._meta_from_rows(R, chunk, rows)
-                    self._timed_prefill(ifm, meta, tel, rows, active,
-                                        rnd=rnd)
-                    for slot, toks, sp in rows:
-                        active[slot].ssm_cache_depth[i] = sp + len(toks)
                     prefilled = True
                     if rnd is not None:
                         rnd.note_cut(self._prefill_kind(active, rows))

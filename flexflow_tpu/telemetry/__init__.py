@@ -27,6 +27,7 @@ ffsv_requests_preempted_total    counter    slot evictions for a deadline
 ffsv_queue_depth                 gauge      submission queue depth (front door)
 ffsv_tokens_generated_total      counter    output tokens committed
 ffsv_prefill_tokens_total        counter    prompt tokens prefilled
+ffsv_prefill_positions_total     counter    positions prefill steps computed
 ffsv_spec_rounds_total           counter    speculation rounds executed
 ffsv_decode_steps_total          counter    incremental decode steps
 ffsv_acceptance_length           histogram  accepted draft tokens per round
@@ -240,6 +241,9 @@ class ServingTelemetry:
             "ffsv_tokens_generated_total", "output tokens committed")
         self.prefill_tokens = r.counter(
             "ffsv_prefill_tokens_total", "prompt tokens prefilled")
+        self.prefill_positions = r.counter(
+            "ffsv_prefill_positions_total",
+            "positions prefill steps computed (batch rows x chunk)")
         self.spec_rounds = r.counter(
             "ffsv_spec_rounds_total", "speculation rounds executed")
         self.decode_steps = r.counter(
@@ -489,11 +493,13 @@ class ServingTelemetry:
                            target=target, trace_id=trace_id)
 
     def record_prefill(self, seconds: float, n_tokens: int, rows=(),
-                       t0: Optional[float] = None):
+                       t0: Optional[float] = None, positions: int = 0):
         """``t0``: the step's start on ``perf_counter`` (None: it ended
-        just now)."""
+        just now). ``positions``: the batch rows x chunk the step's program
+        computed, real tokens or padding."""
         self.prefill_seconds.observe(seconds)
         self.prefill_tokens.inc(n_tokens)
+        self.prefill_positions.inc(positions)
         if t0 is None:
             t0 = time.perf_counter() - seconds
         for guid, start_pos, n in rows:

@@ -55,6 +55,11 @@ def test_flash_attend_exports_for_tpu_at_smoke_shapes():
                                             layer_idx=0),
         sds((R, chunk, H, D), bf), stack, stack, sds((R,), i32),
         sds((R, chunk), i32))
+    _export_tpu(                      # the compact prefill batch: 4 segments
+        lambda q, k, v, n, qp, rows: flash_attend(      # read by row map
+            q, k, v, n, qp, rows=rows, causal=True, layer_idx=0),
+        sds((4, chunk, H, D), bf), stack, stack, sds((4,), i32),
+        sds((4, chunk), i32), sds((4,), i32))
 
 
 @pytest.mark.parametrize("tokens", [32, 512],

@@ -153,6 +153,18 @@ def test_tile_plan_puts_every_pair_in_its_experts_tile():
     assert K.pick_tile(1 << 20, 64) == 128
 
 
+def test_tile_plan_computes_each_destination_once():
+    """A prefill chunk's plan (512 tokens x 8 at the cell's widths): a
+    pair's destination row is two table lookups, and fused into both of the
+    scatters that use it they cost the TPU compiler's fusion pass seconds a
+    layer (most of a 16-layer prefill program's compile). They stay behind
+    a barrier, computed once."""
+    sds = jax.ShapeDtypeStruct
+    lowered = jax.jit(lambda i, v: K.plan_routes(i, v, 64, 64)).lower(
+        sds((512, 8), jnp.int32), sds((512,), jnp.bool_))
+    assert "optimization_barrier" in lowered.as_text()
+
+
 # ---------------------------------------------------------------------------
 # stacked int8 weights
 # ---------------------------------------------------------------------------

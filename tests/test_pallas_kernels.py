@@ -348,3 +348,54 @@ def test_head_dim_64_short_cache_pads_to_keep_flash(monkeypatch):
     assert len(r.output_tokens) == 6
     assert ffk.fast_path_count > 0, "flash path never engaged"
     assert not ffk.fallback_counts, ffk.fallback_counts
+
+
+@pytest.mark.parametrize("stacked", [False, True],
+                         ids=["per_layer", "stacked"])
+@pytest.mark.parametrize("D,KH,H", [(128, 2, 4), (64, 1, 4)],
+                         ids=["pack1_d128", "pack2_mqa_d64"])
+def test_flash_row_map_matches_reference_on_gathered_rows(D, KH, H, stacked):
+    """The compact prefill batch's row map: program p streams cache row
+    rows[p]. Two programs name slot 5 (consecutive chunks of one prompt),
+    one program is inactive, and the cache has more rows than the batch."""
+    P, Q, S, R, L = 4, 16, 512, 6, 3
+    rng = np.random.RandomState(21)
+    q = jnp.asarray(rng.randn(P, Q, H, D).astype(np.float32))
+    k = jnp.asarray(rng.randn(L, R, KH, S, D).astype(np.float32))
+    v = jnp.asarray(rng.randn(L, R, KH, S, D).astype(np.float32))
+    rows = jnp.asarray([5, 2, 5, 0], jnp.int32)
+    start = np.array([250, 3, 250 + Q, 0], np.int32)
+    lengths = jnp.asarray([250 + Q, 3 + 9, 250 + 2 * Q, 0], jnp.int32)
+    qpos = jnp.asarray(start[:, None] + np.arange(Q)[None, :], jnp.int32)
+    layer = 1
+    ref = reference_attend(q, k[layer][rows], v[layer][rows], lengths, qpos)
+    if stacked:
+        out = flash_attend(q, k, v, lengths, qpos, rows=rows,
+                           layer_idx=layer, interpret=True)
+    else:
+        out = flash_attend(q, k[layer], v[layer], lengths, qpos, rows=rows,
+                           interpret=True)
+    _cmp(ref, out, lengths, 2e-5)
+
+
+def test_flash_without_row_map_keeps_its_kernel_arguments():
+    """Decode blocks, tree verify and the speculation block call
+    flash_attend without a map and must get the kernel they always got:
+    one scalar-prefetch vector (the lengths) and seven operands. With a
+    map the call carries one more of each."""
+    R, Q, H, KH, D, S = 2, 8, 4, 2, 128, 256
+    q, k, v = _mk(R, Q, H, KH, D, S)
+    lengths = jnp.asarray([20, 9], jnp.int32)
+    qpos = jnp.tile(jnp.arange(Q, dtype=jnp.int32)[None], (R, 1))
+
+    def call(rows):
+        jaxpr = jax.make_jaxpr(
+            lambda *a: flash_attend.__wrapped__(*a, rows=rows))(
+                q, k, v, lengths, qpos)
+        (eqn,) = [e for e in jaxpr.jaxpr.eqns
+                  if e.primitive.name == "pallas_call"]
+        return (eqn.params["grid_mapping"].num_index_operands,
+                len(eqn.invars))
+
+    assert call(None) == (1, 7)
+    assert call(jnp.asarray([1, 0], jnp.int32)) == (2, 8)

@@ -806,3 +806,275 @@ def test_decode_auto_layout_skipped_under_tp():
     assert len(res[0].output_tokens) == 4
     wq = m.params["layers.0.self_attn"]["wq"]
     assert "model" in str(wq.sharding.spec)      # still TP-sharded
+
+
+# ---------------------------------------------------------------------------
+# The compact prefill batch (segments x chunk, addressed by slot) against
+# the slot grid it replaced
+# ---------------------------------------------------------------------------
+def _tiny_family(family, mode, seed, R=6, max_seq=63, batch_tokens=16):
+    """MHA at D=128, multi-query at D=64 (Falcon's geometry), a tiny OLMoE.
+    63 positions a slot: a multiple of no chunk, so a prompt's last chunk
+    can start within a chunk of the cache's end."""
+    from flexflow_tpu.models.falcon import FalconConfig, create_falcon_model
+    from flexflow_tpu.models.olmoe import OLMoEConfig, create_olmoe_model
+
+    cfg = ff.FFConfig(max_requests_per_batch=R, max_sequence_length=max_seq,
+                      max_tokens_per_batch=batch_tokens, seed=seed,
+                      kv_cache_dtype="float32", use_native_scheduler=False)
+    m = ff.FFModel(cfg)
+    if family == "mha_d128":
+        create_llama_model(m, LLAMAConfig(
+            vocab_size=128, hidden_size=256, intermediate_size=128,
+            num_hidden_layers=2, num_attention_heads=2,
+            num_key_value_heads=2, max_position_embeddings=128), mode=mode)
+    elif family == "mqa_d64":
+        create_falcon_model(m, FalconConfig(
+            vocab_size=128, hidden_size=128, num_hidden_layers=2,
+            num_attention_heads=2, num_kv_heads=1), mode=mode)
+    else:
+        create_olmoe_model(m, OLMoEConfig.from_hf_config(dict(
+            vocab_size=128, hidden_size=64, intermediate_size=32,
+            num_hidden_layers=2, num_attention_heads=4,
+            num_key_value_heads=4, num_experts=8, num_experts_per_tok=2,
+            norm_topk_prob=False, max_position_embeddings=128,
+            rms_norm_eps=1e-5, rope_theta=10000.0)), mode=mode)
+    m.compile(comp_mode=ff.CompMode.COMP_MODE_INFERENCE)
+    return m
+
+
+def _kv_layers(model):
+    """Every K and V cache of the model (one stack) as [R, KH, S, D]."""
+    st = model.op_state["kv_cache"]
+    return [np.asarray(a) for kv in ("k", "v") for a in st[kv]]
+
+
+# six prompts for six slots, admitted together and never replaced, so slot
+# i holds prompt i to the end: more requests filling than the four segments
+# of a step; then fewer, so the long ones take several segments of one
+# step; the last fills to one position short of the cache's end, its final
+# chunk starting at 60 of 63 with the program 4 wide
+_COMPACT_PROMPT_LENS = (30, 2, 9, 13, 5, 62)
+
+
+def _serve_one_way(loop, family, compact, monkeypatch,
+                   lens=_COMPACT_PROMPT_LENS, **sizes):
+    seen = []
+    if compact:
+        build = RequestManager._meta_from_segments
+        monkeypatch.setattr(
+            RequestManager, "_meta_from_segments", staticmethod(
+                lambda *a: seen.append(build(*a)) or seen[-1]))
+    else:       # the old builder, RequestManager._meta_from_rows
+        monkeypatch.setattr(RequestManager, "_compact_prefill",
+                            staticmethod(lambda ifm: False))
+    spec = loop != "incr"
+    llm = _tiny_family(family, InferenceMode.TREE_VERIFY_MODE if spec
+                       else InferenceMode.INC_DECODING_MODE, seed=0, **sizes)
+    ssms = [_tiny_family(family, InferenceMode.BEAM_SEARCH_MODE, seed=s,
+                         **sizes)
+            for s in {"incr": (), "spec_tree_fused": (0, 5)}.get(loop, (0,))]
+    rng = np.random.RandomState(4)
+    prompts = [[int(t) for t in rng.randint(1, 128, size=n)]
+               for n in lens]
+    rm = RequestManager()
+    for p in prompts:
+        rm.register_new_request(p, max_new_tokens=7)
+    if loop == "incr":
+        results = rm.generate_incr_decoding(llm)
+    elif loop == "spec_tree_host":
+        results = rm._generate_spec_tree_host(llm, ssms, spec_depth=3)
+    else:
+        results = rm.generate_spec_infer(llm, ssms, spec_depth=3)
+        assert rm.scheduler_loop == "python:" + loop
+    by_prompt = {tuple(r.input_tokens): r.output_tokens for r in results}
+    outs = [by_prompt[tuple(p)] for p in prompts]
+    # a slot's written positions: all but its pending last token for the
+    # verifier; its prompt (less the pending token) for a draft, whose
+    # later positions only hold what its own chain left
+    verifier = [[c[i, :, :len(p) + len(o) - 1] for c in _kv_layers(llm)]
+                for i, (p, o) in enumerate(zip(prompts, outs))]
+    drafts = [[c[i, :, :len(p) - 1] for m in ssms for c in _kv_layers(m)]
+              for i, p in enumerate(prompts)]
+    return outs, verifier, drafts, seen
+
+
+@pytest.mark.parametrize("family", ["mha_d128", "mqa_d64", "olmoe"])
+@pytest.mark.parametrize("loop", ["incr", "spec_chain", "spec_tree_fused",
+                                  "spec_tree_host"])
+def test_compact_prefill_matches_slot_grid(loop, family, monkeypatch):
+    """The same requests served with the compact [segments x chunk]
+    prefill batch and with the slot grid give the same output tokens and
+    equal K/V caches over every written position, in each Python loop."""
+    with monkeypatch.context() as mp:
+        outs, kv, draft_kv, seen = _serve_one_way(loop, family, True, mp)
+    with monkeypatch.context() as mp:
+        g_outs, g_kv, g_draft_kv, _ = _serve_one_way(loop, family, False,
+                                                       mp)
+    assert outs == g_outs
+    assert [len(o) for o in outs] == [7, 7, 7, 7, 7, 1]
+    for got, want in zip(kv + draft_kv, g_kv + g_draft_kv):
+        for a, b in zip(got, want):
+            np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
+    assert any(np.abs(a).max() > 0 for a in kv[-1])     # it was written
+    # the compact program is one shape, segments x chunk
+    assert {m.tokens.shape for m in seen} == {(4, 4)}
+    live = [m.slots[m.active] for m in seen]
+    # one step held two chunks of one slot, and one all four segments
+    assert any(len(set(s)) < len(s) for s in live)
+    assert any(len(set(s)) == 4 for s in live)
+    # the last prompt's final chunk started within a chunk of the cache end
+    assert any(int(m.start_pos[m.active].max()) == 60 for m in seen)
+
+
+@pytest.mark.parametrize("slots", [1, 2])
+@pytest.mark.parametrize("loop", ["incr", "spec_chain", "spec_tree_fused",
+                                  "spec_tree_host"])
+def test_compact_prefill_chunk_wider_than_cache(loop, slots, monkeypatch):
+    """With one or two slots the chunk is the batch's token budget over
+    them, which a short max_sequence_length falls under: the by-slot
+    append then writes through the whole row, and the loops serve what
+    the slot grid serves."""
+    sizes = dict(R=slots, max_seq=24, batch_tokens=64)
+    lens = (23, 11)[:slots]
+    with monkeypatch.context() as mp:
+        outs, kv, draft_kv, seen = _serve_one_way(loop, "mha_d128", True, mp,
+                                                  lens, **sizes)
+    with monkeypatch.context() as mp:
+        g_outs, g_kv, g_draft_kv, _ = _serve_one_way(loop, "mha_d128", False,
+                                                       mp, lens, **sizes)
+    assert outs == g_outs
+    assert [len(o) for o in outs] == [1, 7][:slots]
+    for got, want in zip(kv + draft_kv, g_kv + g_draft_kv):
+        for a, b in zip(got, want):
+            np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
+    assert {m.tokens.shape for m in seen} == {(slots, 64 // slots)}
+
+
+@pytest.mark.parametrize("Q", [6, 40], ids=["chunk6", "chunk_over_cache"])
+@pytest.mark.parametrize("stacked", [False, True],
+                         ids=["per_layer", "stacked"])
+def test_segment_append_is_exact_like_the_scatter(stacked, Q):
+    """append_kv_contiguous by slot (the compact prefill's append) leaves
+    the cache the windowed scatter leaves, position for position: runs
+    that start anywhere (a draft catching up from an odd depth), two runs
+    of one slot in either order, padding that must not touch what the
+    other run wrote, a run within a chunk of the cache's end and one that
+    would pass it, an inactive row; and the same with a chunk wider than
+    the cache is long."""
+    import jax.numpy as jnp
+
+    from flexflow_tpu.ops.inc_attention import (append_kv,
+                                                append_kv_contiguous)
+
+    R, KH, S, D, L = 5, 2, 37, 8, 3
+    rng = np.random.RandomState(8)
+    cache = jnp.asarray(rng.randn(L, R, KH, S, D).astype(np.float32))
+    new = jnp.asarray(rng.randn(7, Q, KH, D).astype(np.float32))
+    #        slot start  n   active
+    segs = [(3,   17,   2,  True),      # the later chunk first ...
+            (3,   11,   6,  True),      # ... its padding-free predecessor
+            (0,   33,   4,  True),      # starts within Q of the end
+            (1,   35,   6,  True),      # would pass the end: 2 land
+            (4,   5,    0,  True),      # nothing real
+            (2,   9,    6,  False),     # inactive
+            (2,   20,   3,  True)]
+    slots, start, num, act = (jnp.asarray(c) for c in zip(*segs))
+    layer = 1
+    want = cache[layer]
+    for i, (slot, sp, n, on) in enumerate(segs):    # the oracle, row by row
+        grid = jnp.zeros((R, Q, KH, D)).at[slot].set(new[i])
+        want = append_kv(want, grid, jnp.zeros((R,), jnp.int32).at[slot].set(sp),
+                         jnp.zeros((R,), jnp.int32).at[slot].set(n),
+                         jnp.zeros((R,), bool).at[slot].set(on))
+    if stacked:
+        got = append_kv_contiguous(cache, layer, new, start, act, slots, num)
+        np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(cache[0]))
+        got = got[layer]
+    else:
+        got = append_kv_contiguous(cache[layer], None, new, start, act, slots,
+                                   num)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert not np.array_equal(np.asarray(got), np.asarray(cache[layer]))
+
+
+def test_prefill_segments_go_to_the_oldest_admission_first():
+    """Eight requests filling over four segments a step, slots refilled as
+    they free: no request waits more than two rounds for its first
+    segment, whichever slot it sits in (the lowest slot used to win, and
+    the high ones starved while low ones were refilled)."""
+    from types import SimpleNamespace as NS
+
+    # the chooser alone: slot 0 was granted last, slot 5 first
+    reqs = [NS(slot=i, tokens=list(range(n)), finished=False, d=0,
+               prefill_start_s=t)
+            for i, (n, t) in enumerate([(30, 9.0), (3, 2.0), (10, 3.0),
+                                        (1, 0.5), (6, 4.0), (20, 1.0)])]
+    rows = RequestManager._prefill_rows(reqs + [None], 4, lambda r: r.d, 4)
+    assert [(slot, sp, len(t)) for slot, t, sp in rows] == [
+        (5, 0, 4), (1, 0, 2), (2, 0, 4), (4, 0, 4)]
+    # two filling, four segments: the spare ones are their next chunks,
+    # one each in the same order, and the last token stays pending
+    rows = RequestManager._prefill_rows([reqs[0], reqs[2]], 4,
+                                        lambda r: r.d, 4)
+    assert [(slot, sp, len(t)) for slot, t, sp in rows] == [
+        (2, 0, 4), (0, 0, 4), (2, 4, 4), (0, 4, 4)]
+    reqs[2].d = 4
+    rows = RequestManager._prefill_rows([reqs[2]], 4, lambda r: r.d, 4)
+    assert [(slot, sp, len(t)) for slot, t, sp in rows] == [
+        (2, 4, 4), (2, 8, 1)]
+    # on the slot grid a slot has one row
+    rows = RequestManager._prefill_rows([reqs[0]], 4, lambda r: r.d, 4,
+                                        consecutive=False)
+    assert [(slot, sp, len(t)) for slot, t, sp in rows] == [(0, 0, 4)]
+
+    # the loop: 14 requests of 9 tokens (two chunks) and one output token
+    # over 8 slots
+    model = _tiny_family("mqa_d64", InferenceMode.INC_DECODING_MODE, 0, R=8)
+    rm = RequestManager()
+    rng = np.random.RandomState(2)
+    for _ in range(14):
+        rm.register_new_request([int(t) for t in rng.randint(1, 128, size=9)],
+                                max_new_tokens=1)
+    rounds, granted, first = [0], {}, {}
+    grant, prefill = rm._grant, rm._prefill
+
+    def note_grant(req, *a):
+        granted[req.guid] = rounds[0]
+        return grant(req, *a)
+
+    def note_prefill(ifm, active, *a, **kw):
+        rows = prefill(ifm, active, *a, **kw)
+        for slot, _, _ in rows:
+            first.setdefault(active[slot].guid, rounds[0])
+        rounds[0] += 1
+        return rows
+
+    rm._grant, rm._prefill = note_grant, note_prefill
+    assert len(rm.generate_incr_decoding(model)) == 14
+    waits = [first[g] - granted[g] for g in granted]
+    assert len(waits) == 14 and max(waits) == 2, waits
+
+
+@pytest.mark.parametrize("config", ["falcon-7b", "olmoe-1b-7b"])
+def test_check_compact_prefill_tool_rehearses(config, monkeypatch, capsys):
+    """tools/check_compact_prefill.py (the on-chip check of the compact
+    program against the slot grid) runs at a configuration's rehearsal
+    sizes: on the CPU the two programs agree to the bit, and an expert
+    model's compact run, sent where the grid run went, overrides no pick
+    of its own."""
+    import json
+
+    monkeypatch.syspath_prepend(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    for key in ("JAX_PLATFORMS", "FF_PALLAS_INTERPRET"):
+        monkeypatch.setenv(key, os.environ.get(key, ""))   # restored after
+    from tools import check_compact_prefill
+
+    assert check_compact_prefill.main(["--rehearse", config]) == 0
+    res = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert res["ok"] and res["config"] == config
+    assert res["steps_compact"] < res["steps_grid"]
+    assert res["logits_max_rel_l2"] == 0.0 and res["cache_max_abs_diff"] == 0.0
+    assert (res["routed_tokens"] > 0) == (config == "olmoe-1b-7b")
+    assert not any(res["routes_overridden_by_layer"])
