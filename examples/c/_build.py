@@ -54,8 +54,15 @@ def compile_and_run_serve(c_basename: str, ok_marker: str,
         env["LD_LIBRARY_PATH"] = os.pathsep.join(
             p for p in (lib_dir, pylibdir, env.get("LD_LIBRARY_PATH"))
             if p)
+        # a host that dies says where: its interpreter dumps the threads'
+        # stacks on a fatal signal and the failure below carries them
+        env.setdefault("PYTHONFAULTHANDLER", "1")
         # the embedded interpreter picks its backend from JAX_PLATFORMS
-        out = subprocess.run([exe, _ROOT, *extra_args], check=True,
-                             env=env, capture_output=True, text=True)
+        out = subprocess.run([exe, _ROOT, *extra_args], env=env,
+                             capture_output=True, text=True)
+        if out.returncode != 0:
+            raise RuntimeError(
+                f"{c_basename} exited {out.returncode}\nstdout:\n"
+                f"{out.stdout[-2000:]}\nstderr:\n{out.stderr[-6000:]}")
         assert ok_marker in out.stdout, out.stdout
         return out.stdout.strip()

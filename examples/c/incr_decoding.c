@@ -80,6 +80,7 @@ int main(int argc, char **argv) {
 
   ffsv_release(llm);
   ffsv_release(cfg);
+  ffsv_shutdown();
   printf("C incr_decoding OK\n");
   return 0;
 }

@@ -204,8 +204,9 @@ int main(int argc, char **argv) {
     ffsv_release(llm);
   }
 
-  printf("C spec_infer OK\n");
   ffsv_release(pair);
   ffsv_release(cfg);
+  ffsv_shutdown();
+  printf("C spec_infer OK\n");
   return 0;
 }

@@ -3,8 +3,11 @@
 Capability-parity with the reference FFConfig (reference
 include/flexflow/config.h:102 and flag parsing src/runtime/model.cc:4082-4280):
 training hyperparams, cluster geometry, parallelism degrees, search knobs,
-fusion, offload, quantization, profiling. The Legion ``-ll:*`` resource flags
-have no TPU meaning; cluster geometry is expressed directly as a device mesh.
+serving shapes, offload, quantization, profiling. The Legion ``-ll:*``
+resource flags have no TPU meaning; cluster geometry is expressed directly as
+a device mesh. A field stays only while some module of the program reads it
+(tests/test_serve_api.py::test_every_option_is_read); a serving path that the
+benchmark's cells do not measure gets no switch here.
 """
 
 from __future__ import annotations
@@ -25,9 +28,9 @@ class FFConfig:
 
     # --- cluster geometry ---
     # The reference counts nodes x workers(GPUs) x cpus; on TPU the unit is a
-    # chip in a mesh. num_devices=None -> len(jax.devices()).
+    # chip in a mesh. num_devices=None -> len(jax.devices()), resolved at
+    # compile time (resolve_num_devices) so that this module imports no jax.
     num_nodes: int = 1
-    workers_per_node: Optional[int] = None
     num_devices: Optional[int] = None
 
     # --- parallelism degrees (reference config.h:156-159) ---
@@ -50,7 +53,6 @@ class FFConfig:
     only_data_parallel: bool = False
     search_budget: int = -1
     search_alpha: float = 1.2
-    search_overlap_backward_update: bool = False
     export_strategy_file: str = ""
     include_costs_dot_graph: bool = False
     substitution_json_path: Optional[str] = None
@@ -76,8 +78,6 @@ class FFConfig:
     # (graph.cc:2107). Opt-in: it multiplies search time by the number of
     # factorizations and compile() adopts the winning degrees.
     search_mesh: bool = False
-    # memory-aware search (reference graph.cc:2126 lambda binary search)
-    mem_search_budget: int = -1
     # inter-slice (DCN) fabric for the search's cost model: a
     # search.network.NetworkTopology over the num_nodes slices. The routed
     # ring's bottleneck link bounds cross-slice collective bandwidth, so a
@@ -88,26 +88,10 @@ class FFConfig:
     dcn_topology: Optional[object] = None
 
     # --- execution ---
-    enable_fusion: bool = True          # XLA fuses; flag kept for parity/tests
-    # serving weight-gemm fusion (qkv, SwiGLU gate|up -> one gemm each;
-    # serve/gemm_fusion.py). Off by default: a 7-vs-4-gemm microbenchmark
-    # wins 11% but the END-TO-END 7B int8 decode step measures 6% SLOWER
-    # fused on v5e (XLA overlaps the separate weight streams with the
-    # Pallas attention call better than one wide gemm) — see the
-    # measurement log in serve/gemm_fusion.py.
-    gemm_fusion: bool = False
-    # compile the fused decode block with AUTO parameter layouts (XLA
-    # picks gemm-preferred weight layouts — engine.py
-    # make_decode_block_auto). Off by default: one controlled run
-    # measured -3.3% per decode step at 7B int8, but ordered A/B through
-    # this code path shows no repeatable end-to-end gain (PARITY.md
-    # round-4 record). Falls back to default layouts on any backend/API
-    # limitation.
-    decode_auto_layout: bool = False
-    computation_mode: str = "training"
+    # the reference's --fusion flag: parsed (from_args, serve/api.py's key
+    # map, ffsv_config_set) and carried; XLA fuses whatever it says
+    enable_fusion: bool = True
     seed: int = 0
-    # numerics: params kept in param_dtype, compute in compute_dtype
-    param_dtype: str = "float32"
     compute_dtype: str = "float32"
 
     # --- serving shapes (reference BatchConfig::max_requests_per_batch /
@@ -142,18 +126,16 @@ class FFConfig:
 
     # --- serving / offload / quantization (reference config.h:144-163) ---
     cpu_offload: bool = False
-    offload_reserve_space_size: int = 8 * 1024 * 1024 * 1024
     quantization_type: Optional[str] = None   # None | "int8" | "int4"
-    benchmarking: bool = False
     inference_debugging: bool = False
-    # host-side batch bookkeeping in native C++ (native/src/
-    # batch_scheduler.cpp) when the library builds; falls back to Python
-    use_native_scheduler: bool = True
+    # Read by nothing: the C++ scheduler loop it selected is gone. Kept
+    # only because benchmark/families/_common.ffconfig passes it; it goes
+    # when a `benchmark` PR drops the key there and from the three files
+    # under benchmark/configs/.
+    use_native_scheduler: bool = False
 
     # --- profiling / logging (reference config.h:127-130) ---
     profiling: bool = False
-    perform_fusion_checks: bool = False
-    log_instance_creation: bool = False
     # serving telemetry (flexflow_tpu/telemetry): enables the global
     # metrics registry + per-request span tracing at LLM.compile /
     # ffsv_llm_create — the runtime counterpart of the reference's two
@@ -168,11 +150,6 @@ class FFConfig:
     mesh_axis_names: Sequence[str] = ("data", "model")
     use_pallas: bool = True        # allow pure-jax fallback (CPU tests)
     remat: bool = False            # jax.checkpoint the forward pass
-
-    def __post_init__(self):
-        if self.num_devices is None:
-            # Resolved lazily at compile time to avoid importing jax here.
-            pass
 
     def resolve_num_devices(self) -> int:
         if self.num_devices is not None:

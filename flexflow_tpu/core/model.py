@@ -792,7 +792,6 @@ class FFModel:
         self.policy = ShardingPolicy(self.mesh)
         self._pp_plan = None
         self._pp_segment_fn = None
-        self._gemm_fusion_done = False
 
         # --- Unity-style auto-parallelization (reference model.cc:3327
         # launches GRAPH_OPTIMIZE_TASK inside compile). A strategy the
@@ -1345,27 +1344,6 @@ class FFModel:
             finalize_pipeline(self)
         return self
 
-    def finalize_gemm_fusion(self):
-        """Fuse serving decode gemms (qkv, SwiGLU gate|up) in place — the
-        reference's --fusion/FusedOp analog (model.cc:2864 apply_fusion);
-        see serve/gemm_fusion.py for eligibility and measurements. Called
-        after weight loading (InferenceManager / engine init, like
-        finalize_pipeline); idempotent."""
-        from flexflow_tpu.serve.gemm_fusion import (apply_gemm_fusion,
-                                                    fusion_eligible)
-
-        if getattr(self, "_gemm_fusion_done", False):
-            return self
-        if fusion_eligible(self):
-            apply_gemm_fusion(self)
-            self._gemm_fusion_done = True
-        elif getattr(self, "comp_mode", None) is not None:
-            # compiled and ineligible (TP/PP/offload/debugging/training):
-            # the decision is final for this compile. A pre-compile call
-            # stays un-latched so the post-compile call still fuses.
-            self._gemm_fusion_done = True
-        return self
-
     def get_parameter_by_key(self, key: Tuple[str, str]) -> np.ndarray:
         layer_name, weight_name = key
         from flexflow_tpu.quant import dequantize_array, is_quantized
@@ -1386,15 +1364,6 @@ class FFModel:
                                                stack.dtype)
                     return np.asarray(dequantize_array(layer_qw))
                 return np.asarray(stack[i])
-        if (layer_name not in self.params
-                or weight_name not in self.params[layer_name]):
-            # gemm fusion may have folded this weight into a fused leaf
-            # (serve/gemm_fusion.py): slice it back out
-            from flexflow_tpu.serve.gemm_fusion import fused_param_get
-
-            got = fused_param_get(self, layer_name, weight_name)
-            if got is not None:
-                return got
         leaf = self.params[layer_name][weight_name]
         if is_quantized(leaf):
             return np.asarray(dequantize_array(leaf))
@@ -1457,14 +1426,6 @@ class FFModel:
                 assert arr.shape == stack.shape[1:], (arr.shape, stack.shape)
                 self.params[PP_PARAMS_KEY][pos][weight_name] = \
                     stack.at[i].set(arr)
-                return
-        if (layer_name not in self.params
-                or weight_name not in self.params[layer_name]):
-            # gemm fusion may have folded this weight into a fused leaf
-            # (serve/gemm_fusion.py): splice the columns back in
-            from flexflow_tpu.serve.gemm_fusion import fused_param_set
-
-            if fused_param_set(self, layer_name, weight_name, value):
                 return
         old = self.params[layer_name][weight_name]
         if is_quantized(old):   # writes to a quantized weight re-quantize

@@ -2,9 +2,9 @@
 
 The reference implements its runtime in C++ with a flat C API consumed by
 Python cffi (src/c/flexflow_c.cc). Here the native surface covers the
-host-side components that are not XLA's job: the GPT-2 BPE tokenizer
-(reference src/runtime/gpt_tokenizer.cc) and the continuous-batching
-scheduler hot loop (reference src/runtime/request_manager.cc bookkeeping).
+host-side components that are not XLA's job: the GPT-2 BPE and
+SentencePiece tokenizers (reference src/runtime/gpt_tokenizer.cc) and the
+C graph builder.
 
 The shared library is built lazily with g++ on first use (sources live in
 ``native/`` at the repo root) and cached; every binding has a pure-Python
@@ -37,8 +37,7 @@ _build_failed = False
 def _sources():
     src = os.path.join(_NATIVE_DIR, "src")
     return [os.path.join(src, f) for f in
-            ("bpe_tokenizer.cpp", "batch_scheduler.cpp",
-             "sp_tokenizer.cpp", "graph_builder.cpp")]
+            ("bpe_tokenizer.cpp", "sp_tokenizer.cpp", "graph_builder.cpp")]
 
 
 def _source_hash() -> str:
@@ -69,11 +68,15 @@ def _needs_build() -> bool:
 def _build() -> bool:
     os.makedirs(_BUILD_DIR, exist_ok=True)
     src_hash = _source_hash()
+    # linked under this process's own name and renamed into place: another
+    # process (a test worker, ``make``) may be loading the same path
+    tmp = f"{_LIB_PATH}.{os.getpid()}.tmp"
     cmd = ["g++", "-O2", "-std=c++17", "-fPIC", "-Wall", "-shared",
            "-I", os.path.join(_NATIVE_DIR, "include"),
-           "-o", _LIB_PATH] + _sources()
+           "-o", tmp] + _sources()
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
+        os.replace(tmp, _LIB_PATH)
     except (OSError, subprocess.SubprocessError):
         return False
     with open(_STAMP_PATH, "w") as f:
@@ -84,7 +87,6 @@ def _build() -> bool:
 def _declare(lib: ctypes.CDLL):
     c = ctypes
     i32p = c.POINTER(c.c_int32)
-    u8p = c.POINTER(c.c_uint8)
     lib.ffbpe_create.restype = c.c_void_p
     lib.ffbpe_create.argtypes = [c.c_char_p, c.c_char_p]
     lib.ffbpe_create_from_buffers.restype = c.c_void_p
@@ -98,33 +100,6 @@ def _declare(lib: ctypes.CDLL):
     lib.ffbpe_decode.restype = c.c_int
     lib.ffbpe_decode.argtypes = [c.c_void_p, i32p, c.c_int, c.c_char_p,
                                  c.c_int]
-
-    lib.ffs_create.restype = c.c_void_p
-    lib.ffs_create.argtypes = [c.c_int, c.c_int, c.c_int64]
-    lib.ffs_destroy.argtypes = [c.c_void_p]
-    lib.ffs_add_request.argtypes = [c.c_void_p, c.c_int64, i32p, c.c_int,
-                                    c.c_int, c.c_int]
-    lib.ffs_has_work.restype = c.c_int
-    lib.ffs_has_work.argtypes = [c.c_void_p]
-    lib.ffs_fill_slots.restype = c.c_int
-    lib.ffs_fill_slots.argtypes = [c.c_void_p]
-    lib.ffs_assemble_prefill.restype = c.c_int
-    lib.ffs_assemble_prefill.argtypes = [c.c_void_p, c.c_int, c.c_int,
-                                         c.c_int, i32p, i32p, i32p, i32p, u8p]
-    lib.ffs_assemble_decode.restype = c.c_int
-    lib.ffs_assemble_decode.argtypes = [c.c_void_p, i32p, i32p, u8p]
-    lib.ffs_decode_block.restype = c.c_int
-    lib.ffs_decode_block.argtypes = [c.c_void_p, c.c_int]
-    lib.ffs_append_block.restype = c.c_int
-    lib.ffs_append_block.argtypes = [c.c_void_p, i32p, c.c_int]
-    lib.ffs_pop_done.restype = c.c_int
-    lib.ffs_pop_done.argtypes = [c.c_void_p, c.POINTER(c.c_int64), i32p]
-    lib.ffs_done_tokens.restype = c.c_int
-    lib.ffs_done_tokens.argtypes = [c.c_void_p, c.c_int64, i32p, c.c_int]
-    lib.ffs_prompt_len.restype = c.c_int
-    lib.ffs_prompt_len.argtypes = [c.c_void_p, c.c_int64]
-    lib.ffs_cancel.restype = c.c_int
-    lib.ffs_cancel.argtypes = [c.c_void_p, c.c_int64]
 
     ip = c.POINTER(c.c_int)
     lib.ffgb_create.restype = c.c_void_p

@@ -130,11 +130,6 @@ def append_kv_stacked(stack: jnp.ndarray, layer_idx: int, new: jnp.ndarray,
 def _qkv(attrs, params, x, compute_dtype):
     """Project x [R, Q, E] -> q [R,Q,H,D], k/v [R,Q,KH,D].
 
-    With a fused "wqkv" weight (serve/gemm_fusion.py — the reference's
-    --fusion/FusedOp analog) the three projections run as ONE gemm and
-    slice: at decode widths each gemm pass is weight-load bound, so two
-    fewer passes is ~2/7 less per-gemm fixed cost per layer.
-
     With ``attrs["qk_norm_eps"]`` (OLMoE; absent = no such step) q and k
     are RMS-normalised over their WHOLE projection, before the split into
     heads and before the rotary embedding."""
@@ -143,25 +138,16 @@ def _qkv(attrs, params, x, compute_dtype):
     H = attrs["num_q_heads"]
     KH = attrs["num_kv_heads"]
     D = attrs["head_dim"]
-    if "wqkv" in params:
-        qkv = qmatmul(x, params["wqkv"])
-        if "bqkv" in params:
-            qkv = qkv + params["bqkv"]
-        hd, khd = H * D, KH * D
-        q = qkv[..., :hd]
-        k = qkv[..., hd:hd + khd]
-        v = qkv[..., hd + khd:]
-    else:
-        q = qmatmul(x, params["wq"])
-        k = qmatmul(x, params["wk"])
-        v = qmatmul(x, params["wv"])
-        n_bias = sum(k_ in params for k_ in ("bq", "bk", "bv"))
-        if n_bias == 3:
-            q, k, v = q + params["bq"], k + params["bk"], v + params["bv"]
-        elif n_bias:
-            raise ValueError(
-                "attention qkv bias set must be all-present or all-absent; "
-                f"got {sorted(k_ for k_ in ('bq', 'bk', 'bv') if k_ in params)}")
+    q = qmatmul(x, params["wq"])
+    k = qmatmul(x, params["wk"])
+    v = qmatmul(x, params["wv"])
+    n_bias = sum(k_ in params for k_ in ("bq", "bk", "bv"))
+    if n_bias == 3:
+        q, k, v = q + params["bq"], k + params["bk"], v + params["bv"]
+    elif n_bias:
+        raise ValueError(
+            "attention qkv bias set must be all-present or all-absent; "
+            f"got {sorted(k_ for k_ in ('bq', 'bk', 'bv') if k_ in params)}")
     eps = attrs.get("qk_norm_eps")
     if eps is not None:
         from flexflow_tpu.ops.norm import _rms_norm

@@ -187,12 +187,6 @@ class SigmoidSiluMulti(OpImpl):
 
     @staticmethod
     def forward(attrs, params, inputs, ctx):
-        if attrs.get("packed"):
-            # gemm fusion rewired the (gate, up) pair into one packed
-            # [..., 2I] input (serve/gemm_fusion.py); split halves here
-            x = inputs[0]
-            half = x.shape[-1] // 2
-            return [jax.nn.silu(x[..., :half]) * x[..., half:]]
         return [jax.nn.silu(inputs[0]) * inputs[1]]
 
 

@@ -119,8 +119,7 @@ def is_quantized(leaf) -> bool:
 
 
 # weights eligible for quantization: the serving matmul weights
-# ("wqkv" = the gemm-fusion concat, serve/gemm_fusion.py)
-_QUANT_NAMES = {"kernel", "wq", "wk", "wv", "wo", "wqkv", "weight",
+_QUANT_NAMES = {"kernel", "wq", "wk", "wv", "wo", "weight",
                 "w1", "w2", "w3", "gate", "up", "down"}
 # ... and of those, the ones that may be a stack [E, in, out] (ops/moe.py)
 _STACKED_NAMES = {"gate", "up", "down"}

@@ -29,12 +29,12 @@ from flexflow_tpu.search.graph_search import (
     UnitySearch, data_parallel_model_strategy, mcmc_optimize, optimize_model,
 )
 from flexflow_tpu.search.measure import (
-    format_ab, searched_vs_dp_wallclock, wallclock_train,
+    format_ab, searched_vs_dp_wallclock,
 )
 
 __all__ = [
     "TPU_CHIPS", "ChipSpec", "MachineModel", "OpStrategy", "Strategy",
     "CostModel", "CostMetrics", "PCG", "PCGNode", "UnitySearch",
     "mcmc_optimize", "optimize_model", "data_parallel_model_strategy",
-    "searched_vs_dp_wallclock", "wallclock_train", "format_ab",
+    "searched_vs_dp_wallclock", "format_ab",
 ]

@@ -10,39 +10,39 @@ seconds per step next to the analytic costs.
 
 Timing: ``train_one_batch`` returns ``float(loss)`` — a host readback,
 which is the honest fence on this runtime (utils/profiling.device_fence).
-Per-step times are min-of-reps over a timed block of steps after warmup.
+After warmup the variants' timed blocks of steps take turns, and a
+variant's per-step time is the median of its blocks.
 """
 
 from __future__ import annotations
 
+import statistics
 import time
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Callable, Dict, Optional, Tuple
 
 
-def wallclock_train(build_model: Callable[[], object], strategy, xs, ys,
-                    steps: int = 6, reps: int = 3, lr: float = 0.01
-                    ) -> Tuple[float, object]:
-    """Compile ``build_model()`` under a FORCED ``strategy`` (no search)
-    and wall-clock ``steps`` train steps, ``reps`` times, returning
-    (best seconds/step, model). ``strategy=None`` compiles whatever the
-    model's config dictates (plain GSPMD defaults)."""
+def _compile_forced(build_model: Callable[[], object], strategy, xs, ys):
+    """``build_model()`` compiled under a FORCED ``strategy`` (no search)
+    and warmed by two train steps. ``strategy=None`` compiles whatever
+    the model's config dictates (plain GSPMD defaults)."""
     import flexflow_tpu as ff
 
     model = build_model()
     model.config.auto_parallel = False   # the strategy is given, not searched
     model.strategy = strategy            # compile adopts strategy.axis_degrees
     model.compile(
-        optimizer=ff.SGDOptimizer(model, lr),
+        optimizer=ff.SGDOptimizer(model, 0.01),
         loss_type=ff.LossType.LOSS_SPARSE_CATEGORICAL_CROSSENTROPY)
     for _ in range(2):                   # compile + warm
         model.train_one_batch([x for x in xs], ys)
-    best = float("inf")
-    for _ in range(reps):
-        t0 = time.perf_counter()
-        for _ in range(steps):
-            model.train_one_batch([x for x in xs], ys)
-        best = min(best, (time.perf_counter() - t0) / steps)
-    return best, model
+    return model
+
+
+def _seconds_per_step(model, xs, ys, steps: int) -> float:
+    t0 = time.perf_counter()
+    for _ in range(steps):
+        model.train_one_batch([x for x in xs], ys)
+    return (time.perf_counter() - t0) / steps
 
 
 def searched_vs_dp_wallclock(build_model: Callable[[], object], xs, ys,
@@ -63,11 +63,18 @@ def searched_vs_dp_wallclock(build_model: Callable[[], object], xs, ys,
     are chosen under the ``chip`` analytic machine model but EXECUTED on
     whatever mesh the current jax backend provides — on the virtual CPU
     mesh the ratio is a structural sanity check (does the searched
-    placement actually run no slower than DP?), not TPU physics."""
+    placement actually run no slower than DP?), not TPU physics.
+
+    Every variant is compiled first and the timed blocks then take turns,
+    one of each variant a repetition, so that whatever else loads the host
+    loads all variants alike; a variant's time is the median of its
+    ``reps`` blocks (the best block of a few is an extreme: beside busy
+    neighbours it moved by 25% between equal programs, the median by 2%)."""
     from flexflow_tpu.search.graph_search import (
         data_parallel_model_strategy, optimize_model)
 
     out: Dict[str, Dict[str, float]] = {}
+    models = {}
     for variant in variants:
         probe = build_model()
         n = (num_devices if num_devices is not None
@@ -99,10 +106,14 @@ def searched_vs_dp_wallclock(build_model: Callable[[], object], xs, ys,
                 enable_nonsequence=(variant == "searched"),
                 search_mesh=True)
             builder = build_model
-        sec, _model = wallclock_train(builder, strat, xs, ys,
-                                      steps=steps, reps=reps)
-        out[variant] = {"analytic": float(strat.cost) if strat else -1.0,
-                        "wallclock": sec}
+        models[variant] = _compile_forced(builder, strat, xs, ys)
+        out[variant] = {"analytic": float(strat.cost) if strat else -1.0}
+    blocks = {variant: [] for variant in models}
+    for _ in range(reps):
+        for variant, model in models.items():
+            blocks[variant].append(_seconds_per_step(model, xs, ys, steps))
+    for variant in models:
+        out[variant]["wallclock"] = statistics.median(blocks[variant])
     return out
 
 

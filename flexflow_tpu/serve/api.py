@@ -234,7 +234,6 @@ class LLM:
             # (reference -offload); quantize-then-offload streams 4-8x
             # fewer bytes per step
             self.ffmodel.offload_weights()
-        self.ffmodel.finalize_gemm_fusion()
 
         self.rm = RequestManager()
         if self.tokenizer is not None:
@@ -476,11 +475,6 @@ class _BackgroundServer:
             for _, ev in self._waiters:
                 ev.set()
             self._waiters.clear()
-        if not self._thread.is_alive():
-            # a clean shutdown must leave no native FIFO shadow entries —
-            # a leak here means a C++-scheduler request was lost
-            assert self.llm.rm.native_shadow_empty(), \
-                "native FIFO shadow not empty after stop()"
 
     def _run(self):
         rm = self.llm.rm

@@ -135,13 +135,16 @@ def _adapt_depth_rule(adapt, act_i, n_acc, depth_v, alive, min_depth,
       a zero accept, hold otherwise, bounded by [min_depth, compiled
       depth]; the host re-anchors from its EWMA cost model at the block
       boundary;
-    * give-up — a row already AT the floor that still accepts nothing
-      exits the block, so a collapsed draft costs at most the shrink
-      path (~depth rounds) before the host parks it on incremental
-      decoding, never a whole max_rounds block.
+    * give-up — once every live row is AT the floor and still accepts
+      nothing the block ends, so a collapsed draft costs at most the
+      shrink path (~depth rounds) before the host parks the batch on
+      incremental decoding, never a whole max_rounds block. A collapsed
+      row beside one that still accepts stays: the block runs on anyway,
+      and leaving would cost it the verifier's token of each round left.
 
     Returns (depth_v, alive)."""
-    give_up = adapt & act_i & (n_acc == 0) & (depth_v == min_depth)
+    collapsed = adapt & act_i & (n_acc == 0) & (depth_v == min_depth)
+    give_up = collapsed & ~jnp.any(act_i & ~collapsed)
     alive = alive & ~give_up
     grown = jnp.where(n_acc >= depth_v, depth_v + 1,
                       jnp.where(n_acc == 0, depth_v - 1, depth_v))
@@ -180,9 +183,23 @@ def make_draft_chain(model, compute_dtype, depth: int):
     return jax.jit(chain, donate_argnums=(1,))
 
 
-def _decode_block_fn(model, compute_dtype, max_steps: int, width: int = 1):
-    """The raw (unjitted) decode-block body shared by make_decode_block
-    and make_decode_block_auto."""
+def make_decode_block(model, compute_dtype, max_steps: int, width: int = 1):
+    """Build the jitted dynamic-length decode program for ``model``.
+
+    Signature: (params, op_state, tok [R], pos [R], active [R], rng,
+    n (device scalar <= max_steps)) -> (tokens [R, max_steps], new_op_state,
+    last_tok [R]). Only the first n columns are meaningful; the rest stay 0.
+    ``pos[r]`` is the sequence index of the pending token ``tok[r]``.
+    One program compiles for ALL n (dynamic while_loop trip count).
+
+    ``width > 1`` runs each step at the spec verify pass's token width
+    with 1 real token per row (verify-consistent decode: identical gemm
+    shapes and attention-kernel instantiation, so near-tie argmaxes
+    resolve the same way in both paths). Only the real token's KV is
+    appended (kv_append_q=1) — the padding rows' KV is never attended —
+    via the attention kernel's fused in-place append (inc_attention._attend
+    append_kv), so no staging window needs reserving near the cache end.
+    """
 
     def block(params, op_state, tok, pos, active, rng, n):
         R = tok.shape[0]
@@ -222,67 +239,7 @@ def _decode_block_fn(model, compute_dtype, max_steps: int, width: int = 1):
             cond, body, (jnp.int32(0), op_state, tok, pos, out0))
         return out, op_state, tok
 
-    return block
-
-
-def make_decode_block(model, compute_dtype, max_steps: int, width: int = 1):
-    """Build the jitted dynamic-length decode program for ``model``.
-
-    Signature: (params, op_state, tok [R], pos [R], active [R], rng,
-    n (device scalar <= max_steps)) -> (tokens [R, max_steps], new_op_state,
-    last_tok [R]). Only the first n columns are meaningful; the rest stay 0.
-    ``pos[r]`` is the sequence index of the pending token ``tok[r]``.
-    One program compiles for ALL n (dynamic while_loop trip count).
-
-    ``width > 1`` runs each step at the spec verify pass's token width
-    with 1 real token per row (verify-consistent decode: identical gemm
-    shapes and attention-kernel instantiation, so near-tie argmaxes
-    resolve the same way in both paths). Only the real token's KV is
-    appended (kv_append_q=1) — the padding rows' KV is never attended —
-    via the attention kernel's fused in-place append (inc_attention._attend
-    append_kv), so no staging window needs reserving near the cache end.
-    """
-    return jax.jit(_decode_block_fn(model, compute_dtype, max_steps, width),
-                   donate_argnums=(1,))
-
-
-def make_decode_block_auto(model, compute_dtype, max_steps: int,
-                           width: int = 1):
-    """AUTO-parameter-layout variant of make_decode_block.
-
-    The decode while-loop's gemms stage the attention-side weights
-    through serial layout-conversion DMA copies when params arrive in
-    the default row-major layout (~1.3 ms/step of zero-overlap
-    slice-copy stalls at 7B int8 on one v5e, tools/profile_trace.py
-    decode). Letting XLA choose the parameter INPUT layouts removes a
-    third of that: measured 11.16 -> 10.79 ms/step (-3.3%).
-
-    Compiles eagerly from avals with ``Format(Layout.AUTO)`` on the
-    params argument only (the donated op_state keeps default layouts so
-    its carry cycle is unaffected), then relayouts ``model.params`` IN
-    PLACE to the compiled formats and returns the compiled executable
-    (same call signature as the jitted block). Other programs compiled
-    against the old layouts will retrace once — a one-time cost.
-
-    Raises on any backend/API limitation; callers fall back to
-    make_decode_block.
-    """
-    from jax.experimental.layout import Format, Layout
-
-    blk = _decode_block_fn(model, compute_dtype, max_steps, width)
-    auto = Format(Layout.AUTO)
-    jb = jax.jit(blk, donate_argnums=(1,),
-                 in_shardings=(auto,) + (None,) * 6)
-    R = model.config.max_requests_per_batch
-    sample = (model.params, model.op_state,
-              jnp.zeros((R,), jnp.int32), jnp.zeros((R,), jnp.int32),
-              jnp.zeros((R,), bool), jax.random.PRNGKey(0), jnp.int32(1))
-    avals = jax.tree.map(
-        lambda a: jax.ShapeDtypeStruct(jnp.shape(a), a.dtype), sample)
-    compiled = jb.lower(*avals).compile()
-    pfmt = compiled.input_formats[0][0]
-    model.params = jax.device_put(model.params, pfmt)
-    return compiled
+    return jax.jit(block, donate_argnums=(1,))
 
 
 class MultiSpecEngine:
@@ -321,10 +278,8 @@ class MultiSpecEngine:
         self.llm = llm
         self.ssms = list(ssms)
         llm.finalize_pipeline()
-        llm.finalize_gemm_fusion()
         for s in self.ssms:
             s.finalize_pipeline()
-            s.finalize_gemm_fusion()
         self.depth = depth
         self.max_rounds = max_rounds
         self.telemetry = None   # explicit ServingTelemetry; None -> global
@@ -663,8 +618,6 @@ class SpecChainEngine:
         self.ssm = ssm
         llm.finalize_pipeline()
         ssm.finalize_pipeline()
-        llm.finalize_gemm_fusion()
-        ssm.finalize_gemm_fusion()
         self.depth = depth
         self.max_rounds = max_rounds
         self.telemetry = None   # explicit ServingTelemetry; None -> global
@@ -814,8 +767,8 @@ class SpecChainEngine:
         mixed batch runs different depths in one round with no retrace.
         Between rounds the device grows/shrinks each row's depth (full
         accept -> +1, zero accept -> -1, clipped to [min_depth, depth])
-        and a row that accepts nothing while already at the floor EXITS
-        the block (give-up) so the host controller can park it;
+        and once every live row accepts nothing at the floor the block
+        ends (give-up) so the host controller can park the batch;
         depth_used[r, k] reports the bound each round actually ran under
         (-1 on idle rounds) so the host can attribute its acceptance
         observations.
@@ -893,8 +846,6 @@ class BeamSpecEngine:
         self.ssm = ssm
         llm.finalize_pipeline()
         ssm.finalize_pipeline()
-        llm.finalize_gemm_fusion()
-        ssm.finalize_gemm_fusion()
         self.depth = depth
         self.width = width
         self.max_rounds = max_rounds

@@ -153,8 +153,6 @@ def check_invariants(handle) -> List[str]:
     stuck = [g for g, r in rm.inflight.items() if not r.finished]
     if stuck:
         problems.append(f"unfinished inflight requests: {stuck}")
-    if not rm.native_shadow_empty():
-        problems.append("native FIFO shadow not empty")
     srv = getattr(handle, "_server", None)
     if srv is not None and srv._waiters:
         problems.append(f"{len(srv._waiters)} unreleased waiter(s)")

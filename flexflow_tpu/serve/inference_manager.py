@@ -29,7 +29,6 @@ class InferenceManager:
     def __init__(self, model):
         self.model = model
         model.finalize_pipeline()   # no-op unless a pipeline plan is pending
-        model.finalize_gemm_fusion()  # serving gemm fusion (see gemm_fusion.py)
         if model._pp_plan is not None and model.config.inference_debugging:
             raise NotImplementedError(
                 "inference_debugging dumps need per-layer params; not "
@@ -146,47 +145,9 @@ class InferenceManager:
             return self._decode_block_debug(tok, pos, active, n_steps)
         if self._decode_block is None:
             cfg = self.model.config
-            # AUTO layouts are a single-chip experiment: sharding-free
-            # avals would compile a single-device executable and
-            # de-shard a TP/PP model's params on relayout
-            if (cfg.decode_auto_layout and self.model._pp_plan is None
-                    and self.model.mesh.devices.size == 1):
-                try:
-                    from flexflow_tpu.serve.engine import \
-                        make_decode_block_auto
-
-                    blk = make_decode_block_auto(
-                        self.model, self._compute_dtype,
-                        cfg.decode_block_steps, width=self.decode_width)
-                    # AOT executables reject mismatched inputs instead of
-                    # retracing: validate with one all-inactive step (no
-                    # KV writes, outputs unread) BEFORE adopting the
-                    # path. The executable donates its op_state argument,
-                    # so validate against a throwaway COPY — a failure
-                    # mid-execution must never delete the live buffers the
-                    # jitted fallback (and in-flight KV state) depend on.
-                    # A failure leaves params relayouted, which jitted
-                    # fallbacks handle by retracing.
-                    R = cfg.max_requests_per_batch
-                    z = jnp.zeros((R,), jnp.int32)
-                    state_copy = jax.tree_util.tree_map(
-                        jnp.copy, self.model.op_state)
-                    _, st, _ = blk(self.model.params, state_copy,
-                                   z, z, jnp.zeros((R,), bool),
-                                   jax.random.PRNGKey(0), jnp.int32(1))
-                    self.model.op_state = st
-                    self._decode_block = blk
-                except Exception as e:     # pragma: no cover - backend-dep
-                    import warnings
-
-                    warnings.warn(
-                        f"decode_auto_layout unavailable ({e}); using "
-                        "default layouts", stacklevel=2)
-            if self._decode_block is None:
-                self._decode_block = make_decode_block(
-                    self.model, self._compute_dtype,
-                    cfg.decode_block_steps,
-                    width=self.decode_width)
+            self._decode_block = make_decode_block(
+                self.model, self._compute_dtype, cfg.decode_block_steps,
+                width=self.decode_width)
         n_steps = min(int(n_steps), self.model.config.decode_block_steps)
         ph = None
         if tel is not None:

@@ -223,10 +223,6 @@ class _PoolRM:
                 rep.handle.rm.cancel(e.cur_guid)
             return True
 
-    def native_shadow_empty(self) -> bool:
-        return all(r.handle is None or r.handle.rm.native_shadow_empty()
-                   for r in self._pool.replicas)
-
 
 class ReplicaPool:
     """N replicas behind one submission front door (see module docs).

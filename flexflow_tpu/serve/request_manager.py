@@ -67,8 +67,7 @@ class Request:
     # fields exist even with telemetry disabled). prefill_start_s is
     # stamped when the request wins a batch slot (admission -> slot is
     # the queue wait; slot -> first token is the service time to first
-    # token). The native-scheduler path attributes both through a FIFO
-    # shadow of ffs_fill_slots (see _generate_incr_native).
+    # token).
     arrival_s: float = 0.0
     prefill_start_s: float = 0.0
     first_token_s: float = 0.0
@@ -126,16 +125,14 @@ class GenerationResult:
     output_text: str = ""
     # per-request latency (reference serving writes latency per request
     # to -output-file; here it rides on the result object): admission ->
-    # finish, and admission -> first generated token (0.0 when the path
-    # cannot attribute first-token time, e.g. the native scheduler owns
-    # the token bookkeeping)
+    # finish, and admission -> first generated token (0.0 for a request
+    # that ended before generating one)
     latency_s: float = 0.0
     ttft_s: float = 0.0
     # queue-wait vs service decomposition (SLO observability, loadgen):
     # admission -> batch-slot grant, and slot grant -> first generated
-    # token. ttft_s == queue_wait_s + prefill_s wherever both are
-    # attributed (all scheduler paths, incl. the native one via its
-    # FIFO slot shadow); 0.0 only when attribution was impossible.
+    # token. ttft_s == queue_wait_s + prefill_s wherever both are set;
+    # 0.0 for a request that never won a slot or generated no token.
     queue_wait_s: float = 0.0
     prefill_s: float = 0.0
     # terminal disposition (overload front door): "ok", "timed_out"
@@ -194,10 +191,9 @@ class RequestManager:
         # once attached it persists across generate calls so pooled
         # prefixes survive between serving rounds.
         self.prefix_cache = None
-        # which scheduler loop served the last generate call: "native"
-        # (C++ batch scheduler) or "python[:<spec loop>]". A failed g++
-        # build selects the Python loop without an error, so callers that
-        # report where a run happened read it from here.
+        # which scheduler loop served the last generate call:
+        # "python[:<spec loop>]"; callers that report where a run happened
+        # (the benchmark's families, chip_smoke.py) read it from here.
         self.scheduler_loop: Optional[str] = None
 
     def _tel(self):
@@ -295,17 +291,7 @@ class RequestManager:
             req.finished = True
             req.slot = -1
             out.append(self._collect(req))
-        # the native loop's FIFO shadow died with the loop; clear it so
-        # the invariant check (and stop_server) see a consistent table
-        self._native_unslotted = deque()
-        self._native_slotted = {}
         return out
-
-    def native_shadow_empty(self) -> bool:
-        """True when the native scheduler's FIFO shadow holds no
-        requests (always true outside a native-path generation loop)."""
-        return (not getattr(self, "_native_unslotted", None)
-                and not getattr(self, "_native_slotted", None))
 
     # -- scheduling helpers ------------------------------------------------
     def _finish_if_done(self, req: Request, max_seq: int) -> bool:
@@ -605,17 +591,14 @@ class RequestManager:
         if not req.first_token_s and req.num_generated > 0:
             req.first_token_s = time.perf_counter()
 
-    def _timed_prefill(self, ifm, meta, tel, rows=(), active=None,
-                       n_tokens=None, rnd=None):
+    def _timed_prefill(self, ifm, meta, tel, rows, active, rnd=None):
         """One prefill step, optionally wall-clocked. The step's outputs
         are discarded (want_output=False dispatches asynchronously), so
         honest timing needs an explicit fence on the new op_state
         (utils/profiling.device_fence). The fence only runs with
         telemetry enabled; the disabled path keeps the async overlap.
 
-        ``rows``/``active`` feed per-request prefill spans; paths whose
-        slot->request mapping lives elsewhere (the native scheduler)
-        pass ``n_tokens`` alone and get metrics without spans. ``rnd`` is
+        ``rows``/``active`` feed per-request prefill spans. ``rnd`` is
         the round's RoundTrace in the loops that have one: the call's
         ``call_*`` leaves take over from the open phase, and
         ``sched_build`` resumes after the fence."""
@@ -638,12 +621,9 @@ class RequestManager:
             tel.call_phase(wait, None)
             dt = time.perf_counter() - t0
             rnd.phase("sched_build")
-        if n_tokens is None:
-            n_tokens = sum(len(chunk) for _, chunk, _ in rows)
-        tel.record_prefill(dt, n_tokens,
+        tel.record_prefill(dt, sum(len(chunk) for _, chunk, _ in rows),
                            [(active[slot].guid, sp, len(chunk))
-                            for slot, chunk, sp in rows]
-                           if active is not None else (), t0,
+                            for slot, chunk, sp in rows], t0,
                            positions=meta.tokens.size)
 
     def _tel_tick(self, tel, live, slots: int, max_seq: int):
@@ -757,29 +737,6 @@ class RequestManager:
             ifm = model._inference_manager = InferenceManager(model)
         cfg = model.config
         self._resolve_prefix_cache(generation_config)
-        if getattr(cfg, "use_native_scheduler", True):
-            # Only the library load/construction may fall back; device
-            # errors inside the generation loop must propagate (requests
-            # have already been dequeued by then).
-            sched = None
-            try:
-                from flexflow_tpu.native.scheduler import NativeBatchScheduler
-                sched = NativeBatchScheduler(cfg.max_requests_per_batch,
-                                             cfg.max_sequence_length,
-                                             self.eos_token_id)
-            except RuntimeError:
-                pass  # no toolchain: pure-Python path below
-            if sched is not None:
-                # priorities need the host's preemption machinery
-                needs_host = any(r.priority for r in self.pending)
-                # the shared-prefix pool (and its decode-interleaved
-                # prefill) lives host-side; the C++ scheduler owns its
-                # own serial prefill bookkeeping
-                needs_host = needs_host or self.prefix_cache is not None
-                if not needs_host:
-                    self.scheduler_loop = "native"
-                    return self._generate_incr_native(model, ifm, cfg,
-                                                      sched)
         self.scheduler_loop = "python"
         R = cfg.max_requests_per_batch
         max_seq = cfg.max_sequence_length
@@ -862,126 +819,6 @@ class RequestManager:
                 rnd.end()
         return done
 
-    def _generate_incr_native(self, model, ifm, cfg,
-                              sched) -> List[GenerationResult]:
-        """Incremental decoding with the native (C++) batch scheduler owning
-        slot fill, batch assembly, and EOS/limit bookkeeping
-        (native/src/batch_scheduler.cpp; same semantics as the Python loop
-        above — parity-tested in tests/test_native.py)."""
-        R = cfg.max_requests_per_batch
-        max_seq = cfg.max_sequence_length
-        chunk = max(1, cfg.max_tokens_per_batch // max(1, min(R, 4)))
-        reqs: Dict[int, Request] = {}
-        # FIFO shadow of the C++ scheduler's pending queue: ffs_fill_slots
-        # pops strictly in add order (rejecting over-long prompts along
-        # the way), so the Python side can attribute slot-grant times —
-        # the queue-wait/service decomposition — without a C ABI change.
-        unslotted = deque()
-        while self.pending:
-            req = self.pending.popleft()
-            reqs[req.guid] = req
-            unslotted.append(req)
-            sched.add_request(req.guid, req.prompt_tokens,
-                              req.max_new_tokens, req.max_sequence_length)
-        done: List[GenerationResult] = []
-        slotted: Dict[int, Request] = {}       # guid -> live slotted request
-        # expose the shadow for the stop_server()/fault-harness invariant
-        # (both must end empty when the loop exits)
-        self._native_unslotted = unslotted
-        self._native_slotted = slotted
-
-        def reap_native():
-            """Between-rounds timeout/cancel seam, native flavor: the C++
-            scheduler owns the slot table, so expiry/cancellation goes
-            through ffs_cancel (request moved to its done queue with the
-            partial tokens); drain() below collects it with the status
-            set here. An unslotted cancellee also leaves the FIFO shadow
-            (ffs_cancel removed it from the C++ pending queue, so the
-            pop order the shadow mirrors skips it too)."""
-            now = time.perf_counter()
-            for req in reqs.values():
-                if req.finished or req.status != "ok":
-                    continue
-                if req.cancel_requested or (req.deadline_s
-                                            and now >= req.deadline_s):
-                    status = ("cancelled" if req.cancel_requested
-                              else "timed_out")
-                    if sched.cancel(req.guid):
-                        req.status = status
-                        if req.guid not in slotted:
-                            try:
-                                unslotted.remove(req)
-                            except ValueError:
-                                pass
-
-        def drain():
-            while True:
-                popped = sched.pop_done()
-                if popped is None:
-                    return
-                guid, tokens, _plen = popped
-                req = reqs[guid]
-                req.tokens = tokens
-                req.finished = True
-                slotted.pop(guid, None)
-                done.append(self._collect(req))
-
-        def note_slots(placed: int):
-            now = time.perf_counter()
-            while placed > 0 and unslotted:
-                req = unslotted.popleft()
-                limit = min(req.max_sequence_length or max_seq, max_seq)
-                if len(req.prompt_tokens) >= limit:
-                    # C++ rejected it straight to done; stamp the explicit
-                    # rejection so drain() collects it as such
-                    self._reject_overlong(req, limit)
-                    req.finished = False   # drain() owns the terminal flip
-                    continue
-                req.prefill_start_s = now
-                slotted[req.guid] = req
-                placed -= 1
-
-        while sched.has_work():
-            tel = self._tel()
-            reap_native()
-            note_slots(sched.fill_slots())
-            drain()  # over-long prompts + reaped requests -> done
-            rows, tokens, positions, start, num, act = \
-                sched.assemble_prefill(chunk, cfg.max_tokens_per_batch, chunk)
-            if rows:
-                meta = BatchMeta(tokens=tokens, positions=positions,
-                                 start_pos=start, num_tokens=num,
-                                 active=act)
-                # the native scheduler owns slot->guid bookkeeping, so
-                # no per-request prefill spans on this path
-                self._timed_prefill(ifm, meta, tel,
-                                    n_tokens=int(np.asarray(num).sum()))
-                continue
-            live, tok, pos, act = sched.assemble_decode()
-            if live:
-                block = sched.decode_block(cfg.decode_block_steps)
-                if tel is not None:
-                    # self.pending drained into the C++ scheduler up
-                    # front: its queue depth = registered - finished -
-                    # requests currently holding a live slot
-                    tel.note_batch(max(0, len(reqs) - len(done) - live),
-                                   live, R, None)
-                t0 = time.perf_counter()
-                toks = ifm.decode_block(tok, pos, act, block)
-                if tel is not None:
-                    tel.record_decode_block(time.perf_counter() - t0,
-                                            block, live)
-                sched.append_block(np.asarray(toks)[:, :block])
-                # every live slot emitted >= 1 token inside this fused
-                # block; first-token time is block-end granular, the same
-                # resolution the fused Python decode path records
-                now = time.perf_counter()
-                for req in slotted.values():
-                    if not req.first_token_s:
-                        req.first_token_s = now
-            drain()
-        return done
-
     # -- adaptive speculation support (serve/spec_controller.py) ----------
     @staticmethod
     def _spec_controller(gc: Optional[GenerationConfig], llm, ssms,
@@ -1005,10 +842,11 @@ class RequestManager:
         tel.note_spec_controller(stats["ewma_mean"], stats["n_fallback"],
                                  ctrl.take_new_fallbacks())
 
-    def _partition_spec(self, ctrl, rnd, live, roomy, rounds):
+    def _partition_spec(self, ctrl, drafting, rnd, live, roomy, rounds):
         """Controller partition shared by the two fused scheduler loops
         (which must stay in sync — see _generate_spec_tree_fused): split
-        the roomy requests into (draftable, parked), feed the controller
+        the roomy requests into (draftable, parked) by ``drafting`` (the
+        round's ``SpecController.drafting`` set), feed the controller
         telemetry gauges, and shrink a pure-probe tick to ONE round (one
         acceptance sample — minimal probe tax on parked traffic).
         ``rnd`` is the round's RoundTrace (None: telemetry off).
@@ -1016,9 +854,8 @@ class RequestManager:
         self._tick_controller(ctrl, None if rnd is None else rnd.tel, live)
         if ctrl is None:
             return roomy, [], rounds
-        draftable = [req for req in roomy if ctrl.wants_draft(req.guid)]
-        draft_guids = {req.guid for req in draftable}
-        parked = [req for req in roomy if req.guid not in draft_guids]
+        draftable = [req for req in roomy if req.guid in drafting]
+        parked = [req for req in roomy if req.guid not in drafting]
         if draftable and all(ctrl.in_fallback(r.guid) for r in draftable):
             if rnd is not None and rounds > 1:
                 rnd.note_cut("probe")
@@ -1344,6 +1181,8 @@ class RequestManager:
                              and ctrl.in_fallback(req.guid)}
                             if ctrl is not None else ())
             self._fill_slots(active, max_seq, done, parked_guids)
+            drafting_guids = (None if ctrl is None else ctrl.drafting(
+                req.guid for req in active if req is not None))
             self._prefix_install(active, (("llm", llm_ifm),
                                           ("ssm0", ssm_ifm)))
             if rnd is not None:
@@ -1364,7 +1203,7 @@ class RequestManager:
             # anyway.
             drafting = [req if req is not None
                         and max_seq - len(req.tokens) - 1 >= room_needed
-                        and (ctrl is None or ctrl.wants_draft(req.guid))
+                        and (ctrl is None or req.guid in drafting_guids)
                         else None for req in active]
             ssm_rows = self._prefill(ssm_ifm, drafting, shape,
                                      lambda r: r.ssm_cache_depth.get(0, 0),
@@ -1399,7 +1238,7 @@ class RequestManager:
                 # fused incremental block (same cost/tokens as plain
                 # incremental) until their probe round recovers them
                 draftable, parked, rounds = self._partition_spec(
-                    ctrl, rnd, live, roomy,
+                    ctrl, drafting_guids, rnd, live, roomy,
                     min(cfg.spec_rounds_per_call, engine.max_rounds))
                 if prefilled:
                     # prefill still pending somewhere: one spec round,
@@ -1587,6 +1426,8 @@ class RequestManager:
                              and ctrl.in_fallback(req.guid)}
                             if ctrl is not None else ())
             self._fill_slots(active, max_seq, done, parked_guids)
+            drafting_guids = (None if ctrl is None else ctrl.drafting(
+                req.guid for req in active if req is not None))
             self._prefix_install(
                 active, (("llm", llm_ifm),
                          *((f"ssm{i}", m)
@@ -1608,7 +1449,7 @@ class RequestManager:
             # fed in chunks here
             owing = [req if req is not None and not carries_block(req)
                      and max_seq - len(req.tokens) >= room_needed
-                     and (ctrl is None or ctrl.wants_draft(req.guid))
+                     and (ctrl is None or req.guid in drafting_guids)
                      else None for req in active]
             for i, ifm in enumerate(ssm_ifms):
                 rows = self._prefill(
@@ -1635,7 +1476,7 @@ class RequestManager:
             cramped = [req for req in ready
                        if max_seq - len(req.tokens) < room_needed]
             draftable, parked, rounds = self._partition_spec(
-                ctrl, rnd, live, roomy,
+                ctrl, drafting_guids, rnd, live, roomy,
                 min(cfg.spec_rounds_per_call, engine.max_rounds))
             if prefilled:
                 rounds = 1      # see chain-path note

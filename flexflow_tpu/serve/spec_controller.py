@@ -18,7 +18,9 @@ request in FALLBACK: it decodes through the same fused incremental
 decode block the non-speculative path uses (token-identical — both
 paths emit the verifier's greedy continuation) and only re-drafts a
 cheap probe round every ``probe_every`` fallback blocks so acceptance
-can be re-measured and the request can recover.
+can be re-measured and the request can recover. Parking is per request,
+the incremental block per batch: beside requests that still speculate a
+parked one rides in their block (``SpecController.drafting``).
 
 Cost model (everything in units of one verifier forward, which is what
 an incremental decode step costs — both are weight-stream bound):
@@ -233,8 +235,9 @@ class SpecController:
     """Per-request adaptive speculation state for one serving loop.
 
     The RequestManager asks three questions per scheduling tick —
-    ``wants_draft`` (speculate or serve incrementally this tick, probes
-    included), ``depth_for`` (the depth bound to hand the engine), and
+    ``drafting`` (which of the batch speculate this tick and which serve
+    incrementally, probes included), ``depth_for`` (the depth bound to
+    hand the engine), and
     after each fused block reports what actually happened via
     ``observe_block`` / ``note_fallback_block``.
     """
@@ -277,9 +280,19 @@ class SpecController:
         self._reported_fallbacks = self.fallback_entries_total
         return n
 
-    def wants_draft(self, guid: int) -> bool:
-        st = self._state(guid)
-        return (not st.fallback) or probe_due(st, self.policy)
+    def drafting(self, guids: Iterable[int]) -> set:
+        """Of a batch's requests, the ones that speculate this tick.
+
+        While any of them is not parked a speculation block runs anyway
+        and carries the parked ones too: its cost does not depend on how
+        many rows are live in it, a parked row still commits the
+        verifier's own token every round, and the fallback decode block
+        it would need instead holds every other row still. Only a batch
+        parked whole decodes incrementally, but for the probes due."""
+        guids = list(guids)
+        if any(not self._state(g).fallback for g in guids):
+            return set(guids)
+        return {g for g in guids if probe_due(self._state(g), self.policy)}
 
     def depth_for(self, guid: int) -> int:
         return self._state(guid).depth

@@ -1,11 +1,11 @@
 /* Flat C API for the native runtime components of flexflow_tpu.
  *
  * Capability parity with the reference's native layer: the GPT-2 byte-level
- * BPE tokenizer (reference src/runtime/gpt_tokenizer.cc, 324 LoC) and the
- * continuous-batching request scheduler's host-side hot loop (reference
- * src/runtime/request_manager.cc slot fill / batch assembly). The Python
- * runtime binds these via ctypes (reference used a cffi C API,
- * src/c/flexflow_c.cc); device compute stays in XLA/Pallas.
+ * BPE tokenizer (reference src/runtime/gpt_tokenizer.cc, 324 LoC), the
+ * SentencePiece tokenizer, the C graph builder and the ffsv_* serving ABI.
+ * The Python runtime binds the first three via ctypes (reference used a
+ * cffi C API, src/c/flexflow_c.cc); scheduling is the Python serving loop
+ * and device compute stays in XLA/Pallas.
  */
 
 #ifndef FLEXFLOW_TPU_C_H
@@ -39,71 +39,6 @@ int ffbpe_encode(void *handle, const char *text, int text_len,
 /* Decode ids to UTF-8. Returns bytes written (excluding NUL), or negative
  * required capacity. */
 int ffbpe_decode(void *handle, const int32_t *ids, int n, char *out, int cap);
-
-/* ---------------- continuous-batching scheduler ---------------- */
-
-/* Create a scheduler with R request slots, a max KV length of max_seq and
- * an optional EOS id (pass -1 for none). */
-void *ffs_create(int max_requests, int max_seq, int64_t eos_id);
-
-void ffs_destroy(void *handle);
-
-/* Queue a request. tokens are the prompt; max_new bounds generation;
- * max_seq_len (0 = no per-request bound) caps prompt+generation. */
-void ffs_add_request(void *handle, int64_t guid, const int32_t *tokens,
-                     int n_tokens, int max_new, int max_seq_len);
-
-/* Non-zero while any request is pending or active. */
-int ffs_has_work(void *handle);
-
-/* Move pending requests into free slots. Over-long prompts (no room to
- * generate a single token) are rejected straight to the done queue.
- * Returns the number of requests newly placed in slots. */
-int ffs_fill_slots(void *handle);
-
-/* Assemble a prefill batch: for every active slot with >1 pending
- * (uncached) prompt tokens, emit up to `chunk` of them (leaving >=1 pending
- * so the final chunk produces the first generated token), bounded by a
- * total token budget. Writes [R x Q] tokens/positions and per-slot
- * start/num/active arrays, advances each slot's cache depth, and returns
- * the number of rows emitted (0 = no prefill work; proceed to decode). */
-int ffs_assemble_prefill(void *handle, int chunk, int budget, int Q,
-                         int32_t *tokens, int32_t *positions,
-                         int32_t *start_pos, int32_t *num_tokens,
-                         uint8_t *active);
-
-/* Assemble a decode step: per live slot the last token and its position.
- * Returns the number of live slots. */
-int ffs_assemble_decode(void *handle, int32_t *tok, int32_t *pos,
-                        uint8_t *active);
-
-/* Largest safe fused-decode block size: min over live slots of remaining
- * generation budget, clamped to max_block and to the KV cache end. */
-int ffs_decode_block(void *handle, int max_block);
-
-/* Feed back a [R x B] block of sampled tokens after a fused decode. Applies
- * EOS/length termination per slot, frees finished slots to the done queue.
- * Returns the number of requests finished by this block. */
-int ffs_append_block(void *handle, const int32_t *toks, int B);
-
-/* Cancel a request by guid: a pending request is moved straight to the
- * done queue; an active one is finished in place and its slot freed.
- * Partial tokens (prompt + whatever was generated) stay readable via
- * ffs_pop_done/ffs_done_tokens. Returns 1 if the request was found and
- * cancelled, 0 if unknown or already finished. */
-int ffs_cancel(void *handle, int64_t guid);
-
-/* Drain the done queue: returns guid and token count of the next finished
- * request, or 0 if none. */
-int ffs_pop_done(void *handle, int64_t *guid, int32_t *n_tokens);
-
-/* Copy the full token sequence (prompt + generated) of a finished request
- * popped by ffs_pop_done. Returns tokens written. Also releases it. */
-int ffs_done_tokens(void *handle, int64_t guid, int32_t *out, int cap);
-
-/* Number of prompt tokens for a request (for output splitting). */
-int ffs_prompt_len(void *handle, int64_t guid);
-
 
 /* ---------------- SentencePiece tokenizer (LLaMA family) ----------------
  * Reference: tokenizers-cpp selected by ModelType in
@@ -193,6 +128,9 @@ int ffgb_serialize(void *handle, char *out, int cap);
 int ffsv_init(const char *repo_root);
 const char *ffsv_last_error(void);
 void ffsv_release(void *handle);
+/* Call last, before returning from main: stops the runtime's threads so
+ * the host's exit() does not race them. No ffsv_* call may follow. */
+void ffsv_shutdown(void);
 
 void *ffsv_config_create(void);
 /* Reference flexflow_config_parse_args (same flag set as FFConfig.from_args). */
@@ -275,7 +213,7 @@ long ffsv_register_request_timeout(void *llm, const int32_t *tokens,
 /* Flag a registered request for cancellation; the next
  * ffsv_generate/ffsv_generate_spec round reaps it (slot freed, partial
  * output kept, status -> 2 cancelled). Works on all scheduler paths
- * (incremental python loop, native C++ scheduler, fused speculative).
+ * (incremental and fused speculative).
  * Returns 1 if cancelled, 0 if unknown or already finished, -1 error. */
 int ffsv_request_cancel(void *llm, long guid);
 /* Resolution status of a request guid: -1 unknown, 0 ok (completed),

@@ -31,6 +31,7 @@ namespace {
 
 std::string g_error;
 PyObject *g_host = nullptr;  // the capi_host module
+bool g_owns_python = false;  // ffsv_init started the interpreter
 
 void set_error_from_python() {
   PyObject *type = nullptr, *value = nullptr, *tb = nullptr;
@@ -91,7 +92,10 @@ const char *ffsv_last_error(void) { return g_error.c_str(); }
  * is already importable). Returns 0 on success. */
 int ffsv_init(const char *repo_root) {
   if (g_host) return 0;
-  if (!Py_IsInitialized()) Py_Initialize();
+  if (!Py_IsInitialized()) {
+    Py_Initialize();
+    g_owns_python = true;
+  }
   if (repo_root && *repo_root) {
     PyObject *sys_path = PySys_GetObject("path");  // borrowed
     PyObject *p = PyUnicode_FromString(repo_root);
@@ -109,6 +113,19 @@ int ffsv_init(const char *repo_root) {
 /* Tear down handles (the interpreter stays up: XLA backends do not
  * survive re-initialization). */
 void ffsv_release(void *handle) { Py_XDECREF((PyObject *)handle); }
+
+/* Shut the runtime down before the host exits: finalizing the
+ * interpreter runs its exit hooks, where JAX releases its backends and
+ * joins their threads. A host that returns from main with it up runs
+ * exit()'s static destructors under those threads (SIGSEGV after the
+ * last line of output, one run in six on a loaded machine). A no-op
+ * where ffsv_init found Python running: that host finalizes its own. */
+void ffsv_shutdown(void) {
+  if (!g_owns_python || !Py_IsInitialized()) return;
+  Py_CLEAR(g_host);
+  Py_FinalizeEx();
+  g_owns_python = false;
+}
 
 void *ffsv_config_create(void) { return call("config_create", nullptr); }
 

@@ -110,8 +110,7 @@ def test_serving_debug_dumps(tmp_path, monkeypatch):
     from flexflow_tpu.serve.request_manager import RequestManager
 
     cfg = ff.FFConfig(max_requests_per_batch=2, max_tokens_per_batch=16,
-                      max_sequence_length=32, inference_debugging=True,
-                      use_native_scheduler=False)
+                      max_sequence_length=32, inference_debugging=True)
     mcfg = LLAMAConfig(vocab_size=64, hidden_size=32, intermediate_size=64,
                        num_hidden_layers=1, num_attention_heads=2,
                        num_key_value_heads=2, max_position_embeddings=32)

@@ -9,7 +9,8 @@ simulated — the reference bar is Unity's measured speedup (OSDI'22,
 README.md:68).
 
 Wall-clock thresholds are deliberately loose (the virtual CPU mesh is a
-structural check, not TPU physics) and each variant takes min-of-reps.
+structural check, not TPU physics); the variants' timed blocks take turns
+and each variant takes the median of its own.
 """
 
 import numpy as np
@@ -75,8 +76,11 @@ def test_branchy_searched_not_worse_than_dp_wallclock():
     rng = np.random.RandomState(1)
     xs = [rng.randn(16, 32, 8, 8).astype(np.float32)]
     ys = rng.randint(0, 10, size=(16, 1)).astype(np.int32)
+    # the two strategies are the same program here, so the bound has to
+    # hold on 9 ms steps beside the suite's other workers: the variants
+    # take turns step by step, 32 steps each, and the medians are compared
     res = searched_vs_dp_wallclock(_inception, xs, ys, chip="v5e",
-                                   num_devices=8, steps=4, reps=2,
+                                   num_devices=8, steps=1, reps=32,
                                    variants=("searched", "dp", "seq_only"))
     print(format_ab("inception", res))
     assert res["searched"]["wallclock"] <= 1.25 * res["dp"]["wallclock"], res

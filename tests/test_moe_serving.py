@@ -305,11 +305,11 @@ def test_counters_count_real_tokens_times_k_and_only_with_telemetry():
     from flexflow_tpu.telemetry import ServingTelemetry
 
     prompts = [[3, 17, 42, 99, 7, 21, 5], [9, 8, 7]]
-    off = _build(TINY, use_native_scheduler=False)
+    off = _build(TINY)
     assert set(off.op_state) == {"kv_cache"}
     plain = _serve(off, prompts, 6)
 
-    on = _build(TINY, use_native_scheduler=False, telemetry=True)
+    on = _build(TINY, telemetry=True)
     assert on.op_state["moe_counters"].shape == (2, 8 + 4 * 3)  # one leaf
     tel = ServingTelemetry()
     got = _serve(on, prompts, 6, tel=tel)

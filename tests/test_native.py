@@ -1,6 +1,6 @@
-"""Native (C++) layer tests: BPE tokenizer parity/round-trip (reference
-tests/gpt_tokenizer.cpp) and batch-scheduler parity with the Python
-RequestManager loop."""
+"""Native (C++) layer tests: BPE and SentencePiece tokenizer
+parity/round-trip (reference tests/gpt_tokenizer.cpp), the C graph
+builder and the ffsv_* serving ABI."""
 
 import random
 import string
@@ -97,123 +97,6 @@ def test_native_tokenizer_decode_utf8():
     tok = BPETokenizer(vocab=vocab, merges=merges)
     text = "héllo wörld 你好"
     assert tok.decode(tok.encode(text)) == text
-
-
-# ---------------------------------------------------------------------------
-# scheduler parity
-# ---------------------------------------------------------------------------
-
-
-@needs_native
-def test_scheduler_basic_lifecycle():
-    from flexflow_tpu.native.scheduler import NativeBatchScheduler
-
-    s = NativeBatchScheduler(max_requests=2, max_seq=32, eos_id=99)
-    s.add_request(1, [5, 6, 7], max_new=4)
-    s.add_request(2, [8], max_new=2)
-    s.add_request(3, [9, 10], max_new=3)   # waits for a free slot
-    assert s.has_work()
-    assert s.fill_slots() == 2
-
-    # prefill: req1 has 3 prompt tokens -> 2 emitted (one pending);
-    # req2 has 1 -> no prefill needed
-    rows, tokens, positions, start, num, act = s.assemble_prefill(
-        chunk=8, budget=64, Q=8)
-    assert rows == 1
-    assert act[0] and not act[1]
-    assert list(tokens[0][:2]) == [5, 6] and num[0] == 2
-
-    live, tok, pos, act = s.assemble_decode()
-    assert live == 2
-    assert tok[0] == 7 and pos[0] == 2
-    assert tok[1] == 8 and pos[1] == 0
-
-    block = s.decode_block(8)
-    assert block == 4  # max remaining budget among live requests
-
-    toks = np.zeros((2, block), np.int32)
-    toks[0] = [20, 21, 22, 23]
-    toks[1] = [30, 99, 0, 0]   # EOS after 2 tokens
-    finished = s.append_block(toks)
-    assert finished == 2       # req1 hit max_new=4, req2 hit EOS
-
-    done = {}
-    while True:
-        p = s.pop_done()
-        if p is None:
-            break
-        done[p[0]] = p
-    assert done[1][1] == [5, 6, 7, 20, 21, 22, 23] and done[1][2] == 3
-    assert done[2][1] == [8, 30, 99]
-    # req3 now fills the free slot
-    assert s.has_work()
-    assert s.fill_slots() == 1
-
-
-@needs_native
-def test_scheduler_rejects_overlong_prompt():
-    from flexflow_tpu.native.scheduler import NativeBatchScheduler
-
-    s = NativeBatchScheduler(max_requests=1, max_seq=8, eos_id=None)
-    s.add_request(7, list(range(8)), max_new=4)   # prompt fills max_seq
-    s.fill_slots()
-    p = s.pop_done()
-    assert p is not None and p[0] == 7
-    assert not s.has_work()
-
-
-@needs_native
-def test_scheduler_matches_python_request_manager():
-    """Run the same synthetic workload through the native scheduler loop and
-    the pure-Python loop with a deterministic fake model; outputs must be
-    token-identical."""
-    from flexflow_tpu.serve.request_manager import RequestManager
-
-    class FakeIFM:
-        """Deterministic 'model': next token = (last + position) % 50 + 1."""
-
-        def step(self, meta, want_output=True, tel=None):
-            pass
-
-        def decode_block(self, tok, pos, act, block, tel=None):
-            R = tok.shape[0]
-            out = np.zeros((R, block), np.int32)
-            cur = tok.copy()
-            p = pos.copy()
-            for j in range(block):
-                cur = (cur + p) % 50 + 1
-                p = p + 1
-                out[:, j] = np.where(act, cur, 0)
-            return out
-
-    class Cfg:
-        max_requests_per_batch = 3
-        max_sequence_length = 24
-        max_tokens_per_batch = 16
-        decode_block_steps = 4
-        use_native_scheduler = True
-
-    def run(native: bool):
-        rm = RequestManager(eos_token_id=13)
-        rm.max_spec_depth = 4
-        prompts = [[3, 4, 5], [10], [7, 8], [1, 2, 3, 4, 5, 6], [9, 9]]
-        for i, pr in enumerate(prompts):
-            rm.register_new_request(pr, max_new_tokens=6 + i)
-        cfg = Cfg()
-        cfg.use_native_scheduler = native
-
-        class Model:
-            config = cfg
-            _inference_manager = FakeIFM()
-
-        res = rm.generate_incr_decoding(Model())
-        return sorted((tuple(int(t) for t in r.input_tokens),
-                       tuple(int(t) for t in r.output_tokens)) for r in res)
-
-    a = run(native=True)
-    b = run(native=False)
-    assert a == b
-    assert len(a) == 5
 
 
 # ======================================================================
@@ -705,3 +588,8 @@ def test_ffsv_serving_abi_in_process():
         lib.ffsv_release(tl)
     lib.ffsv_release(llm)
     lib.ffsv_release(cfg)
+    # ffsv_shutdown finalizes only an interpreter ffsv_init started; in
+    # this one it found Python running and must leave it so
+    lib.ffsv_shutdown.restype = None
+    lib.ffsv_shutdown()
+    assert lib.ffsv_config_create()

@@ -8,8 +8,8 @@ trend gate.
 
 Budget discipline: pure-math tests dominate; the integration tests share
 the session tiny spec pair plus ONE module-scoped tiny incremental
-model (needed because the incremental loops — python and native — are
-distinct scheduler paths from the speculative one)."""
+model (needed because the incremental loop is a distinct scheduler path
+from the speculative one)."""
 
 import json
 import os
@@ -237,14 +237,14 @@ def test_overload_telemetry_counters():
 
 
 # ---------------------------------------------------------------------------
-# integration: the three scheduler paths on tiny models
+# integration: the incremental and speculative loops on tiny models
 # ---------------------------------------------------------------------------
 
 @pytest.fixture(scope="module")
 def tiny_incr_model():
-    """One tiny INC_DECODING model: the python and native incremental
-    loops are scheduler paths of their own (the session spec pair only
-    exercises generate_spec_infer)."""
+    """One tiny INC_DECODING model: the incremental loop is a scheduler
+    path of its own (the session spec pair only exercises
+    generate_spec_infer)."""
     import flexflow_tpu as ff
     from flexflow_tpu.ffconst import InferenceMode
     from flexflow_tpu.models.llama import LLAMAConfig, create_llama_model
@@ -265,46 +265,28 @@ PROMPT_A = [5, 9, 23, 7]
 PROMPT_B = [11, 3, 19]
 
 
-def test_cancel_before_loop_all_three_paths(tiny_incr_model, tiny_spec_pair):
+def test_cancel_before_loop_both_paths(tiny_incr_model, tiny_spec_pair):
     """A request cancelled before its generation round resolves as
-    status='cancelled' with no output on every scheduler path; the
-    co-registered request is unaffected."""
+    status='cancelled' with no output on the incremental and on the
+    speculative path; the co-registered request is unaffected."""
     llm, ssm = tiny_spec_pair
 
-    def run(loop, model_cfg=None, use_native=None):
-        saved = None
-        if use_native is not None:
-            saved = getattr(model_cfg, "use_native_scheduler", True)
-            model_cfg.use_native_scheduler = use_native
-        try:
-            rm = RequestManager()
-            rm.max_spec_depth = 2
-            g_ok = rm.register_new_request(PROMPT_A, max_new_tokens=4)
-            g_cx = rm.register_new_request(PROMPT_B, max_new_tokens=4)
-            assert rm.cancel(g_cx) is True
-            assert rm.cancel(424242) is False      # unknown guid
-            loop(rm)
-            res_ok, res_cx = rm.results[g_ok], rm.results[g_cx]
-            assert res_ok.status == "ok" and len(res_ok.output_tokens) == 4
-            assert res_cx.status == "cancelled" and res_cx.cancelled
-            assert res_cx.output_tokens == []
-            assert rm.cancel(g_cx) is False        # already finished
-            assert rm.native_shadow_empty()
-            assert not rm.pending and not rm.inflight
-            return res_ok
-        finally:
-            if saved is not None:
-                model_cfg.use_native_scheduler = saved
+    def run(loop):
+        rm = RequestManager()
+        rm.max_spec_depth = 2
+        g_ok = rm.register_new_request(PROMPT_A, max_new_tokens=4)
+        g_cx = rm.register_new_request(PROMPT_B, max_new_tokens=4)
+        assert rm.cancel(g_cx) is True
+        assert rm.cancel(424242) is False      # unknown guid
+        loop(rm)
+        res_ok, res_cx = rm.results[g_ok], rm.results[g_cx]
+        assert res_ok.status == "ok" and len(res_ok.output_tokens) == 4
+        assert res_cx.status == "cancelled" and res_cx.cancelled
+        assert res_cx.output_tokens == []
+        assert rm.cancel(g_cx) is False        # already finished
+        assert not rm.pending and not rm.inflight
 
-    # python incremental loop
-    r_py = run(lambda rm: rm.generate_incr_decoding(tiny_incr_model),
-               model_cfg=tiny_incr_model.config, use_native=False)
-    # native (C++ scheduler) incremental loop — silently identical when
-    # the toolchain is absent (the loop falls back to python itself)
-    r_nat = run(lambda rm: rm.generate_incr_decoding(tiny_incr_model),
-                model_cfg=tiny_incr_model.config, use_native=True)
-    assert r_py.output_tokens == r_nat.output_tokens
-    # speculative loop
+    run(lambda rm: rm.generate_incr_decoding(tiny_incr_model))
     run(lambda rm: rm.generate_spec_infer(llm, [ssm]))
 
 
@@ -321,13 +303,10 @@ def test_timeout_resolves_with_partial_result(tiny_incr_model):
     assert res.status == "timed_out" and res.timed_out
     assert res.output_tokens == []
     assert rm.results[g_ok].status == "ok"
-    # expiry mid-generation keeps the partial prefix (python path so the
-    # host sees every between-round seam). A stall injector paces each
-    # decode block to >= 80 ms, so 48 tokens (6 blocks) CANNOT beat the
-    # 0.2 s deadline no matter how fast the warm model decodes — the
-    # reap seam must fire mid-generation.
-    saved = getattr(tiny_incr_model.config, "use_native_scheduler", True)
-    tiny_incr_model.config.use_native_scheduler = False
+    # expiry mid-generation keeps the partial prefix. A stall injector
+    # paces each decode block to >= 80 ms, so 48 tokens (6 blocks) CANNOT
+    # beat the 0.2 s deadline no matter how fast the warm model decodes —
+    # the reap seam must fire mid-generation.
     inj = FaultInjector(stall_every=1, stall_s=0.08).install(tiny_incr_model)
     try:
         g_mid = rm.register_new_request(PROMPT_A, max_new_tokens=48,
@@ -335,7 +314,6 @@ def test_timeout_resolves_with_partial_result(tiny_incr_model):
         rm.generate_incr_decoding(tiny_incr_model)
     finally:
         inj.uninstall()
-        tiny_incr_model.config.use_native_scheduler = saved
     res_mid = rm.results[g_mid]
     assert res_mid.status == "timed_out"
     assert len(res_mid.output_tokens) < 48
@@ -470,7 +448,6 @@ def test_stop_server_flush_timeout_cancels_stragglers(tiny_incr_model):
     assert res is not None
     assert res.status in ("cancelled", "ok")   # ok only if absurdly fast
     assert handle._server is None
-    assert handle.rm.native_shadow_empty()
     assert check_invariants(handle) == []
 
 

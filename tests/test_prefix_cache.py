@@ -220,16 +220,11 @@ def tiny_ssm2():
 def incr_ref(tiny_incr_model):
     """Cold (no prefix cache) incremental outputs for P0/PA/PB at
     max_new_tokens=REF_NEW — the reference every warm run must reproduce."""
-    saved = getattr(tiny_incr_model.config, "use_native_scheduler", True)
-    tiny_incr_model.config.use_native_scheduler = False
-    try:
-        rm = RequestManager()
-        guids = {tuple(p): rm.register_new_request(list(p),
-                                                   max_new_tokens=REF_NEW)
-                 for p in (P0, PA, PB)}
-        rm.generate_incr_decoding(tiny_incr_model)
-    finally:
-        tiny_incr_model.config.use_native_scheduler = saved
+    rm = RequestManager()
+    guids = {tuple(p): rm.register_new_request(list(p),
+                                               max_new_tokens=REF_NEW)
+             for p in (P0, PA, PB)}
+    rm.generate_incr_decoding(tiny_incr_model)
     assert all(rm.results[g].status == "ok" for g in guids.values())
     return {p: rm.results[g].output_tokens for p, g in guids.items()}
 
@@ -380,8 +375,6 @@ def test_decode_interleaves_with_chunked_prefill(tiny_incr_model):
     short request never waits for the full prefill as it did under the
     old drain-prefill-then-decode order."""
     model = tiny_incr_model
-    saved = getattr(model.config, "use_native_scheduler", True)
-    model.config.use_native_scheduler = False
     rm = RequestManager()
     long_prompt = [(i % 96) + 1 for i in range(28)]   # 4 chunks at chunk=8
     gl = rm.register_new_request(long_prompt, max_new_tokens=2)
@@ -389,11 +382,9 @@ def test_decode_interleaves_with_chunked_prefill(tiny_incr_model):
     events = []
     orig_prefill = rm._timed_prefill
 
-    def spy_prefill(ifm, meta, tel, rows=(), active=None, n_tokens=None,
-                    rnd=None):
+    def spy_prefill(*args, **kwargs):
         events.append("prefill")
-        return orig_prefill(ifm, meta, tel, rows=rows, active=active,
-                            n_tokens=n_tokens, rnd=rnd)
+        return orig_prefill(*args, **kwargs)
 
     rm._timed_prefill = spy_prefill
     from flexflow_tpu.serve.request_manager import InferenceManager
@@ -412,7 +403,6 @@ def test_decode_interleaves_with_chunked_prefill(tiny_incr_model):
         rm.generate_incr_decoding(model)
     finally:
         ifm.decode_block = orig_decode
-        model.config.use_native_scheduler = saved
     assert rm.results[gl].status == "ok"
     assert rm.results[gs].status == "ok"
     assert len(rm.results[gs].output_tokens) == 2

@@ -119,3 +119,54 @@ def test_qtake_matches_dequantized_gather():
         want = jnp.take(dequantize_array(qt), ids, axis=0)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=1e-6, atol=1e-6)
+
+
+def _tiny_llama(quant):
+    from flexflow_tpu.ffconst import CompMode, InferenceMode
+    from flexflow_tpu.models.llama import LLAMAConfig, create_llama_model
+
+    cfg = ff.FFConfig(max_requests_per_batch=2, max_sequence_length=64,
+                      max_tokens_per_batch=16, kv_cache_dtype="float32",
+                      quantization_type=quant, seed=3)
+    m = ff.FFModel(cfg)
+    create_llama_model(
+        m,
+        LLAMAConfig(vocab_size=128, hidden_size=128, intermediate_size=96,
+                    num_hidden_layers=1, num_attention_heads=4,
+                    num_key_value_heads=2, max_position_embeddings=64),
+        InferenceMode.INC_DECODING_MODE)
+    m.compile(comp_mode=CompMode.COMP_MODE_INFERENCE)
+    return m
+
+
+@pytest.mark.parametrize("quant", [None, "int8"])
+def test_param_accessors_roundtrip(quant):
+    """get/set_parameter_by_key on an unstacked model: a write to a
+    quantized weight re-quantizes it, and its neighbours stay as they
+    were."""
+    m = _tiny_llama(quant)
+    tol = dict(rtol=0.02, atol=1e-4) if quant else dict(rtol=1e-6)
+    for key, shape, neighbour in (
+            (("layers.0.self_attn", "wq"), (128, 128),
+             ("layers.0.self_attn", "wk")),
+            (("layers.0.mlp.gate_proj", "kernel"), (128, 96),
+             ("layers.0.mlp.up_proj", "kernel"))):
+        assert is_quantized(m.params[key[0]][key[1]]) == bool(quant)
+        w = m.get_parameter_by_key(key)
+        assert w.shape == shape
+        before = m.get_parameter_by_key(neighbour)
+        new = np.full_like(w, 0.01)
+        m.set_parameter_by_key(key, new)
+        assert is_quantized(m.params[key[0]][key[1]]) == bool(quant)
+        np.testing.assert_allclose(m.get_parameter_by_key(key), new, **tol)
+        np.testing.assert_array_equal(m.get_parameter_by_key(neighbour),
+                                      before)
+
+
+def test_param_set_rejects_wrong_shape():
+    m = _tiny_llama("int8")
+    with pytest.raises(AssertionError):
+        m.set_parameter_by_key(("layers.0.self_attn", "wq"),
+                               np.zeros(128, np.float32))
+    with pytest.raises(KeyError):
+        m.get_parameter_by_key(("layers.0.self_attn", "no_such_weight"))

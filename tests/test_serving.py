@@ -36,6 +36,55 @@ def make_model(mode=InferenceMode.INC_DECODING_MODE, seed=0, max_requests=4,
     return model
 
 
+@pytest.fixture(scope="module")
+def models():
+    """``make_model`` memoised for the tests that only serve from a model:
+    a fresh RequestManager starts every slot from position 0, so the
+    scenarios below share one build (and its compiled programs) a shape."""
+    built = {}
+
+    def get(**kw):
+        key = tuple(sorted(kw.items()))
+        if key not in built:
+            built[key] = make_model(**kw)
+        return built[key]
+
+    return get
+
+
+def _spec_infer(rm, engine, llm, ssm, spec_depth, monkeypatch):
+    """One draft model through the chain engine (generate_spec_infer's
+    choice where ``use_pallas`` is off, as on the CPU) or through the fused
+    tree engine (its choice on a TPU). The draft is priced at a tenth of
+    the verifier, as a real one is: at the tiny pair's own ratio of 1 the
+    controller parks every request before either engine runs a block."""
+    from flexflow_tpu.serve import engine as engines
+    from flexflow_tpu.serve.batch_config import GenerationConfig
+
+    cls = {"chain": engines.SpecChainEngine,
+           "tree": engines.MultiSpecEngine}[engine]
+    run_block, blocks = cls.run_block, []
+
+    def counted(self, *args, **kwargs):
+        blocks.append(type(self))
+        return run_block(self, *args, **kwargs)
+
+    monkeypatch.setattr(cls, "run_block", counted)
+    gc = GenerationConfig(spec_draft_cost_ratio=0.1)
+    if engine == "chain":
+        out = rm.generate_spec_infer(llm, [ssm], spec_depth=spec_depth,
+                                     generation_config=gc)
+        assert rm.scheduler_loop == "python:spec_chain"
+    else:
+        out = rm._generate_spec_tree_fused(llm, [ssm], spec_depth=spec_depth,
+                                           generation_config=gc)
+    assert blocks and set(blocks) == {cls}
+    return out
+
+
+SPEC_ENGINES = pytest.mark.parametrize("engine", ["chain", "tree"])
+
+
 def test_incr_decoding_deterministic():
     model = make_model()
     rm = RequestManager()
@@ -120,46 +169,49 @@ def test_verify_consistent_decode_width_matches_width1():
     assert run(8, max_new=60, max_seq=40) == run(1, max_new=60, max_seq=40)
 
 
-def test_spec_infer_matches_incr_decoding():
+@SPEC_ENGINES
+def test_spec_infer_matches_incr_decoding(models, engine, monkeypatch):
     """With the SSM = the LLM's own weights, speculation must accept nearly
     everything and the output must be token-identical to incremental
     decoding (the reference CI gate, python_inference_tests.sh:29)."""
     prompts = [[5, 9, 23, 44], [7, 3, 11]]
-    incr_model = make_model(seed=0)
+    incr_model = models(seed=0)
     rm = RequestManager()
     for p in prompts:
         rm.register_new_request(p, max_new_tokens=12)
     incr = {tuple(r.input_tokens): r.output_tokens
             for r in rm.generate_incr_decoding(incr_model)}
 
-    llm = make_model(mode=InferenceMode.TREE_VERIFY_MODE, seed=0)
-    ssm = make_model(mode=InferenceMode.BEAM_SEARCH_MODE, seed=0)
+    llm = models(mode=InferenceMode.TREE_VERIFY_MODE, seed=0)
+    ssm = models(mode=InferenceMode.BEAM_SEARCH_MODE, seed=0)
     rm2 = RequestManager()
     for p in prompts:
         rm2.register_new_request(p, max_new_tokens=12)
-    spec = rm2.generate_spec_infer(llm, [ssm], spec_depth=4)
+    spec = _spec_infer(rm2, engine, llm, ssm, 4, monkeypatch)
     assert len(spec) == 2
     for r in spec:
         assert incr[tuple(r.input_tokens)][:12] == r.output_tokens[:12]
 
 
-def test_spec_infer_divergent_ssm_still_correct():
+@SPEC_ENGINES
+def test_spec_infer_divergent_ssm_still_correct(models, engine,
+                                                monkeypatch):
     """A different-weight SSM proposes mostly-wrong drafts; the verifier must
     still emit exactly the incremental-decoding tokens."""
     prompts = [[5, 9, 23, 44]]
-    incr_model = make_model(seed=0)
+    incr_model = models(seed=0)
     rm = RequestManager()
     for p in prompts:
         rm.register_new_request(p, max_new_tokens=10)
     incr = {tuple(r.input_tokens): r.output_tokens
             for r in rm.generate_incr_decoding(incr_model)}
 
-    llm = make_model(mode=InferenceMode.TREE_VERIFY_MODE, seed=0)
-    ssm = make_model(mode=InferenceMode.BEAM_SEARCH_MODE, seed=123)
+    llm = models(mode=InferenceMode.TREE_VERIFY_MODE, seed=0)
+    ssm = models(mode=InferenceMode.BEAM_SEARCH_MODE, seed=123)
     rm2 = RequestManager()
     for p in prompts:
         rm2.register_new_request(p, max_new_tokens=10)
-    spec = rm2.generate_spec_infer(llm, [ssm], spec_depth=4)
+    spec = _spec_infer(rm2, engine, llm, ssm, 4, monkeypatch)
     for r in spec:
         assert incr[tuple(r.input_tokens)][:10] == r.output_tokens[:10]
 
@@ -210,7 +262,9 @@ def test_spec_infer_tensor_parallel_matches():
         assert incr[tuple(r.input_tokens)] == r.output_tokens
 
 
-def test_spec_chain_cramped_and_roomy_requests_coexist():
+@SPEC_ENGINES
+def test_spec_chain_cramped_and_roomy_requests_coexist(models, engine,
+                                                       monkeypatch):
     """A request whose prompt nearly fills the KV cache (no room to draft a
     full round) must finish via the single-step path while a roomy request
     speculates — without tripping the draft-cache assertions."""
@@ -219,7 +273,7 @@ def test_spec_chain_cramped_and_roomy_requests_coexist():
     cramped_prompt = list(range(1, 28))       # room = 32-27-1 = 4 < depth+1
     roomy_prompt = [5, 9, 23]
 
-    incr_model = make_model(seed=0, max_seq=max_seq)
+    incr_model = models(seed=0, max_seq=max_seq)
     rm = RequestManager()
     rm.register_new_request(cramped_prompt, max_new_tokens=8)
     rm.register_new_request(roomy_prompt, max_new_tokens=12)
@@ -227,23 +281,24 @@ def test_spec_chain_cramped_and_roomy_requests_coexist():
             for r in rm.generate_incr_decoding(incr_model)}
     assert len(incr[tuple(cramped_prompt)]) == max_seq - len(cramped_prompt)
 
-    llm = make_model(mode=InferenceMode.TREE_VERIFY_MODE, seed=0,
-                     max_seq=max_seq)
-    ssm = make_model(mode=InferenceMode.BEAM_SEARCH_MODE, seed=0,
-                     max_seq=max_seq)
+    llm = models(mode=InferenceMode.TREE_VERIFY_MODE, seed=0,
+                 max_seq=max_seq)
+    ssm = models(mode=InferenceMode.BEAM_SEARCH_MODE, seed=0,
+                 max_seq=max_seq)
     rm2 = RequestManager()
     rm2.register_new_request(cramped_prompt, max_new_tokens=8)
     rm2.register_new_request(roomy_prompt, max_new_tokens=12)
-    spec = rm2.generate_spec_infer(llm, [ssm], spec_depth=depth)
+    spec = _spec_infer(rm2, engine, llm, ssm, depth, monkeypatch)
     assert len(spec) == 2
     for r in spec:
         assert incr[tuple(r.input_tokens)] == r.output_tokens
 
 
-def test_spec_infer_eos_and_budget_respected():
+@SPEC_ENGINES
+def test_spec_infer_eos_and_budget_respected(models, engine, monkeypatch):
     """EOS accepted mid-chunk must stop generation exactly there, and the
     output must never exceed max_new_tokens (matching incremental)."""
-    incr_model = make_model(seed=0)
+    incr_model = models(seed=0)
     rm = RequestManager()
     rm.register_new_request([5, 9, 23, 44], max_new_tokens=7)
     (incr,) = rm.generate_incr_decoding(incr_model)
@@ -251,11 +306,11 @@ def test_spec_infer_eos_and_budget_respected():
     eos = incr.output_tokens[3]
     stop_at = incr.output_tokens.index(eos) + 1
 
-    llm = make_model(mode=InferenceMode.TREE_VERIFY_MODE, seed=0)
-    ssm = make_model(mode=InferenceMode.BEAM_SEARCH_MODE, seed=0)
+    llm = models(mode=InferenceMode.TREE_VERIFY_MODE, seed=0)
+    ssm = models(mode=InferenceMode.BEAM_SEARCH_MODE, seed=0)
     rm2 = RequestManager(eos_token_id=eos)
     rm2.register_new_request([5, 9, 23, 44], max_new_tokens=7)
-    (spec,) = rm2.generate_spec_infer(llm, [ssm], spec_depth=4)
+    (spec,) = _spec_infer(rm2, engine, llm, ssm, 4, monkeypatch)
     assert len(spec.output_tokens) == stop_at
     assert spec.output_tokens == incr.output_tokens[:stop_at]
     assert len(spec.output_tokens) <= 7
@@ -549,30 +604,6 @@ def test_spec_infer_multi_ssm_draftable_window_terminates():
     assert len(spec[0].output_tokens) == 10
 
 
-def test_single_ssm_fused_tree_path_matches_chain():
-    """On TPU a single SSM routes through the B=1 fused tree engine
-    (backend-dependent dispatch in generate_spec_infer); its output must
-    be identical to the chain engine's — same greedy acceptance, same
-    verifier — exercised here by calling the tree path directly."""
-    prompts = [[5, 9, 23, 44], [7, 3, 11]]
-    incr_model = make_model(seed=0)
-    rm = RequestManager()
-    for p in prompts:
-        rm.register_new_request(p, max_new_tokens=12)
-    incr = {tuple(r.input_tokens): r.output_tokens
-            for r in rm.generate_incr_decoding(incr_model)}
-
-    llm = make_model(mode=InferenceMode.TREE_VERIFY_MODE, seed=0)
-    ssm = make_model(mode=InferenceMode.BEAM_SEARCH_MODE, seed=0)
-    rm2 = RequestManager()
-    for p in prompts:
-        rm2.register_new_request(p, max_new_tokens=12)
-    spec = rm2._generate_spec_tree_fused(llm, [ssm], spec_depth=4)
-    assert len(spec) == 2
-    for r in spec:
-        assert incr[tuple(r.input_tokens)][:12] == r.output_tokens[:12]
-
-
 @pytest.mark.parametrize("adaptive", [False, True],
                          ids=["static", "adaptive"])
 @pytest.mark.parametrize("n_ssm", [1, 2])
@@ -746,66 +777,175 @@ def test_long_context_serving():
     assert res[tuple(short_prompt)] == solo
 
 
-def test_decode_auto_layout_matches_default():
-    """decode_auto_layout=True (AUTO weight layouts on the fused decode
-    block, engine.make_decode_block_auto) must produce the same tokens
-    as the default-layout path — it is a pure layout transformation.
-    Exercises the aval lowering + params relayout + compiled-executable
-    call path on whatever backend runs the tests."""
-    import flexflow_tpu as ff
-    from flexflow_tpu.models.llama import LLAMAConfig, create_llama_model
-    from flexflow_tpu.serve.request_manager import RequestManager
-
-    def gen(auto):
-        cfg = ff.FFConfig(max_requests_per_batch=2, max_sequence_length=64,
-                          max_tokens_per_batch=16, kv_cache_dtype="float32",
-                          decode_auto_layout=auto, seed=11)
-        m = ff.FFModel(cfg)
-        create_llama_model(
-            m,
-            LLAMAConfig(vocab_size=96, hidden_size=64, intermediate_size=96,
-                        num_hidden_layers=2, num_attention_heads=4,
-                        num_key_value_heads=2, max_position_embeddings=64),
-            InferenceMode.INC_DECODING_MODE)
-        m.compile(comp_mode=ff.CompMode.COMP_MODE_INFERENCE)
-        rm = RequestManager()
-        rm.register_new_request([3, 7, 11], max_new_tokens=6)
-        with warnings.catch_warnings(record=True) as caught:
-            warnings.simplefilter("always")
-            toks = rm.generate_incr_decoding(m)[0].output_tokens
-        fell_back = any("decode_auto_layout unavailable" in str(w.message)
-                        for w in caught)
-        return toks, fell_back
-
-    toks_auto, fell_back = gen(True)
-    toks_dflt, _ = gen(False)
-    assert toks_auto == toks_dflt
-    # the auto path must actually engage here (a silent fallback would
-    # make this test pass with the feature dead)
-    assert not fell_back
+# ---------------------------------------------------------------------------
+# The incremental loop's bookkeeping against a rule, with no model: slots,
+# blocks, EOS and budgets (what every default user's generate call runs)
+# ---------------------------------------------------------------------------
+_RULE_EOS = 13
 
 
-def test_decode_auto_layout_skipped_under_tp():
-    """Under tensor parallelism the AUTO-layout decode experiment must
-    not engage (sharding-free avals would de-shard the params)."""
-    cfg = ff.FFConfig(max_requests_per_batch=2, max_sequence_length=64,
-                      max_tokens_per_batch=16, kv_cache_dtype="float32",
-                      tensor_parallelism_degree=2, decode_auto_layout=True,
-                      seed=11)
-    m = ff.FFModel(cfg)
-    create_llama_model(
-        m,
-        LLAMAConfig(vocab_size=96, hidden_size=64, intermediate_size=96,
-                    num_hidden_layers=2, num_attention_heads=4,
-                    num_key_value_heads=2, max_position_embeddings=64),
-        InferenceMode.INC_DECODING_MODE)
-    m.compile(comp_mode=ff.CompMode.COMP_MODE_INFERENCE)
+def _rule_next(last, pos):
+    return (last + pos) % 50 + 1
+
+
+def _closed_form(prompt, max_new, max_seq, eos=_RULE_EOS):
+    """What one request generates alone under ``_rule_next``."""
+    toks = list(prompt)
+    while len(toks) - len(prompt) < max_new and len(toks) < max_seq:
+        toks.append(_rule_next(toks[-1], len(toks) - 1))
+        if toks[-1] == eos:
+            break
+    return toks[len(prompt):]
+
+
+class _RuleIFM:
+    """Stands in for InferenceManager: the next token is ``_rule_next`` of
+    the last token and its position. Records every call; ``on_decode`` runs
+    at the start of each decode block with the call's index."""
+
+    def __init__(self, on_decode=None):
+        self.prefills = []          # BatchMeta of every prefill step
+        self.decodes = []           # (tok, pos, act, block) of every block
+        self.on_decode = on_decode
+
+    def step(self, meta, want_output=True, tel=None):
+        assert not want_output
+        self.prefills.append(meta)
+
+    def decode_block(self, tok, pos, act, block, tel=None):
+        if self.on_decode is not None:
+            self.on_decode(len(self.decodes))
+        self.decodes.append((tok.copy(), pos.copy(), act.copy(), block))
+        out = np.zeros((tok.shape[0], block), np.int32)
+        cur, p = tok.copy(), pos.copy()
+        for j in range(block):
+            cur = _rule_next(cur, p)
+            p = p + 1
+            out[:, j] = np.where(act, cur, 0)
+        return out
+
+
+def _rule_model(cfg, ifm):
+    class Model:
+        config = cfg
+        _inference_manager = ifm
+
+    return Model()
+
+
+_RULE_PROMPTS = [[3, 4, 5], [10], [7, 8], [1, 2, 3, 4, 5, 6], [9, 9]]
+
+
+@pytest.mark.parametrize("block", [1, 4, 8])
+@pytest.mark.parametrize("slots", [1, 2, 3])
+def test_incr_loop_matches_closed_form(slots, block):
+    """Five requests through 1-3 slots in blocks of 1-8 steps: each gets
+    exactly what it would generate alone (EOS inside a block, a budget
+    that ends mid-block, rows refilled from the queue, one-token prompts)."""
+    cfg = ff.FFConfig(max_requests_per_batch=slots, max_sequence_length=24,
+                      max_tokens_per_batch=16, decode_block_steps=block)
+    rm = RequestManager(eos_token_id=_RULE_EOS)
+    for i, pr in enumerate(_RULE_PROMPTS):
+        rm.register_new_request(pr, max_new_tokens=6 + i)
+    ifm = _RuleIFM()
+    res = rm.generate_incr_decoding(_rule_model(cfg, ifm))
+    assert rm.scheduler_loop == "python"
+    got = {tuple(r.input_tokens): r.output_tokens for r in res}
+    want = {tuple(pr): _closed_form(pr, 6 + i, 24)
+            for i, pr in enumerate(_RULE_PROMPTS)}
+    assert got == want
+    # the scenarios the docstring names are in this workload
+    assert any(out[-1] == _RULE_EOS and len(out) < 6 + i
+               for i, out in enumerate(want.values()))
+    assert any(len(out) % 4 for out in want.values())
+    assert all(r.status == "ok" for r in res)
+    assert max(int(act.sum()) for _, _, act, _ in ifm.decodes) == slots
+
+
+def test_incr_loop_lifecycle():
+    """Two slots, three requests: the third waits for a slot, a row that
+    meets EOS mid-block is cut there, the freed slot refills the next
+    round, and a one-token prompt causes no prefill step."""
+    first = _closed_form([8], 8, 32, eos=None)
+    eos = first[1]                      # [8]'s second token, mid-block
+    assert eos not in _closed_form([5, 6, 7], 4, 32, eos=None)
+    cfg = ff.FFConfig(max_requests_per_batch=2, max_sequence_length=32,
+                      max_tokens_per_batch=16, decode_block_steps=4)
+    rm = RequestManager(eos_token_id=eos)
+    g1 = rm.register_new_request([5, 6, 7], max_new_tokens=4)
+    g2 = rm.register_new_request([8], max_new_tokens=8)
+    g3 = rm.register_new_request([9, 10], max_new_tokens=3)
+    waiting = []
+    ifm = _RuleIFM(on_decode=lambda i: waiting.append(len(rm.pending)))
+    rm.generate_incr_decoding(_rule_model(cfg, ifm))
+    # round 1: slots 0 and 1 decode, the third request waits
+    tok, pos, act, block = ifm.decodes[0]
+    assert waiting[0] == 1 and block == 4
+    assert (tok[0], pos[0], tok[1], pos[1]) == (7, 2, 8, 0) and act.all()
+    assert rm.results[g1].output_tokens == _closed_form([5, 6, 7], 4, 32)
+    assert rm.results[g2].output_tokens == first[:2]     # cut at EOS
+    # round 2: the freed slots refill; [9, 10] prefills 9 and decodes 10
+    tok, pos, act, block = ifm.decodes[1]
+    assert waiting[1] == 0 and block == 3
+    assert (tok[0], pos[0]) == (10, 1) and list(act) == [True, False]
+    assert len(rm.results[g3].output_tokens) == 3
+    # prefill: [5, 6] in round 1, [9] in round 2; never the one-token prompt
+    filled = [[int(t) for row, n in zip(m.tokens, m.num_tokens)
+               for t in row[:n]] for m in ifm.prefills]
+    assert filled == [[5, 6], [9]]
+    assert not rm.pending and not rm.inflight
+
+
+def test_incr_loop_prompt_fills_cache():
+    """A prompt as long as the cache has no room for one token: it ends
+    with no output and the loop terminates (and serves its neighbour)."""
+    cfg = ff.FFConfig(max_requests_per_batch=1, max_sequence_length=8,
+                      max_tokens_per_batch=16, decode_block_steps=4)
     rm = RequestManager()
-    rm.register_new_request([3, 7, 11], max_new_tokens=4)
-    res = rm.generate_incr_decoding(m)
-    assert len(res[0].output_tokens) == 4
-    wq = m.params["layers.0.self_attn"]["wq"]
-    assert "model" in str(wq.sharding.spec)      # still TP-sharded
+    full = rm.register_new_request(list(range(1, 9)), max_new_tokens=4)
+    ok = rm.register_new_request([7], max_new_tokens=2)
+    ifm = _RuleIFM()
+    res = rm.generate_incr_decoding(_rule_model(cfg, ifm))
+    assert len(res) == 2
+    assert rm.results[full].output_tokens == []
+    assert rm.results[full].status == "rejected"
+    assert rm.results[ok].output_tokens == _closed_form([7], 2, 8, eos=None)
+    assert not ifm.prefills and len(ifm.decodes) == 1
+
+
+def test_arrival_mid_call_is_served():
+    """A request that arrives while a decode block runs is admitted in the
+    next round and finishes in the same generate call."""
+    cfg = ff.FFConfig(max_requests_per_batch=2, max_sequence_length=32,
+                      max_tokens_per_batch=16, decode_block_steps=4)
+    rm = RequestManager()
+    early = rm.register_new_request([3, 4, 5], max_new_tokens=10)
+    late = []
+
+    def arrive(i):
+        if i == 0:
+            late.append(rm.register_new_request([6, 7], max_new_tokens=5))
+
+    ifm = _RuleIFM(on_decode=arrive)
+    res = rm.generate_incr_decoding(_rule_model(cfg, ifm))
+    assert {r.guid for r in res} == {early, late[0]}
+    assert rm.results[late[0]].output_tokens == \
+        _closed_form([6, 7], 5, 32, eos=None)
+    assert rm.results[early].output_tokens == \
+        _closed_form([3, 4, 5], 10, 32, eos=None)
+    # it shared the second block with the request that was running
+    assert ifm.decodes[1][2].all()
+
+
+def test_default_incr_path():
+    """FFConfig() as it comes: the Python loop, and a prefill step that is
+    the compact batch addressed by slot (what the benchmark's cells run)."""
+    rm = RequestManager()
+    rm.register_new_request([3, 4, 5, 6], max_new_tokens=3)
+    ifm = _RuleIFM()
+    rm.generate_incr_decoding(_rule_model(ff.FFConfig(), ifm))
+    assert rm.scheduler_loop == "python"
+    assert ifm.prefills and all(m.slots is not None for m in ifm.prefills)
 
 
 # ---------------------------------------------------------------------------
@@ -821,7 +961,7 @@ def _tiny_family(family, mode, seed, R=6, max_seq=63, batch_tokens=16):
 
     cfg = ff.FFConfig(max_requests_per_batch=R, max_sequence_length=max_seq,
                       max_tokens_per_batch=batch_tokens, seed=seed,
-                      kv_cache_dtype="float32", use_native_scheduler=False)
+                      kv_cache_dtype="float32")
     m = ff.FFModel(cfg)
     if family == "mha_d128":
         create_llama_model(m, LLAMAConfig(

@@ -131,25 +131,53 @@ def test_probe_cadence_and_recovery():
                            probe_every=3, recover_margin=1.05)
     ctrl = SpecController(pol)
     guid = 7
-    assert not ctrl.wants_draft(guid)          # parked at admission
+
+    def drafts():
+        return guid in ctrl.drafting([guid])
+
+    assert not drafts()                        # parked at admission
     assert ctrl.take_new_fallbacks() == 1
     for _ in range(pol.probe_every - 1):
         ctrl.note_fallback_block(guid)
-        assert not ctrl.wants_draft(guid)
+        assert not drafts()
     ctrl.note_fallback_block(guid)
-    assert ctrl.wants_draft(guid)              # probe due
+    assert drafts()                            # probe due
     # a bad probe re-parks and restarts the clock
     ctrl.observe_block(guid, [(1, 0)])
-    assert not ctrl.wants_draft(guid)
+    assert not drafts()
     assert probe_due(note_fallback_block(ctrl.states[guid]), pol) is False
     # an empty probe block (engine masked every round) also restarts it
     for _ in range(pol.probe_every):
         ctrl.note_fallback_block(guid)
-    assert ctrl.wants_draft(guid)
+    assert drafts()
     ctrl.observe_block(guid, [])
-    assert not ctrl.wants_draft(guid)
+    assert not drafts()
     ctrl.drop(guid)
     assert guid not in ctrl.states
+
+
+def test_parked_request_rides_with_a_drafting_one():
+    """Parking is per request, the incremental block per batch: beside a
+    request that speculates a parked one speculates too (the block runs
+    anyway), at the depth its own acceptance earns; a batch parked whole
+    decodes incrementally but for the probes that are due."""
+    pol = ControllerPolicy(min_depth=1, max_depth=4, draft_cost_ratio=0.1,
+                           probe_every=2)
+    ctrl = SpecController(pol)
+    for _ in range(6):                         # zero acceptance: parks
+        ctrl.observe_block(1, [(4, 0)])
+        ctrl.observe_block(2, [(4, 0)])
+    ctrl.observe_block(3, [(4, 4)])
+    assert ctrl.in_fallback(1) and ctrl.in_fallback(2)
+    assert not ctrl.in_fallback(3)
+    assert ctrl.drafting([1, 2, 3]) == {1, 2, 3}
+    assert ctrl.depth_for(1) == 1 and ctrl.depth_for(3) == 4
+    assert ctrl.in_fallback(1)                 # riding is not recovering
+    assert ctrl.drafting([1, 2]) == set()
+    ctrl.note_fallback_block(1)
+    ctrl.note_fallback_block(1)
+    assert ctrl.drafting([1, 2]) == {1}        # its probe, not 2's
+    assert ctrl.drafting([]) == set()
 
 
 # ---------------------------------------------------------------------------
@@ -183,6 +211,35 @@ def test_engine_depth_vector_caps_and_adapts(tiny_spec_pair):
     assert d_used[0, 1] == 2
     # the full-depth row is already at the compiled max and stays there
     assert n_acc[1, 0] == 4 and d_used[1, 1] == 4
+
+
+def test_give_up_is_the_blocks_not_the_rows():
+    """A row at the floor that accepts nothing leaves a block only when
+    every live row has collapsed: beside one that still accepts, the
+    block runs on anyway and the row keeps committing the verifier's
+    token. Depths still shrink and grow per row."""
+    import jax.numpy as jnp
+
+    from flexflow_tpu.serve.engine import _adapt_depth_rule
+
+    def rule(adapt, act, n_acc, depth):
+        d, alive = _adapt_depth_rule(
+            jnp.bool_(adapt), jnp.array(act), jnp.array(n_acc, jnp.int32),
+            jnp.array(depth, jnp.int32), jnp.array(act), jnp.int32(1), 4)
+        return d.tolist(), alive.tolist()
+
+    # row 0 collapsed, row 1 accepts fully, row 2 is not in the block
+    assert rule(True, [True, True, False], [0, 3, 0], [1, 3, 1]) == \
+        ([1, 4, 1], [True, True, False])
+    # both live rows collapsed: the block ends
+    assert rule(True, [True, True, False], [0, 0, 0], [1, 1, 1]) == \
+        ([1, 1, 1], [False, False, False])
+    # a zero accept above the floor shrinks and stays
+    assert rule(True, [True, True, False], [0, 0, 0], [1, 2, 1]) == \
+        ([1, 1, 1], [True, True, False])
+    # a static block adapts nothing
+    assert rule(False, [True, True, False], [0, 0, 0], [1, 1, 1]) == \
+        ([1, 1, 1], [True, True, False])
 
 
 # ---------------------------------------------------------------------------
@@ -301,6 +358,60 @@ def test_zero_acceptance_fused_tree_path_parks_too(tiny_spec_pair):
     assert {tuple(r.input_tokens): r.output_tokens for r in res} == incr
     assert tel.registry.get("ffsv_spec_fallback_total").value >= 2
     assert tel.registry.get("ffsv_spec_rounds_total").value <= 12
+
+
+@pytest.mark.parametrize("engine", ["chain", "tree"])
+def test_parked_row_never_stalls_a_drafting_batch(tiny_spec_pair, engine,
+                                                  monkeypatch):
+    """A request the controller holds parked from its first round, beside
+    one that drafts: no fallback decode block runs while both are live
+    (such a block decodes the parked row alone for decode_block_steps
+    steps and every other row waits it out), the parked one is served by
+    the speculation blocks, and both get the incremental tokens."""
+    import dataclasses
+
+    llm, ssm = tiny_spec_pair
+    prompts = [[5, 9, 23, 44], [7, 3, 11]]
+    rm = RequestManager()
+    for p in prompts:
+        rm.register_new_request(p, max_new_tokens=12)
+    incr = {tuple(r.input_tokens): r.output_tokens
+            for r in rm.generate_incr_decoding(llm)}
+
+    rm2 = RequestManager()
+    held = rm2.register_new_request(prompts[0], max_new_tokens=12)
+    rm2.register_new_request(prompts[1], max_new_tokens=20)
+    state = SpecController._state
+
+    def hold_parked(self, guid):
+        st = state(self, guid)
+        if guid == held and not st.fallback:
+            st = self.states[guid] = dataclasses.replace(
+                st, fallback=True, acceptance=0.0, depth=1)
+        return st
+
+    monkeypatch.setattr(SpecController, "_state", hold_parked)
+    monkeypatch.setattr(SpecController, "observe_block",
+                        lambda self, guid, rounds: None)
+    fallbacks = []
+    fallback = RequestManager._fallback_decode
+
+    def counted(self, ifm, reqs, *args, **kwargs):
+        fallbacks.append([r.guid for r in reqs])
+        return fallback(self, ifm, reqs, *args, **kwargs)
+
+    monkeypatch.setattr(RequestManager, "_fallback_decode", counted)
+    gc = GenerationConfig(spec_draft_cost_ratio=0.1)
+    if engine == "chain":
+        res = rm2.generate_spec_infer(llm, [ssm], generation_config=gc)
+        assert rm2.scheduler_loop == "python:spec_chain"
+    else:
+        res = rm2._generate_spec_tree_fused(llm, [ssm],
+                                            generation_config=gc)
+    out = {tuple(r.input_tokens): r.output_tokens for r in res}
+    assert out[tuple(prompts[0])] == incr[tuple(prompts[0])]
+    assert out[tuple(prompts[1])][:12] == incr[tuple(prompts[1])]
+    assert fallbacks == []
 
 
 def test_adaptive_output_matches_static(tiny_spec_pair):

@@ -241,12 +241,7 @@ def _serve(loop, llm, ssm, rm):
     for p in [[5, 9, 23, 44], [7, 3, 11], [9, 9, 4, 1, 2]]:
         rm.register_new_request(p, max_new_tokens=8)
     if loop == "incr":
-        saved = getattr(llm.config, "use_native_scheduler", True)
-        llm.config.use_native_scheduler = False
-        try:
-            return rm.generate_incr_decoding(llm)
-        finally:
-            llm.config.use_native_scheduler = saved
+        return rm.generate_incr_decoding(llm)
     gc = GenerationConfig(adaptive_spec=False)
     if loop == "spec_chain":
         return rm._generate_spec_chain(llm, ssm, spec_depth=2,

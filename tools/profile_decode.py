@@ -12,21 +12,17 @@ fenced by host readback):
                kernel path.
   --head       head-only fused loop (embed -> final norm -> lm_head ->
                argmax) isolating the fixed per-step overhead.
-  --no-fusion  disable serving gemm fusion (serve/gemm_fusion.py) to
-               measure its contribution.
 
 Findings that shaped the shipped code (7B-geometry int8, one v5e):
-  * per-layer slope 0.325 ms vs 0.247 ms stream bound -> the qkv and
-    gate|up gemm fusion (serve/gemm_fusion.py, tools/profile_gemmfuse.py);
+  * per-layer slope 0.325 ms vs 0.247 ms stream bound;
   * verify-consistent width-8 decode costs only +4.6% over width-1;
   * native int8xint8 MXU gemms are NOT faster than the shipped
-    dequant-into-bf16 gemm at M=64 (same T-slope protocol as
-    profile_gemmfuse.py), so dequant-on-read stays;
+    dequant-into-bf16 gemm at M=64, so dequant-on-read stays;
   * jnp whole-cache attention at S=256 is slower than the Pallas block
     kernel (12.0 vs 11.2 ms/step), so the kernel dispatch stays.
 
 Usage: python tools/profile_decode.py [--layers] [--width] [--jnp-attn]
-                                      [--head] [--no-fusion]
+                                      [--head]
 """
 
 import sys
@@ -37,7 +33,7 @@ import numpy as np
 sys.path.insert(0, ".")
 
 
-def build(layers, bench, use_pallas=True, fusion=True):
+def build(layers, bench, use_pallas=True):
     import flexflow_tpu as ff
     from flexflow_tpu.ffconst import InferenceMode
     from flexflow_tpu.models.llama import LLAMAConfig, create_llama_model
@@ -55,8 +51,7 @@ def build(layers, bench, use_pallas=True, fusion=True):
                       * bench.PROMPT_LEN,
                       kv_cache_dtype="bfloat16", compute_dtype="bfloat16",
                       seed=7, quantization_type=bench.QUANT,
-                      decode_block_steps=128, use_pallas=use_pallas,
-                      enable_fusion=fusion, gemm_fusion=fusion)
+                      decode_block_steps=128, use_pallas=use_pallas)
     m = ff.FFModel(ffc)
     create_llama_model(m, vcfg, mode=InferenceMode.TREE_VERIFY_MODE,
                        data_type=ff.DataType.DT_BFLOAT16)
@@ -78,7 +73,7 @@ def time_block(ifm, R, prompt_len, n=96):
     return best
 
 
-def run_layer_scaling(bench, fusion):
+def run_layer_scaling(bench):
     import gc
 
     from flexflow_tpu.search.machine_model import TPU_CHIPS
@@ -88,7 +83,7 @@ def run_layer_scaling(bench, fusion):
     results = {}
     lm_head = 0
     for L in (32, 16, 8):
-        m, ifm = build(L, bench, fusion=fusion)
+        m, ifm = build(L, bench)
         wbytes = sum(int(w.nbytes) for ln, lp in m.params.items()
                      if "embed" not in ln for w in lp.values())
         lm_head = sum(int(w.nbytes) for w in m.params["lm_head"].values())
@@ -110,14 +105,14 @@ def run_layer_scaling(bench, fusion):
           f"(lm_head stream alone {lm_head / bw * 1e3:.3f} ms)")
 
 
-def run_width(bench, fusion):
+def run_width(bench):
     import jax
     import jax.numpy as jnp
 
     from flexflow_tpu.serve.engine import make_decode_block
 
     R, P = bench.NUM_REQUESTS, bench.PROMPT_LEN
-    m, ifm = build(bench.LAYERS, bench, fusion=fusion)
+    m, ifm = build(bench.LAYERS, bench)
     t = time_block(ifm, R, P)
     print(f"decode_block(width={ifm.decode_width}): {t * 1e3:.3f} ms/step")
     blk1 = make_decode_block(m, jnp.bfloat16, 128, width=1)
@@ -143,8 +138,8 @@ def run_width(bench, fusion):
           f"{(t / best - 1) * 100:+.1f}%)")
 
 
-def run_jnp_attention(bench, fusion):
-    m, ifm = build(bench.LAYERS, bench, use_pallas=False, fusion=fusion)
+def run_jnp_attention(bench):
+    m, ifm = build(bench.LAYERS, bench, use_pallas=False)
     t = time_block(ifm, bench.NUM_REQUESTS, bench.PROMPT_LEN)
     print(f"decode_block(jnp attention, width={ifm.decode_width}): "
           f"{t * 1e3:.3f} ms/step")
@@ -205,17 +200,16 @@ def main():
     sys.argv = [sys.argv[0]]       # bench.py parses argv at import time
     import bench
 
-    fusion = "--no-fusion" not in args
-    if "--layers" in args or not (args - {"--no-fusion"}):
-        run_layer_scaling(bench, fusion)
+    if "--layers" in args or not args:
+        run_layer_scaling(bench)
     if "--width" in args:
-        run_width(bench, fusion)
+        run_width(bench)
     m = None
     if "--jnp-attn" in args:
-        m = run_jnp_attention(bench, fusion)
+        m = run_jnp_attention(bench)
     if "--head" in args:
         if m is None:
-            m, _ = build(bench.LAYERS, bench, fusion=fusion)
+            m, _ = build(bench.LAYERS, bench)
         run_head_only(bench, m)
 
 
