@@ -53,7 +53,9 @@ def _pack_factor(D: int) -> int:
     row (PACK=2) so every DMA slice stays lane-full — the kernel then
     processes each block's even/odd position halves as two online-softmax
     sub-block updates, with zero-padded q variants and lane-masked v so no
-    in-kernel relayout is ever needed. Unsupported D returns 0."""
+    in-kernel relayout is ever needed. A packed cache is STORED packed,
+    ``[.., S/PACK, 128]`` (ops/kv_layout.py owns that layout), so nothing
+    outside the kernel relays it either. Unsupported D returns 0."""
     if D % LANE == 0:
         return 1
     if D == 64:
@@ -370,9 +372,15 @@ def flash_attend(q, k_cache, v_cache, lengths, qpos, bias=None,
     """Batched KV-cache attention.
 
     q        [R, Q, H, D]   new-token queries (rotary already applied)
-    k/v      [R, KH, S, D]  full cache (new tokens already appended), or the
-                            whole stacked [L, R, KH, S, D] buffer with
-                            ``layer_idx`` selecting the layer to stream
+    k/v      [R, KH, S/PACK, PACK*D]  full cache AS STORED (new tokens
+                            already appended): [R, KH, S, D] where D fills
+                            the lanes, the packed [R, KH, S/2, 128] at
+                            D=64 (position p in row p // 2, lanes
+                            [(p % 2) * 64, +64): ops/kv_layout.py). Or the
+                            whole stacked [L, R, KH, ...] buffer with
+                            ``layer_idx`` selecting the layer to stream.
+                            Taken and returned as is: no cache operand is
+                            reshaped here
     lengths  [R] int32      valid cache extent per request (0 => skip slot)
     qpos     [R, Q] int32   absolute position of each query token
     bias     [R, Q, S] f32  optional additive mask (tree mask; NEG_INF=hidden)
@@ -393,10 +401,13 @@ def flash_attend(q, k_cache, v_cache, lengths, qpos, bias=None,
     """
     assert rows is None or append_kv is None, "no fused append by row map"
     R, Q, H, D = q.shape
-    KH, S = k_cache.shape[-3], k_cache.shape[-2]
+    PACK = _pack_factor(D)
+    assert PACK > 0 and k_cache.shape[-1] == PACK * D, (
+        f"a D={D} cache is stored [.., S/{PACK}, {PACK * D}]; got "
+        f"{k_cache.shape}")
+    KH, S = k_cache.shape[-3], k_cache.shape[-2] * PACK
     G = H // KH
     GQ = G * Q
-    PACK = _pack_factor(D)
     BS = _pick_block_s(S, D)
     assert BS > 0, f"S={S}/D={D} not tileable by a supported block size"
     SB = BS // PACK
@@ -415,10 +426,6 @@ def flash_attend(q, k_cache, v_cache, lengths, qpos, bias=None,
         qt = jnp.stack(
             [jnp.pad(qt, ((0, 0),) * 3 + ((h * D, LANE - (h + 1) * D),))
              for h in range(PACK)], axis=1)         # [R, PACK, KH, GQ, LANE]
-        # packed cache view: [.., S, D] -> [.., S/PACK, LANE] (row-major
-        # bitcast: row j holds positions PACK*j .. PACK*j+PACK-1)
-        k_cache = k_cache.reshape(k_cache.shape[:-2] + (S // PACK, LANE))
-        v_cache = v_cache.reshape(v_cache.shape[:-2] + (S // PACK, LANE))
     qp_gq = jnp.tile(qpos.astype(jnp.int32), (1, G))            # [R, GQ]
     has_bias = bias is not None
     has_alibi = alibi is not None
@@ -544,10 +551,6 @@ def flash_attend(q, k_cache, v_cache, lengths, qpos, bias=None,
     )(lengths.astype(jnp.int32), appos.astype(jnp.int32), qt, qp_gq,
       slopes_gq, k_new.astype(cache_dt), v_new.astype(cache_dt),
       bias.astype(jnp.float32), k_cache, v_cache)
-    if PACK > 1:
-        # un-pack the cache views back to the caller's [.., S, D] shape
-        k_out = k_out.reshape(k_out.shape[:-2] + (S, D))
-        v_out = v_out.reshape(v_out.shape[:-2] + (S, D))
     return post(out), k_out, v_out
 
 

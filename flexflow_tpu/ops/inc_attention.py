@@ -32,6 +32,7 @@ from jax.sharding import PartitionSpec as P
 from flexflow_tpu.core.layer import WeightSpec
 from flexflow_tpu.core.initializer import default_kernel_initializer, ZeroInitializer
 from flexflow_tpu.ffconst import DataType, OpType
+from flexflow_tpu.ops import kv_layout as kvl
 from flexflow_tpu.ops.base import OpImpl, register_op, register_op_as
 
 
@@ -63,8 +64,13 @@ def apply_rotary(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray
 # KV cache update (reference update_kv_cache_kernel, inc_mha.cu:376)
 # ----------------------------------------------------------------------
 def append_kv(cache: jnp.ndarray, new: jnp.ndarray, start_pos: jnp.ndarray,
-              num_tokens: jnp.ndarray, active: jnp.ndarray) -> jnp.ndarray:
+              num_tokens: jnp.ndarray, active: jnp.ndarray,
+              pack: int = 1) -> jnp.ndarray:
     """Write new [R, Q, KH, D] into cache [R, KH, S, D] at per-slot offsets.
+
+    A packed cache (``pack`` > 1: [R, KH, S/pack, pack*D], ops/kv_layout.py)
+    has no row to scatter a position into: it takes the exact contiguous
+    append below, which drops the same tokens.
 
     Padding tokens and inactive slots are dropped. The head-major cache
     layout keeps each head's [S, D] block contiguous, which is what the
@@ -79,6 +85,9 @@ def append_kv(cache: jnp.ndarray, new: jnp.ndarray, start_pos: jnp.ndarray,
     step. Prefill / tree steps (Q > 1) keep the windowed scatter: the copy
     cost is amortized over the whole chunk.
     """
+    if pack > 1:
+        return append_kv_contiguous(cache, None, new, start_pos, active,
+                                    num_tokens=num_tokens, pack=pack)
     R, Q = new.shape[0], new.shape[1]
     S = cache.shape[2]
     KH = cache.shape[1]
@@ -102,9 +111,9 @@ _append_kv_fn = append_kv   # alias: _attend's append_kv kwarg shadows it
 
 def append_kv_stacked(stack: jnp.ndarray, layer_idx: int, new: jnp.ndarray,
                       start_pos: jnp.ndarray, num_tokens: jnp.ndarray,
-                      active: jnp.ndarray) -> jnp.ndarray:
+                      active: jnp.ndarray, pack: int = 1) -> jnp.ndarray:
     """Write new [R, Q, KH, D] into the stacked cache [L, R, KH, S, D] at
-    layer ``layer_idx``, in place.
+    layer ``layer_idx``, in place (a packed stack: as ``append_kv``).
 
     Scattering one D-row per (layer, request, head, token) keeps the
     stack's canonical layout and updates the donated buffer with no
@@ -112,6 +121,9 @@ def append_kv_stacked(stack: jnp.ndarray, layer_idx: int, new: jnp.ndarray,
     (``stack[i]`` -> append -> ``stack.at[i].set``) costs an 8.4MB read +
     8.4MB write per cache per layer per step at bench geometry.
     """
+    if pack > 1:
+        return append_kv_contiguous(stack, layer_idx, new, start_pos, active,
+                                    num_tokens=num_tokens, pack=pack)
     R, Q = new.shape[0], new.shape[1]
     KH, S = stack.shape[2], stack.shape[3]
     sh = (R, KH, Q)
@@ -174,7 +186,10 @@ def alibi_slopes(num_heads: int) -> jnp.ndarray:
 def _attend(attrs, q, k_cache, v_cache, lengths, qpos, out_dtype, ctx,
             bias=None, causal=True, layer_idx=None, append_kv=None,
             rows=None):
-    """q [R,Q,H,D] x cache [R,KH,S,D] -> [R, Q, H*D].
+    """q [R,Q,H,D] x cache [R,KH,S,D] -> [R, Q, H*D]. The cache comes as
+    stored (packed [R,KH,S/2,128] at D=64 on the flash path, see
+    ops/kv_layout.py) and goes to the kernel as it is; the jnp paths unpack
+    the one layer they attend.
 
     ``rows`` [R] (the compact prefill batch's ``BatchMeta.slots``): batch
     row r attends cache row rows[r]. The Pallas kernel takes it as a DMA
@@ -208,8 +223,9 @@ def _attend(attrs, q, k_cache, v_cache, lengths, qpos, out_dtype, ctx,
         scale = scale * attrs.get("scaling_factor", 1.0)
     alibi = (alibi_slopes(attrs["num_q_heads"])
              if attrs.get("position_bias", False) else None)
-    S = k_cache.shape[-2]
-    Dp = k_cache.shape[-1]          # cache head dim (128-padded)
+    S = attrs["max_seq_length"]
+    pack = kvl.pack_of(k_cache, S)
+    Dp = k_cache.shape[-1] // pack  # cache head dim (128-padded)
     cfg = ctx.config if ctx is not None else None
     from flexflow_tpu.kernels.attention import supports_shapes
     Q = q.shape[1]
@@ -217,7 +233,8 @@ def _attend(attrs, q, k_cache, v_cache, lengths, qpos, out_dtype, ctx,
     seq_deg = (mesh.shape["seq"] if mesh is not None
                and "seq" in getattr(mesh, "axis_names", ()) else 1)
     if not ffk.use_pallas(cfg):
-        pass                        # CPU/tests: jnp is the intended path
+        if pack > 1:                # allocated for a kernel now switched off
+            ffk.record_fallback("packed cache attended off the Pallas path")
     elif seq_deg > 1 and S % seq_deg == 0:
         # the cache's S dim lives sharded over the mesh: the kernel streams
         # one device's HBM, so this plan attends through the jnp
@@ -262,18 +279,20 @@ def _attend(attrs, q, k_cache, v_cache, lengths, qpos, out_dtype, ctx,
         kp, vp = _pad_d(k_new, Dp), _pad_d(v_new, Dp)
         if layer_idx is not None:
             k_cache = append_kv_stacked(k_cache, layer_idx, kp, start, num,
-                                        valid)
+                                        valid, pack)
             v_cache = append_kv_stacked(v_cache, layer_idx, vp, start, num,
-                                        valid)
+                                        valid, pack)
         else:
-            k_cache = _append_kv_fn(k_cache, kp, start, num, valid)
-            v_cache = _append_kv_fn(v_cache, vp, start, num, valid)
+            k_cache = _append_kv_fn(k_cache, kp, start, num, valid, pack)
+            v_cache = _append_kv_fn(v_cache, vp, start, num, valid, pack)
         new_caches = (k_cache, v_cache)
     kc, vc = k_cache, v_cache
     if layer_idx is not None:
         kc, vc = k_cache[layer_idx], v_cache[layer_idx]
     if rows is not None:
         kc, vc = kc[rows], vc[rows]
+    # the jnp oracles attend position-major [R, KH, S, D]
+    kc, vc = kvl.to_positions(kc, pack), kvl.to_positions(vc, pack)
     if seq_deg > 1 and S % seq_deg == 0:
         # searched sequence-parallel plan: the cache S dim is sharded over
         # the mesh's "seq" axis — score local slices, reconcile the softmax
@@ -363,16 +382,18 @@ def _weight_specs(attrs, input_specs):
 
 def padded_head_dim(D: int, want_pallas: bool = True,
                     max_seq: Optional[int] = None) -> int:
-    """Cache head-dim allocation for the flash path. D=64 (GPT-2-class)
-    needs NO padding anymore: the kernel packs two positions per 128-lane
-    cache row (kernels/attention.py _pack_factor), so KV memory and
-    stream bandwidth stay 1x (r2 VERDICT: the former pad-to-128 cost 2x
-    both, forever). The packed mode needs the cache length divisible by
-    its 256-position block, so when ``max_seq`` can't tile it (e.g.
-    S=128) the cache falls back to the pad-to-128 layout rather than off
-    the flash path entirely. Other dims round up to the lane tile so DMA
-    slices stay lane-full. Configs that can never take the flash path
-    (use_pallas off, non-TPU backend) keep the exact D."""
+    """Per-position width of the cache for the flash path. D=64
+    (GPT-2-class) needs NO padding: the kernel packs two positions per
+    128-lane cache row (kernels/attention.py _pack_factor) and the cache
+    is stored that way, [.., S/2, 128] (``_init_kv_state`` asks
+    ops/kv_layout.stored_pack), so KV memory and stream bandwidth stay 1x
+    and no step relays the cache. The packed mode needs the cache length
+    divisible by its 256-position block, so when ``max_seq`` can't tile it
+    (e.g. S=128) the cache falls back to the pad-to-128 layout,
+    [.., S, 128], rather than off the flash path entirely. Other dims
+    round up to the lane tile so DMA slices stay lane-full. Configs that
+    can never take the flash path (use_pallas off, non-TPU backend) keep
+    the exact D, position-major."""
     if not want_pallas:
         return D
     from flexflow_tpu.kernels.attention import (LANE, _pack_factor,
@@ -405,12 +426,14 @@ def _init_kv_state(attrs, input_specs):
     S = attrs["max_seq_length"]
     KH, D = attrs["num_kv_heads"], attrs["head_dim"]
     cache_dtype = jnp.dtype(attrs.get("cache_dtype", "bfloat16"))
-    Dp = padded_head_dim(
-        D, want_pallas=(attrs.get("use_pallas", True) and ffk.use_pallas()),
-        max_seq=S)
+    want_pallas = attrs.get("use_pallas", True) and ffk.use_pallas()
+    Dp = padded_head_dim(D, want_pallas=want_pallas, max_seq=S)
+    # stored as the kernel reads it: [R, KH, S, Dp], or packed
+    # [R, KH, S/2, 128] where a D=64 cache takes the packed flash path
+    shape = kvl.cache_shape(R, KH, S, Dp, kvl.stored_pack(Dp, S, want_pallas))
     return {
-        "k_cache": jnp.zeros((R, KH, S, Dp), dtype=cache_dtype),
-        "v_cache": jnp.zeros((R, KH, S, Dp), dtype=cache_dtype),
+        "k_cache": jnp.zeros(shape, dtype=cache_dtype),
+        "v_cache": jnp.zeros(shape, dtype=cache_dtype),
     }
 
 
@@ -459,25 +482,30 @@ def write_kv(ctx, attrs, k_cache, v_cache):
 
 
 def append_kv_contiguous(cache, layer_idx, new, start_pos, active,
-                         slots=None, num_tokens=None):
+                         slots=None, num_tokens=None, pack: int = 1):
     """In-place contiguous append: per-request dynamic_update_slice of the
-    [KH, Q, D] run at start_pos[r] — no scatter at all.
+    [KH, Q, D] run at start_pos[r] — no scatter at all. The one writer of
+    a packed cache (``pack`` > 1, ops/kv_layout.py): the run is laid into
+    the window of stored rows that holds its positions (Q/pack + 1 of
+    them: any parity of start_pos) and merged by position, so packing is a
+    relayout of the run, never of the cache.
 
-    Without ``slots`` (the fused engines): callable ONLY under their
+    Without ``num_tokens`` (the fused engines): callable ONLY under their
     guarantee that every ACTIVE row has start_pos + Q <= S (their
     live_masks enforce it). Inactive rows re-write their current region
     unchanged (a slot can be live but sitting out of an engine block —
     e.g. cramped near the cache end — so its KV must not be touched).
     Padding tokens beyond num_tokens write garbage BEYOND the valid
-    extent, masked by lengths until overwritten by the next real append.
+    extent, masked by lengths until overwritten by the next real append
+    (a packed cache keeps what it held there: the merge is by position).
 
-    With ``slots`` (the compact prefill batch; ``num_tokens`` is read
-    only then): batch row r's run lands in cache row slots[r], and the
-    append is exact, as the windowed scatter's drop is: a padding position
-    keeps what the cache held (a later row may be the same slot's next
-    chunk), and a run that starts within Q of the cache's end is written
-    through the window [S - Q, S) with its tokens shifted to their own
-    positions (the whole row, where Q > S).
+    With ``num_tokens`` the append is exact, as the windowed scatter's
+    drop is: a padding position keeps what the cache held (a later row may
+    be the same slot's next chunk), and a run that starts within Q of the
+    cache's end is written through the window that ends at S with its
+    tokens shifted to their own positions (the whole row, where Q > S).
+    With ``slots`` besides (the compact prefill batch), batch row r's run
+    lands in cache row slots[r] and the rows are unrolled.
 
     This beats both scatter forms: the windowed scatter forces a permuted
     layout + full per-layer cache copies (~134MB/layer/step at 7B), and
@@ -485,49 +513,56 @@ def append_kv_contiguous(cache, layer_idx, new, start_pos, active,
     scatter at 7B MHA).
     """
     R, Q = new.shape[0], new.shape[1]
-    S = cache.shape[-2]
-    KH, D = cache.shape[-3], cache.shape[-1]
+    rows, lanes = cache.shape[-2:]
+    KH = cache.shape[-3]
     newT = jnp.swapaxes(new.astype(cache.dtype), 1, 2)    # [R, KH, Q, D]
     lead = () if layer_idx is None else (layer_idx,)
     # the eager debug dump (utils/debugging) hands numpy descriptors over
     start_pos, active = jnp.asarray(start_pos), jnp.asarray(active)
     lead1 = (1,) * (len(lead) + 1)
-    if slots is not None:
-        slots, num_tokens = jnp.asarray(slots), jnp.asarray(num_tokens)
-        if Q > S:
-            # a chunk wider than the cache (tiny max_sequence_length): no
-            # position past S exists, so the run's first S columns hold
-            # every token that can land
-            newT, Q = newT[:, :, :S], S
-        s = jnp.clip(start_pos, 0, S - Q)
-        shift = start_pos - s             # > 0 only within Q of the end
-        t = jnp.arange(Q)[None] - shift[:, None]    # a window column's token
-        keep = active[:, None] & (t >= 0) & (t < num_tokens[:, None])
-        # a handful of rows (the step's segments), in order and unrolled:
-        # as a device loop each row read its scalars one operation apiece,
-        # dozens a row, in every layer of every prefill step
-        for r in range(R):
-            at = lead + (slots[r], 0, s[r], 0)
-            run = jnp.roll(newT[r], shift[r], axis=1)
-            cur = jax.lax.dynamic_slice(cache, at, lead1 + (KH, Q, D))
-            upd = jnp.where(keep[r][None, :, None],
-                            run[(None,) * len(lead1)], cur)
-            cache = jax.lax.dynamic_update_slice(cache, upd, at)
-        return cache
+    if pack > 1:        # merged by position: the window is wider than Q
+        num_tokens = (jnp.full((R,), Q, jnp.int32) if num_tokens is None
+                      else jnp.minimum(jnp.asarray(num_tokens), Q))
+    if num_tokens is None:
+        def body(r, c):
+            at = lead + (r, 0, jnp.clip(start_pos[r], 0, rows - Q), 0)
+            cur = jax.lax.dynamic_slice(c, at, lead1 + (KH, Q, lanes))
+            upd = jnp.where(active[r], newT[r][(None,) * len(lead1)], cur)
+            return jax.lax.dynamic_update_slice(c, upd, at)
 
-    def body(r, c):
-        at = lead + (r, 0, jnp.clip(start_pos[r], 0, S - Q), 0)
-        cur = jax.lax.dynamic_slice(c, at, lead1 + (KH, Q, D))
-        upd = jnp.where(active[r], newT[r][(None,) * len(lead1)], cur)
-        return jax.lax.dynamic_update_slice(c, upd, at)
+        return jax.lax.fori_loop(0, R, body, cache)
 
-    return jax.lax.fori_loop(0, R, body, cache)
+    num_tokens = jnp.asarray(num_tokens)
+    if Q > rows * pack:
+        # a chunk wider than the cache (tiny max_sequence_length): no
+        # position past S exists, so the run's first S columns hold
+        # every token that can land
+        newT, Q = newT[:, :, :rows * pack], rows * pack
+    row, W, off = kvl.window(start_pos, Q, rows, pack)
+    t = jnp.arange(W * pack)[None] - off[:, None]   # a window column's token
+    keep = active[:, None] & (t >= 0) & (t < num_tokens[:, None])
+
+    def put(c, r, slot):
+        at = lead + (slot, 0, row[r], 0)
+        cur = jax.lax.dynamic_slice(c, at, lead1 + (KH, W, lanes))
+        return jax.lax.dynamic_update_slice(
+            c, kvl.merge_window(cur, newT[r], keep[r], off[r], pack), at)
+
+    if slots is None:
+        return jax.lax.fori_loop(0, R, lambda r, c: put(c, r, r), cache)
+    slots = jnp.asarray(slots)
+    # a handful of rows (the step's segments), in order and unrolled:
+    # as a device loop each row read its scalars one operation apiece,
+    # dozens a row, in every layer of every prefill step
+    for r in range(R):
+        cache = put(cache, r, slots[r])
+    return cache
 
 
 # One trace serves every layer's K and V append of a compact prefill step:
 # the layer index is an operand here, where the engines' calls bake it in
 # (tracing the loop 2 x layers times cost seconds of set-up at 32 layers).
-_append_by_slot = jax.jit(append_kv_contiguous)
+_append_by_slot = jax.jit(append_kv_contiguous, static_argnames=("pack",))
 
 
 def append_and_ref(ctx, attrs, k, v, start_pos, num_tokens, active,
@@ -536,7 +571,9 @@ def append_and_ref(ctx, attrs, k, v, start_pos, num_tokens, active,
     over: layer_idx is None when the refs are this layer's own [R,KH,S,D]
     caches, or the layer's index when they are the full [L,...] stack
     (stacked caches append in place — see append_kv_stacked). New k/v pad
-    to the cache's (128-lane-tiled) head dim first.
+    to the cache's per-position width first (128-lane-tiled, or the exact
+    D of a packed cache: ops/kv_layout.py), and every append is told the
+    cache's pack factor.
 
     ``slots`` (the compact prefill batch): row r's run goes to cache row
     slots[r] by the exact in-place append of append_kv_contiguous, so a
@@ -556,40 +593,49 @@ def append_and_ref(ctx, attrs, k, v, start_pos, num_tokens, active,
     assert ov is None or slots is None, "no row map inside a pipeline stage"
     if ov is not None or idx is None:
         k0, v0 = read_kv(ctx, attrs)
-        k, v = _pad_d(k, k0.shape[-1]), _pad_d(v, v0.shape[-1])
+        pack = kvl.pack_of(k0, attrs["max_seq_length"])
+        Dp = k0.shape[-1] // pack
+        k, v = _pad_d(k, Dp), _pad_d(v, Dp)
         if slots is not None:
             kc = _append_by_slot(k0, None, k, start_pos, active, slots,
-                                 num_tokens)
+                                 num_tokens, pack=pack)
             vc = _append_by_slot(v0, None, v, start_pos, active, slots,
-                                 num_tokens)
+                                 num_tokens, pack=pack)
         elif contiguous and k.shape[1] != 1:
-            kc = append_kv_contiguous(k0, None, k, start_pos, active)
-            vc = append_kv_contiguous(v0, None, v, start_pos, active)
+            kc = append_kv_contiguous(k0, None, k, start_pos, active,
+                                      pack=pack)
+            vc = append_kv_contiguous(v0, None, v, start_pos, active,
+                                      pack=pack)
         else:
-            kc = append_kv(k0, k, start_pos, num_tokens, active)
-            vc = append_kv(v0, v, start_pos, num_tokens, active)
+            kc = append_kv(k0, k, start_pos, num_tokens, active, pack)
+            vc = append_kv(v0, v, start_pos, num_tokens, active, pack)
         write_kv(ctx, attrs, kc, vc)
         return kc, vc, None
     st = ctx.state_out.get("kv_cache") or ctx.state_in["kv_cache"]
-    k, v = _pad_d(k, st["k"].shape[-1]), _pad_d(v, st["v"].shape[-1])
+    pack = kvl.pack_of(st["k"], attrs["max_seq_length"])
+    Dp = st["k"].shape[-1] // pack
+    k, v = _pad_d(k, Dp), _pad_d(v, Dp)
     if slots is not None:
         ks = _append_by_slot(st["k"], jnp.int32(idx), k, start_pos, active,
-                             slots, num_tokens)
+                             slots, num_tokens, pack=pack)
         vs = _append_by_slot(st["v"], jnp.int32(idx), v, start_pos, active,
-                             slots, num_tokens)
+                             slots, num_tokens, pack=pack)
     elif contiguous and k.shape[1] != 1:
         # wide contiguous appends (engine verify/catch-up): scatter-free
         # DUS; decode (Q == 1) stays on the per-(r,kh) row scatter — at 7B
         # the stacked 5D DUS read-modify loop defeats XLA's in-place
         # aliasing and copies the stack, while the 64-256-row scatter is
         # cheap
-        ks = append_kv_contiguous(st["k"], idx, k, start_pos, active)
-        vs = append_kv_contiguous(st["v"], idx, v, start_pos, active)
-    elif k.shape[1] == 1:
+        ks = append_kv_contiguous(st["k"], idx, k, start_pos, active,
+                                  pack=pack)
+        vs = append_kv_contiguous(st["v"], idx, v, start_pos, active,
+                                  pack=pack)
+    elif k.shape[1] == 1 or pack > 1:
+        # (a packed stack takes every width in place: append_kv_stacked)
         ks = append_kv_stacked(st["k"], idx, k, start_pos, num_tokens,
-                               active)
+                               active, pack)
         vs = append_kv_stacked(st["v"], idx, v, start_pos, num_tokens,
-                               active)
+                               active, pack)
     else:
         # host-stepped wide appends (prefill chunks, host tree verify):
         # drop-exact windowed scatter on the per-layer slice — paid once
@@ -662,7 +708,7 @@ class IncMultiHeadSelfAttention(OpImpl):
             else:          # full stacked [L, R, KH, S, D] buffers
                 st = ctx.state_out.get("kv_cache") or ctx.state_in["kv_cache"]
                 k0, v0 = st["k"], st["v"]
-            S = k0.shape[-2]
+            S = attrs["max_seq_length"]
             appos = jnp.where(
                 meta.active & (meta.num_tokens > 0) & (meta.start_pos < S),
                 meta.start_pos, -1)
@@ -725,7 +771,7 @@ class TreeIncMultiHeadSelfAttention(OpImpl):
             ctx, attrs, k, v, meta.start_pos, meta.num_nodes, meta.active)
         # Tree mask as additive bias: committed prefix (s < start) is open by
         # default; within the tree region only ancestor-or-self is open.
-        S = k_ref.shape[-2]
+        S = attrs["max_seq_length"]
         T = x.shape[1]
         key_pos = jnp.arange(S)[None, None, :]
         committed = key_pos < meta.start_pos[:, None, None]        # [R,1,S]
@@ -746,9 +792,25 @@ class TreeIncMultiHeadSelfAttention(OpImpl):
         return [_project_out(attrs, params, ctx, out)]
 
 
+def move_kv(cache, src, dst_start, num, active, pack: int):
+    """``cache[.., r, :, dst_start[r] + i] = cache[.., r, :, src[r, i]]``
+    for ``i < num[r]`` on a packed cache, one layer ``[R, KH, ..]`` or the
+    stack ``[L, R, KH, ..]`` (the speculation commits): gather the sources
+    first, then the exact contiguous append of the gathered run."""
+    moved = jnp.swapaxes(kvl.gather_positions(cache, src, pack), -3, -2)
+
+    def write(c, m):                                # m [R, C, KH, D]
+        return append_kv_contiguous(c, None, m, dst_start, active,
+                                    num_tokens=num, pack=pack)
+
+    return write(cache, moved) if cache.ndim == 4 else jax.vmap(write)(
+        cache, moved)
+
+
 def commit_tree_kv(op_state: Dict[str, Any], src_node: jnp.ndarray,
                    num_commit: jnp.ndarray, start_pos: jnp.ndarray,
-                   active: jnp.ndarray) -> Dict[str, Any]:
+                   active: jnp.ndarray,
+                   max_seq: Optional[int] = None) -> Dict[str, Any]:
     """Compact accepted tree nodes into the committed cache region.
 
     For every KV-cache layer: cache[r, start+i] = cache[r, start+src_node[r,i]]
@@ -757,17 +819,22 @@ def commit_tree_kv(op_state: Dict[str, Any], src_node: jnp.ndarray,
     yet-unread source: src_node[i] >= i always, and we gather first anyway).
 
     Reference: commit_tokens_kernel (tree_inc_multihead_self_attention.cu:35)
-    driven by TreeVerifyBatchConfig::committed_tokens.
+    driven by TreeVerifyBatchConfig::committed_tokens. ``max_seq`` (static)
+    is the caches' length in positions, from which a packed cache's layout
+    is read (ops/kv_layout.py); None: position-major caches.
     """
 
     def commit_one(cache):                          # [R, KH, S, D]
         R = cache.shape[0]
-        S = cache.shape[2]
+        S = cache.shape[2] if max_seq is None else max_seq
+        pack = kvl.pack_of(cache, S)
         C = src_node.shape[1]
         rows = jnp.arange(R)[:, None]
         valid = (jnp.arange(C)[None, :] < num_commit[:, None]) & active[:, None]
         src = start_pos[:, None] + src_node
         src = jnp.clip(src, 0, S - 1)
+        if pack > 1:
+            return move_kv(cache, src, start_pos, num_commit, active, pack)
         moved = cache[rows, :, src]                                # [R,C,KH,D]
         dst = jnp.where(valid, start_pos[:, None] + jnp.arange(C)[None, :], S)
         return cache.at[rows, :, dst].set(moved, mode="drop")

@@ -1,0 +1,130 @@
+"""Who owns the KV cache's storage layout.
+
+A cache is stored the way its attention kernel reads it. A head dim that
+fills the 128 lanes (``pack == 1``) is stored position-major,
+``[.., S, D]``. A head dim of 64 that takes the packed flash path
+(kernels/attention.py ``_pack_factor``) is stored PACKED,
+``[.., S // pack, pack * D]``: position ``p`` lives in row ``p // pack``,
+lanes ``[(p % pack) * D, (p % pack + 1) * D)``, so the kernel's DMA slices
+are lane-full and no step reshapes the cache (a 64-wide minor dim is tiled
+half-empty on the chip, so the former ``[.., S, 64]`` -> ``[.., S/2, 128]``
+view was a relayout of the whole cache, in every layer of every step).
+
+The layout is chosen once, where the cache is allocated (``stored_pack``),
+from what the code observes there: the head dim, the cache length, whether
+the Pallas path is in use. Everybody else reads it back from the cache's
+shape against the op's ``max_seq_length`` (``pack_of``) and addresses
+positions through the functions here; this is the only place that knows
+the position <-> (row, lanes) arithmetic. With ``pack == 1`` each of them
+is the identity.
+"""
+
+from __future__ import annotations
+
+import jax.numpy as jnp
+
+from flexflow_tpu.kernels.attention import _pack_factor, supports_seq_len
+
+
+def stored_pack(Dp: int, max_seq: int, want_pallas: bool) -> int:
+    """Positions per stored cache row for a cache head dim ``Dp`` (as
+    ``ops/inc_attention.padded_head_dim`` gives it): the kernel's pack
+    factor where the packed flash path will serve this cache, else 1."""
+    if want_pallas and supports_seq_len(max_seq, Dp):
+        return _pack_factor(Dp)
+    return 1
+
+
+def cache_shape(R: int, KH: int, max_seq: int, Dp: int, pack: int):
+    return (R, KH, max_seq // pack, pack * Dp)
+
+
+def pack_of(cache, max_seq: int) -> int:
+    """The pack factor of a stored cache ``[.., rows, lanes]`` whose op
+    holds ``max_seq`` positions; its per-position width is then
+    ``cache.shape[-1] // pack``."""
+    pack, rem = divmod(max_seq, cache.shape[-2])
+    assert rem == 0 and pack >= 1, (cache.shape, max_seq)
+    return pack
+
+
+def row_of(pos, pack: int):
+    """The stored row of position ``pos``; its lanes start at
+    ``(pos - pack * row) * D``."""
+    return pos if pack == 1 else pos // pack
+
+
+def to_rows(x, pack: int):
+    """Position-major ``[.., C, D]`` (``C`` a multiple of ``pack``, from a
+    position that starts a row) -> stored rows ``[.., C // pack, pack * D]``.
+    For a run or a segment; nothing cache-sized goes through here on the
+    kernel path."""
+    if pack == 1:
+        return x
+    C, D = x.shape[-2:]
+    return x.reshape(x.shape[:-2] + (C // pack, pack * D))
+
+
+def to_positions(x, pack: int):
+    """Stored rows ``[.., W, pack * D]`` -> position-major
+    ``[.., W * pack, D]`` (numpy or jax). A whole layer comes through here
+    only on the jnp attention paths."""
+    if pack == 1:
+        return x
+    W, lanes = x.shape[-2:]
+    return x.reshape(x.shape[:-2] + (W * pack, lanes // pack))
+
+
+def read_positions(cache, a: int, b: int, pack: int, at=()):
+    """Positions ``[a, b)`` (static) of a stored cache as ``[.., b-a, D]``;
+    ``at`` indexes leading dims (a layer, a slot) in the same one slice, so
+    no more than the rows asked for is ever copied."""
+    r0 = a // pack
+    rows = cache[tuple(at) + (Ellipsis, slice(r0, -(-b // pack)),
+                              slice(None))]
+    return to_positions(rows, pack)[..., a - r0 * pack:b - r0 * pack, :]
+
+
+def gather_positions(cache, pos, pack: int):
+    """``cache [*lead, R, KH, rows, lanes]`` at per-row positions
+    ``pos [R, C]`` -> ``[*lead, R, KH, C, D]``."""
+    lead = (None,) * (cache.ndim - 4)
+    at = lead + (slice(None), None, slice(None), None)
+    got = jnp.take_along_axis(cache, row_of(pos, pack)[at], axis=-2)
+    if pack == 1:
+        return got
+    D = cache.shape[-1] // pack
+    half = (pos % pack)[at]
+    out = got[..., :D]
+    for h in range(1, pack):
+        out = jnp.where(half == h, got[..., h * D:(h + 1) * D], out)
+    return out
+
+
+def window(start, Q: int, rows: int, pack: int):
+    """The stored rows through which a run of ``Q`` positions from
+    ``start`` (traced, any parity, possibly past the cache's end) is
+    written: ``(row, W, off)`` with rows ``[row, row + W)`` inside the
+    cache and token ``t`` of the run in the window's column ``off + t``
+    (of ``W * pack``). A run that would pass the end is shifted right in a
+    window that ends with the cache, as far as it takes (``off`` then
+    exceeds ``pack - 1``); its tokens past the end fall outside."""
+    W = min((Q + 2 * pack - 2) // pack, rows)
+    row = jnp.clip(row_of(start, pack), 0, rows - W)
+    return row, W, start - (row if pack == 1 else pack * row)
+
+
+def merge_window(cur, run, keep, off, pack: int):
+    """A window of stored rows ``cur [.., KH, W, pack * D]`` with the run
+    ``run [KH, Q, D]`` laid in from column ``off`` wherever
+    ``keep [W * pack]`` (by window column) is set."""
+    C = cur.shape[-2] * pack
+    Q, D = run.shape[-2:]
+    if C > Q:
+        run = jnp.pad(run, ((0, 0), (0, C - Q), (0, 0)))
+    run = to_rows(jnp.roll(run, off, axis=1), pack)
+    if pack == 1:
+        keep = keep[None, :, None]
+    else:
+        keep = to_rows(jnp.broadcast_to(keep[:, None], (C, D)), pack)[None]
+    return jnp.where(keep, run[(None,) * (cur.ndim - 3)], cur)

@@ -76,7 +76,11 @@ class ShardingPolicy:
         return NamedSharding(self.mesh, P(*spec))
 
     def kv_cache_sharding(self, shape: Tuple[int, ...]) -> NamedSharding:
-        """KV-cache buffers [R, KH, S, D] (or stacked [L, R, KH, S, D]).
+        """KV-cache buffers [R, KH, S, D] (or stacked [L, R, KH, S, D]; a
+        D=64 cache on the packed flash path is stored [.., S/2, 128],
+        ops/kv_layout.py: the same rank, the same head axis, and a row
+        holds two consecutive positions, so both rules below hold as they
+        stand).
 
         Under tensor parallelism the KV-head dim (dim -3) splits over
         'model' when it divides — the same whole-head split as wk/wv

@@ -33,7 +33,9 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from flexflow_tpu.ops import kv_layout as kvl
 from flexflow_tpu.ops.base import OpContext
+from flexflow_tpu.ops.inc_attention import move_kv
 from flexflow_tpu.serve.batch_config import BatchMeta
 from flexflow_tpu.telemetry import get_telemetry
 
@@ -374,10 +376,14 @@ class MultiSpecEngine:
         st = llm_state["kv_cache"]
 
         def move(cache):                            # [L, R, KH, S, D]
-            L, R, KH, S, D = cache.shape
+            L, R, KH = cache.shape[:3]
+            S = self.llm.config.max_sequence_length
+            pack = kvl.pack_of(cache, S)
             i = jnp.arange(d)[None, :]              # committed index
             src = r_pos[:, None] + 1 + best_j[:, None] * d + i
             src = jnp.clip(src, 0, S - 1)
+            if pack > 1:        # stored packed: ops/kv_layout.py
+                return move_kv(cache, src, r_pos + 1, n_acc, active, pack)
             moved = jnp.take_along_axis(
                 cache, src[None, :, None, :, None], axis=3)  # [L,R,KH,d,D]
             valid = (i < n_acc[:, None]) & active[:, None]
@@ -1028,10 +1034,14 @@ class BeamSpecEngine:
         st = llm_state["kv_cache"]
 
         def move(cache):                            # [L, R, KH, S, D]
-            L, R, KH, S, D = cache.shape
+            L, R, KH = cache.shape[:3]
+            S = self.llm.config.max_sequence_length
+            pack = kvl.pack_of(cache, S)
             i = jnp.arange(d)[None, :]
             src = r_pos[:, None] + path
             src = jnp.clip(src, 0, S - 1)
+            if pack > 1:        # stored packed: ops/kv_layout.py
+                return move_kv(cache, src, r_pos + 1, n_acc, active, pack)
             moved = jnp.take_along_axis(
                 cache, src[None, :, None, :, None], axis=3)  # [L,R,KH,d,D]
             valid = (i < n_acc[:, None]) & active[:, None]

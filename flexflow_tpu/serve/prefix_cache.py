@@ -20,7 +20,12 @@ pool of finished prompts' KV:
   back into another slot, handling both op_state layouts
   (per-layer ``{"k_cache","v_cache"}`` of ``[R, KH, S, Dp]`` and the
   stacked ``op_state["kv_cache"] = {"k","v"}`` of ``[L, R, KH, S, Dp]``,
-  see ops/inc_attention.py). Segments are padded to a sublane multiple
+  see ops/inc_attention.py), and a cache stored packed
+  (``[.., S/2, 128]`` at D=64: ops/kv_layout.py, which reads the layout
+  from the cache's shape against the model's ``max_seq``). A segment is
+  position-major ``[.., P, Dp]`` on the host whatever the cache's layout;
+  ``_PAD`` is even, so a segment is whole stored rows. Segments are padded
+  to a sublane multiple
   of positions so the jitted installer compiles per LENGTH BUCKET, not
   per prefix length; the pad positions hold stale KV but sit beyond the
   slot's valid extent (``flash_attend`` masks ``s_ids < length``) and
@@ -50,6 +55,8 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+
+from flexflow_tpu.ops import kv_layout as kvl
 
 # position-count granularity for stored/installed segments (matches
 # kernels/attention.SUBLANE, imported lazily nowhere: the value is a
@@ -81,26 +88,29 @@ def _kv_slots(op_state) -> List[Tuple[str, str, str, bool]]:
     return out
 
 
-def extract_prefix_kv(op_state, slot: int, length: int) -> Optional[Dict]:
+def extract_prefix_kv(op_state, slot: int, length: int,
+                      max_seq: int) -> Optional[Dict]:
     """Copy the first ``length`` positions of ``slot``'s KV to host numpy,
-    padded up to a ``_PAD`` multiple of positions. Returns None when the
+    position-major, padded up to a ``_PAD`` multiple of positions.
+    ``max_seq`` is the caches' length in positions. Returns None when the
     cache is too short to hold the padded segment."""
     P = _round_up(length)
+    if P > max_seq:
+        return None
     segs: Dict[str, Dict[str, np.ndarray]] = {}
     for name, kk, vk, stacked in _kv_slots(op_state):
-        k, v = op_state[name][kk], op_state[name][vk]
-        if P > k.shape[-2]:
-            return None
-        if stacked:      # [L, R, KH, S, Dp]
-            segs[name] = {"k": np.asarray(k[:, slot, :, :P, :]),
-                          "v": np.asarray(v[:, slot, :, :P, :])}
-        else:            # [R, KH, S, Dp]
-            segs[name] = {"k": np.asarray(k[slot, :, :P, :]),
-                          "v": np.asarray(v[slot, :, :P, :])}
+        at = (slice(None), slot) if stacked else (slot,)
+        # [L, R, KH, S, Dp] or [R, KH, S, Dp], as stored
+        segs[name] = {
+            c: np.asarray(kvl.read_positions(
+                cache, 0, P, kvl.pack_of(cache, max_seq), at))
+            for c, cache in (("k", op_state[name][kk]),
+                             ("v", op_state[name][vk]))}
     return segs or None
 
 
-def prefix_compatible(op_state, segs: Dict, length: int) -> bool:
+def prefix_compatible(op_state, segs: Dict, length: int,
+                      max_seq: int) -> bool:
     """True when ``segs`` (one model's stored segment dict) can be
     installed into ``op_state`` for ``length`` shared tokens — every KV
     cache present, geometry matching, padded length within the cache."""
@@ -113,10 +123,11 @@ def prefix_compatible(op_state, segs: Dict, length: int) -> bool:
         if seg is None:
             return False
         cache, k = op_state[name][kk], seg["k"]
-        if P > cache.shape[-2] or k.shape[-2] < P:
+        if P > max_seq or k.shape[-2] < P:
             return False
-        want = ((cache.shape[0], cache.shape[2], cache.shape[4])
-                if stacked else (cache.shape[1], cache.shape[3]))
+        Dp = cache.shape[-1] // kvl.pack_of(cache, max_seq)
+        want = ((cache.shape[0], cache.shape[2], Dp)
+                if stacked else (cache.shape[1], Dp))
         got = ((k.shape[0], k.shape[1], k.shape[3])
                if stacked else (k.shape[0], k.shape[2]))
         if want != got:
@@ -124,16 +135,18 @@ def prefix_compatible(op_state, segs: Dict, length: int) -> bool:
     return True
 
 
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _install_fn(op_state, segs, slot):
+@functools.partial(jax.jit, donate_argnums=(0,), static_argnums=(3,))
+def _install_fn(op_state, segs, slot, max_seq):
     out = dict(op_state)
     for name, kk, vk, stacked in _kv_slots(op_state):
         seg = segs.get(name)
         if seg is None:
             continue
         k_cache, v_cache = op_state[name][kk], op_state[name][vk]
-        k = seg["k"].astype(k_cache.dtype)
-        v = seg["v"].astype(v_cache.dtype)
+        # a prefix starts at position 0, so it is whole stored rows
+        pack = kvl.pack_of(k_cache, max_seq)
+        k = kvl.to_rows(seg["k"].astype(k_cache.dtype), pack)
+        v = kvl.to_rows(seg["v"].astype(v_cache.dtype), pack)
         if stacked:      # seg [L, KH, P, Dp] -> cache [L, R, KH, S, Dp]
             kc = jax.lax.dynamic_update_slice(
                 k_cache, k[:, None], (0, slot, 0, 0, 0))
@@ -148,7 +161,8 @@ def _install_fn(op_state, segs, slot):
     return out
 
 
-def install_prefix_kv(op_state, slot: int, segs: Dict, length: int):
+def install_prefix_kv(op_state, slot: int, segs: Dict, length: int,
+                      max_seq: int):
     """Write the first ``length`` shared positions of a stored segment
     into ``slot``, returning the new (donated-in) op_state. One fused
     dynamic_update_slice per cache; compiles per length BUCKET (``_PAD``
@@ -157,7 +171,7 @@ def install_prefix_kv(op_state, slot: int, segs: Dict, length: int):
     P = _round_up(length)
     cut = {name: {"k": s["k"][..., :P, :], "v": s["v"][..., :P, :]}
            for name, s in segs.items()}
-    return _install_fn(op_state, cut, jnp.int32(slot))
+    return _install_fn(op_state, cut, jnp.int32(slot), max_seq)
 
 
 # ----------------------------------------------------------------------

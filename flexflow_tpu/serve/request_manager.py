@@ -180,7 +180,8 @@ class RequestManager:
         self.preempt_enabled = True
         self.preempt_risk = 0.5
         self.max_spec_depth = MAX_BEAM_DEPTH
-        self._commit = jax.jit(commit_tree_kv, donate_argnums=(0,))
+        self._commit = jax.jit(commit_tree_kv, donate_argnums=(0,),
+                               static_argnames=("max_seq",))
         self.output_filepath: Optional[str] = None
         # explicit ServingTelemetry, or None -> the process-global one
         # (resolved per loop iteration, so enabling mid-session attaches)
@@ -544,11 +545,12 @@ class RequestManager:
                 continue
             for key, ifm in pairs:
                 segs = entry.segments.get(key)
+                max_seq = ifm.model.config.max_sequence_length
                 if segs is None or not pcm.prefix_compatible(
-                        ifm.model.op_state, segs, n):
+                        ifm.model.op_state, segs, n, max_seq):
                     continue    # this model prefills the prefix cold
                 ifm.model.op_state = pcm.install_prefix_kv(
-                    ifm.model.op_state, req.slot, segs, n)
+                    ifm.model.op_state, req.slot, segs, n, max_seq)
                 if key == "llm":
                     req.cache_depth = n
                 else:
@@ -574,8 +576,9 @@ class RequestManager:
                      else req.ssm_cache_depth.get(int(key[3:]), 0))
             if depth < len(prompt):
                 continue
-            segs = pcm.extract_prefix_kv(ifm.model.op_state, req.slot,
-                                         len(prompt))
+            segs = pcm.extract_prefix_kv(
+                ifm.model.op_state, req.slot, len(prompt),
+                ifm.model.config.max_sequence_length)
             if segs is not None:
                 segments[key] = segs
         if "llm" not in segments:
@@ -1860,7 +1863,7 @@ class RequestManager:
             llm.op_state = self._commit(
                 llm.op_state, jax.numpy.asarray(src_node),
                 jax.numpy.asarray(ncommit), jax.numpy.asarray(start + 1),
-                jax.numpy.asarray(act))
+                jax.numpy.asarray(act), max_seq=max_seq)
 
 
 _request_manager: Optional[RequestManager] = None

@@ -10,8 +10,21 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from flexflow_tpu.kernels.attention import (NEG_INF, flash_attend,
-                                            reference_attend)
+from flexflow_tpu.kernels import attention as fa
+from flexflow_tpu.kernels.attention import NEG_INF, reference_attend
+from flexflow_tpu.ops import kv_layout as kvl
+
+
+def flash_attend(q, k, v, *args, **kw):
+    """``kernels.attention.flash_attend`` on position-major test caches
+    ``[.., S, D]``: handed over in the stored layout (packed at D=64,
+    ops/kv_layout.py), and a fused append's caches unpacked again."""
+    pack = fa._pack_factor(q.shape[-1])
+    out = fa.flash_attend(q, kvl.to_rows(k, pack), kvl.to_rows(v, pack),
+                          *args, **kw)
+    if kw.get("append_kv") is None:
+        return out
+    return (out[0],) + tuple(kvl.to_positions(c, pack) for c in out[1:])
 
 
 def _mk(R, Q, H, KH, D, S, dtype=jnp.float32, seed=0):
@@ -141,8 +154,10 @@ def test_head_dim_64_takes_flash_path_and_matches_jnp(monkeypatch):
         m = ff.FFModel(cfg)
         create_llama_model(m, tiny, mode=InferenceMode.INC_DECODING_MODE)
         m.compile(comp_mode=ff.CompMode.COMP_MODE_INFERENCE)
-        # the packed flash path needs NO head-dim padding: cache stays D=64
-        assert m.op_state["kv_cache"]["k"].shape[-1] == 64
+        # the packed flash path needs NO head-dim padding, and the cache
+        # is stored as the kernel reads it: two positions to a 128-lane row
+        want = (256 // 2, 128) if ffk.use_pallas() else (256, 64)
+        assert m.op_state["kv_cache"]["k"].shape[-2:] == want
         rm = RequestManager()
         rm.register_new_request([5, 9, 23], max_new_tokens=6)
         return [r.output_tokens for r in rm.generate_incr_decoding(m)]
@@ -226,7 +241,8 @@ def test_fallback_is_recorded_and_warned(monkeypatch):
 
     monkeypatch.setenv("FF_PALLAS_INTERPRET", "1")
     ffk.reset_dispatch_stats()
-    attrs = dict(head_dim=16, num_q_heads=2, num_kv_heads=2)
+    attrs = dict(head_dim=16, num_q_heads=2, num_kv_heads=2,
+                 max_seq_length=100)
     q = jnp.zeros((2, 1, 2, 16))
     k = jnp.zeros((2, 2, 100, 16))   # S=100: not tileable
     lengths = jnp.asarray([1, 1], jnp.int32)
@@ -390,7 +406,7 @@ def test_flash_without_row_map_keeps_its_kernel_arguments():
 
     def call(rows):
         jaxpr = jax.make_jaxpr(
-            lambda *a: flash_attend.__wrapped__(*a, rows=rows))(
+            lambda *a: fa.flash_attend.__wrapped__(*a, rows=rows))(
                 q, k, v, lengths, qpos)
         (eqn,) = [e for e in jaxpr.jaxpr.eqns
                   if e.primitive.name == "pallas_call"]

@@ -142,7 +142,7 @@ def test_kv_segment_roundtrip_both_layouts():
            "kv_cache": {"k": jnp.asarray(src_sk),
                         "v": mk((L, R, KH, S, D))},
            "other": 3}                              # non-KV state ignored
-    segs = pcm.extract_prefix_kv(src, slot=0, length=5)
+    segs = pcm.extract_prefix_kv(src, slot=0, length=5, max_seq=S)
     P = 8                                           # padded to _PAD bucket
     assert set(segs) == {"attn0", "kv_cache"}
     assert segs["attn0"]["k"].shape == (KH, P, D)
@@ -156,8 +156,8 @@ def test_kv_segment_roundtrip_both_layouts():
                      "v_cache": jnp.zeros((R, KH, S, D), jnp.float32)},
            "kv_cache": {"k": jnp.zeros((L, R, KH, S, D), jnp.float32),
                         "v": jnp.zeros((L, R, KH, S, D), jnp.float32)}}
-    assert pcm.prefix_compatible(dst, segs, 5)
-    out = pcm.install_prefix_kv(dst, 1, segs, 5)
+    assert pcm.prefix_compatible(dst, segs, 5, S)
+    out = pcm.install_prefix_kv(dst, 1, segs, 5, S)
     np.testing.assert_array_equal(np.asarray(out["attn0"]["k_cache"])[1, :, :P],
                                   src_ak[0, :, :P])
     np.testing.assert_array_equal(
@@ -167,9 +167,9 @@ def test_kv_segment_roundtrip_both_layouts():
     # geometry mismatches refuse loudly instead of corrupting
     bad = {"attn0": {"k_cache": jnp.zeros((R, KH + 1, S, D), jnp.float32),
                      "v_cache": jnp.zeros((R, KH + 1, S, D), jnp.float32)}}
-    assert not pcm.prefix_compatible(bad, segs, 5)
-    assert not pcm.prefix_compatible(dst, segs, S)  # seg holds only 8 pos
-    assert pcm.extract_prefix_kv(dst, 0, S + 1) is None
+    assert not pcm.prefix_compatible(bad, segs, 5, S)
+    assert not pcm.prefix_compatible(dst, segs, S, S)  # seg holds only 8 pos
+    assert pcm.extract_prefix_kv(dst, 0, S + 1, S) is None
 
 
 # ---------------------------------------------------------------------------

@@ -218,6 +218,7 @@ def check(name: str, rehearse: bool) -> dict:
     from benchmark.families import _common as C
     from flexflow_tpu.ffconst import InferenceMode
     from flexflow_tpu.models import FAMILIES
+    from flexflow_tpu.ops import kv_layout as kvl
     from flexflow_tpu.serve.request_manager import RequestManager as RM
 
     with open(os.path.join(ROOT, "benchmark", "configs", f"{name}.json")) as f:
@@ -245,8 +246,12 @@ def check(name: str, rehearse: bool) -> dict:
         model.op_state = jax.tree.map(jnp.zeros_like, model.op_state)
         steps = prefill(model, fill, prompts, compact, routes)
         st = model.op_state["kv_cache"]
-        kv = {(c, slot): np.asarray(st[c][:, slot, :, :len(toks) - 1],
-                                    np.float32)     # [L, KH, written, D]
+        # the cache as stored (packed at D=64): the layout's owner reads it
+        pack = kvl.pack_of(st["k"], model.config.max_sequence_length)
+        kv = {(c, slot): np.asarray(
+                  kvl.read_positions(st[c], 0, len(toks) - 1, pack,
+                                     at=(slice(None), slot)),
+                  np.float32)                       # [L, KH, written, D]
               for c in ("k", "v") for slot, toks in prompts}
         got[compact] = steps, kv, decode(model, one, prompts, routes,
                                          record=not compact)
