@@ -297,8 +297,17 @@ class FFModel:
                            apply_rotary_embedding: bool, scaling_query: bool,
                            scaling_factor: float, qk_prod_scaling: bool,
                            position_bias: bool, rope_theta: float,
-                           name, qk_norm_eps: Optional[float] = None
+                           name, qk_norm_eps: Optional[float] = None,
+                           qk_norm_per_head: bool = False,
+                           sliding_window: Optional[int] = None
                            ) -> Tensor:
+        if sliding_window is not None \
+                and op_type != OpType.INC_MULTIHEAD_SELF_ATTENTION:
+            raise NotImplementedError(
+                "a windowed attention layer is served by incremental "
+                f"decoding only, not as {op_type.name}: tree verification "
+                "and beam drafting stage and move cache positions that a "
+                "ring (ops/kv_layout.py) does not keep")
         if add_bias_kv or add_zero_attn:
             raise NotImplementedError(
                 "add_bias_kv/add_zero_attn are not supported by the serving "
@@ -321,7 +330,13 @@ class FFModel:
             cache_dtype=self.config.kv_cache_dtype,
             # only a model that has the step carries the key, so every
             # other model's attrs (and program) stay what they were
-            **({} if qk_norm_eps is None else {"qk_norm_eps": qk_norm_eps})),
+            **({} if qk_norm_eps is None else {"qk_norm_eps": qk_norm_eps}),
+            **({"qk_norm_per_head": True} if qk_norm_per_head else {}),
+            # a windowed layer: its ring holds the window plus the most
+            # one step appends to a slot, the batch's token budget
+            **({} if sliding_window is None else
+               {"sliding_window": int(sliding_window),
+                "max_step_tokens": self.config.max_tokens_per_batch})),
             name)
 
     def inc_multihead_self_attention(self, input: Tensor, embed_dim: int,
@@ -339,13 +354,20 @@ class FFModel:
             scaling_factor: float = 1.0, qk_prod_scaling: bool = True,
             position_bias: bool = False, rope_theta: float = 10000.0,
             name: Optional[str] = None,
-            qk_norm_eps: Optional[float] = None) -> Tensor:
+            qk_norm_eps: Optional[float] = None,
+            qk_norm_per_head: bool = False,
+            sliding_window: Optional[int] = None) -> Tensor:
+        """``qk_norm_eps``: RMS-normalise q and k, over the whole projection
+        or (``qk_norm_per_head``) over each head. ``sliding_window``: a
+        query sees the last that many positions, and the layer keeps a ring
+        of them instead of ``max_sequence_length`` (ops/kv_layout.py)."""
         return self._serving_attention(
             OpType.INC_MULTIHEAD_SELF_ATTENTION, input, embed_dim, num_q_heads,
             num_kv_heads, kdim, vdim, dropout, bias, add_bias_kv,
             add_zero_attn, data_type, kernel_initializer,
             apply_rotary_embedding, scaling_query, scaling_factor,
-            qk_prod_scaling, position_bias, rope_theta, name, qk_norm_eps)
+            qk_prod_scaling, position_bias, rope_theta, name, qk_norm_eps,
+            qk_norm_per_head, sliding_window)
 
     def spec_inc_multihead_self_attention(self, input: Tensor, embed_dim: int,
                                           num_heads: int, **kw) -> Tensor:
@@ -547,12 +569,15 @@ class FFModel:
 
     def parameter(self, dims: Sequence[int],
                   dtype: DataType = DataType.DT_FLOAT, init: float = 1.0,
-                  name=None):
+                  name=None, initializer=None):
         """Free-standing trainable parameter (reference PCG Weight node) —
-        e.g. a bare nn.Parameter read in a traced torch module."""
-        return self._add_layer(OpType.WEIGHT, [],
-                               dict(shape=list(dims), dtype=dtype.value,
-                                    init=init), name)
+        e.g. a bare nn.Parameter read in a traced torch module. Starts at
+        the constant ``init``, or as ``initializer`` makes it."""
+        return self._add_layer(
+            OpType.WEIGHT, [],
+            dict(shape=list(dims), dtype=dtype.value, init=init,
+                 **({} if initializer is None
+                    else {"initializer": initializer})), name)
 
     def where(self, cond: Tensor, x: Tensor, y: Tensor, name=None):
         return self._add_layer(OpType.WHERE, [cond, x, y], {}, name)
@@ -630,14 +655,24 @@ class FFModel:
 
     def moe_experts(self, input: Tensor, indices: Tensor, weights: Tensor,
                     num_experts: int, expert_width: int,
-                    data_type: Optional[DataType] = None, name=None):
+                    data_type: Optional[DataType] = None, name=None,
+                    held: Optional[Tuple[int, int]] = None):
         """The serving path's routed SwiGLU experts (ops/moe.MoeExperts):
         dropless, over the step's real tokens, through the grouped kernel.
-        ``indices``/``weights`` are the router's top-k."""
+        ``indices``/``weights`` are the router's top-k over ``num_experts``.
+        ``held`` ``(first, count)``: this chip's share of an expert-parallel
+        layer. It holds experts ``[first, first + count)`` of the router's
+        ``num_experts`` and computes their part of the result; a pair routed
+        elsewhere is no work here. Without it the layer holds them all."""
+        attrs = dict(num_experts=num_experts, expert_width=expert_width,
+                     data_type=data_type)
+        if held is not None and tuple(held) != (0, num_experts):
+            first, count = held
+            assert 0 <= first and first + count <= num_experts, held
+            attrs.update(num_experts=count, router_width=num_experts,
+                         first_expert=first)
         return self._add_layer(OpType.MOE_EXPERTS, [input, indices, weights],
-                               dict(num_experts=num_experts,
-                                    expert_width=expert_width,
-                                    data_type=data_type), name)
+                               attrs, name)
 
     def cache(self, input: Tensor, num_batches: int = 1, name=None):
         """Cross-batch activation cache with staleness score (reference
@@ -831,6 +866,10 @@ class FFModel:
         # --- parameter + op-state init ---
         key = jax.random.PRNGKey(self.config.seed)
         params: Dict[str, Dict[str, jnp.ndarray]] = {}
+        quantize = bool(self.config.quantization_type
+                        and comp_mode == CompMode.COMP_MODE_INFERENCE)
+        if quantize:
+            from flexflow_tpu.quant import quantize_params
         for layer in self.layers:
             if not layer.weights:
                 continue
@@ -847,14 +886,22 @@ class FFModel:
                 sharding = self.policy.weight_sharding(
                     w.shape, wdims, w.shard_multiples)
                 lp[w.name] = jax.device_put(arr, sharding)
-            if (self.config.quantization_type
-                    and comp_mode == CompMode.COMP_MODE_INFERENCE):
+                if quantize and "router_width" in layer.attrs:
+                    # a chip's share of an expert-parallel layer fills the
+                    # chip: one weight at a time, and wait for it. The
+                    # host runs ahead of the device, and every temporary
+                    # of the unfused quantisation of every weight it has
+                    # queued is allocated at once (seen on the chip: a
+                    # 3.1 GB cut peaked at 12.5 GB, 13.0 GB at all 16.27)
+                    lp[w.name] = jax.block_until_ready(quantize_params(
+                        {layer.name: {w.name: lp[w.name]}},
+                        self.config.quantization_type)[layer.name][w.name])
+            if quantize:
                 # quantize each layer as it is initialized (the reference
                 # also compresses at load time, per tensor) — peak HBM
                 # holds ONE full-precision layer, so a 7B-class model can
                 # be built int8/int4 on a chip its bf16 form wouldn't fit
-                from flexflow_tpu.quant import quantize_params
-
+                # (a weight quantized above is left as it is)
                 lp = quantize_params({layer.name: lp},
                                      self.config.quantization_type
                                      )[layer.name]
@@ -869,6 +916,13 @@ class FFModel:
                 self.op_state[layer.name] = impl.init_state(layer.attrs,
                                                             input_specs)
         self._consolidate_kv_caches()
+        split = {a: n for a, n in self.mesh.shape.items()
+                 if a != "data" and n > 1}
+        if split:
+            from flexflow_tpu.ops.inc_attention import refuse_windowed
+
+            refuse_windowed(self.op_state,
+                            f"a mesh that divides a model ({split})")
         from flexflow_tpu.ops.moe import init_counters
 
         init_counters(self)     # routed-expert layers, telemetry on
@@ -1078,28 +1132,61 @@ class FFModel:
         Cuts the per-call donated-buffer count from 2*num_layers to 2 and
         lets the speculative tree commit vectorize over layers. Layers get
         attrs["cache_layer_idx"]; see ops/inc_attention.py read_kv/write_kv.
+        A model with windowed layers has one pair of stacks a kind: the
+        full caches as ever, the rings under inc_attention.WINDOW_STACK
+        (those layers carry attrs["cache_stack"] too).
         """
+        from flexflow_tpu.ops.inc_attention import FULL_STACK, WINDOW_STACK
+
         names = [n for n, st in self.op_state.items()
                  if isinstance(st, dict) and "k_cache" in st]
-        if len(names) < 2:
-            return
-        shapes = {self.op_state[n]["k_cache"].shape for n in names}
-        dtypes = {self.op_state[n]["k_cache"].dtype for n in names}
-        if len(shapes) != 1 or len(dtypes) != 1:
-            return  # heterogeneous caches keep the per-layer layout
         by_name = {layer.name: layer for layer in self.layers}
-        for i, n in enumerate(names):
-            by_name[n].attrs["cache_layer_idx"] = i
-        # A cache starts zeroed, so allocate the stacks instead of copying
-        # the per-layer buffers into them, and free those first: a
-        # jnp.stack holds both generations at once, which a cache sized
-        # to fill the chip beside the weights cannot afford (seen on the
-        # chip: 7B int8 + 8 x 1024 slots, 2 GiB short of 16).
-        for n in names:
-            del self.op_state[n]
-        shape, dtype = (len(names),) + shapes.pop(), dtypes.pop()
-        self.op_state["kv_cache"] = {"k": jnp.zeros(shape, dtype),
-                                     "v": jnp.zeros(shape, dtype)}
+        rings = [n for n in names
+                 if by_name[n].attrs.get("sliding_window") is not None]
+        if rings:
+            kinds = {FULL_STACK: [n for n in names if n not in rings],
+                     WINDOW_STACK: rings}
+        elif len(names) < 2:
+            return
+        else:
+            kinds = {FULL_STACK: names}
+        if rings:
+            # what telemetry says of the two kinds (ffsv_kv_cache_bytes,
+            # ffsv_attn_positions_read_total): only such a model has it
+            (window,) = {by_name[n].attrs["sliding_window"] for n in rings}
+            self.attention_kinds = {
+                kind: {"layers": len(kinds[key]), "window": w,
+                       "cache_bytes": sum(
+                           2 * self.op_state[n]["k_cache"].nbytes
+                           for n in kinds[key])}
+                for kind, key, w in (("full", FULL_STACK, None),
+                                     ("window", WINDOW_STACK, window))}
+        for key, names in kinds.items():    # (one kind unless ``rings``)
+            if not names:           # every layer of a model may be windowed
+                continue
+            shapes = {self.op_state[n]["k_cache"].shape for n in names}
+            dtypes = {self.op_state[n]["k_cache"].dtype for n in names}
+            if len(shapes) != 1 or len(dtypes) != 1:
+                if rings:
+                    raise NotImplementedError(
+                        "windowed layers beside full ones need one cache "
+                        f"shape a kind; {key} has {sorted(shapes)}")
+                return  # heterogeneous caches keep the per-layer layout
+            for i, n in enumerate(names):
+                by_name[n].attrs["cache_layer_idx"] = i
+                if key != FULL_STACK:
+                    by_name[n].attrs["cache_stack"] = key
+            # A cache starts zeroed, so allocate the stacks instead of
+            # copying the per-layer buffers into them, and free those
+            # first: a jnp.stack holds both generations at once, which a
+            # cache sized to fill the chip beside the weights cannot afford
+            # (seen on the chip: 7B int8 + 8 x 1024 slots, 2 GiB short of
+            # 16).
+            for n in names:
+                del self.op_state[n]
+            shape, dtype = (len(names),) + shapes.pop(), dtypes.pop()
+            self.op_state[key] = {"k": jnp.zeros(shape, dtype),
+                                  "v": jnp.zeros(shape, dtype)}
 
     # ==================================================================
     # Training verbs (reference model.cc:2784/2807/2838 + fit)

@@ -151,12 +151,35 @@ def _append_kernel(len_ref, appos_ref,     # scalar prefetch: [R] int32 each
                    PACK=PACK, D=D)
 
 
+def _window_kernel(len_ref, first_ref, *refs, mode, **static):
+    """A windowed layer's variant of the three kernels above (``mode``
+    None, "rows" or "append"): one more scalar-prefetched operand,
+    ``first_ref`` [R], the first cache block a row's window touches. The
+    cache is a ring of whole blocks (ops/kv_layout.py): the stream starts
+    at that block and block ``b`` is read from ring block ``b % n``."""
+    appos_ref = rows_ref = knew_ref = vnew_ref = asem = None
+    if mode == "append":
+        (appos_ref, q_ref, qp_ref, slopes_ref, knew_ref, vnew_ref, bias_hbm,
+         _, _, o_ref, k_hbm, v_hbm, acc, m, l, kbuf, vbuf, bbuf, sem,
+         asem) = refs
+    else:
+        if mode == "rows":
+            rows_ref, *refs = refs
+        (q_ref, qp_ref, slopes_ref, bias_hbm, k_hbm, v_hbm, o_ref, acc, m, l,
+         kbuf, vbuf, bbuf, sem) = refs
+    _stream_attend(len_ref, appos_ref, q_ref, qp_ref, slopes_ref, knew_ref,
+                   vnew_ref, bias_hbm, k_hbm, v_hbm, o_ref, acc, m, l, kbuf,
+                   vbuf, bbuf, sem, asem, rows_ref=rows_ref,
+                   first_ref=first_ref, **static)
+
+
 def _stream_attend(len_ref, appos_ref, q_ref, qp_ref, slopes_ref, knew_ref,
                    vnew_ref, bias_hbm, k_hbm, v_hbm, o_ref,
                    acc, m, l, kbuf, vbuf, bbuf, sem, asem,
                    *, BS: int, causal: bool, has_bias: bool,
                    has_alibi: bool, qk_scale: float, G: int, Q: int,
-                   layer_idx, PACK: int, D: int, rows_ref=None):
+                   layer_idx, PACK: int, D: int, rows_ref=None,
+                   first_ref=None, window=None):
     """Shared stream-attend body.
 
     PACK == 1: one position per 128-lane cache row (D % 128 == 0).
@@ -167,6 +190,11 @@ def _stream_attend(len_ref, appos_ref, q_ref, qp_ref, slopes_ref, knew_ref,
     slicing, v is lane-masked with a select, and the [KH, GQ, LANE]
     accumulator's halves are summed OUTSIDE the kernel — no in-kernel
     relayout anywhere.
+
+    ``window`` (with ``first_ref``; PACK == 1): a query at position i sees
+    keys ``i - window < j <= i``, masked by ABSOLUTE position; program r
+    streams blocks ``first[r] .. ceil(length / BS)`` of the positions and
+    reads block b from the ring's block ``b % (ring rows / SB)``.
     """
     has_append = appos_ref is not None
     r = pl.program_id(0)
@@ -174,8 +202,11 @@ def _stream_attend(len_ref, appos_ref, q_ref, qp_ref, slopes_ref, knew_ref,
     length = len_ref[r]
     SB = BS // PACK                       # packed rows per block
 
-    def nb_of(j):
-        return (len_ref[j] + jnp.asarray(BS - 1, jnp.int32)) // BS
+    def nb_of(j):                         # blocks program j streams
+        nb = (len_ref[j] + jnp.asarray(BS - 1, jnp.int32)) // BS
+        if first_ref is not None:
+            nb = jnp.maximum(nb - first_ref[j], 0)
+        return nb
 
     def row_of(j):                        # the cache row program j streams
         return j if rows_ref is None else rows_ref[j]
@@ -191,6 +222,12 @@ def _stream_attend(len_ref, appos_ref, q_ref, qp_ref, slopes_ref, knew_ref,
     if layer_idx is not None:
         k_hbm = k_hbm.at[layer_idx]
         v_hbm = v_hbm.at[layer_idx]
+    ring_blocks = k_hbm.shape[-2] // SB
+
+    def src(j, i):                        # cache block of program j's i-th
+        if first_ref is None:
+            return i
+        return (first_ref[j] + i) % ring_blocks
 
     # Cross-program DMA pipeline: the R grid programs run sequentially on
     # one core, so each program's FIRST block fetch is started by its
@@ -241,14 +278,20 @@ def _stream_attend(len_ref, appos_ref, q_ref, qp_ref, slopes_ref, knew_ref,
 
     @pl.when((nb > 0) & jnp.logical_not(prev_live))
     def _():                              # first live program self-starts
-        start_dmas(row_of(r), g0 % 2, 0)
+        start_dmas(row_of(r), g0 % 2, src(r, 0))
 
     GQ = q_ref.shape[-2]
     qp = qp_ref[r]                                  # [GQ] absolute positions
     if has_append:
         p_app = appos_ref[r]
         bp = p_app // BS                  # block holding the new position
-        pr = p_app // PACK                # its global packed row
+        p_row = pr = p_app // PACK        # its global packed row
+        if first_ref is not None:         # as the stream counts and stores
+            bp = bp - first_ref[r]
+            pr = pr % k_hbm.shape[-2]
+
+    def app_row():                        # the new row within its block
+        return pr - bp * SB if first_ref is None else pr % SB
 
     def body(i, _):
         slot = (g0 + i) % 2
@@ -256,13 +299,13 @@ def _stream_attend(len_ref, appos_ref, q_ref, qp_ref, slopes_ref, knew_ref,
 
         @pl.when(i + 1 < nb)
         def _():
-            start_dmas(row_of(r), nxt_slot, i + 1)
+            start_dmas(row_of(r), nxt_slot, src(r, i + 1))
 
         @pl.when((i + 1 == nb) & (r_next < R))
         def _():                          # hand off to the next live row
-            start_dmas(row_of(r_next), nxt_slot, 0)
+            start_dmas(row_of(r_next), nxt_slot, src(r_next, 0))
 
-        wait_dmas(row_of(r), slot, i)
+        wait_dmas(row_of(r), slot, src(r, i))
         if has_append:
             @pl.when(i == bp)
             def _():
@@ -270,8 +313,8 @@ def _stream_attend(len_ref, appos_ref, q_ref, qp_ref, slopes_ref, knew_ref,
                 # (bitwise-identical to appending before the stream), and
                 # write back the aligned 8-packed-row window it lives in
                 KH = kbuf.shape[1]
-                pm_row = pr - bp * SB     # packed row within the block
-                hm = p_app - pr * PACK    # lane half within the row
+                pm_row = app_row()        # packed row within the block
+                hm = p_app - p_row * PACK  # lane half within the row
                 sub = jax.lax.broadcasted_iota(
                     jnp.int32, (KH, SB, LANE if PACK > 1 else D), 1)
                 lane = jax.lax.broadcasted_iota(
@@ -303,7 +346,8 @@ def _stream_attend(len_ref, appos_ref, q_ref, qp_ref, slopes_ref, knew_ref,
                 dimension_numbers=(((2,), (2,)), ((0,), (0,))),
                 preferred_element_type=jnp.float32)     # [KH, GQ, SB]
             s = s * qk_scale
-            s_ids = (i * BS + h
+            b_abs = i if first_ref is None else first_ref[r] + i
+            s_ids = (b_abs * BS + h
                      + PACK * jax.lax.broadcasted_iota(jnp.int32, (GQ, SB),
                                                        1))
             if has_alibi:
@@ -317,6 +361,8 @@ def _stream_attend(len_ref, appos_ref, q_ref, qp_ref, slopes_ref, knew_ref,
             else:
                 visible = jnp.ones((GQ, SB), dtype=bool)
             visible = visible & (s_ids < length)
+            if window is not None:
+                visible = visible & (s_ids > qp[:, None] - window)
             s = jnp.where(visible[None], s, NEG_INF)
 
             m_new = jnp.maximum(m[:], jnp.max(s, axis=-1, keepdims=True))
@@ -344,7 +390,7 @@ def _stream_attend(len_ref, appos_ref, q_ref, qp_ref, slopes_ref, knew_ref,
                 # the write-back must land before this program ends (the
                 # buffer slot is reused two global blocks later, and the
                 # next layer's kernel reads the region through the alias)
-                pm_row = pr - bp * SB
+                pm_row = app_row()
                 wo = (pm_row // SUBLANE) * SUBLANE
                 pb_abs = (pr // SUBLANE) * SUBLANE
                 pltpu.make_async_copy(
@@ -364,11 +410,11 @@ def _stream_attend(len_ref, appos_ref, q_ref, qp_ref, slopes_ref, knew_ref,
 @functools.partial(
     jax.jit,
     static_argnames=("causal", "qk_scale", "interpret", "out_dtype",
-                     "layer_idx"))
+                     "layer_idx", "window"))
 def flash_attend(q, k_cache, v_cache, lengths, qpos, bias=None,
                  alibi=None, append_kv=None, rows=None, *, causal=True,
                  qk_scale=None, out_dtype=None, layer_idx=None,
-                 interpret=False):
+                 interpret=False, window=None):
     """Batched KV-cache attention.
 
     q        [R, Q, H, D]   new-token queries (rotary already applied)
@@ -397,6 +443,16 @@ def flash_attend(q, k_cache, v_cache, lengths, qpos, bias=None,
                             rows, and a row may be named twice); without it
                             the kernel is the unmapped one, argument for
                             argument. Not with append_kv.
+    window   int (static)   a windowed layer: query i sees keys
+                            ``i - window < j <= i``. The cache is then a
+                            RING of ``S`` rows, whole blocks, position p in
+                            row ``p % S`` (ops/kv_layout.py; D fills the
+                            lanes), that holds each row's last positions up
+                            to ``lengths`` (absolute, as ``qpos``): at least
+                            from the window of its first query on. Only the
+                            blocks a row's window touches are streamed, and
+                            the device operation is ``flash_attend_window``.
+                            Without it the kernels are what they were.
     returns  [R, Q, H*D], or (out, k_cache, v_cache) with append_kv
     """
     assert rows is None or append_kv is None, "no fused append by row map"
@@ -443,8 +499,16 @@ def flash_attend(q, k_cache, v_cache, lengths, qpos, bias=None,
         bias = jnp.zeros((1, 1, 1, 1) if PACK > 1 else (1, 1, 1),
                          jnp.float32)
 
-    # Clamp: an out-of-range length would DMA past the cache end.
-    lengths = jnp.minimum(lengths.astype(jnp.int32), S)
+    if window is None:
+        # Clamp: an out-of-range length would DMA past the cache end.
+        lengths = jnp.minimum(lengths.astype(jnp.int32), S)
+        first, call_name = [], {}
+    else:
+        assert PACK == 1 and not has_bias, "a ring is position-major, causal"
+        # the first block that the window of a row's first query touches
+        first = [jnp.maximum(qpos[:, 0].astype(jnp.int32) - (window - 1), 0)
+                 // BS]
+        call_name = {"name": "flash_attend_window"}
 
     cache_dt = k_cache.dtype
     kv_bytes = 2 * 2 * SB * KH * DL * cache_dt.itemsize
@@ -497,13 +561,19 @@ def flash_attend(q, k_cache, v_cache, lengths, qpos, bias=None,
             R, Q, H * D)
 
     if append_kv is None:
-        prefetch = [lengths.astype(jnp.int32)]
+        prefetch = [lengths.astype(jnp.int32)] + first
         if rows is not None:
             prefetch.append(rows.astype(jnp.int32))
-        kern = functools.partial(
-            _kernel if rows is None else _rows_kernel, BS=BS, causal=causal,
-            has_bias=has_bias, has_alibi=has_alibi, qk_scale=float(qk_scale),
-            G=G, Q=Q, layer_idx=layer_idx, PACK=PACK, D=D)
+        static = dict(BS=BS, causal=causal, has_bias=has_bias,
+                      has_alibi=has_alibi, qk_scale=float(qk_scale), G=G,
+                      Q=Q, layer_idx=layer_idx, PACK=PACK, D=D)
+        if window is None:
+            kern = functools.partial(
+                _kernel if rows is None else _rows_kernel, **static)
+        else:
+            kern = functools.partial(
+                _window_kernel, mode=None if rows is None else "rows",
+                window=window, **static)
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(prefetch), grid=(R,),
             in_specs=qkv_in_specs + tail_in_specs,
@@ -514,7 +584,7 @@ def flash_attend(q, k_cache, v_cache, lengths, qpos, bias=None,
                 (R, KH, GQ, DL),
                 jnp.float32 if PACK > 1 else out_dtype),
             compiler_params=compiler_params, cost_estimate=cost_estimate,
-            interpret=interpret,
+            interpret=interpret, **call_name,
         )(*prefetch, qt, qp_gq, slopes_gq,
           bias.astype(jnp.float32), k_cache, v_cache)
         return post(out)
@@ -527,14 +597,18 @@ def flash_attend(q, k_cache, v_cache, lengths, qpos, bias=None,
         # tiling the D lanes PACK times gives it the value in every half
         k_new = jnp.concatenate([k_new] * PACK, axis=-1)
         v_new = jnp.concatenate([v_new] * PACK, axis=-1)
-    kern = functools.partial(
-        _append_kernel, BS=BS, causal=causal, has_bias=has_bias,
-        has_alibi=has_alibi, qk_scale=float(qk_scale), G=G, Q=Q,
-        layer_idx=layer_idx, PACK=PACK, D=D)
+    static = dict(BS=BS, causal=causal, has_bias=has_bias,
+                  has_alibi=has_alibi, qk_scale=float(qk_scale), G=G, Q=Q,
+                  layer_idx=layer_idx, PACK=PACK, D=D)
+    if window is None:
+        kern = functools.partial(_append_kernel, **static)
+    else:
+        kern = functools.partial(_window_kernel, mode="append",
+                                 window=window, **static)
     knew_spec = pl.BlockSpec((1, 1, KH, DL), lambda r, *_: (r, 0, 0, 0),
                              memory_space=pltpu.VMEM)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2, grid=(R,),
+        num_scalar_prefetch=2 + len(first), grid=(R,),
         in_specs=qkv_in_specs + [knew_spec, knew_spec] + tail_in_specs,
         out_specs=(o_spec, pl.BlockSpec(memory_space=pl.ANY),
                    pl.BlockSpec(memory_space=pl.ANY)),
@@ -545,10 +619,11 @@ def flash_attend(q, k_cache, v_cache, lengths, qpos, bias=None,
             (R, KH, GQ, DL), jnp.float32 if PACK > 1 else out_dtype),
                    jax.ShapeDtypeStruct(k_cache.shape, k_cache.dtype),
                    jax.ShapeDtypeStruct(v_cache.shape, v_cache.dtype)),
-        input_output_aliases={8: 1, 9: 2},   # k/v cache operands -> outputs
+        # k/v cache operands -> outputs
+        input_output_aliases={8 + len(first): 1, 9 + len(first): 2},
         compiler_params=compiler_params, cost_estimate=cost_estimate,
-        interpret=interpret,
-    )(lengths.astype(jnp.int32), appos.astype(jnp.int32), qt, qp_gq,
+        interpret=interpret, **call_name,
+    )(lengths.astype(jnp.int32), *first, appos.astype(jnp.int32), qt, qp_gq,
       slopes_gq, k_new.astype(cache_dt), v_new.astype(cache_dt),
       bias.astype(jnp.float32), k_cache, v_cache)
     return post(out), k_out, v_out
@@ -556,8 +631,11 @@ def flash_attend(q, k_cache, v_cache, lengths, qpos, bias=None,
 
 def reference_attend(q, k_cache, v_cache, lengths, qpos, bias=None,
                      alibi=None, *, causal=True, qk_scale=None,
-                     out_dtype=None):
-    """Pure-jnp oracle with identical semantics (used on CPU and in tests)."""
+                     out_dtype=None, window=None, key_pos=None):
+    """Pure-jnp oracle with identical semantics (used on CPU and in tests).
+    ``window``: query i sees keys ``i - window < j <= i``. ``key_pos``
+    [R, S]: the position each cache row holds, negative for none (a ring:
+    ops/kv_layout.ring_positions); without it row s holds position s."""
     R, Q, H, D = q.shape
     KH, S = k_cache.shape[1], k_cache.shape[2]
     G = H // KH
@@ -570,6 +648,8 @@ def reference_attend(q, k_cache, v_cache, lengths, qpos, bias=None,
     s = jnp.einsum("rqkgd,rksd->rkgqs", qg, kc,
                    preferred_element_type=jnp.float32) * qk_scale
     s_ids = jnp.arange(S)[None, None, :]                       # [1,1,S]
+    if key_pos is not None:
+        s_ids = key_pos[:, None, :]                            # [R,1,S]
     if alibi is not None:
         dist = (qpos[:, :, None] - s_ids).astype(jnp.float32)  # [R,Q,S]
         slopes = alibi.astype(jnp.float32).reshape(KH, G)
@@ -580,6 +660,10 @@ def reference_attend(q, k_cache, v_cache, lengths, qpos, bias=None,
     visible = jnp.ones((R, Q, S), bool) if not causal else \
         (s_ids <= qpos[:, :, None])
     visible = visible & (s_ids < lengths[:, None, None])
+    if key_pos is not None:
+        visible = visible & (s_ids >= 0)
+    if window is not None:
+        visible = visible & (s_ids > qpos[:, :, None] - window)
     s = jnp.where(visible[:, None, None, :, :], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("rkgqs,rksd->rqkgd", p.astype(q.dtype), vc)

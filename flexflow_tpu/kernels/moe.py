@@ -16,7 +16,11 @@ padded to whole tiles), then walks the tiles in one Pallas call:
 * int8 expert weights are read as int8 and scaled per (expert, output
   column) on the f32 accumulator: no bf16 copy of an expert exists in HBM;
 * a pair of a padded position or an inactive slot is sorted past the last
-  group and lands in no tile: it touches no expert and adds nothing.
+  group and lands in no tile: it touches no expert and adds nothing;
+* a chip that holds experts ``[first, first + E)`` of a wider router (its
+  share of an expert-parallel layer) treats a pair routed elsewhere the
+  same way: no tile, no bytes, no arithmetic. What it returns is its own
+  experts' part of the sum.
 
 Backends without Mosaic (the CPU tests) run the same layout through
 ``lax.ragged_dot``; that is counted as a fallback, the way
@@ -72,13 +76,16 @@ def plan_routes(idx, valid, num_experts: int, tm: int):
     (0 for padding rows, whose results nobody reads); ``pair_row`` [T, k]:
     the row holding each pair's result (M for a pair of a token that is not
     ``valid``); ``tile_expert`` [n_tiles], ``n_active`` (tiles in use: the
-    walk skips the rest) and ``sizes`` [E], the pairs each expert got."""
+    walk skips the rest) and ``sizes`` [E], the pairs each expert got.
+    ``valid`` may also be [T, k], a pair at a time (a held range)."""
     T, k = idx.shape
     P, E = T * k, num_experts
     n_tiles = (P + min(E, P) * (tm - 1)) // tm
     M = n_tiles * tm
     i32 = jnp.int32
-    keys = jnp.where(valid[:, None], idx.astype(i32), E).reshape(P)
+    if valid.ndim == 1:
+        valid = valid[:, None]
+    keys = jnp.where(valid, idx.astype(i32), E).reshape(P)
     # one single-operand sort: the pair's index rides in the low digits
     packed = jnp.sort(keys * P + jnp.arange(P, dtype=i32))
     ks, order = packed // P, packed % P
@@ -217,14 +224,22 @@ def moe_experts_ragged(xs, tiles_per_expert, gate, up, down, *, tm: int):
 
 
 def moe_experts(x, idx, weights, valid, gate, up, down, *, pallas: bool,
-                interpret: bool = False):
+                interpret: bool = False, held=None):
     """x [T, H], idx/weights [T, k], valid [T] -> (y [T, H], sizes [E]).
 
     ``sizes`` is the number of routed pairs each expert got: the op's
-    counters are made of it."""
+    counters are made of it. ``held`` ``(first, router width)``: the E
+    experts here are ``[first, first + E)`` of that many; ``idx`` is over
+    all of them and only the pairs of the held ones are pairs."""
     T, k = idx.shape
     E = gate.shape[0]
-    tm = pick_tile(T * k, E)
+    pairs = T * k
+    if held is not None:
+        first, width = held
+        idx = idx - first
+        valid = valid[:, None] & (idx >= 0) & (idx < E)
+        pairs = -(-pairs * E // width)      # this share's, evenly routed
+    tm = pick_tile(pairs, E)
     row_token, pair_row, tile_expert, n_active, sizes = plan_routes(
         idx, valid, E, tm)
     xs = x[row_token]

@@ -2,7 +2,7 @@
 
 Capability parity with the reference model zoo (reference inference/models/
 llama.cc, opt.cc, falcon.cc, mpt.cc, starcoder.cc and their Python twins in
-python/flexflow/serve/models/; OLMoE, a sparse-expert family, is beyond it): each model family is a builder that records
+python/flexflow/serve/models/; OLMoE and EXAONE-MoE, sparse-expert families, are beyond it): each model family is a builder that records
 the decoder graph through the FFModel op-builder surface, plus a HuggingFace
 state-dict name mapping so real checkpoints load. ``FAMILIES`` maps the HF
 ``model_type`` to the family (the reference's ModelType enum +
@@ -12,12 +12,15 @@ serve.py architecture dispatch).
 import dataclasses
 from typing import Callable, Optional
 
+from flexflow_tpu.models import exaone_moe as _exaone_moe
 from flexflow_tpu.models import falcon as _falcon
 from flexflow_tpu.models import llama as _llama
 from flexflow_tpu.models import mpt as _mpt
 from flexflow_tpu.models import olmoe as _olmoe
 from flexflow_tpu.models import opt as _opt
 from flexflow_tpu.models import starcoder as _starcoder
+from flexflow_tpu.models.exaone_moe import (ExaoneMoEConfig,
+                                            create_exaone_moe_model)
 from flexflow_tpu.models.falcon import FalconConfig, create_falcon_model
 from flexflow_tpu.models.hf_utils import load_hf_state_dict
 from flexflow_tpu.models.llama import LLAMAConfig, create_llama_model
@@ -60,6 +63,10 @@ FAMILIES = {
     "olmoe": ModelFamily("olmoe", OLMoEConfig, create_olmoe_model,
                          _olmoe.hf_weight_map,
                          _olmoe.preprocess_hf_state_dict),
+    "exaone_moe": ModelFamily("exaone_moe", ExaoneMoEConfig,
+                              create_exaone_moe_model,
+                              _exaone_moe.hf_weight_map,
+                              _exaone_moe.preprocess_hf_state_dict),
     "gpt_bigcode": ModelFamily("gpt_bigcode", STARCODERConfig,
                                create_starcoder_model,
                                _starcoder.hf_weight_map,
@@ -82,6 +89,7 @@ def family_for_hf_config(hf_config) -> ModelFamily:
 
 
 __all__ = [
+    "ExaoneMoEConfig",
     "FAMILIES",
     "FalconConfig",
     "LLAMAConfig",
@@ -90,6 +98,7 @@ __all__ = [
     "OLMoEConfig",
     "OPTConfig",
     "STARCODERConfig",
+    "create_exaone_moe_model",
     "create_falcon_model",
     "create_llama_model",
     "create_mpt_model",

@@ -53,7 +53,8 @@ class WeightParam(OpImpl):
 
         return [WeightSpec("weight", tuple(attrs["shape"]),
                            DataType(attrs["dtype"]),
-                           ConstantInitializer(attrs.get("init", 1.0)))]
+                           attrs.get("initializer")
+                           or ConstantInitializer(attrs.get("init", 1.0)))]
 
     @staticmethod
     def forward(attrs, params, inputs, ctx):

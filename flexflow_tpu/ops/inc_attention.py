@@ -65,8 +65,10 @@ def apply_rotary(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray
 # ----------------------------------------------------------------------
 def append_kv(cache: jnp.ndarray, new: jnp.ndarray, start_pos: jnp.ndarray,
               num_tokens: jnp.ndarray, active: jnp.ndarray,
-              pack: int = 1) -> jnp.ndarray:
-    """Write new [R, Q, KH, D] into cache [R, KH, S, D] at per-slot offsets.
+              pack: int = 1, ring: bool = False) -> jnp.ndarray:
+    """Write new [R, Q, KH, D] into cache [R, KH, S, D] at per-slot offsets
+    (``ring``: a windowed layer's ring of S rows, ops/kv_layout.py: a
+    position lands in its ring row, and none is past the end).
 
     A packed cache (``pack`` > 1: [R, KH, S/pack, pack*D], ops/kv_layout.py)
     has no row to scatter a position into: it takes the exact contiguous
@@ -91,6 +93,8 @@ def append_kv(cache: jnp.ndarray, new: jnp.ndarray, start_pos: jnp.ndarray,
     R, Q = new.shape[0], new.shape[1]
     S = cache.shape[2]
     KH = cache.shape[1]
+    if ring:
+        start_pos = kvl.ring_row(start_pos, S)
     if Q == 1:
         valid = (num_tokens > 0) & active & (start_pos < S)
         rows = jnp.broadcast_to(jnp.arange(R)[:, None], (R, KH))
@@ -101,6 +105,8 @@ def append_kv(cache: jnp.ndarray, new: jnp.ndarray, start_pos: jnp.ndarray,
         return cache.at[rows, heads, cols].set(upd, mode="drop")
     rows = jnp.arange(R)[:, None]                                   # [R, 1]
     cols = start_pos[:, None] + jnp.arange(Q)[None, :]              # [R, Q]
+    if ring:
+        cols = kvl.ring_row(cols, S)
     valid = (jnp.arange(Q)[None, :] < num_tokens[:, None]) & active[:, None]
     cols = jnp.where(valid, cols, S)  # out of bounds -> dropped
     return cache.at[rows, :, cols].set(new.astype(cache.dtype), mode="drop")
@@ -111,9 +117,10 @@ _append_kv_fn = append_kv   # alias: _attend's append_kv kwarg shadows it
 
 def append_kv_stacked(stack: jnp.ndarray, layer_idx: int, new: jnp.ndarray,
                       start_pos: jnp.ndarray, num_tokens: jnp.ndarray,
-                      active: jnp.ndarray, pack: int = 1) -> jnp.ndarray:
+                      active: jnp.ndarray, pack: int = 1,
+                      ring: bool = False) -> jnp.ndarray:
     """Write new [R, Q, KH, D] into the stacked cache [L, R, KH, S, D] at
-    layer ``layer_idx``, in place (a packed stack: as ``append_kv``).
+    layer ``layer_idx``, in place (a packed stack, a ring: as ``append_kv``).
 
     Scattering one D-row per (layer, request, head, token) keeps the
     stack's canonical layout and updates the donated buffer with no
@@ -132,6 +139,8 @@ def append_kv_stacked(stack: jnp.ndarray, layer_idx: int, new: jnp.ndarray,
     heads = jnp.broadcast_to(jnp.arange(KH)[None, :, None], sh)
     cols = (jnp.broadcast_to(start_pos[:, None, None], sh)
             + jnp.arange(Q)[None, None, :])
+    if ring:
+        cols = kvl.ring_row(cols, S)
     valid = ((jnp.arange(Q)[None, None, :] < num_tokens[:, None, None])
              & active[:, None, None])
     cols = jnp.where(valid, cols, S)  # out of bounds -> dropped
@@ -144,7 +153,8 @@ def _qkv(attrs, params, x, compute_dtype):
 
     With ``attrs["qk_norm_eps"]`` (OLMoE; absent = no such step) q and k
     are RMS-normalised over their WHOLE projection, before the split into
-    heads and before the rotary embedding."""
+    heads and before the rotary embedding; where the norm weight is one
+    head wide (EXAONE-MoE), over each head's ``head_dim`` instead."""
     from flexflow_tpu.quant import qmatmul
 
     H = attrs["num_q_heads"]
@@ -164,6 +174,9 @@ def _qkv(attrs, params, x, compute_dtype):
     if eps is not None:
         from flexflow_tpu.ops.norm import _rms_norm
 
+        if params["q_norm"].shape[0] != q.shape[-1]:    # a head's norm
+            q = q.reshape(q.shape[:-1] + (H, D))
+            k = k.reshape(k.shape[:-1] + (KH, D))
         q = _rms_norm(q, params["q_norm"], eps)
         k = _rms_norm(k, params["k_norm"], eps)
     R, Q = x.shape[0], x.shape[1]
@@ -213,6 +226,10 @@ def _attend(attrs, q, k_cache, v_cache, lengths, qpos, out_dtype, ctx,
     the passed caches are consumed (aliased through the kernel). The jnp
     path performs the same append with the scatter, so semantics are
     identical everywhere.
+
+    A windowed layer (``attrs["sliding_window"]``) hands over its ring
+    (ops/kv_layout.py): the kernel is told the window and the jnp oracle
+    the position every ring row holds.
     """
     from flexflow_tpu import kernels as ffk
     from flexflow_tpu.kernels.attention import flash_attend, reference_attend
@@ -224,6 +241,10 @@ def _attend(attrs, q, k_cache, v_cache, lengths, qpos, out_dtype, ctx,
     alibi = (alibi_slopes(attrs["num_q_heads"])
              if attrs.get("position_bias", False) else None)
     S = attrs["max_seq_length"]
+    window = attrs.get("sliding_window")
+    if window is not None:
+        S = k_cache.shape[-2]       # the ring's rows, which the kernel tiles
+        assert bias is None and causal, "a windowed layer attends causally"
     pack = kvl.pack_of(k_cache, S)
     Dp = k_cache.shape[-1] // pack  # cache head dim (128-padded)
     cfg = ctx.config if ctx is not None else None
@@ -259,7 +280,7 @@ def _attend(attrs, q, k_cache, v_cache, lengths, qpos, out_dtype, ctx,
         attend = functools.partial(
             flash_attend, causal=causal, qk_scale=scale,
             out_dtype=out_dtype, layer_idx=layer_idx,
-            interpret=ffk.pallas_interpret_forced())
+            interpret=ffk.pallas_interpret_forced(), window=window)
         args = (_pad_d(q, Dp), k_cache, v_cache, lengths, qpos, bias, alibi,
                 fkv, rows)
         if (mesh is not None and mesh.devices.size > 1
@@ -277,14 +298,17 @@ def _attend(attrs, q, k_cache, v_cache, lengths, qpos, out_dtype, ctx,
         start = jnp.maximum(appos, 0)
         num = valid.astype(jnp.int32)
         kp, vp = _pad_d(k_new, Dp), _pad_d(v_new, Dp)
+        ring = window is not None
         if layer_idx is not None:
             k_cache = append_kv_stacked(k_cache, layer_idx, kp, start, num,
-                                        valid, pack)
+                                        valid, pack, ring)
             v_cache = append_kv_stacked(v_cache, layer_idx, vp, start, num,
-                                        valid, pack)
+                                        valid, pack, ring)
         else:
-            k_cache = _append_kv_fn(k_cache, kp, start, num, valid, pack)
-            v_cache = _append_kv_fn(v_cache, vp, start, num, valid, pack)
+            k_cache = _append_kv_fn(k_cache, kp, start, num, valid, pack,
+                                    ring)
+            v_cache = _append_kv_fn(v_cache, vp, start, num, valid, pack,
+                                    ring)
         new_caches = (k_cache, v_cache)
     kc, vc = k_cache, v_cache
     if layer_idx is not None:
@@ -304,7 +328,9 @@ def _attend(attrs, q, k_cache, v_cache, lengths, qpos, out_dtype, ctx,
         return out if append_kv is None else (out,) + new_caches
     out = reference_attend(
         q, kc[..., :D], vc[..., :D], lengths, qpos, bias=bias,
-        alibi=alibi, causal=causal, qk_scale=scale, out_dtype=out_dtype)
+        alibi=alibi, causal=causal, qk_scale=scale, out_dtype=out_dtype,
+        window=window,
+        key_pos=None if window is None else kvl.ring_positions(lengths, S))
     return out if append_kv is None else (out,) + new_caches
 
 
@@ -375,8 +401,12 @@ def _weight_specs(attrs, input_specs):
         from flexflow_tpu.core.initializer import ConstantInitializer
 
         one = ConstantInitializer(1.0)
-        specs += [WeightSpec("q_norm", (H * D,), dt, one),
-                  WeightSpec("k_norm", (KH * D,), dt, one)]
+        if attrs.get("qk_norm_per_head"):
+            specs += [WeightSpec("q_norm", (D,), dt, one),
+                      WeightSpec("k_norm", (D,), dt, one)]
+        else:
+            specs += [WeightSpec("q_norm", (H * D,), dt, one),
+                      WeightSpec("k_norm", (KH * D,), dt, one)]
     return specs
 
 
@@ -427,10 +457,20 @@ def _init_kv_state(attrs, input_specs):
     KH, D = attrs["num_kv_heads"], attrs["head_dim"]
     cache_dtype = jnp.dtype(attrs.get("cache_dtype", "bfloat16"))
     want_pallas = attrs.get("use_pallas", True) and ffk.use_pallas()
-    Dp = padded_head_dim(D, want_pallas=want_pallas, max_seq=S)
-    # stored as the kernel reads it: [R, KH, S, Dp], or packed
-    # [R, KH, S/2, 128] where a D=64 cache takes the packed flash path
-    shape = kvl.cache_shape(R, KH, S, Dp, kvl.stored_pack(Dp, S, want_pallas))
+    window = attrs.get("sliding_window")
+    if window is None:
+        Dp = padded_head_dim(D, want_pallas=want_pallas, max_seq=S)
+        # stored as the kernel reads it: [R, KH, S, Dp], or packed
+        # [R, KH, S/2, 128] where a D=64 cache takes the packed flash path
+        shape = kvl.cache_shape(R, KH, S, Dp,
+                                kvl.stored_pack(Dp, S, want_pallas))
+    else:
+        # a windowed layer keeps a ring of the window plus one step's
+        # tokens, position-major (a ring is never packed)
+        from flexflow_tpu.kernels.attention import LANE, round_up
+
+        rows = kvl.ring_rows(window, attrs["max_step_tokens"], S)
+        shape = (R, KH, rows, round_up(D, LANE) if want_pallas else D)
     return {
         "k_cache": jnp.zeros(shape, dtype=cache_dtype),
         "v_cache": jnp.zeros(shape, dtype=cache_dtype),
@@ -453,8 +493,28 @@ def _project_out(attrs, params, ctx, attn_out):
 #    layers share one cache shape): op_state["kv_cache"] = {"k": [L, ...],
 #    "v": [L, ...]} and each layer carries attrs["cache_layer_idx"].
 # Stacking cuts the donated-arg count from 2*L to 2 and lets tree-commit
-# run vectorized over layers.
+# run vectorized over layers. A model with windowed layers beside full
+# ones has a stack a kind: the windowed layers' rings are
+# op_state[WINDOW_STACK] and each carries attrs["cache_stack"].
 # ----------------------------------------------------------------------
+FULL_STACK, WINDOW_STACK = "kv_cache", "kv_cache_window"
+
+
+def refuse_windowed(op_state, what: str):
+    """A ring holds a slot's last positions only, by ``p % rows``: what
+    moves, copies or shards cache positions by their index says so."""
+    if WINDOW_STACK in (op_state or {}):
+        raise NotImplementedError(
+            f"{what} is not supported over a windowed attention layer: its "
+            "cache is a ring (ops/kv_layout.py), not every position")
+
+
+def _stack(ctx, attrs):
+    """(key, {"k", "v"}) of the stack that holds this layer's cache."""
+    key = attrs.get("cache_stack", FULL_STACK)
+    return key, ctx.state_out.get(key) or ctx.state_in[key]
+
+
 def read_kv(ctx, attrs):
     ov = getattr(ctx, "kv_override", None)
     if ov is not None:   # pipeline-parallel block execution: the stage
@@ -463,7 +523,7 @@ def read_kv(ctx, attrs):
     if idx is None:
         st = ctx.state_in[ctx.layer_name]
         return st["k_cache"], st["v_cache"]
-    st = ctx.state_out.get("kv_cache") or ctx.state_in["kv_cache"]
+    _, st = _stack(ctx, attrs)
     return st["k"][idx], st["v"][idx]
 
 
@@ -476,13 +536,14 @@ def write_kv(ctx, attrs, k_cache, v_cache):
         ctx.state_out[ctx.layer_name] = {"k_cache": k_cache,
                                          "v_cache": v_cache}
         return
-    st = ctx.state_out.get("kv_cache") or ctx.state_in["kv_cache"]
-    ctx.state_out["kv_cache"] = {"k": st["k"].at[idx].set(k_cache),
-                                 "v": st["v"].at[idx].set(v_cache)}
+    key, st = _stack(ctx, attrs)
+    ctx.state_out[key] = {"k": st["k"].at[idx].set(k_cache),
+                          "v": st["v"].at[idx].set(v_cache)}
 
 
 def append_kv_contiguous(cache, layer_idx, new, start_pos, active,
-                         slots=None, num_tokens=None, pack: int = 1):
+                         slots=None, num_tokens=None, pack: int = 1,
+                         ring: bool = False):
     """In-place contiguous append: per-request dynamic_update_slice of the
     [KH, Q, D] run at start_pos[r] — no scatter at all. The one writer of
     a packed cache (``pack`` > 1, ops/kv_layout.py): the run is laid into
@@ -505,7 +566,10 @@ def append_kv_contiguous(cache, layer_idx, new, start_pos, active,
     cache's end is written through the window that ends at S with its
     tokens shifted to their own positions (the whole row, where Q > S).
     With ``slots`` besides (the compact prefill batch), batch row r's run
-    lands in cache row slots[r] and the rows are unrolled.
+    lands in cache row slots[r] and the rows are unrolled. ``ring`` (a
+    windowed layer's cache; always exact): the run is written through the
+    two windows of ops/kv_layout.ring_pieces, to the ring's end and on
+    from its start.
 
     This beats both scatter forms: the windowed scatter forces a permuted
     layout + full per-layer cache copies (~134MB/layer/step at 7B), and
@@ -520,7 +584,7 @@ def append_kv_contiguous(cache, layer_idx, new, start_pos, active,
     # the eager debug dump (utils/debugging) hands numpy descriptors over
     start_pos, active = jnp.asarray(start_pos), jnp.asarray(active)
     lead1 = (1,) * (len(lead) + 1)
-    if pack > 1:        # merged by position: the window is wider than Q
+    if pack > 1 or ring:    # merged by position: the window is wider than Q
         num_tokens = (jnp.full((R,), Q, jnp.int32) if num_tokens is None
                       else jnp.minimum(jnp.asarray(num_tokens), Q))
     if num_tokens is None:
@@ -538,15 +602,24 @@ def append_kv_contiguous(cache, layer_idx, new, start_pos, active,
         # position past S exists, so the run's first S columns hold
         # every token that can land
         newT, Q = newT[:, :, :rows * pack], rows * pack
-    row, W, off = kvl.window(start_pos, Q, rows, pack)
-    t = jnp.arange(W * pack)[None] - off[:, None]   # a window column's token
-    keep = active[:, None] & (t >= 0) & (t < num_tokens[:, None])
+    if ring:
+        W, windows = Q, kvl.ring_pieces(start_pos, Q, rows)
+    else:
+        row, W, off = kvl.window(start_pos, Q, rows, pack)
+        windows = [(row, off)]
+    pieces = []
+    for row, off in windows:
+        t = jnp.arange(W * pack)[None] - off[:, None]   # a column's token
+        pieces.append((row, off, active[:, None] & (t >= 0)
+                       & (t < num_tokens[:, None])))
 
     def put(c, r, slot):
-        at = lead + (slot, 0, row[r], 0)
-        cur = jax.lax.dynamic_slice(c, at, lead1 + (KH, W, lanes))
-        return jax.lax.dynamic_update_slice(
-            c, kvl.merge_window(cur, newT[r], keep[r], off[r], pack), at)
+        for row, off, keep in pieces:
+            at = lead + (slot, 0, row[r], 0)
+            cur = jax.lax.dynamic_slice(c, at, lead1 + (KH, W, lanes))
+            c = jax.lax.dynamic_update_slice(
+                c, kvl.merge_window(cur, newT[r], keep[r], off[r], pack), at)
+        return c
 
     if slots is None:
         return jax.lax.fori_loop(0, R, lambda r, c: put(c, r, r), cache)
@@ -562,7 +635,8 @@ def append_kv_contiguous(cache, layer_idx, new, start_pos, active,
 # One trace serves every layer's K and V append of a compact prefill step:
 # the layer index is an operand here, where the engines' calls bake it in
 # (tracing the loop 2 x layers times cost seconds of set-up at 32 layers).
-_append_by_slot = jax.jit(append_kv_contiguous, static_argnames=("pack",))
+_append_by_slot = jax.jit(append_kv_contiguous,
+                          static_argnames=("pack", "ring"))
 
 
 def append_and_ref(ctx, attrs, k, v, start_pos, num_tokens, active,
@@ -588,38 +662,48 @@ def append_and_ref(ctx, attrs, k, v, start_pos, num_tokens, active,
     ov = getattr(ctx, "kv_override", None)
     idx = attrs.get("cache_layer_idx")
     contiguous = getattr(ctx, "kv_contiguous", False)
+    # a windowed layer's ring takes the appends that are exact everywhere
+    # (by slot, or the scatter)
+    ring = attrs.get("sliding_window") is not None
+    contiguous = contiguous and not ring
+    if ring and (k.shape[1] * (k.shape[0] if slots is not None else 1)
+                 > attrs["max_step_tokens"]):
+        raise ValueError(
+            f"a step of {k.shape[:2]} tokens may append more to one slot "
+            f"than the {attrs['max_step_tokens']} (max_tokens_per_batch) a "
+            "windowed layer's ring was sized for")
     # a pipeline stage's microbatch holds a slice of the cache's rows: its
     # loops keep the slot grid (RequestManager._compact_prefill)
     assert ov is None or slots is None, "no row map inside a pipeline stage"
     if ov is not None or idx is None:
         k0, v0 = read_kv(ctx, attrs)
-        pack = kvl.pack_of(k0, attrs["max_seq_length"])
+        pack = 1 if ring else kvl.pack_of(k0, attrs["max_seq_length"])
         Dp = k0.shape[-1] // pack
         k, v = _pad_d(k, Dp), _pad_d(v, Dp)
         if slots is not None:
             kc = _append_by_slot(k0, None, k, start_pos, active, slots,
-                                 num_tokens, pack=pack)
+                                 num_tokens, pack=pack, ring=ring)
             vc = _append_by_slot(v0, None, v, start_pos, active, slots,
-                                 num_tokens, pack=pack)
+                                 num_tokens, pack=pack, ring=ring)
         elif contiguous and k.shape[1] != 1:
             kc = append_kv_contiguous(k0, None, k, start_pos, active,
                                       pack=pack)
             vc = append_kv_contiguous(v0, None, v, start_pos, active,
                                       pack=pack)
         else:
-            kc = append_kv(k0, k, start_pos, num_tokens, active, pack)
-            vc = append_kv(v0, v, start_pos, num_tokens, active, pack)
+            kc = append_kv(k0, k, start_pos, num_tokens, active, pack, ring)
+            vc = append_kv(v0, v, start_pos, num_tokens, active, pack, ring)
         write_kv(ctx, attrs, kc, vc)
         return kc, vc, None
-    st = ctx.state_out.get("kv_cache") or ctx.state_in["kv_cache"]
-    pack = kvl.pack_of(st["k"], attrs["max_seq_length"])
+    key, st = _stack(ctx, attrs)
+    pack = 1 if ring else kvl.pack_of(st["k"], attrs["max_seq_length"])
     Dp = st["k"].shape[-1] // pack
     k, v = _pad_d(k, Dp), _pad_d(v, Dp)
     if slots is not None:
         ks = _append_by_slot(st["k"], jnp.int32(idx), k, start_pos, active,
-                             slots, num_tokens, pack=pack)
+                             slots, num_tokens, pack=pack, ring=ring)
         vs = _append_by_slot(st["v"], jnp.int32(idx), v, start_pos, active,
-                             slots, num_tokens, pack=pack)
+                             slots, num_tokens, pack=pack, ring=ring)
     elif contiguous and k.shape[1] != 1:
         # wide contiguous appends (engine verify/catch-up): scatter-free
         # DUS; decode (Q == 1) stays on the per-(r,kh) row scatter — at 7B
@@ -630,12 +714,13 @@ def append_and_ref(ctx, attrs, k, v, start_pos, num_tokens, active,
                                   pack=pack)
         vs = append_kv_contiguous(st["v"], idx, v, start_pos, active,
                                   pack=pack)
-    elif k.shape[1] == 1 or pack > 1:
-        # (a packed stack takes every width in place: append_kv_stacked)
+    elif k.shape[1] == 1 or pack > 1 or ring:
+        # (a packed stack and a ring take every width in place:
+        # append_kv_stacked)
         ks = append_kv_stacked(st["k"], idx, k, start_pos, num_tokens,
-                               active, pack)
+                               active, pack, ring=ring)
         vs = append_kv_stacked(st["v"], idx, v, start_pos, num_tokens,
-                               active, pack)
+                               active, pack, ring=ring)
     else:
         # host-stepped wide appends (prefill chunks, host tree verify):
         # drop-exact windowed scatter on the per-layer slice — paid once
@@ -644,7 +729,7 @@ def append_and_ref(ctx, attrs, k, v, start_pos, num_tokens, active,
         vc = append_kv(st["v"][idx], v, start_pos, num_tokens, active)
         ks = st["k"].at[idx].set(kc)
         vs = st["v"].at[idx].set(vc)
-    ctx.state_out["kv_cache"] = {"k": ks, "v": vs}
+    ctx.state_out[key] = {"k": ks, "v": vs}
     return ks, vs, idx
 
 
@@ -706,7 +791,7 @@ class IncMultiHeadSelfAttention(OpImpl):
                 st = ctx.state_in[ctx.layer_name]
                 k0, v0 = st["k_cache"], st["v_cache"]
             else:          # full stacked [L, R, KH, S, D] buffers
-                st = ctx.state_out.get("kv_cache") or ctx.state_in["kv_cache"]
+                key, st = _stack(ctx, attrs)
                 k0, v0 = st["k"], st["v"]
             S = attrs["max_seq_length"]
             appos = jnp.where(
@@ -718,7 +803,7 @@ class IncMultiHeadSelfAttention(OpImpl):
             if idx is None:
                 write_kv(ctx, attrs, knew, vnew)
             else:
-                ctx.state_out["kv_cache"] = {"k": knew, "v": vnew}
+                ctx.state_out[key] = {"k": knew, "v": vnew}
             return [_project_out(attrs, params, ctx, out)]
         k_ref, v_ref, layer_idx = append_and_ref(
             ctx, attrs, k, v, meta.start_pos, meta.num_tokens, meta.active,
@@ -823,6 +908,8 @@ def commit_tree_kv(op_state: Dict[str, Any], src_node: jnp.ndarray,
     is the caches' length in positions, from which a packed cache's layout
     is read (ops/kv_layout.py); None: position-major caches.
     """
+
+    refuse_windowed(op_state, "tree verification (commit_tree_kv)")
 
     def commit_one(cache):                          # [R, KH, S, D]
         R = cache.shape[0]

@@ -17,13 +17,25 @@ shape against the op's ``max_seq_length`` (``pack_of``) and addresses
 positions through the functions here; this is the only place that knows
 the position <-> (row, lanes) arithmetic. With ``pack == 1`` each of them
 is the identity.
+
+A WINDOWED layer (``sliding_window`` in the op's attrs: a query sees only
+the last ``window`` positions) keeps a RING instead of every position:
+``ring_rows`` rows, position-major, position ``p`` in row ``p % rows``. The
+ring holds the window plus the most one step appends to a slot, so every
+query of a step still finds its whole window after all of the step's
+tokens have been written; it is whole kernel blocks, so that block ``b`` of
+the positions is block ``b % (rows / block)`` of the ring and the kernel
+streams it unchanged. A ring as long as the op's ``max_seq_length`` never
+wraps and the same arithmetic is the identity. The functions below
+(``ring_*``) are the only place that knows it.
 """
 
 from __future__ import annotations
 
 import jax.numpy as jnp
 
-from flexflow_tpu.kernels.attention import _pack_factor, supports_seq_len
+from flexflow_tpu.kernels.attention import (LANE, _pack_factor, round_up,
+                                            supports_seq_len)
 
 
 def stored_pack(Dp: int, max_seq: int, want_pallas: bool) -> int:
@@ -128,3 +140,46 @@ def merge_window(cur, run, keep, off, pack: int):
     else:
         keep = to_rows(jnp.broadcast_to(keep[:, None], (C, D)), pack)[None]
     return jnp.where(keep, run[(None,) * (cur.ndim - 3)], cur)
+
+
+def ring_rows(window: int, step_tokens: int, max_seq: int) -> int:
+    """Rows of a windowed layer's cache: the window plus the most tokens
+    one step appends to a slot (``step_tokens``: the batch's token
+    budget), in whole blocks of the kernel's smallest tile; never more than
+    the positions a slot can hold."""
+    return min(max_seq, round_up(window + step_tokens, LANE))
+
+
+def ring_row(pos, rows: int):
+    """The ring row of position ``pos`` (any integer array or scalar)."""
+    return pos % rows
+
+
+def ring_positions(lengths, rows: int):
+    """``[R, rows]``: the position each ring row holds once a slot's first
+    ``lengths[r]`` positions are written (the largest below the length
+    that lands in the row), negative where the row was never written."""
+    last = lengths[:, None] - 1
+    return last - (last - jnp.arange(rows)[None, :]) % rows
+
+
+def read_ring(cache, a: int, b: int, at=()):
+    """Positions ``[a, b)`` (static, no more than the ring's rows, all
+    still held) of a ring ``[.., rows, D]`` as ``[.., b-a, D]``; ``at``
+    indexes leading dims, as in ``read_positions``."""
+    rows = cache.shape[-2]
+    assert 0 <= b - a <= rows, (a, b, rows)
+    return cache[tuple(at)][..., ring_row(jnp.arange(a, b), rows), :]
+
+
+def ring_pieces(start, Q: int, rows: int):
+    """The two windows of ``Q`` ring rows through which a run of ``Q``
+    positions from ``start`` [R] is written, as ``window`` gives one for a
+    cache that does not wrap: ``[(row, off), (row, off)]`` with token ``t``
+    of the run in column ``off + t`` of its window. The first holds the
+    tokens up to the ring's end, the second, at row 0, those that wrap
+    (none: every column's token is then past the run)."""
+    assert Q <= rows, (Q, rows)
+    r0 = ring_row(start, rows)
+    a = jnp.minimum(r0, rows - Q)
+    return [(a, r0 - a), (jnp.zeros_like(r0), r0 - rows)]

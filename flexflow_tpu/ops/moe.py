@@ -247,7 +247,14 @@ class MoeExperts(OpImpl):
     in its row of the op state's ``MOE_COUNTERS``: routed pairs per expert
     and, per phase of ``MOE_PHASES``, ``calls``, ``tokens``, ``routed``
     (pairs) and ``touched`` (distinct experts, summed over calls).
-    ``ServingTelemetry`` reads them at a snapshot and nowhere else."""
+    ``ServingTelemetry`` reads them at a snapshot and nowhere else.
+
+    With ``attrs["router_width"]`` the layer is one chip's share of an
+    expert-parallel layer: its ``num_experts`` are experts
+    ``[first_expert, first_expert + num_experts)`` of that many, the
+    indices are the router's over all of them, and the output is the held
+    experts' part of the sum (a pair routed elsewhere is no pair: it is
+    neither computed nor counted, and experts count by held index)."""
 
     op_type = OpType.MOE_EXPERTS
     quant_aware = True
@@ -286,10 +293,12 @@ class MoeExperts(OpImpl):
             K.record_fallback("backend without Mosaic")
         else:
             K.record_fast_path()
+        width = attrs.get("router_width")
         run = functools.partial(
             K.moe_experts, gate=params["gate"], up=params["up"],
             down=params["down"], pallas=pallas,
-            interpret=ffk.pallas_interpret_forced())
+            interpret=ffk.pallas_interpret_forced(),
+            held=None if width is None else (attrs["first_expert"], width))
         T = R * q
         flat = (x[:, :q].reshape(T, H), idx[:, :q].reshape(T, -1),
                 w[:, :q].reshape(T, -1), valid.reshape(T))

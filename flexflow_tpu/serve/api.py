@@ -25,6 +25,7 @@ from flexflow_tpu.ffconst import CompMode, DataType, InferenceMode
 from flexflow_tpu.serve.batch_config import GenerationConfig
 from flexflow_tpu.serve.request_manager import (GenerationResult,
                                                 RequestManager)
+from flexflow_tpu.utils.deep_stack import with_deep_stack
 
 _global_init_kwargs: dict = {}
 
@@ -416,8 +417,10 @@ class _BackgroundServer:
         self._stopping = False
         # (remaining-guid-set, event) per submission
         self._waiters: List[Tuple[set, threading.Event]] = []
-        self._thread = threading.Thread(target=self._run, daemon=True,
-                                        name="flexflow-serve")
+        # the loop traces every serving program: give its frames one chunk
+        self._thread = threading.Thread(
+            target=lambda: with_deep_stack(self._run), daemon=True,
+            name="flexflow-serve")
         self._error: Optional[BaseException] = None
 
     def start(self):

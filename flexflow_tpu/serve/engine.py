@@ -35,7 +35,7 @@ import jax.numpy as jnp
 
 from flexflow_tpu.ops import kv_layout as kvl
 from flexflow_tpu.ops.base import OpContext
-from flexflow_tpu.ops.inc_attention import move_kv
+from flexflow_tpu.ops.inc_attention import move_kv, refuse_windowed
 from flexflow_tpu.serve.batch_config import BatchMeta
 from flexflow_tpu.telemetry import get_telemetry
 
@@ -373,6 +373,7 @@ class MultiSpecEngine:
         """cache[r, :, r_pos+1+i] <- cache[r, :, r_pos+1+best_j*d+i] for
         i < n_acc, all layers (branch 0 is already contiguous)."""
         d = self.depth
+        refuse_windowed(llm_state, "a speculation commit (move_kv)")
         st = llm_state["kv_cache"]
 
         def move(cache):                            # [L, R, KH, S, D]
@@ -1031,6 +1032,7 @@ class BeamSpecEngine:
         """cache[r, :, r_pos+1+i] <- cache[r, :, r_pos+path[r, i]] for
         i < n_acc, all layers (path holds staged NODE indices)."""
         d = self.depth
+        refuse_windowed(llm_state, "a speculation commit (move_kv)")
         st = llm_state["kv_cache"]
 
         def move(cache):                            # [L, R, KH, S, D]

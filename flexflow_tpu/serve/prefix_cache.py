@@ -77,6 +77,9 @@ def _round_up(n: int, m: int = _PAD) -> int:
 # ----------------------------------------------------------------------
 def _kv_slots(op_state) -> List[Tuple[str, str, str, bool]]:
     """KV-cache entries of an op_state: (name, k_key, v_key, stacked)."""
+    from flexflow_tpu.ops.inc_attention import refuse_windowed
+
+    refuse_windowed(op_state, "the shared-prefix pool")
     out = []
     for name, st in op_state.items():
         if not isinstance(st, dict):

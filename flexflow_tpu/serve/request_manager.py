@@ -796,6 +796,9 @@ class RequestManager:
                 block = max(1, min(block,
                                    max_seq - 1 - int(pos[act].max())))
                 self._tel_tick(tel, live, R, max_seq)
+                kinds = getattr(model, "attention_kinds", None)
+                if tel is not None and kinds:   # windowed beside full
+                    tel.note_attention_reads(kinds, pos[act] + 1, block)
                 if rnd is not None:
                     rnd.phase(None)
                 t0 = time.perf_counter()

@@ -54,18 +54,33 @@ ffsv_prefix_cache_misses_total   counter    admission lookups with no match
 ffsv_prefix_cache_evictions_total counter   pooled prefixes LRU-evicted
 ffsv_prefix_shared_tokens_total  counter    prompt tokens served from the pool
 ffsv_prefix_pool_tokens          gauge      tokens held by the prefix pool
+ffsv_kv_cache_bytes              gauge      {kind} bytes of the caches of a kind
+ffsv_attn_positions_read_total   counter    {kind} layer-positions decode read
 ffsv_moe_routed_pairs_total      counter    {phase} (token, expert) pairs run
 ffsv_moe_tokens_total            counter    {phase} real tokens the experts saw
 ffsv_moe_experts_touched         summary    {phase} distinct experts a call read
 ffsv_moe_expert_pairs_total      counter    {expert} routed pairs of one expert
 ===============================  =========  =================================
 
+``kind`` is ``window`` or ``full``: a model with windowed attention layers
+beside full ones (``FFModel.attention_kinds``; no other model has the two
+series) keeps a ring a windowed layer and every position a full one.
+``ffsv_kv_cache_bytes`` is what compile allocated for each kind;
+``ffsv_attn_positions_read_total`` is what the rows of the decode steps had
+to attend, from the batch's lengths on the host: for each row of each step
+its length, cut to the window in a windowed layer, times the layers of the
+kind (times ``families``' bytes a position a layer, the cache bytes a step
+must read).
+
 The ``ffsv_moe_*`` series are the routed-expert op's (ops/moe.py), which
 counts ON THE DEVICE, in its op state, as the steps run; ``watch_model``
 makes the registry fetch those counters when a snapshot or a scrape is
 taken (one small device-to-host read) and at no other time. They are sums
 over the model's expert layers: a token counts once per layer, and
-``ffsv_moe_experts_touched``'s count is layer-steps. ``phase`` is
+``ffsv_moe_experts_touched``'s count is layer-steps. A layer that holds a
+share of its router's experts (``held``) counts its own: ``expert`` is the
+held index, and a pair routed to an expert held elsewhere is no pair.
+``phase`` is
 ``decode``, ``prefill`` or ``verify``, fixed when a program is traced.
 
 Batch-level spans (``tracing.SpanTracer.begin``/``end``, ``tid`` 0): the
@@ -348,6 +363,25 @@ class ServingTelemetry:
         if (id(model) not in self._watched
                 and MOE_COUNTERS in (model.op_state or {})):
             self._watched[id(model)] = [weakref.ref(model), 0]
+        for kind, a in (getattr(model, "attention_kinds", None)
+                        or {}).items():     # windowed layers beside full
+            self.registry.gauge(
+                f'ffsv_kv_cache_bytes{{kind="{kind}"}}',
+                "bytes of the KV caches of one kind of attention layer"
+                ).set(a["cache_bytes"])
+
+    def note_attention_reads(self, kinds, lengths, steps: int):
+        """A decode block of ``steps`` steps over rows whose caches hold
+        ``lengths`` positions after the first step's append: the
+        layer-positions each kind of attention layer has to read
+        (``kinds``: ``FFModel.attention_kinds``)."""
+        at = np.asarray(lengths, np.int64)[:, None] + np.arange(steps)
+        for kind, a in kinds.items():
+            seen = at if a["window"] is None else np.minimum(at, a["window"])
+            self.registry.counter(
+                f'ffsv_attn_positions_read_total{{kind="{kind}"}}',
+                "layer-positions the decode steps' rows had to attend"
+                ).inc(int(seen.sum()) * a["layers"])
 
     @staticmethod
     def _read_counters(model):

@@ -7,7 +7,9 @@ The benchmark's reference check prefills through the slot grid
 (``benchmark/families/_common.program_logits``) and cannot see the compact
 ``[segments x chunk]`` program the serving loops run. This does: for each
 benchmark configuration, on a 2-layer cut at its published widths (the cut
-the reference check uses, same seeded weights), the same prompts are
+the reference check uses, same seeded weights; a configuration whose layers
+are not all alike takes one whole period of them: ``CONFIGS``), the same
+prompts are
 prefilled by ``RequestManager``'s own chooser and builders once through the
 compact program and once through the slot-grid program. It compares the K/V
 caches over every written position and the logits of the next decode step,
@@ -18,6 +20,8 @@ model's router is discontinuous, so its compact run is sent, token by token,
 to the experts the grid run chose (at its own probabilities for them): a
 token whose own top-k this overrode is counted and its tie measured, and
 more than a tie, or more than a tenth of the tokens, fails the check too.
+A windowed layer's ring (ops/kv_layout.py) is compared over the positions it
+still holds, a prompt's last ``ring rows``.
 ``--rehearse``: CPU, the configuration's tiny rehearsal sizes, interpreted
 kernels.
 """
@@ -35,8 +39,10 @@ import numpy as np
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
-CONFIGS = ("falcon-7b", "opt-6.7b-spec", "olmoe-1b-7b")
-LAYERS = 2
+# configuration -> layers of the cut (K-EXAONE: one period, so that a full
+# layer and the windowed ones, the dense layer and the sparse ones are there)
+CONFIGS = {"falcon-7b": 2, "opt-6.7b-spec": 2, "olmoe-1b-7b": 2,
+           "k-exaone-236b-a23b": 4}
 TOL_LOGITS = 0.03           # the families' REFERENCE_TOL
 # A cache row's relative L2. The first layer's rows are a projection of the
 # embedding: one bfloat16 rounding (2**-8) either way. A later layer's come
@@ -44,7 +50,7 @@ TOL_LOGITS = 0.03           # the families' REFERENCE_TOL
 # own batch) already rounded apart in each of some ten operations.
 TOL_CACHE_FIRST = 2.0 ** -7
 TOL_CACHE = 0.02
-TOL_TIE = 0.08              # families/olmoe.ROUTE_MARGIN: what counts as a tie
+TOL_TIE = 0.08              # the families' ROUTE_MARGIN: what counts as a tie
 
 
 def prompts_for(cfg: dict, chunk: int):
@@ -210,6 +216,27 @@ def decode(model, step, prompts, routes: Routes, record: bool):
     return np.asarray(out)[[slot for slot, _ in prompts]]
 
 
+def written(model, c: str, slot: int, n: int):
+    """What ``slot``'s cache ``c`` ("k" or "v") holds of its first ``n``
+    positions, one float32 ``[KH, positions, D]`` per attention layer in
+    the model's order, read as stored through the layout's owner (packed
+    at D=64; a windowed layer's ring: the last positions it keeps)."""
+    from flexflow_tpu.ops import kv_layout as kvl
+    from flexflow_tpu.ops.inc_attention import FULL_STACK
+
+    S = model.config.max_sequence_length
+    for ly in model.layers:
+        if "cache_layer_idx" not in ly.attrs:
+            continue
+        stack = model.op_state[ly.attrs.get("cache_stack", FULL_STACK)][c]
+        at = (ly.attrs["cache_layer_idx"], slot)
+        if ly.attrs.get("sliding_window") is None:
+            rows = kvl.read_positions(stack, 0, n, kvl.pack_of(stack, S), at)
+        else:
+            rows = kvl.read_ring(stack, max(0, n - stack.shape[-2]), n, at)
+        yield np.asarray(rows, np.float32)
+
+
 def check(name: str, rehearse: bool) -> dict:
     import jax
     import jax.numpy as jnp
@@ -218,7 +245,6 @@ def check(name: str, rehearse: bool) -> dict:
     from benchmark.families import _common as C
     from flexflow_tpu.ffconst import InferenceMode
     from flexflow_tpu.models import FAMILIES
-    from flexflow_tpu.ops import kv_layout as kvl
     from flexflow_tpu.serve.request_manager import RequestManager as RM
 
     with open(os.path.join(ROOT, "benchmark", "configs", f"{name}.json")) as f:
@@ -226,9 +252,10 @@ def check(name: str, rehearse: bool) -> dict:
     if rehearse:
         bench_run.apply_rehearsal(cfg, {"cycle": []})
     family = bench_run.load_module("families", cfg["family"])
+    layers = CONFIGS.get(name, 2)
     model = C.build_model(C.ffconfig(cfg, False),
                           FAMILIES[cfg["family"]].build,
-                          family._model_cfg(cfg, LAYERS),
+                          family._model_cfg(cfg, layers),
                           InferenceMode.INC_DECODING_MODE)
     chunk, segments = RM._prefill_shape(model.config)
     prompts = prompts_for(cfg, chunk)
@@ -245,34 +272,31 @@ def check(name: str, rehearse: bool) -> dict:
     for compact in (False, True):
         model.op_state = jax.tree.map(jnp.zeros_like, model.op_state)
         steps = prefill(model, fill, prompts, compact, routes)
-        st = model.op_state["kv_cache"]
-        # the cache as stored (packed at D=64): the layout's owner reads it
-        pack = kvl.pack_of(st["k"], model.config.max_sequence_length)
-        kv = {(c, slot): np.asarray(
-                  kvl.read_positions(st[c], 0, len(toks) - 1, pack,
-                                     at=(slice(None), slot)),
-                  np.float32)                       # [L, KH, written, D]
-              for c in ("k", "v") for slot, toks in prompts}
+        kv = {(c, slot, layer): rows
+              for c in ("k", "v") for slot, toks in prompts
+              for layer, rows in enumerate(written(model, c, slot,
+                                                   len(toks) - 1))}
         got[compact] = steps, kv, decode(model, one, prompts, routes,
                                          record=not compact)
     (steps_c, kv_c, lg_c), (steps_g, kv_g, lg_g) = got[True], got[False]
     scale = max(float(np.abs(a).max()) for a in kv_g.values())
 
-    def rows_of(a):         # [L, KH, pos, D] -> [L, pos, KH x D]
-        return np.moveaxis(a, 2, 1).reshape(a.shape[0], a.shape[2], -1)
+    def rows_of(a):         # [KH, pos, D] -> [pos, KH x D]
+        return np.moveaxis(a, 1, 0).reshape(a.shape[1], -1)
 
     # a cache row (one position of one layer, all heads) against its twin:
     # relative L2 and largest element
     err = {key: np.linalg.norm(rows_of(kv_c[key] - kv_g[key]), axis=-1)
            / np.maximum(np.linalg.norm(rows_of(kv_g[key]), axis=-1), 1e-30)
            for key in kv_g}
-    by_layer = [max(float(e[layer].max()) for e in err.values())
-                for layer in range(LAYERS)]
+    by_layer = [max(float(e.max()) for key, e in err.items()
+                    if key[2] == layer and e.size)
+                for layer in range(layers)]
     rel = (np.linalg.norm(lg_c - lg_g, axis=-1)
            / np.linalg.norm(lg_g, axis=-1))
     overridden = [routes.overridden.count(layer)
                   for layer in range(routes.layers)]
-    return {"config": name, "layers": LAYERS, "prompts": len(prompts),
+    return {"config": name, "layers": layers, "prompts": len(prompts),
             "program": [segments, chunk], "steps_compact": steps_c,
             "steps_grid": steps_g,
             "positions_compared": sum(len(t) - 1 for _, t in prompts),
@@ -287,7 +311,8 @@ def check(name: str, rehearse: bool) -> dict:
             "ok": bool(scale > 0 and by_layer[0] <= TOL_CACHE_FIRST
                        and max(by_layer) <= TOL_CACHE
                        and float(rel.max()) < TOL_LOGITS
-                       and routes.tie < TOL_TIE
+                       and routes.tie < getattr(family, "ROUTE_MARGIN",
+                                                TOL_TIE)
                        and sum(overridden) <= 0.1 * max(1, len(routes.picks))),
             "device": jax.devices()[0].device_kind}
 
