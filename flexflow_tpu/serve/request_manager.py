@@ -45,8 +45,10 @@ from flexflow_tpu.serve.batch_config import (
     ancestor_mask_from_parents,
 )
 from flexflow_tpu.serve.inference_manager import InferenceManager
+from flexflow_tpu.serve.step_costs import StepCosts
 from flexflow_tpu.ops.inc_attention import commit_tree_kv
 from flexflow_tpu.telemetry import get_telemetry, mint_trace_id
+from flexflow_tpu.utils.profiling import device_fence
 
 
 @dataclasses.dataclass
@@ -608,8 +610,6 @@ class RequestManager:
         if tel is None:
             ifm.step(meta, want_output=False)
             return
-        from flexflow_tpu.utils.profiling import device_fence
-
         if rnd is None:
             t0 = time.perf_counter()
             ifm.step(meta, want_output=False)
@@ -713,10 +713,12 @@ class RequestManager:
         return getattr(getattr(ifm, "model", None), "_pp_plan", None) is None
 
     def _prefill(self, ifm, active, shape, depth_of, tel, rnd=None):
-        """One round's prefill for ``ifm``'s model: choose the segments
+        """One prefill step for ``ifm``'s model: choose the segments
         among ``active`` (None: not a candidate), run them in one
-        output-free step, return them. The one prefill path of the Python
-        loops; the caller moves its depth marks by the rows returned."""
+        output-free step, return them (none: nothing is filling). The one
+        prefill path of the Python loops; the caller moves its depth marks
+        by the rows returned. The speculation loops call it once a round,
+        the incremental loop as often as its decode block pays for."""
         chunk, segments = shape
         compact = self._compact_prefill(ifm)
         rows = self._prefill_rows(active, chunk, depth_of, segments,
@@ -746,6 +748,34 @@ class RequestManager:
         shape = chunk, _ = self._prefill_shape(cfg)
         active: List[Optional[Request]] = [None] * R
         done: List[GenerationResult] = []
+        # the two programs' measured cost outlives the call: the server
+        # re-enters this loop whenever its queue has emptied
+        costs = getattr(ifm, "step_costs", None)
+        if costs is None:
+            costs = ifm.step_costs = StepCosts()
+
+        def caught_up():
+            return [req for req in active
+                    if req is not None and not req.finished
+                    and req.cache_depth == len(req.tokens) - 1]
+
+        def block_steps(live, prefilled: bool) -> int:
+            """The decode block's steps for ``live``. Dynamic trip count:
+            exactly the steps still needed, one compiled program
+            regardless of size (engine.py). The verify-consistent wide
+            decode (decode_width > 1) appends only the real token's KV
+            (kv_append_q), so no staging window needs reserving near the
+            cache end."""
+            block = min(
+                max(self._remaining_budget(req, max_seq) for req in live),
+                cfg.decode_block_steps)
+            if prefilled:
+                # prefill still pending: keep the decode block short
+                # so the next chunk isn't starved behind it
+                block = min(block, chunk)
+            # never scan past the KV cache end
+            return max(1, min(block, max_seq - max(len(req.tokens)
+                                                   for req in live)))
 
         while self.pending or any(a is not None for a in active):
             tel = self._tel()
@@ -755,36 +785,49 @@ class RequestManager:
             self._prefix_install(active, (("llm", ifm),))
             if rnd is not None:
                 rnd.admitted(R - active.count(None), len(self.pending))
-            # decode-interleaved chunked prefill (ISSUE 19): each engine
-            # round dispatches at most ONE bounded prefill step (its
-            # outputs unused) AND the decode block for already-caught-up
-            # slots — a queued short request's TTFT no longer tracks the
-            # longest resident prompt's full prefill.
-            rows = self._prefill(ifm, active, shape,
-                                 lambda r: r.cache_depth, tel, rnd)
-            for slot, chunk_toks, sp in rows:
-                active[slot].cache_depth = sp + len(chunk_toks)
+            # decode-interleaved chunked prefill (ISSUE 19, 32): a round
+            # dispatches bounded prefill steps (separate calls of the one
+            # program, outputs unused) while a request is still filling
+            # and the steps together cost no more than the decode block
+            # that follows them (StepCosts: the loop's own measurement of
+            # the two programs), AND that block for the caught-up slots.
+            # One step is always allowed. A decoding row so waits for
+            # prefill at most a block's time, and a queued short request's
+            # TTFT does not track the longest resident prompt's prefill.
+            # With nothing decoding there is nobody to stall: the round
+            # prefills until a request has caught up.
+            decoding = caught_up()
+            allowed = (costs.allowance(block_steps(decoding, True))
+                       if decoding else None)
+            steps, timed, t0 = 0, False, time.perf_counter()
+            while allowed is None or steps < allowed:
+                rows = self._prefill(ifm, active, shape,
+                                     lambda r: r.cache_depth, tel, rnd)
+                if not rows:
+                    break
+                if not steps:
+                    timed = costs.due()
+                steps += 1
+                if timed and tel is None:
+                    # a timed round waits for each step as telemetry does
+                    # for every step, so both time the same thing
+                    device_fence(ifm.model.op_state)
+                for slot, chunk_toks, sp in rows:
+                    active[slot].cache_depth = sp + len(chunk_toks)
+                if allowed is None and caught_up():
+                    break
+            if timed:
+                costs.note_prefill(time.perf_counter() - t0, steps)
+            if tel is not None:
+                tel.note_round_prefill(steps)
             # decode: every caught-up slot feeds its pending token; the
             # token-feedback loop runs fused on device (DECODE_BLOCK steps
             # per call); EOS/length overshoot is reconciled host-side.
             # Mid-prefill slots (cache_depth short of the pending token)
             # sit this block out.
-            live = [req for req in active
-                    if req is not None and not req.finished
-                    and req.cache_depth == len(req.tokens) - 1]
+            live = caught_up()
             if live:
-                # dynamic trip count: exactly the steps still needed, one
-                # compiled program regardless of size (engine.py). The
-                # verify-consistent wide decode (decode_width > 1) appends
-                # only the real token's KV (kv_append_q), so no staging
-                # window needs reserving near the cache end.
-                block = min(
-                    max(self._remaining_budget(req, max_seq) for req in live),
-                    cfg.decode_block_steps)
-                if rows:
-                    # prefill still pending: keep the decode block short
-                    # so the next chunk isn't starved behind it
-                    block = min(block, chunk)
+                block = block_steps(live, steps > 0)
                 tok = np.zeros((R,), np.int32)
                 pos = np.zeros((R,), np.int32)
                 act = np.zeros((R,), bool)
@@ -792,9 +835,6 @@ class RequestManager:
                     tok[req.slot] = req.tokens[-1]
                     pos[req.slot] = len(req.tokens) - 1
                     act[req.slot] = True
-                # never scan past the KV cache end
-                block = max(1, min(block,
-                                   max_seq - 1 - int(pos[act].max())))
                 self._tel_tick(tel, live, R, max_seq)
                 kinds = getattr(model, "attention_kinds", None)
                 if tel is not None and kinds:   # windowed beside full
@@ -803,8 +843,10 @@ class RequestManager:
                     rnd.phase(None)
                 t0 = time.perf_counter()
                 toks = ifm.decode_block(tok, pos, act, block, tel=tel)
-                if tel is not None:   # decode_block's np readback = fence
-                    dt = time.perf_counter() - t0
+                dt = time.perf_counter() - t0   # the np readback = fence
+                if timed or not steps:  # the device was idle at dispatch
+                    costs.note_decode(dt, block)
+                if tel is not None:
                     rnd.phase("sched_commit", live)
                     tel.record_decode_block(dt, block, len(live),
                                             [r.guid for r in live], t0)

@@ -1,0 +1,85 @@
+"""What a prefill step and a decode step cost on this model, as the
+incremental loop itself measures them.
+
+``RequestManager.generate_incr_decoding`` prefills, each scheduler round,
+as many steps as the round's decode block pays for: the steps of one
+round together may take as long as the block that follows them and no
+longer, so a decoding row waits for prefill at most one block's time.
+That bound needs the two programs' cost on the model being served, and
+nothing states it ahead of time: a prefill step is 1.2 decode steps of
+OLMoE, 2.8 of K-EXAONE, 3.4-3.9 of Falcon (PERF.md section 6, PR 32).
+
+A prefill step is dispatched without a fence and the decode block's
+readback fences both, so a round's wall time does not say which program
+took it. Every ``EVERY``-th round that prefills is therefore TIMED: the
+loop waits for each of the round's prefill steps before it stages the
+next (a few ms of lost overlap a step), and gets a sample of each cost.
+That is what telemetry does to every step, so a timed round measures the
+same thing with telemetry on and off, and the traced run has the policy
+of the untraced. What it measures is a step run alone and waited for: a
+little more than the step costs the device when the next one is staged
+behind it, so the bound is kept with room. A round that prefills nothing
+gives a decode sample for free. Each estimate is the median of its last
+``KEEP`` samples, so a compile or a stop of the machine (0.1-10 s at
+times) inside one sample moves nothing, and there is no estimate until
+``MIN`` samples are in.
+"""
+
+from __future__ import annotations
+
+import statistics
+from collections import deque
+
+
+class StepCosts:
+    KEEP = 5        # samples an estimate is the median of
+    MIN = 3         # samples before there is an estimate
+    EVERY = 8       # of the rounds that prefill, one in EVERY is timed
+
+    def __init__(self):
+        self._prefill = deque(maxlen=self.KEEP)     # seconds a step
+        self._decode = deque(maxlen=self.KEEP)      # seconds a step
+        self._rounds = 0
+
+    def due(self) -> bool:
+        """Asked once by each round that prefilled: whether to time it.
+        Every one until both estimates stand, then one in ``EVERY``."""
+        self._rounds += 1
+        return (min(len(self._prefill), len(self._decode)) < self.MIN
+                or self._rounds % self.EVERY == 0)
+
+    def note_prefill(self, seconds: float, steps: int):
+        """``steps`` prefill steps, each waited for, took ``seconds``."""
+        self._prefill.append(seconds / steps)
+
+    def note_decode(self, seconds: float, steps: int):
+        """A decode block of ``steps`` took ``seconds`` on an idle device."""
+        self._decode.append(seconds / steps)
+
+    def allowance(self, block_steps: int) -> int:
+        """The prefill steps a decode block of ``block_steps`` pays for:
+        as many as together cost no more than the block. One always; one
+        alone while either cost is still unknown."""
+        if min(len(self._prefill), len(self._decode)) < self.MIN:
+            return 1
+        block_s = block_steps * statistics.median(self._decode)
+        return max(1, int(block_s / statistics.median(self._prefill)))
+
+
+class GivenCosts(StepCosts):
+    """The two costs as given and never timed, for a test or a check that
+    wants the same steps a round on every machine: set it as the
+    InferenceManager's ``step_costs`` before the loop runs."""
+
+    def __init__(self, prefill_s: float, decode_step_s: float):
+        super().__init__()
+        self._prefill.extend([prefill_s] * self.MIN)
+        self._decode.extend([decode_step_s] * self.MIN)
+
+    def due(self) -> bool:
+        return False
+
+    def note_prefill(self, seconds: float, steps: int):
+        pass
+
+    note_decode = note_prefill

@@ -28,6 +28,7 @@ ffsv_queue_depth                 gauge      submission queue depth (front door)
 ffsv_tokens_generated_total      counter    output tokens committed
 ffsv_prefill_tokens_total        counter    prompt tokens prefilled
 ffsv_prefill_positions_total     counter    positions prefill steps computed
+ffsv_round_prefill_steps         histogram  prefill steps a round dispatched
 ffsv_spec_rounds_total           counter    speculation rounds executed
 ffsv_decode_steps_total          counter    incremental decode steps
 ffsv_acceptance_length           histogram  accepted draft tokens per round
@@ -259,6 +260,11 @@ class ServingTelemetry:
         self.prefill_positions = r.counter(
             "ffsv_prefill_positions_total",
             "positions prefill steps computed (batch rows x chunk)")
+        self.round_prefill_steps = r.histogram(
+            "ffsv_round_prefill_steps",
+            "prefill steps one round of the incremental loop dispatched "
+            "(above 1: the round's decode block paid for more than one)",
+            buckets=COUNT_BUCKETS)
         self.spec_rounds = r.counter(
             "ffsv_spec_rounds_total", "speculation rounds executed")
         self.decode_steps = r.counter(
@@ -538,6 +544,11 @@ class ServingTelemetry:
             t0 = time.perf_counter() - seconds
         for guid, start_pos, n in rows:
             self.tracer.prefill(guid, start_pos, n, t0, seconds)
+
+    def note_round_prefill(self, steps: int):
+        """Once per round of the incremental loop: the prefill steps it
+        dispatched before its decode block, none included."""
+        self.round_prefill_steps.observe(steps)
 
     def record_decode_block(self, seconds: float, steps: int, n_live: int,
                             guids=(), t0: Optional[float] = None):

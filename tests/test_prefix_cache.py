@@ -1,11 +1,13 @@
-"""Shared-prefix KV cache + decode-interleaved chunked prefill (ISSUE 19):
+"""Shared-prefix KV cache + decode-interleaved chunked prefill (ISSUE 19, 32):
 radix trie match/insert/evict/refcount math on a fake clock, KV segment
 extract/install roundtrip on both op_state layouts, token identity cold
 vs warm on all three scheduler paths (incremental, spec chain, multi-SSM
 fused) including a preemption re-queue that crosses a pooled prefix,
 eviction-under-pressure never corrupting a live slot, the
-decode-interleaves-with-prefill dispatch order, and the serving_prefix
-absolute floors in the bench trend gate.
+decode-interleaves-with-prefill dispatch order (a round prefills as many
+steps as its decode block pays for, by the two programs' given costs, with
+telemetry on and off: ISSUE 32), and the serving_prefix absolute floors in
+the bench trend gate.
 
 Budget discipline: pure-math tests dominate; the integration tests share
 the session tiny spec pair plus ONE module-scoped tiny incremental model
@@ -368,17 +370,20 @@ def test_eviction_pressure_keeps_tokens_identical(tiny_incr_model, incr_ref):
 # decode-interleaved chunked prefill: dispatch order
 # ---------------------------------------------------------------------------
 
-def test_decode_interleaves_with_chunked_prefill(tiny_incr_model):
-    """The deterministic form of the TTFT claim: with a long prompt
-    prefilling in chunks, a co-resident caught-up request's decode block
-    is dispatched BEFORE the long prompt's final prefill chunk — the
-    short request never waits for the full prefill as it did under the
-    old drain-prefill-then-decode order."""
-    model = tiny_incr_model
+def _interleave_events(model, telemetry: bool):
+    """A 60-token prompt (59 to fill: five steps of 2 segments x 8, the
+    first shared) beside a 3-token one that decodes 20 tokens in blocks of
+    8, with the two programs' costs GIVEN: a decode block of 8 steps pays
+    for two prefill steps (8 x 0.3 / 1.0). Returns the loop's device calls
+    in order and the two results."""
+    from flexflow_tpu.serve.request_manager import InferenceManager
+    from flexflow_tpu.serve.step_costs import GivenCosts
+    from flexflow_tpu.telemetry import disable_telemetry, enable_telemetry
+
     rm = RequestManager()
-    long_prompt = [(i % 96) + 1 for i in range(28)]   # 4 chunks at chunk=8
+    long_prompt = [(i % 96) + 1 for i in range(60)]
     gl = rm.register_new_request(long_prompt, max_new_tokens=2)
-    gs = rm.register_new_request([7, 3, 2], max_new_tokens=2)
+    gs = rm.register_new_request([7, 3, 2], max_new_tokens=20)
     events = []
     orig_prefill = rm._timed_prefill
 
@@ -387,8 +392,6 @@ def test_decode_interleaves_with_chunked_prefill(tiny_incr_model):
         return orig_prefill(*args, **kwargs)
 
     rm._timed_prefill = spy_prefill
-    from flexflow_tpu.serve.request_manager import InferenceManager
-
     ifm = getattr(model, "_inference_manager", None)
     if ifm is None:
         ifm = model._inference_manager = InferenceManager(model)
@@ -399,19 +402,51 @@ def test_decode_interleaves_with_chunked_prefill(tiny_incr_model):
         return orig_decode(tok, pos, act, block, tel=tel)
 
     ifm.decode_block = spy_decode
+    ifm.step_costs = GivenCosts(1.0, 0.3)
+    if telemetry:
+        enable_telemetry()
     try:
         rm.generate_incr_decoding(model)
     finally:
+        disable_telemetry()
         ifm.decode_block = orig_decode
-    assert rm.results[gl].status == "ok"
-    assert rm.results[gs].status == "ok"
-    assert len(rm.results[gs].output_tokens) == 2
-    # the long prompt needed several bounded chunks...
-    assert events.count("prefill") >= 3
+        del ifm.step_costs
+    return events, rm.results[gl], rm.results[gs]
+
+
+def test_decode_interleaves_with_chunked_prefill(tiny_incr_model):
+    """The deterministic form of the TTFT claim: with a long prompt
+    prefilling in chunks, a co-resident caught-up request's decode block
+    is dispatched BEFORE the long prompt's final prefill chunk — the
+    short request never waits for the full prefill as it did under the
+    old drain-prefill-then-decode order. The prompt is longer than one
+    round's allowance: a round prefills as many steps as its decode block
+    pays for (two, by the costs given) and no more."""
+    events, long_res, short_res = _interleave_events(tiny_incr_model, False)
+    assert long_res.status == "ok" and short_res.status == "ok"
+    assert len(short_res.output_tokens) == 20
+    # the long prompt needed several bounded steps...
+    assert events.count("prefill") == 5
     # ...and the short request decoded while those were still pending
     first_decode = events.index("decode")
     last_prefill = len(events) - 1 - events[::-1].index("prefill")
     assert first_decode < last_prefill, events
+    # round 1 stops at the short request's catch-up; round 2 takes the two
+    # steps its block of 8 pays for; round 3's block is the short request's
+    # last 4 tokens, which pay for one; round 4 has nobody to stall
+    assert events == ["prefill", "decode", "prefill", "prefill", "decode",
+                      "prefill", "decode", "prefill", "decode"], events
+
+
+def test_telemetry_leaves_the_rounds_as_they_are(tiny_incr_model):
+    """Telemetry fences every prefill step and times it; the steps a round
+    takes come from the two costs and the state alone, so the traced run
+    measures the schedule the untraced run has, token for token."""
+    plain = _interleave_events(tiny_incr_model, False)
+    traced = _interleave_events(tiny_incr_model, True)
+    assert traced[0] == plain[0]
+    assert traced[1].output_tokens == plain[1].output_tokens
+    assert traced[2].output_tokens == plain[2].output_tokens
 
 
 # ---------------------------------------------------------------------------

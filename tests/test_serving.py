@@ -8,6 +8,7 @@ deterministic, (b) spec-infer output must token-match incremental decoding
 """
 
 import os
+import types
 import warnings
 
 import jax
@@ -801,21 +802,29 @@ def _closed_form(prompt, max_new, max_seq, eos=_RULE_EOS):
 class _RuleIFM:
     """Stands in for InferenceManager: the next token is ``_rule_next`` of
     the last token and its position. Records every call; ``on_decode`` runs
-    at the start of each decode block with the call's index."""
+    at the start of each decode block with the call's index. ``costs``: the
+    loop's ``step_costs``, given and not timed (None: the loop times the
+    fake's calls itself, and has no estimate before its third sample)."""
 
-    def __init__(self, on_decode=None):
+    def __init__(self, on_decode=None, costs=None):
         self.prefills = []          # BatchMeta of every prefill step
         self.decodes = []           # (tok, pos, act, block) of every block
+        self.rounds = [0]           # prefill steps before each decode block
         self.on_decode = on_decode
+        self.model = types.SimpleNamespace(op_state=None)   # none to fence
+        if costs is not None:
+            self.step_costs = costs
 
     def step(self, meta, want_output=True, tel=None):
         assert not want_output
         self.prefills.append(meta)
+        self.rounds[-1] += 1
 
     def decode_block(self, tok, pos, act, block, tel=None):
         if self.on_decode is not None:
             self.on_decode(len(self.decodes))
         self.decodes.append((tok.copy(), pos.copy(), act.copy(), block))
+        self.rounds.append(0)
         out = np.zeros((tok.shape[0], block), np.int32)
         cur, p = tok.copy(), pos.copy()
         for j in range(block):
@@ -834,32 +843,199 @@ def _rule_model(cfg, ifm):
 
 
 _RULE_PROMPTS = [[3, 4, 5], [10], [7, 8], [1, 2, 3, 4, 5, 6], [9, 9]]
+# a queue for four slots: prompts of 6-14 chunks of 4 (16 tokens a step)
+_RULE_QUEUE = [[(7 * i + j) % 50 + 1 for j in range(n)]
+               for i, n in enumerate([24, 56, 33, 41, 25, 50, 37, 29])]
 
 
-@pytest.mark.parametrize("block", [1, 4, 8])
-@pytest.mark.parametrize("slots", [1, 2, 3])
-def test_incr_loop_matches_closed_form(slots, block):
+def _allowing(steps, block):
+    """Costs under which a decode block of ``block`` pays for ``steps``
+    prefill steps."""
+    from flexflow_tpu.serve.step_costs import GivenCosts
+
+    return GivenCosts(1.0, (steps + 0.5) / block)
+
+
+@pytest.mark.parametrize("slots,block,steps", [
+    (slots, block, None) for slots in (1, 2, 3) for block in (1, 4, 8)
+] + [(4, 4, 1), (4, 4, 2), (4, 4, 5), (4, 2, 3), (4, 1, 16)])
+def test_incr_loop_matches_closed_form(slots, block, steps):
     """Five requests through 1-3 slots in blocks of 1-8 steps: each gets
     exactly what it would generate alone (EOS inside a block, a budget
-    that ends mid-block, rows refilled from the queue, one-token prompts)."""
-    cfg = ff.FFConfig(max_requests_per_batch=slots, max_sequence_length=24,
+    that ends mid-block, rows refilled from the queue, one-token prompts).
+    ``steps``: a queue of eight long prompts through four slots where a
+    decode block pays for that many prefill steps a round; whatever the
+    rounds held, every request's tokens are its own."""
+    queue = _RULE_PROMPTS if steps is None else _RULE_QUEUE
+    max_seq = 24 if steps is None else 80
+    cfg = ff.FFConfig(max_requests_per_batch=slots,
+                      max_sequence_length=max_seq,
                       max_tokens_per_batch=16, decode_block_steps=block)
     rm = RequestManager(eos_token_id=_RULE_EOS)
-    for i, pr in enumerate(_RULE_PROMPTS):
+    for i, pr in enumerate(queue):
         rm.register_new_request(pr, max_new_tokens=6 + i)
-    ifm = _RuleIFM()
+    costs, asked = None, []
+    if steps is not None:
+        costs = _allowing(steps, block)
+        allowance = costs.allowance
+        costs.allowance = lambda b: asked.append(
+            (len(ifm.decodes), allowance(b))) or asked[-1][1]
+    ifm = _RuleIFM(costs=costs)
     res = rm.generate_incr_decoding(_rule_model(cfg, ifm))
     assert rm.scheduler_loop == "python"
     got = {tuple(r.input_tokens): r.output_tokens for r in res}
-    want = {tuple(pr): _closed_form(pr, 6 + i, 24)
-            for i, pr in enumerate(_RULE_PROMPTS)}
+    want = {tuple(pr): _closed_form(pr, 6 + i, max_seq)
+            for i, pr in enumerate(queue)}
     assert got == want
     # the scenarios the docstring names are in this workload
     assert any(out[-1] == _RULE_EOS and len(out) < 6 + i
                for i, out in enumerate(want.values()))
     assert any(len(out) % 4 for out in want.values())
     assert all(r.status == "ok" for r in res)
-    assert max(int(act.sum()) for _, _, act, _ in ifm.decodes) == slots
+    if steps is None:
+        assert max(int(act.sum()) for _, _, act, _ in ifm.decodes) == slots
+        return
+    # the first round has nothing decoding and prefills until a request
+    # has caught up; a round that begins with a row decoding asks what
+    # its block pays for, and keeps to it
+    assert ifm.rounds[0] == 6 and asked[0][0] == 1
+    assert all(ifm.rounds[i] <= allowed for i, allowed in asked)
+    assert max(allowed for _, allowed in asked) == steps
+    assert any(ifm.rounds[i] > 1 for i, _ in asked) == (steps > 1)
+
+
+def test_incr_loop_fills_its_batch_sooner_with_several_steps_a_round():
+    """The same queue, one step a round against five: the four slots all
+    decode after fewer rounds, no token differs, and
+    ``ffsv_round_prefill_steps`` counts the rounds that took more than one
+    step. Telemetry fences each step (the fake has no state to fence) and
+    changes nothing of the schedule."""
+    from flexflow_tpu.telemetry import disable_telemetry, enable_telemetry
+
+    cfg = ff.FFConfig(max_requests_per_batch=4, max_sequence_length=80,
+                      max_tokens_per_batch=16, decode_block_steps=4)
+
+    def run(steps):
+        rm = RequestManager(eos_token_id=_RULE_EOS)
+        for pr in _RULE_QUEUE:
+            rm.register_new_request(pr, max_new_tokens=24)
+        ifm = _RuleIFM(costs=_allowing(steps, 4))
+        res = rm.generate_incr_decoding(_rule_model(cfg, ifm))
+        full = [int(act.sum()) for _, _, act, _ in ifm.decodes].index(4)
+        return ({tuple(r.input_tokens): r.output_tokens for r in res},
+                full, ifm.rounds)
+
+    one, full_one, rounds_one = run(1)
+    tel = enable_telemetry()
+    try:
+        before = tel.registry.snapshot()
+        five, full_five, rounds_five = run(5)
+        hist = tel.registry.get("ffsv_round_prefill_steps")
+        # the benchmark's reader of it; nothing from a program without it
+        from benchmark.layer_metrics import prefill_steps_per_round as metric
+
+        ctx = {"tel": {"before": before, "after": tel.registry.snapshot()}}
+        assert metric.read(ctx) == sum(rounds_five) / (len(rounds_five) - 1)
+        assert metric.read({"tel": {"before": {}, "after": {}}}) is None
+        assert metric.read({"tel": None}) is None
+        assert hist.count == len(rounds_five) - 1   # one a decode block
+        assert hist.sum == sum(rounds_five)
+        # observations above 1: the rounds the rule engaged in
+        assert sum(hist._counts[2:]) == sum(n > 1 for n in rounds_five) >= 3
+    finally:
+        disable_telemetry()
+    assert five == one
+    assert full_five < full_one, (full_five, full_one)
+    assert max(rounds_five[1:]) == 5 and max(rounds_one[1:]) <= 2
+    assert run(5)[1:] == (full_five, rounds_five)   # telemetry off: the same
+
+
+def test_step_costs_are_medians_of_a_few_timed_rounds():
+    """No estimate, and so one step a round, until three samples of each
+    program are in; then as many steps as together cost no more than the
+    block, from the medians of the last five samples: a stop of the machine
+    inside one sample moves nothing. Every prefilling round is timed until
+    both estimates stand, then one in eight."""
+    from flexflow_tpu.serve.step_costs import StepCosts
+
+    costs = StepCosts()
+    assert costs.allowance(16) == 1
+    for i in range(3):
+        assert costs.due()
+        costs.note_prefill(2 * 0.0226, 2)     # two steps, timed together
+        assert costs.allowance(16) == 1
+        costs.note_decode(16 * 0.0106, 16)
+    assert costs.allowance(16) == 7             # 169.6 / 22.6 ms
+    assert costs.allowance(4) == 1 and costs.allowance(1) == 1
+    assert [costs.due() for _ in range(16)].count(True) == 2
+    costs.note_prefill(9.0, 1)                  # the machine stopped
+    costs.note_decode(16 * 0.6, 16)
+    assert costs.allowance(16) == 7
+    for _ in range(3):                          # the model got slower
+        costs.note_prefill(0.03, 1)
+    assert costs.allowance(16) == 5
+
+
+@pytest.mark.parametrize("stop_at", [None, 7])
+def test_incr_loop_times_the_same_rounds_traced_and_untraced(monkeypatch,
+                                                             stop_at):
+    """The loop's own timing on a device that runs what it is sent in order
+    (a prefill step 1.0 s, a decode step 0.8 s, dispatch free, a fence or a
+    readback waits for the device): a timed round waits for each of its
+    steps with telemetry off as telemetry does for every step, so the
+    estimates, and with them the steps of every round, are the same traced
+    and untraced. ``stop_at``: the machine stops for 100 s inside that
+    prefill step; nothing changes."""
+    from flexflow_tpu.serve import request_manager as RM
+    from flexflow_tpu.telemetry import disable_telemetry, enable_telemetry
+
+    cfg = ff.FFConfig(max_requests_per_batch=4, max_sequence_length=80,
+                      max_tokens_per_batch=16, decode_block_steps=4)
+
+    def run(traced):
+        host, device = [0.0], [0.0]     # the clock; when the device is free
+
+        def wait(_state=None):
+            host[0] = max(host[0], device[0])
+
+        class Device(_RuleIFM):
+            def step(self, meta, want_output=True, tel=None):
+                super().step(meta, want_output, tel)
+                stop = 100.0 * (len(self.prefills) == stop_at)
+                device[0] = max(host[0], device[0]) + 1.0 + stop
+
+            def decode_block(self, tok, pos, act, block, tel=None):
+                device[0] = max(host[0], device[0]) + 0.8 * block
+                wait()
+                return super().decode_block(tok, pos, act, block, tel)
+
+        monkeypatch.setattr(RM, "device_fence", wait)
+        monkeypatch.setattr(RM, "time", types.SimpleNamespace(
+            perf_counter=lambda: host[0]))
+        rm = RequestManager(eos_token_id=_RULE_EOS)
+        for pr in _RULE_QUEUE:
+            rm.register_new_request(pr, max_new_tokens=24)
+        ifm = Device()
+        if traced:
+            enable_telemetry()
+        try:
+            res = rm.generate_incr_decoding(_rule_model(cfg, ifm))
+        finally:
+            disable_telemetry()
+        return ({tuple(r.input_tokens): r.output_tokens for r in res},
+                ifm.rounds, list(ifm.step_costs._prefill),
+                list(ifm.step_costs._decode))
+
+    plain, traced = run(False), run(True)
+    assert plain == traced
+    # the first round has nothing decoding; then one step a round until the
+    # third sample of each program, then the three that a block of 4 steps
+    # (3.2 s) pays for
+    assert plain[1][:4] == [6, 1, 1, 3], plain[1]
+    assert max(plain[1][1:]) == 3
+    assert sorted(plain[2])[:-1] == [1.0] * (len(plain[2]) - 1)
+    assert max(plain[2]) == (1.0 if stop_at is None else 101.0)
+    assert {round(d, 6) for d in plain[3]} == {0.8}
 
 
 def test_incr_loop_lifecycle():
@@ -1196,14 +1372,19 @@ def test_prefill_segments_go_to_the_oldest_admission_first():
     assert len(waits) == 14 and max(waits) == 2, waits
 
 
-@pytest.mark.parametrize("config", ["falcon-7b", "olmoe-1b-7b",
-                                    "k-exaone-236b-a23b"])
-def test_check_compact_prefill_tool_rehearses(config, monkeypatch, capsys):
+@pytest.mark.parametrize("config,rounds", [
+    ("falcon-7b", False), ("olmoe-1b-7b", False),
+    ("k-exaone-236b-a23b", False), ("k-exaone-236b-a23b", True)])
+def test_check_compact_prefill_tool_rehearses(config, rounds, monkeypatch,
+                                              capsys):
     """tools/check_compact_prefill.py (the on-chip check of the compact
     program against the slot grid) runs at a configuration's rehearsal
     sizes: on the CPU the two programs agree to the bit, and an expert
     model's compact run, sent where the grid run went, overrides no pick
-    of its own."""
+    of its own. ``rounds``: the windowed cut is then served by the
+    scheduler loop, whose rounds take four consecutive steps (256
+    positions through a ring of 128 rows) while rows decode: every token
+    is what one step a round gives, the first the grid run's pick."""
     import json
 
     monkeypatch.syspath_prepend(os.path.dirname(os.path.dirname(
@@ -1212,10 +1393,16 @@ def test_check_compact_prefill_tool_rehearses(config, monkeypatch, capsys):
         monkeypatch.setenv(key, os.environ.get(key, ""))   # restored after
     from tools import check_compact_prefill
 
-    assert check_compact_prefill.main(["--rehearse", config]) == 0
+    assert check_compact_prefill.main(
+        ["--rehearse"] + ["--rounds"] * rounds + [config]) == 0
     res = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert res["ok"] and res["config"] == config
     assert res["steps_compact"] < res["steps_grid"]
     assert res["logits_max_rel_l2"] == 0.0 and res["cache_max_abs_diff"] == 0.0
     assert (res["routed_tokens"] > 0) == (config != "falcon-7b")
     assert not any(res["routes_overridden_by_layer"])
+    if rounds:
+        assert res["served_tokens_equal"]
+        assert res["served_first_tokens_off_the_grid"] == 0
+        assert res["served_steps_by_round"][:6] == [1, 4, 4, 4, 4, 2]
+        assert res["served_positions_a_round_max"] >= 2 * res["ring_rows"][0]

@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """The compact prefill batch against the slot grid, on the device.
 
-    python3 tools/check_compact_prefill.py [--rehearse] [config ...]
+    python3 tools/check_compact_prefill.py [--rehearse] [--rounds] [config ...]
 
 The benchmark's reference check prefills through the slot grid
 (``benchmark/families/_common.program_logits``) and cannot see the compact
@@ -23,7 +23,11 @@ more than a tie, or more than a tenth of the tokens, fails the check too.
 A windowed layer's ring (ops/kv_layout.py) is compared over the positions it
 still holds, a prompt's last ``ring rows``.
 ``--rehearse``: CPU, the configuration's tiny rehearsal sizes, interpreted
-kernels.
+kernels. ``--rounds``: the prompts are then also served by the scheduler
+loop, with one prefill step a round and with as many as a decode block has
+steps (consecutive compact steps, a ring wrapping inside a round): the
+tokens must be the same, and how many first tokens differ from the grid
+run's pick is reported (none on the CPU).
 """
 
 from __future__ import annotations
@@ -216,6 +220,45 @@ def decode(model, step, prompts, routes: Routes, record: bool):
     return np.asarray(out)[[slot for slot, _ in prompts]]
 
 
+def served(model, prompts, new_tokens: int = 24):
+    """The prompts through ``RequestManager.generate_incr_decoding`` itself,
+    once with one prefill step a scheduler round and once with as many as a
+    decode block's steps (the two programs' costs given, not timed: a
+    prefill step costs one decode step). Returns for each the tokens
+    generated, by prompt, and the prefill steps of every round."""
+    import jax
+    import jax.numpy as jnp
+
+    from flexflow_tpu.serve.request_manager import (InferenceManager,
+                                                    RequestManager)
+    from flexflow_tpu.serve.step_costs import GivenCosts
+
+    ifm = model._inference_manager = InferenceManager(model)
+    step, block = ifm.step, ifm.decode_block
+    out = []
+    for decode_step_s in (0.0, 1.0):
+        model.op_state = jax.tree.map(jnp.zeros_like, model.op_state)
+        ifm.step_costs = GivenCosts(1.0, decode_step_s)
+        rounds = [0]
+
+        def counted_step(*a, **kw):
+            rounds[-1] += 1
+            return step(*a, **kw)
+
+        def counted_block(*a, **kw):
+            rounds.append(0)
+            return block(*a, **kw)
+
+        ifm.step, ifm.decode_block = counted_step, counted_block
+        rm = RequestManager()
+        guids = [rm.register_new_request(list(toks), max_new_tokens=new_tokens)
+                 for _, toks in prompts]
+        rm.generate_incr_decoding(model)
+        out.append(([rm.results[g].output_tokens for g in guids], rounds))
+    ifm.step, ifm.decode_block = step, block
+    return out
+
+
 def written(model, c: str, slot: int, n: int):
     """What ``slot``'s cache ``c`` ("k" or "v") holds of its first ``n``
     positions, one float32 ``[KH, positions, D]`` per attention layer in
@@ -237,7 +280,7 @@ def written(model, c: str, slot: int, n: int):
         yield np.asarray(rows, np.float32)
 
 
-def check(name: str, rehearse: bool) -> dict:
+def check(name: str, rehearse: bool, rounds: bool = False) -> dict:
     import jax
     import jax.numpy as jnp
 
@@ -296,7 +339,28 @@ def check(name: str, rehearse: bool) -> dict:
            / np.linalg.norm(lg_g, axis=-1))
     overridden = [routes.overridden.count(layer)
                   for layer in range(routes.layers)]
-    return {"config": name, "layers": layers, "prompts": len(prompts),
+    through_loop = {}
+    if rounds:
+        # the scheduler's rounds: several consecutive compact steps before
+        # a decode block (a windowed layer's ring wraps inside a round)
+        # give every request the tokens one step a round gives it, and the
+        # first of them is the grid run's pick
+        (one, rounds_one), (many, rounds_many) = served(model, prompts)
+        through_loop = {
+            "served_steps_a_round_max": [max(rounds_one), max(rounds_many)],
+            "served_rounds": [len(rounds_one), len(rounds_many)],
+            "served_steps_by_round": rounds_many,
+            "served_positions_a_round_max": max(rounds_many) * segments * chunk,
+            "ring_rows": sorted({int(leaf.shape[-2]) for leaf in
+                                 model.op_state.get("kv_cache_window",
+                                                    {}).values()}),
+            "served_tokens_equal": one == many,
+            "served_first_tokens_off_the_grid": sum(
+                int(toks[0] != int(np.argmax(lg_g[i])))
+                for i, toks in enumerate(many)),
+        }
+    return {"config": name, **through_loop, "layers": layers,
+            "prompts": len(prompts),
             "program": [segments, chunk], "steps_compact": steps_c,
             "steps_grid": steps_g,
             "positions_compared": sum(len(t) - 1 for _, t in prompts),
@@ -313,7 +377,8 @@ def check(name: str, rehearse: bool) -> dict:
                        and float(rel.max()) < TOL_LOGITS
                        and routes.tie < getattr(family, "ROUTE_MARGIN",
                                                 TOL_TIE)
-                       and sum(overridden) <= 0.1 * max(1, len(routes.picks))),
+                       and sum(overridden) <= 0.1 * max(1, len(routes.picks))
+                       and through_loop.get("served_tokens_equal", True)),
             "device": jax.devices()[0].device_kind}
 
 
@@ -321,6 +386,9 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("configs", nargs="*", default=list(CONFIGS))
     ap.add_argument("--rehearse", action="store_true")
+    ap.add_argument("--rounds", action="store_true",
+                    help="also serve the prompts through the scheduler loop, "
+                         "with one prefill step a round and with several")
     args = ap.parse_args(argv)
     if args.rehearse:
         os.environ["JAX_PLATFORMS"] = "cpu"
@@ -332,7 +400,7 @@ def main(argv=None) -> int:
         return 2
     ok = True
     for name in args.configs:
-        res = check(name, args.rehearse)
+        res = check(name, args.rehearse, args.rounds)
         print(json.dumps(res), flush=True)
         ok = ok and res["ok"]
     return 0 if ok else 1
