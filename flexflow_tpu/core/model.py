@@ -339,6 +339,42 @@ class FFModel:
                 "max_step_tokens": self.config.max_tokens_per_batch})),
             name)
 
+    def inc_multihead_latent_attention(
+            self, input: Tensor, embed_dim: int, num_heads: int,
+            q_lora_rank: int, kv_lora_rank: int, qk_nope_head_dim: int,
+            qk_rope_head_dim: int, v_head_dim: int, softmax_scale: float,
+            rope_inv_freq, rope_theta: float = 10000.0,
+            rope_factor: float = 1.0, pos_scale_beta: float = 0.0,
+            pos_scale_period: int = 1, norm_eps: float = 1e-6,
+            data_type: Optional[DataType] = None, kernel_initializer=None,
+            name=None) -> Tensor:
+        """Multi-head latent attention for incremental decoding
+        (ops/latent_attention.py): the layer caches one shared entry a
+        position, the normed ``kv_lora_rank`` latent and the rotated
+        ``qk_rope_head_dim`` key part, and attends it in the absorbed form.
+        ``rope_inv_freq``: the rotary frequency table (``qk_rope_head_dim /
+        2`` numbers), times ``rope_factor`` on cos and sin;
+        ``pos_scale_beta``/``pos_scale_period``: a query is scaled by ``1 +
+        beta * ln(1 + floor(p / period))`` of its own position."""
+        assert len(rope_inv_freq) * 2 == qk_rope_head_dim, (
+            len(rope_inv_freq), qk_rope_head_dim)
+        return self._add_layer(OpType.INC_MULTIHEAD_LATENT_ATTENTION, [input],
+                               dict(
+            embed_dim=embed_dim, num_q_heads=num_heads,
+            q_lora_rank=q_lora_rank, kv_lora_rank=kv_lora_rank,
+            qk_nope_head_dim=qk_nope_head_dim,
+            qk_rope_head_dim=qk_rope_head_dim, v_head_dim=v_head_dim,
+            softmax_scale=float(softmax_scale),
+            rope_inv_freq=tuple(float(f) for f in rope_inv_freq),
+            rope_theta=rope_theta, rope_factor=float(rope_factor),
+            pos_scale_beta=float(pos_scale_beta),
+            pos_scale_period=int(pos_scale_period), norm_eps=norm_eps,
+            data_type=data_type, kernel_initializer=kernel_initializer,
+            max_requests=self.config.max_requests_per_batch,
+            max_seq_length=self.config.max_sequence_length,
+            use_pallas=self.config.use_pallas,
+            cache_dtype=self.config.kv_cache_dtype), name)
+
     def inc_multihead_self_attention(self, input: Tensor, embed_dim: int,
                                      num_heads: int, **kw) -> Tensor:
         return self.inc_multiquery_self_attention(input, embed_dim, num_heads,
@@ -1134,13 +1170,34 @@ class FFModel:
         attrs["cache_layer_idx"]; see ops/inc_attention.py read_kv/write_kv.
         A model with windowed layers has one pair of stacks a kind: the
         full caches as ever, the rings under inc_attention.WINDOW_STACK
-        (those layers carry attrs["cache_stack"] too).
+        (those layers carry attrs["cache_stack"] too). Latent layers
+        (ops/latent_attention.py) keep one stream each: their stack, of any
+        depth, is inc_attention.LATENT_STACK.
         """
-        from flexflow_tpu.ops.inc_attention import FULL_STACK, WINDOW_STACK
+        from flexflow_tpu.ops.inc_attention import (FULL_STACK, LATENT_STACK,
+                                                    WINDOW_STACK)
 
+        by_name = {layer.name: layer for layer in self.layers}
+        latent = [n for n, st in self.op_state.items()
+                  if isinstance(st, dict) and "c_cache" in st]
+        if latent:
+            (shape,) = {self.op_state[n]["c_cache"].shape for n in latent}
+            (dtype,) = {self.op_state[n]["c_cache"].dtype for n in latent}
+            for i, n in enumerate(latent):
+                by_name[n].attrs["cache_layer_idx"] = i
+                del self.op_state[n]
+            self.op_state[LATENT_STACK] = {
+                "c": jnp.zeros((len(latent),) + shape, dtype)}
+            # what telemetry says of the kind (ffsv_kv_cache_bytes,
+            # ffsv_attn_positions_read_total)
+            self.attention_kinds = {"latent": {
+                "layers": len(latent), "window": None,
+                "cache_bytes": self.op_state[LATENT_STACK]["c"].nbytes}}
         names = [n for n, st in self.op_state.items()
                  if isinstance(st, dict) and "k_cache" in st]
-        by_name = {layer.name: layer for layer in self.layers}
+        if latent and names:
+            raise NotImplementedError(
+                "latent attention layers beside k/v ones in one model")
         rings = [n for n in names
                  if by_name[n].attrs.get("sliding_window") is not None]
         if rings:

@@ -193,3 +193,6 @@ class OpType(enum.Enum):
     FUSED_PARALLEL = enum.auto()
     # fused
     FUSED = enum.auto()
+    # serving attention over a latent cache (ops/latent_attention.py); last,
+    # so that no earlier member's value moved
+    INC_MULTIHEAD_LATENT_ATTENTION = enum.auto()

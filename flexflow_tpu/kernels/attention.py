@@ -668,3 +668,301 @@ def reference_attend(q, k_cache, v_cache, lengths, qpos, bias=None,
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("rkgqs,rksd->rqkgd", p.astype(q.dtype), vc)
     return out.reshape(R, Q, H * D).astype(out_dtype)
+
+
+# ----------------------------------------------------------------------
+# Latent (MLA) attention: one stored stream a layer, read as keys and as
+# values (ops/kv_layout.py ``latent_*``, ops/latent_attention.py).
+# ----------------------------------------------------------------------
+def _pick_latent_blocks(S: int):
+    """(DMA block, softmax block) of the latent kernel, in positions, or
+    (0, 0) where ``S`` cannot be tiled. An entry is a few hundred bytes, so
+    a DMA takes as many positions as divide ``S`` (up to 1024: 0.8 MB at
+    384 lanes) to pay for its fixed cost; the online softmax advances in
+    sub-blocks of that block. As ``_pick_block_s`` states, the softmax
+    partition depends on ``S`` alone, never on the query width."""
+    if S % LANE:
+        return 0, 0
+    sb = 256 if S % 256 == 0 else 128
+    for db in (1024, 512, 256, 128):
+        if S % db == 0 and db % sb == 0:
+            return db, sb
+    return 0, 0
+
+
+def supports_latent(S: int, width: int, rank: int) -> bool:
+    """True iff ``flash_attend_latent`` can tile a latent cache of ``S``
+    positions of ``width`` stored values, the first ``rank`` the latent."""
+    return (width % LANE == 0 and rank % LANE == 0
+            and _pick_latent_blocks(S)[0] > 0)
+
+
+# rows of a write-back window: a whole packed tile of a 16-bit cache
+LATENT_APPEND_ROWS = 16
+
+
+def _latent_kernel(len_ref, *refs, mode, DB: int, SB: int, rank: int,
+                   qk_scale: float, layer_idx):
+    """Program ``r`` attends queries ``q [GQ, W]`` (all heads of all the
+    row's query tokens) over cache row ``r``. Every fetched block ``[DB, W]``
+    serves the scores (all ``W`` lanes) and the values (its first ``rank``
+    lanes). ``mode`` "rows": the compact prefill batch's row map, program
+    ``r`` reads cache row ``rows[r]``. ``mode`` "append" (decode): the row's
+    new entry lands at position ``appos[r]`` IN PLACE (the cache is aliased
+    in/out), merged into the streamed block in VMEM and its aligned window
+    written back, as ``_append_kernel`` does for a k/v pair. The
+    cross-program DMA pipeline is ``_stream_attend``'s."""
+    rows_ref = appos_ref = new_ref = asem = None
+    if mode == "append":
+        (appos_ref, q_ref, qp_ref, new_ref, _, o_ref, c_hbm, acc, m, l, cbuf,
+         sem, asem) = refs
+    else:
+        if mode == "rows":
+            rows_ref, *refs = refs
+        q_ref, qp_ref, c_hbm, o_ref, acc, m, l, cbuf, sem = refs
+    r = pl.program_id(0)
+    R = len_ref.shape[0]
+    length = len_ref[r]
+
+    def nb_of(j):
+        return (len_ref[j] + jnp.asarray(DB - 1, jnp.int32)) // DB
+
+    def row_of(j):
+        return j if rows_ref is None else rows_ref[j]
+
+    nb = nb_of(r)
+    acc[:] = jnp.zeros_like(acc)
+    m[:] = jnp.full_like(m, NEG_INF)
+    l[:] = jnp.zeros_like(l)
+    if layer_idx is not None:
+        c_hbm = c_hbm.at[layer_idx]
+
+    def _pipe_scan(j, carry):
+        g0, prev_live, r_next = carry
+        nbj = nb_of(j)
+        g0 = g0 + jnp.where(j < r, nbj, 0)
+        prev_live = prev_live | ((j < r) & (nbj > 0))
+        r_next = jnp.where((j > r) & (nbj > 0) & (r_next == R), j, r_next)
+        return g0, prev_live, r_next
+
+    g0, prev_live, r_next = jax.lax.fori_loop(
+        0, R, _pipe_scan,
+        (jnp.int32(0), jnp.asarray(False), jnp.int32(R)))
+
+    def dma(row, slot, i):
+        return pltpu.make_async_copy(
+            c_hbm.at[row, 0, pl.ds(i * DB, DB)], cbuf.at[slot], sem.at[slot])
+
+    @pl.when((nb > 0) & jnp.logical_not(prev_live))
+    def _():
+        dma(row_of(r), g0 % 2, 0).start()
+
+    GQ = q_ref.shape[-2]
+    qp = qp_ref[r]                                  # [GQ] absolute positions
+    q = q_ref[0]                                    # [GQ, W]
+    if mode == "append":
+        p_app = appos_ref[r]
+        bp = p_app // DB                  # the block holding the new position
+        A = LATENT_APPEND_ROWS
+
+        def writeback(slot):
+            at = ((p_app - bp * DB) // A) * A       # within the block
+            return pltpu.make_async_copy(
+                cbuf.at[slot, pl.ds(pl.multiple_of(at, A), A)],
+                c_hbm.at[r, 0, pl.ds(pl.multiple_of(bp * DB + at, A), A)],
+                asem.at[0])
+
+    def body(i, _):
+        slot = (g0 + i) % 2
+        nxt_slot = (g0 + i + 1) % 2
+
+        @pl.when(i + 1 < nb)
+        def _():
+            dma(row_of(r), nxt_slot, i + 1).start()
+
+        @pl.when((i + 1 == nb) & (r_next < R))
+        def _():
+            dma(row_of(r_next), nxt_slot, 0).start()
+
+        dma(row_of(r), slot, i).wait()
+        if mode == "append":
+            @pl.when(i == bp)
+            def _():
+                # merge the new entry into the streamed block (attention
+                # then sees the cache as after the append) and write its
+                # aligned window back; rows before it re-land as they
+                # were, rows after it hold nothing valid yet
+                sub_ids = jax.lax.broadcasted_iota(jnp.int32, cbuf.shape[1:],
+                                                   0)
+                cbuf[slot] = jnp.where(sub_ids == p_app - bp * DB,
+                                       new_ref[0], cbuf[slot])
+                writeback(slot).start()
+        # the sub-blocks of this block that hold a valid position
+        live = jnp.minimum((length - i * DB + (SB - 1)) // SB, DB // SB)
+
+        def sub(j, _):
+            off = pl.multiple_of(j * SB, SB)
+            c = cbuf[slot, pl.ds(off, SB), :]       # [SB, W]
+            s = jax.lax.dot_general(
+                q.astype(c.dtype), c,
+                dimension_numbers=(((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)     # [GQ, SB]
+            s = s * qk_scale
+            s_ids = (i * DB + off
+                     + jax.lax.broadcasted_iota(jnp.int32, (GQ, SB), 1))
+            visible = (s_ids <= qp[:, None]) & (s_ids < length)
+            s = jnp.where(visible, s, NEG_INF)
+            m_new = jnp.maximum(m[:], jnp.max(s, axis=-1, keepdims=True))
+            corr = jnp.exp(m[:] - m_new)
+            p = jnp.exp(s - m_new)
+            l[:] = l[:] * corr + jnp.sum(p, axis=-1, keepdims=True)
+            pv = jax.lax.dot_general(
+                p.astype(c.dtype), c[:, :rank],
+                dimension_numbers=(((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)     # [GQ, rank]
+            acc[:] = acc[:] * corr + pv
+            m[:] = m_new
+            return 0
+
+        jax.lax.fori_loop(0, live, sub, 0)
+        if mode == "append":
+            @pl.when(i == bp)
+            def _():
+                # before the slot is reused and the next layer reads the
+                # region through the alias
+                writeback(slot).wait()
+        return 0
+
+    jax.lax.fori_loop(0, nb, body, 0)
+    o_ref[:] = (acc[:] / jnp.maximum(l[:], 1e-30))[None].astype(o_ref.dtype)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("rank", "qk_scale", "interpret", "out_dtype",
+                     "layer_idx"))
+def flash_attend_latent(q, cache, lengths, qpos, rows=None, append=None, *,
+                        rank: int, qk_scale: float, out_dtype=None,
+                        layer_idx=None, interpret=False):
+    """Causal attention of absorbed queries over a latent cache.
+
+    q        [R, Q, H, W]   a token's queries carried into the stored
+                            space, laid out as an entry is
+                            (ops/kv_layout.latent_query; any scale by
+                            position already applied)
+    cache    [R, 1, S, W]   the latent cache as stored; or the stack
+                            [L, R, 1, S, W] with ``layer_idx``. One stream:
+                            each block is fetched once, scored against
+                            whole and its first ``rank`` lanes taken as the
+                            values
+    lengths  [R] int32      valid cache extent per row (0 => skip), the new
+                            entries included
+    qpos     [R, Q] int32   absolute position of each query token
+    rows     [R] int32      optional row map (the compact prefill batch,
+                            whose entries are appended before the call)
+    append   (entry [R, 1, 1, W], appos [R] int32)   decode fused append:
+                            each row's new entry is written at position
+                            appos[r] (< 0: skip) IN PLACE before attending;
+                            the cache is aliased in/out, the call returns
+                            (out, cache) and the passed cache is consumed.
+                            Not with ``rows``.
+    returns  [R, Q, H, rank]: softmax-weighted latents, still to be carried
+                            out through the value half of the up-projection
+    The device operation is ``flash_attend_latent``.
+    """
+    assert rows is None or append is None, "no fused append by row map"
+    R, Q, H, W = q.shape
+    S = cache.shape[-2]
+    assert cache.shape[-1] == W and cache.shape[-3] == 1, (q.shape,
+                                                           cache.shape)
+    DB, SB = _pick_latent_blocks(S)
+    assert supports_latent(S, W, rank), (S, W, rank)
+    GQ = H * Q
+    out_dtype = out_dtype or q.dtype
+    # [R, Q, H, W] -> [R, H*Q, W], row index h*Q + q
+    qt = q.transpose(0, 2, 1, 3).reshape(R, GQ, W)
+    qp_gq = jnp.tile(qpos.astype(jnp.int32), (1, H))            # [R, GQ]
+    lengths = jnp.minimum(lengths.astype(jnp.int32), S)
+    mode = "rows" if rows is not None else (
+        "append" if append is not None else None)
+    isz = cache.dtype.itemsize
+    compiler_params = pltpu.CompilerParams(
+        vmem_limit_bytes=int(min(
+            100 * 1024 * 1024,
+            2 * GQ * (W + rank) * q.dtype.itemsize     # q, o double-buffered
+            + GQ * (rank + 2 * LANE) * 4               # acc, m, l
+            + 4 * DB * W * isz                         # the stream's buffers
+            + 6 * GQ * SB * 4                          # scores and their kin
+            + 2 * GQ * rank * 4 + 4 * 1024 * 1024)))
+    cost_estimate = pl.CostEstimate(
+        flops=2 * R * GQ * S * (W + rank),
+        bytes_accessed=R * S * W * isz,
+        transcendentals=R * GQ * S)
+    kern = functools.partial(
+        _latent_kernel, mode=mode, DB=DB, SB=SB, rank=rank,
+        qk_scale=float(qk_scale), layer_idx=layer_idx)
+    q_specs = [
+        pl.BlockSpec((1, GQ, W), lambda r, *_: (r, 0, 0),
+                     memory_space=pltpu.VMEM),                   # qt
+        pl.BlockSpec(memory_space=pltpu.VMEM),                   # qp [R, GQ]
+    ]
+    hbm = pl.BlockSpec(memory_space=pl.ANY)                      # the cache
+    o_spec = pl.BlockSpec((1, GQ, rank), lambda r, *_: (r, 0, 0),
+                          memory_space=pltpu.VMEM)
+    scratch = [
+        pltpu.VMEM((GQ, rank), jnp.float32),                     # acc
+        pltpu.VMEM((GQ, 1), jnp.float32),                        # m
+        pltpu.VMEM((GQ, 1), jnp.float32),                        # l
+        pltpu.VMEM((2, DB, W), cache.dtype),                     # stream
+        pltpu.SemaphoreType.DMA((2,)),
+    ]
+    o_shape = jax.ShapeDtypeStruct((R, GQ, rank), out_dtype)
+
+    def post(out):
+        return out.reshape(R, H, Q, rank).transpose(0, 2, 1, 3)
+
+    if append is None:
+        prefetch = [lengths] + ([] if rows is None
+                                else [rows.astype(jnp.int32)])
+        out = pl.pallas_call(
+            kern, grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=len(prefetch), grid=(R,),
+                in_specs=q_specs + [hbm], out_specs=o_spec,
+                scratch_shapes=scratch),
+            out_shape=o_shape, compiler_params=compiler_params,
+            cost_estimate=cost_estimate, interpret=interpret,
+            name="flash_attend_latent",
+        )(*prefetch, qt, qp_gq, cache)
+        return post(out)
+    entry, appos = append
+    out, cache = pl.pallas_call(
+        kern, grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(R,),
+            in_specs=q_specs + [
+                pl.BlockSpec((1, 1, W), lambda r, *_: (r, 0, 0),
+                             memory_space=pltpu.VMEM), hbm],
+            out_specs=(o_spec, hbm),
+            scratch_shapes=scratch + [pltpu.SemaphoreType.DMA((1,))]),
+        out_shape=(o_shape, jax.ShapeDtypeStruct(cache.shape, cache.dtype)),
+        input_output_aliases={5: 1},        # the cache operand -> output
+        compiler_params=compiler_params, cost_estimate=cost_estimate,
+        interpret=interpret, name="flash_attend_latent",
+    )(lengths, appos.astype(jnp.int32), qt, qp_gq,
+      entry.reshape(R, 1, W).astype(cache.dtype), cache)
+    return post(out), cache
+
+
+def reference_attend_latent(q, cache, lengths, qpos, *, rank: int,
+                            qk_scale: float, out_dtype=None):
+    """Pure-jnp oracle of ``flash_attend_latent``: q [R, Q, H, W] over one
+    layer's cache [R, 1, S, W] (rows already gathered) -> [R, Q, H, rank]."""
+    S = cache.shape[-2]
+    c = cache[:, 0].astype(q.dtype)                             # [R, S, W]
+    s = jnp.einsum("rqhw,rsw->rhqs", q, c,
+                   preferred_element_type=jnp.float32) * qk_scale
+    s_ids = jnp.arange(S)[None, None, :]
+    visible = (s_ids <= qpos[:, :, None]) & (s_ids < lengths[:, None, None])
+    s = jnp.where(visible[:, None], s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    out = jnp.einsum("rhqs,rsc->rqhc", p.astype(q.dtype), c[..., :rank])
+    return out.astype(out_dtype or q.dtype)

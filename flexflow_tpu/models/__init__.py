@@ -2,7 +2,7 @@
 
 Capability parity with the reference model zoo (reference inference/models/
 llama.cc, opt.cc, falcon.cc, mpt.cc, starcoder.cc and their Python twins in
-python/flexflow/serve/models/; OLMoE and EXAONE-MoE, sparse-expert families, are beyond it): each model family is a builder that records
+python/flexflow/serve/models/; OLMoE, EXAONE-MoE and Mistral-4, sparse-expert families, are beyond it): each model family is a builder that records
 the decoder graph through the FFModel op-builder surface, plus a HuggingFace
 state-dict name mapping so real checkpoints load. ``FAMILIES`` maps the HF
 ``model_type`` to the family (the reference's ModelType enum +
@@ -15,6 +15,7 @@ from typing import Callable, Optional
 from flexflow_tpu.models import exaone_moe as _exaone_moe
 from flexflow_tpu.models import falcon as _falcon
 from flexflow_tpu.models import llama as _llama
+from flexflow_tpu.models import mistral4 as _mistral4
 from flexflow_tpu.models import mpt as _mpt
 from flexflow_tpu.models import olmoe as _olmoe
 from flexflow_tpu.models import opt as _opt
@@ -24,6 +25,8 @@ from flexflow_tpu.models.exaone_moe import (ExaoneMoEConfig,
 from flexflow_tpu.models.falcon import FalconConfig, create_falcon_model
 from flexflow_tpu.models.hf_utils import load_hf_state_dict
 from flexflow_tpu.models.llama import LLAMAConfig, create_llama_model
+from flexflow_tpu.models.mistral4 import (Mistral4Config,
+                                          create_mistral4_model)
 from flexflow_tpu.models.mpt import MPTConfig, create_mpt_model
 from flexflow_tpu.models.olmoe import OLMoEConfig, create_olmoe_model
 from flexflow_tpu.models.opt import OPTConfig, create_opt_model
@@ -67,6 +70,9 @@ FAMILIES = {
                               create_exaone_moe_model,
                               _exaone_moe.hf_weight_map,
                               _exaone_moe.preprocess_hf_state_dict),
+    "mistral4": ModelFamily("mistral4", Mistral4Config,
+                            create_mistral4_model, _mistral4.hf_weight_map,
+                            _mistral4.preprocess_hf_state_dict),
     "gpt_bigcode": ModelFamily("gpt_bigcode", STARCODERConfig,
                                create_starcoder_model,
                                _starcoder.hf_weight_map,
@@ -94,6 +100,7 @@ __all__ = [
     "FalconConfig",
     "LLAMAConfig",
     "MPTConfig",
+    "Mistral4Config",
     "ModelFamily",
     "OLMoEConfig",
     "OPTConfig",
@@ -101,6 +108,7 @@ __all__ = [
     "create_exaone_moe_model",
     "create_falcon_model",
     "create_llama_model",
+    "create_mistral4_model",
     "create_mpt_model",
     "create_olmoe_model",
     "create_opt_model",

@@ -145,13 +145,47 @@ def _swiglu(model, x, width: int, hidden: int, data_type, prefix: str):
                        name=f"{prefix}.down_proj")
 
 
+def sparse_layer(model, x, p: str, num_experts: int, top_k: int,
+                 scaling: float, expert_width: int, shared_experts: int,
+                 hidden: int, held, data_type):
+    """``Routed(x) + Shared(x)`` of the family's sparse layer, recorded
+    under the checkpoint's names below ``p`` (``layers.{i}.mlp``): the
+    router's graph ops, the held routed experts, the shared expert. Also
+    what models/mistral4.py builds."""
+    from flexflow_tpu.core.initializer import NormInitializer
+
+    # float32 router logits (the gemm's accumulator), as OLMoE's: the
+    # scores, the choice and the weights are made in float32
+    logits = model.dense(x, num_experts, use_bias=False,
+                         datatype=data_type, keep_f32_logits=True,
+                         name=f"{p}.gate")
+    scores = model.sigmoid(logits, name=f"{p}.scores")
+    # the checkpoint's per-expert selection bias: it moves the choice
+    # and never the weight. Seeded non-zero, so that a test sees it
+    bias = model.parameter(
+        [num_experts], DataType.DT_FLOAT,
+        initializer=NormInitializer(stddev=0.05),
+        name=f"{p}.gate.e_score_correction_bias")
+    _, chosen = model.top_k(model.add(scores, bias), top_k,
+                            name=f"{p}.top_k")
+    picked = model.gather(scores, chosen, dim=2, name=f"{p}.picked")
+    total = model.scalar_add(
+        model.reduce_sum(picked, [-1], keepdims=True), 1e-20)
+    weights = model.scalar_multiply(model.divide(picked, total), scaling,
+                                    name=f"{p}.weights")
+    routed = model.moe_experts(
+        x, chosen, weights, num_experts, expert_width,
+        data_type=data_type, held=held, name=f"{p}.experts")
+    shared = _swiglu(model, x, shared_experts * expert_width, hidden,
+                     data_type, f"{p}.shared_experts")
+    return model.add(routed, shared)
+
+
 def create_exaone_moe_model(model, config: ExaoneMoEConfig,
                             mode: InferenceMode = InferenceMode.INC_DECODING_MODE,
                             generation_config: Optional[GenerationConfig] = None,
                             data_type: DataType = DataType.DT_FLOAT):
     """Record the EXAONE-MoE decoder graph into ``model`` (an FFModel)."""
-    from flexflow_tpu.core.initializer import NormInitializer
-
     c = config
     if mode != InferenceMode.INC_DECODING_MODE:
         raise NotImplementedError(
@@ -183,33 +217,10 @@ def create_exaone_moe_model(model, config: ExaoneMoEConfig,
             h = model.add(h, _swiglu(model, x, c.intermediate_size,
                                      c.hidden_size, data_type, p))
             continue
-        # float32 router logits (the gemm's accumulator), as OLMoE's: the
-        # scores, the choice and the weights are made in float32
-        logits = model.dense(x, c.num_experts, use_bias=False,
-                             datatype=data_type, keep_f32_logits=True,
-                             name=f"{p}.gate")
-        scores = model.sigmoid(logits, name=f"{p}.scores")
-        # the checkpoint's per-expert selection bias: it moves the choice
-        # and never the weight. Seeded non-zero, so that a test sees it
-        bias = model.parameter(
-            [c.num_experts], DataType.DT_FLOAT,
-            initializer=NormInitializer(stddev=0.05),
-            name=f"{p}.gate.e_score_correction_bias")
-        _, chosen = model.top_k(model.add(scores, bias),
-                                c.num_experts_per_tok, name=f"{p}.top_k")
-        picked = model.gather(scores, chosen, dim=2, name=f"{p}.picked")
-        total = model.scalar_add(
-            model.reduce_sum(picked, [-1], keepdims=True), 1e-20)
-        weights = model.scalar_multiply(model.divide(picked, total),
-                                        c.routed_scaling_factor,
-                                        name=f"{p}.weights")
-        routed = model.moe_experts(
-            x, chosen, weights, c.num_experts, c.moe_intermediate_size,
-            data_type=data_type, held=c.held_experts, name=f"{p}.experts")
-        shared = _swiglu(model, x,
-                         c.num_shared_experts * c.moe_intermediate_size,
-                         c.hidden_size, data_type, f"{p}.shared_experts")
-        h = model.add(h, model.add(routed, shared))
+        h = model.add(h, sparse_layer(
+            model, x, p, c.num_experts, c.num_experts_per_tok,
+            c.routed_scaling_factor, c.moe_intermediate_size,
+            c.num_shared_experts, c.hidden_size, c.held_experts, data_type))
 
     x = model.rms_norm(h, eps=c.rms_norm_eps, dim=c.hidden_size, name="norm")
     logits = model.dense(x, c.vocab_size, use_bias=False,
@@ -228,24 +239,31 @@ def _experts_key(i: int, proj: str) -> str:
     return f"model.layers.{i}.mlp.experts.{proj}.weight"
 
 
+def stack_held_experts(sd, i: int, num_experts: int, first: int, count: int):
+    """Layer ``i``'s HELD experts' ``[out, in]`` Linears stacked into
+    ``[count, in, out]`` under ``_experts_key``; every expert's own entries
+    are dropped, the others' unread."""
+    from flexflow_tpu.models.hf_utils import _to_numpy
+
+    for proj, _ in _EXPERT_PROJ:
+        keys = [f"model.layers.{i}.mlp.experts.{e}.{proj}.weight"
+                for e in range(num_experts)]
+        held = keys[first:first + count]
+        if all(k in sd for k in held):
+            sd[_experts_key(i, proj)] = np.stack(
+                [_to_numpy(sd[k]).T for k in held])
+        for k in keys:
+            sd.pop(k, None)
+
+
 def preprocess_hf_state_dict(sd, config: ExaoneMoEConfig):
     """Stack the HELD experts' ``[out, in]`` Linears into ``[count, in,
     out]``; the other experts' entries are dropped unread."""
-    from flexflow_tpu.models.hf_utils import _to_numpy
-
     first, count = config.held
     for i, kind in enumerate(config.mlp_layer_types):
         if kind != "sparse":
             continue
-        for proj, _ in _EXPERT_PROJ:
-            keys = [f"model.layers.{i}.mlp.experts.{e}.{proj}.weight"
-                    for e in range(config.num_experts)]
-            held = keys[first:first + count]
-            if all(k in sd for k in held):
-                sd[_experts_key(i, proj)] = np.stack(
-                    [_to_numpy(sd[k]).T for k in held])
-            for k in keys:
-                sd.pop(k, None)
+        stack_held_experts(sd, i, config.num_experts, first, count)
     for k in [k for k in sd if k.startswith("mtp.") or ".mtp." in k]:
         del sd[k]                   # the prediction head is not loaded
 

@@ -14,6 +14,7 @@ from flexflow_tpu.ops import (  # noqa: F401
     elementwise,
     embedding,
     inc_attention,
+    latent_attention,
     linear,
     matmul,
     moe,

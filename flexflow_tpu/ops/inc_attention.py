@@ -42,10 +42,16 @@ from flexflow_tpu.ops.base import OpImpl, register_op, register_op_as
 # which is the alignment oracle for the model zoo).
 # ----------------------------------------------------------------------
 def rotary_cos_sin(positions: jnp.ndarray, head_dim: int, theta: float,
-                   dtype) -> tuple:
-    """positions [R, Q] -> cos/sin [R, Q, head_dim]."""
-    inv_freq = 1.0 / (theta ** (
-        jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+                   dtype, inv_freq=None) -> tuple:
+    """positions [R, Q] -> cos/sin [R, Q, head_dim]. ``inv_freq``
+    (``head_dim / 2`` numbers): the frequency table itself, where it is not
+    ``theta``'s (a scaled rotary embedding such as YaRN is such a table:
+    models/mistral4.yarn_inv_freq)."""
+    if inv_freq is None:
+        inv_freq = 1.0 / (theta ** (
+            jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+    else:
+        inv_freq = jnp.asarray(inv_freq, jnp.float32)
     angles = positions[..., None].astype(jnp.float32) * inv_freq  # [R,Q,D/2]
     angles = jnp.concatenate([angles, angles], axis=-1)           # [R,Q,D]
     return jnp.cos(angles).astype(dtype), jnp.sin(angles).astype(dtype)
@@ -495,18 +501,28 @@ def _project_out(attrs, params, ctx, attn_out):
 # Stacking cuts the donated-arg count from 2*L to 2 and lets tree-commit
 # run vectorized over layers. A model with windowed layers beside full
 # ones has a stack a kind: the windowed layers' rings are
-# op_state[WINDOW_STACK] and each carries attrs["cache_stack"].
+# op_state[WINDOW_STACK] and each carries attrs["cache_stack"]. A latent
+# layer (ops/latent_attention.py) keeps ONE stream, not a pair: its stack is
+# op_state[LATENT_STACK] = {"c": [L, R, 1, S, W]}.
 # ----------------------------------------------------------------------
 FULL_STACK, WINDOW_STACK = "kv_cache", "kv_cache_window"
+LATENT_STACK = "kv_cache_latent"
 
 
 def refuse_windowed(op_state, what: str):
-    """A ring holds a slot's last positions only, by ``p % rows``: what
-    moves, copies or shards cache positions by their index says so."""
+    """A ring holds a slot's last positions only, by ``p % rows``, and a
+    latent layer one shared entry a position, not a k/v pair: what moves,
+    copies or shards cache positions by their index, a pair at a time,
+    says so."""
     if WINDOW_STACK in (op_state or {}):
         raise NotImplementedError(
             f"{what} is not supported over a windowed attention layer: its "
             "cache is a ring (ops/kv_layout.py), not every position")
+    if LATENT_STACK in (op_state or {}):
+        raise NotImplementedError(
+            f"{what} is not supported over a latent attention layer: its "
+            "cache is one shared entry a position (ops/kv_layout.py), not "
+            "a k/v pair")
 
 
 def _stack(ctx, attrs):

@@ -28,6 +28,19 @@ the positions is block ``b % (rows / block)`` of the ring and the kernel
 streams it unchanged. A ring as long as the op's ``max_seq_length`` never
 wraps and the same arithmetic is the identity. The functions below
 (``ring_*``) are the only place that knows it.
+
+A LATENT layer (multi-head latent attention, ops/latent_attention.py) stores
+ONE entry a position, shared by all its query heads: the normed latent
+(``rank`` values, which the kernel reads as keys AND as values) and beside
+it the rotated key part (``rope`` values, keys only). The entry is one
+stream, ``[R, 1, S, width]``, position-major: ``[latent | rope | zeros]``
+with ``width`` the entry rounded up to whole 128-lane tiles where the
+kernel serves it (the chip tiles a minor dim to 128 lanes anyway, so a
+320-value entry costs its 384 lanes whether they are asked for or not, and
+the kernel's DMA slices need them lane-full), the exact entry elsewhere.
+The leading ``1`` is the one shared "head", so the appends of the other
+kinds (a ``[R, Q, KH, D]`` run into ``[R, KH, S, D]``) write it unchanged.
+``latent_*`` below are the only place that knows it.
 """
 
 from __future__ import annotations
@@ -183,3 +196,43 @@ def ring_pieces(start, Q: int, rows: int):
     r0 = ring_row(start, rows)
     a = jnp.minimum(r0, rows - Q)
     return [(a, r0 - a), (jnp.zeros_like(r0), r0 - rows)]
+
+
+def latent_width(rank: int, rope: int, want_pallas: bool) -> int:
+    """Stored values a position of a latent layer: the entry itself, in
+    whole lane tiles where the latent kernel will read it."""
+    return round_up(rank + rope, LANE) if want_pallas else rank + rope
+
+
+def latent_cache_shape(R: int, max_seq: int, width: int):
+    return (R, 1, max_seq, width)
+
+
+def _as_entry(latent, rope, width: int):
+    """``[.., rank]`` and ``[.., rope]`` side by side in ``width`` values."""
+    parts = [latent, rope.astype(latent.dtype)]
+    pad = width - latent.shape[-1] - rope.shape[-1]
+    if pad:
+        parts.append(jnp.zeros(latent.shape[:-1] + (pad,), latent.dtype))
+    return jnp.concatenate(parts, axis=-1)
+
+
+def latent_entry(latent, rope, width: int):
+    """``latent [R, Q, rank]`` and ``rope [R, Q, rope]`` as the stored run
+    ``[R, Q, 1, width]`` the appends take."""
+    return _as_entry(latent, rope, width)[:, :, None, :]
+
+
+def latent_query(q_latent, q_rope, width: int):
+    """``q_latent [R, Q, H, rank]`` and ``q_rope [R, Q, H, rope]`` laid out
+    as an entry is, ``[R, Q, H, width]``: one contraction over the stored
+    lanes is then the whole score."""
+    return _as_entry(q_latent, q_rope, width)
+
+
+def read_latent(cache, a: int, b: int, rank: int, rope: int, at=()):
+    """Positions ``[a, b)`` (static) of a latent cache ``[.., 1, S, width]``
+    as ``(latent [.., b-a, rank], rope [.., b-a, rope])``; ``at`` indexes
+    leading dims, as in ``read_positions``."""
+    rows = cache[tuple(at) + (Ellipsis, 0, slice(a, b), slice(None))]
+    return rows[..., :rank], rows[..., rank:rank + rope]

@@ -15,6 +15,7 @@ scale-only): q = round(w / s), s = max|w_col| / qmax.
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, Optional
 
 import jax
@@ -83,19 +84,37 @@ def quantize_array(w, qtype: str) -> QuantizedWeight:
         raise NotImplementedError(
             f"{qtype} for a stacked [E, in, out] weight {w.shape}: only int8 "
             "is implemented (int4's row packing is 2-D)")
-    qmax = 127.0 if qtype == "int8" else 7.0
-    scale = jnp.max(jnp.abs(w), axis=-2) / qmax           # [out] | [E, out]
-    scale = jnp.where(scale == 0, 1.0, scale).astype(jnp.float32)
-    q = jnp.clip(jnp.round(w / scale[..., None, :]), -qmax,
-                 qmax).astype(jnp.int8)
-    rows = int(w.shape[-2])
+    # (qmax an operand: XLA turns a division by a constant into a
+    # multiplication by its reciprocal, which is another last bit)
+    q, scale = _quantize(w, jnp.float32(127.0 if qtype == "int8" else 7.0),
+                         qtype)
+    return QuantizedWeight(qtype, q, scale, int(w.shape[-2]), str(w.dtype))
+
+
+@functools.partial(jax.jit, static_argnames=("qtype",))
+def _quantize(w, qmax, qtype: str):
+    """(payload, scale) of ``quantize_array``: ONE program a weight shape.
+    As eager operations these were eight programs a shape, most of the
+    programs a cold build compiles (PERF.md section 6, PR 35). The numbers
+    are the eager ones to the bit: the scale is ``max|w| / qmax`` ROUNDED TO
+    ``w``'s OWN TYPE, as the eager division of a bfloat16 weight rounded
+    it; inside one fused program XLA would keep the quotient's float32
+    bits (``xla_allow_excess_precision``), so the rounding is spelled out
+    with ``reduce_precision``, which no pass removes."""
+    fi = jnp.finfo(w.dtype)
+    scale = jax.lax.reduce_precision(
+        jnp.max(jnp.abs(w), axis=-2).astype(jnp.float32) / qmax,
+        exponent_bits=fi.nexp, mantissa_bits=fi.nmant)    # [out] | [E, out]
+    scale = jnp.where(scale == 0, 1.0, scale)
+    q = jnp.clip(jnp.round(w.astype(jnp.float32) / scale[..., None, :]),
+                 -qmax, qmax).astype(jnp.int8)
     if qtype == "int4":
         if q.shape[0] % 2:
             q = jnp.pad(q, ((0, 1), (0, 0)))
         lo = q[0::2] & 0x0F
         hi = (q[1::2] & 0x0F) << 4
         q = (lo | hi).astype(jnp.int8)                    # [ceil(in/2), out]
-    return QuantizedWeight(qtype, q, scale, rows, str(w.dtype))
+    return q, scale
 
 
 def _unpack_int4(q, rows: int):
@@ -120,9 +139,12 @@ def is_quantized(leaf) -> bool:
 
 # weights eligible for quantization: the serving matmul weights
 _QUANT_NAMES = {"kernel", "wq", "wk", "wv", "wo", "weight",
-                "w1", "w2", "w3", "gate", "up", "down"}
-# ... and of those, the ones that may be a stack [E, in, out] (ops/moe.py)
-_STACKED_NAMES = {"gate", "up", "down"}
+                "w1", "w2", "w3", "gate", "up", "down",
+                # a latent attention layer's (ops/latent_attention.py)
+                "wq_a", "wq_b", "wkv_a", "wk_b", "wv_b"}
+# ... and of those, the ones that may be a stack [E, in, out] (ops/moe.py;
+# a latent layer's up-projection halves, a head apart)
+_STACKED_NAMES = {"gate", "up", "down", "wk_b", "wv_b"}
 
 
 def quantize_params(params: Dict[str, Dict[str, Any]], qtype: str,

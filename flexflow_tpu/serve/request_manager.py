@@ -837,7 +837,7 @@ class RequestManager:
                     act[req.slot] = True
                 self._tel_tick(tel, live, R, max_seq)
                 kinds = getattr(model, "attention_kinds", None)
-                if tel is not None and kinds:   # windowed beside full
+                if tel is not None and kinds:   # rings beside full; latent
                     tel.note_attention_reads(kinds, pos[act] + 1, block)
                 if rnd is not None:
                     rnd.phase(None)

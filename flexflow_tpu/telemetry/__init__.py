@@ -28,6 +28,7 @@ ffsv_queue_depth                 gauge      submission queue depth (front door)
 ffsv_tokens_generated_total      counter    output tokens committed
 ffsv_prefill_tokens_total        counter    prompt tokens prefilled
 ffsv_prefill_positions_total     counter    positions prefill steps computed
+ffsv_prefill_attended_pairs_total counter   (query, key) pairs prefill attended
 ffsv_round_prefill_steps         histogram  prefill steps a round dispatched
 ffsv_spec_rounds_total           counter    speculation rounds executed
 ffsv_decode_steps_total          counter    incremental decode steps
@@ -63,9 +64,11 @@ ffsv_moe_experts_touched         summary    {phase} distinct experts a call read
 ffsv_moe_expert_pairs_total      counter    {expert} routed pairs of one expert
 ===============================  =========  =================================
 
-``kind`` is ``window`` or ``full``: a model with windowed attention layers
-beside full ones (``FFModel.attention_kinds``; no other model has the two
-series) keeps a ring a windowed layer and every position a full one.
+``kind`` is ``window``, ``full`` or ``latent``: a model with windowed
+attention layers beside full ones keeps a ring a windowed layer and every
+position a full one; a model of latent layers (ops/latent_attention.py) one
+shared entry a position (``FFModel.attention_kinds``; no other model has
+these series).
 ``ffsv_kv_cache_bytes`` is what compile allocated for each kind;
 ``ffsv_attn_positions_read_total`` is what the rows of the decode steps had
 to attend, from the batch's lengths on the host: for each row of each step
@@ -260,6 +263,10 @@ class ServingTelemetry:
         self.prefill_positions = r.counter(
             "ffsv_prefill_positions_total",
             "positions prefill steps computed (batch rows x chunk)")
+        self.prefill_pairs = r.counter(
+            "ffsv_prefill_attended_pairs_total",
+            "(query, key) pairs the prefill steps' rows had to attend, "
+            "causal, in one attention layer")
         self.round_prefill_steps = r.histogram(
             "ffsv_round_prefill_steps",
             "prefill steps one round of the incremental loop dispatched "
@@ -370,7 +377,7 @@ class ServingTelemetry:
                 and MOE_COUNTERS in (model.op_state or {})):
             self._watched[id(model)] = [weakref.ref(model), 0]
         for kind, a in (getattr(model, "attention_kinds", None)
-                        or {}).items():     # windowed layers beside full
+                        or {}).items():     # rings beside full, or latent
             self.registry.gauge(
                 f'ffsv_kv_cache_bytes{{kind="{kind}"}}',
                 "bytes of the KV caches of one kind of attention layer"
@@ -540,6 +547,10 @@ class ServingTelemetry:
         self.prefill_seconds.observe(seconds)
         self.prefill_tokens.inc(n_tokens)
         self.prefill_positions.inc(positions)
+        # token t of a run from start_pos sees the start_pos + t + 1
+        # positions up to itself
+        self.prefill_pairs.inc(sum(n * sp + n * (n + 1) // 2
+                                   for _, sp, n in rows))
         if t0 is None:
             t0 = time.perf_counter() - seconds
         for guid, start_pos, n in rows:

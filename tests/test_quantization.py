@@ -170,3 +170,32 @@ def test_param_set_rejects_wrong_shape():
                                np.zeros(128, np.float32))
     with pytest.raises(KeyError):
         m.get_parameter_by_key(("layers.0.self_attn", "no_such_weight"))
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("shape,qtype", [((192, 80), "int8"),
+                                         ((3, 192, 80), "int8"),
+                                         ((191, 80), "int4")])
+def test_the_one_program_quantiser_gives_the_eager_numbers_to_the_bit(
+        dtype, shape, qtype):
+    """``quantize_array`` is one jitted program a shape; its payload and its
+    scale are what the eager operations it replaced gave (a fused bfloat16
+    quotient would keep float32 bits, a division by a constant become a
+    multiplication), so no served weight moved with it."""
+    import jax
+    import jax.numpy as jnp
+
+    from flexflow_tpu.quant import _unpack_int4
+
+    w = jax.random.normal(jax.random.PRNGKey(2), shape, jnp.dtype(dtype)) * .02
+    w = w.at[..., 1].set(0)                     # a column of zeros: scale 1
+    got = quantize_array(w, qtype)
+    qmax = 127.0 if qtype == "int8" else 7.0
+    scale = jnp.max(jnp.abs(w), axis=-2) / qmax
+    scale = jnp.where(scale == 0, 1.0, scale).astype(jnp.float32)
+    q = jnp.clip(jnp.round(w / scale[..., None, :]), -qmax,
+                 qmax).astype(jnp.int8)
+    payload = got.q if qtype == "int8" else _unpack_int4(got.q, got.rows)
+    np.testing.assert_array_equal(np.asarray(payload), np.asarray(q))
+    np.testing.assert_array_equal(np.asarray(got.scale), np.asarray(scale))
+    assert got.scale.dtype == jnp.float32 and got.shape == shape

@@ -1374,7 +1374,8 @@ def test_prefill_segments_go_to_the_oldest_admission_first():
 
 @pytest.mark.parametrize("config,rounds", [
     ("falcon-7b", False), ("olmoe-1b-7b", False),
-    ("k-exaone-236b-a23b", False), ("k-exaone-236b-a23b", True)])
+    ("k-exaone-236b-a23b", False), ("k-exaone-236b-a23b", True),
+    ("mistral-small-4-119b", False)])
 def test_check_compact_prefill_tool_rehearses(config, rounds, monkeypatch,
                                               capsys):
     """tools/check_compact_prefill.py (the on-chip check of the compact

@@ -21,7 +21,8 @@ to the experts the grid run chose (at its own probabilities for them): a
 token whose own top-k this overrode is counted and its tie measured, and
 more than a tie, or more than a tenth of the tokens, fails the check too.
 A windowed layer's ring (ops/kv_layout.py) is compared over the positions it
-still holds, a prompt's last ``ring rows``.
+still holds, a prompt's last ``ring rows``; a latent layer's one stream over
+the entry's own values (the latent and the rotated key part).
 ``--rehearse``: CPU, the configuration's tiny rehearsal sizes, interpreted
 kernels. ``--rounds``: the prompts are then also served by the scheduler
 loop, with one prefill step a round and with as many as a decode block has
@@ -46,7 +47,7 @@ sys.path.insert(0, ROOT)
 # configuration -> layers of the cut (K-EXAONE: one period, so that a full
 # layer and the windowed ones, the dense layer and the sparse ones are there)
 CONFIGS = {"falcon-7b": 2, "opt-6.7b-spec": 2, "olmoe-1b-7b": 2,
-           "k-exaone-236b-a23b": 4}
+           "k-exaone-236b-a23b": 4, "mistral-small-4-119b": 2}
 TOL_LOGITS = 0.03           # the families' REFERENCE_TOL
 # A cache row's relative L2. The first layer's rows are a projection of the
 # embedding: one bfloat16 rounding (2**-8) either way. A later layer's come
@@ -260,16 +261,25 @@ def served(model, prompts, new_tokens: int = 24):
 
 
 def written(model, c: str, slot: int, n: int):
-    """What ``slot``'s cache ``c`` ("k" or "v") holds of its first ``n``
-    positions, one float32 ``[KH, positions, D]`` per attention layer in
-    the model's order, read as stored through the layout's owner (packed
-    at D=64; a windowed layer's ring: the last positions it keeps)."""
+    """What ``slot``'s cache ``c`` ("k" or "v"; "c": a latent layer's one
+    stream) holds of its first ``n`` positions, one float32 ``[KH,
+    positions, D]`` per attention layer in the model's order, read as stored
+    through the layout's owner (packed at D=64; a windowed layer's ring: the
+    last positions it keeps; a latent entry: its own values)."""
     from flexflow_tpu.ops import kv_layout as kvl
-    from flexflow_tpu.ops.inc_attention import FULL_STACK
+    from flexflow_tpu.ops.inc_attention import FULL_STACK, LATENT_STACK
 
     S = model.config.max_sequence_length
     for ly in model.layers:
         if "cache_layer_idx" not in ly.attrs:
+            continue
+        if c == "c":
+            got = kvl.read_latent(
+                model.op_state[LATENT_STACK][c], 0, n,
+                ly.attrs["kv_lora_rank"], ly.attrs["qk_rope_head_dim"],
+                (ly.attrs["cache_layer_idx"], slot))
+            yield np.concatenate([np.asarray(a, np.float32)
+                                  for a in got], axis=-1)[None]
             continue
         stack = model.op_state[ly.attrs.get("cache_stack", FULL_STACK)][c]
         at = (ly.attrs["cache_layer_idx"], slot)
@@ -312,11 +322,12 @@ def check(name: str, rehearse: bool, rounds: bool = False) -> dict:
     # counted and its tie measured; caches and logits are then compared
     # over every position of every prompt.
     routes, got = Routes(model), {}
+    streams = ("c",) if "kv_cache_latent" in model.op_state else ("k", "v")
     for compact in (False, True):
         model.op_state = jax.tree.map(jnp.zeros_like, model.op_state)
         steps = prefill(model, fill, prompts, compact, routes)
         kv = {(c, slot, layer): rows
-              for c in ("k", "v") for slot, toks in prompts
+              for c in streams for slot, toks in prompts
               for layer, rows in enumerate(written(model, c, slot,
                                                    len(toks) - 1))}
         got[compact] = steps, kv, decode(model, one, prompts, routes,
