@@ -718,7 +718,7 @@ class RequestManager:
         output-free step, return them (none: nothing is filling). The one
         prefill path of the Python loops; the caller moves its depth marks
         by the rows returned. The speculation loops call it once a round,
-        the incremental loop as often as its decode block pays for."""
+        the incremental loop as often as StepCosts allows the round."""
         chunk, segments = shape
         compact = self._compact_prefill(ifm)
         rows = self._prefill_rows(active, chunk, depth_of, segments,
@@ -785,20 +785,32 @@ class RequestManager:
             self._prefix_install(active, (("llm", ifm),))
             if rnd is not None:
                 rnd.admitted(R - active.count(None), len(self.pending))
-            # decode-interleaved chunked prefill (ISSUE 19, 32): a round
+            # decode-interleaved chunked prefill (ISSUE 19, 32, 36): a round
             # dispatches bounded prefill steps (separate calls of the one
-            # program, outputs unused) while a request is still filling
-            # and the steps together cost no more than the decode block
-            # that follows them (StepCosts: the loop's own measurement of
-            # the two programs), AND that block for the caught-up slots.
-            # One step is always allowed. A decoding row so waits for
-            # prefill at most a block's time, and a queued short request's
+            # program, outputs unused) while a request is still filling,
+            # AND the decode block for the caught-up slots. The two sides
+            # share the round by how many requests each holds (StepCosts:
+            # the loop's own measurement of the two programs): the steps
+            # together cost no more than the block that follows them,
+            # times filling / decoding where the requests still filling
+            # their slots outnumber the rows decoding. One step is always
+            # allowed. So a decoding row waits for prefill at most a
+            # block's time while the decoders are the majority, the
+            # row-seconds stalled never pass the request-seconds the
+            # block holds the fillers off, and a queued short request's
             # TTFT does not track the longest resident prompt's prefill.
-            # With nothing decoding there is nobody to stall: the round
-            # prefills until a request has caught up.
+            # Queued requests without a slot do not count: no prefill step
+            # helps them. With nothing decoding there is nobody to stall:
+            # the round prefills until a request has caught up.
             decoding = caught_up()
-            allowed = (costs.allowance(block_steps(decoding, True))
-                       if decoding else None)
+            allowed = None
+            if decoding:
+                filling = (sum(req is not None and not req.finished
+                               for req in active) - len(decoding))
+                allowed = costs.allowance(block_steps(decoding, True),
+                                          len(decoding), filling)
+                if tel is not None:
+                    tel.note_round_allowance(allowed)
             steps, timed, t0 = 0, False, time.perf_counter()
             while allowed is None or steps < allowed:
                 rows = self._prefill(ifm, active, shape,

@@ -2,12 +2,20 @@
 incremental loop itself measures them.
 
 ``RequestManager.generate_incr_decoding`` prefills, each scheduler round,
-as many steps as the round's decode block pays for: the steps of one
-round together may take as long as the block that follows them and no
-longer, so a decoding row waits for prefill at most one block's time.
-That bound needs the two programs' cost on the model being served, and
-nothing states it ahead of time: a prefill step is 1.2 decode steps of
-OLMoE, 2.8 of K-EXAONE, 3.4-3.9 of Falcon (PERF.md section 6, PR 32).
+as many steps as the round's two sides share it by: ``steps x p <= block
+x d x max(1, filling / decoding)``, one step always. Where the decoding
+rows are the majority the steps of one round together may take as long as
+the block that follows them and no longer, so a decoding row waits for
+prefill at most one block's time (PR 32's bound, and the floor). Where
+the requests still filling their slots are the majority, the row-seconds
+the decoders are stalled (``decoding x steps x p``) may reach the
+request-seconds the fillers are held off by the block (``filling x block
+x d``) and no further: a decode block that is cheaper than a prefill step
+(few rows, a latent cache) no longer holds a queue of long prompts to one
+step a round. Both need the two programs' cost on the model being served,
+and nothing states it ahead of time: a prefill step is 1.2 decode steps
+of OLMoE, 2.8 of K-EXAONE, 3.4-3.9 of Falcon, 15 of Mistral-4 at 3 rows
+(PERF.md section 6, PR 32 and 36).
 
 A prefill step is dispatched without a fence and the decode block's
 readback fences both, so a round's wall time does not say which program
@@ -56,14 +64,18 @@ class StepCosts:
         """A decode block of ``steps`` took ``seconds`` on an idle device."""
         self._decode.append(seconds / steps)
 
-    def allowance(self, block_steps: int) -> int:
-        """The prefill steps a decode block of ``block_steps`` pays for:
-        as many as together cost no more than the block. One always; one
-        alone while either cost is still unknown."""
+    def allowance(self, block_steps: int, decoding: int, filling: int) -> int:
+        """The prefill steps a round may take before its decode block of
+        ``block_steps`` for ``decoding`` rows, with ``filling`` requests
+        in slots still short of their prompts: as many as together cost
+        no more than the block, times ``filling / decoding`` where the
+        fillers are the majority. One always; one alone while either cost
+        is still unknown."""
         if min(len(self._prefill), len(self._decode)) < self.MIN:
             return 1
         block_s = block_steps * statistics.median(self._decode)
-        return max(1, int(block_s / statistics.median(self._prefill)))
+        weight = max(1.0, filling / decoding)
+        return max(1, int(block_s * weight / statistics.median(self._prefill)))
 
 
 class GivenCosts(StepCosts):

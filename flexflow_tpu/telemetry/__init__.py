@@ -30,6 +30,7 @@ ffsv_prefill_tokens_total        counter    prompt tokens prefilled
 ffsv_prefill_positions_total     counter    positions prefill steps computed
 ffsv_prefill_attended_pairs_total counter   (query, key) pairs prefill attended
 ffsv_round_prefill_steps         histogram  prefill steps a round dispatched
+ffsv_round_prefill_allowance     histogram  prefill steps a round was allowed
 ffsv_spec_rounds_total           counter    speculation rounds executed
 ffsv_decode_steps_total          counter    incremental decode steps
 ffsv_acceptance_length           histogram  accepted draft tokens per round
@@ -270,7 +271,14 @@ class ServingTelemetry:
         self.round_prefill_steps = r.histogram(
             "ffsv_round_prefill_steps",
             "prefill steps one round of the incremental loop dispatched "
-            "(above 1: the round's decode block paid for more than one)",
+            "(above 1: the round was allowed more than one)",
+            buckets=COUNT_BUCKETS)
+        self.round_prefill_allowance = r.histogram(
+            "ffsv_round_prefill_allowance",
+            "prefill steps StepCosts allowed one round of the incremental "
+            "loop that began with a row decoding (the block's worth, times "
+            "filling / decoding where the filling requests outnumber the "
+            "decoding rows)",
             buckets=COUNT_BUCKETS)
         self.spec_rounds = r.counter(
             "ffsv_spec_rounds_total", "speculation rounds executed")
@@ -560,6 +568,11 @@ class ServingTelemetry:
         """Once per round of the incremental loop: the prefill steps it
         dispatched before its decode block, none included."""
         self.round_prefill_steps.observe(steps)
+
+    def note_round_allowance(self, allowed: int):
+        """Once per round of the incremental loop that began with a row
+        decoding: the prefill steps the rule allowed it."""
+        self.round_prefill_allowance.observe(allowed)
 
     def record_decode_block(self, seconds: float, steps: int, n_live: int,
                             guids=(), t0: Optional[float] = None):

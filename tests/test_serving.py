@@ -858,14 +858,16 @@ def _allowing(steps, block):
 
 @pytest.mark.parametrize("slots,block,steps", [
     (slots, block, None) for slots in (1, 2, 3) for block in (1, 4, 8)
-] + [(4, 4, 1), (4, 4, 2), (4, 4, 5), (4, 2, 3), (4, 1, 16)])
+] + [(4, 4, 1), (4, 4, 2), (4, 4, 5), (4, 2, 3), (4, 1, 16), (6, 4, 1)])
 def test_incr_loop_matches_closed_form(slots, block, steps):
     """Five requests through 1-3 slots in blocks of 1-8 steps: each gets
     exactly what it would generate alone (EOS inside a block, a budget
     that ends mid-block, rows refilled from the queue, one-token prompts).
     ``steps``: a queue of eight long prompts through four slots where a
-    decode block pays for that many prefill steps a round; whatever the
-    rounds held, every request's tokens are its own."""
+    decode block pays for that many prefill steps a round, times filling
+    over decoding where the requests still filling outnumber the rows
+    decoding (six slots: up to five to one); whatever the rounds held,
+    every request's tokens are its own."""
     queue = _RULE_PROMPTS if steps is None else _RULE_QUEUE
     max_seq = 24 if steps is None else 80
     cfg = ff.FFConfig(max_requests_per_batch=slots,
@@ -878,8 +880,14 @@ def test_incr_loop_matches_closed_form(slots, block, steps):
     if steps is not None:
         costs = _allowing(steps, block)
         allowance = costs.allowance
-        costs.allowance = lambda b: asked.append(
-            (len(ifm.decodes), allowance(b))) or asked[-1][1]
+
+        def asking(b, decoding, filling):
+            asked.append(types.SimpleNamespace(
+                round=len(ifm.decodes), block=b, decoding=decoding,
+                filling=filling, allowed=allowance(b, decoding, filling)))
+            return asked[-1].allowed
+
+        costs.allowance = asking
     ifm = _RuleIFM(costs=costs)
     res = rm.generate_incr_decoding(_rule_model(cfg, ifm))
     assert rm.scheduler_loop == "python"
@@ -896,20 +904,34 @@ def test_incr_loop_matches_closed_form(slots, block, steps):
         assert max(int(act.sum()) for _, _, act, _ in ifm.decodes) == slots
         return
     # the first round has nothing decoding and prefills until a request
-    # has caught up; a round that begins with a row decoding asks what
-    # its block pays for, and keeps to it
-    assert ifm.rounds[0] == 6 and asked[0][0] == 1
-    assert all(ifm.rounds[i] <= allowed for i, allowed in asked)
-    assert max(allowed for _, allowed in asked) == steps
-    assert any(ifm.rounds[i] > 1 for i, _ in asked) == (steps > 1)
+    # has caught up; a round that begins with a row decoding asks what it
+    # may take, and keeps to it
+    assert ifm.rounds[0] == 6 and asked[0].round == 1
+    assert all(ifm.rounds[a.round] <= a.allowed for a in asked)
+    # both counts are read off the slots: the rest are empty or finished
+    assert all(a.decoding >= 1 and a.filling >= 0
+               and a.decoding + a.filling <= slots for a in asked)
+    worth = (steps + 0.5) / block       # of one decode step, in prefill steps
+    assert all(a.allowed == max(1, int(
+        a.block * worth * max(1.0, a.filling / a.decoding))) for a in asked)
+    # decoders the majority: what the block pays for, to the letter
+    assert max(a.allowed for a in asked
+               if a.filling <= a.decoding) == steps
+    # fillers the majority: more, and taken (with one step a block too)
+    most = max(asked, key=lambda a: a.filling / a.decoding)
+    assert most.filling / most.decoding == (5 if slots == 6 else 3)
+    assert max(a.allowed for a in asked) > steps
+    assert steps == 16 or any(      # sixteen: more than the queue asks for
+        ifm.rounds[a.round] > max(1, int(a.block * worth)) for a in asked)
 
 
 def test_incr_loop_fills_its_batch_sooner_with_several_steps_a_round():
-    """The same queue, one step a round against five: the four slots all
-    decode after fewer rounds, no token differs, and
+    """The same queue, a block that pays for one step against five: the
+    four slots all decode after fewer rounds, no token differs,
     ``ffsv_round_prefill_steps`` counts the rounds that took more than one
-    step. Telemetry fences each step (the fake has no state to fence) and
-    changes nothing of the schedule."""
+    step and ``ffsv_round_prefill_allowance`` what each round that began
+    with a row decoding was allowed. Telemetry fences each step (the fake
+    has no state to fence) and changes nothing of the schedule."""
     from flexflow_tpu.telemetry import disable_telemetry, enable_telemetry
 
     cfg = ff.FFConfig(max_requests_per_batch=4, max_sequence_length=80,
@@ -925,14 +947,16 @@ def test_incr_loop_fills_its_batch_sooner_with_several_steps_a_round():
         return ({tuple(r.input_tokens): r.output_tokens for r in res},
                 full, ifm.rounds)
 
-    one, full_one, rounds_one = run(1)
+    one, full_one, rounds_one = run(0)      # half a step: one, even at 3 to 1
     tel = enable_telemetry()
     try:
         before = tel.registry.snapshot()
         five, full_five, rounds_five = run(5)
         hist = tel.registry.get("ffsv_round_prefill_steps")
-        # the benchmark's reader of it; nothing from a program without it
-        from benchmark.layer_metrics import prefill_steps_per_round as metric
+        # the benchmark's readers; nothing from a program without them
+        from benchmark.layer_metrics import (
+            prefill_allowance_per_round as allowance,
+            prefill_steps_per_round as metric)
 
         ctx = {"tel": {"before": before, "after": tel.registry.snapshot()}}
         assert metric.read(ctx) == sum(rounds_five) / (len(rounds_five) - 1)
@@ -942,38 +966,60 @@ def test_incr_loop_fills_its_batch_sooner_with_several_steps_a_round():
         assert hist.sum == sum(rounds_five)
         # observations above 1: the rounds the rule engaged in
         assert sum(hist._counts[2:]) == sum(n > 1 for n in rounds_five) >= 3
+        # what the rule allowed: one observation for each round that began
+        # with a row decoding (all but the first), and no round took more
+        allowed = tel.registry.get("ffsv_round_prefill_allowance")
+        assert allowed.count == len(rounds_five) - 2 == len(allowed._samples)
+        assert all(took <= may for took, may
+                   in zip(rounds_five[1:], allowed._samples))
+        # 5.5 steps a block, three requests filling to one row decoding
+        assert max(allowed._samples) == 16 > min(allowed._samples) == 5
+        assert allowance.read(ctx) == allowed.sum / allowed.count
+        assert allowance.read({"tel": {"before": {}, "after": {}}}) is None
+        assert allowance.read({"tel": None}) is None
     finally:
         disable_telemetry()
     assert five == one
     assert full_five < full_one, (full_five, full_one)
-    assert max(rounds_five[1:]) == 5 and max(rounds_one[1:]) <= 2
+    # three filling to one decoding: more than the block's five, and taken
+    assert max(rounds_five[1:]) == 8 and max(rounds_one[1:]) <= 2
     assert run(5)[1:] == (full_five, rounds_five)   # telemetry off: the same
 
 
-def test_step_costs_are_medians_of_a_few_timed_rounds():
+@pytest.mark.parametrize("decoding,filling,weight", [
+    (1, 0, 1), (12, 4, 1), (8, 8, 1),       # decoders the majority: PR 32's
+    (4, 12, 3), (3, 13, 13 / 3), (1, 3, 3), (2, 3, 1.5)])
+def test_step_costs_are_medians_of_a_few_timed_rounds(decoding, filling,
+                                                      weight):
     """No estimate, and so one step a round, until three samples of each
     program are in; then as many steps as together cost no more than the
-    block, from the medians of the last five samples: a stop of the machine
-    inside one sample moves nothing. Every prefilling round is timed until
-    both estimates stand, then one in eight."""
+    block, times ``filling / decoding`` where the requests filling are the
+    majority, from the medians of the last five samples: a stop of the
+    machine inside one sample moves nothing. Every prefilling round is
+    timed until both estimates stand, then one in eight."""
     from flexflow_tpu.serve.step_costs import StepCosts
 
     costs = StepCosts()
-    assert costs.allowance(16) == 1
+
+    def allowance(block):
+        return costs.allowance(block, decoding, filling)
+
+    assert allowance(16) == 1
     for i in range(3):
         assert costs.due()
         costs.note_prefill(2 * 0.0226, 2)     # two steps, timed together
-        assert costs.allowance(16) == 1
+        assert allowance(16) == 1
         costs.note_decode(16 * 0.0106, 16)
-    assert costs.allowance(16) == 7             # 169.6 / 22.6 ms
-    assert costs.allowance(4) == 1 and costs.allowance(1) == 1
+    assert allowance(16) == int(7.5044 * weight)    # 169.6 / 22.6 ms
+    assert allowance(4) == max(1, int(1.8761 * weight))
+    assert allowance(1) == max(1, int(0.4690 * weight))
     assert [costs.due() for _ in range(16)].count(True) == 2
     costs.note_prefill(9.0, 1)                  # the machine stopped
     costs.note_decode(16 * 0.6, 16)
-    assert costs.allowance(16) == 7
+    assert allowance(16) == int(7.5044 * weight)
     for _ in range(3):                          # the model got slower
         costs.note_prefill(0.03, 1)
-    assert costs.allowance(16) == 5
+    assert allowance(16) == int(5.6533 * weight)
 
 
 @pytest.mark.parametrize("stop_at", [None, 7])
@@ -1030,9 +1076,10 @@ def test_incr_loop_times_the_same_rounds_traced_and_untraced(monkeypatch,
     assert plain == traced
     # the first round has nothing decoding; then one step a round until the
     # third sample of each program, then the three that a block of 4 steps
-    # (3.2 s) pays for
+    # (3.2 s) pays for, and more only where the requests filling outnumber
+    # the rows decoding (late in the run, two to one: four, of six allowed)
     assert plain[1][:4] == [6, 1, 1, 3], plain[1]
-    assert max(plain[1][1:]) == 3
+    assert max(plain[1][1:]) == 4
     assert sorted(plain[2])[:-1] == [1.0] * (len(plain[2]) - 1)
     assert max(plain[2]) == (1.0 if stop_at is None else 101.0)
     assert {round(d, 6) for d in plain[3]} == {0.8}
@@ -1383,9 +1430,10 @@ def test_check_compact_prefill_tool_rehearses(config, rounds, monkeypatch,
     sizes: on the CPU the two programs agree to the bit, and an expert
     model's compact run, sent where the grid run went, overrides no pick
     of its own. ``rounds``: the windowed cut is then served by the
-    scheduler loop, whose rounds take four consecutive steps (256
-    positions through a ring of 128 rows) while rows decode: every token
-    is what one step a round gives, the first the grid run's pick."""
+    scheduler loop, whose rounds take a block's four consecutive steps
+    (256 positions through a ring of 128 rows) while rows decode, and
+    twelve while three requests fill and one row decodes: every token is
+    what one step a round gives, the first the grid run's pick."""
     import json
 
     monkeypatch.syspath_prepend(os.path.dirname(os.path.dirname(
@@ -1405,5 +1453,5 @@ def test_check_compact_prefill_tool_rehearses(config, rounds, monkeypatch,
     if rounds:
         assert res["served_tokens_equal"]
         assert res["served_first_tokens_off_the_grid"] == 0
-        assert res["served_steps_by_round"][:6] == [1, 4, 4, 4, 4, 2]
+        assert res["served_steps_by_round"][:4] == [1, 12, 4, 2]
         assert res["served_positions_a_round_max"] >= 2 * res["ring_rows"][0]
