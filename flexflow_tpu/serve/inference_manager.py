@@ -89,10 +89,13 @@ class InferenceManager:
         donated to the device program). ``want_output=False`` skips the
         blocking device->host readback — prefill chunks whose outputs are
         discarded dispatch asynchronously and overlap with the host
-        building the next batch. ``tel`` (a ServingTelemetry; None: no
+        building the next batch: such a step hands back its output as the
+        device's future, which is not donated onward as the op_state is, so
+        whoever times the step can wait on it (never read it) after later
+        calls have been launched. ``tel`` (a ServingTelemetry; None: no
         spans) records the call's ``call_stage`` / ``call_launch`` /
         ``call_wait`` leaves; an output-free step is program ``prefill``
-        and its caller fences.
+        and its wait is its caller's (telemetry.PendingPrefill).
         """
         ph, prog = None, "step" if want_output else "prefill"
         if tel is not None:
@@ -116,7 +119,7 @@ class InferenceManager:
         if not want_output:
             if tel is not None:
                 tel.call_phase(ph, None)
-            return None
+            return out
         if tel is not None:
             ph = tel.call_phase(ph, "call_wait", prog)
         out = np.asarray(out)
@@ -126,7 +129,7 @@ class InferenceManager:
 
     def decode_block(self, tok: np.ndarray, pos: np.ndarray,
                      active: np.ndarray, n_steps: int,
-                     tel=None) -> np.ndarray:
+                     tel=None, rnd=None) -> np.ndarray:
         """Run ``n_steps`` fused decode steps in ONE device program.
 
         The TPU answer to the reference's depth-4 in-flight Legion batch
@@ -134,7 +137,11 @@ class InferenceManager:
         batches, the whole token-feedback loop runs on device via a
         dynamic-trip while_loop — one host round-trip AND one compiled
         program for every block size. Returns int32 [R, n_steps].
-        ``tel``: as in ``step`` (program ``decode_block``).
+        ``tel``: as in ``step`` (program ``decode_block``). ``rnd`` (the
+        caller's telemetry.RoundTrace; None: it has none) may hold a
+        prefill step that was launched before this block and is not waited
+        for yet: its wait comes once the block is queued behind it, between
+        this call's ``call_launch`` and ``call_wait``.
         """
         from flexflow_tpu.serve.engine import make_decode_block
 
@@ -161,7 +168,10 @@ class InferenceManager:
             self.model.params, self.model.op_state, *args)
         self.model.op_state = new_state
         if tel is not None:
-            ph = tel.call_phase(ph, "call_wait", "decode_block")
+            tel.call_phase(ph, None)
+            if rnd is not None:
+                rnd.settle()
+            ph = tel.call_phase(None, "call_wait", "decode_block")
         toks = np.asarray(toks)[:, :n_steps]
         if tel is not None:
             tel.call_phase(ph, None)

@@ -47,7 +47,8 @@ from flexflow_tpu.serve.batch_config import (
 from flexflow_tpu.serve.inference_manager import InferenceManager
 from flexflow_tpu.serve.step_costs import StepCosts
 from flexflow_tpu.ops.inc_attention import commit_tree_kv
-from flexflow_tpu.telemetry import get_telemetry, mint_trace_id
+from flexflow_tpu.telemetry import (PendingPrefill, get_telemetry,
+                                    mint_trace_id)
 from flexflow_tpu.utils.profiling import device_fence
 
 
@@ -596,38 +597,38 @@ class RequestManager:
         if not req.first_token_s and req.num_generated > 0:
             req.first_token_s = time.perf_counter()
 
-    def _timed_prefill(self, ifm, meta, tel, rows, active, rnd=None):
-        """One prefill step, optionally wall-clocked. The step's outputs
-        are discarded (want_output=False dispatches asynchronously), so
-        honest timing needs an explicit fence on the new op_state
-        (utils/profiling.device_fence). The fence only runs with
-        telemetry enabled; the disabled path keeps the async overlap.
+    def _timed_prefill(self, ifm, meta, tel, rows, active, rnd=None,
+                       lag=False):
+        """One prefill step. Its outputs are discarded (want_output=False
+        dispatches asynchronously and is forgotten); with telemetry on a
+        wait on the small output its program hands back times the step and
+        records its spans and counters (telemetry.PendingPrefill).
 
         ``rows``/``active`` feed per-request prefill spans. ``rnd`` is
         the round's RoundTrace in the loops that have one: the call's
         ``call_*`` leaves take over from the open phase, and
-        ``sched_build`` resumes after the fence."""
+        ``sched_build`` resumes after them. With ``lag`` the step is
+        waited for once the round's NEXT device call has been launched
+        (the next step, here; InferenceManager.decode_block), so the
+        device has work queued meanwhile, as it has with telemetry off."""
         if tel is None:
             ifm.step(meta, want_output=False)
             return
-        if rnd is None:
-            t0 = time.perf_counter()
-            ifm.step(meta, want_output=False)
-            device_fence(ifm.model.op_state)
-            dt = time.perf_counter() - t0
-        else:
+        if rnd is not None:
             rnd.phase(None)
-            t0 = time.perf_counter()
-            ifm.step(meta, want_output=False, tel=tel)
-            wait = tel.call_phase(None, "call_wait", "prefill")
-            device_fence(ifm.model.op_state)
-            tel.call_phase(wait, None)
-            dt = time.perf_counter() - t0
-            rnd.phase("sched_build")
-        tel.record_prefill(dt, sum(len(chunk) for _, chunk, _ in rows),
-                           [(active[slot].guid, sp, len(chunk))
-                            for slot, chunk, sp in rows], t0,
-                           positions=meta.tokens.size)
+        step = PendingPrefill(tel, [(active[slot].guid, sp, len(chunk))
+                                    for slot, chunk, sp in rows],
+                              meta.tokens.size, leaf=rnd is not None)
+        step.out = ifm.step(meta, want_output=False,
+                            tel=tel if rnd is not None else None)
+        if rnd is None:
+            step.settle()
+            return
+        rnd.settle()            # the step before, now that this is queued
+        rnd.pending = step
+        if not lag:
+            rnd.settle()
+        rnd.phase("sched_build")
 
     def _tel_tick(self, tel, live, slots: int, max_seq: int):
         """Once per scheduling tick that dispatches decode/spec work:
@@ -712,13 +713,15 @@ class RequestManager:
         reach another slot's cache: a pipelined model keeps the slot grid."""
         return getattr(getattr(ifm, "model", None), "_pp_plan", None) is None
 
-    def _prefill(self, ifm, active, shape, depth_of, tel, rnd=None):
+    def _prefill(self, ifm, active, shape, depth_of, tel, rnd=None,
+                 lag=False):
         """One prefill step for ``ifm``'s model: choose the segments
         among ``active`` (None: not a candidate), run them in one
         output-free step, return them (none: nothing is filling). The one
         prefill path of the Python loops; the caller moves its depth marks
         by the rows returned. The speculation loops call it once a round,
-        the incremental loop as often as StepCosts allows the round."""
+        the incremental loop as often as StepCosts allows the round and
+        with ``lag`` (_timed_prefill)."""
         chunk, segments = shape
         compact = self._compact_prefill(ifm)
         rows = self._prefill_rows(active, chunk, depth_of, segments,
@@ -727,7 +730,7 @@ class RequestManager:
             meta = (self._meta_from_segments(segments, chunk, rows)
                     if compact else
                     self._meta_from_rows(len(active), chunk, rows))
-            self._timed_prefill(ifm, meta, tel, rows, active, rnd=rnd)
+            self._timed_prefill(ifm, meta, tel, rows, active, rnd, lag)
         return rows
 
     # =====================================================================
@@ -814,16 +817,24 @@ class RequestManager:
             steps, timed, t0 = 0, False, time.perf_counter()
             while allowed is None or steps < allowed:
                 rows = self._prefill(ifm, active, shape,
-                                     lambda r: r.cache_depth, tel, rnd)
+                                     lambda r: r.cache_depth, tel, rnd,
+                                     lag=True)
                 if not rows:
                     break
                 if not steps:
                     timed = costs.due()
                 steps += 1
-                if timed and tel is None:
-                    # a timed round waits for each step as telemetry does
-                    # for every step, so both time the same thing
-                    device_fence(ifm.model.op_state)
+                if timed:
+                    # a timed round waits for each step before it stages
+                    # the next, telemetry or not, so both time the same
+                    # thing; any other round's steps queue behind each
+                    # other, telemetry or not
+                    if rnd is None:
+                        device_fence(ifm.model.op_state)
+                    else:
+                        rnd.phase(None)
+                        rnd.settle()
+                        rnd.phase("sched_build")
                 for slot, chunk_toks, sp in rows:
                     active[slot].cache_depth = sp + len(chunk_toks)
                 if allowed is None and caught_up():
@@ -854,7 +865,8 @@ class RequestManager:
                 if rnd is not None:
                     rnd.phase(None)
                 t0 = time.perf_counter()
-                toks = ifm.decode_block(tok, pos, act, block, tel=tel)
+                toks = ifm.decode_block(tok, pos, act, block, tel=tel,
+                                        rnd=rnd)
                 dt = time.perf_counter() - t0   # the np readback = fence
                 if timed or not steps:  # the device was idle at dispatch
                     costs.note_decode(dt, block)

@@ -22,15 +22,16 @@ readback fences both, so a round's wall time does not say which program
 took it. Every ``EVERY``-th round that prefills is therefore TIMED: the
 loop waits for each of the round's prefill steps before it stages the
 next (a few ms of lost overlap a step), and gets a sample of each cost.
-That is what telemetry does to every step, so a timed round measures the
-same thing with telemetry on and off, and the traced run has the policy
-of the untraced. What it measures is a step run alone and waited for: a
-little more than the step costs the device when the next one is staged
-behind it, so the bound is kept with room. A round that prefills nothing
-gives a decode sample for free. Each estimate is the median of its last
-``KEEP`` samples, so a compile or a stop of the machine (0.1-10 s at
-times) inside one sample moves nothing, and there is no estimate until
-``MIN`` samples are in.
+Telemetry or not: with it on the wait is on the step's own output and
+with it off a fence of the state, and every other round's steps queue
+behind each other either way, so the traced run has the policy and the
+staging of the untraced. What a timed round measures is a step run alone
+and waited for: a little more than the step costs the device when the
+next one is staged behind it, so the bound is kept with room. A round that
+prefills nothing gives a decode sample for free. Each estimate is the
+median of its last ``KEEP`` samples, so a compile or a stop of the machine
+(0.1-10 s at times) inside one sample moves nothing, and there is no
+estimate until ``MIN`` samples are in.
 """
 
 from __future__ import annotations

@@ -37,7 +37,7 @@ ffsv_acceptance_length           histogram  accepted draft tokens per round
 ffsv_tokens_per_round            histogram  committed tokens per round (+bonus)
 ffsv_batch_occupancy             histogram  live slots / max slots per tick
 ffsv_kv_cache_utilization        histogram  mean seq_len / max_seq over live
-ffsv_prefill_step_seconds        histogram  device-fenced prefill step time
+ffsv_prefill_step_seconds        histogram  a prefill step's span (see below)
 ffsv_decode_block_seconds        histogram  device-fenced decode block time
 ffsv_spec_block_seconds          histogram  device-fenced speculation block
 ffsv_request_latency_seconds     histogram  admission -> finish
@@ -93,7 +93,9 @@ Python scheduler loops open a ``RoundTrace`` per iteration (``sched_round``
 with the leaves ``sched_admit`` / ``sched_build`` / ``sched_commit``), and
 every device call records ``call_stage`` / ``call_launch`` / ``call_wait``
 through ``ServingTelemetry.call_phase`` (inside a ``spec_block`` span for
-the fused engines); README "Telemetry" has the table.
+the fused engines), in sequence and never two open. A call's wait may come
+after the next call's launch (``stage k+1, launch k+1, wait k``: a lagged
+prefill step); README "Telemetry" has the table.
 
 Fleet layer (this package's distributed half): ``fleet.FleetTelemetry``
 keeps one ServingTelemetry per replica (distinct Chrome-trace ``pid``
@@ -110,11 +112,22 @@ CURRENT tail, not the whole-run aggregate (serve/loadgen.py's live-SLO
 contract).
 
 Timing honesty: block/step timings are recorded by the serving loop
-AROUND device calls whose results are read back to the host
-(``np.asarray`` of the packed block output, or an explicit
-``utils/profiling.device_fence`` on the donated op_state for
-output-free prefill steps), so a recorded time never measures the
-enqueue alone (utils/profiling.py protocol).
+AROUND device calls whose results the host has waited for (``np.asarray``
+of the packed block output; for an output-free prefill step a
+``block_until_ready`` on the small output its program hands back beside the
+donated op_state: ``PendingPrefill``), so a recorded time never measures
+the enqueue alone (utils/profiling.py protocol). The incremental loop
+makes a prefill step's wait only after the round's NEXT device call has
+been launched, so the device is never left with nothing queued for the
+measurement's sake; a span therefore starts where the last recorded call's
+ended if that is later than its own launch (``_own_time``). The spans of
+calls queued behind each other do not overlap, each brackets its own
+call's device time, and ``ffsv_prefill_step_seconds`` observes the span's
+length: a step's service time on a busy device, staging and launch
+included only for a step that found the device idle. The boundary between
+two such spans is the host's return from the earlier call's wait, so it
+is as late as the host is in learning that a call ended (milliseconds on
+a shared host: PERF.md 7 (s)): a round's sum is exact, its split is not.
 """
 
 from __future__ import annotations
@@ -123,6 +136,7 @@ import time
 import weakref
 from typing import Optional
 
+import jax
 import numpy as np
 
 from flexflow_tpu.telemetry.metrics import (
@@ -151,10 +165,13 @@ class RoundTrace:
     from the start, then whatever ``phase`` names; ``phase(None)`` closes
     the open leaf before a device call, whose own ``call_*`` leaves
     (``ServingTelemetry.call_phase``) take over until the loop names the
-    next phase. Built only with telemetry on (``begin_round``)."""
+    next phase. ``pending`` is the round's prefill step that was launched
+    and is not waited for yet (``PendingPrefill``; the incremental loop
+    lags each step's wait by one device call): it never outlives the
+    round. Built only with telemetry on (``begin_round``)."""
 
     __slots__ = ("tel", "round", "leaf", "args", "grants0", "reqs",
-                 "tokens0")
+                 "tokens0", "pending")
 
     def __init__(self, tel: "ServingTelemetry", loop: str, slots: int):
         self.tel = tel
@@ -162,6 +179,7 @@ class RoundTrace:
         self.grants0 = tel.n_grants
         self.reqs = None
         self.tokens0 = 0
+        self.pending = None
         self.round = tel.tracer.begin("sched_round")
         self.leaf = tel.tracer.begin("sched_admit")
 
@@ -202,9 +220,52 @@ class RoundTrace:
         stands."""
         self.args.setdefault("cut", why)
 
+    def settle(self):
+        """Wait for the round's pending prefill step, if it has one. Its
+        ``call_wait`` is a leaf: no other may be open."""
+        if self.pending is not None:
+            self.pending.settle()
+            self.pending = None
+
     def end(self):
         self.phase(None)
+        self.settle()       # a round that ended without a decode block
         self.tel.tracer.end(self.round, **self.args)
+
+
+class PendingPrefill:
+    """A prefill step between its launch and the wait that times it. The
+    step's program hands back a small output beside the op state it donates
+    onward (``InferenceManager.step``): ``settle`` waits on that output,
+    never reads it, and records the step, its spans and its counters
+    together. WHEN is the loop's choice: at once, or after the next device
+    call has been launched so that the device has work queued meanwhile.
+    ``leaf``: whether the wait is a ``call_wait`` leaf (a loop with a
+    ``RoundTrace``). Built only with telemetry on; the launch time is taken
+    here, on the clock ``settle`` reads."""
+
+    __slots__ = ("tel", "rows", "positions", "leaf", "t0", "out")
+
+    def __init__(self, tel: "ServingTelemetry", rows, positions: int,
+                 leaf: bool):
+        self.tel = tel
+        self.rows = rows            # [(guid, start_pos, n_tokens)]
+        self.positions = positions
+        self.leaf = leaf
+        self.out = None             # the launched step's output, a future
+        self.t0 = time.perf_counter()
+
+    def settle(self):
+        """Once, after ``out`` is set."""
+        tel = self.tel
+        wait = (tel.call_phase(None, "call_wait", "prefill")
+                if self.leaf else None)
+        jax.block_until_ready(self.out)
+        tel.call_phase(wait, None)
+        self.out = None
+        tel.record_prefill(time.perf_counter() - self.t0,
+                           sum(n for _, _, n in self.rows), self.rows,
+                           self.t0, positions=self.positions)
 
 
 class ServingTelemetry:
@@ -232,6 +293,8 @@ class ServingTelemetry:
         # slot grants so far: a scheduler round's ``sched_admit`` span
         # reports the difference over its admission phase
         self.n_grants = 0
+        # when the last recorded prefill step or decode block ended
+        self._call_end = 0.0
         win = self.SLO_WINDOW_S if slo_window_s is None else slo_window_s
         r = self.registry
         self.requests_total = r.counter(
@@ -300,7 +363,8 @@ class ServingTelemetry:
             "mean sequence length / max_seq over live requests",
             buckets=FRACTION_BUCKETS)
         self.prefill_seconds = r.histogram(
-            "ffsv_prefill_step_seconds", "device-fenced prefill step time")
+            "ffsv_prefill_step_seconds",
+            "a prefill step's span: its time on a busy device")
         self.decode_block_seconds = r.histogram(
             "ffsv_decode_block_seconds",
             "device-fenced fused decode block time")
@@ -547,11 +611,27 @@ class ServingTelemetry:
         self.flight.record("failover", guid=guid, replica=replica,
                            target=target, trace_id=trace_id)
 
+    def _own_time(self, seconds: float, t0: Optional[float]):
+        """(start, seconds) of the span of a device call that was launched
+        at ``t0`` (None: ``seconds`` ago) and waited for until ``seconds``
+        later: it starts no earlier than the last such call ended. A call
+        launched behind another (a lagged prefill step, the decode block
+        after it) ran on the device from then on, so the spans bracket each
+        its own call's device time and do not overlap."""
+        end = time.perf_counter() if t0 is None else t0 + seconds
+        t0 = max(end - seconds, min(self._call_end, end))
+        self._call_end = end
+        return t0, end - t0
+
     def record_prefill(self, seconds: float, n_tokens: int, rows=(),
                        t0: Optional[float] = None, positions: int = 0):
-        """``t0``: the step's start on ``perf_counter`` (None: it ended
-        just now). ``positions``: the batch rows x chunk the step's program
-        computed, real tokens or padding."""
+        """``t0``: the step's launch on ``perf_counter`` (None: it ended
+        just now), ``seconds`` from there to the end of its wait; the span
+        and the histogram get the step's own time (``_own_time``).
+        ``positions``: the batch rows x chunk the step's program computed,
+        real tokens or padding. Counters and spans move together, so a
+        snapshot never counts a step whose span is not out yet."""
+        t0, seconds = self._own_time(seconds, t0)
         self.prefill_seconds.observe(seconds)
         self.prefill_tokens.inc(n_tokens)
         self.prefill_positions.inc(positions)
@@ -559,8 +639,6 @@ class ServingTelemetry:
         # positions up to itself
         self.prefill_pairs.inc(sum(n * sp + n * (n + 1) // 2
                                    for _, sp, n in rows))
-        if t0 is None:
-            t0 = time.perf_counter() - seconds
         for guid, start_pos, n in rows:
             self.tracer.prefill(guid, start_pos, n, t0, seconds)
 
@@ -576,13 +654,15 @@ class ServingTelemetry:
 
     def record_decode_block(self, seconds: float, steps: int, n_live: int,
                             guids=(), t0: Optional[float] = None):
-        """``t0``: as in ``record_prefill``."""
+        """``t0``: as in ``record_prefill``; a block launched behind a
+        prefill step starts where that step's wait returned, so no prefill
+        time falls inside a ``decode_block`` span. Every request's copy of
+        the span carries the block's live rows, ``n_live``."""
+        t0, seconds = self._own_time(seconds, t0)
         self.decode_block_seconds.observe(seconds)
         self.decode_steps.inc(steps * n_live)
-        if t0 is None:
-            t0 = time.perf_counter() - seconds
         for g in guids:
-            self.tracer.decode_block(g, steps, t0, seconds)
+            self.tracer.decode_block(g, steps, t0, seconds, int(n_live))
         self.flight.record("decode_block", seconds=round(seconds, 6),
                            steps=int(steps), n_live=int(n_live))
 
@@ -617,9 +697,10 @@ class ServingTelemetry:
     def call_phase(self, prev, name: Optional[str], program: str = ""):
         """One device call's leaf spans, in order: ``call_stage`` (build
         and transfer the inputs), ``call_launch`` (the jitted call until
-        it returns its futures), ``call_wait`` (the blocking read-back
-        or fence). Closes ``prev`` (None: nothing open) and opens
-        ``name`` (None: the call is over); returns the new token."""
+        it returns its futures), ``call_wait`` (the blocking read-back,
+        or the wait on a prefill step's output). Closes ``prev`` (None:
+        nothing open) and opens ``name`` (None: the call is over); returns
+        the new token."""
         if prev is not None:
             self.tracer.end(prev)
         return self.tracer.begin(name, program=program) if name else None

@@ -215,9 +215,11 @@ class SpanTracer:
                   start_pos=start_pos, n_tokens=n_tokens)
 
     def decode_block(self, guid: int, steps: int, ts_s: float,
-                     dur_s: float):
+                     dur_s: float, rows: int):
+        """``rows``: the block's live rows, the same on every request's
+        copy of the span."""
         self.emit("decode_block", "X", guid, ts_s=ts_s, dur_s=dur_s,
-                  request_guid=guid, steps=steps)
+                  request_guid=guid, steps=steps, rows=rows)
 
     def decode_round(self, guid: int, round_idx: int, n_accepted: int,
                      committed: int, block_t0: float, block_dur: float,

@@ -397,9 +397,9 @@ def _interleave_events(model, telemetry: bool):
         ifm = model._inference_manager = InferenceManager(model)
     orig_decode = ifm.decode_block
 
-    def spy_decode(tok, pos, act, block, tel=None):
+    def spy_decode(tok, pos, act, block, **kw):
         events.append("decode")
-        return orig_decode(tok, pos, act, block, tel=tel)
+        return orig_decode(tok, pos, act, block, **kw)
 
     ifm.decode_block = spy_decode
     ifm.step_costs = GivenCosts(1.0, 0.3)

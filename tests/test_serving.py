@@ -820,7 +820,9 @@ class _RuleIFM:
         self.prefills.append(meta)
         self.rounds[-1] += 1
 
-    def decode_block(self, tok, pos, act, block, tel=None):
+    def decode_block(self, tok, pos, act, block, tel=None, rnd=None):
+        if rnd is not None:         # telemetry on: the round's last step
+            rnd.settle()
         if self.on_decode is not None:
             self.on_decode(len(self.decodes))
         self.decodes.append((tok.copy(), pos.copy(), act.copy(), block))
@@ -930,8 +932,8 @@ def test_incr_loop_fills_its_batch_sooner_with_several_steps_a_round():
     four slots all decode after fewer rounds, no token differs,
     ``ffsv_round_prefill_steps`` counts the rounds that took more than one
     step and ``ffsv_round_prefill_allowance`` what each round that began
-    with a row decoding was allowed. Telemetry fences each step (the fake
-    has no state to fence) and changes nothing of the schedule."""
+    with a row decoding was allowed. Telemetry waits for each step's output
+    (the fake has none) and changes nothing of the schedule."""
     from flexflow_tpu.telemetry import disable_telemetry, enable_telemetry
 
     cfg = ff.FFConfig(max_requests_per_batch=4, max_sequence_length=80,
@@ -1026,12 +1028,14 @@ def test_step_costs_are_medians_of_a_few_timed_rounds(decoding, filling,
 def test_incr_loop_times_the_same_rounds_traced_and_untraced(monkeypatch,
                                                              stop_at):
     """The loop's own timing on a device that runs what it is sent in order
-    (a prefill step 1.0 s, a decode step 0.8 s, dispatch free, a fence or a
-    readback waits for the device): a timed round waits for each of its
-    steps with telemetry off as telemetry does for every step, so the
-    estimates, and with them the steps of every round, are the same traced
-    and untraced. ``stop_at``: the machine stops for 100 s inside that
-    prefill step; nothing changes."""
+    (a prefill step 1.0 s, a decode step 0.8 s, dispatch free; a fence, a
+    readback or a wait on a step's output waits for the device): a timed
+    round waits for each of its steps before it stages the next, with a
+    fence of the state when telemetry is off and a wait on the step's
+    output when it is on, and every other round's steps queue behind each
+    other either way, so the estimates, and with them the steps of every
+    round, are the same traced and untraced. ``stop_at``: the machine stops
+    for 100 s inside that prefill step; nothing changes."""
     from flexflow_tpu.serve import request_manager as RM
     from flexflow_tpu.telemetry import disable_telemetry, enable_telemetry
 
@@ -1049,11 +1053,17 @@ def test_incr_loop_times_the_same_rounds_traced_and_untraced(monkeypatch,
                 super().step(meta, want_output, tel)
                 stop = 100.0 * (len(self.prefills) == stop_at)
                 device[0] = max(host[0], device[0]) + 1.0 + stop
+                # the step's output: ready when the device has run it
+                return types.SimpleNamespace(
+                    block_until_ready=lambda end=device[0]: host.__setitem__(
+                        0, max(host[0], end)))
 
-            def decode_block(self, tok, pos, act, block, tel=None):
+            def decode_block(self, tok, pos, act, block, tel=None,
+                             rnd=None):
                 device[0] = max(host[0], device[0]) + 0.8 * block
+                out = super().decode_block(tok, pos, act, block, tel, rnd)
                 wait()
-                return super().decode_block(tok, pos, act, block, tel)
+                return out
 
         monkeypatch.setattr(RM, "device_fence", wait)
         monkeypatch.setattr(RM, "time", types.SimpleNamespace(

@@ -8,7 +8,8 @@ HTTP endpoint, a 2-round speculative decode recording the expected
 acceptance-length events, the disabled path recording nothing, the
 batch-level span vocabulary of the three Python scheduler loops (in the
 tracer, and in a jax.profiler session with its clock marks), and the
-benchmark's readers of those spans on a hand-built trace.
+benchmark's readers of those spans on hand-built traces (the lagged prefill
+step's order of leaves itself: tests/test_telemetry_lag.py).
 """
 
 import inspect
@@ -471,14 +472,14 @@ _EXPECTED = {
 }
 
 
-def _hand_ctx(spans):
+def _hand_ctx(spans, busy=_BUSY):
     from benchmark.lib import trace as TR
 
     off, t0_s = 1000.0, 100.0              # profiler = perf_counter + 1 us
     t0 = t0_s * 1e9 + off
     raw = {"planes": {"/device:TPU:0": [[f"fusion.{i}", t0 + a * 1e3,
                                          (b - a) * 1e3]
-                                        for i, (a, b) in enumerate(_BUSY)]},
+                                        for i, (a, b) in enumerate(busy)]},
            "marks": {"bench_mark_0": t0, "bench_mark_1": t0 + 100e6}}
     origin = 99.0
     events = [{"name": "clock_sync", "ph": "M", "ts": 0.0,
@@ -489,7 +490,7 @@ def _hand_ctx(spans):
                        "dur": float(b - a), "args": dict(args)})
     red = TR.reduce_trace(raw, {"bench_mark_0": t0_s,
                                 "bench_mark_1": t0_s + 0.1}, events)
-    assert red["busy_s"] == pytest.approx(0.058)
+    assert red["busy_s"] == pytest.approx(sum(b - a for a, b in busy) / 1e6)
     return {"trace": red}
 
 
@@ -513,3 +514,83 @@ def test_phase_span_readers_on_a_hand_built_trace(metric, capsys):
     assert all(line.startswith("# ") for line in out.splitlines())
     if metric == "spec_rounds_per_block":
         assert "rounds asked 2.500" in out and "'catch_up': 1" in out
+
+
+# the four readers this PR adds, on a hand-built traced stretch of 100 ms
+# (microseconds after its start): a round of two lagged prefill steps and a
+# block, a round of one step whose staging found the device idle, and a
+# block that runs over the stretch's end
+_LAG_ROUNDS = [
+    ("sched_round", 1000, 45000, {"loop": "incr"}),
+    ("call_stage", 2000, 3000, {"program": "prefill"}),
+    ("call_launch", 3000, 4000, {"program": "prefill"}),
+    ("call_stage", 5000, 6000, {"program": "prefill"}),
+    ("call_launch", 6000, 7000, {"program": "prefill"}),
+    ("call_wait", 7000, 14000, {"program": "prefill"}),
+    ("prefill", 2000, 14000, {"n_tokens": 8}),
+    ("prefill", 2000, 14000, {"n_tokens": 8}),      # second request
+    ("call_stage", 15000, 16000, {"program": "decode_block"}),
+    ("call_launch", 16000, 17000, {"program": "decode_block"}),
+    ("call_wait", 17000, 24000, {"program": "prefill"}),
+    ("prefill", 14000, 24000, {"n_tokens": 16}),
+    ("call_wait", 24000, 44000, {"program": "decode_block"}),
+    ("decode_block", 24000, 44000, {"steps": 4, "rows": 3}),
+    ("decode_block", 24000, 44000, {"steps": 4, "rows": 3}),
+    ("sched_round", 50000, 90000, {"loop": "incr"}),
+    ("call_stage", 51000, 53000, {"program": "prefill"}),
+    ("call_launch", 53000, 54000, {"program": "prefill"}),
+    ("call_wait", 54000, 60000, {"program": "prefill"}),
+    ("prefill", 51000, 60000, {"n_tokens": 4}),
+    ("decode_block", 62000, 88000, {"steps": 4, "rows": 1}),
+    ("sched_round", 95000, 105000, {"loop": "incr"}),
+    ("decode_block", 96000, 104000, {"steps": 4, "rows": 2}),
+]
+_LAG_BUSY = [(3500, 23500), (24500, 43500), (54500, 59500), (63000, 87000)]
+_LAG_EXPECTED = {
+    "prefill_step_ms": (10.5 + 9.5 + 5.0) / 3,
+    # idle in the staging of: the first round's first step 1.5 ms, its
+    # second 0, the second round's step 3 ms
+    "prefill_stage_idle_ms": (1.5 + 0.0 + 3.0) / 3,
+    "decode_rows_per_block": (3 + 1) / 2,
+    "traced_output_tok_s": (10 * 0.5 + 20 * 0.5) / 4.0,
+}
+_LAG_RECORDS = [{"n_out": 10, "submit": 1.0, "finish": 3.0},
+                {"n_out": 20, "submit": 4.0, "finish": 8.0}]
+
+
+def _lag_ctx(spans):
+    return dict(_hand_ctx(spans, _LAG_BUSY), records=_LAG_RECORDS, w0=2.0,
+                w1=6.0)
+
+
+@pytest.mark.parametrize("metric", sorted(_LAG_EXPECTED))
+def test_lag_readers_on_a_hand_built_trace(metric, capsys):
+    """Known spans, busy intervals and records give known numbers; a
+    program without the source (the parent: no ``rows``; one before ISSUE
+    24: no leaves; an untraced context) gives None."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, root)
+    try:
+        from benchmark.run import load_module
+
+        read = load_module("layer_metrics", metric).read
+        assert read(_lag_ctx(_LAG_ROUNDS)) == pytest.approx(_LAG_EXPECTED[metric])
+        parent = [(n, a, b, {k: v for k, v in args.items() if k != "rows"})
+                  for n, a, b, args in _LAG_ROUNDS]
+        bare = [s for s in parent if s[0] == "decode_block"]
+        missing = {"prefill_step_ms": _lag_ctx(bare),
+                   "prefill_stage_idle_ms": _lag_ctx(bare),
+                   "decode_rows_per_block": _lag_ctx(parent),
+                   "traced_output_tok_s": {"records": [], "w0": 2.0,
+                                           "w1": 6.0}}[metric]
+        assert read(missing) is None
+        assert read({"trace": None}) is None
+        if metric != "decode_rows_per_block":   # the parent's spans do
+            assert read(_lag_ctx(parent)) == pytest.approx(
+                _LAG_EXPECTED[metric])
+    finally:
+        sys.path.remove(root)
+    out = capsys.readouterr().out
+    assert all(line.startswith("# ") for line in out.splitlines())
+    if metric == "prefill_stage_idle_ms":
+        assert "first step x2 2.250, later steps x1 0.000" in out
