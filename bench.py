@@ -10,8 +10,9 @@ compare_speed_spec_infer_incr_decoding), target >= 2.0. The reference's
 correctness gate — spec output token-matches incr output for the first 30
 tokens (check_partial_token_match, python_inference_tests.sh:29) — is
 ASSERTED here at full generation length: incremental decoding runs
-verify-consistent (config.decode_width), so its per-token argmaxes are
-bitwise reproductions of the spec verify pass.
+verify-consistent (its manager takes the verify width of the engine built
+over the model: InferenceManager.verified_at), so its per-token argmaxes
+are bitwise reproductions of the spec verify pass.
 
 Zero-egress environment: no HF checkpoint downloads, so the verifier is a
 randomly-initialized LLaMA-2-7B-geometry decoder and the draft model is its
@@ -903,6 +904,9 @@ def main():
     else:
         llm._chain_engine = eng = SpecChainEngine(llm, ssms[0], SPEC_DEPTH,
                                                   max_rounds=SPEC_ROUNDS)
+    # the model is served both ways and the tokens compared: its manager
+    # decodes at the engine's verify width from the first block on
+    ifm.verified_at(eng.tree_width)
 
     def warmup():
         # one compile each: the block programs take a dynamic trip count
@@ -950,8 +954,9 @@ def main():
 
     # correctness gate (reference check_partial_token_match asserts the
     # FIRST 30 tokens match, python_inference_tests.sh:29). Incremental
-    # decoding runs verify-consistent (decode_width = the verify width:
-    # identical gemm shapes + attention kernel instantiation); the
+    # decoding runs verify-consistent (the manager was told the engine's
+    # verify width above: identical gemm shapes + attention kernel
+    # instantiation); the
     # 30-token reference gate is ASSERTED at the end of main, and the
     # full-length match is reported beside it (see the note at the JSON
     # keys for why the latter stays informational).

@@ -405,6 +405,16 @@ def phase_serving(g: Geometry, tp: int, meter: CompileMeter):
     prompts = [[int(t) for t in rng.randint(1, g.vocab, size=n)]
                for n in g.prompt_lens]
 
+    # One model is served both ways here and the tokens compared. The
+    # speculative handle comes first: a front door that is handed draft
+    # models builds the engine that will verify the model, so the model's
+    # manager decodes at the engine's verify width from its first block on
+    # (a model that no engine verifies decodes one token a row), and the
+    # incremental pass's decode block serves the speculative pass's parked
+    # requests too.
+    spec_handle = EngineHandle(llm, ssms=[ssm], spec_depth=g.spec_depth)
+    out["decode_width"] = llm._inference_manager.decode_width
+
     t0, snap = time.perf_counter(), meter.snapshot()
     incr_handle = EngineHandle(llm)
     incr = serve_pass(incr_handle, prompts, g.new_tokens)
@@ -415,7 +425,6 @@ def phase_serving(g: Geometry, tp: int, meter: CompileMeter):
     log(f"  incremental: {out['incremental']}")
 
     t0, snap = time.perf_counter(), meter.snapshot()
-    spec_handle = EngineHandle(llm, ssms=[ssm], spec_depth=g.spec_depth)
     spec = serve_pass(spec_handle, prompts, g.new_tokens)
     n_match = sum(a.output_tokens[:FIRST_N_MATCH]
                   == b.output_tokens[:FIRST_N_MATCH]

@@ -108,15 +108,18 @@ class FFConfig:
     # at the cost of more overshoot past EOS.
     decode_block_steps: int = 8
     spec_rounds_per_call: int = 4
-    # incremental-decode step width. 0 = auto: the sublane-padded verify
-    # width (8) on the Pallas path, 1 elsewhere. Widths > 1 stage the
-    # pending token as node 0 of a chain tree so the decode step runs the
-    # SAME program shapes (gemm M, attention kernel instantiation) as the
-    # speculative verify pass — XLA tiles a width-1 decode gemm differently
-    # from a width-(d+1) verify gemm, and the resulting f32 accumulation
-    # deltas flip near-tie argmaxes, breaking the reference's spec-vs-incr
-    # first-30-token CI gate (python_inference_tests.sh:29). Decode is
-    # weight-stream bound, so the extra query rows are hidden by the MXU.
+    # incremental-decode step width. 0 = what the code observes: the verify
+    # width of the speculation engine that verifies this model once one has
+    # been built over it (InferenceManager.verified_at), one token a row
+    # otherwise. Widths > 1 stage the pending token as node 0 of a chain
+    # tree so the decode step runs the SAME program shapes (gemm M,
+    # attention kernel instantiation) as the speculative verify pass — XLA
+    # tiles a width-1 decode gemm differently from a width-(d+1) verify
+    # gemm, and the resulting f32 accumulation deltas flip near-tie
+    # argmaxes, breaking the reference's spec-vs-incr first-30-token CI
+    # gate (python_inference_tests.sh:29). The extra rows are paid for: at
+    # int8 weights a step of 16 slots x 8 is over the chip's ridge, so a
+    # model that never speculates takes none. A value set here wins.
     decode_width: int = 0
     # draft beam width (reference BeamSearchBatchConfig::MAX_BEAM_WIDTH,
     # batch_config.h:125; default 1 = greedy chains). Width > 1 makes a

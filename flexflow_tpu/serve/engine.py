@@ -194,7 +194,9 @@ def make_decode_block(model, compute_dtype, max_steps: int, width: int = 1):
     ``pos[r]`` is the sequence index of the pending token ``tok[r]``.
     One program compiles for ALL n (dynamic while_loop trip count).
 
-    ``width > 1`` runs each step at the spec verify pass's token width
+    ``width > 1`` (a model that a speculation engine verifies:
+    InferenceManager.decode_width) runs each step at the spec verify
+    pass's token width
     with 1 real token per row (verify-consistent decode: identical gemm
     shapes and attention-kernel instantiation, so near-tie argmaxes
     resolve the same way in both paths). Only the real token's KV is
@@ -635,6 +637,12 @@ class SpecChainEngine:
         self._traces_reported = 0
         # concrete (created outside any trace: jit closes over it as a const)
         self._rng_const = jax.random.PRNGKey(llm.config.seed)
+
+    @property
+    def tree_width(self) -> int:
+        """Verify width: the pending token and the chain, one causal pass
+        (a chain needs no bias block, so no sublane padding)."""
+        return self.depth + 1
 
     def _round(self, llm_params, llm_state, ssm_params, ssm_state, tok, pos,
                rng, active, depth_r):

@@ -38,42 +38,29 @@ class InferenceManager:
         self._step = jax.jit(self._step_impl, donate_argnums=(1,))
         self._rng = jax.random.PRNGKey(cfg.seed)
         self._decode_block = None
+        self._decode_block_width = 0    # the width _decode_block was built at
+        self._verify_width = 0          # verified_at: 0 = no engine verifies
         self._debug_step = 0
-        self.decode_width = self._resolve_decode_width(cfg)
 
-    def _resolve_decode_width(self, cfg) -> int:
-        """Step width for fused incremental decode (config.decode_width;
-        0 = auto). Widths > 1 make decode verify-consistent — identical
-        program shapes to the spec verify pass, so near-tie argmaxes
-        resolve identically in both (the reference's spec-vs-incr 30-token
-        CI gate). Auto picks the sublane-padded single-SSM verify width
-        only when the Pallas kernel will actually serve this config:
-        use_pallas AND supports_shapes(S, Dp) at the model's PADDED cache
-        head dims — the exact predicate _attend dispatches on (ADVICE r3:
-        the former supports_seq_len(S) check assumed D=128 and could
-        disagree with the kernel for packed-D layouts). Everywhere else
-        the jnp path runs in fp32 with no bf16 near-tie problem, so wide
-        queries would be pure waste."""
-        if cfg.decode_width:
-            return int(cfg.decode_width)
-        from flexflow_tpu import kernels as ffk
-        from flexflow_tpu.kernels.attention import SUBLANE, supports_shapes
-        from flexflow_tpu.ops.inc_attention import padded_head_dim
+    @property
+    def decode_width(self) -> int:
+        """Tokens a row of a fused decode step: ``config.decode_width``
+        where it is set, else the verify width of the speculation engine
+        that verifies this model (``verified_at``), else 1. A wider step
+        carries ONE real token a row and is verify-consistent: the program
+        shapes of the verify pass, so near-tie argmaxes of the incremental
+        and the speculative path of one model resolve alike (the
+        reference's spec-vs-incr 30-token CI gate). A model no engine
+        verifies has nothing to agree with and pays for no padding."""
+        return int(self.model.config.decode_width) or self._verify_width or 1
 
-        if not ffk.use_pallas(cfg):
-            return 1
-        S = cfg.max_sequence_length
-        dps = {padded_head_dim(layer.attrs["head_dim"], True, S)
-               for layer in self.model.layers
-               if "head_dim" in layer.attrs and "num_kv_heads" in layer.attrs}
-        if dps and all(supports_shapes(S, dp) for dp in dps):
-            # SUBLANE == MultiSpecEngine.tree_width for the single-SSM
-            # depth-4 default (1 + 4 rounded up to the sublane), and the
-            # Pallas path always specs through that engine
-            # (request_manager.generate_spec_infer routes the chain engine
-            # off-TPU only) — so decode and verify really do share shapes.
-            return SUBLANE
-        return 1
+    def verified_at(self, width: int):
+        """Whoever builds or fetches the speculation engine over this model
+        (RequestManager._engine_of: the loops, and a front door handed
+        draft models) says that it verifies the model ``width`` tokens a
+        row, the engine's ``tree_width``. A decode block built at another
+        width is dropped by its next call."""
+        self._verify_width = int(width)
 
     def _step_impl(self, params, op_state, meta, rng):
         from flexflow_tpu.serve.engine import forward_with_meta
@@ -150,11 +137,12 @@ class InferenceManager:
             # every decode token's op tensors are dumped (the fused
             # while_loop body cannot host-dump); same numerics, slower.
             return self._decode_block_debug(tok, pos, active, n_steps)
-        if self._decode_block is None:
-            cfg = self.model.config
+        width = self.decode_width
+        if self._decode_block_width != width:
             self._decode_block = make_decode_block(
-                self.model, self._compute_dtype, cfg.decode_block_steps,
-                width=self.decode_width)
+                self.model, self._compute_dtype,
+                self.model.config.decode_block_steps, width=width)
+            self._decode_block_width = width
         n_steps = min(int(n_steps), self.model.config.decode_block_steps)
         ph = None
         if tel is not None:

@@ -230,6 +230,12 @@ class EngineHandle:
         # caching / spec-controller knobs for checkpoint-less models)
         self.generation_config = generation_config
         self._server = None
+        if self.ssms:
+            # the engine that will verify ``ffmodel`` exists from here on:
+            # whoever serves the model incrementally beside this handle
+            # decodes at its verify width
+            self.rm.prepare_spec_infer(ffmodel, list(ssms),
+                                       generation_config=generation_config)
 
     def start_server(self, admission=None):
         from flexflow_tpu.serve.api import _BackgroundServer

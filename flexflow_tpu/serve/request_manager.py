@@ -740,9 +740,7 @@ class RequestManager:
                                generation_config:
                                Optional[GenerationConfig] = None
                                ) -> List[GenerationResult]:
-        ifm = getattr(model, "_inference_manager", None)
-        if ifm is None:
-            ifm = model._inference_manager = InferenceManager(model)
+        ifm = self._manager_of(model)
         cfg = model.config
         self._resolve_prefix_cache(generation_config)
         self.scheduler_loop = "python"
@@ -873,7 +871,8 @@ class RequestManager:
                 if tel is not None:
                     rnd.phase("sched_commit", live)
                     tel.record_decode_block(dt, block, len(live),
-                                            [r.guid for r in live], t0)
+                                            [r.guid for r in live], t0,
+                                            width=ifm.decode_width)
                 for req in live:
                     for j in range(block):
                         req.tokens.append(int(toks[req.slot, j]))
@@ -947,10 +946,11 @@ class RequestManager:
                          rnd) -> int:
         """Fused incremental decode for requests the adaptive speculation
         controller parked in fallback: the same decode-block program
-        generate_incr_decoding drives (verify-consistent width), so a
-        parked request pays exactly the incremental cost and emits the
-        identical greedy tokens. Draft caches are left stale; the prefill
-        cycle heals them if/when the request probes back into drafting."""
+        generate_incr_decoding drives, at the verify width of the engine
+        that parked them (``llm_ifm.decode_width``), so a parked request
+        emits the tokens the verify pass would have committed. Draft
+        caches are left stale; the prefill cycle heals them if/when the
+        request probes back into drafting."""
         if rnd is not None:
             rnd.phase("sched_build")
         block = min(max(self._remaining_budget(r, max_seq) for r in reqs),
@@ -972,7 +972,8 @@ class RequestManager:
             dt = time.perf_counter() - t0
             rnd.phase("sched_commit", reqs)
             tel.record_decode_block(dt, block, len(reqs),
-                                    [r.guid for r in reqs], t0)
+                                    [r.guid for r in reqs], t0,
+                                    width=llm_ifm.decode_width)
         for req in reqs:
             for j in range(block):
                 req.tokens.append(int(toks[req.slot, j]))
@@ -1014,6 +1015,40 @@ class RequestManager:
         if generation_config is not None and generation_config.spec_depth:
             spec_depth = generation_config.spec_depth
         self._resolve_prefix_cache(generation_config)
+        loop, W = self._spec_route(llm, ssms, beam_width)
+        self.scheduler_loop = "python:" + loop
+        if loop == "spec_chain":
+            return self._generate_spec_chain(
+                llm, ssms[0], spec_depth=spec_depth, beam_width=W,
+                generation_config=generation_config)
+        if loop == "spec_tree_fused":
+            return self._generate_spec_tree_fused(
+                llm, ssms, spec_depth=spec_depth,
+                generation_config=generation_config)
+        return self._generate_spec_tree_host(llm, ssms,
+                                             spec_depth=spec_depth,
+                                             beam_width=W)
+
+    def prepare_spec_infer(self, llm, ssms: List[Any],
+                           spec_depth: Optional[int] = None,
+                           beam_width: Optional[int] = None,
+                           generation_config: Optional[GenerationConfig]
+                           = None):
+        """Build the engine ``generate_spec_infer`` will run with these
+        arguments, ahead of the first request. A front door that is handed
+        draft models calls this (serve/loadgen.EngineHandle), so the
+        verifier's manager knows an engine verifies the model, and at what
+        width, before the model's first decode block: served incrementally
+        beside it, the model decodes at the verify width from the start."""
+        if generation_config is not None and generation_config.spec_depth:
+            spec_depth = generation_config.spec_depth
+        loop, W = self._spec_route(llm, ssms, beam_width)
+        return self._engine_of(loop, llm, ssms, self._depth_of(spec_depth), W)
+
+    @staticmethod
+    def _spec_route(llm, ssms, beam_width):
+        """``(loop, beam width)``: which of the three speculation loops
+        serves this verifier with these drafts."""
         widths = [s.config.max_beam_width for s in ssms]
         W = beam_width or max(widths)
         if any(w != W for w in widths):
@@ -1031,18 +1066,12 @@ class RequestManager:
                 # LAYOUT is compile-time static (frontier = the newest W
                 # nodes), so drafting + verify + accept + commit all run
                 # inside one device while_loop (engine.BeamSpecEngine)
-                self.scheduler_loop = "python:spec_chain"
-                return self._generate_spec_chain(
-                    llm, ssms[0], spec_depth=spec_depth, beam_width=W,
-                    generation_config=generation_config)
+                return "spec_chain", W
             # multi-SSM beams (merged cross-draft trees) and debug dumps
             # run the host tree path: frontier nodes step through the
             # draft as STAGED TREE NODES (no per-beam KV), and the
             # surviving beam paths merge like extra chains
-            self.scheduler_loop = "python:spec_tree_host"
-            return self._generate_spec_tree_host(llm, ssms,
-                                                 spec_depth=spec_depth,
-                                                 beam_width=W)
+            return "spec_tree_host", W
         from flexflow_tpu import kernels as ffk
 
         if len(ssms) == 1 and not ffk.use_pallas(llm.config):
@@ -1054,23 +1083,64 @@ class RequestManager:
             # chain's extra KV-backfill draft step saves. On TPU the
             # weight-bound rounds invert that tradeoff and the fused
             # tree engine below wins (~12% per round at 7B geometry).
-            self.scheduler_loop = "python:spec_chain"
-            return self._generate_spec_chain(
-                llm, ssms[0], spec_depth=spec_depth,
-                generation_config=generation_config)
+            return "spec_chain", 1
         if not llm.config.inference_debugging:
             # multi-SSM trees also run fully fused (engine.MultiSpecEngine:
             # all drafts + tree verify + acceptance + KV compaction inside
-            # one device while_loop); the host-stepped path below remains
-            # for inference_debugging's per-op tensor dumps.
-            self.scheduler_loop = "python:spec_tree_fused"
-            return self._generate_spec_tree_fused(
-                llm, ssms, spec_depth=spec_depth,
-                generation_config=generation_config)
-        self.scheduler_loop = "python:spec_tree_host"
-        return self._generate_spec_tree_host(llm, ssms,
-                                             spec_depth=spec_depth,
-                                             beam_width=1)
+            # one device while_loop); the host-stepped path remains for
+            # inference_debugging's per-op tensor dumps.
+            return "spec_tree_fused", 1
+        return "spec_tree_host", 1
+
+    def _depth_of(self, spec_depth: Optional[int]) -> int:
+        return min(spec_depth or self.max_spec_depth, self.max_spec_depth)
+
+    def _engine_of(self, loop: str, llm, ssms, depth: int, beam_width: int):
+        """The fused engine that ``loop`` runs over the verifier ``llm``,
+        kept on the model and rebuilt only when the drafts or the depth
+        changed; None for the host-stepped loop, which has none. Whichever,
+        the verifier's manager is told the verify width, so that the decode
+        blocks of this model (``_fallback_decode``, and incremental decoding
+        from here on) take the verify pass's shapes."""
+        from flexflow_tpu.kernels.attention import SUBLANE, round_up
+        from flexflow_tpu.serve.engine import (BeamSpecEngine,
+                                               MultiSpecEngine,
+                                               SpecChainEngine)
+
+        llm_ifm = self._manager_of(llm)
+        if loop == "spec_tree_host":
+            # _verify_and_commit's width: root + depth nodes a branch
+            llm_ifm.verified_at(round_up(
+                1 + depth * len(ssms) * beam_width, SUBLANE))
+            return None
+        rounds = llm.config.spec_rounds_per_call
+        if loop == "spec_tree_fused":
+            attr = "_multi_engine"
+            same = lambda e: e.ssms == list(ssms)
+            make = lambda: MultiSpecEngine(llm, ssms, depth, max_rounds=rounds)
+        elif beam_width > 1:
+            attr = "_beam_engine"
+            same = lambda e: e.ssm is ssms[0] and e.width == beam_width
+            make = lambda: BeamSpecEngine(llm, ssms[0], depth, beam_width,
+                                          max_rounds=rounds)
+        else:
+            attr = "_chain_engine"
+            same = lambda e: e.ssm is ssms[0]
+            make = lambda: SpecChainEngine(llm, ssms[0], depth,
+                                           max_rounds=rounds)
+        engine = getattr(llm, attr, None)
+        if engine is None or engine.depth != depth or not same(engine):
+            engine = make()
+            setattr(llm, attr, engine)
+        llm_ifm.verified_at(engine.tree_width)
+        return engine
+
+    @staticmethod
+    def _manager_of(model) -> InferenceManager:
+        ifm = getattr(model, "_inference_manager", None)
+        if ifm is None:
+            ifm = model._inference_manager = InferenceManager(model)
+        return ifm
 
     def _generate_spec_tree_host(self, llm, ssms: List[Any],
                                  spec_depth: Optional[int] = None,
@@ -1086,22 +1156,16 @@ class RequestManager:
         shared-prefix pool — per-op dumps stay phase-ordered. The
         throughput loops (incremental, spec-chain, multi-SSM fused)
         carry the ISSUE 19 interleaving + prefix reuse."""
-        llm_ifm = getattr(llm, "_inference_manager", None)
-        if llm_ifm is None:
-            llm_ifm = llm._inference_manager = InferenceManager(llm)
-        ssm_ifms = []
-        for ssm in ssms:
-            m = getattr(ssm, "_inference_manager", None)
-            if m is None:
-                m = ssm._inference_manager = InferenceManager(ssm)
-            ssm_ifms.append(m)
+        llm_ifm = self._manager_of(llm)
+        ssm_ifms = [self._manager_of(ssm) for ssm in ssms]
         cfg = llm.config
         R = cfg.max_requests_per_batch
         max_seq = cfg.max_sequence_length
-        depth = min(spec_depth or self.max_spec_depth, self.max_spec_depth)
+        depth = self._depth_of(spec_depth)
         shape = self._prefill_shape(cfg)
         # tree capacity: root + depth nodes per surviving branch
         T = 1 + depth * len(ssms) * beam_width
+        self._engine_of("spec_tree_host", llm, ssms, depth, beam_width)
         active: List[Optional[Request]] = [None] * R
         done: List[GenerationResult] = []
 
@@ -1203,43 +1267,22 @@ class RequestManager:
         estimated spec speedup falls below incremental break-even decode
         through ``_fallback_decode`` until a probe round recovers them.
         """
-        from flexflow_tpu.serve.engine import BeamSpecEngine, SpecChainEngine
-
-        llm_ifm = getattr(llm, "_inference_manager", None)
-        if llm_ifm is None:
-            llm_ifm = llm._inference_manager = InferenceManager(llm)
-        ssm_ifm = getattr(ssm, "_inference_manager", None)
-        if ssm_ifm is None:
-            ssm_ifm = ssm._inference_manager = InferenceManager(ssm)
+        llm_ifm, ssm_ifm = self._manager_of(llm), self._manager_of(ssm)
         cfg = llm.config
         R = cfg.max_requests_per_batch
         max_seq = cfg.max_sequence_length
-        depth = min(spec_depth or self.max_spec_depth, self.max_spec_depth)
+        depth = self._depth_of(spec_depth)
         ctrl, gc = self._spec_controller(generation_config, llm, [ssm],
                                          engine_depth=depth,
                                          beam_width=beam_width)
-        if beam_width > 1:
-            engine = getattr(llm, "_beam_engine", None)
-            if (engine is None or engine.ssm is not ssm
-                    or engine.depth != depth
-                    or engine.width != beam_width):
-                engine = llm._beam_engine = BeamSpecEngine(
-                    llm, ssm, depth, beam_width,
-                    max_rounds=cfg.spec_rounds_per_call)
-            # the beam engine stages a Tp-node tree per round; its
-            # live_mask reserves the full window, so the host must gate
-            # at least as strictly or cramped requests would be
-            # rescheduled into an engine that masks them dead every
-            # round, hanging the loop. (NB: named room_needed, not room —
-            # the per-request budget remainder below shadows that name.)
-            room_needed = engine.tree_width
-        else:
-            engine = getattr(llm, "_chain_engine", None)
-            if (engine is None or engine.ssm is not ssm
-                    or engine.depth != depth):
-                engine = llm._chain_engine = SpecChainEngine(
-                    llm, ssm, depth, max_rounds=cfg.spec_rounds_per_call)
-            room_needed = depth + 1
+        engine = self._engine_of("spec_chain", llm, [ssm], depth, beam_width)
+        # the beam engine stages a Tp-node tree per round (the chain its
+        # depth + 1 tokens); its live_mask reserves the full window, so the
+        # host must gate at least as strictly or cramped requests would be
+        # rescheduled into an engine that masks them dead every round,
+        # hanging the loop. (NB: named room_needed, not room — the
+        # per-request budget remainder below shadows that name.)
+        room_needed = engine.tree_width
         shape = self._prefill_shape(cfg)
         active: List[Optional[Request]] = [None] * R
         done: List[GenerationResult] = []
@@ -1448,29 +1491,16 @@ class RequestManager:
         a scheduling/EOS fix in one path almost certainly applies to the
         other — keep them in sync.
         """
-        from flexflow_tpu.serve.engine import MultiSpecEngine
-
-        llm_ifm = getattr(llm, "_inference_manager", None)
-        if llm_ifm is None:
-            llm_ifm = llm._inference_manager = InferenceManager(llm)
-        ssm_ifms = []
-        for ssm in ssms:
-            m = getattr(ssm, "_inference_manager", None)
-            if m is None:
-                m = ssm._inference_manager = InferenceManager(ssm)
-            ssm_ifms.append(m)
+        llm_ifm = self._manager_of(llm)
+        ssm_ifms = [self._manager_of(ssm) for ssm in ssms]
         cfg = llm.config
         R = cfg.max_requests_per_batch
         max_seq = cfg.max_sequence_length
         B = len(ssms)
-        depth = min(spec_depth or self.max_spec_depth, self.max_spec_depth)
+        depth = self._depth_of(spec_depth)
         ctrl, gc = self._spec_controller(generation_config, llm, ssms,
                                          engine_depth=depth)
-        engine = getattr(llm, "_multi_engine", None)
-        if (engine is None or [s for s in engine.ssms] != list(ssms)
-                or engine.depth != depth):
-            engine = llm._multi_engine = MultiSpecEngine(
-                llm, ssms, depth, max_rounds=cfg.spec_rounds_per_call)
+        engine = self._engine_of("spec_tree_fused", llm, ssms, depth, 1)
         shape = self._prefill_shape(cfg)
         active: List[Optional[Request]] = [None] * R
         done: List[GenerationResult] = []

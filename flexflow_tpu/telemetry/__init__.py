@@ -39,6 +39,7 @@ ffsv_batch_occupancy             histogram  live slots / max slots per tick
 ffsv_kv_cache_utilization        histogram  mean seq_len / max_seq over live
 ffsv_prefill_step_seconds        histogram  a prefill step's span (see below)
 ffsv_decode_block_seconds        histogram  device-fenced decode block time
+ffsv_decode_width                gauge      tokens a row of the last decode block
 ffsv_spec_block_seconds          histogram  device-fenced speculation block
 ffsv_request_latency_seconds     histogram  admission -> finish
 ffsv_request_ttft_seconds        histogram  admission -> first token
@@ -368,6 +369,10 @@ class ServingTelemetry:
         self.decode_block_seconds = r.histogram(
             "ffsv_decode_block_seconds",
             "device-fenced fused decode block time")
+        self.decode_width = r.gauge(
+            "ffsv_decode_width",
+            "tokens a row the last decode block's steps computed: 1, or "
+            "the verify width where a speculation engine verifies the model")
         self.spec_block_seconds = r.histogram(
             "ffsv_spec_block_seconds",
             "device-fenced fused speculation block time")
@@ -653,16 +658,21 @@ class ServingTelemetry:
         self.round_prefill_allowance.observe(allowed)
 
     def record_decode_block(self, seconds: float, steps: int, n_live: int,
-                            guids=(), t0: Optional[float] = None):
+                            guids=(), t0: Optional[float] = None,
+                            width: int = 1):
         """``t0``: as in ``record_prefill``; a block launched behind a
         prefill step starts where that step's wait returned, so no prefill
         time falls inside a ``decode_block`` span. Every request's copy of
-        the span carries the block's live rows, ``n_live``."""
+        the span carries the block's live rows, ``n_live``, and the tokens
+        a row each step computed, ``width`` (InferenceManager.decode_width:
+        one, or the verify width of the engine that verifies the model)."""
         t0, seconds = self._own_time(seconds, t0)
         self.decode_block_seconds.observe(seconds)
         self.decode_steps.inc(steps * n_live)
+        self.decode_width.set(width)
         for g in guids:
-            self.tracer.decode_block(g, steps, t0, seconds, int(n_live))
+            self.tracer.decode_block(g, steps, t0, seconds, int(n_live),
+                                     int(width))
         self.flight.record("decode_block", seconds=round(seconds, 6),
                            steps=int(steps), n_live=int(n_live))
 

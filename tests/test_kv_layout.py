@@ -380,6 +380,7 @@ def test_no_step_relays_a_layer_of_the_cache(step, monkeypatch):
     rng = jax.random.PRNGKey(0)
     Rm = m.config.max_requests_per_batch
     if step == "decode_block":
+        assert ifm.decode_width == 1    # the kernel on, no engine verifies m
         block = make_decode_block(m, ifm._compute_dtype, 4,
                                   width=ifm.decode_width)
         closed = jax.make_jaxpr(block)(

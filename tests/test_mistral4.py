@@ -97,6 +97,9 @@ KERNEL_CASES = {
         (1, (0, 1024, 2047), (1, 1, 1), "append", False),
     "decode_append_on_a_stack_with_an_idle_row":
         (1, (15, 1039, 1500), (1, 0, 1), "append", True),
+    # one token a row at the published 32 heads: 32 query rows a program
+    "decode_append_of_32_heads_on_a_stack":
+        (1, (0, 1023, 2047), (1, 1, 1), "append", True),
     "row_map_chunks_ragged_on_a_stack_with_an_idle_row":
         (8, (0, 250, 1300), (8, 0, 5), True, True),
     "row_map_two_segments_of_one_slot":
@@ -116,7 +119,7 @@ def test_latent_flash_attend_matches_the_oracle(case):
     decode step's entry lands at its position in place, before the row is
     attended, and an idle row's cache is left as it was."""
     Q, starts, nums, row_map, stacked = KERNEL_CASES[case]
-    R, H, W, rank, S = 3, 4, 256, 128, 2048
+    R, H, W, rank, S = 3, 32 if "32_heads" in case else 4, 256, 128, 2048
     rng = np.random.default_rng(0)
     starts, nums = np.array(starts), np.array(nums)
     lengths = np.where(nums > 0, starts + nums, 0)

@@ -336,6 +336,87 @@ def test_flash_fused_append_stacked_layer():
                                       k_new[r, 0])
 
 
+#  name: (query heads a key/value head, key/value heads, head dim, ring rows)
+ONE_TOKEN_CASES = {
+    "mha_d128": (1, 4, 128, None),          # OLMoE, OPT's verifier
+    "gqa8_d128": (8, 2, 128, None),         # K-EXAONE's full layers
+    "gqa8_d128_ring": (8, 2, 128, 256),     # its windowed layers
+    "mqa71_d64_packed": (71, 1, 64, None),  # Falcon
+    "mha_d64_packed": (1, 4, 64, None),     # a D=64 draft
+}
+
+
+@pytest.mark.parametrize("case", sorted(ONE_TOKEN_CASES))
+def test_flash_fused_append_at_one_token_a_row(case):
+    """A decode step of a model that no engine verifies: ONE query token a
+    row, so ``G x Q`` = 1, 8 or 71 query rows a key/value head (no sublane
+    of them at 1, no whole one at 71), with the fused append, over the full
+    cache, a ring and the packed D=64 layout; an idle row is left alone."""
+    G, KH, D, ring = ONE_TOKEN_CASES[case]
+    R, S, W = 4, 512, 16
+    rows = ring or S
+    q, k, v = _mk(R, 1, G * KH, KH, D, rows, seed=3)
+    rng = np.random.RandomState(17)
+    k_new = jnp.asarray(rng.randn(R, 1, KH, D).astype(np.float32))
+    v_new = jnp.asarray(rng.randn(R, 1, KH, D).astype(np.float32))
+    # position 0, inside a block, the last (past a ring's wrap), idle
+    appos = np.array([0, 300, (1023 if ring else S - 1), -1])
+    lengths = jnp.asarray(np.where(appos >= 0, appos + 1, 0), jnp.int32)
+    qpos = jnp.asarray(np.maximum(appos, 0)[:, None], jnp.int32)
+    live = np.nonzero(appos >= 0)[0]
+    at = (live, slice(None), appos[live] % rows)
+    k_ref, v_ref = k.at[at].set(k_new[live, 0]), v.at[at].set(v_new[live, 0])
+    kw = {} if ring is None else dict(
+        window=W, key_pos=kvl.ring_positions(lengths, rows))
+    ref = reference_attend(q, k_ref, v_ref, lengths, qpos, **kw)
+    out, k_out, v_out = flash_attend(
+        q, k, v, lengths, qpos,
+        append_kv=(k_new, v_new, jnp.asarray(appos, jnp.int32)),
+        interpret=True, **({} if ring is None else {"window": W}))
+    assert out.shape == (R, 1, G * KH * D)
+    _cmp(ref, out, lengths, 2e-5)
+    for r in live:
+        np.testing.assert_array_equal(k_out[r, :, appos[r] % rows],
+                                      k_new[r, 0])
+        np.testing.assert_array_equal(v_out[r, :, appos[r] % rows],
+                                      v_new[r, 0])
+    np.testing.assert_array_equal(k_out[3], k[3])
+    # what a row held below its new position is what it holds now
+    np.testing.assert_array_equal(k_out[1, :, :300 % rows],
+                                  k[1, :, :300 % rows])
+
+
+@pytest.mark.parametrize("config", [
+    "falcon-7b", "olmoe-1b-7b", "k-exaone-236b-a23b", "mistral-small-4-119b",
+    "opt-6.7b-spec"])
+def test_decode_block_at_one_token_a_row_yields_the_wide_blocks_tokens(
+        config, monkeypatch, capsys):
+    """tools/profile_decode.py --config (the A/B of the decode block's two
+    widths, by hand on the chip) at a configuration's rehearsal sizes, in
+    float32: with no engine over the model the manager resolves one token a
+    row, the kernels (interpreted) serve both blocks, and the block at
+    width 1 decodes the tokens of the block at the verify width 8."""
+    import json
+    import os
+
+    monkeypatch.syspath_prepend(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    for key in ("JAX_PLATFORMS", "FF_PALLAS_INTERPRET"):
+        monkeypatch.setenv(key, os.environ.get(key, ""))   # restored after
+    from flexflow_tpu import kernels as ffk
+    from tools import profile_decode
+
+    ffk.reset_dispatch_stats()
+    assert profile_decode.main_configs([config, "--rehearse"]) == 0
+    res = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert res["config"] == config and res["width_resolved"] == 1
+    assert res["widths"] == [1, 8] and res["rows"] >= 2
+    assert res["tokens_equal"] == 1.0
+    assert res["attention"]["fast_path_traces"] > 0
+    assert not res["attention"]["fallback_traces"]
+    assert "ms_per_step" not in res         # no device time from a CPU
+
+
 def test_head_dim_64_short_cache_pads_to_keep_flash(monkeypatch):
     """D=64 with a cache length the packed 256-position block can't tile
     (S=128) must fall back to the pad-to-128 cache layout — NOT off the

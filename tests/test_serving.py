@@ -170,6 +170,84 @@ def test_verify_consistent_decode_width_matches_width1():
     assert run(8, max_new=60, max_seq=40) == run(1, max_new=60, max_seq=40)
 
 
+@pytest.mark.parametrize("case", ["kernel_on_and_no_engine",
+                                  "config_wins_over_an_engine"])
+def test_decode_width_without_and_against_an_engine(case, monkeypatch):
+    """A model that no engine verifies decodes one token a row, with the
+    Pallas kernel serving it too (where the width used to be the verify
+    pass's 8 unasked); ``FFConfig.decode_width``, when set, is the width
+    whatever an engine says."""
+    from flexflow_tpu import kernels as ffk
+    from flexflow_tpu.serve.inference_manager import InferenceManager
+
+    monkeypatch.setenv("FF_PALLAS_INTERPRET", "1")
+    asked = 4 if case == "config_wins_over_an_engine" else 0
+    cfg = ff.FFConfig(max_requests_per_batch=2, max_sequence_length=128,
+                      max_tokens_per_batch=16, seed=0, decode_width=asked)
+    model = ff.FFModel(cfg)
+    create_llama_model(model, TINY, mode=InferenceMode.TREE_VERIFY_MODE)
+    model.compile(comp_mode=ff.CompMode.COMP_MODE_INFERENCE)
+    assert ffk.use_pallas(cfg)
+    ifm = InferenceManager(model)
+    assert ifm.decode_width == (asked or 1)
+    ifm.verified_at(8)
+    assert ifm.decode_width == (asked or 8)
+
+
+@pytest.mark.parametrize("engine,depth,width", [
+    ("tree", 4, 8), ("tree", 8, 16), ("chain", 4, 5)])
+def test_decode_width_is_the_verifying_engines(engine, depth, width,
+                                               monkeypatch):
+    """Served incrementally and then speculatively, one model ends up at
+    its engine's verify width (the fused tree's nodes padded to sublanes,
+    16 past a depth of 7; the chain's depth + 1): the loop that fetches the
+    engine tells the verifier's manager, the block built at one token a row
+    is dropped, ``_fallback_decode`` (every request parks at the tiny
+    pair's cost ratio of 1) runs at the engine's width, the span and the
+    gauge say so, and the tokens are what they were."""
+    from flexflow_tpu.serve import engine as engines
+    from flexflow_tpu.telemetry import ServingTelemetry
+
+    llm = make_model(InferenceMode.TREE_VERIFY_MODE, max_requests=2)
+    ssm = make_model(InferenceMode.BEAM_SEARCH_MODE, max_requests=2)
+    make, built = engines.make_decode_block, []
+
+    def counted(model, dtype, steps, width=1):
+        built.append(width)
+        return make(model, dtype, steps, width=width)
+
+    monkeypatch.setattr(engines, "make_decode_block", counted)
+    cls = {"chain": engines.SpecChainEngine,
+           "tree": engines.MultiSpecEngine}[engine]
+    monkeypatch.setattr(cls, "run_block", lambda *a, **k: pytest.fail(
+        "a request drafted: the fallback was to decode every token"))
+
+    def serve(loop, tel=None):
+        rm = RequestManager(telemetry=tel)
+        for p in [[5, 9, 23, 44], [7, 3, 11]]:
+            rm.register_new_request(p, max_new_tokens=10)
+        return {tuple(r.input_tokens): r.output_tokens for r in loop(rm)}
+
+    incr = serve(lambda rm: rm.generate_incr_decoding(llm))
+    ifm = llm._inference_manager
+    assert ifm.decode_width == 1 and built == [1]
+    tel = ServingTelemetry()
+    spec = serve(lambda rm: (rm.generate_spec_infer if engine == "chain"
+                             else rm._generate_spec_tree_fused)(
+        llm, [ssm], spec_depth=depth), tel)
+    assert getattr(llm, f"_{'chain' if engine == 'chain' else 'multi'}"
+                   "_engine").tree_width == width
+    assert ifm.decode_width == width and built == [1, width]
+    assert spec == incr
+    assert tel.registry.get("ffsv_decode_width").value == width
+    blocks = [e for e in tel.tracer.events
+              if e.get("name") == "decode_block"]
+    assert blocks and {e["args"]["width"] for e in blocks} == {width}
+    # from here on incremental decoding takes the verify pass's shapes too
+    assert serve(lambda rm: rm.generate_incr_decoding(llm)) == incr
+    assert built == [1, width]
+
+
 @SPEC_ENGINES
 def test_spec_infer_matches_incr_decoding(models, engine, monkeypatch):
     """With the SSM = the LLM's own weights, speculation must accept nearly
@@ -805,6 +883,8 @@ class _RuleIFM:
     at the start of each decode block with the call's index. ``costs``: the
     loop's ``step_costs``, given and not timed (None: the loop times the
     fake's calls itself, and has no estimate before its third sample)."""
+
+    decode_width = 1                # what the loop stamps its blocks' spans
 
     def __init__(self, on_decode=None, costs=None):
         self.prefills = []          # BatchMeta of every prefill step

@@ -7,7 +7,20 @@ fenced by host readback):
                per-layer slope (vs the weight-stream bound) and a fixed
                per-step intercept (embed + final norm + lm_head + argmax
                + loop machinery).
-  --width      decode_block at the verify-consistent width vs width=1.
+  --width      decode_block at the width the manager resolved (one token
+               a row unless an engine verifies the model) and, beside it,
+               at the other of 1 and the sublane-padded verify width 8.
+  --config NAME [NAME ...] [--rehearse] [--cut N]
+               the same A/B on a benchmark configuration
+               (benchmark/configs/NAME.json, built as its cell builds it; N
+               layers instead of its depth): ragged prompts are prefilled
+               into every slot but the last, then the two blocks decode the
+               same rows. One JSON line a configuration: the width resolved,
+               ms a step at each width, and how many of the tokens agree
+               (a near-tie may fall either way in bfloat16; float32 on the
+               CPU, where they must all agree). --rehearse: the CPU, the
+               configuration's rehearsal sizes, interpreted kernels, no
+               times.
   --jnp-attn   use_pallas=False variant: XLA jnp attention vs the Pallas
                kernel path.
   --head       head-only fused loop (embed -> final norm -> lm_head ->
@@ -15,7 +28,10 @@ fenced by host readback):
 
 Findings that shaped the shipped code (7B-geometry int8, one v5e):
   * per-layer slope 0.325 ms vs 0.247 ms stream bound;
-  * verify-consistent width-8 decode costs only +4.6% over width-1;
+  * verify-consistent width-8 decode costs only +4.6% over width-1 at 8
+    slots (64 rows: under the ridge of 240 flops a byte of int8 weight);
+    at 16 and 32 slots it is over the ridge, so since PR 38 a model takes
+    the verify width only when an engine verifies it (PERF.md section 6);
   * native int8xint8 MXU gemms are NOT faster than the shipped
     dequant-into-bf16 gemm at M=64, so dequant-on-read stays;
   * jnp whole-cache attention at S=256 is slower than the Pallas block
@@ -23,8 +39,11 @@ Findings that shaped the shipped code (7B-geometry int8, one v5e):
 
 Usage: python tools/profile_decode.py [--layers] [--width] [--jnp-attn]
                                       [--head]
+       python tools/profile_decode.py --config falcon-7b [--rehearse]
 """
 
+import json
+import os
 import sys
 import time
 
@@ -60,17 +79,10 @@ def build(layers, bench, use_pallas=True):
 
 
 def time_block(ifm, R, prompt_len, n=96):
-    tok = np.ones((R,), np.int32)
-    pos = np.full((R,), prompt_len, np.int32)
-    act = np.ones((R,), bool)
-    ifm.decode_block(tok, pos, act, 4)            # compile
-    best = float("inf")
-    for _ in range(3):
-        t0 = time.perf_counter()
-        out = ifm.decode_block(tok, pos, act, n)  # one device call
-        np.asarray(out)
-        best = min(best, (time.perf_counter() - t0) / n)
-    return best
+    """Seconds a step of the manager's own decode block."""
+    return best_ms(ifm.decode_block, np.ones((R,), np.int32),
+                   np.full((R,), prompt_len, np.int32), np.ones((R,), bool),
+                   n) / 1e3
 
 
 def run_layer_scaling(bench):
@@ -105,37 +117,115 @@ def run_layer_scaling(bench):
           f"(lm_head stream alone {lm_head / bw * 1e3:.3f} ms)")
 
 
-def run_width(bench):
+def other_width(width: int) -> int:
+    from flexflow_tpu.kernels.attention import SUBLANE
+
+    return SUBLANE if width == 1 else 1
+
+
+def block_at(m, width: int, steps: int):
+    """``(tok, pos, act, n) -> tokens [R, n]`` through the decode block of
+    ``m`` at ``width``, the state threaded through the model."""
     import jax
     import jax.numpy as jnp
 
     from flexflow_tpu.serve.engine import make_decode_block
 
-    R, P = bench.NUM_REQUESTS, bench.PROMPT_LEN
-    m, ifm = build(bench.LAYERS, bench)
-    t = time_block(ifm, R, P)
-    print(f"decode_block(width={ifm.decode_width}): {t * 1e3:.3f} ms/step")
-    blk1 = make_decode_block(m, jnp.bfloat16, 128, width=1)
+    blk = make_decode_block(m, jnp.dtype(m.config.compute_dtype), steps,
+                            width=width)
     rng = jax.random.PRNGKey(0)
-    tok = jnp.ones((R,), jnp.int32)
-    pos = jnp.full((R,), P, jnp.int32)
-    act = jnp.ones((R,), bool)
 
-    def run1(n):
-        toks, st, _ = blk1(m.params, m.op_state, tok, pos, act, rng,
-                           jnp.int32(n))
-        m.op_state = st
-        return np.asarray(toks)
+    def run(tok, pos, act, n):
+        toks, m.op_state, _ = blk(m.params, m.op_state, jnp.asarray(tok),
+                                  jnp.asarray(pos), jnp.asarray(act), rng,
+                                  jnp.int32(n))
+        return np.asarray(toks)[:, :n]
 
-    run1(4)
+    return run
+
+
+def best_ms(run, tok, pos, act, n):
+    run(tok, pos, act, 4)                         # compile
     best = float("inf")
     for _ in range(3):
         t0 = time.perf_counter()
-        run1(96)
-        best = min(best, (time.perf_counter() - t0) / 96)
-    print(f"decode_block(width=1): {best * 1e3:.3f} ms/step "
-          f"(width-{ifm.decode_width} costs "
-          f"{(t / best - 1) * 100:+.1f}%)")
+        run(tok, pos, act, n)                     # its read-back = fence
+        best = min(best, (time.perf_counter() - t0) / n)
+    return best * 1e3
+
+
+def run_width(bench):
+    R, P = bench.NUM_REQUESTS, bench.PROMPT_LEN
+    m, ifm = build(bench.LAYERS, bench)
+    tok = np.ones((R,), np.int32)
+    pos = np.full((R,), P, np.int32)
+    act = np.ones((R,), bool)
+    ms = {w: best_ms(block_at(m, w, 128), tok, pos, act, 96)
+          for w in (ifm.decode_width, other_width(ifm.decode_width))}
+    (w0, t0), (w1, t1) = ms.items()
+    print(f"decode_block(width={w0}, resolved): {t0:.3f} ms/step")
+    print(f"decode_block(width={w1}): {t1:.3f} ms/step "
+          f"({(t1 / t0 - 1) * 100:+.1f}% against width {w0})")
+
+
+def run_config(name: str, rehearse: bool, cut=None) -> dict:
+    """The two widths of the decode block on one benchmark configuration."""
+    import jax
+
+    from benchmark import run as bench_run
+    from benchmark.families import _common as C
+    from flexflow_tpu.ffconst import InferenceMode
+    from flexflow_tpu.models import FAMILIES
+    from flexflow_tpu.serve.inference_manager import InferenceManager
+    from flexflow_tpu.serve.request_manager import RequestManager as RM
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", f"{name}.json")) as f:
+        cfg = json.load(f)
+    f32 = {}
+    if rehearse:
+        bench_run.apply_rehearsal(cfg, {"cycle": []})
+        f32 = dict(compute_dtype="float32", kv_cache_dtype="float32",
+                   quantization_type=None)
+    family = bench_run.load_module("families", cfg["family"])
+    mc = family._model_cfg(cfg, cut)
+    m = C.build_model(C.ffconfig(cfg, False, **f32),
+                      FAMILIES[cfg["family"]].build, mc,
+                      InferenceMode.INC_DECODING_MODE)
+    ifm = InferenceManager(m)
+    R, steps = m.config.max_requests_per_batch, m.config.decode_block_steps
+    chunk = C.prefill_chunk(cfg)
+    rng = np.random.default_rng(cfg["weights_seed"])
+    prompts = [rng.integers(1, cfg["vocab_size"],
+                            size=2 * chunk + 1 + 3 * r).tolist()
+               for r in range(R - 1)]             # the last slot stays idle
+    for at in range(0, max(map(len, prompts)) - 1, chunk):
+        rows = [(r, p[at:min(at + chunk, len(p) - 1)], at)
+                for r, p in enumerate(prompts) if at < len(p) - 1]
+        ifm.step(RM._meta_from_rows(R, chunk, rows), want_output=False)
+    tok = np.array([p[-1] for p in prompts] + [0], np.int32)
+    pos = np.array([len(p) - 1 for p in prompts] + [0], np.int32)
+    act = np.arange(R) < R - 1
+    widths = (ifm.decode_width, other_width(ifm.decode_width))
+    blocks = {w: block_at(m, w, steps) for w in widths}
+    # the second block rewrites the positions the first wrote: a row only
+    # ever attends up to its own position
+    toks = {w: blocks[w](tok, pos, act, steps)[act] for w in widths}
+    same = toks[widths[0]] == toks[widths[1]]
+    out = {"config": name, "layers": mc.num_hidden_layers,
+           "rows": int(act.sum()), "positions": [int(pos[act].min()),
+                                                 int(pos[act].max())],
+           "steps": steps, "width_resolved": widths[0],
+           "widths": list(widths),
+           "tokens_equal": float(same.mean()),
+           "first_tokens_equal": float(same[:, 0].mean()),
+           "attention": C.attention_paths(),
+           "device": jax.devices()[0].device_kind}
+    if not rehearse:
+        out["ms_per_step"] = {str(w): round(best_ms(blocks[w], tok, pos, act,
+                                                    steps), 4)
+                              for w in widths}
+    return out
 
 
 def run_jnp_attention(bench):
@@ -192,9 +282,33 @@ def run_head_only(bench, model):
           f"{getattr(head, 'nbytes', 0) / bw * 1e3:.3f} ms)")
 
 
+def main_configs(argv) -> int:
+    rehearse = "--rehearse" in argv
+    cut = int(argv[argv.index("--cut") + 1]) if "--cut" in argv else None
+    names = [a for i, a in enumerate(argv) if not a.startswith("--")
+             and argv[i - 1] != "--cut"]
+    if rehearse:
+        os.environ["JAX_PLATFORMS"] = "cpu"
+        os.environ["FF_PALLAS_INTERPRET"] = "1"
+    import jax
+
+    if not rehearse and jax.devices()[0].platform != "tpu":
+        print("no TPU; nothing was run", file=sys.stderr)
+        return 2
+    for name in names:
+        print(json.dumps(run_config(name, rehearse, cut)), flush=True)
+    return 0
+
+
 def main():
     from flexflow_tpu.utils.compile_cache import enable_compile_cache
 
+    if "--config" in sys.argv:
+        sys.path.insert(0, os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))))
+        if "--rehearse" not in sys.argv:
+            enable_compile_cache()
+        sys.exit(main_configs(sys.argv[sys.argv.index("--config") + 1:]))
     enable_compile_cache()
     args = set(sys.argv[1:])
     sys.argv = [sys.argv[0]]       # bench.py parses argv at import time
