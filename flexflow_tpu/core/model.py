@@ -299,8 +299,18 @@ class FFModel:
                            position_bias: bool, rope_theta: float,
                            name, qk_norm_eps: Optional[float] = None,
                            qk_norm_per_head: bool = False,
-                           sliding_window: Optional[int] = None
+                           sliding_window: Optional[int] = None,
+                           block_length: Optional[int] = None
                            ) -> Tensor:
+        if block_length is not None and (
+                op_type != OpType.INC_MULTIHEAD_SELF_ATTENTION
+                or sliding_window is not None or position_bias):
+            raise NotImplementedError(
+                "an attention layer of a block-diffusion model is served by "
+                f"incremental decoding only, not as {op_type.name}, and "
+                "with neither a window nor a position bias: a query sees "
+                "its whole block, which tree verification, beam drafting, "
+                "a ring and ALiBi all read as a causal position")
         if sliding_window is not None \
                 and op_type != OpType.INC_MULTIHEAD_SELF_ATTENTION:
             raise NotImplementedError(
@@ -336,7 +346,11 @@ class FFModel:
             # one step appends to a slot, the batch's token budget
             **({} if sliding_window is None else
                {"sliding_window": int(sliding_window),
-                "max_step_tokens": self.config.max_tokens_per_batch})),
+                "max_step_tokens": self.config.max_tokens_per_batch}),
+            # a block-diffusion model's layer: a query sees the keys of its
+            # own block both ways (ops/inc_attention.block_visibility)
+            **({} if block_length is None else
+               {"block_length": int(block_length)})),
             name)
 
     def inc_multihead_latent_attention(
@@ -392,18 +406,21 @@ class FFModel:
             name: Optional[str] = None,
             qk_norm_eps: Optional[float] = None,
             qk_norm_per_head: bool = False,
-            sliding_window: Optional[int] = None) -> Tensor:
+            sliding_window: Optional[int] = None,
+            block_length: Optional[int] = None) -> Tensor:
         """``qk_norm_eps``: RMS-normalise q and k, over the whole projection
         or (``qk_norm_per_head``) over each head. ``sliding_window``: a
         query sees the last that many positions, and the layer keeps a ring
-        of them instead of ``max_sequence_length`` (ops/kv_layout.py)."""
+        of them instead of ``max_sequence_length`` (ops/kv_layout.py).
+        ``block_length``: a block-diffusion model's layer: causal across
+        blocks of that many positions, both ways inside one."""
         return self._serving_attention(
             OpType.INC_MULTIHEAD_SELF_ATTENTION, input, embed_dim, num_q_heads,
             num_kv_heads, kdim, vdim, dropout, bias, add_bias_kv,
             add_zero_attn, data_type, kernel_initializer,
             apply_rotary_embedding, scaling_query, scaling_factor,
             qk_prod_scaling, position_bias, rope_theta, name, qk_norm_eps,
-            qk_norm_per_head, sliding_window)
+            qk_norm_per_head, sliding_window, block_length)
 
     def spec_inc_multihead_self_attention(self, input: Tensor, embed_dim: int,
                                           num_heads: int, **kw) -> Tensor:
@@ -641,6 +658,18 @@ class FFModel:
     def argmax(self, input: Tensor, beam_search: bool = False, name=None):
         return self._add_layer(OpType.ARGMAX, [input],
                                dict(beam_search=beam_search), name)
+
+    def unmasking_head(self, logits: Tensor, diffusion, name=None):
+        """The head of a block-diffusion model: at every position the
+        greedy pick and its float32 softmax probability, two outputs
+        (ops/sampling_ops.ArgMax ``confidence``). ``diffusion`` (a
+        serve/batch_config.BlockDiffusion) is how the serving stack fills
+        a block from them; compile hands it on as ``self.block_diffusion``.
+        Returns the picks."""
+        return self._add_layer(
+            OpType.ARGMAX, [logits],
+            dict(beam_search=False, confidence=True, diffusion=diffusion),
+            name)
 
     def sampling(self, input: Tensor, top_p: float = 1.0,
                  temperature: float = 1.0, name=None):
@@ -959,6 +988,7 @@ class FFModel:
 
             refuse_windowed(self.op_state,
                             f"a mesh that divides a model ({split})")
+        self._note_block_diffusion(split)
         from flexflow_tpu.ops.moe import init_counters
 
         init_counters(self)     # routed-expert layers, telemetry on
@@ -1161,6 +1191,49 @@ class FFModel:
         self._eval_step = jax.jit(eval_step)
         self._predict_step = jax.jit(predict_step)
         self._compiled = True
+
+    def _note_block_diffusion(self, split):
+        """``self.block_diffusion``: the serve/batch_config.BlockDiffusion
+        a model's head carries (``unmasking_head``), None for every other
+        model: what the decode block, the scheduler and the telemetry read
+        off the compiled model to know that a step fills a block and yields
+        a count of tokens. Refuses here what cannot serve such a model."""
+        head = self.layers[-1].attrs if self.layers else {}
+        blocks = {ly.attrs["block_length"] for ly in self.layers
+                  if "block_length" in ly.attrs}
+        bd = self.block_diffusion = head.get("diffusion")
+        if bd is None:
+            if blocks:
+                raise NotImplementedError(
+                    "attention layers with a block_length need the "
+                    "unmasking_head that fills their blocks")
+            return
+        from flexflow_tpu.ops.inc_attention import refuse_block_diffusion
+        from flexflow_tpu.serve.request_manager import RequestManager
+
+        cfg, B = self.config, bd.block_length
+        if blocks != {B}:
+            raise NotImplementedError(
+                f"a head that fills blocks of {B} over attention layers "
+                f"that see blocks of {sorted(blocks)}")
+        if split:
+            refuse_block_diffusion(self,
+                                   f"a mesh that divides a model ({split})")
+        if cfg.inference_debugging:
+            refuse_block_diffusion(
+                self, "inference_debugging (its dumped decode runs through "
+                "InferenceManager.step, one token a row)")
+        if cfg.decode_width not in (0, B):
+            raise NotImplementedError(
+                f"FFConfig.decode_width {cfg.decode_width} over a model "
+                f"whose decode step is its block of {B}")
+        chunk, _ = RequestManager._prefill_shape(cfg)
+        if cfg.max_sequence_length % B or chunk % B:
+            raise NotImplementedError(
+                f"block-diffusion serving keeps whole blocks of {B}: "
+                f"max_sequence_length {cfg.max_sequence_length} and the "
+                f"prefill chunk {chunk} (max_tokens_per_batch over at most "
+                "four rows) have to be multiples of it")
 
     def _consolidate_kv_caches(self):
         """Stack homogeneous per-layer KV caches into two [L, ...] arrays.

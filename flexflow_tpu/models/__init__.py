@@ -2,7 +2,7 @@
 
 Capability parity with the reference model zoo (reference inference/models/
 llama.cc, opt.cc, falcon.cc, mpt.cc, starcoder.cc and their Python twins in
-python/flexflow/serve/models/; OLMoE, EXAONE-MoE and Mistral-4, sparse-expert families, are beyond it): each model family is a builder that records
+python/flexflow/serve/models/; OLMoE, EXAONE-MoE, Mistral-4 and SDAR-MoE, sparse-expert families, are beyond it): each model family is a builder that records
 the decoder graph through the FFModel op-builder surface, plus a HuggingFace
 state-dict name mapping so real checkpoints load. ``FAMILIES`` maps the HF
 ``model_type`` to the family (the reference's ModelType enum +
@@ -19,6 +19,7 @@ from flexflow_tpu.models import mistral4 as _mistral4
 from flexflow_tpu.models import mpt as _mpt
 from flexflow_tpu.models import olmoe as _olmoe
 from flexflow_tpu.models import opt as _opt
+from flexflow_tpu.models import sdar_moe as _sdar_moe
 from flexflow_tpu.models import starcoder as _starcoder
 from flexflow_tpu.models.exaone_moe import (ExaoneMoEConfig,
                                             create_exaone_moe_model)
@@ -30,6 +31,7 @@ from flexflow_tpu.models.mistral4 import (Mistral4Config,
 from flexflow_tpu.models.mpt import MPTConfig, create_mpt_model
 from flexflow_tpu.models.olmoe import OLMoEConfig, create_olmoe_model
 from flexflow_tpu.models.opt import OPTConfig, create_opt_model
+from flexflow_tpu.models.sdar_moe import SDARMoEConfig, create_sdar_moe_model
 from flexflow_tpu.models.starcoder import (STARCODERConfig,
                                            create_starcoder_model)
 
@@ -73,6 +75,9 @@ FAMILIES = {
     "mistral4": ModelFamily("mistral4", Mistral4Config,
                             create_mistral4_model, _mistral4.hf_weight_map,
                             _mistral4.preprocess_hf_state_dict),
+    "sdar_moe": ModelFamily("sdar_moe", SDARMoEConfig, create_sdar_moe_model,
+                            _sdar_moe.hf_weight_map,
+                            _sdar_moe.preprocess_hf_state_dict),
     "gpt_bigcode": ModelFamily("gpt_bigcode", STARCODERConfig,
                                create_starcoder_model,
                                _starcoder.hf_weight_map,
@@ -104,6 +109,7 @@ __all__ = [
     "ModelFamily",
     "OLMoEConfig",
     "OPTConfig",
+    "SDARMoEConfig",
     "STARCODERConfig",
     "create_exaone_moe_model",
     "create_falcon_model",
@@ -112,6 +118,7 @@ __all__ = [
     "create_mpt_model",
     "create_olmoe_model",
     "create_opt_model",
+    "create_sdar_moe_model",
     "create_starcoder_model",
     "family_for_hf_config",
     "load_hf_state_dict",

@@ -68,6 +68,11 @@ TINY_CONFIGS: Dict[str, Dict[str, Any]] = {
                   num_hidden_layers=2, num_attention_heads=4,
                   num_key_value_heads=4, num_experts=8,
                   num_experts_per_tok=2, max_position_embeddings=128),
+    "sdar_moe": dict(vocab_size=128, hidden_size=64, moe_intermediate_size=32,
+                     num_hidden_layers=2, num_attention_heads=4,
+                     num_key_value_heads=2, head_dim=16, num_experts=8,
+                     num_experts_per_tok=2, max_position_embeddings=128,
+                     mask_token_id=127),
     "gpt_bigcode": dict(vocab_size=128, hidden_size=64,
                         intermediate_size=128, num_hidden_layers=2,
                         num_attention_heads=4, max_position_embeddings=64),
@@ -168,6 +173,10 @@ def hf_config_dict(family_name: str, config) -> Dict[str, Any]:
         d = dataclasses.asdict(c)
     elif family_name == "olmoe":
         d = dict(dataclasses.asdict(c), norm_topk_prob=False, clip_qkv=None)
+    elif family_name == "sdar_moe":
+        d = dict(dataclasses.asdict(c), norm_topk_prob=True,
+                 decoder_sparse_step=1, mlp_only_layers=[],
+                 sliding_window=None, rope_scaling=None)
     elif family_name == "opt":
         d = dataclasses.asdict(c)
     elif family_name == "falcon":
@@ -278,7 +287,7 @@ def _unstack_olmoe(sd: Dict[str, np.ndarray], c) -> None:
 
 _REFUSE = {"falcon": _refuse_falcon, "mpt": _refuse_mpt,
            "gpt_bigcode": _refuse_starcoder, "starcoder": _refuse_starcoder,
-           "olmoe": _unstack_olmoe}
+           "olmoe": _unstack_olmoe, "sdar_moe": _unstack_olmoe}
 
 
 # --------------------------------------------------------------- save/load

@@ -57,7 +57,8 @@ class OLMoEConfig:
             raise NotImplementedError(
                 "OLMoE with clip_qkv or norm_topk_prob set: the graph below "
                 "neither clamps q/k/v nor renormalises the chosen experts' "
-                "weights (OLMoE-1B-7B publishes null and false)")
+                "weights (OLMoE-1B-7B publishes null and false; "
+                "models/sdar_moe.py is the family that renormalises)")
         kw = {f.name: get(f.name) for f in dataclasses.fields(cls)}
         kw = {k: v for k, v in kw.items() if v is not None}
         kw.setdefault("num_key_value_heads",

@@ -525,6 +525,30 @@ def refuse_windowed(op_state, what: str):
             "a k/v pair")
 
 
+def refuse_block_diffusion(model, what: str):
+    """A block-diffusion model (``FFModel.block_diffusion``) sees a block
+    both ways and keeps it only when it is whole: what reads a cache
+    position as causal, or one token as a step, says so. (Also what the
+    uncompiled builder's refusals say.)"""
+    if getattr(model, "block_diffusion", None) is not None:
+        raise NotImplementedError(
+            f"{what} is not supported over a block-diffusion model: its "
+            "decode step fills a block of positions that see each other "
+            "both ways, and commits it to the cache only when it is whole")
+
+
+def block_visibility(attrs, q_abs):
+    """The position a query is masked by. A block-diffusion model's layer
+    (``attrs["block_length"]``): the LAST position of the query's block,
+    so that key ``j`` is visible to query ``i`` iff ``blk(j) <= blk(i)``,
+    causal across blocks and both ways inside one; the kernels mask by
+    ``key <= qpos`` and nothing else reads it here (no ALiBi, no window).
+    Rotary and every append stay on true positions. Any other layer: the
+    query's own position."""
+    B = attrs.get("block_length")
+    return q_abs if B is None else q_abs // B * B + (B - 1)
+
+
 def _stack(ctx, attrs):
     """(key, {"k", "v"}) of the stack that holds this layer's cache."""
     key = attrs.get("cache_stack", FULL_STACK)
@@ -730,9 +754,10 @@ def append_and_ref(ctx, attrs, k, v, start_pos, num_tokens, active,
                                   pack=pack)
         vs = append_kv_contiguous(st["v"], idx, v, start_pos, active,
                                   pack=pack)
-    elif k.shape[1] == 1 or pack > 1 or ring:
+    elif k.shape[1] == 1 or pack > 1 or ring or "block_length" in attrs:
         # (a packed stack and a ring take every width in place:
-        # append_kv_stacked)
+        # append_kv_stacked; so does a block-diffusion layer's pass, a
+        # block's few rows a (request, head) in every decode step)
         ks = append_kv_stacked(st["k"], idx, k, start_pos, num_tokens,
                                active, pack, ring=ring)
         vs = append_kv_stacked(st["v"], idx, v, start_pos, num_tokens,
@@ -792,7 +817,8 @@ class IncMultiHeadSelfAttention(OpImpl):
         # Causal over absolute cache positions: query token i (at position
         # start+i) sees cache[s] for s <= start+i (enforced in the kernel).
         Q = x.shape[1]
-        q_abs = meta.start_pos[:, None] + jnp.arange(Q)[None, :]   # [R,Q]
+        q_abs = block_visibility(
+            attrs, meta.start_pos[:, None] + jnp.arange(Q)[None, :])  # [R,Q]
         lengths = jnp.where(meta.active, meta.start_pos + meta.num_tokens, 0)
         append_q = getattr(ctx, "kv_append_q", None)
         eff_q = append_q if (append_q is not None and Q > append_q) else Q

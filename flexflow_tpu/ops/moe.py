@@ -197,7 +197,10 @@ def init_counters(model):
 def _step_tokens(ctx, x):
     """(columns that can hold a real token, valid [R, q], phase) of a
     serving step, from the batch descriptor: a padded position or an
-    inactive slot is not a token."""
+    inactive slot is not a token. The phase is what the program that runs
+    the step says it is (``ctx.step_phase``: a block-diffusion model's
+    decode block, whose pass is a block of real tokens wide), else what the
+    descriptor shows."""
     meta = ctx.batch_config
     R, Q = x.shape[0], x.shape[1]
     tree = hasattr(meta, "ancestor")
@@ -207,7 +210,8 @@ def _step_tokens(ctx, x):
     append_q = getattr(ctx, "kv_append_q", None)
     q = append_q if (append_q is not None and Q > append_q) else Q
     valid = (jnp.arange(q)[None, :] < n[:, None]) & meta.active[:, None]
-    phase = "verify" if tree else ("decode" if q == 1 else "prefill")
+    phase = getattr(ctx, "step_phase", None) or (
+        "verify" if tree else ("decode" if q == 1 else "prefill"))
     return q, valid, phase
 
 

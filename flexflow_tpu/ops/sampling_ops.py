@@ -27,6 +27,10 @@ class ArgMax(OpImpl):
         if attrs.get("beam_search", False):
             # beam variant also returns parent ids (reference argmax.cc)
             return [(out_shape, DataType.DT_INT32), (out_shape, DataType.DT_INT32)]
+        if attrs.get("confidence", False):
+            # the pick and its float32 softmax probability (the head of a
+            # block-diffusion model: FFModel.unmasking_head)
+            return [(out_shape, DataType.DT_INT32), (out_shape, DataType.DT_FLOAT)]
         return [(out_shape, DataType.DT_INT32)]
 
     @staticmethod
@@ -34,6 +38,11 @@ class ArgMax(OpImpl):
         idx = jnp.argmax(inputs[0], axis=-1).astype(jnp.int32)
         if attrs.get("beam_search", False):
             return [idx, jnp.zeros_like(idx)]
+        if attrs.get("confidence", False):
+            # softmax(x)[argmax] = 1 / sum(exp(x - max)), in float32
+            x = inputs[0].astype(jnp.float32)
+            top = jnp.max(x, axis=-1, keepdims=True)
+            return [idx, 1.0 / jnp.sum(jnp.exp(x - top), axis=-1)]
         return [idx]
 
 

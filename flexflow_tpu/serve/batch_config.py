@@ -38,6 +38,47 @@ MAX_BEAM_DEPTH = 8
 DEFAULT_PIPELINE_DEPTH = 4
 
 
+@dataclasses.dataclass(frozen=True)
+class BlockDiffusion:
+    """How a block-diffusion model generates (a model's property, set by
+    its builder's ``FFModel.unmasking_head`` and read off the compiled model
+    as ``FFModel.block_diffusion``; never a serving option). A row's next
+    ``block_length`` positions start as mask tokens after what is known of
+    them; a denoise pass runs the block against the cache and the block
+    itself, stores nothing, and unmasks every masked position whose pick
+    is more probable than ``threshold``, or the ``floor`` most confident
+    where fewer clear it; a pass that begins with no mask left commits the
+    block: its keys and values are what the cache keeps, and the row's
+    length grows by the block."""
+
+    block_length: int
+    denoising_steps: int
+    threshold: float
+    mask_token_id: int
+
+    def __post_init__(self):
+        if self.block_length % self.denoising_steps:
+            raise NotImplementedError(
+                f"a schedule of {self.denoising_steps} denoising steps over "
+                f"a block of {self.block_length}: the floor of a pass is "
+                "one number, block_length / denoising_steps")
+
+    @property
+    def floor(self) -> int:
+        """Positions a denoise pass unmasks at least (the schedule)."""
+        return self.block_length // self.denoising_steps
+
+    def passes_for(self, tokens: int) -> int:
+        """The most passes ``tokens`` more tokens of a row can take: every
+        block at the floor, and its commit."""
+        return -(-tokens // self.block_length) * (self.denoising_steps + 1)
+
+    def emitted_most(self, passes: int) -> int:
+        """The most tokens a row can emit in ``passes``: a commit every
+        other pass."""
+        return self.block_length * ((passes + 1) // 2)
+
+
 @dataclasses.dataclass
 class GenerationConfig:
     """Sampling + speculation-policy configuration (reference

@@ -96,7 +96,8 @@ def build_feeds(model, meta):
 
 
 def forward_with_meta(model, params, state, meta, rng, compute_dtype,
-                      kv_contiguous=False, kv_append_q=None):
+                      kv_contiguous=False, kv_append_q=None, phase=None,
+                      outputs=None):
     """One serving forward over a BatchMeta inside jit — the single traced
     body shared by InferenceManager.step and the fused engines.
 
@@ -105,13 +106,19 @@ def forward_with_meta(model, params, state, meta, rng, compute_dtype,
     scatter-free dynamic_update_slice KV append (inc_attention.py
     append_kv_contiguous). ``kv_append_q`` (verify-consistent decode)
     declares that only the first kv_append_q tokens per row are real, so
-    the KV append can skip the padding columns entirely."""
+    the KV append can skip the padding columns entirely. ``phase``: the
+    program that runs the step says what the step is where its width does
+    not ("decode": a block-diffusion pass; ops/moe._step_tokens). ``outputs``:
+    the tensors to hand back, as a tuple, in place of the final one."""
     ctx = OpContext(training=False, rng=rng, compute_dtype=compute_dtype,
                     batch_config=meta, mesh=model.mesh, config=model.config)
     ctx.kv_contiguous = kv_contiguous
     ctx.kv_append_q = kv_append_q
+    ctx.step_phase = phase
     values, new_state = model._run_graph(params, build_feeds(model, meta),
                                          ctx, state)
+    if outputs is not None:
+        return tuple(values[t.tensor_id] for t in outputs), new_state
     return values[model._final_tensor.tensor_id], new_state
 
 
@@ -203,7 +210,14 @@ def make_decode_block(model, compute_dtype, max_steps: int, width: int = 1):
     appended (kv_append_q=1) — the padding rows' KV is never attended —
     via the attention kernel's fused in-place append (inc_attention._attend
     append_kv), so no staging window needs reserving near the cache end.
+
+    A block-diffusion model (``model.block_diffusion``) gets the program of
+    ``_diffusion_block`` under the same signature: ``tok`` is ``[R, B]``.
     """
+    bd = getattr(model, "block_diffusion", None)
+    if bd is not None:
+        return jax.jit(_diffusion_block(model, compute_dtype, max_steps, bd),
+                       donate_argnums=(1,))
 
     def block(params, op_state, tok, pos, active, rng, n):
         R = tok.shape[0]
@@ -244,6 +258,96 @@ def make_decode_block(model, compute_dtype, max_steps: int, width: int = 1):
         return out, op_state, tok
 
     return jax.jit(block, donate_argnums=(1,))
+
+
+# A diffusion block's read-back, one int32 row a slot: the tokens the row
+# emitted (``BlockDiffusion.emitted_most`` columns, the first ``count``
+# real), its carried block (-1: still masked), then PASS_STATS.
+PASS_STATS = ("count", "passes", "commits", "by_threshold", "by_floor")
+
+
+def _diffusion_block(model, compute_dtype, max_steps: int, bd):
+    """The decode block of a block-diffusion model: ``n`` passes of ``B =
+    bd.block_length`` tokens a row, all of them real.
+
+    (params, op_state, blk [R, B], pos [R], active [R], rng, n) ->
+    (packed [R, E + B + len(PASS_STATS)], new_op_state, blk). ``pos[r]`` is
+    the row's committed length, a multiple of B, and ``blk[r]`` its block
+    at positions ``[pos, pos + B)``: a token id, or -1 where the position
+    is still masked (what a row carries from one call into the next; a
+    row that begins a block hands over what is known of it, the prompt's
+    remainder, then -1).
+
+    Every pass is ONE forward of ``[R, B]`` at the true positions, masked
+    positions holding the mask token, with all B keys and values written
+    at those positions before any row attends (one row-granular scatter a
+    layer's cache, in place: inc_attention.append_and_ref), so the block
+    sees itself both ways (inc_attention.block_visibility). A row that
+    entered the pass with a mask left takes a DENOISE pass: ``pos`` does
+    not move, so what the pass wrote is overwritten by the next; it unmasks
+    every masked position whose pick is more probable than the threshold,
+    or the ``bd.floor`` most confident where fewer clear it. A row that entered it whole takes
+    a COMMIT pass: what was written is what the cache keeps, ``pos`` grows
+    by B, the block is emitted and the next begins, all masked. A row
+    whose next block would pass the cache's end sits out. The host
+    reconciles ``max_new_tokens`` and end-of-sequence; overshoot is
+    bounded by the call, as in the one-token block."""
+    B, floor = bd.block_length, bd.floor
+    E = bd.emitted_most(max_steps)
+    S = model.config.max_sequence_length
+    head = model.layers[-1].outputs            # (pick, confidence)
+
+    def block(params, op_state, blk, pos, active, rng, n):
+        R = blk.shape[0]
+        cols = jnp.arange(B, dtype=jnp.int32)
+
+        def body(carry):
+            i, state, blk, pos, out, stats = carry
+            act = active & (pos + B <= S)
+            masked = blk < 0
+            meta = BatchMeta(
+                tokens=jnp.where(masked, bd.mask_token_id, blk),
+                positions=pos[:, None] + cols[None, :], start_pos=pos,
+                num_tokens=B * act.astype(jnp.int32), active=act)
+            (x0, conf), state = forward_with_meta(
+                model, params, state, meta, jax.random.fold_in(rng, i),
+                compute_dtype, phase="decode", outputs=head)
+            commit = act & ~masked.any(axis=1)
+            # denoise: the picks above the threshold, or the floor's most
+            # confident (ties to the earlier position)
+            c = jnp.where(masked, conf.astype(jnp.float32), -jnp.inf)
+            high = masked & (c > bd.threshold)
+            ahead = ((c[:, None, :] > c[:, :, None])
+                     | ((c[:, None, :] == c[:, :, None])
+                        & (cols[None, None, :] < cols[None, :, None])))
+            low = masked & (ahead.sum(axis=-1) < floor)
+            cleared = high.sum(axis=1) >= floor
+            unmask = jnp.where(cleared[:, None], high, low) & act[:, None]
+            blk = jnp.where(unmask, x0.astype(jnp.int32), blk)
+            # commit: emit the block at the row's count, begin the next
+            count = stats[:, 0]
+            at = jnp.arange(E, dtype=jnp.int32)[None, :] - count[:, None]
+            out = jnp.where(
+                commit[:, None] & (at >= 0) & (at < B),
+                jnp.take_along_axis(blk, jnp.clip(at, 0, B - 1), axis=1),
+                out)
+            blk = jnp.where(commit[:, None], -1, blk)
+            step = commit.astype(jnp.int32)
+            took = unmask.sum(axis=1, dtype=jnp.int32)
+            stats = stats + jnp.stack(
+                [B * step, act.astype(jnp.int32), step,
+                 jnp.where(cleared, took, 0), jnp.where(cleared, 0, took)],
+                axis=1)
+            return i + 1, state, blk, pos + B * step, out, stats
+
+        _, op_state, blk, _, out, stats = jax.lax.while_loop(
+            lambda carry: carry[0] < n, body,
+            (jnp.int32(0), op_state, blk, pos,
+             jnp.zeros((R, E), jnp.int32),
+             jnp.zeros((R, len(PASS_STATS)), jnp.int32)))
+        return jnp.concatenate([out, blk, stats], axis=1), op_state, blk
+
+    return block
 
 
 class MultiSpecEngine:

@@ -13,7 +13,7 @@ the compiled-program cache plays the role of the reference's Legion traces.
 
 from __future__ import annotations
 
-from typing import Any, Dict, Optional
+from typing import Any, Dict, NamedTuple, Optional
 
 import numpy as np
 
@@ -21,6 +21,23 @@ import jax
 import jax.numpy as jnp
 
 from flexflow_tpu.ops.base import OpContext
+
+
+class BlockPasses(NamedTuple):
+    """What a decode block of a block-diffusion model hands back, a row a
+    slot: the tokens the row's commit passes emitted (``tokens[r, :count[r]]``),
+    the block it carries into its next call (-1: still masked), and
+    ``stats``, the row's engine.PASS_STATS (tokens emitted, passes run,
+    commit passes, positions unmasked above the threshold and by the
+    floor)."""
+
+    tokens: np.ndarray
+    block: np.ndarray
+    stats: Dict[str, np.ndarray]
+
+    @property
+    def count(self) -> np.ndarray:
+        return self.stats["count"]
 
 
 class InferenceManager:
@@ -45,14 +62,19 @@ class InferenceManager:
     @property
     def decode_width(self) -> int:
         """Tokens a row of a fused decode step: ``config.decode_width``
-        where it is set, else the verify width of the speculation engine
+        where it is set, else the model's block length where it fills
+        blocks (``FFModel.block_diffusion``: every one of them real), else
+        the verify width of the speculation engine
         that verifies this model (``verified_at``), else 1. A wider step
         carries ONE real token a row and is verify-consistent: the program
         shapes of the verify pass, so near-tie argmaxes of the incremental
         and the speculative path of one model resolve alike (the
         reference's spec-vs-incr 30-token CI gate). A model no engine
         verifies has nothing to agree with and pays for no padding."""
-        return int(self.model.config.decode_width) or self._verify_width or 1
+        bd = getattr(self.model, "block_diffusion", None)
+        return (int(self.model.config.decode_width)
+                or (bd.block_length if bd is not None else 0)
+                or self._verify_width or 1)
 
     def verified_at(self, width: int):
         """Whoever builds or fetches the speculation engine over this model
@@ -123,7 +145,11 @@ class InferenceManager:
         pipeline (request_manager.cc:1829): instead of pipelining host-built
         batches, the whole token-feedback loop runs on device via a
         dynamic-trip while_loop — one host round-trip AND one compiled
-        program for every block size. Returns int32 [R, n_steps].
+        program for every block size. Returns int32 [R, n_steps]. For a
+        block-diffusion model a step is a pass over a block: ``tok`` is
+        ``[R, decode_width]`` (-1: a masked position), ``pos`` the rows'
+        committed lengths, and the return a ``BlockPasses``
+        (engine._diffusion_block).
         ``tel``: as in ``step`` (program ``decode_block``). ``rnd`` (the
         caller's telemetry.RoundTrace; None: it has none) may hold a
         prefill step that was launched before this block and is not waited
@@ -160,10 +186,17 @@ class InferenceManager:
             if rnd is not None:
                 rnd.settle()
             ph = tel.call_phase(None, "call_wait", "decode_block")
-        toks = np.asarray(toks)[:, :n_steps]
+        toks = np.asarray(toks)
         if tel is not None:
             tel.call_phase(ph, None)
-        return toks
+        if getattr(self.model, "block_diffusion", None) is None:
+            return toks[:, :n_steps]
+        from flexflow_tpu.serve.engine import PASS_STATS
+
+        stats = dict(zip(PASS_STATS, toks[:, -len(PASS_STATS):].T))
+        emitted = toks.shape[1] - width - len(PASS_STATS)
+        return BlockPasses(toks[:, :emitted],
+                           toks[:, emitted:emitted + width], stats)
 
     def _decode_block_debug(self, tok, pos, active, n_steps: int):
         from flexflow_tpu.serve.batch_config import BatchMeta

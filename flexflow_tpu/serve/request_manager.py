@@ -16,9 +16,15 @@ KV commit run on device.
 
 Slot/convention notes:
 * A request's ``tokens`` = prompt + generated. ``cache_depth`` counts tokens
-  whose KV is in a model's cache. The last token is always "pending" — it is
-  fed to produce the next token (matching the reference's per-request
-  ``token_start_offset``/depth bookkeeping, batch_config.h:66-75).
+  whose KV is in a model's cache. What a decode step is fed is "pending":
+  the last token, which produces the next one (matching the reference's
+  per-request ``token_start_offset``/depth bookkeeping,
+  batch_config.h:66-75); for a block-diffusion model
+  (``FFModel.block_diffusion``) the remainder of the last whole block, 0 to
+  B-1 tokens, which begin the block that the row's passes fill
+  (``Request.block``). A decode block yields one token a row a step, or for
+  such a model a count of tokens a row; ``_stage_decode`` and
+  ``_commit_decode`` are the two places that know which.
 * Single-chain speculation (one SSM, MAX_BEAM_WIDTH=1 — the reference
   default) needs no KV commit at all: accepted drafts are already contiguous
   in the verifier's cache. Multi-SSM token trees use ``commit_tree_kv``.
@@ -44,9 +50,11 @@ from flexflow_tpu.serve.batch_config import (
     MAX_BEAM_DEPTH,
     ancestor_mask_from_parents,
 )
-from flexflow_tpu.serve.inference_manager import InferenceManager
+from flexflow_tpu.serve.inference_manager import (BlockPasses,
+                                                  InferenceManager)
 from flexflow_tpu.serve.step_costs import StepCosts
-from flexflow_tpu.ops.inc_attention import commit_tree_kv
+from flexflow_tpu.ops.inc_attention import (commit_tree_kv,
+                                            refuse_block_diffusion)
 from flexflow_tpu.telemetry import (PendingPrefill, get_telemetry,
                                     mint_trace_id)
 from flexflow_tpu.utils.profiling import device_fence
@@ -107,6 +115,10 @@ class Request:
     prefix_len: int = 0
     prefix_hit_tokens: int = 0
     prefix_checked: bool = False
+    # a block-diffusion row's unfinished block at positions [cache_depth,
+    # cache_depth + B), as its last decode block left it (-1: a position
+    # still masked); None: the row begins the block from its pending tokens
+    block: Any = None
 
     def __post_init__(self):
         if not self.tokens:
@@ -446,6 +458,7 @@ class RequestManager:
             slot = victim.slot
             victim.slot = -1
             victim.cache_depth = 0
+            victim.block = None
             victim.ssm_cache_depth.clear()
             victim.preemptions += 1
             victim.prefill_start_s = 0.0
@@ -678,31 +691,43 @@ class RequestManager:
         return chunk, max(1, cfg.max_tokens_per_batch // chunk)
 
     @staticmethod
+    def _held_back(model):
+        """req -> the tokens a prefill leaves pending, the next decode
+        step's input: the last token, which emits the next one; for a
+        block-diffusion model what follows the last whole block (prefill
+        stores whole blocks only), 0 to B-1 tokens."""
+        bd = getattr(model, "block_diffusion", None)
+        if bd is None:
+            return lambda req: 1
+        return lambda req: len(req.tokens) % bd.block_length
+
+    @staticmethod
     def _prefill_rows(active, chunk: int, depth_of, segments: int,
-                      consecutive: bool = True):
+                      consecutive: bool = True, hold=lambda req: 1):
         """At most ``segments`` segments (slot, tokens, start_pos) of at
-        most ``chunk`` tokens for one prefill step. The requests whose
-        pending tokens exceed 1 get one each, oldest admission first
-        (leaving at least one token pending so the final chunk emits the
-        next token); with ``consecutive`` the spare segments go, in the
-        same order, to those with more still pending, as their next chunks.
-        A slot's segments come in ascending order of start_pos."""
+        most ``chunk`` tokens for one prefill step. The requests with more
+        pending than the decode step takes (``hold``: _held_back) get one
+        each, oldest admission first; with ``consecutive`` the spare
+        segments go, in the same order, to those with more still pending,
+        as their next chunks. A slot's segments come in ascending order of
+        start_pos."""
         rows, taken = [], {}
 
         def pending(req):
-            return len(req.tokens) - depth_of(req) - taken.get(req.slot, 0)
+            return (len(req.tokens) - depth_of(req) - taken.get(req.slot, 0)
+                    - hold(req))
 
         filling = sorted((req for req in active if req is not None
-                          and not req.finished and pending(req) > 1),
+                          and not req.finished and pending(req) > 0),
                          key=lambda req: req.prefill_start_s)
         while filling and len(rows) < segments:
             for req in filling[:segments - len(rows)]:
-                d = len(req.tokens) - pending(req)
-                take = min(pending(req) - 1, chunk)
+                d = len(req.tokens) - hold(req) - pending(req)
+                take = min(pending(req), chunk)
                 rows.append((req.slot, req.tokens[d:d + take], d))
                 taken[req.slot] = taken.get(req.slot, 0) + take
             filling = [req for req in filling
-                       if consecutive and pending(req) > 1]
+                       if consecutive and pending(req) > 0]
         return rows
 
     @staticmethod
@@ -725,13 +750,78 @@ class RequestManager:
         chunk, segments = shape
         compact = self._compact_prefill(ifm)
         rows = self._prefill_rows(active, chunk, depth_of, segments,
-                                  consecutive=compact)
+                                  consecutive=compact,
+                                  hold=self._held_back(ifm.model))
         if rows:
             meta = (self._meta_from_segments(segments, chunk, rows)
                     if compact else
                     self._meta_from_rows(len(active), chunk, rows))
             self._timed_prefill(ifm, meta, tel, rows, active, rnd, lag)
         return rows
+
+    # -- a decode block's two ends ------------------------------------------
+    def _block_steps(self, ifm, live, max_seq: int, cap: int) -> int:
+        """The decode block's steps for ``live``, at most ``cap``: the
+        tokens the row with most to go still needs, never past the KV
+        cache's end, as steps of ``ifm``'s model: a token a step, or the
+        passes those tokens take at most (BlockDiffusion.passes_for: a row
+        that needs fewer finishes early and the host cuts the overshoot)."""
+        tokens = min(max(self._remaining_budget(req, max_seq)
+                         for req in live),
+                     max_seq - max(len(req.tokens) for req in live))
+        bd = getattr(ifm.model, "block_diffusion", None)
+        return max(1, min(tokens if bd is None else bd.passes_for(tokens),
+                          cap))
+
+    @staticmethod
+    def _stage_decode(ifm, live, R: int):
+        """(tok, pos, active) of a decode block over ``live``: each row's
+        pending token and its position; for a block-diffusion model the
+        row's block ``[R, B]`` (what it carried from its last call, else
+        its pending tokens, then -1 for the masked rest) and its committed
+        length."""
+        bd = getattr(ifm.model, "block_diffusion", None)
+        tok = (np.zeros((R,), np.int32) if bd is None
+               else np.full((R, bd.block_length), -1, np.int32))
+        pos = np.zeros((R,), np.int32)
+        act = np.zeros((R,), bool)
+        for req in live:
+            act[req.slot] = True
+            if bd is None:
+                tok[req.slot] = req.tokens[-1]
+                pos[req.slot] = len(req.tokens) - 1
+                continue
+            pos[req.slot] = req.cache_depth
+            if req.block is not None:
+                tok[req.slot] = req.block
+            else:
+                known = req.tokens[req.cache_depth:]
+                tok[req.slot, :len(known)] = known
+        return tok, pos, act
+
+    def _commit_decode(self, live, out, steps: int, max_seq: int):
+        """Give ``live`` what their decode block yielded: ``steps`` tokens
+        a row (``out`` [R, steps]), or each row's count
+        (inference_manager.BlockPasses: whole blocks, the first of which
+        begins with the tokens the row already had pending), up to where
+        the request is done; stamp the first token; move the cache depth."""
+        counted = isinstance(out, BlockPasses)
+        for req in live:
+            if counted:
+                n = int(out.count[req.slot])
+                new = out.tokens[req.slot,
+                                 min(n, len(req.tokens) - req.cache_depth):n]
+                req.cache_depth += n
+                req.block = out.block[req.slot]
+            else:
+                new = out[req.slot, :steps]
+            for t in new:
+                req.tokens.append(int(t))
+                if self._finish_if_done(req, max_seq):
+                    break
+            self._note_first_token(req)
+            if not counted:
+                req.cache_depth = len(req.tokens) - 1
 
     # =====================================================================
     # Incremental decoding (reference generate_incr_decoding :1810)
@@ -743,6 +833,11 @@ class RequestManager:
         ifm = self._manager_of(model)
         cfg = model.config
         self._resolve_prefix_cache(generation_config)
+        if self.prefix_cache is not None:
+            refuse_block_diffusion(
+                model, "the shared-prefix pool (it shares positions, not "
+                "whole blocks)")
+        held_back = self._held_back(model)
         self.scheduler_loop = "python"
         R = cfg.max_requests_per_batch
         max_seq = cfg.max_sequence_length
@@ -758,7 +853,7 @@ class RequestManager:
         def caught_up():
             return [req for req in active
                     if req is not None and not req.finished
-                    and req.cache_depth == len(req.tokens) - 1]
+                    and req.cache_depth == len(req.tokens) - held_back(req)]
 
         def block_steps(live, prefilled: bool) -> int:
             """The decode block's steps for ``live``. Dynamic trip count:
@@ -766,17 +861,12 @@ class RequestManager:
             regardless of size (engine.py). The verify-consistent wide
             decode (decode_width > 1) appends only the real token's KV
             (kv_append_q), so no staging window needs reserving near the
-            cache end."""
-            block = min(
-                max(self._remaining_budget(req, max_seq) for req in live),
-                cfg.decode_block_steps)
-            if prefilled:
-                # prefill still pending: keep the decode block short
-                # so the next chunk isn't starved behind it
-                block = min(block, chunk)
-            # never scan past the KV cache end
-            return max(1, min(block, max_seq - max(len(req.tokens)
-                                                   for req in live)))
+            cache end. With prefill still pending the block is kept
+            short, so that the next chunk isn't starved behind it."""
+            return self._block_steps(
+                ifm, live, max_seq,
+                min(cfg.decode_block_steps, chunk) if prefilled
+                else cfg.decode_block_steps)
 
         while self.pending or any(a is not None for a in active):
             tel = self._tel()
@@ -841,21 +931,15 @@ class RequestManager:
                 costs.note_prefill(time.perf_counter() - t0, steps)
             if tel is not None:
                 tel.note_round_prefill(steps)
-            # decode: every caught-up slot feeds its pending token; the
+            # decode: every caught-up slot feeds what it has pending; the
             # token-feedback loop runs fused on device (DECODE_BLOCK steps
             # per call); EOS/length overshoot is reconciled host-side.
-            # Mid-prefill slots (cache_depth short of the pending token)
+            # Mid-prefill slots (cache_depth short of the pending tokens)
             # sit this block out.
             live = caught_up()
             if live:
                 block = block_steps(live, steps > 0)
-                tok = np.zeros((R,), np.int32)
-                pos = np.zeros((R,), np.int32)
-                act = np.zeros((R,), bool)
-                for req in live:
-                    tok[req.slot] = req.tokens[-1]
-                    pos[req.slot] = len(req.tokens) - 1
-                    act[req.slot] = True
+                tok, pos, act = self._stage_decode(ifm, live, R)
                 self._tel_tick(tel, live, R, max_seq)
                 kinds = getattr(model, "attention_kinds", None)
                 if tel is not None and kinds:   # rings beside full; latent
@@ -872,14 +956,10 @@ class RequestManager:
                     rnd.phase("sched_commit", live)
                     tel.record_decode_block(dt, block, len(live),
                                             [r.guid for r in live], t0,
-                                            width=ifm.decode_width)
-                for req in live:
-                    for j in range(block):
-                        req.tokens.append(int(toks[req.slot, j]))
-                        if self._finish_if_done(req, max_seq):
-                            break
-                    self._note_first_token(req)
-                    req.cache_depth = len(req.tokens) - 1
+                                            width=ifm.decode_width,
+                                            passes=toks if isinstance(
+                                                toks, BlockPasses) else None)
+                self._commit_decode(live, toks, block, max_seq)
             for slot in range(R):
                 req = active[slot]
                 if req is not None and req.finished:
@@ -953,16 +1033,9 @@ class RequestManager:
         request probes back into drafting."""
         if rnd is not None:
             rnd.phase("sched_build")
-        block = min(max(self._remaining_budget(r, max_seq) for r in reqs),
-                    cfg.decode_block_steps)
-        R_tok = np.zeros((R,), np.int32)
-        pos = np.zeros((R,), np.int32)
-        act = np.zeros((R,), bool)
-        for req in reqs:
-            R_tok[req.slot] = req.tokens[-1]
-            pos[req.slot] = len(req.tokens) - 1
-            act[req.slot] = True
-        block = max(1, min(block, max_seq - 1 - int(pos[act].max())))
+        block = self._block_steps(llm_ifm, reqs, max_seq,
+                                  cfg.decode_block_steps)
+        R_tok, pos, act = self._stage_decode(llm_ifm, reqs, R)
         self._tel_tick(tel, reqs, R, max_seq)
         if rnd is not None:
             rnd.phase(None)
@@ -974,13 +1047,7 @@ class RequestManager:
             tel.record_decode_block(dt, block, len(reqs),
                                     [r.guid for r in reqs], t0,
                                     width=llm_ifm.decode_width)
-        for req in reqs:
-            for j in range(block):
-                req.tokens.append(int(toks[req.slot, j]))
-                if self._finish_if_done(req, max_seq):
-                    break
-            self._note_first_token(req)
-            req.cache_depth = len(req.tokens) - 1
+        self._commit_decode(reqs, toks, block, max_seq)
         return block
 
     # =====================================================================
@@ -1049,6 +1116,9 @@ class RequestManager:
     def _spec_route(llm, ssms, beam_width):
         """``(loop, beam width)``: which of the three speculation loops
         serves this verifier with these drafts."""
+        for m in (llm, *ssms):
+            refuse_block_diffusion(m, "speculation (drafting, tree "
+                                   "verification and its commit)")
         widths = [s.config.max_beam_width for s in ssms]
         W = beam_width or max(widths)
         if any(w != W for w in widths):

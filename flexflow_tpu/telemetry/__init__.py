@@ -32,7 +32,10 @@ ffsv_prefill_attended_pairs_total counter   (query, key) pairs prefill attended
 ffsv_round_prefill_steps         histogram  prefill steps a round dispatched
 ffsv_round_prefill_allowance     histogram  prefill steps a round was allowed
 ffsv_spec_rounds_total           counter    speculation rounds executed
-ffsv_decode_steps_total          counter    incremental decode steps
+ffsv_decode_steps_total          counter    row-steps of decode blocks
+ffsv_diffusion_row_passes_total  counter    passes block-diffusion rows ran
+ffsv_diffusion_commit_passes_total counter  those that committed a block
+ffsv_diffusion_tokens_total      counter    {by} positions denoise passes unmasked
 ffsv_acceptance_length           histogram  accepted draft tokens per round
 ffsv_tokens_per_round            histogram  committed tokens per round (+bonus)
 ffsv_batch_occupancy             histogram  live slots / max slots per tick
@@ -65,6 +68,13 @@ ffsv_moe_tokens_total            counter    {phase} real tokens the experts saw
 ffsv_moe_experts_touched         summary    {phase} distinct experts a call read
 ffsv_moe_expert_pairs_total      counter    {expert} routed pairs of one expert
 ===============================  =========  =================================
+
+A decode block's step is one token a row, or one pass over a row's block
+where the model fills blocks by diffusion (``FFModel.block_diffusion``;
+no other model has the ``ffsv_diffusion_*`` series): a row-pass either
+denoises, unmasking positions ``by`` ``threshold`` (every pick more
+probable than it) or by ``floor`` (the schedule's most confident, where
+fewer cleared it), or commits a whole block, which is when tokens come out.
 
 ``kind`` is ``window``, ``full`` or ``latent``: a model with windowed
 attention layers beside full ones keeps a ring a windowed layer and every
@@ -347,7 +357,21 @@ class ServingTelemetry:
         self.spec_rounds = r.counter(
             "ffsv_spec_rounds_total", "speculation rounds executed")
         self.decode_steps = r.counter(
-            "ffsv_decode_steps_total", "incremental decode steps")
+            "ffsv_decode_steps_total",
+            "row-steps of incremental decode blocks (a step: one token a "
+            "row, or one pass over a block-diffusion row's block)")
+        self.diffusion_row_passes = r.counter(
+            "ffsv_diffusion_row_passes_total",
+            "passes the rows of a block-diffusion model's decode blocks "
+            "ran (denoise and commit)")
+        self.diffusion_commit_passes = r.counter(
+            "ffsv_diffusion_commit_passes_total",
+            "row-passes that committed a whole block to the cache")
+        self.diffusion_tokens = {
+            by: r.counter(f'ffsv_diffusion_tokens_total{{by="{by}"}}',
+                          "positions denoise passes unmasked: above the "
+                          "confidence threshold, or the schedule's floor")
+            for by in ("threshold", "floor")}
         self.acceptance_length = r.histogram(
             "ffsv_acceptance_length",
             "accepted draft tokens per speculation round",
@@ -659,20 +683,32 @@ class ServingTelemetry:
 
     def record_decode_block(self, seconds: float, steps: int, n_live: int,
                             guids=(), t0: Optional[float] = None,
-                            width: int = 1):
+                            width: int = 1, passes=None):
         """``t0``: as in ``record_prefill``; a block launched behind a
         prefill step starts where that step's wait returned, so no prefill
         time falls inside a ``decode_block`` span. Every request's copy of
         the span carries the block's live rows, ``n_live``, and the tokens
         a row each step computed, ``width`` (InferenceManager.decode_width:
-        one, or the verify width of the engine that verifies the model)."""
+        one, the verify width of the engine that verifies the model, or a
+        block-diffusion model's block). ``passes`` (such a model's
+        inference_manager.BlockPasses; None: a token a row a step) feeds
+        the ``ffsv_diffusion_*`` counters and gives the span ``committed``
+        (tokens the call emitted) and ``commits`` (its commit passes)."""
         t0, seconds = self._own_time(seconds, t0)
         self.decode_block_seconds.observe(seconds)
         self.decode_steps.inc(steps * n_live)
         self.decode_width.set(width)
+        extra = {}
+        if passes is not None:
+            ran = {k: int(v.sum()) for k, v in passes.stats.items()}
+            self.diffusion_row_passes.inc(ran["passes"])
+            self.diffusion_commit_passes.inc(ran["commits"])
+            self.diffusion_tokens["threshold"].inc(ran["by_threshold"])
+            self.diffusion_tokens["floor"].inc(ran["by_floor"])
+            extra = {"committed": ran["count"], "commits": ran["commits"]}
         for g in guids:
             self.tracer.decode_block(g, steps, t0, seconds, int(n_live),
-                                     int(width))
+                                     int(width), **extra)
         self.flight.record("decode_block", seconds=round(seconds, 6),
                            steps=int(steps), n_live=int(n_live))
 

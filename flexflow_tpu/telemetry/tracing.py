@@ -215,12 +215,14 @@ class SpanTracer:
                   start_pos=start_pos, n_tokens=n_tokens)
 
     def decode_block(self, guid: int, steps: int, ts_s: float,
-                     dur_s: float, rows: int, width: int = 1):
+                     dur_s: float, rows: int, width: int = 1, **extra):
         """``rows``: the block's live rows; ``width``: the tokens a row
-        each step computed (one of them real). The same on every request's
-        copy of the span."""
+        each step computed (one of them real; all of them where the model
+        fills blocks by diffusion, whose blocks also carry ``committed``
+        and ``commits``). The same on every request's copy of the span."""
         self.emit("decode_block", "X", guid, ts_s=ts_s, dur_s=dur_s,
-                  request_guid=guid, steps=steps, rows=rows, width=width)
+                  request_guid=guid, steps=steps, rows=rows, width=width,
+                  **extra)
 
     def decode_round(self, guid: int, round_idx: int, n_accepted: int,
                      committed: int, block_t0: float, block_dur: float,

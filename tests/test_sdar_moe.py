@@ -1,0 +1,353 @@
+"""SDAR-MoE on the serving path, at tiny sizes on the CPU in float32: a
+decode step that fills a block of four positions by diffusion, sees the block
+both ways and commits it to the cache only when it is whole.
+
+The system's forward (whole blocks through the compact prefill, then passes
+of the decode block through the cache) against the plain reference's full
+forward, with two deliberately wrong variants that must fail; served tokens
+through ``generate_incr_decoding`` against the reference's published loop,
+rows out of phase, at thresholds that unmask 1, 2 and 4 positions a pass;
+the renormalised expert weights; what refuses such a model; and the
+one-token decode block of a dense model, which is the parent's program.
+"""
+
+import dataclasses
+import hashlib
+import json
+import os
+import sys
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import flexflow_tpu as ff
+from flexflow_tpu.ffconst import InferenceMode
+from flexflow_tpu.models.sdar_moe import SDARMoEConfig, create_sdar_moe_model
+from flexflow_tpu.serve.batch_config import BlockDiffusion, GenerationConfig
+from flexflow_tpu.serve.request_manager import RequestManager
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+TINY = dict(vocab_size=256, hidden_size=128, moe_intermediate_size=64,
+            num_hidden_layers=2, num_attention_heads=4,
+            num_key_value_heads=2, head_dim=32, num_experts=8,
+            num_experts_per_tok=2, mask_token_id=255)
+# (confidence threshold, denoising steps) -> positions a denoise pass
+# unmasks: the floor of 1 (no pick of these seeded weights clears 0.9), the
+# floor of 2, everything at once (every pick clears 0)
+SCHEDULES = {1: (0.9, 4), 2: (0.9, 2), 4: (0.0, 4)}
+
+
+@pytest.fixture(scope="module")
+def bench():
+    """The benchmark's family and reference for SDAR-MoE, loaded as run.py
+    loads them."""
+    sys.path.insert(0, ROOT)
+    try:
+        from benchmark.run import load_module
+
+        yield (load_module("families", "sdar_moe"),
+               load_module("reference", "sdar_moe"))
+    finally:
+        sys.path.remove(ROOT)
+
+
+def _build(threshold=0.9, steps=4, mode=InferenceMode.INC_DECODING_MODE,
+           **ffkw):
+    kw = dict(max_requests_per_batch=4, max_sequence_length=128,
+              max_tokens_per_batch=32, seed=3, compute_dtype="float32",
+              kv_cache_dtype="float32", num_devices=1, decode_block_steps=5)
+    kw.update(ffkw)
+    m = ff.FFModel(ff.FFConfig(**kw))
+    c = SDARMoEConfig(**TINY, confidence_threshold=threshold,
+                      denoising_steps=steps)
+    create_sdar_moe_model(m, c, mode=mode, data_type=ff.DataType.DT_FLOAT)
+    m.compile(comp_mode=ff.CompMode.COMP_MODE_INFERENCE)
+    return m, c
+
+
+def _reference_cfg(c):
+    return dict(TINY, rms_norm_eps=c.rms_norm_eps, rope_theta=c.rope_theta,
+                assumed=dict(block_length=c.block_length,
+                             denoising_steps=c.denoising_steps,
+                             confidence_threshold=c.confidence_threshold,
+                             mask_token_id=c.mask_token_id))
+
+
+@pytest.fixture(scope="module")
+def served(bench):
+    """One compiled model a schedule, with the reference's weights."""
+    fam, _ = bench
+    out = {}
+    for per_pass, (thr, steps) in SCHEDULES.items():
+        m, c = _build(thr, steps)
+        out[per_pass] = (m, c, fam.reference_weights(m, c.num_hidden_layers))
+    return out
+
+
+# ---------------------------------------------------------------------------
+# (a) logits: prefill of whole blocks, a denoise pass, the pass after a commit
+# ---------------------------------------------------------------------------
+
+def _two_blocks(bench, served, known, **kw):
+    fam, ref = bench
+    m, c, w = served[1]
+    m.op_state = jax.tree_util.tree_map(jnp.zeros_like, m.op_state)
+    toks = np.random.default_rng(known).integers(1, 255, size=24 + known)
+    return fam.compare_two_blocks(m, _reference_cfg(c), ref, w,
+                                  toks.tolist(), known, 1e-4, 0.0, **kw)
+
+
+@pytest.mark.parametrize("known", [0, 1, 3])
+def test_forward_matches_reference(bench, served, known):
+    """Prompts of length 4k, 4k + 1 and 4k + 3: three chunks of whole
+    blocks through the compact prefill, the remainder and masks through a
+    denoise pass, then the next block's first pass, which reads what the
+    commit pass stored."""
+    out = _two_blocks(bench, served, known)
+    assert out["ok"] and out["route_flips"] == 0, out
+    assert out["positions"] == 24 + 4 + 4
+    assert max(out["denoise_rel_l2"], out["after_commit_rel_l2"]) < 1e-4
+
+
+@pytest.mark.parametrize("wrong", ["one_way", "stale_commit"])
+def test_wrong_variants_fail(bench, served, wrong):
+    """A mask that is causal inside the block (here: the reference computes
+    it, so the program's two-way block is what differs), and a cache
+    committed from a denoise pass's tokens, are each far outside the check."""
+    kw = ({"one_way": True} if wrong == "one_way"
+          else {"wrong": "stale_commit"})
+    out = _two_blocks(bench, served, 1, **kw)
+    assert not out["ok"] and out["max_rel_l2"] > 0.1, out
+    if wrong == "stale_commit":         # only the pass that reads the cache
+        assert out["denoise_rel_l2"] < 1e-4 < out["after_commit_rel_l2"]
+
+
+# ---------------------------------------------------------------------------
+# (b) served tokens, rows out of phase
+# ---------------------------------------------------------------------------
+
+PROMPTS = (8, 9, 11, 3, 10, 5)          # six requests over four slots
+NEW = (7, 8, 9, 5, 6, 10)               # most of them no multiple of 4
+
+
+def _serve(m, prompts, news, eos=None):
+    rm = RequestManager(eos_token_id=eos)
+    guids = [rm.register_new_request(p, max_new_tokens=n)
+             for p, n in zip(prompts, news)]
+    rm.generate_incr_decoding(m)
+    return [list(rm.results[g].output_tokens) for g in guids]
+
+
+@pytest.mark.parametrize("per_pass", sorted(SCHEDULES))
+def test_served_tokens_match_reference_generate(bench, served, per_pass):
+    """Ragged prompts (one shorter than a block), rows that enter at
+    different passes (the two last wait for a slot), answers cut inside a
+    block: token for token the published loop's."""
+    _, ref = bench
+    m, c, w = served[per_pass]
+    rng = np.random.default_rng(0)
+    prompts = [rng.integers(1, 255, size=n).tolist() for n in PROMPTS]
+    got = _serve(m, prompts, NEW)
+    took = []
+    for p, n, g in zip(prompts, NEW, got):
+        trace = []
+        assert g == ref.generate(w, p, n, _reference_cfg(c), trace=trace)
+        assert len(g) == n
+        took += [t[1] for t in trace if t[0] == "denoise"]
+    # the schedule is the one the case names: most passes unmask that many
+    assert np.bincount(took).argmax() == per_pass, took
+
+
+def test_end_of_sequence_inside_a_block(bench, served):
+    """The output ends with the first end-of-sequence token of a committed
+    block, though the block was generated whole."""
+    _, ref = bench
+    m, c, w = served[1]
+    rng = np.random.default_rng(0)
+    prompts = [rng.integers(1, 255, size=n).tolist() for n in PROMPTS]
+    free = [ref.generate(w, p, 9, _reference_cfg(c)) for p in prompts]
+    # an id some answer first shows inside a block, not at its end
+    eos = next(o[i] for o, p in zip(free, prompts) for i in range(len(o))
+               if o.index(o[i]) == i and (len(p) + i) % 4 not in (3,))
+    got = _serve(m, prompts, [9] * len(prompts), eos=eos)
+    want = [ref.generate(w, p, 9, _reference_cfg(c), eos=eos)
+            for p in prompts]
+    assert got == want
+    assert any(len(g) < 9 and g[-1] == eos for g in got), (eos, got)
+
+
+# ---------------------------------------------------------------------------
+# (c) the expert weights; the family's config
+# ---------------------------------------------------------------------------
+
+def test_expert_weights_are_renormalised_top_k(bench, served):
+    fam, ref = bench
+    m, c, w = served[1]
+    m.op_state = jax.tree_util.tree_map(jnp.zeros_like, m.op_state)
+    toks = np.random.default_rng(5).integers(1, 255, size=8)
+    by_name = {ly.name: ly for ly in m.layers}
+    run = fam.Passes(m)
+    _, routes = run.prefill(toks.tolist())
+    _, probs = ref.forward_routed(w, jnp.asarray(toks), _reference_cfg(c))
+    for i, (chosen, p) in enumerate(zip(routes, probs)):
+        p = np.asarray(p)
+        assert (np.sort(chosen, -1)
+                == np.sort(np.argsort(-p, -1)[:, :2], -1)).all()
+    # the graph's weights sum to one over the chosen: read them at a step
+    from flexflow_tpu.serve.engine import forward_with_meta
+    from flexflow_tpu.serve.request_manager import RequestManager as RM
+
+    weights_t = [by_name[f"layers.{i}.mlp.weights"].outputs[0]
+                 for i in range(2)]
+    meta = RM._meta_from_segments(4, 8, [(0, toks.tolist(), 0)])
+    vals, _ = jax.jit(lambda p, st: forward_with_meta(
+        m, p, st, meta, None, jnp.float32, outputs=weights_t))(
+            m.params, m.op_state)
+    for v, p in zip(vals, probs):
+        top = np.sort(np.asarray(p), -1)[:, -2:][:, ::-1]
+        np.testing.assert_allclose(np.asarray(v)[0], top / top.sum(-1,
+                                   keepdims=True), rtol=1e-5)
+
+
+def test_config_reads_the_catalog_keys_and_refuses_the_rest():
+    with open(os.path.join(ROOT, "benchmark/configs/sdar-30b-a3b.json")) as f:
+        hf = json.load(f)
+    c = SDARMoEConfig.from_hf_config(hf)
+    assert (c.num_hidden_layers, c.num_experts, c.moe_intermediate_size,
+            c.num_attention_heads, c.num_key_value_heads, c.head_dim,
+            c.vocab_size) == (12, 128, 768, 32, 4, 128, 151936)
+    assert c.diffusion == BlockDiffusion(4, 4, 0.9, 151669)
+    assert c.diffusion.floor == 1 and c.diffusion.passes_for(5) == 10
+    for bad in ({"sliding_window": 4096}, {"use_sliding_window": True},
+                {"mlp_only_layers": [0]}, {"decoder_sparse_step": 2},
+                {"rope_scaling": {"type": "yarn"}},
+                {"norm_topk_prob": False}):
+        with pytest.raises(NotImplementedError, match="sdar_moe with"):
+            SDARMoEConfig.from_hf_config({**hf, **bad})
+    with pytest.raises(NotImplementedError, match="denoising steps"):
+        BlockDiffusion(4, 3, 0.9, 0)
+
+
+def test_olmoe_still_refuses_norm_topk_prob():
+    from flexflow_tpu.models.olmoe import OLMoEConfig
+
+    with pytest.raises(NotImplementedError, match="sdar_moe"):
+        OLMoEConfig.from_hf_config({"norm_topk_prob": True})
+
+
+def test_family_is_registered_with_its_weight_map():
+    from flexflow_tpu.models import FAMILIES, family_for_hf_config
+
+    fam = family_for_hf_config({"model_type": "sdar_moe"})
+    assert fam is FAMILIES["sdar_moe"] and fam.config_cls is SDARMoEConfig
+    c = SDARMoEConfig(**TINY)
+    keys = fam.hf_weight_map(c)
+    assert keys["model.layers.1.mlp.experts.down_proj.weight"] == (
+        "layers.1.mlp.experts", "down", False)
+    assert keys["model.layers.0.self_attn.k_norm.weight"][1] == "k_norm"
+    # stacked, and unstacked again
+    rng = np.random.default_rng(0)
+    sd = {f"model.layers.0.mlp.experts.{e}.{p}.weight":
+          rng.normal(size=(3, 5)).astype(np.float32)
+          for e in range(8) for p in ("gate_proj", "up_proj", "down_proj")}
+    one = dataclasses.replace(c, num_hidden_layers=1)
+    before = {k: v.copy() for k, v in sd.items()}
+    sd["model.embed_tokens.weight"] = np.zeros((2, 2), np.float32)
+    fam.preprocess(sd, one)
+    assert sd["model.layers.0.mlp.experts.up_proj.weight"].shape == (8, 5, 3)
+    from flexflow_tpu.models.sdar_moe import unstack_hf_experts
+
+    unstack_hf_experts(sd, one)
+    assert all((sd[k] == v).all() for k, v in before.items())
+
+
+# ---------------------------------------------------------------------------
+# (d) what refuses a block-diffusion model
+# ---------------------------------------------------------------------------
+
+def test_compiled_model_carries_its_block(served):
+    m, c, _ = served[2]
+    assert m.block_diffusion == BlockDiffusion(4, 2, 0.9, 255)
+    assert RequestManager._manager_of(m).decode_width == 4
+    assert all(ly.attrs.get("block_length") == 4 for ly in m.layers
+               if "self_attn" in ly.name)
+
+
+@pytest.mark.parametrize("what", ["tree_verify", "beam", "speculation",
+                                  "prefix_pool", "mesh", "debug", "chunk",
+                                  "decode_width"])
+def test_refusals_name_the_reason(served, what):
+    m, c, _ = served[1]
+    if what in ("tree_verify", "beam"):
+        mode = (InferenceMode.TREE_VERIFY_MODE if what == "tree_verify"
+                else InferenceMode.BEAM_SEARCH_MODE)
+        with pytest.raises(NotImplementedError,
+                           match="incremental decoding only"):
+            _build(mode=mode)
+    elif what == "speculation":
+        for llm, ssms in ((m, [m]),):
+            with pytest.raises(NotImplementedError,
+                               match="speculation .* block-diffusion"):
+                RequestManager().generate_spec_infer(llm, ssms)
+    elif what == "prefix_pool":
+        rm = RequestManager()
+        rm.register_new_request([1, 2, 3, 4, 5], max_new_tokens=4)
+        with pytest.raises(NotImplementedError, match="shared-prefix pool"):
+            rm.generate_incr_decoding(m, GenerationConfig(prefix_cache=True))
+    elif what == "mesh":
+        with pytest.raises(NotImplementedError,
+                           match="a mesh that divides a model"):
+            _build(num_devices=2, tensor_parallelism_degree=2)
+    elif what == "debug":
+        with pytest.raises(NotImplementedError, match="inference_debugging"):
+            _build(inference_debugging=True)
+    elif what == "chunk":
+        with pytest.raises(NotImplementedError, match="whole blocks of 4"):
+            _build(max_tokens_per_batch=24)     # 24 / 4 rows: a chunk of 6
+    else:
+        with pytest.raises(NotImplementedError, match="decode_width 8"):
+            _build(decode_width=8)
+
+
+# ---------------------------------------------------------------------------
+# (e) a dense model's decode block is the parent's program
+# ---------------------------------------------------------------------------
+
+# sha256 of the StableHLO text of a tiny LLaMA's decode block at width 1 and
+# at a verify width of 8, as the commit before this family traced it (PR
+# 38's method; lowering prints no locations). This PR threads a phase and a
+# block length through the step without a trace of either in a program that
+# has neither. A PR that changes these programs on purpose records its own.
+PARENT_DECODE_BLOCK = {
+    1: "d054135dba46a8f1b56ccbdcbe80bcefddaeb08b4a855a96859588f1810c6e55",
+    8: "903863173ff087d48d5d814b6603ddaaa8280f1595884ab0fe5bc3ca645bfa8c"}
+
+
+def decode_block_hash(width: int) -> str:
+    from flexflow_tpu.models import FAMILIES
+    from flexflow_tpu.models.checkpoint_store import TINY_CONFIGS
+    from flexflow_tpu.serve.engine import make_decode_block
+
+    fam = FAMILIES["llama"]
+    m = ff.FFModel(ff.FFConfig(max_requests_per_batch=2,
+                               max_sequence_length=64,
+                               max_tokens_per_batch=16, seed=0,
+                               kv_cache_dtype="float32", num_devices=1))
+    fam.build(m, fam.config_cls(**TINY_CONFIGS["llama"]),
+              mode=InferenceMode.INC_DECODING_MODE)
+    m.compile(comp_mode=ff.CompMode.COMP_MODE_INFERENCE)
+    block = make_decode_block(m, jnp.dtype(m.config.compute_dtype), 8,
+                              width=width)
+    text = block.lower(m.params, m.op_state, jnp.zeros((2,), jnp.int32),
+                       jnp.zeros((2,), jnp.int32), jnp.ones((2,), bool),
+                       jax.random.PRNGKey(0), jnp.int32(2)).as_text()
+    return hashlib.sha256(text.encode()).hexdigest()
+
+
+@pytest.mark.parametrize("width", [1, 8])
+def test_dense_decode_block_is_the_parents_program(width):
+    assert decode_block_hash(width) == PARENT_DECODE_BLOCK[width]

@@ -594,3 +594,76 @@ def test_lag_readers_on_a_hand_built_trace(metric, capsys):
     assert all(line.startswith("# ") for line in out.splitlines())
     if metric == "prefill_stage_idle_ms":
         assert "first step x2 2.250, later steps x1 0.000" in out
+
+
+# ---------------------------------------------------------------------------
+# a block-diffusion model's decode blocks: counters and span attributes
+# against a hand-counted run (ISSUE 39)
+# ---------------------------------------------------------------------------
+
+def test_diffusion_counters_and_span_attributes_hand_counted():
+    """One request, prompt 5 (a remainder of 1), 7 new tokens, the floor of
+    one position a denoise pass (no pick of seeded weights clears 0.9),
+    blocks of 8 passes. Call 1: block one (3 denoise + commit), 4 denoise
+    passes of block two. Call 2 (4 tokens to go: at most 5 passes): its
+    commit, then 4 passes into a block the host cuts."""
+    import flexflow_tpu as ff
+    from flexflow_tpu.ffconst import CompMode, InferenceMode
+    from flexflow_tpu.models import FAMILIES
+    from flexflow_tpu.models.checkpoint_store import TINY_CONFIGS
+
+    fam = FAMILIES["sdar_moe"]
+    model = ff.FFModel(ff.FFConfig(
+        max_requests_per_batch=2, max_sequence_length=64,
+        max_tokens_per_batch=16, seed=0, kv_cache_dtype="float32",
+        decode_block_steps=8))
+    fam.build(model, fam.config_cls(**TINY_CONFIGS["sdar_moe"]),
+              mode=InferenceMode.INC_DECODING_MODE)
+    model.compile(comp_mode=CompMode.COMP_MODE_INFERENCE)
+    tel = enable_telemetry()
+    try:
+        rm = RequestManager()
+        rm.register_new_request([5, 9, 23, 44, 7], max_new_tokens=7)
+        (res,) = rm.generate_incr_decoding(model)
+        assert len(res.output_tokens) == 7
+        snap = tel.registry.snapshot()
+        value = lambda name: snap[name]["value"]
+        assert value("ffsv_diffusion_row_passes_total") == 8 + 5
+        assert value("ffsv_diffusion_commit_passes_total") == 2
+        assert value('ffsv_diffusion_tokens_total{by="floor"}') == 3 + 4 + 4
+        assert value('ffsv_diffusion_tokens_total{by="threshold"}') == 0
+        assert value("ffsv_decode_steps_total") == 8 + 5
+        assert value("ffsv_decode_width") == 4
+        blocks = [e["args"] for e in tel.tracer.events
+                  if e["name"] == "decode_block"]
+        assert [(b["steps"], b["rows"], b["width"], b["committed"],
+                 b["commits"]) for b in blocks] == [(8, 1, 4, 4, 1),
+                                                    (5, 1, 4, 4, 1)]
+    finally:
+        disable_telemetry()
+
+
+def test_diffusion_readers_on_hand_made_snapshots():
+    """The three per-layer readers over known counter gains; a program
+    without the series (the parent, any other model) gives None."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, root)
+    try:
+        from benchmark.run import load_module
+
+        read = {n: load_module("layer_metrics", f"diffusion_{n}").read
+                for n in ("tokens_per_pass", "commit_share",
+                          "threshold_share")}
+    finally:
+        sys.path.remove(root)
+    series = {"ffsv_diffusion_row_passes_total": (100.0, 600.0),
+              "ffsv_diffusion_commit_passes_total": (20.0, 130.0),
+              'ffsv_diffusion_tokens_total{by="threshold"}': (0.0, 90.0),
+              'ffsv_diffusion_tokens_total{by="floor"}': (80.0, 350.0)}
+    ctx = {"tel": {"before": {k: {"value": a} for k, (a, _) in series.items()},
+                   "after": {k: {"value": b} for k, (_, b) in series.items()}}}
+    assert read["tokens_per_pass"](ctx) == pytest.approx(360 / 500)
+    assert read["commit_share"](ctx) == pytest.approx(22.0)
+    assert read["threshold_share"](ctx) == pytest.approx(25.0)
+    for bare in ({"tel": None}, {"tel": {"before": {}, "after": {}}}):
+        assert all(r(bare) is None for r in read.values())
