@@ -836,19 +836,25 @@ class FFModel:
             values[t.tensor_id] = v
 
     def _run_graph(self, params, feeds: Dict[int, Any], ctx: OpContext,
-                   state: Optional[Dict[str, Any]] = None):
+                   state: Optional[Dict[str, Any]] = None, narrow=None):
         """Walk the layer list (creation order == topo order) computing every
-        tensor value. Returns (values_by_tensor_id, new_state)."""
+        tensor value. Returns (values_by_tensor_id, new_state). ``narrow``
+        (a layer, a function): that layer, and so whatever follows it, is
+        given the function of its inputs' values."""
         if (not ctx.training and self._pp_plan is not None
                 and "__pp_blocks__" in params):
             from flexflow_tpu.serve.pipeline_plan import run_pp_graph
 
+            assert narrow is None, "a pipeline stage runs whole layers"
             return run_pp_graph(self, params, feeds, ctx, state)
         values: Dict[int, Any] = dict(feeds)
         ctx.state_in = state or {}
         ctx.state_out = {}
         plan = getattr(self, "_branch_plan", None)
         for layer in self.layers:
+            if narrow is not None and layer is narrow[0]:
+                for t in layer.inputs:
+                    values[t.tensor_id] = narrow[1](values[t.tensor_id])
             if plan is not None:
                 if layer.name in plan.skip:
                     continue            # executed inside its branch region

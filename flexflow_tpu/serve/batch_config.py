@@ -45,11 +45,12 @@ class BlockDiffusion:
     as ``FFModel.block_diffusion``; never a serving option). A row's next
     ``block_length`` positions start as mask tokens after what is known of
     them; a denoise pass runs the block against the cache and the block
-    itself, stores nothing, and unmasks every masked position whose pick
-    is more probable than ``threshold``, or the ``floor`` most confident
-    where fewer clear it; a pass that begins with no mask left commits the
-    block: its keys and values are what the cache keeps, and the row's
-    length grows by the block."""
+    itself, stores nothing that lasts, and unmasks every masked position
+    whose pick is more probable than ``threshold``, or the ``floor`` most
+    confident where fewer clear it; the pass that leaves no mask emits the
+    block, and the row's next pass carries it in front of the next block:
+    its keys and values are what the cache keeps, and the row's stored
+    length grows by the block (serve/engine._diffusion_block)."""
 
     block_length: int
     denoising_steps: int
@@ -70,13 +71,13 @@ class BlockDiffusion:
 
     def passes_for(self, tokens: int) -> int:
         """The most passes ``tokens`` more tokens of a row can take: every
-        block at the floor, and its commit."""
-        return -(-tokens // self.block_length) * (self.denoising_steps + 1)
+        block at the floor (the pass that completes a block emits it)."""
+        return -(-tokens // self.block_length) * self.denoising_steps
 
     def emitted_most(self, passes: int) -> int:
-        """The most tokens a row can emit in ``passes``: a commit every
-        other pass."""
-        return self.block_length * ((passes + 1) // 2)
+        """The most tokens a row can emit in ``passes``: a block a pass,
+        where every pick clears the threshold."""
+        return self.block_length * passes
 
 
 @dataclasses.dataclass

@@ -33,6 +33,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from flexflow_tpu.ffconst import OpType
 from flexflow_tpu.ops import kv_layout as kvl
 from flexflow_tpu.ops.base import OpContext
 from flexflow_tpu.ops.inc_attention import move_kv, refuse_windowed
@@ -97,7 +98,7 @@ def build_feeds(model, meta):
 
 def forward_with_meta(model, params, state, meta, rng, compute_dtype,
                       kv_contiguous=False, kv_append_q=None, phase=None,
-                      outputs=None):
+                      outputs=None, narrow=None):
     """One serving forward over a BatchMeta inside jit — the single traced
     body shared by InferenceManager.step and the fused engines.
 
@@ -109,14 +110,17 @@ def forward_with_meta(model, params, state, meta, rng, compute_dtype,
     the KV append can skip the padding columns entirely. ``phase``: the
     program that runs the step says what the step is where its width does
     not ("decode": a block-diffusion pass; ops/moe._step_tokens). ``outputs``:
-    the tensors to hand back, as a tuple, in place of the final one."""
+    the tensors to hand back, as a tuple, in place of the final one.
+    ``narrow``: a (layer, fn) whose layer is given ``fn`` of its inputs (a
+    pass that is two blocks wide hands the model's tail, the head with it,
+    the one block a row reads: ``_diffusion_block``)."""
     ctx = OpContext(training=False, rng=rng, compute_dtype=compute_dtype,
                     batch_config=meta, mesh=model.mesh, config=model.config)
     ctx.kv_contiguous = kv_contiguous
     ctx.kv_append_q = kv_append_q
     ctx.step_phase = phase
     values, new_state = model._run_graph(params, build_feeds(model, meta),
-                                         ctx, state)
+                                         ctx, state, narrow=narrow)
     if outputs is not None:
         return tuple(values[t.tensor_id] for t in outputs), new_state
     return values[model._final_tensor.tensor_id], new_state
@@ -262,57 +266,112 @@ def make_decode_block(model, compute_dtype, max_steps: int, width: int = 1):
 
 # A diffusion block's read-back, one int32 row a slot: the tokens the row
 # emitted (``BlockDiffusion.emitted_most`` columns, the first ``count``
-# real), its carried block (-1: still masked), then PASS_STATS.
-PASS_STATS = ("count", "passes", "commits", "by_threshold", "by_floor")
+# real), its window (2B columns, -1: still masked), then PASS_STATS.
+PASS_STATS = ("count", "passes", "folded", "by_threshold", "by_floor")
+
+# What a model's tail is made of: the layers that end the graph and work on
+# each position alone (final norm, head), below the pick.
+_POSITION_WISE = (OpType.LINEAR, OpType.RMS_NORM)
+
+
+def _tail_of(model):
+    """The first layer of ``model``'s tail: the chain of position-wise
+    layers that ends in the graph's last (the final norm, the head, the
+    pick). What it is given is all that those layers compute."""
+    layer = model.layers[-1]
+    while True:
+        prev = layer.inputs[0].owner_layer
+        if prev is None or prev.op_type not in _POSITION_WISE:
+            return layer
+        layer = prev
+
+
+def diffusion_pass(model, params, state, win, pos, act, carried, rng,
+                   compute_dtype, outputs=None):
+    """One pass of ``_diffusion_block``: the rows' windows ``win`` [R, 2B]
+    at the lengths ``pos`` their caches hold, 2B tokens a row, of which a
+    row that carries no whole block (``carried`` [R]) has its first B real.
+    Returns (``outputs``, new_state); the graph's tail, the head with it,
+    sees the B columns of the block a row fills, so the default outputs,
+    the head's pick and confidence, are [R, B]."""
+    bd = model.block_diffusion
+    B = bd.block_length
+    first = B * carried.astype(jnp.int32)
+    at = (first[:, None] + jnp.arange(B, dtype=jnp.int32))[:, :, None]
+    meta = BatchMeta(
+        tokens=jnp.where(win < 0, bd.mask_token_id, win),
+        positions=pos[:, None] + jnp.arange(2 * B, dtype=jnp.int32),
+        start_pos=pos, num_tokens=jnp.where(act, B + first, 0), active=act)
+    return forward_with_meta(
+        model, params, state, meta, rng, compute_dtype, phase="decode",
+        outputs=model.layers[-1].outputs if outputs is None else outputs,
+        narrow=(_tail_of(model),
+                lambda h: jnp.take_along_axis(h, at, axis=1)))
 
 
 def _diffusion_block(model, compute_dtype, max_steps: int, bd):
-    """The decode block of a block-diffusion model: ``n`` passes of ``B =
-    bd.block_length`` tokens a row, all of them real.
+    """The decode block of a block-diffusion model: ``n`` passes over
+    blocks of ``B = bd.block_length`` positions a row.
 
-    (params, op_state, blk [R, B], pos [R], active [R], rng, n) ->
-    (packed [R, E + B + len(PASS_STATS)], new_op_state, blk). ``pos[r]`` is
-    the row's committed length, a multiple of B, and ``blk[r]`` its block
-    at positions ``[pos, pos + B)``: a token id, or -1 where the position
-    is still masked (what a row carries from one call into the next; a
-    row that begins a block hands over what is known of it, the prompt's
-    remainder, then -1).
+    (params, op_state, win [R, 2B], pos [R], active [R], rng, n) ->
+    (packed [R, E + 2B + len(PASS_STATS)], new_op_state, win). ``pos[r]`` is
+    the length the row's cache holds, a multiple of B, and ``win[r]`` its
+    window, the positions ``[pos, pos + 2B)``: a token id, or -1 where the
+    position is still masked (what a row carries from one call into the
+    next; a row that begins a block hands over what is known of it, the
+    prompt's remainder, then -1). A window whose first block is WHOLE
+    carries that block, emitted and not stored yet, in front of the block
+    the row fills; any other window holds the block it fills and B masks.
 
-    Every pass is ONE forward of ``[R, B]`` at the true positions, masked
-    positions holding the mask token, with all B keys and values written
-    at those positions before any row attends (one row-granular scatter a
-    layer's cache, in place: inc_attention.append_and_ref), so the block
-    sees itself both ways (inc_attention.block_visibility). A row that
-    entered the pass with a mask left takes a DENOISE pass: ``pos`` does
-    not move, so what the pass wrote is overwritten by the next; it unmasks
+    Every pass is ONE forward of ``[R, 2B]`` at the true positions, masked
+    positions holding the mask token, with the keys and values of every
+    real token written at its position before any row attends (one
+    row-granular scatter a layer's cache, in place:
+    inc_attention.append_and_ref). A row fills its block by DENOISE passes:
+    B real tokens at ``[pos, pos + B)`` (``num_tokens``: the rest of the row
+    is routed to no expert, stored nowhere and counted by nothing), which
+    see the cache and the block itself both ways
+    (inc_attention.block_visibility); ``pos`` does not move, so what the
+    pass wrote is overwritten by the next; it unmasks
     every masked position whose pick is more probable than the threshold,
-    or the ``bd.floor`` most confident where fewer clear it. A row that entered it whole takes
-    a COMMIT pass: what was written is what the cache keeps, ``pos`` grows
-    by B, the block is emitted and the next begins, all masked. A row
-    whose next block would pass the cache's end sits out. The host
-    reconciles ``max_new_tokens`` and end-of-sequence; overshoot is
-    bounded by the call, as in the one-token block."""
+    or the ``bd.floor`` most confident where fewer clear it. The pass that
+    leaves the block whole EMITS it, and the row's next pass STORES it while
+    it denoises the next block: 2B real tokens at ``[pos, pos + 2B)``, the
+    whole block seeing itself and the cache, the new one seeing both
+    besides, which is what a pass over the whole block alone followed by
+    the new block's first pass would compute; then ``pos`` grows by B: the
+    first B of what was written are what the cache keeps. The head reads
+    the B columns of the block a row fills, gathered before the model's
+    tail. No pass only stores: a block emitted by a row's last pass stays
+    in its window, for the next call or for nobody (a finished row's cache
+    is read by nothing). A row whose next pass would write past the cache's
+    end sits out. The host reconciles ``max_new_tokens`` and
+    end-of-sequence; overshoot is bounded by the call, as in the one-token
+    block.
+
+    One width, not two: a B-wide pass for the passes in which no row
+    carries a block is 2-3 ms cheaper on the chip, and its second loop body
+    (a second trace and executable of the graph) costs more set-up than the
+    benchmark's bound allows (PERF.md section 6, PR 40)."""
     B, floor = bd.block_length, bd.floor
     E = bd.emitted_most(max_steps)
     S = model.config.max_sequence_length
-    head = model.layers[-1].outputs            # (pick, confidence)
 
-    def block(params, op_state, blk, pos, active, rng, n):
-        R = blk.shape[0]
+    def block(params, op_state, win, pos, active, rng, n):
+        R = win.shape[0]
         cols = jnp.arange(B, dtype=jnp.int32)
+        masks = jnp.full((R, B), -1, jnp.int32)
 
         def body(carry):
-            i, state, blk, pos, out, stats = carry
-            act = active & (pos + B <= S)
+            i, state, win, pos, out, stats = carry
+            whole = ~(win[:, :B] < 0).any(axis=1)
+            act = active & (pos + B * (1 + whole) <= S)
+            carried = act & whole
+            (x0, conf), state = diffusion_pass(
+                model, params, state, win, pos, act, carried,
+                jax.random.fold_in(rng, i), compute_dtype)
+            blk = jnp.where(whole[:, None], win[:, B:], win[:, :B])
             masked = blk < 0
-            meta = BatchMeta(
-                tokens=jnp.where(masked, bd.mask_token_id, blk),
-                positions=pos[:, None] + cols[None, :], start_pos=pos,
-                num_tokens=B * act.astype(jnp.int32), active=act)
-            (x0, conf), state = forward_with_meta(
-                model, params, state, meta, jax.random.fold_in(rng, i),
-                compute_dtype, phase="decode", outputs=head)
-            commit = act & ~masked.any(axis=1)
             # denoise: the picks above the threshold, or the floor's most
             # confident (ties to the earlier position)
             c = jnp.where(masked, conf.astype(jnp.float32), -jnp.inf)
@@ -324,28 +383,34 @@ def _diffusion_block(model, compute_dtype, max_steps: int, bd):
             cleared = high.sum(axis=1) >= floor
             unmask = jnp.where(cleared[:, None], high, low) & act[:, None]
             blk = jnp.where(unmask, x0.astype(jnp.int32), blk)
-            # commit: emit the block at the row's count, begin the next
+            # a block left whole is emitted at the row's count
+            emit = act & ~(blk < 0).any(axis=1)
             count = stats[:, 0]
             at = jnp.arange(E, dtype=jnp.int32)[None, :] - count[:, None]
             out = jnp.where(
-                commit[:, None] & (at >= 0) & (at < B),
+                emit[:, None] & (at >= 0) & (at < B),
                 jnp.take_along_axis(blk, jnp.clip(at, 0, B - 1), axis=1),
                 out)
-            blk = jnp.where(commit[:, None], -1, blk)
-            step = commit.astype(jnp.int32)
+            # the window begins with the block the row fills, past the one
+            # this pass stored (a row that sat out with a whole block keeps
+            # both)
+            win = jnp.where((whole & ~act)[:, None], win,
+                            jnp.concatenate([blk, masks], axis=1))
             took = unmask.sum(axis=1, dtype=jnp.int32)
             stats = stats + jnp.stack(
-                [B * step, act.astype(jnp.int32), step,
+                [B * emit.astype(jnp.int32), act.astype(jnp.int32),
+                 carried.astype(jnp.int32),
                  jnp.where(cleared, took, 0), jnp.where(cleared, 0, took)],
                 axis=1)
-            return i + 1, state, blk, pos + B * step, out, stats
+            return (i + 1, state, win, pos + B * carried.astype(jnp.int32),
+                    out, stats)
 
-        _, op_state, blk, _, out, stats = jax.lax.while_loop(
+        _, op_state, win, _, out, stats = jax.lax.while_loop(
             lambda carry: carry[0] < n, body,
-            (jnp.int32(0), op_state, blk, pos,
+            (jnp.int32(0), op_state, win, pos,
              jnp.zeros((R, E), jnp.int32),
              jnp.zeros((R, len(PASS_STATS)), jnp.int32)))
-        return jnp.concatenate([out, blk, stats], axis=1), op_state, blk
+        return jnp.concatenate([out, win, stats], axis=1), op_state, win
 
     return block
 

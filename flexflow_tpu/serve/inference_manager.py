@@ -25,11 +25,13 @@ from flexflow_tpu.ops.base import OpContext
 
 class BlockPasses(NamedTuple):
     """What a decode block of a block-diffusion model hands back, a row a
-    slot: the tokens the row's commit passes emitted (``tokens[r, :count[r]]``),
-    the block it carries into its next call (-1: still masked), and
+    slot: the tokens of the blocks its passes left whole
+    (``tokens[r, :count[r]]``), the window it carries into its next call
+    (``block`` [R, 2B], the positions from the row's stored length on, -1:
+    still masked; a whole first block is emitted and not stored yet), and
     ``stats``, the row's engine.PASS_STATS (tokens emitted, passes run,
-    commit passes, positions unmasked above the threshold and by the
-    floor)."""
+    blocks a pass stored in front of the next, positions unmasked above the
+    threshold and by the floor)."""
 
     tokens: np.ndarray
     block: np.ndarray
@@ -38,6 +40,15 @@ class BlockPasses(NamedTuple):
     @property
     def count(self) -> np.ndarray:
         return self.stats["count"]
+
+    @property
+    def block_length(self) -> int:
+        return self.block.shape[1] // 2
+
+    @property
+    def stored(self) -> np.ndarray:
+        """Positions a row's passes added to its cache."""
+        return self.stats["folded"] * self.block_length
 
 
 class InferenceManager:
@@ -147,9 +158,9 @@ class InferenceManager:
         dynamic-trip while_loop — one host round-trip AND one compiled
         program for every block size. Returns int32 [R, n_steps]. For a
         block-diffusion model a step is a pass over a block: ``tok`` is
-        ``[R, decode_width]`` (-1: a masked position), ``pos`` the rows'
-        committed lengths, and the return a ``BlockPasses``
-        (engine._diffusion_block).
+        the rows' windows ``[R, 2 * decode_width]`` (-1: a masked
+        position), ``pos`` the lengths their caches hold, and the return a
+        ``BlockPasses`` (engine._diffusion_block).
         ``tel``: as in ``step`` (program ``decode_block``). ``rnd`` (the
         caller's telemetry.RoundTrace; None: it has none) may hold a
         prefill step that was launched before this block and is not waited
@@ -194,9 +205,9 @@ class InferenceManager:
         from flexflow_tpu.serve.engine import PASS_STATS
 
         stats = dict(zip(PASS_STATS, toks[:, -len(PASS_STATS):].T))
-        emitted = toks.shape[1] - width - len(PASS_STATS)
+        emitted = toks.shape[1] - 2 * width - len(PASS_STATS)
         return BlockPasses(toks[:, :emitted],
-                           toks[:, emitted:emitted + width], stats)
+                           toks[:, emitted:emitted + 2 * width], stats)
 
     def _decode_block_debug(self, tok, pos, active, n_steps: int):
         from flexflow_tpu.serve.batch_config import BatchMeta

@@ -21,10 +21,13 @@ Slot/convention notes:
   per-request ``token_start_offset``/depth bookkeeping,
   batch_config.h:66-75); for a block-diffusion model
   (``FFModel.block_diffusion``) the remainder of the last whole block, 0 to
-  B-1 tokens, which begin the block that the row's passes fill
-  (``Request.block``). A decode block yields one token a row a step, or for
-  such a model a count of tokens a row; ``_stage_decode`` and
-  ``_commit_decode`` are the two places that know which.
+  B-1 tokens, which begin the block that the row's passes fill, and once
+  the row decodes whatever its window holds (``Request.block``: a block
+  that is whole is emitted before the pass that stores it, so
+  ``cache_depth`` may be a block behind). A decode block yields one token a
+  row a step, or for such a model a count of tokens a row;
+  ``_stage_decode`` and ``_commit_decode`` are the two places that know
+  which.
 * Single-chain speculation (one SSM, MAX_BEAM_WIDTH=1 — the reference
   default) needs no KV commit at all: accepted drafts are already contiguous
   in the verifier's cache. Multi-SSM token trees use ``commit_tree_kv``.
@@ -115,9 +118,11 @@ class Request:
     prefix_len: int = 0
     prefix_hit_tokens: int = 0
     prefix_checked: bool = False
-    # a block-diffusion row's unfinished block at positions [cache_depth,
-    # cache_depth + B), as its last decode block left it (-1: a position
-    # still masked); None: the row begins the block from its pending tokens
+    # a block-diffusion row's window, the positions [cache_depth,
+    # cache_depth + 2B), as its last decode block left it (-1: a position
+    # still masked; a whole first block is in ``tokens`` already and its
+    # keys and values are stored by the row's next pass); None: the row
+    # begins a block from its pending tokens
     block: Any = None
 
     def __post_init__(self):
@@ -695,11 +700,15 @@ class RequestManager:
         """req -> the tokens a prefill leaves pending, the next decode
         step's input: the last token, which emits the next one; for a
         block-diffusion model what follows the last whole block (prefill
-        stores whole blocks only), 0 to B-1 tokens."""
+        stores whole blocks only), 0 to B-1 tokens, or all that the row's
+        window holds once it has one (a whole block not stored yet is the
+        next pass's to store, not a prefill step's)."""
         bd = getattr(model, "block_diffusion", None)
         if bd is None:
             return lambda req: 1
-        return lambda req: len(req.tokens) % bd.block_length
+        return lambda req: (len(req.tokens) % bd.block_length
+                            if req.block is None
+                            else len(req.tokens) - req.cache_depth)
 
     @staticmethod
     def _prefill_rows(active, chunk: int, depth_of, segments: int,
@@ -777,12 +786,12 @@ class RequestManager:
     def _stage_decode(ifm, live, R: int):
         """(tok, pos, active) of a decode block over ``live``: each row's
         pending token and its position; for a block-diffusion model the
-        row's block ``[R, B]`` (what it carried from its last call, else
-        its pending tokens, then -1 for the masked rest) and its committed
-        length."""
+        row's window ``[R, 2B]`` (what it carried from its last call, else
+        its pending tokens, then -1 for the masked rest) and the length its
+        cache holds."""
         bd = getattr(ifm.model, "block_diffusion", None)
         tok = (np.zeros((R,), np.int32) if bd is None
-               else np.full((R, bd.block_length), -1, np.int32))
+               else np.full((R, 2 * bd.block_length), -1, np.int32))
         pos = np.zeros((R,), np.int32)
         act = np.zeros((R,), bool)
         for req in live:
@@ -804,14 +813,16 @@ class RequestManager:
         a row (``out`` [R, steps]), or each row's count
         (inference_manager.BlockPasses: whole blocks, the first of which
         begins with the tokens the row already had pending), up to where
-        the request is done; stamp the first token; move the cache depth."""
+        the request is done; stamp the first token; move the cache depth
+        (such a row's by what its passes stored, a block behind what they
+        emitted where the last one left a block whole)."""
         counted = isinstance(out, BlockPasses)
         for req in live:
             if counted:
                 n = int(out.count[req.slot])
-                new = out.tokens[req.slot,
-                                 min(n, len(req.tokens) - req.cache_depth):n]
-                req.cache_depth += n
+                known = (len(req.tokens) - req.cache_depth) % out.block_length
+                new = out.tokens[req.slot, min(n, known):n]
+                req.cache_depth += int(out.stored[req.slot])
                 req.block = out.block[req.slot]
             else:
                 new = out[req.slot, :steps]

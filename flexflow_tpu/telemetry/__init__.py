@@ -34,7 +34,8 @@ ffsv_round_prefill_allowance     histogram  prefill steps a round was allowed
 ffsv_spec_rounds_total           counter    speculation rounds executed
 ffsv_decode_steps_total          counter    row-steps of decode blocks
 ffsv_diffusion_row_passes_total  counter    passes block-diffusion rows ran
-ffsv_diffusion_commit_passes_total counter  those that committed a block
+ffsv_diffusion_commit_passes_total counter  those that only stored a block
+ffsv_diffusion_folded_commits_total counter blocks stored by a denoise pass
 ffsv_diffusion_tokens_total      counter    {by} positions denoise passes unmasked
 ffsv_acceptance_length           histogram  accepted draft tokens per round
 ffsv_tokens_per_round            histogram  committed tokens per round (+bonus)
@@ -74,7 +75,12 @@ where the model fills blocks by diffusion (``FFModel.block_diffusion``;
 no other model has the ``ffsv_diffusion_*`` series): a row-pass either
 denoises, unmasking positions ``by`` ``threshold`` (every pick more
 probable than it) or by ``floor`` (the schedule's most confident, where
-fewer cleared it), or commits a whole block, which is when tokens come out.
+fewer cleared it); the pass that leaves a block whole is when its tokens
+come out, and the row's next pass stores the block in front of the block
+it denoises (``ffsv_diffusion_folded_commits_total``). A row-pass that did
+nothing but store a block is a ``commit_passes``; the decode block runs
+none since it folds them (serve/engine._diffusion_block), and the series
+stays at 0 as the measure of what is left of them.
 
 ``kind`` is ``window``, ``full`` or ``latent``: a model with windowed
 attention layers beside full ones keeps a ring a windowed layer and every
@@ -363,10 +369,17 @@ class ServingTelemetry:
         self.diffusion_row_passes = r.counter(
             "ffsv_diffusion_row_passes_total",
             "passes the rows of a block-diffusion model's decode blocks "
-            "ran (denoise and commit)")
-        self.diffusion_commit_passes = r.counter(
+            "ran")
+        # nothing feeds it: the decode block stores every block in the
+        # row's next denoise pass, and a reader of the share that such
+        # passes take (what is left of them) reads 0, not nothing
+        r.counter(
             "ffsv_diffusion_commit_passes_total",
-            "row-passes that committed a whole block to the cache")
+            "row-passes that did nothing but store a whole block")
+        self.diffusion_folded_commits = r.counter(
+            "ffsv_diffusion_folded_commits_total",
+            "whole blocks a row stored in the pass that began to denoise "
+            "its next block")
         self.diffusion_tokens = {
             by: r.counter(f'ffsv_diffusion_tokens_total{{by="{by}"}}',
                           "positions denoise passes unmasked: above the "
@@ -693,7 +706,8 @@ class ServingTelemetry:
         block-diffusion model's block). ``passes`` (such a model's
         inference_manager.BlockPasses; None: a token a row a step) feeds
         the ``ffsv_diffusion_*`` counters and gives the span ``committed``
-        (tokens the call emitted) and ``commits`` (its commit passes)."""
+        (tokens the call emitted) and ``folded`` (blocks its passes stored
+        in front of the blocks they denoised)."""
         t0, seconds = self._own_time(seconds, t0)
         self.decode_block_seconds.observe(seconds)
         self.decode_steps.inc(steps * n_live)
@@ -702,10 +716,10 @@ class ServingTelemetry:
         if passes is not None:
             ran = {k: int(v.sum()) for k, v in passes.stats.items()}
             self.diffusion_row_passes.inc(ran["passes"])
-            self.diffusion_commit_passes.inc(ran["commits"])
+            self.diffusion_folded_commits.inc(ran["folded"])
             self.diffusion_tokens["threshold"].inc(ran["by_threshold"])
             self.diffusion_tokens["floor"].inc(ran["by_floor"])
-            extra = {"committed": ran["count"], "commits": ran["commits"]}
+            extra = {"committed": ran["count"], "folded": ran["folded"]}
         for g in guids:
             self.tracer.decode_block(g, steps, t0, seconds, int(n_live),
                                      int(width), **extra)

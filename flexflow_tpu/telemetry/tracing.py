@@ -218,8 +218,9 @@ class SpanTracer:
                      dur_s: float, rows: int, width: int = 1, **extra):
         """``rows``: the block's live rows; ``width``: the tokens a row
         each step computed (one of them real; all of them where the model
-        fills blocks by diffusion, whose blocks also carry ``committed``
-        and ``commits``). The same on every request's copy of the span."""
+        fills blocks by diffusion: its block length, a pass computing two
+        blocks a row; its blocks also carry ``committed`` and ``folded``).
+        The same on every request's copy of the span."""
         self.emit("decode_block", "X", guid, ts_s=ts_s, dur_s=dur_s,
                   request_guid=guid, steps=steps, rows=rows, width=width,
                   **extra)

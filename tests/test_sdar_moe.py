@@ -1,17 +1,21 @@
 """SDAR-MoE on the serving path, at tiny sizes on the CPU in float32: a
 decode step that fills a block of four positions by diffusion, sees the block
-both ways and commits it to the cache only when it is whole.
+both ways, emits it when it is whole and stores it in the row's next pass,
+in front of the next block.
 
 The system's forward (whole blocks through the compact prefill, then passes
 of the decode block through the cache) against the plain reference's full
 forward, with two deliberately wrong variants that must fail; served tokens
 through ``generate_incr_decoding`` against the reference's published loop,
 rows out of phase, at thresholds that unmask 1, 2 and 4 positions a pass;
-the renormalised expert weights; what refuses such a model; and the
+the fold itself: a pass of two blocks against the two passes it replaces, a
+whole block carried over a call, a request that ends on the pass that
+completes a block, a row at the cache's end; the renormalised expert weights; what refuses such a model; and the
 one-token decode block of a dense model, which is the parent's program.
 """
 
 import dataclasses
+import functools
 import hashlib
 import json
 import os
@@ -181,6 +185,220 @@ def test_end_of_sequence_inside_a_block(bench, served):
 
 
 # ---------------------------------------------------------------------------
+# (b2) the fold: a block that is whole is stored by its row's next pass
+# ---------------------------------------------------------------------------
+
+def _watched(m, prompts, news, eos=None):
+    """``_serve``, and what every decode block was handed and handed back:
+    (live slots, windows staged, stored lengths staged, steps asked,
+    BlockPasses)."""
+    ifm, calls = RequestManager._manager_of(m), []
+    run = ifm.decode_block
+
+    def noting(tok, pos, act, n, **kw):
+        out = run(tok, pos, act, n, **kw)
+        calls.append((np.flatnonzero(act), tok.copy(), pos.copy(), n, out))
+        return out
+
+    ifm.decode_block = noting
+    try:
+        return _serve(m, prompts, news, eos), calls
+    finally:
+        del ifm.decode_block
+
+
+def _whole(win):
+    return (win[:, :4] >= 0).all(axis=1)
+
+
+@pytest.mark.parametrize("per_pass", sorted(SCHEDULES))
+def test_every_emitted_block_is_stored_by_a_later_pass_or_carried(
+        bench, served, per_pass):
+    """Over the ragged, out-of-phase run of (b): in every call and every
+    live row, the blocks emitted are those a pass of the call stored in
+    front of the next, less the one the row came with, plus the one it
+    leaves with; the stored length a row is staged at moves by just what
+    its passes stored; no pass only stores."""
+    m, c, _ = served[per_pass]
+    rng = np.random.default_rng(0)
+    prompts = [rng.integers(1, 255, size=n).tolist() for n in PROMPTS]
+    got, calls = _watched(m, prompts, NEW)
+    assert [len(g) for g in got] == list(NEW)
+    folded = 0
+    for live, tok, pos, n, out in calls:
+        came, left = _whole(tok), _whole(out.block)
+        st = out.stats
+        assert (st["count"][live] // 4
+                == st["folded"][live] - came[live] + left[live]).all()
+        assert (st["passes"][live] == n).all()
+        assert (st["by_threshold"] + st["by_floor"]
+                >= st["passes"] * (1 if per_pass == 1 else 0)).all()
+        assert (out.stored == 4 * st["folded"]).all()
+        folded += int(st["folded"].sum())
+    assert folded > 0
+
+
+def test_a_whole_block_crosses_a_call_and_is_staged_back(bench, served):
+    """Two positions a pass, two passes a block, calls of five passes: the
+    tenth pass completes the fifth block as the second call ends. The
+    request leaves the call with the block in its tokens and not in its
+    cache; the third call is handed it whole in front of four masks, at the
+    stored length, and its first pass stores it; its last pass completes the
+    seventh block, which nothing stores: the request is done."""
+    _, ref = bench
+    m, c, w = served[2]
+    prompt = np.random.default_rng(7).integers(1, 255, size=8).tolist()
+    (got,), calls = _watched(m, [prompt], [28])
+    assert got == ref.generate(w, prompt, 28, _reference_cfg(c))
+    assert [n for *_, n, _ in calls] == [5, 5, 4]
+    (_, _, _, _, second), (_, tok, pos, _, third) = calls[1:]
+    assert _whole(second.block)[0] and second.count[0] == 12
+    assert second.stats["folded"][0] == 2           # blocks three and four
+    assert pos[0] == 8 + 16 and tok[0].tolist() == got[16:20] + [-1] * 4
+    assert third.stats["folded"][0] == 2 and third.count[0] == 8
+    assert _whole(third.block)[0]
+
+
+@pytest.mark.parametrize("how", ["max_new_tokens", "eos"])
+def test_request_that_ends_on_the_completing_pass_needs_no_more(
+        bench, served, how):
+    """The pass that leaves the block whole emits it: a request whose last
+    token (or end-of-sequence) is in that block is done when the call
+    returns, four passes for four tokens at the floor of one, and nothing
+    stores the block: nothing will read that row's cache."""
+    _, ref = bench
+    m, c, w = served[1]
+    rng = np.random.default_rng(11)
+    while True:     # an answer whose fourth token it has not shown before
+        prompt = rng.integers(1, 255, size=8).tolist()
+        free = ref.generate(w, prompt, 12, _reference_cfg(c))
+        if free[3] not in free[:3]:
+            break
+    eos = free[3] if how == "eos" else None
+    (got,), calls = _watched(m, [prompt], [4 if eos is None else 12], eos)
+    assert got == free[:4] and len(calls) == 1
+    (_, _, _, n, out), = calls
+    if eos is None:
+        assert n == 4 and out.stats["passes"][0] == 4
+        assert out.stats["folded"][0] == 0 and _whole(out.block)[0]
+    assert out.count[0] >= 4 and out.stats["by_floor"][0] >= 4
+
+
+def test_row_one_block_short_of_the_caches_end(bench, served):
+    """A prompt that leaves two blocks of the cache's 128 positions: the
+    first is stored by the pass that begins the second, at [120, 128); the
+    second is emitted and the row then sits out (a pass of two blocks would
+    write past the end) while the other row goes on in wide passes; the
+    request ends at the cache's length."""
+    _, ref = bench
+    m, c, w = served[1]
+    rng = np.random.default_rng(13)
+    prompts = [rng.integers(1, 255, size=n).tolist() for n in (120, 9)]
+    got, calls = _watched(m, prompts, [20, 14])
+    want = [ref.generate(w, p, n, _reference_cfg(c))
+            for p, n in zip(prompts, (8, 14))]
+    assert got == want
+    slot = next(s for live, tok, pos, _, _ in calls
+                for s in live if pos[s] == 120)
+    last = [out for live, _, pos, _, out in calls if slot in live][-1]
+    assert _whole(last.block)[slot]
+    assert sum(out.stats["folded"][slot] for live, _, pos, _, out in calls
+               if slot in live and pos[slot] >= 120) == 1
+
+
+def _rel_l2(a, b):
+    return float((np.linalg.norm(a - b, axis=-1)
+                  / np.linalg.norm(b, axis=-1)).max())
+
+
+@pytest.mark.parametrize("stale", [False, True])
+def test_wide_pass_is_the_two_passes_it_replaces(bench, served, stale):
+    """The decode block's own pass (engine.diffusion_pass), slot 0 with a
+    whole block at [16, 20) in front of four masks, slot 1 with a ragged
+    block at [8, 12) and nothing carried: ONE pass of 2B tokens against a
+    B-wide pass over the whole block followed by the next block's first
+    pass (the two forwards the commit and the denoise pass used to be). The new block's logits, both rows', and the keys and values of
+    [16, 20) in every layer are the same; slot 1's cache past its B real
+    tokens is not written. ``stale``: the cache keeps what the block's last
+    denoise pass wrote (one position still masked): far outside."""
+    from flexflow_tpu.serve.batch_config import BatchMeta
+    from flexflow_tpu.serve.engine import diffusion_pass, forward_with_meta
+    from flexflow_tpu.serve.request_manager import RequestManager as RM
+
+    fam, _ = bench
+    m, c, _ = served[1]
+    m.op_state = jax.tree_util.tree_map(jnp.zeros_like, m.op_state)
+    rng = np.random.default_rng(17)
+    run = fam.Passes(m)
+    run.prefill(rng.integers(1, 255, size=16).tolist())
+    chunk, segments = RM._prefill_shape(m.config)
+    run._step(RM._meta_from_segments(
+        segments, chunk, [(1, rng.integers(1, 255, size=8).tolist(), 0)]),
+        False, 8)
+    logits_t = m.layers[-1].inputs[0]
+    wide = functools.partial(jax.jit(
+        lambda params, st, win, pos, act, carried: diffusion_pass(
+            m, params, st, win, pos, act, carried, None, jnp.float32,
+            outputs=[logits_t])), m.params)
+
+    @jax.jit
+    def b_wide(params, st, blk, pos, act):      # the pass of one block
+        meta = BatchMeta(
+            tokens=jnp.where(blk < 0, c.mask_token_id, blk),
+            positions=pos[:, None] + jnp.arange(4), start_pos=pos,
+            num_tokens=4 * act.astype(jnp.int32), active=act)
+        return forward_with_meta(m, params, st, meta, None, jnp.float32,
+                                 phase="decode", outputs=[logits_t])
+
+    block = rng.integers(1, 255, size=4).tolist()
+    ragged = [int(rng.integers(1, 255)), -1, -1, -1]
+    masks = [-1] * 4
+    rows = lambda *r: jnp.asarray(list(r) + [masks * (len(r[0]) // 4)] * 2,
+                                  jnp.int32)
+    only = lambda *slots: jnp.isin(jnp.arange(4), jnp.asarray(slots, int))
+    start = m.op_state
+    # the two passes: the whole block alone, then the next block's first
+    seen = list(block)
+    if stale:
+        seen[2] = -1
+    _, st = b_wide(m.params, start, rows(seen, masks),
+                   jnp.asarray([16, 8, 0, 0]), only(0))
+    (two,), st_two = b_wide(m.params, st, rows(masks, ragged),
+                            jnp.asarray([20, 8, 0, 0]), only(0, 1))
+    # the one pass
+    (one,), st_one = wide(start, rows(block + masks, ragged + masks),
+                          jnp.asarray([16, 8, 0, 0]), only(0, 1), only(0))
+    assert one.shape == two.shape == (4, 4, 256)
+    err = _rel_l2(np.asarray(one[:2]), np.asarray(two[:2]))
+    kv = lambda st, r, a, b: np.concatenate(
+        [np.asarray(st["kv_cache"][x][:, r, :, a:b]) for x in "kv"])
+    stored = _rel_l2(kv(st_one, 0, 16, 20), kv(st_two, 0, 16, 20))
+    if stale:
+        assert err > 0.1 and stored > 0.1, (err, stored)
+        return
+    assert err < 1e-4 and stored < 1e-4, (err, stored)
+    assert np.abs(kv(st_one, 0, 16, 20)).min(axis=-1).max() > 0
+    assert not np.asarray(kv(st_one, 1, 12, 16)).any()
+    assert np.asarray(kv(st_one, 1, 8, 12)).any()
+
+
+def test_check_folded_commit_tool_rehearses(monkeypatch, capsys):
+    """tools/check_folded_commit.py (the on-chip check of the decode
+    block's wide pass against the plain reference, which the benchmark's
+    reference check, a B-wide pass, does not run) at the configuration's
+    rehearsal sizes."""
+    monkeypatch.syspath_prepend(ROOT)
+    for key in ("JAX_PLATFORMS", "FF_PALLAS_INTERPRET"):
+        monkeypatch.setenv(key, os.environ.get(key, ""))   # restored after
+    from tools import check_folded_commit
+
+    assert check_folded_commit.main(["--rehearse"]) == 0
+    res = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert res["ok"] and res["routes_ok"] and res["wide_rel_l2"] < res["tol"]
+    assert res["stored_rel_l2"] < res["tol_cache"]
+
+
+# ---------------------------------------------------------------------------
 # (c) the expert weights; the family's config
 # ---------------------------------------------------------------------------
 
@@ -221,7 +439,8 @@ def test_config_reads_the_catalog_keys_and_refuses_the_rest():
             c.num_attention_heads, c.num_key_value_heads, c.head_dim,
             c.vocab_size) == (12, 128, 768, 32, 4, 128, 151936)
     assert c.diffusion == BlockDiffusion(4, 4, 0.9, 151669)
-    assert c.diffusion.floor == 1 and c.diffusion.passes_for(5) == 10
+    assert c.diffusion.floor == 1 and c.diffusion.passes_for(5) == 8
+    assert c.diffusion.emitted_most(16) == 64
     for bad in ({"sliding_window": 4096}, {"use_sliding_window": True},
                 {"mlp_only_layers": [0]}, {"decoder_sparse_step": 2},
                 {"rope_scaling": {"type": "yarn"}},

@@ -598,15 +598,19 @@ def test_lag_readers_on_a_hand_built_trace(metric, capsys):
 
 # ---------------------------------------------------------------------------
 # a block-diffusion model's decode blocks: counters and span attributes
-# against a hand-counted run (ISSUE 39)
+# against a hand-counted run (ISSUE 39; the fold of a block's store into the
+# row's next pass: ISSUE 40)
 # ---------------------------------------------------------------------------
 
 def test_diffusion_counters_and_span_attributes_hand_counted():
-    """One request, prompt 5 (a remainder of 1), 7 new tokens, the floor of
-    one position a denoise pass (no pick of seeded weights clears 0.9),
-    blocks of 8 passes. Call 1: block one (3 denoise + commit), 4 denoise
-    passes of block two. Call 2 (4 tokens to go: at most 5 passes): its
-    commit, then 4 passes into a block the host cuts."""
+    """One request, prompt 5 (a remainder of 1), 11 new tokens, the floor
+    of one position a denoise pass (no pick of seeded weights clears 0.9),
+    calls of 7 passes. Call 1: block one takes 3 passes and is emitted by
+    the third (4 tokens, 3 of them new); pass 4 stores it in front of block
+    two, whose fourth pass, the call's last, emits it: the request leaves
+    with 7 new tokens and block two not stored. Call 2 (4 tokens to go: 4
+    passes): its first stores block two in front of block three, its last
+    emits that one. No pass only stores."""
     import flexflow_tpu as ff
     from flexflow_tpu.ffconst import CompMode, InferenceMode
     from flexflow_tpu.models import FAMILIES
@@ -616,29 +620,31 @@ def test_diffusion_counters_and_span_attributes_hand_counted():
     model = ff.FFModel(ff.FFConfig(
         max_requests_per_batch=2, max_sequence_length=64,
         max_tokens_per_batch=16, seed=0, kv_cache_dtype="float32",
-        decode_block_steps=8))
+        decode_block_steps=7))
     fam.build(model, fam.config_cls(**TINY_CONFIGS["sdar_moe"]),
               mode=InferenceMode.INC_DECODING_MODE)
     model.compile(comp_mode=CompMode.COMP_MODE_INFERENCE)
     tel = enable_telemetry()
     try:
         rm = RequestManager()
-        rm.register_new_request([5, 9, 23, 44, 7], max_new_tokens=7)
+        rm.register_new_request([5, 9, 23, 44, 7], max_new_tokens=11)
         (res,) = rm.generate_incr_decoding(model)
-        assert len(res.output_tokens) == 7
+        assert len(res.output_tokens) == 11
         snap = tel.registry.snapshot()
         value = lambda name: snap[name]["value"]
-        assert value("ffsv_diffusion_row_passes_total") == 8 + 5
-        assert value("ffsv_diffusion_commit_passes_total") == 2
+        assert value("ffsv_diffusion_row_passes_total") == 7 + 4
+        assert value("ffsv_diffusion_commit_passes_total") == 0
+        assert value("ffsv_diffusion_folded_commits_total") == 1 + 1
         assert value('ffsv_diffusion_tokens_total{by="floor"}') == 3 + 4 + 4
         assert value('ffsv_diffusion_tokens_total{by="threshold"}') == 0
-        assert value("ffsv_decode_steps_total") == 8 + 5
+        assert value("ffsv_decode_steps_total") == 7 + 4
         assert value("ffsv_decode_width") == 4
         blocks = [e["args"] for e in tel.tracer.events
                   if e["name"] == "decode_block"]
         assert [(b["steps"], b["rows"], b["width"], b["committed"],
-                 b["commits"]) for b in blocks] == [(8, 1, 4, 4, 1),
-                                                    (5, 1, 4, 4, 1)]
+                 b["folded"]) for b in blocks] == [(7, 1, 4, 8, 1),
+                                                   (4, 1, 4, 4, 1)]
+        assert not any("commits" in b for b in blocks)
     finally:
         disable_telemetry()
 
