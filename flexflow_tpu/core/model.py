@@ -243,9 +243,17 @@ class FFModel:
             use_bias=use_bias), name)
 
     def rms_norm(self, input: Tensor, eps: float = 1e-6,
-                 dim: Optional[int] = None, name: Optional[str] = None) -> Tensor:
+                 dim: Optional[int] = None, name: Optional[str] = None,
+                 unit_offset: bool = False,
+                 data_type: Optional[DataType] = None) -> Tensor:
+        """``unit_offset``: the weight is an offset from one, ``x / rms *
+        (1 + g)``; ``data_type``: the output's, where it is not the
+        input's (ops/norm.RMSNorm). Only a model that asks carries the
+        keys."""
         return self._add_layer(OpType.RMS_NORM, [input], dict(
-            eps=eps, dim=dim or input.dims[-1]), name)
+            eps=eps, dim=dim or input.dims[-1],
+            **({"unit_offset": True} if unit_offset else {}),
+            **({} if data_type is None else {"data_type": data_type})), name)
 
     def residual_rms_norm(self, input1: Tensor, input2: Tensor,
                           eps: float = 1e-6, dim: Optional[int] = None,
@@ -300,8 +308,13 @@ class FFModel:
                            name, qk_norm_eps: Optional[float] = None,
                            qk_norm_per_head: bool = False,
                            sliding_window: Optional[int] = None,
-                           block_length: Optional[int] = None
+                           block_length: Optional[int] = None,
+                           eva_window: Optional[int] = None,
+                           chunk_size: Optional[int] = None
                            ) -> Tensor:
+        if eva_window is not None:
+            self._check_chunked(op_type, eva_window, chunk_size,
+                                sliding_window, block_length, position_bias)
         if block_length is not None and (
                 op_type != OpType.INC_MULTIHEAD_SELF_ATTENTION
                 or sliding_window is not None or position_bias):
@@ -350,8 +363,48 @@ class FFModel:
             # a block-diffusion model's layer: a query sees the keys of its
             # own block both ways (ops/inc_attention.block_visibility)
             **({} if block_length is None else
-               {"block_length": int(block_length)})),
+               {"block_length": int(block_length)}),
+            # a chunked (EVA) layer: an exact tumbling window and one
+            # learned summary pair a chunk of the positions before it
+            # (ops/kv_layout.py ``chunked_*``)
+            **({} if eva_window is None else
+               {"eva_window": int(eva_window),
+                "chunk_size": int(chunk_size)})),
             name)
+
+    def _check_chunked(self, op_type, window, chunk, sliding_window,
+                       block_length, position_bias):
+        """What a chunked attention layer cannot be built with, each by its
+        reason (the serving refusals over its cache are
+        ops/inc_attention.refuse_windowed's)."""
+        from flexflow_tpu.serve.request_manager import RequestManager
+
+        if op_type != OpType.INC_MULTIHEAD_SELF_ATTENTION:
+            raise NotImplementedError(
+                "a chunked attention layer is served by incremental "
+                f"decoding only, not as {op_type.name}: tree verification "
+                "and beam drafting stage, move and roll back cache "
+                "positions, and a position that has left the window "
+                "survives only inside its chunk's summary")
+        if (sliding_window is not None or block_length is not None
+                or position_bias):
+            raise NotImplementedError(
+                "a chunked attention layer with a sliding window, a block "
+                "length or a position bias: its visibility is its own (an "
+                "exact window and the summaries before it)")
+        cfg = self.config
+        seg, _ = RequestManager._prefill_shape(cfg)
+        if (not chunk or window % chunk or cfg.max_sequence_length % window
+                or window % seg or seg % chunk):
+            raise NotImplementedError(
+                f"a chunked attention layer of window {window} and chunk "
+                f"{chunk} under max_sequence_length "
+                f"{cfg.max_sequence_length} and a prefill chunk of {seg} "
+                "(max_tokens_per_batch over at most four rows): chunks "
+                "tile the window, windows the slot, and a prefill segment "
+                "is whole chunks inside ONE window (its queries share the "
+                "window's rows and the summaries before it), so the "
+                "chunk has to divide the prefill chunk and that the window")
 
     def inc_multihead_latent_attention(
             self, input: Tensor, embed_dim: int, num_heads: int,
@@ -407,8 +460,15 @@ class FFModel:
             qk_norm_eps: Optional[float] = None,
             qk_norm_per_head: bool = False,
             sliding_window: Optional[int] = None,
-            block_length: Optional[int] = None) -> Tensor:
-        """``qk_norm_eps``: RMS-normalise q and k, over the whole projection
+            block_length: Optional[int] = None,
+            eva_window: Optional[int] = None,
+            chunk_size: Optional[int] = None) -> Tensor:
+        """``eva_window``, ``chunk_size``: a chunked (EVA) layer: a query
+        sees its own window of ``eva_window`` positions exactly and one
+        learned summary pair for every ``chunk_size`` positions of the
+        windows before, in one softmax, from a cache that keeps both
+        (ops/kv_layout.py ``chunked_*``).
+        ``qk_norm_eps``: RMS-normalise q and k, over the whole projection
         or (``qk_norm_per_head``) over each head. ``sliding_window``: a
         query sees the last that many positions, and the layer keeps a ring
         of them instead of ``max_sequence_length`` (ops/kv_layout.py).
@@ -420,7 +480,8 @@ class FFModel:
             add_zero_attn, data_type, kernel_initializer,
             apply_rotary_embedding, scaling_query, scaling_factor,
             qk_prod_scaling, position_bias, rope_theta, name, qk_norm_eps,
-            qk_norm_per_head, sliding_window, block_length)
+            qk_norm_per_head, sliding_window, block_length, eva_window,
+            chunk_size)
 
     def spec_inc_multihead_self_attention(self, input: Tensor, embed_dim: int,
                                           num_heads: int, **kw) -> Tensor:
@@ -1253,7 +1314,8 @@ class FFModel:
         (ops/latent_attention.py) keep one stream each: their stack, of any
         depth, is inc_attention.LATENT_STACK.
         """
-        from flexflow_tpu.ops.inc_attention import (FULL_STACK, LATENT_STACK,
+        from flexflow_tpu.ops.inc_attention import (CHUNKED_STACK,
+                                                    FULL_STACK, LATENT_STACK,
                                                     WINDOW_STACK)
 
         by_name = {layer.name: layer for layer in self.layers}
@@ -1279,7 +1341,23 @@ class FFModel:
                 "latent attention layers beside k/v ones in one model")
         rings = [n for n in names
                  if by_name[n].attrs.get("sliding_window") is not None]
-        if rings:
+        chunked = [n for n in names
+                   if by_name[n].attrs.get("eva_window") is not None]
+        if chunked:
+            # one stream of two extents a layer, its own stack at any depth
+            if len(chunked) != len(names):
+                raise NotImplementedError(
+                    "chunked attention layers beside others in one model")
+            kinds = {CHUNKED_STACK: chunked}
+            (window,) = {by_name[n].attrs["eva_window"] for n in chunked}
+            (chunk,) = {by_name[n].attrs["chunk_size"] for n in chunked}
+            # what telemetry says of the kind (ffsv_kv_cache_bytes; the
+            # positions read are counted by extent, note_attention_reads)
+            self.attention_kinds = {"chunked": {
+                "layers": len(chunked), "window": window, "chunk": chunk,
+                "cache_bytes": sum(2 * self.op_state[n]["k_cache"].nbytes
+                                   for n in chunked)}}
+        elif rings:
             kinds = {FULL_STACK: [n for n in names if n not in rings],
                      WINDOW_STACK: rings}
         elif len(names) < 2:

@@ -151,12 +151,15 @@ def _append_kernel(len_ref, appos_ref,     # scalar prefetch: [R] int32 each
                    PACK=PACK, D=D)
 
 
-def _window_kernel(len_ref, first_ref, *refs, mode, **static):
+def _window_kernel(len_ref, first_ref, *refs, mode, operand="first_ref",
+                   **static):
     """A windowed layer's variant of the three kernels above (``mode``
     None, "rows" or "append"): one more scalar-prefetched operand,
     ``first_ref`` [R], the first cache block a row's window touches. The
     cache is a ring of whole blocks (ops/kv_layout.py): the stream starts
-    at that block and block ``b`` is read from ring block ``b % n``."""
+    at that block and block ``b`` is read from ring block ``b % n``.
+    A chunked layer's variant likewise (``operand="nsum_ref"``): the operand
+    is the summary rows a row sees, of a stream of two extents."""
     appos_ref = rows_ref = knew_ref = vnew_ref = asem = None
     if mode == "append":
         (appos_ref, q_ref, qp_ref, slopes_ref, knew_ref, vnew_ref, bias_hbm,
@@ -170,7 +173,7 @@ def _window_kernel(len_ref, first_ref, *refs, mode, **static):
     _stream_attend(len_ref, appos_ref, q_ref, qp_ref, slopes_ref, knew_ref,
                    vnew_ref, bias_hbm, k_hbm, v_hbm, o_ref, acc, m, l, kbuf,
                    vbuf, bbuf, sem, asem, rows_ref=rows_ref,
-                   first_ref=first_ref, **static)
+                   **{operand: first_ref}, **static)
 
 
 def _stream_attend(len_ref, appos_ref, q_ref, qp_ref, slopes_ref, knew_ref,
@@ -179,7 +182,8 @@ def _stream_attend(len_ref, appos_ref, q_ref, qp_ref, slopes_ref, knew_ref,
                    *, BS: int, causal: bool, has_bias: bool,
                    has_alibi: bool, qk_scale: float, G: int, Q: int,
                    layer_idx, PACK: int, D: int, rows_ref=None,
-                   first_ref=None, window=None):
+                   first_ref=None, window=None, nsum_ref=None,
+                   summary_rows=None):
     """Shared stream-attend body.
 
     PACK == 1: one position per 128-lane cache row (D % 128 == 0).
@@ -195,6 +199,13 @@ def _stream_attend(len_ref, appos_ref, q_ref, qp_ref, slopes_ref, knew_ref,
     keys ``i - window < j <= i``, masked by ABSOLUTE position; program r
     streams blocks ``first[r] .. ceil(length / BS)`` of the positions and
     reads block b from the ring's block ``b % (ring rows / SB)``.
+
+    ``summary_rows`` (with ``nsum_ref``; PACK == 1): the stream is two
+    extents, rows ``[0, summary_rows)`` and the rows behind them. Program r
+    sees the first ``nsum[r]`` rows of the first, all of them, and the
+    second up to ``length`` as ever (``key <= qpos``, ``key < length``, all
+    three as rows of the stream); it streams ``ceil(nsum / BS)`` blocks from
+    row 0, then the blocks from ``summary_rows`` (whole blocks) on.
     """
     has_append = appos_ref is not None
     r = pl.program_id(0)
@@ -206,7 +217,12 @@ def _stream_attend(len_ref, appos_ref, q_ref, qp_ref, slopes_ref, knew_ref,
         nb = (len_ref[j] + jnp.asarray(BS - 1, jnp.int32)) // BS
         if first_ref is not None:
             nb = jnp.maximum(nb - first_ref[j], 0)
+        if nsum_ref is not None:          # less the blocks between the two
+            nb = jnp.maximum(nb - summary_rows // BS, 0) + nsb_of(j)
         return nb
+
+    def nsb_of(j):                        # summary blocks program j streams
+        return (nsum_ref[j] + jnp.asarray(BS - 1, jnp.int32)) // BS
 
     def row_of(j):                        # the cache row program j streams
         return j if rows_ref is None else rows_ref[j]
@@ -225,6 +241,9 @@ def _stream_attend(len_ref, appos_ref, q_ref, qp_ref, slopes_ref, knew_ref,
     ring_blocks = k_hbm.shape[-2] // SB
 
     def src(j, i):                        # cache block of program j's i-th
+        if nsum_ref is not None:
+            return jnp.where(i < nsb_of(j), i,
+                             i - nsb_of(j) + summary_rows // BS)
         if first_ref is None:
             return i
         return (first_ref[j] + i) % ring_blocks
@@ -289,9 +308,13 @@ def _stream_attend(len_ref, appos_ref, q_ref, qp_ref, slopes_ref, knew_ref,
         if first_ref is not None:         # as the stream counts and stores
             bp = bp - first_ref[r]
             pr = pr % k_hbm.shape[-2]
+        if nsum_ref is not None:
+            bp = bp - summary_rows // BS + nsb_of(r)
 
     def app_row():                        # the new row within its block
-        return pr - bp * SB if first_ref is None else pr % SB
+        if first_ref is None and nsum_ref is None:
+            return pr - bp * SB
+        return pr % SB
 
     def body(i, _):
         slot = (g0 + i) % 2
@@ -347,6 +370,8 @@ def _stream_attend(len_ref, appos_ref, q_ref, qp_ref, slopes_ref, knew_ref,
                 preferred_element_type=jnp.float32)     # [KH, GQ, SB]
             s = s * qk_scale
             b_abs = i if first_ref is None else first_ref[r] + i
+            if nsum_ref is not None:
+                b_abs = src(r, i)
             s_ids = (b_abs * BS + h
                      + PACK * jax.lax.broadcasted_iota(jnp.int32, (GQ, SB),
                                                        1))
@@ -363,6 +388,9 @@ def _stream_attend(len_ref, appos_ref, q_ref, qp_ref, slopes_ref, knew_ref,
             visible = visible & (s_ids < length)
             if window is not None:
                 visible = visible & (s_ids > qp[:, None] - window)
+            if nsum_ref is not None:
+                visible = visible & ((s_ids < nsum_ref[r])
+                                     | (s_ids >= summary_rows))
             s = jnp.where(visible[None], s, NEG_INF)
 
             m_new = jnp.maximum(m[:], jnp.max(s, axis=-1, keepdims=True))
@@ -410,11 +438,11 @@ def _stream_attend(len_ref, appos_ref, q_ref, qp_ref, slopes_ref, knew_ref,
 @functools.partial(
     jax.jit,
     static_argnames=("causal", "qk_scale", "interpret", "out_dtype",
-                     "layer_idx", "window"))
+                     "layer_idx", "window", "summary_rows"))
 def flash_attend(q, k_cache, v_cache, lengths, qpos, bias=None,
-                 alibi=None, append_kv=None, rows=None, *, causal=True,
-                 qk_scale=None, out_dtype=None, layer_idx=None,
-                 interpret=False, window=None):
+                 alibi=None, append_kv=None, rows=None, summaries=None, *,
+                 causal=True, qk_scale=None, out_dtype=None, layer_idx=None,
+                 interpret=False, window=None, summary_rows=None):
     """Batched KV-cache attention.
 
     q        [R, Q, H, D]   new-token queries (rotary already applied)
@@ -453,6 +481,18 @@ def flash_attend(q, k_cache, v_cache, lengths, qpos, bias=None,
                             blocks a row's window touches are streamed, and
                             the device operation is ``flash_attend_window``.
                             Without it the kernels are what they were.
+    summary_rows  int (static), with ``summaries`` [R] int32: a chunked
+                            layer, whose cache is two extents in one stream
+                            (ops/kv_layout.py; D fills the lanes): rows
+                            ``[0, summary_rows)`` and the rows behind them.
+                            Row r sees the first ``summaries[r]`` rows of
+                            the first and, of the second, the rows ``<=
+                            qpos`` and ``< lengths``: both, and append_kv's
+                            ``appos``, are then ROWS OF THE STREAM
+                            (kv_layout.chunked_view). Only the blocks that
+                            hold a visible row are streamed, and the device
+                            operation is ``flash_attend_chunked``. Without
+                            it the kernels are what they were.
     returns  [R, Q, H*D], or (out, k_cache, v_cache) with append_kv
     """
     assert rows is None or append_kv is None, "no fused append by row map"
@@ -499,7 +539,14 @@ def flash_attend(q, k_cache, v_cache, lengths, qpos, bias=None,
         bias = jnp.zeros((1, 1, 1, 1) if PACK > 1 else (1, 1, 1),
                          jnp.float32)
 
-    if window is None:
+    if summary_rows is not None:
+        assert PACK == 1 and not has_bias and window is None, (
+            "a chunked layer is position-major, causal")
+        assert summary_rows % BS == 0, (summary_rows, BS)
+        lengths = jnp.minimum(lengths.astype(jnp.int32), S)
+        first = [jnp.minimum(summaries.astype(jnp.int32), summary_rows)]
+        call_name = {"name": "flash_attend_chunked"}
+    elif window is None:
         # Clamp: an out-of-range length would DMA past the cache end.
         lengths = jnp.minimum(lengths.astype(jnp.int32), S)
         first, call_name = [], {}
@@ -567,7 +614,11 @@ def flash_attend(q, k_cache, v_cache, lengths, qpos, bias=None,
         static = dict(BS=BS, causal=causal, has_bias=has_bias,
                       has_alibi=has_alibi, qk_scale=float(qk_scale), G=G,
                       Q=Q, layer_idx=layer_idx, PACK=PACK, D=D)
-        if window is None:
+        if summary_rows is not None:
+            kern = functools.partial(
+                _window_kernel, mode=None if rows is None else "rows",
+                operand="nsum_ref", summary_rows=summary_rows, **static)
+        elif window is None:
             kern = functools.partial(
                 _kernel if rows is None else _rows_kernel, **static)
         else:
@@ -600,7 +651,11 @@ def flash_attend(q, k_cache, v_cache, lengths, qpos, bias=None,
     static = dict(BS=BS, causal=causal, has_bias=has_bias,
                   has_alibi=has_alibi, qk_scale=float(qk_scale), G=G, Q=Q,
                   layer_idx=layer_idx, PACK=PACK, D=D)
-    if window is None:
+    if summary_rows is not None:
+        kern = functools.partial(_window_kernel, mode="append",
+                                 operand="nsum_ref",
+                                 summary_rows=summary_rows, **static)
+    elif window is None:
         kern = functools.partial(_append_kernel, **static)
     else:
         kern = functools.partial(_window_kernel, mode="append",
@@ -668,6 +723,127 @@ def reference_attend(q, k_cache, v_cache, lengths, qpos, bias=None,
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("rkgqs,rksd->rqkgd", p.astype(q.dtype), vc)
     return out.reshape(R, Q, H * D).astype(out_dtype)
+
+
+# ----------------------------------------------------------------------
+# Chunked (EVA) attention: an exact window and one learned summary pair a
+# chunk of the positions before it, two extents in one stream
+# (ops/kv_layout.py ``chunked_*``). The attend is ``flash_attend`` with
+# ``summary_rows``; what follows is the summariser.
+# ----------------------------------------------------------------------
+def supports_chunked(rows: int, summary_rows: int, D: int) -> bool:
+    """True iff ``flash_attend`` can tile a chunked layer's stream of
+    ``rows`` rows whose window extent starts at row ``summary_rows``: D
+    fills the lanes and both extents are whole blocks."""
+    bs = _pick_block_s(rows, D)
+    return _pack_factor(D) == 1 and bs > 0 and summary_rows % bs == 0
+
+
+def pool_chunk(k, v, mu, phi):
+    """One summary pair a chunk, the learned softmax pools of
+    EVA: ``kbar = sum_m softmax_m(mu . k_m) k_m`` and ``vbar = sum_m
+    softmax_m(phi . k_m) v_m`` over the chunk's positions ``m`` (the rotated
+    keys score both), in float32, no temperature. ``k``, ``v`` ``[.., c,
+    D]`` with ``mu``, ``phi`` broadcastable to ``[.., D]`` -> two ``[..,
+    D]`` in float32."""
+    kf, vf = k.astype(jnp.float32), v.astype(jnp.float32)
+
+    def pool(x, w):
+        s = jnp.sum(kf * w.astype(jnp.float32)[..., None, :], axis=-1,
+                    keepdims=True)                          # [.., c, 1]
+        e = jnp.exp(s - jnp.max(s, axis=-2, keepdims=True))
+        return jnp.sum(e / jnp.sum(e, axis=-2, keepdims=True) * x, axis=-2)
+
+    return pool(kf, mu), pool(vf, phi)
+
+
+# rows of the summary extent's write-back window: a whole packed tile of a
+# 16-bit cache, as LATENT_APPEND_ROWS below
+SUMMARY_WINDOW_ROWS = 16
+
+
+def _summarise_kernel(src_ref, dst_ref, mu_ref, phi_ref, _k_in, _v_in,
+                      k_hbm, v_hbm, kbuf, vbuf, kwin, vwin, sem,
+                      *, chunk: int, layer_idx):
+    """Program ``r``: where ``src[r] >= 0`` pool the ``chunk`` rows from
+    ``src[r]`` of cache row ``r`` (keys and values) into one pair and write
+    it to row ``dst[r]`` IN PLACE: the aligned window of rows around
+    ``dst[r]`` is read, merged and written back (a DMA slice is whole
+    sublane tiles)."""
+    r = pl.program_id(0)
+    src, dst = src_ref[r], dst_ref[r]
+    if layer_idx is not None:
+        k_hbm, v_hbm = k_hbm.at[layer_idx], v_hbm.at[layer_idx]
+    A = kwin.shape[1]
+
+    @pl.when(src >= 0)
+    def _():
+        win = (dst // A) * A
+
+        def copies(back: bool):
+            for i, (hbm, buf, at, n) in enumerate((
+                    (k_hbm, kbuf, src, chunk), (v_hbm, vbuf, src, chunk),
+                    (k_hbm, kwin, win, A), (v_hbm, vwin, win, A))):
+                if back and i < 2:
+                    continue
+                ref = hbm.at[r, :, pl.ds(pl.multiple_of(at, n), n)]
+                yield (pltpu.make_async_copy(buf, ref, sem.at[i]) if back
+                       else pltpu.make_async_copy(ref, buf, sem.at[i]))
+
+        for d in copies(False):
+            d.start()
+        for d in copies(False):
+            d.wait()
+        kbar, vbar = pool_chunk(kbuf[:], vbuf[:], mu_ref[:], phi_ref[:])
+        at = jax.lax.broadcasted_iota(jnp.int32, kwin.shape, 1) == dst - win
+        kwin[:] = jnp.where(at, kbar[:, None, :].astype(kwin.dtype), kwin[:])
+        vwin[:] = jnp.where(at, vbar[:, None, :].astype(vwin.dtype), vwin[:])
+        for d in copies(True):
+            d.start()
+        for d in copies(True):
+            d.wait()
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("chunk", "layer_idx", "interpret"))
+def summarise_chunks(k_cache, v_cache, mu, phi, src, dst, *, chunk: int,
+                     layer_idx=None, interpret=False):
+    """The decode step's summariser of a chunked layer, in place.
+
+    k/v      [R, KH, rows, D]  the layer's stream as stored (or the stack
+                            [L, R, KH, rows, D] with ``layer_idx``), aliased
+                            in/out: the passed caches are consumed
+    mu, phi  [KH, D] f32    the heads' two learned vectors
+    src      [R] int32      the stored row of the first position of the
+                            chunk that row ``r`` just completed (a multiple
+                            of ``chunk``), or < 0: nothing to do for the row
+    dst      [R] int32      the summary row that chunk's pair goes to
+    returns  (k_cache, v_cache). The device operation is ``eva_summarise``.
+    """
+    R = src.shape[0]
+    KH, rows, D = k_cache.shape[-3:]
+    A = min(SUMMARY_WINDOW_ROWS, rows)
+    dt = k_cache.dtype
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    vec = pl.BlockSpec((KH, D), lambda r, *_: (0, 0),
+                       memory_space=pltpu.VMEM)
+    return pl.pallas_call(
+        functools.partial(_summarise_kernel, chunk=chunk,
+                          layer_idx=layer_idx),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(R,),
+            in_specs=[vec, vec, hbm, hbm], out_specs=(hbm, hbm),
+            scratch_shapes=[pltpu.VMEM((KH, chunk, D), dt),
+                            pltpu.VMEM((KH, chunk, D), dt),
+                            pltpu.VMEM((KH, A, D), dt),
+                            pltpu.VMEM((KH, A, D), dt),
+                            pltpu.SemaphoreType.DMA((4,))]),
+        out_shape=(jax.ShapeDtypeStruct(k_cache.shape, dt),
+                   jax.ShapeDtypeStruct(v_cache.shape, dt)),
+        input_output_aliases={4: 0, 5: 1},
+        interpret=interpret, name="eva_summarise",
+    )(src.astype(jnp.int32), dst.astype(jnp.int32),
+      mu.astype(jnp.float32), phi.astype(jnp.float32), k_cache, v_cache)
 
 
 # ----------------------------------------------------------------------

@@ -2,7 +2,7 @@
 
 Capability parity with the reference model zoo (reference inference/models/
 llama.cc, opt.cc, falcon.cc, mpt.cc, starcoder.cc and their Python twins in
-python/flexflow/serve/models/; OLMoE, EXAONE-MoE, Mistral-4 and SDAR-MoE, sparse-expert families, are beyond it): each model family is a builder that records
+python/flexflow/serve/models/; OLMoE, EXAONE-MoE, Mistral-4 and SDAR-MoE, sparse-expert families, and EvaByte, a byte-level model of chunked attention, are beyond it): each model family is a builder that records
 the decoder graph through the FFModel op-builder surface, plus a HuggingFace
 state-dict name mapping so real checkpoints load. ``FAMILIES`` maps the HF
 ``model_type`` to the family (the reference's ModelType enum +
@@ -12,6 +12,7 @@ serve.py architecture dispatch).
 import dataclasses
 from typing import Callable, Optional
 
+from flexflow_tpu.models import evabyte as _evabyte
 from flexflow_tpu.models import exaone_moe as _exaone_moe
 from flexflow_tpu.models import falcon as _falcon
 from flexflow_tpu.models import llama as _llama
@@ -21,6 +22,7 @@ from flexflow_tpu.models import olmoe as _olmoe
 from flexflow_tpu.models import opt as _opt
 from flexflow_tpu.models import sdar_moe as _sdar_moe
 from flexflow_tpu.models import starcoder as _starcoder
+from flexflow_tpu.models.evabyte import EvaByteConfig, create_evabyte_model
 from flexflow_tpu.models.exaone_moe import (ExaoneMoEConfig,
                                             create_exaone_moe_model)
 from flexflow_tpu.models.falcon import FalconConfig, create_falcon_model
@@ -78,6 +80,9 @@ FAMILIES = {
     "sdar_moe": ModelFamily("sdar_moe", SDARMoEConfig, create_sdar_moe_model,
                             _sdar_moe.hf_weight_map,
                             _sdar_moe.preprocess_hf_state_dict),
+    "evabyte": ModelFamily("evabyte", EvaByteConfig, create_evabyte_model,
+                           _evabyte.hf_weight_map,
+                           _evabyte.preprocess_hf_state_dict),
     "gpt_bigcode": ModelFamily("gpt_bigcode", STARCODERConfig,
                                create_starcoder_model,
                                _starcoder.hf_weight_map,
@@ -100,6 +105,7 @@ def family_for_hf_config(hf_config) -> ModelFamily:
 
 
 __all__ = [
+    "EvaByteConfig",
     "ExaoneMoEConfig",
     "FAMILIES",
     "FalconConfig",
@@ -111,6 +117,7 @@ __all__ = [
     "OPTConfig",
     "SDARMoEConfig",
     "STARCODERConfig",
+    "create_evabyte_model",
     "create_exaone_moe_model",
     "create_falcon_model",
     "create_llama_model",

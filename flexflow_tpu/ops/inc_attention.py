@@ -236,6 +236,11 @@ def _attend(attrs, q, k_cache, v_cache, lengths, qpos, out_dtype, ctx,
     A windowed layer (``attrs["sliding_window"]``) hands over its ring
     (ops/kv_layout.py): the kernel is told the window and the jnp oracle
     the position every ring row holds.
+
+    A chunked layer (``attrs["eva_window"]``) hands over its stream of two
+    extents (ops/kv_layout.py): ``lengths``, ``qpos`` and the append's
+    position go on as rows of that stream, with the summary rows each row
+    of the batch sees, to the kernel and to the jnp oracle alike.
     """
     from flexflow_tpu import kernels as ffk
     from flexflow_tpu.kernels.attention import flash_attend, reference_attend
@@ -251,10 +256,23 @@ def _attend(attrs, q, k_cache, v_cache, lengths, qpos, out_dtype, ctx,
     if window is not None:
         S = k_cache.shape[-2]       # the ring's rows, which the kernel tiles
         assert bias is None and causal, "a windowed layer attends causally"
+    chunked = attrs.get("eva_window")
+    if chunked is not None:
+        S = k_cache.shape[-2]       # both extents' rows
+        assert bias is None and causal, "a chunked layer attends causally"
+        ns = kvl.chunked_summary_rows(attrs["max_seq_length"],
+                                      attrs["chunk_size"])
+        summaries, lengths, qpos = kvl.chunked_view(
+            lengths, qpos, ns, chunked, attrs["chunk_size"])
+        if append_kv is not None:
+            k_new, v_new, appos = append_kv
+            append_kv = (k_new, v_new, jnp.where(
+                appos >= 0, kvl.chunked_window_row(appos, ns, chunked), -1))
     pack = kvl.pack_of(k_cache, S)
     Dp = k_cache.shape[-1] // pack  # cache head dim (128-padded)
     cfg = ctx.config if ctx is not None else None
-    from flexflow_tpu.kernels.attention import supports_shapes
+    from flexflow_tpu.kernels.attention import (supports_chunked,
+                                                supports_shapes)
     Q = q.shape[1]
     mesh = getattr(ctx, "mesh", None) if ctx is not None else None
     seq_deg = (mesh.shape["seq"] if mesh is not None
@@ -269,6 +287,9 @@ def _attend(attrs, q, k_cache, v_cache, lengths, qpos, out_dtype, ctx,
         ffk.record_fallback("cache sharded over the mesh's 'seq' axis")
     elif not supports_shapes(S, Dp):
         ffk.record_fallback(f"cache shape S={S} D={Dp} not tileable")
+    elif chunked is not None and not supports_chunked(S, ns, Dp):
+        ffk.record_fallback(f"chunked stream of {ns} + {S - ns} rows, "
+                            f"D={Dp}: the extents are not whole blocks")
     elif Q > 256:
         ffk.record_fallback(f"query width {Q} > 256")
     elif bias is not None and Q % 8 != 0:
@@ -286,7 +307,9 @@ def _attend(attrs, q, k_cache, v_cache, lengths, qpos, out_dtype, ctx,
         attend = functools.partial(
             flash_attend, causal=causal, qk_scale=scale,
             out_dtype=out_dtype, layer_idx=layer_idx,
-            interpret=ffk.pallas_interpret_forced(), window=window)
+            interpret=ffk.pallas_interpret_forced(), window=window,
+            **({} if chunked is None else
+               {"summaries": summaries, "summary_rows": ns}))
         args = (_pad_d(q, Dp), k_cache, v_cache, lengths, qpos, bias, alibi,
                 fkv, rows)
         if (mesh is not None and mesh.devices.size > 1
@@ -336,7 +359,9 @@ def _attend(attrs, q, k_cache, v_cache, lengths, qpos, out_dtype, ctx,
         q, kc[..., :D], vc[..., :D], lengths, qpos, bias=bias,
         alibi=alibi, causal=causal, qk_scale=scale, out_dtype=out_dtype,
         window=window,
-        key_pos=None if window is None else kvl.ring_positions(lengths, S))
+        key_pos=(kvl.chunked_key_rows(summaries, ns, S)
+                 if chunked is not None else
+                 None if window is None else kvl.ring_positions(lengths, S)))
     return out if append_kv is None else (out,) + new_caches
 
 
@@ -413,6 +438,18 @@ def _weight_specs(attrs, input_specs):
         else:
             specs += [WeightSpec("q_norm", (H * D,), dt, one),
                       WeightSpec("k_norm", (KH * D,), dt, one)]
+    if attrs.get("eva_window") is not None:
+        # a head's two learned pooling vectors (EvaByte's adaptive_mu_k and
+        # adaptive_phi, [1, KH, 1, 1, D] there), float32: drawn so that a
+        # chunk's pool weights spread (mu . k of spread about 2 at keys of
+        # unit spread) instead of starting at the chunk's plain mean
+        from flexflow_tpu.core.initializer import NormInitializer
+
+        pool = NormInitializer(stddev=2.0 / math.sqrt(D))
+        specs += [WeightSpec("adaptive_mu_k", (KH, D), DataType.DT_FLOAT,
+                             pool),
+                  WeightSpec("adaptive_phi", (KH, D), DataType.DT_FLOAT,
+                             pool)]
     return specs
 
 
@@ -464,7 +501,15 @@ def _init_kv_state(attrs, input_specs):
     cache_dtype = jnp.dtype(attrs.get("cache_dtype", "bfloat16"))
     want_pallas = attrs.get("use_pallas", True) and ffk.use_pallas()
     window = attrs.get("sliding_window")
-    if window is None:
+    if attrs.get("eva_window") is not None:
+        # a chunked layer keeps one summary row a chunk and, behind them,
+        # its window, position-major (never packed)
+        from flexflow_tpu.kernels.attention import LANE, round_up
+
+        shape = kvl.chunked_cache_shape(
+            R, KH, S, attrs["chunk_size"], attrs["eva_window"],
+            round_up(D, LANE) if want_pallas else D)
+    elif window is None:
         Dp = padded_head_dim(D, want_pallas=want_pallas, max_seq=S)
         # stored as the kernel reads it: [R, KH, S, Dp], or packed
         # [R, KH, S/2, 128] where a D=64 cache takes the packed flash path
@@ -507,6 +552,10 @@ def _project_out(attrs, params, ctx, attn_out):
 # ----------------------------------------------------------------------
 FULL_STACK, WINDOW_STACK = "kv_cache", "kv_cache_window"
 LATENT_STACK = "kv_cache_latent"
+# A chunked layer (``eva_window``) keeps a summary a chunk and its window in
+# one stream: its stack is op_state[CHUNKED_STACK] = {"k", "v"} of
+# [L, R, KH, S / chunk + window, D], whatever the depth.
+CHUNKED_STACK = "kv_cache_chunked"
 
 
 def refuse_windowed(op_state, what: str):
@@ -523,6 +572,12 @@ def refuse_windowed(op_state, what: str):
             f"{what} is not supported over a latent attention layer: its "
             "cache is one shared entry a position (ops/kv_layout.py), not "
             "a k/v pair")
+    if CHUNKED_STACK in (op_state or {}):
+        raise NotImplementedError(
+            f"{what} is not supported over a chunked attention layer: its "
+            "cache keeps a window's positions and one summary a chunk of "
+            "those before (ops/kv_layout.py), so a position once left "
+            "cannot be read back, moved or rolled back")
 
 
 def refuse_block_diffusion(model, what: str):
@@ -705,7 +760,10 @@ def append_and_ref(ctx, attrs, k, v, start_pos, num_tokens, active,
     # a windowed layer's ring takes the appends that are exact everywhere
     # (by slot, or the scatter)
     ring = attrs.get("sliding_window") is not None
-    contiguous = contiguous and not ring
+    # a chunked layer's stream likewise (the caller has made ``start_pos``
+    # the stored row of the run's first entry, in either extent)
+    chunked = attrs.get("eva_window") is not None
+    contiguous = contiguous and not ring and not chunked
     if ring and (k.shape[1] * (k.shape[0] if slots is not None else 1)
                  > attrs["max_step_tokens"]):
         raise ValueError(
@@ -717,7 +775,8 @@ def append_and_ref(ctx, attrs, k, v, start_pos, num_tokens, active,
     assert ov is None or slots is None, "no row map inside a pipeline stage"
     if ov is not None or idx is None:
         k0, v0 = read_kv(ctx, attrs)
-        pack = 1 if ring else kvl.pack_of(k0, attrs["max_seq_length"])
+        pack = (1 if ring or chunked
+                else kvl.pack_of(k0, attrs["max_seq_length"]))
         Dp = k0.shape[-1] // pack
         k, v = _pad_d(k, Dp), _pad_d(v, Dp)
         if slots is not None:
@@ -736,7 +795,8 @@ def append_and_ref(ctx, attrs, k, v, start_pos, num_tokens, active,
         write_kv(ctx, attrs, kc, vc)
         return kc, vc, None
     key, st = _stack(ctx, attrs)
-    pack = 1 if ring else kvl.pack_of(st["k"], attrs["max_seq_length"])
+    pack = (1 if ring or chunked
+            else kvl.pack_of(st["k"], attrs["max_seq_length"]))
     Dp = st["k"].shape[-1] // pack
     k, v = _pad_d(k, Dp), _pad_d(v, Dp)
     if slots is not None:
@@ -754,7 +814,8 @@ def append_and_ref(ctx, attrs, k, v, start_pos, num_tokens, active,
                                   pack=pack)
         vs = append_kv_contiguous(st["v"], idx, v, start_pos, active,
                                   pack=pack)
-    elif k.shape[1] == 1 or pack > 1 or ring or "block_length" in attrs:
+    elif (k.shape[1] == 1 or pack > 1 or ring or chunked
+          or "block_length" in attrs):
         # (a packed stack and a ring take every width in place:
         # append_kv_stacked; so does a block-diffusion layer's pass, a
         # block's few rows a (request, head) in every decode step)
@@ -772,6 +833,83 @@ def append_and_ref(ctx, attrs, k, v, start_pos, num_tokens, active,
         vs = st["v"].at[idx].set(vc)
     ctx.state_out[key] = {"k": ks, "v": vs}
     return ks, vs, idx
+
+
+# ----------------------------------------------------------------------
+# A chunked layer's summariser (EVA; kernels/attention.pool_chunk has the
+# two pools). A chunk's pair is written to its summary row as soon as the
+# chunk's last position is in the cache, by the step that appends it; the
+# visibility rule alone (kv_layout.chunked_view) hides a window's summaries
+# until the window is left, so crossing into the next window moves nothing.
+# ----------------------------------------------------------------------
+def _chunked_geometry(attrs):
+    """(summary rows, window, chunk) of a chunked layer."""
+    c = attrs["chunk_size"]
+    return (kvl.chunked_summary_rows(attrs["max_seq_length"], c),
+            attrs["eva_window"], c)
+
+
+def summarise_run(attrs, params, k, v, num_tokens):
+    """The pairs of the whole chunks of a step's fresh runs: ``k``, ``v``
+    ``[R, Q, KH, D]`` (keys rotated), each run from a position that starts a
+    chunk (FFModel._check_chunked holds the prefill chunk to whole chunks) ->
+    ``(kbar, vbar [R, Q // c, KH, D], n [R])``, ``n`` of them whole by
+    ``num_tokens``. No cache is read: the step then appends the pairs to
+    the summary extent as it appends the run to the window extent."""
+    from flexflow_tpu.kernels.attention import pool_chunk
+
+    c = attrs["chunk_size"]
+    R, Q, KH, D = k.shape
+    n = Q // c
+
+    def chunks(x):                                  # [R, n, KH, c, D]
+        return x[:, :n * c].reshape(R, n, c, KH, D).transpose(0, 1, 3, 2, 4)
+
+    with jax.named_scope("eva_summarise"):
+        kbar, vbar = pool_chunk(chunks(k), chunks(v),
+                                params["adaptive_mu_k"],
+                                params["adaptive_phi"])
+    return kbar.astype(k.dtype), vbar.astype(v.dtype), num_tokens // c
+
+
+def summarise_decode(attrs, params, ks, vs, layer_idx, pos, wrote, cfg):
+    """After a decode step appended position ``pos[r]`` of every row with
+    ``wrote[r]``: where that was the last of its chunk, pool the chunk's
+    rows of the window extent into one pair and write it to the chunk's
+    summary row of the stacks ``ks``, ``vs`` [L, R, KH, rows, D], layer
+    ``layer_idx``. On the Pallas path one kernel in place
+    (kernels/attention.summarise_chunks, device operation
+    ``eva_summarise``); elsewhere the same through slices and the row
+    scatter."""
+    from flexflow_tpu import kernels as ffk
+    from flexflow_tpu.kernels.attention import (SUBLANE, pool_chunk,
+                                                summarise_chunks,
+                                                supports_chunked)
+
+    ns, window, c = _chunked_geometry(attrs)
+    done = wrote & ((pos + 1) % c == 0)
+    src, dst = kvl.chunked_chunk_rows(pos, ns, window, c)
+    mu, phi = params["adaptive_mu_k"], params["adaptive_phi"]
+    KH, rows, D = ks.shape[-3:]
+    interpret = ffk.pallas_interpret_forced()
+    with jax.named_scope("eva_summarise"):
+        if (ffk.use_pallas(cfg) and supports_chunked(rows, ns, D)
+                and (interpret or c % (2 * SUBLANE) == 0)):
+            return summarise_chunks(ks, vs, mu, phi,
+                                    jnp.where(done, src, -1), dst, chunk=c,
+                                    layer_idx=layer_idx, interpret=interpret)
+
+        def chunk_of(stack):                        # [R, KH, c, D]
+            return jnp.stack([jax.lax.dynamic_slice(
+                stack, (layer_idx, r, 0, src[r], 0), (1, 1, KH, c, D))[0, 0]
+                for r in range(pos.shape[0])])
+
+        kbar, vbar = pool_chunk(chunk_of(ks), chunk_of(vs), mu, phi)
+        num = done.astype(jnp.int32)
+        return (append_kv_stacked(ks, layer_idx, kbar[:, None], dst, num,
+                                  done),
+                append_kv_stacked(vs, layer_idx, vbar[:, None], dst, num,
+                                  done))
 
 
 @register_op_as(OpType.INC_MULTIHEAD_SELF_ATTENTION,
@@ -842,14 +980,29 @@ class IncMultiHeadSelfAttention(OpImpl):
             out, knew, vnew = _attend(
                 attrs, q, k0, v0, lengths, q_abs, x.dtype, ctx, causal=True,
                 layer_idx=idx, append_kv=(k[:, :1], v[:, :1], appos))
+            if attrs.get("eva_window") is not None:
+                knew, vnew = summarise_decode(
+                    attrs, params, knew, vnew, idx, meta.start_pos,
+                    appos >= 0, ctx.config)
             if idx is None:
                 write_kv(ctx, attrs, knew, vnew)
             else:
                 ctx.state_out[key] = {"k": knew, "v": vnew}
             return [_project_out(attrs, params, ctx, out)]
+        start = meta.start_pos
+        if attrs.get("eva_window") is not None:
+            # the whole chunks' pairs to the summary extent first (no query
+            # of this step sees them: they are its own window's), then the
+            # run to the window extent
+            ns, window, c = _chunked_geometry(attrs)
+            kbar, vbar, n = summarise_run(attrs, params, k, v,
+                                          meta.num_tokens)
+            if kbar.shape[1]:
+                append_and_ref(ctx, attrs, kbar, vbar, start // c, n,
+                               meta.active, slots)
+            start = kvl.chunked_window_row(start, ns, window)
         k_ref, v_ref, layer_idx = append_and_ref(
-            ctx, attrs, k, v, meta.start_pos, meta.num_tokens, meta.active,
-            slots)
+            ctx, attrs, k, v, start, meta.num_tokens, meta.active, slots)
         out = _attend(attrs, q, k_ref, v_ref, lengths, q_abs, x.dtype,
                       ctx, causal=True, layer_idx=layer_idx, rows=slots)
         return [_project_out(attrs, params, ctx, out)]

@@ -41,6 +41,23 @@ the kernel's DMA slices need them lane-full), the exact entry elsewhere.
 The leading ``1`` is the one shared "head", so the appends of the other
 kinds (a ``[R, Q, KH, D]`` run into ``[R, KH, S, D]``) write it unchanged.
 ``latent_*`` below are the only place that knows it.
+
+A CHUNKED layer (``eva_window`` and ``chunk_size`` in the op's attrs: a
+query sees its own window of ``eva_window`` positions exactly and, of every
+window before, one learned summary pair for each ``chunk_size`` positions)
+keeps TWO EXTENTS in one stream a (row, head), position-major: with ``S``
+the slot's positions, rows ``[0, S / chunk)`` are the summaries (chunk ``n``
+in row ``n``) and rows ``[S / chunk, S / chunk + window)`` the window,
+position ``p`` in row ``S / chunk + p % window``. The window TUMBLES: the
+position that crosses a multiple of ``window`` overwrites the window's first
+row, and nothing is moved, because the summaries of the window just left are
+already in their rows (a chunk's is written when its last position is) and
+only the visibility changes: a query in window ``w`` sees the summary rows
+below ``w * window / chunk`` and the window rows up to its own. The kernel
+is handed that as stored rows (``chunked_view``): how many summary rows a
+row sees, and its queries' and its length's rows in the window extent, which
+it masks causally as it masks positions. ``chunked_*`` below are the only
+place that knows it.
 """
 
 from __future__ import annotations
@@ -236,3 +253,59 @@ def read_latent(cache, a: int, b: int, rank: int, rope: int, at=()):
     leading dims, as in ``read_positions``."""
     rows = cache[tuple(at) + (Ellipsis, 0, slice(a, b), slice(None))]
     return rows[..., :rank], rows[..., rank:rank + rope]
+
+
+def chunked_summary_rows(max_seq: int, chunk: int) -> int:
+    """Rows of a chunked layer's summary extent, one a chunk of the slot's
+    ``max_seq`` positions; the window extent starts behind them."""
+    assert max_seq % chunk == 0, (max_seq, chunk)
+    return max_seq // chunk
+
+
+def chunked_cache_shape(R: int, KH: int, max_seq: int, chunk: int,
+                        window: int, Dp: int):
+    return (R, KH, chunked_summary_rows(max_seq, chunk) + window, Dp)
+
+
+def chunked_window_row(pos, ns: int, window: int):
+    """The stored row of position ``pos`` (any integer array) in the window
+    extent behind ``ns`` summary rows."""
+    return ns + pos % window
+
+
+def chunked_chunk_rows(pos, ns: int, window: int, chunk: int):
+    """For position ``pos`` [R], the LAST of its chunk where the chunk is
+    whole: ``(src, dst)``, the stored row of the chunk's first position in
+    the window extent and the summary row the chunk's pair goes to."""
+    return ns + pos % window // chunk * chunk, pos // chunk
+
+
+def chunked_view(lengths, qpos, ns: int, window: int, chunk: int):
+    """What a step's rows see, as stored rows: ``(summaries, lengths,
+    qpos)`` for valid extents ``lengths`` [R] (0: a row that sits out) and
+    query positions ``qpos`` [R, Q], all of one window a row. Row ``r`` sees
+    summary rows ``[0, summaries[r])``, every chunk of the windows before
+    its own, and of the window extent the rows ``<= qpos`` and ``<
+    lengths``, both returned as rows of the stream."""
+    w = qpos[:, 0] // window
+    live = lengths > 0
+    return (jnp.where(live, w * (window // chunk), 0).astype(jnp.int32),
+            jnp.where(live, ns + lengths - w * window, 0).astype(jnp.int32),
+            chunked_window_row(qpos, ns, window).astype(jnp.int32))
+
+
+def chunked_key_rows(summaries, ns: int, rows: int):
+    """``[R, rows]``: each stored row's own index where a row of the batch
+    may see it by ``chunked_view``'s causal rule, negative where it may not
+    (a summary row of the row's own window or a later one)."""
+    at = jnp.arange(rows)[None, :]
+    return jnp.where((at < summaries[:, None]) | (at >= ns), at, -1)
+
+
+def read_chunked(cache, a: int, b: int, ns: int, window: int, at=()):
+    """Positions ``[a, b)`` (static, of one window, still held) of a chunked
+    layer's window extent as ``[.., b-a, D]``; ``at`` as in
+    ``read_positions``."""
+    assert a // window == (b - 1) // window, (a, b, window)
+    r0 = ns + a % window
+    return cache[tuple(at) + (Ellipsis, slice(r0, r0 + b - a), slice(None))]

@@ -131,24 +131,49 @@ class AddBiasResidualLayerNorm(OpImpl):
         return [added, normed]
 
 
+def _rms_norm_unit_offset(x, g, eps, out_dtype):
+    """``x / sqrt(mean(x^2) + eps) * (1 + g)`` (EvaByte's
+    ``norm_add_unit_offset``): the stored weight is the offset from one.
+    Computed in float32 whole, rounded once, to ``out_dtype``."""
+    xf = x.astype(jnp.float32)
+    var = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+    y = xf * jax.lax.rsqrt(var + eps) * (1.0 + g.astype(jnp.float32))
+    return y.astype(out_dtype)
+
+
 @register_op
 class RMSNorm(OpImpl):
+    """``attrs["unit_offset"]`` (absent: the plain norm, as it was): the
+    weight is an offset from one, zero-initialised, and the output takes
+    ``attrs["data_type"]`` where that is set (a float32 residual stream
+    normed into the compute dtype)."""
+
     op_type = OpType.RMS_NORM
 
     @staticmethod
     def infer_output_specs(attrs, input_specs):
-        return [input_specs[0]]
+        (shape, dtype) = input_specs[0]
+        return [(shape, attrs.get("data_type") or dtype)]
 
     @staticmethod
     def weight_specs(attrs, input_specs):
-        from flexflow_tpu.core.initializer import ConstantInitializer
+        from flexflow_tpu.core.initializer import (ConstantInitializer,
+                                                   ZeroInitializer)
 
         (shape, dtype) = input_specs[0]
+        if attrs.get("unit_offset"):
+            return [WeightSpec("weight", (attrs.get("dim", shape[-1]),),
+                               DataType.DT_FLOAT, ZeroInitializer())]
         return [WeightSpec("weight", (attrs.get("dim", shape[-1]),), dtype,
                            ConstantInitializer(1.0))]
 
     @staticmethod
     def forward(attrs, params, inputs, ctx):
+        if attrs.get("unit_offset"):
+            dt = attrs.get("data_type")
+            return [_rms_norm_unit_offset(
+                inputs[0], params["weight"], attrs.get("eps", 1e-6),
+                inputs[0].dtype if dt is None else dt.to_jnp())]
         return [_rms_norm(inputs[0], params["weight"], attrs.get("eps", 1e-6))]
 
 

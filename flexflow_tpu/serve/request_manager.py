@@ -632,6 +632,10 @@ class RequestManager:
         if tel is None:
             ifm.step(meta, want_output=False)
             return
+        kinds = getattr(ifm.model, "attention_kinds", None)
+        if kinds and "chunked" in kinds:
+            tel.note_chunked_prefill(kinds["chunked"],
+                                     [(sp, len(chunk)) for _, chunk, sp in rows])
         if rnd is not None:
             rnd.phase(None)
         step = PendingPrefill(tel, [(active[slot].guid, sp, len(chunk))
@@ -712,19 +716,29 @@ class RequestManager:
 
     @staticmethod
     def _prefill_rows(active, chunk: int, depth_of, segments: int,
-                      consecutive: bool = True, hold=lambda req: 1):
+                      consecutive: bool = True, hold=lambda req: 1,
+                      window: Optional[int] = None):
         """At most ``segments`` segments (slot, tokens, start_pos) of at
         most ``chunk`` tokens for one prefill step. The requests with more
         pending than the decode step takes (``hold``: _held_back) get one
         each, oldest admission first; with ``consecutive`` the spare
         segments go, in the same order, to those with more still pending,
         as their next chunks. A slot's segments come in ascending order of
-        start_pos."""
-        rows, taken = [], {}
+        start_pos. ``window`` (a model of chunked attention layers,
+        ops/kv_layout.py): the positions a slot is given in ONE step lie in
+        one window of that many, since the step appends them all before any
+        of them attends and the next window's would overwrite this one's
+        rows: a segment is cut at the boundary, and what lies beyond waits
+        for the next step."""
+        rows, taken, first = [], {}, {}
 
         def pending(req):
-            return (len(req.tokens) - depth_of(req) - taken.get(req.slot, 0)
-                    - hold(req))
+            n = (len(req.tokens) - depth_of(req) - taken.get(req.slot, 0)
+                 - hold(req))
+            if (window and n > 0 and first.get(req.slot, -1) not in (
+                    -1, (len(req.tokens) - hold(req) - n) // window)):
+                return 0                # its next start is a window on
+            return n
 
         filling = sorted((req for req in active if req is not None
                           and not req.finished and pending(req) > 0),
@@ -733,6 +747,9 @@ class RequestManager:
             for req in filling[:segments - len(rows)]:
                 d = len(req.tokens) - hold(req) - pending(req)
                 take = min(pending(req), chunk)
+                if window:
+                    take = min(take, window - d % window)
+                    first.setdefault(req.slot, d // window)
                 rows.append((req.slot, req.tokens[d:d + take], d))
                 taken[req.slot] = taken.get(req.slot, 0) + take
             filling = [req for req in filling
@@ -758,9 +775,12 @@ class RequestManager:
         with ``lag`` (_timed_prefill)."""
         chunk, segments = shape
         compact = self._compact_prefill(ifm)
+        chunked = (getattr(ifm.model, "attention_kinds", None)
+                   or {}).get("chunked")
         rows = self._prefill_rows(active, chunk, depth_of, segments,
                                   consecutive=compact,
-                                  hold=self._held_back(ifm.model))
+                                  hold=self._held_back(ifm.model),
+                                  window=chunked and chunked["window"])
         if rows:
             meta = (self._meta_from_segments(segments, chunk, rows)
                     if compact else
@@ -953,8 +973,10 @@ class RequestManager:
                 tok, pos, act = self._stage_decode(ifm, live, R)
                 self._tel_tick(tel, live, R, max_seq)
                 kinds = getattr(model, "attention_kinds", None)
+                reads = None
                 if tel is not None and kinds:   # rings beside full; latent
-                    tel.note_attention_reads(kinds, pos[act] + 1, block)
+                    reads = tel.note_attention_reads(kinds, pos[act] + 1,
+                                                     block)
                 if rnd is not None:
                     rnd.phase(None)
                 t0 = time.perf_counter()
@@ -969,7 +991,8 @@ class RequestManager:
                                             [r.guid for r in live], t0,
                                             width=ifm.decode_width,
                                             passes=toks if isinstance(
-                                                toks, BlockPasses) else None)
+                                                toks, BlockPasses) else None,
+                                            reads=reads)
                 self._commit_decode(live, toks, block, max_seq)
             for slot in range(R):
                 req = active[slot]

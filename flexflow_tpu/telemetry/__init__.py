@@ -64,6 +64,10 @@ ffsv_prefix_shared_tokens_total  counter    prompt tokens served from the pool
 ffsv_prefix_pool_tokens          gauge      tokens held by the prefix pool
 ffsv_kv_cache_bytes              gauge      {kind} bytes of the caches of a kind
 ffsv_attn_positions_read_total   counter    {kind} layer-positions decode read
+ffsv_attn_positions_held_total   counter    layer-positions decode rows held (chunked)
+ffsv_attn_prefill_entries_total  counter    layer-entries prefill segments read (chunked)
+ffsv_chunk_summaries_total       counter    summary rows written (a layer each)
+ffsv_window_rollovers_total      counter    {phase} rows that entered a new window
 ffsv_moe_routed_pairs_total      counter    {phase} (token, expert) pairs run
 ffsv_moe_tokens_total            counter    {phase} real tokens the experts saw
 ffsv_moe_experts_touched         summary    {phase} distinct experts a call read
@@ -86,7 +90,20 @@ stays at 0 as the measure of what is left of them.
 attention layers beside full ones keeps a ring a windowed layer and every
 position a full one; a model of latent layers (ops/latent_attention.py) one
 shared entry a position (``FFModel.attention_kinds``; no other model has
-these series).
+these series). A model of CHUNKED layers (``eva_window``: an exact window
+and one summary a chunk of the positions before it, two extents in one
+stream) has ``ffsv_kv_cache_bytes{kind="chunked"}`` and counts what its
+decode steps read by extent: ``kind="summary"`` (the summary rows of the
+windows before a row's own) and ``kind="chunk_window"`` (its window's
+positions up to its own), both layer-entries of the same bytes;
+``ffsv_attn_positions_held_total`` is the positions those rows held (what
+a full cache would have read), ``ffsv_attn_prefill_entries_total`` what the
+prefill steps' segments had to read, once a segment;
+``ffsv_chunk_summaries_total`` counts the summary rows written (a whole
+chunk of a layer each, prefill and decode) and
+``ffsv_window_rollovers_total{phase}`` the rows whose position entered a
+new window (``prefill``: a segment that starts one; ``decode``: a step),
+where nothing is moved or launched.
 ``ffsv_kv_cache_bytes`` is what compile allocated for each kind;
 ``ffsv_attn_positions_read_total`` is what the rows of the decode steps had
 to attend, from the batch's lengths on the host: for each row of each step
@@ -501,14 +518,77 @@ class ServingTelemetry:
         """A decode block of ``steps`` steps over rows whose caches hold
         ``lengths`` positions after the first step's append: the
         layer-positions each kind of attention layer has to read
-        (``kinds``: ``FFModel.attention_kinds``)."""
+        (``kinds``: ``FFModel.attention_kinds``). Returns what goes on the
+        block's span beside ``steps`` and ``rows``: for a model of chunked
+        layers ``{"entries": the layer-entries the block's rows had to
+        read}``, else nothing."""
         at = np.asarray(lengths, np.int64)[:, None] + np.arange(steps)
+        if "chunked" in kinds:
+            return self._note_chunked_reads(kinds["chunked"], at)
         for kind, a in kinds.items():
             seen = at if a["window"] is None else np.minimum(at, a["window"])
             self.registry.counter(
                 f'ffsv_attn_positions_read_total{{kind="{kind}"}}',
                 "layer-positions the decode steps' rows had to attend"
                 ).inc(int(seen.sum()) * a["layers"])
+        return {}
+
+    def _chunked_counter(self, name, n, layers=1):
+        helps = {
+            'ffsv_attn_positions_read_total{kind="summary"}':
+                "layer-entries the decode steps' rows had to attend",
+            'ffsv_attn_positions_read_total{kind="chunk_window"}':
+                "layer-entries the decode steps' rows had to attend",
+            "ffsv_attn_positions_held_total":
+                "layer-positions the decode steps' rows held",
+            "ffsv_attn_prefill_entries_total":
+                "layer-entries the prefill steps' segments had to attend",
+            "ffsv_chunk_summaries_total":
+                "summary rows written, a layer and a whole chunk each"}
+        self.registry.counter(
+            name, helps.get(name, "rows whose position crossed into a new "
+                            "window of a chunked layer")).inc(int(n) * layers)
+
+    def _note_chunked_reads(self, a, at):
+        """``at`` [rows, steps]: the positions each row's cache holds after
+        each decode step's append, over chunked layers ``a``
+        (ops/kv_layout.py): the summaries of the windows before the last
+        position's own and that window's positions up to it are what the
+        step read; a last position that ends a chunk wrote a summary row; a
+        last position that begins a window (but the first) crossed into it."""
+        last, W, c = at - 1, a["window"], a["chunk"]
+        L = a["layers"]
+        summaries = int((last // W * (W // c)).sum())
+        window = int((last % W + 1).sum())
+        self._chunked_counter(
+            'ffsv_attn_positions_read_total{kind="summary"}', summaries, L)
+        self._chunked_counter(
+            'ffsv_attn_positions_read_total{kind="chunk_window"}', window, L)
+        self._chunked_counter("ffsv_attn_positions_held_total", at.sum(), L)
+        self._chunked_counter("ffsv_chunk_summaries_total",
+                              (at % c == 0).sum(), L)
+        self._chunked_counter(
+            'ffsv_window_rollovers_total{phase="decode"}',
+            ((last % W == 0) & (last > 0)).sum())
+        return {"entries": (summaries + window) * L}
+
+    def note_chunked_prefill(self, a, runs):
+        """A prefill step's ``runs`` [(start, tokens)] over chunked layers
+        ``a``: the whole chunks they hold were summarised, a run that
+        starts a window (but the first) crossed into it, and a run's
+        queries had the summaries before its window and the window up to
+        its last position to read (once a run: the kernel streams them for
+        the segment, not for each query)."""
+        W, c = a["window"], a["chunk"]
+        self._chunked_counter(
+            "ffsv_attn_prefill_entries_total",
+            sum(sp // W * (W // c) + (sp + n - 1) % W + 1
+                for sp, n in runs if n), a["layers"])
+        self._chunked_counter("ffsv_chunk_summaries_total",
+                              sum(n // c for _, n in runs), a["layers"])
+        self._chunked_counter(
+            'ffsv_window_rollovers_total{phase="prefill"}',
+            sum(sp > 0 and sp % W == 0 for sp, _ in runs))
 
     @staticmethod
     def _read_counters(model):
@@ -696,7 +776,7 @@ class ServingTelemetry:
 
     def record_decode_block(self, seconds: float, steps: int, n_live: int,
                             guids=(), t0: Optional[float] = None,
-                            width: int = 1, passes=None):
+                            width: int = 1, passes=None, reads=None):
         """``t0``: as in ``record_prefill``; a block launched behind a
         prefill step starts where that step's wait returned, so no prefill
         time falls inside a ``decode_block`` span. Every request's copy of
@@ -707,19 +787,20 @@ class ServingTelemetry:
         inference_manager.BlockPasses; None: a token a row a step) feeds
         the ``ffsv_diffusion_*`` counters and gives the span ``committed``
         (tokens the call emitted) and ``folded`` (blocks its passes stored
-        in front of the blocks they denoised)."""
+        in front of the blocks they denoised). ``reads``: what
+        ``note_attention_reads`` handed back for the block, onto the span."""
         t0, seconds = self._own_time(seconds, t0)
         self.decode_block_seconds.observe(seconds)
         self.decode_steps.inc(steps * n_live)
         self.decode_width.set(width)
-        extra = {}
+        extra = dict(reads or {})
         if passes is not None:
             ran = {k: int(v.sum()) for k, v in passes.stats.items()}
             self.diffusion_row_passes.inc(ran["passes"])
             self.diffusion_folded_commits.inc(ran["folded"])
             self.diffusion_tokens["threshold"].inc(ran["by_threshold"])
             self.diffusion_tokens["floor"].inc(ran["by_floor"])
-            extra = {"committed": ran["count"], "folded": ran["folded"]}
+            extra.update(committed=ran["count"], folded=ran["folded"])
         for g in guids:
             self.tracer.decode_block(g, steps, t0, seconds, int(n_live),
                                      int(width), **extra)

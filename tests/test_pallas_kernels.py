@@ -388,7 +388,7 @@ def test_flash_fused_append_at_one_token_a_row(case):
 
 @pytest.mark.parametrize("config", [
     "falcon-7b", "olmoe-1b-7b", "k-exaone-236b-a23b", "mistral-small-4-119b",
-    "opt-6.7b-spec"])
+    "opt-6.7b-spec", "evabyte-6.5b"])
 def test_decode_block_at_one_token_a_row_yields_the_wide_blocks_tokens(
         config, monkeypatch, capsys):
     """tools/profile_decode.py --config (the A/B of the decode block's two

@@ -10,7 +10,7 @@ fenced by host readback):
   --width      decode_block at the width the manager resolved (one token
                a row unless an engine verifies the model) and, beside it,
                at the other of 1 and the sublane-padded verify width 8.
-  --config NAME [NAME ...] [--rehearse] [--cut N]
+  --config NAME [NAME ...] [--rehearse] [--cut N] [--positions N]
                the same A/B on a benchmark configuration
                (benchmark/configs/NAME.json, built as its cell builds it; N
                layers instead of its depth): ragged prompts are prefilled
@@ -168,8 +168,12 @@ def run_width(bench):
           f"({(t1 / t0 - 1) * 100:+.1f}% against width {w0})")
 
 
-def run_config(name: str, rehearse: bool, cut=None) -> dict:
-    """The two widths of the decode block on one benchmark configuration."""
+def run_config(name: str, rehearse: bool, cut=None, positions=None) -> dict:
+    """The two widths of the decode block on one benchmark configuration.
+    ``positions``: the shortest prompt's length (default: two prefill chunks
+    and one; a configuration whose layers read more the more a row holds,
+    such as evabyte-6.5b's summaries beyond 2048, is profiled at its
+    cell's lengths: ``--positions 14000``)."""
     import jax
 
     from benchmark import run as bench_run
@@ -197,7 +201,7 @@ def run_config(name: str, rehearse: bool, cut=None) -> dict:
     chunk = C.prefill_chunk(cfg)
     rng = np.random.default_rng(cfg["weights_seed"])
     prompts = [rng.integers(1, cfg["vocab_size"],
-                            size=2 * chunk + 1 + 3 * r).tolist()
+                            size=(positions or 2 * chunk + 1) + 3 * r).tolist()
                for r in range(R - 1)]             # the last slot stays idle
     for at in range(0, max(map(len, prompts)) - 1, chunk):
         rows = [(r, p[at:min(at + chunk, len(p) - 1)], at)
@@ -284,9 +288,10 @@ def run_head_only(bench, model):
 
 def main_configs(argv) -> int:
     rehearse = "--rehearse" in argv
-    cut = int(argv[argv.index("--cut") + 1]) if "--cut" in argv else None
+    cut, positions = (int(argv[argv.index(o) + 1]) if o in argv else None
+                      for o in ("--cut", "--positions"))
     names = [a for i, a in enumerate(argv) if not a.startswith("--")
-             and argv[i - 1] != "--cut"]
+             and argv[i - 1] not in ("--cut", "--positions")]
     if rehearse:
         os.environ["JAX_PLATFORMS"] = "cpu"
         os.environ["FF_PALLAS_INTERPRET"] = "1"
@@ -296,7 +301,8 @@ def main_configs(argv) -> int:
         print("no TPU; nothing was run", file=sys.stderr)
         return 2
     for name in names:
-        print(json.dumps(run_config(name, rehearse, cut)), flush=True)
+        print(json.dumps(run_config(name, rehearse, cut, positions)),
+              flush=True)
     return 0
 
 
