@@ -175,7 +175,7 @@ class Experts(OpImpl):
 # idled the device 131 ms a prefill step).
 MOE_COUNTERS = "moe_counters"
 MOE_PHASES = ("decode", "prefill", "verify")
-MOE_FIELDS = ("calls", "tokens", "routed", "touched")
+MOE_FIELDS = ("calls", "tokens", "routed", "touched", "resident")
 
 
 def init_counters(model):
@@ -250,7 +250,11 @@ class MoeExperts(OpImpl):
     ``FFConfig.telemetry`` the op keeps cumulative counters on the device,
     in its row of the op state's ``MOE_COUNTERS``: routed pairs per expert
     and, per phase of ``MOE_PHASES``, ``calls``, ``tokens``, ``routed``
-    (pairs) and ``touched`` (distinct experts, summed over calls).
+    (pairs), ``touched`` (distinct experts, summed over calls) and
+    ``resident`` (the calls whose rows the kernel gathered and whose
+    results it weighted and added itself, in VMEM: every call of a program
+    whose step fits there, ``kernels/moe.rows_fit``, and none of one whose
+    step does not, which stages the tiles' rows through HBM).
     ``ServingTelemetry`` reads them at a snapshot and nowhere else.
 
     With ``attrs["router_width"]`` the layer is one chip's share of an
@@ -298,15 +302,17 @@ class MoeExperts(OpImpl):
         else:
             K.record_fast_path()
         width = attrs.get("router_width")
+        T = R * q
+        cap = max(R, getattr(ctx.config, "max_tokens_per_batch", T))
+        resident = pallas and K.step_fits(min(T, cap), H, params["gate"],
+                                          x.dtype)
         run = functools.partial(
             K.moe_experts, gate=params["gate"], up=params["up"],
             down=params["down"], pallas=pallas,
-            interpret=ffk.pallas_interpret_forced(),
+            interpret=ffk.pallas_interpret_forced(), resident=resident,
             held=None if width is None else (attrs["first_expert"], width))
-        T = R * q
         flat = (x[:, :q].reshape(T, H), idx[:, :q].reshape(T, -1),
                 w[:, :q].reshape(T, -1), valid.reshape(T))
-        cap = max(R, getattr(ctx.config, "max_tokens_per_batch", T))
         if T <= cap:
             y, sizes = run(*flat)
         else:
@@ -317,7 +323,8 @@ class MoeExperts(OpImpl):
             u32, n = jnp.uint32, len(MOE_PHASES)
             step = jnp.stack([jnp.uint32(1), jnp.sum(valid, dtype=u32),
                               jnp.sum(sizes, dtype=u32),
-                              jnp.sum(sizes > 0, dtype=u32)])
+                              jnp.sum(sizes > 0, dtype=u32),
+                              jnp.uint32(resident)])
             at = E + n * jnp.arange(len(MOE_FIELDS)) + MOE_PHASES.index(phase)
             st = ctx.state_out.get(MOE_COUNTERS)
             if st is None:

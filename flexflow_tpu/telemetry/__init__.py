@@ -71,6 +71,7 @@ ffsv_window_rollovers_total      counter    {phase} rows that entered a new wind
 ffsv_moe_routed_pairs_total      counter    {phase} (token, expert) pairs run
 ffsv_moe_tokens_total            counter    {phase} real tokens the experts saw
 ffsv_moe_experts_touched         summary    {phase} distinct experts a call read
+ffsv_moe_resident_calls_total    counter    {phase} calls that kept their rows in VMEM
 ffsv_moe_expert_pairs_total      counter    {expert} routed pairs of one expert
 ===============================  =========  =================================
 
@@ -116,7 +117,10 @@ counts ON THE DEVICE, in its op state, as the steps run; ``watch_model``
 makes the registry fetch those counters when a snapshot or a scrape is
 taken (one small device-to-host read) and at no other time. They are sums
 over the model's expert layers: a token counts once per layer, and
-``ffsv_moe_experts_touched``'s count is layer-steps. A layer that holds a
+``ffsv_moe_experts_touched``'s count is layer-steps, of which
+``ffsv_moe_resident_calls_total`` are those whose rows the kernel gathered
+and whose results it weighted and added itself (a step that fits in VMEM
+beside the weights' buffers, kernels/moe.rows_fit: static a program). A layer that holds a
 share of its router's experts (``held``) counts its own: ``expert`` is the
 held index, and a pair routed to an expert held elsewhere is no pair.
 ``phase`` is
@@ -624,8 +628,8 @@ class ServingTelemetry:
             watched[1] = raw
             n = len(MOE_PHASES)
             pairs = gained[:-len(MOE_FIELDS) * n]
-            calls, tokens, routed, touched = gained[len(pairs):].reshape(
-                len(MOE_FIELDS), n)
+            calls, tokens, routed, touched, resident = gained[
+                len(pairs):].reshape(len(MOE_FIELDS), n)
             for i, ph in enumerate(MOE_PHASES):
                 lab = f'{{phase="{ph}"}}'
                 r.counter("ffsv_moe_routed_pairs_total" + lab,
@@ -637,6 +641,9 @@ class ServingTelemetry:
                 r.summary("ffsv_moe_experts_touched" + lab,
                           "distinct experts one expert-layer call read"
                           ).add(int(calls[i]), float(touched[i]))
+                r.counter("ffsv_moe_resident_calls_total" + lab,
+                          "expert-layer calls whose rows the kernel gathered "
+                          "and added itself").inc(int(resident[i]))
             for e, n_pairs in enumerate(pairs):
                 r.counter(f'ffsv_moe_expert_pairs_total{{expert="{e}"}}',
                           "routed pairs of one expert, over layers"

@@ -62,17 +62,29 @@ def test_flash_attend_exports_for_tpu_at_smoke_shapes():
         sds((4, chunk), i32), sds((4,), i32))
 
 
-@pytest.mark.parametrize("tokens", [32, 512],
-                         ids=["decode_32_rows", "prefill_512_tokens"])
-def test_moe_experts_exports_for_tpu_at_the_cells_shapes(tokens):
+# (tokens, experts held, router width or None, hidden, expert width, top-k)
+MOE_STEPS = {
+    "decode_32_rows": (32, 64, None, 2048, 1024, 8),
+    "prefill_512_tokens": (512, 64, None, 2048, 1024, 8),
+    "sdar_pass_256_tokens": (256, 128, None, 2048, 768, 8),
+    "k_exaone_prefill_staged": (512, 16, 128, 6144, 2048, 8),
+}
+
+
+@pytest.mark.parametrize("step", MOE_STEPS)
+def test_moe_experts_exports_for_tpu_at_the_cells_shapes(step):
     """The routed-expert kernel at OLMoE-1B-7B's widths (64 int8 experts of
     2048 x 1024, top-8): a decode step of 32 rows and a prefill step of 512
-    real tokens. A Mosaic rejection shows here and not on the chip."""
+    real tokens; SDAR's pass of 32 rows x 8 over 128 experts of 768; all
+    three keep their rows in VMEM. K-EXAONE's prefill step (16 held experts
+    of 6144 x 2048) does not fit there and exports the staged form. A
+    Mosaic rejection shows here and not on the chip."""
     from flexflow_tpu.kernels import moe as K
     from flexflow_tpu.quant import QuantizedWeight
 
-    E, H, inter, k = 64, 2048, 1024, 8
+    tokens, E, width, H, inter, k = MOE_STEPS[step]
     sds = jax.ShapeDtypeStruct
+    assert K.rows_fit(tokens, H, inter, 1, 2) is (width is None)
 
     def q(rows, cols):
         return QuantizedWeight("int8", sds((E, rows, cols), jnp.int8),
@@ -80,7 +92,8 @@ def test_moe_experts_exports_for_tpu_at_the_cells_shapes(tokens):
 
     _export_tpu(
         lambda x, idx, w, valid, g, u, d: K.moe_experts(
-            x, idx, w, valid, g, u, d, pallas=True)[0],
+            x, idx, w, valid, g, u, d, pallas=True,
+            held=None if width is None else (0, width))[0],
         sds((tokens, H), jnp.bfloat16), sds((tokens, k), jnp.int32),
         sds((tokens, k), jnp.float32), sds((tokens,), jnp.bool_),
         q(H, inter), q(H, inter), q(inter, H))

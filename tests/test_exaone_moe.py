@@ -499,7 +499,7 @@ def test_the_loop_serves_it_and_counts_by_kind(bench):
 
     family, _ = bench
     m, c = _build(telemetry=True)
-    assert m.op_state["moe_counters"].shape == (3, 4 + 4 * 3)
+    assert m.op_state["moe_counters"].shape == (3, 4 + 5 * 3)
     prompts = [list(np.random.default_rng(i).integers(1, 256, size=n))
                for i, n in enumerate((150, 9))]
     new = 12

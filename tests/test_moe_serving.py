@@ -83,16 +83,23 @@ CASES = ("uniform", "empty_expert", "one_expert_takes_all",
          "inactive_and_padded", "nothing_real")
 
 
-@pytest.mark.parametrize("pallas", [True, False],
-                         ids=["pallas_interpret", "ragged_fallback"])
+# the Pallas call with its rows resident in VMEM (gathered and added in the
+# kernel), the same call with its tiles staged through HBM, and the ragged
+# fallback
+FORMS = {"pallas_interpret": dict(pallas=True, resident=True),
+         "pallas_staged": dict(pallas=True, resident=False),
+         "ragged_fallback": dict(pallas=False)}
+
+
+@pytest.mark.parametrize("form", FORMS)
 @pytest.mark.parametrize("case", CASES)
-def test_grouped_kernel_matches_dense_einsum(case, pallas):
+def test_grouped_kernel_matches_dense_einsum(case, form):
     x, idx, w, valid, gate, up, down = _drawn(case)
     # a row that is not a token must not reach an expert even if it holds
     # something that would blow up
     x = jnp.where(valid[:, None], x, 1e30)
     y, sizes = K.moe_experts(x, idx, w, valid, gate, up, down,
-                             pallas=pallas, interpret=True)
+                             interpret=True, **FORMS[form])
     want = _dense_experts(jnp.where(valid[:, None], x, 0.0), idx, w, valid,
                           gate, up, down)
     np.testing.assert_allclose(np.asarray(y), np.asarray(want),
@@ -103,15 +110,110 @@ def test_grouped_kernel_matches_dense_einsum(case, pallas):
     assert np.isfinite(np.asarray(y)).all()
 
 
-def test_grouped_kernel_reads_int8_experts_with_their_scales():
+@pytest.mark.parametrize("resident", [True, False],
+                         ids=["resident", "staged"])
+def test_grouped_kernel_reads_int8_experts_with_their_scales(resident):
     x, idx, w, valid, gate, up, down = _drawn("uniform", H=64, inter=64)
     q = [quantize_array(a, "int8") for a in (gate, up, down)]
     assert q[0].scale.shape == (8, 64) and q[0].q.dtype == jnp.int8
-    y, _ = K.moe_experts(x, idx, w, valid, *q, pallas=True, interpret=True)
+    y, _ = K.moe_experts(x, idx, w, valid, *q, pallas=True, interpret=True,
+                         resident=resident)
     want = _dense_experts(x, idx, w, valid,
                           *[dequantize_array(a, jnp.float32) for a in q])
     np.testing.assert_allclose(np.asarray(y), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("first", [0, 2, 6])
+def test_resident_kernel_over_a_held_range_of_int8_experts(first):
+    """A chip's share of a wider router through the form that gathers and
+    adds in the kernel: the pairs routed elsewhere reach no tile and add
+    nothing, a row that is no token may hold anything, and the two Pallas
+    forms agree to the last bit of the float32 sums' rounding."""
+    x, idx, w, valid, gate, up, down = _drawn("inactive_and_padded", H=64,
+                                              inter=64, k=3)
+    x = jnp.where(valid[:, None], x, 1e30)
+    q = [quantize_array(a[first:first + 2], "int8")
+         for a in (gate, up, down)]
+    run = lambda resident: K.moe_experts(
+        x, idx, w, valid, *q, pallas=True, interpret=True,
+        held=(first, 8), resident=resident)
+    (y, sizes), (staged, _) = run(True), run(False)
+    here = (idx >= first) & (idx < first + 2) & valid[:, None]
+    deq = [jnp.zeros_like(a).at[first:first + 2].set(
+        dequantize_array(b, jnp.float32)) for a, b in zip((gate, up, down), q)]
+    want = _dense_experts(jnp.where(valid[:, None], x, 0.0), idx,
+                          jnp.where(here, w, 0.0), valid, *deq)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(staged),
+                               rtol=1e-6, atol=1e-6)
+    np.testing.assert_array_equal(
+        np.asarray(sizes),
+        [int(((idx == first + e) & here).sum()) for e in range(2)])
+    assert np.isfinite(np.asarray(y)).all()
+
+
+# (T, H, expert width) of a decode and a prefill step of the benchmark's four
+# expert configurations (32 or 16 slots, a block-diffusion pass of 32 rows x
+# 8, a token budget of 512), int8 experts, bfloat16 rows
+STEP_SHAPES = {
+    "olmoe.decode": (32, 2048, 1024, True),
+    "olmoe.prefill": (512, 2048, 1024, True),
+    "sdar.decode": (256, 2048, 768, True),
+    "sdar.prefill": (512, 2048, 768, True),
+    "mistral.decode": (16, 4096, 2048, True),
+    "mistral.prefill": (512, 4096, 2048, True),
+    "k-exaone.decode": (32, 6144, 2048, True),
+    "k-exaone.prefill": (512, 6144, 2048, False),
+}
+
+
+@pytest.mark.parametrize("step", STEP_SHAPES)
+def test_rows_stay_in_vmem_where_the_step_fits(step):
+    T, H, inter, fits = STEP_SHAPES[step]
+    assert K.rows_fit(T, H, inter, 1, 2) is fits
+    # the rule is of the shapes alone: more rows or wider weights never fit
+    # where fewer did not
+    if not fits:
+        assert not K.rows_fit(2 * T, H, inter, 1, 2)
+        assert not K.rows_fit(T, H, inter, 2, 2)
+
+
+@pytest.mark.parametrize("step", ["olmoe.decode", "sdar.decode",
+                                  "k-exaone.prefill"])
+def test_no_tiled_buffer_around_the_resident_kernel(step):
+    """At a decode step of the two models that touch nearly every expert,
+    nothing of [M, H] (the tiles' rows, M = tiles x rows a tile) exists
+    outside the Pallas call: no gather of M rows before it, no buffer of
+    results after it. The step that does not fit keeps both."""
+    T, H, inter, fits = STEP_SHAPES[step]
+    E, k = {"olmoe.decode": (64, 8), "sdar.decode": (128, 8),
+            "k-exaone.prefill": (16, 8)}[step]
+    held = (0, 128) if E == 16 else None
+    sds = jax.ShapeDtypeStruct
+    q = lambda *s: QuantizedWeight("int8", sds(s, jnp.int8),
+                                   sds((s[0], s[2]), jnp.float32), s[1],
+                                   "bfloat16")
+    jaxpr = jax.make_jaxpr(lambda *a: K.moe_experts(*a, pallas=True,
+                                                    held=held))(
+        sds((T, H), jnp.bfloat16), sds((T, k), jnp.int32),
+        sds((T, k), jnp.float32), sds((T,), jnp.bool_),
+        q(E, H, inter), q(E, H, inter), q(E, inter, H))
+    pairs = T * k if held is None else T * k * E // 128
+    tm = K.pick_tile(pairs, E)
+    M = (T * k + min(E, T * k) * (tm - 1)) // tm * tm
+
+    def shapes(jp):
+        for eqn in jp.eqns:
+            yield from (v.aval.shape for v in eqn.outvars)
+            if eqn.primitive.name != "pallas_call":   # the kernel's own body
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    yield from shapes(sub)
+
+    seen = set(shapes(jaxpr.jaxpr))
+    assert ((M, H) in seen) is (not fits), sorted(seen)
+    assert (T, H) in seen
 
 
 def test_a_step_over_the_token_budget_runs_in_passes():
@@ -149,6 +251,21 @@ def test_tile_plan_puts_every_pair_in_its_experts_tile():
                 continue
             assert r < n_active[0] * tm
             assert tile_expert[r // tm] == idx[t, j] and row_token[r] == t
+    # the resident form's plan of the same tiles: a tile's pairs are
+    # consecutive places of the sorted order, and every real pair is in one
+    order, start, count, te, na, sz = map(
+        np.asarray, K.plan_tiles(jnp.asarray(idx), jnp.asarray(valid), 8, tm))
+    assert (te == tile_expert).all() and na == n_active and (sz == sizes).all()
+    held = []
+    for i in range(len(te)):
+        assert 0 <= count[i] <= tm and (count[i] > 0) == (i < na[0])
+        for p in order[start[i]:start[i] + count[i]]:
+            assert idx[p // 2, p % 2] == te[i] and valid[p // 2]
+            assert pair_row[p // 2, p % 2] == i * tm + len(held) - sum(
+                count[:i])
+            held.append(p)
+    assert sorted(held) == [t * 2 + j for t in range(40) if valid[t]
+                            for j in range(2)]
     assert K.pick_tile(256, 64) == 16 and K.pick_tile(4096, 64) == 64
     assert K.pick_tile(1 << 20, 64) == 128
 
@@ -302,6 +419,7 @@ def _serve(model, prompts, new_tokens, tel=None):
 
 
 def test_counters_count_real_tokens_times_k_and_only_with_telemetry():
+    from flexflow_tpu.ops.moe import MOE_FIELDS
     from flexflow_tpu.telemetry import ServingTelemetry
 
     prompts = [[3, 17, 42, 99, 7, 21, 5], [9, 8, 7]]
@@ -310,7 +428,7 @@ def test_counters_count_real_tokens_times_k_and_only_with_telemetry():
     plain = _serve(off, prompts, 6)
 
     on = _build(TINY, telemetry=True)
-    assert on.op_state["moe_counters"].shape == (2, 8 + 4 * 3)  # one leaf
+    assert on.op_state["moe_counters"].shape == (2, 8 + 5 * 3)  # one leaf
     tel = ServingTelemetry()
     got = _serve(on, prompts, 6, tel=tel)
     assert [r.output_tokens for r in got] == [r.output_tokens for r in plain]
@@ -332,6 +450,14 @@ def test_counters_count_real_tokens_times_k_and_only_with_telemetry():
     touched = snap['ffsv_moe_experts_touched{phase="decode"}']
     assert touched["type"] == "summary" and touched["count"] == L * 6
     assert 2 <= touched["sum"] / touched["count"] <= 4   # 2 rows x top-2
+    # the CPU's ragged fallback stages its tiles: no call kept its rows in
+    # the kernel, and the field is there to say so
+    raw = np.asarray(on.op_state["moe_counters"])[:, 8:].reshape(2, 5, 3)
+    assert MOE_FIELDS.index("resident") == 4 and not raw[:, 4].any()
+    assert raw[:, 0, 0].sum() == touched["count"]
+    for ph in ("prefill", "decode"):
+        assert snap[f'ffsv_moe_resident_calls_total{{phase="{ph}"}}'][
+            "value"] == 0
     # a second snapshot with no step in between adds nothing
     assert tel.registry.snapshot() == snap
     text = tel.registry.to_prometheus()
