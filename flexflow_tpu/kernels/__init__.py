@@ -30,12 +30,40 @@ def pallas_interpret_forced() -> bool:
 # ----------------------------------------------------------------------
 fallback_counts: dict = {}
 fast_path_count: int = 0
+# How a step with no slot map (a decode step, a slot-grid prefill) put its
+# new keys and values into the cache, in traces by the run's width in
+# positions a row: inside the attention kernel (``fused``), or by a
+# row-granular scatter in front of it (``scatter``: about 75 ns an index
+# row of the scalar unit, R x KH x width of them a cache a layer).
+fused_append_counts: dict = {}
+scatter_append_counts: dict = {}
 _warned: set = set()
 
 
-def record_fast_path():
+def record_fast_path(append=None):
+    """Count a trace of the attention kernel; ``append``: the width of the
+    run of positions it appends to the cache itself."""
     global fast_path_count
     fast_path_count += 1
+    if append is not None:
+        fused_append_counts[append] = fused_append_counts.get(append, 0) + 1
+
+
+def record_scatter_append(width: int):
+    """Count a trace of a row-granular scatter append of ``width`` positions
+    a row in a step with no slot map."""
+    scatter_append_counts[width] = scatter_append_counts.get(width, 0) + 1
+
+
+def append_summary() -> str:
+    """The two append counters in one line, as a run prints them: "fused
+    appends of width 8: 12 traces; scatter appends of width 4: 2 traces"."""
+    def part(kind, counts):
+        return "; ".join(f"{kind} appends of width {w}: {n} traces"
+                         for w, n in sorted(counts.items())) or (
+                             f"{kind} appends: 0")
+    return (part("fused", fused_append_counts) + "; "
+            + part("scatter", scatter_append_counts))
 
 
 def record_fallback(reason: str):
@@ -54,6 +82,8 @@ def record_fallback(reason: str):
 def reset_dispatch_stats():
     global fast_path_count
     fallback_counts.clear()
+    fused_append_counts.clear()
+    scatter_append_counts.clear()
     _warned.clear()
     fast_path_count = 0
     from flexflow_tpu.kernels import moe

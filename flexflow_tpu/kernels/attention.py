@@ -42,6 +42,18 @@ NEG_INF = -1e30  # finite "minus infinity": keeps online softmax NaN-free
 SUBLANE = 8
 LANE = 128
 
+# The fused append of a RUN of positions (``flash_attend`` ``append_kv`` with
+# more than one position a row) merges and writes back aligned windows of
+# this many stored rows: a whole packed tile of a 16-bit cache (two tiles of
+# a 32-bit one), which is what a vector load or store at a dynamic row of the
+# streamed block needs. Two consecutive windows hold any run of up to 17
+# rows from any start. A run is at most APPEND_RUN_MOST positions: the
+# widest decode step there is (an engine's verify width, two diffusion
+# blocks of four), one sublane of new rows and as many selects a window; a
+# wider step is a prefill chunk, which appends by slot.
+APPEND_WINDOW_ROWS = 16
+APPEND_RUN_MOST = 8
+
 
 def round_up(x: int, m: int) -> int:
     return -(-x // m) * m
@@ -124,31 +136,38 @@ def _rows_kernel(len_ref, rows_ref,        # scalar prefetch: [R] int32 each
 
 
 def _append_kernel(len_ref, appos_ref,     # scalar prefetch: [R] int32 each
-                   q_ref, qp_ref, slopes_ref, knew_ref, vnew_ref, bias_hbm,
-                   k_hbm, v_hbm,
-                   o_ref, ok_hbm, ov_hbm,
-                   acc, m, l, kbuf, vbuf, bbuf, sem, asem,
-                   *, BS: int, causal: bool, has_bias: bool,
-                   has_alibi: bool, qk_scale: float, G: int, Q: int,
-                   layer_idx, PACK: int, D: int):
-    """Decode-step variant: this step's single new token's K/V rows land at
-    cache position ``appos[r]`` IN PLACE (the caches are aliased in/out),
-    fused with the attention stream — replacing the XLA Q=1 row scatter
-    that cost ~1.6 ms/step at 7B geometry (R*KH*L = 16K scalar-unit rows).
-    The new rows are merged into the streamed VMEM block (so attention
-    sees the post-append cache with zero extra latency) and the aligned
-    8-packed-row window containing p is written back asynchronously
-    (Mosaic DMA slices need SUBLANE-aligned second-minor dims): rows
-    [pb, p) re-land bitwise-identical, row p gets the new K/V, rows
-    beyond re-land whatever garbage they held (past ``length``, never
-    attended). Write-backs touch only row r's slice, so they never race
-    the cross-program prefetch of other rows."""
+                   *refs, run: bool = False, **static):
+    """Decode-step variant: this step's new K/V rows land at cache position
+    ``appos[r]`` on IN PLACE (the caches are aliased in/out), fused with the
+    attention stream. The new rows are merged into the streamed VMEM block
+    (so attention sees the post-append cache with zero extra latency) and
+    the aligned window of stored rows that holds them is written back
+    asynchronously (Mosaic DMA slices need SUBLANE-aligned second-minor
+    dims). Write-backs touch only row r's slice, so they never race the
+    cross-program prefetch of other rows.
+
+    One new token a row: replaces the XLA Q=1 row scatter that cost ~1.6
+    ms/step at 7B geometry (R*KH*L = 16K scalar-unit rows). The window is
+    the 8 packed rows around p: rows [pb, p) re-land bitwise-identical, row
+    p gets the new K/V, rows beyond re-land whatever garbage they held
+    (past ``length``, never attended).
+
+    ``run`` (a third scalar-prefetched operand, ``napp_ref`` [R]): a RUN of
+    ``napp[r]`` positions from ``appos[r]``, of the up to A the new rows'
+    operand holds (a block-diffusion pass: R*KH*Q = 1024 scatter rows a
+    cache a layer otherwise). The windows are the one or two of
+    APPEND_WINDOW_ROWS rows that hold ``[appos, appos + napp)``, in this
+    stream block or the next: every stored row outside the run re-lands
+    what it held, bit for bit, the rows of the operand past ``napp``
+    included (the scatter's ``mode="drop"``)."""
+    napp_ref = None
+    if run:
+        napp_ref, *refs = refs
+    (q_ref, qp_ref, slopes_ref, knew_ref, vnew_ref, bias_hbm, _, _, o_ref,
+     ok_hbm, ov_hbm, acc, m, l, kbuf, vbuf, bbuf, sem, asem) = refs
     _stream_attend(len_ref, appos_ref, q_ref, qp_ref, slopes_ref, knew_ref,
                    vnew_ref, bias_hbm, ok_hbm, ov_hbm, o_ref, acc, m, l,
-                   kbuf, vbuf, bbuf, sem, asem, BS=BS, causal=causal,
-                   has_bias=has_bias, has_alibi=has_alibi,
-                   qk_scale=qk_scale, G=G, Q=Q, layer_idx=layer_idx,
-                   PACK=PACK, D=D)
+                   kbuf, vbuf, bbuf, sem, asem, napp_ref=napp_ref, **static)
 
 
 def _window_kernel(len_ref, first_ref, *refs, mode, operand="first_ref",
@@ -183,7 +202,7 @@ def _stream_attend(len_ref, appos_ref, q_ref, qp_ref, slopes_ref, knew_ref,
                    has_alibi: bool, qk_scale: float, G: int, Q: int,
                    layer_idx, PACK: int, D: int, rows_ref=None,
                    first_ref=None, window=None, nsum_ref=None,
-                   summary_rows=None):
+                   summary_rows=None, napp_ref=None):
     """Shared stream-attend body.
 
     PACK == 1: one position per 128-lane cache row (D % 128 == 0).
@@ -206,8 +225,12 @@ def _stream_attend(len_ref, appos_ref, q_ref, qp_ref, slopes_ref, knew_ref,
     second up to ``length`` as ever (``key <= qpos``, ``key < length``, all
     three as rows of the stream); it streams ``ceil(nsum / BS)`` blocks from
     row 0, then the blocks from ``summary_rows`` (whole blocks) on.
+
+    ``napp_ref`` (with ``appos_ref``; a plain cache: none of the above): the
+    append is a run of ``napp[r]`` positions, ``_append_kernel``'s ``run``.
     """
     has_append = appos_ref is not None
+    has_run = napp_ref is not None
     r = pl.program_id(0)
     R = len_ref.shape[0]
     length = len_ref[r]
@@ -303,6 +326,7 @@ def _stream_attend(len_ref, appos_ref, q_ref, qp_ref, slopes_ref, knew_ref,
     qp = qp_ref[r]                                  # [GQ] absolute positions
     if has_append:
         p_app = appos_ref[r]
+    if has_append and not has_run:
         bp = p_app // BS                  # block holding the new position
         p_row = pr = p_app // PACK        # its global packed row
         if first_ref is not None:         # as the stream counts and stores
@@ -315,6 +339,27 @@ def _stream_attend(len_ref, appos_ref, q_ref, qp_ref, slopes_ref, knew_ref,
         if first_ref is None and nsum_ref is None:
             return pr - bp * SB
         return pr % SB
+
+    if has_run:
+        W = APPEND_WINDOW_ROWS
+        n_app = napp_ref[r]               # 0: the row sits out
+        p_end = p_app + n_app
+        w0 = (p_app // W) * W             # the run's first window
+
+    def run_windows(i, slot):
+        """The windows of this row's run that lie in stream block ``i``
+        (a window never straddles a block: whole blocks of whole windows):
+        (is it here, its first row in the cache and in the block, its
+        write-backs)."""
+        for t in range(2):
+            wa = pl.multiple_of(w0 + t * W, W)
+            off = pl.multiple_of(wa - i * BS, W)
+            yield ((n_app > 0) & (wa < p_end) & (wa // BS == i), wa, off, [
+                pltpu.make_async_copy(
+                    buf.at[slot, :, pl.ds(off, W)],
+                    hbm.at[r, :, pl.ds(wa, W)], asem.at[t, c])
+                for c, (buf, hbm) in enumerate(((kbuf, k_hbm),
+                                                (vbuf, v_hbm)))])
 
     def body(i, _):
         slot = (g0 + i) % 2
@@ -329,7 +374,26 @@ def _stream_attend(len_ref, appos_ref, q_ref, qp_ref, slopes_ref, knew_ref,
             start_dmas(row_of(r_next), nxt_slot, src(r_next, 0))
 
         wait_dmas(row_of(r), slot, src(r, i))
-        if has_append:
+        if has_run:
+            # merge the part of the run that this block holds into the
+            # streamed block, a window at a time, and write the window back
+            for here, wa, off, copies in run_windows(i, slot):
+                @pl.when(here)
+                def _():
+                    pos = wa + jax.lax.broadcasted_iota(jnp.int32, (W, D), 0)
+
+                    def put(a, cur):      # the run's a-th position
+                        return tuple(
+                            jnp.where((pos == p_app + a)[None],
+                                      new_ref[0, a][:, None, :], c)
+                            for new_ref, c in zip((knew_ref, vnew_ref), cur))
+
+                    at = (slot, slice(None), pl.ds(off, W), slice(None))
+                    kbuf[at], vbuf[at] = jax.lax.fori_loop(
+                        0, n_app, put, (kbuf[at], vbuf[at]))
+                    for d in copies:
+                        d.start()
+        elif has_append:
             @pl.when(i == bp)
             def _():
                 # merge the new K/V row into the streamed block in VMEM
@@ -412,7 +476,13 @@ def _stream_attend(len_ref, appos_ref, q_ref, qp_ref, slopes_ref, knew_ref,
                 preferred_element_type=jnp.float32)  # [KH, GQ, D|LANE]
             acc[:] = acc[:] * corr + pv
             m[:] = m_new
-        if has_append:
+        if has_run:
+            for here, _, _, copies in run_windows(i, slot):
+                @pl.when(here)
+                def _():                  # as the one-row form below
+                    for d in copies:
+                        d.wait()
+        elif has_append:
             @pl.when(i == bp)
             def _():
                 # the write-back must land before this program ends (the
@@ -459,13 +529,20 @@ def flash_attend(q, k_cache, v_cache, lengths, qpos, bias=None,
     qpos     [R, Q] int32   absolute position of each query token
     bias     [R, Q, S] f32  optional additive mask (tree mask; NEG_INF=hidden)
     alibi    [H] f32        optional ALiBi slopes
-    append_kv  (k_new [R, 1, KH, D], v_new same, appos [R] int32)
-                            decode fused append: write each row's new K/V at
-                            cache position appos[r] (appos < 0 = skip row)
-                            IN PLACE before attending — the caches are
-                            aliased in/out and the call returns
-                            (out, k_cache, v_cache); callers must treat the
-                            passed caches as consumed (donated)
+    append_kv  (k_new [R, A, KH, D], v_new same, appos [R] int32[, n [R]
+                            int32])  decode fused append: write the first
+                            n[r] (all A without ``n``; clipped to the
+                            cache's end) of each row's new K/V at cache
+                            positions appos[r] on (appos < 0 = skip row) IN
+                            PLACE before attending: positions of the run
+                            past n[r] are written nowhere, and ``lengths``
+                            counts those written. The caches are aliased
+                            in/out and the call returns (out, k_cache,
+                            v_cache); callers must treat the passed caches
+                            as consumed (donated). A == 1 on every layout;
+                            a run, 1 < A <= APPEND_RUN_MOST, on a plain
+                            position-major cache (no packed D=64 rows, no
+                            ``window``, no ``summaries``)
     rows     [R] int32      optional row map: batch row r attends cache row
                             rows[r] (the cache may then hold any number of
                             rows, and a row may be named twice); without it
@@ -642,7 +719,20 @@ def flash_attend(q, k_cache, v_cache, lengths, qpos, bias=None,
 
     # fused decode append: write (k_new, v_new) at appos[r] in place, then
     # attend; the caches alias through to the outputs (donation-safe)
-    k_new, v_new, appos = append_kv
+    k_new, v_new, appos, *count = append_kv
+    A = k_new.shape[1]
+    run = []                              # a run's one more prefetched operand
+    if A > 1:
+        assert (PACK == 1 and window is None and summary_rows is None
+                and A <= APPEND_RUN_MOST), (
+            f"a run of {A} positions is fused into a plain position-major "
+            f"cache's stream, up to {APPEND_RUN_MOST}: a packed cache, a "
+            "ring and a chunked stream append one position a row here, and "
+            "their runs in ops/inc_attention")
+        appos = appos.astype(jnp.int32)
+        n = count[0].astype(jnp.int32) if count else A
+        run = [jnp.where(appos >= 0,
+                         jnp.clip(n, 0, jnp.minimum(A, S - appos)), 0)]
     if PACK > 1:
         # the kernel's merge select places the row in lane half p % PACK;
         # tiling the D lanes PACK times gives it the value in every half
@@ -656,18 +746,22 @@ def flash_attend(q, k_cache, v_cache, lengths, qpos, bias=None,
                                  operand="nsum_ref",
                                  summary_rows=summary_rows, **static)
     elif window is None:
-        kern = functools.partial(_append_kernel, **static)
+        kern = functools.partial(_append_kernel, **static,
+                                 **({"run": True} if run else {}))
     else:
         kern = functools.partial(_window_kernel, mode="append",
                                  window=window, **static)
-    knew_spec = pl.BlockSpec((1, 1, KH, DL), lambda r, *_: (r, 0, 0, 0),
+    knew_spec = pl.BlockSpec((1, A, KH, DL), lambda r, *_: (r, 0, 0, 0),
                              memory_space=pltpu.VMEM)
+    n_prefetch = 2 + len(first) + len(run)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2 + len(first), grid=(R,),
+        num_scalar_prefetch=n_prefetch, grid=(R,),
         in_specs=qkv_in_specs + [knew_spec, knew_spec] + tail_in_specs,
         out_specs=(o_spec, pl.BlockSpec(memory_space=pl.ANY),
                    pl.BlockSpec(memory_space=pl.ANY)),
-        scratch_shapes=scratch + [pltpu.SemaphoreType.DMA((2,))])
+        # a write-back's semaphores: (k, v), a window of a run each
+        scratch_shapes=scratch + [
+            pltpu.SemaphoreType.DMA((2, 2) if run else (2,))])
     out, k_out, v_out = pl.pallas_call(
         kern, grid_spec=grid_spec,
         out_shape=(jax.ShapeDtypeStruct(
@@ -675,11 +769,11 @@ def flash_attend(q, k_cache, v_cache, lengths, qpos, bias=None,
                    jax.ShapeDtypeStruct(k_cache.shape, k_cache.dtype),
                    jax.ShapeDtypeStruct(v_cache.shape, v_cache.dtype)),
         # k/v cache operands -> outputs
-        input_output_aliases={8 + len(first): 1, 9 + len(first): 2},
+        input_output_aliases={n_prefetch + 6: 1, n_prefetch + 7: 2},
         compiler_params=compiler_params, cost_estimate=cost_estimate,
         interpret=interpret, **call_name,
-    )(lengths.astype(jnp.int32), *first, appos.astype(jnp.int32), qt, qp_gq,
-      slopes_gq, k_new.astype(cache_dt), v_new.astype(cache_dt),
+    )(lengths.astype(jnp.int32), *first, appos.astype(jnp.int32), *run, qt,
+      qp_gq, slopes_gq, k_new.astype(cache_dt), v_new.astype(cache_dt),
       bias.astype(jnp.float32), k_cache, v_cache)
     return post(out), k_out, v_out
 

@@ -224,14 +224,16 @@ def _attend(attrs, q, k_cache, v_cache, lengths, qpos, out_dtype, ctx,
     ``qpos`` [R, Q] absolute query positions drive causal masking + ALiBi;
     ``bias`` [R, Q, S] is the additive tree mask for verification.
 
-    ``append_kv = (k_new [R, 1, KH, D], v_new same, appos [R])`` fuses the
-    decode-step KV append into the kernel: each row's new K/V rows land at
-    cache position appos[r] (appos < 0 = skip) via in-place DMA before the
+    ``append_kv = (k_new [R, A, KH, D], v_new same, appos [R][, n [R]])``
+    fuses the decode-step KV append into the kernel: the first n[r] (all A
+    without ``n``) of each row's new K/V rows land at cache positions
+    appos[r] on (appos < 0 = skip) via in-place DMA, merged into the
     stream — replacing the XLA row scatter that cost ~1.6 ms/step at 7B
-    (R*KH*L scalar-unit rows). Returns (out, new_k_cache, new_v_cache);
-    the passed caches are consumed (aliased through the kernel). The jnp
-    path performs the same append with the scatter, so semantics are
-    identical everywhere.
+    (R*KH*L scalar-unit rows a position of the run). Returns (out,
+    new_k_cache, new_v_cache); the passed caches are consumed (aliased
+    through the kernel). The jnp path performs the same append with the
+    scatter, so semantics are identical everywhere. A run (A > 1) is the
+    kernel's on a plain cache only (``takes_run``): the caller asks first.
 
     A windowed layer (``attrs["sliding_window"]``) hands over its ring
     (ops/kv_layout.py): the kernel is told the window and the jnp oracle
@@ -270,40 +272,21 @@ def _attend(attrs, q, k_cache, v_cache, lengths, qpos, out_dtype, ctx,
                 appos >= 0, kvl.chunked_window_row(appos, ns, chunked), -1))
     pack = kvl.pack_of(k_cache, S)
     Dp = k_cache.shape[-1] // pack  # cache head dim (128-padded)
-    cfg = ctx.config if ctx is not None else None
-    from flexflow_tpu.kernels.attention import (supports_chunked,
-                                                supports_shapes)
     Q = q.shape[1]
     mesh = getattr(ctx, "mesh", None) if ctx is not None else None
-    seq_deg = (mesh.shape["seq"] if mesh is not None
-               and "seq" in getattr(mesh, "axis_names", ()) else 1)
-    if not ffk.use_pallas(cfg):
-        if pack > 1:                # allocated for a kernel now switched off
-            ffk.record_fallback("packed cache attended off the Pallas path")
-    elif seq_deg > 1 and S % seq_deg == 0:
-        # the cache's S dim lives sharded over the mesh: the kernel streams
-        # one device's HBM, so this plan attends through the jnp
-        # seq_sharded_attend below
-        ffk.record_fallback("cache sharded over the mesh's 'seq' axis")
-    elif not supports_shapes(S, Dp):
-        ffk.record_fallback(f"cache shape S={S} D={Dp} not tileable")
-    elif chunked is not None and not supports_chunked(S, ns, Dp):
-        ffk.record_fallback(f"chunked stream of {ns} + {S - ns} rows, "
-                            f"D={Dp}: the extents are not whole blocks")
-    elif Q > 256:
-        ffk.record_fallback(f"query width {Q} > 256")
-    elif bias is not None and Q % 8 != 0:
-        # biased (tree) attention DMAs [Q, BS] bias blocks; Mosaic needs
-        # the sublane (Q) dim 8-aligned — unaligned tree widths take the
-        # jnp path (MultiSpecEngine pads its tree so this never triggers)
-        ffk.record_fallback(f"tree width {Q} not 8-aligned")
+    seq_deg = _seq_degree(mesh)
+    refusal = _kernel_refusal(attrs, ctx, k_cache, Q, bias)
+    if refusal is not None:
+        if refusal:                 # ("": switched off, nothing to report)
+            ffk.record_fallback(refusal)
     else:
-        ffk.record_fast_path()
+        ffk.record_fast_path(
+            append=None if append_kv is None else append_kv[0].shape[1])
         R, H = q.shape[0], q.shape[2]
         fkv = None
         if append_kv is not None:
-            k_new, v_new, appos = append_kv           # [R, 1, KH, D] each
-            fkv = (_pad_d(k_new, Dp), _pad_d(v_new, Dp), appos)
+            k_new, v_new, *at = append_kv             # [R, A, KH, D] each
+            fkv = (_pad_d(k_new, Dp), _pad_d(v_new, Dp), *at)
         attend = functools.partial(
             flash_attend, causal=causal, qk_scale=scale,
             out_dtype=out_dtype, layer_idx=layer_idx,
@@ -322,10 +305,11 @@ def _attend(attrs, q, k_cache, v_cache, lengths, qpos, out_dtype, ctx,
         return out if append_kv is None else (out,) + caches
     new_caches = ()
     if append_kv is not None:
-        k_new, v_new, appos = append_kv
+        k_new, v_new, appos, *count = append_kv
+        ffk.record_scatter_append(k_new.shape[1])
         valid = appos >= 0
         start = jnp.maximum(appos, 0)
-        num = valid.astype(jnp.int32)
+        num = count[0] if count else valid.astype(jnp.int32)
         kp, vp = _pad_d(k_new, Dp), _pad_d(v_new, Dp)
         ring = window is not None
         if layer_idx is not None:
@@ -365,6 +349,71 @@ def _attend(attrs, q, k_cache, v_cache, lengths, qpos, out_dtype, ctx,
     return out if append_kv is None else (out,) + new_caches
 
 
+def _seq_degree(mesh) -> int:
+    return (mesh.shape["seq"] if mesh is not None
+            and "seq" in getattr(mesh, "axis_names", ()) else 1)
+
+
+def _kernel_refusal(attrs, ctx, k_cache, Q: int, bias=None):
+    """Why ``_attend`` would attend this layer's cache (as stored; a layer
+    or the stack) off the Pallas kernel at query width ``Q``: a reason to
+    record, "" where the kernels are switched off and nothing is amiss, or
+    None: the kernel serves it."""
+    from flexflow_tpu import kernels as ffk
+    from flexflow_tpu.kernels.attention import (supports_chunked,
+                                                supports_shapes)
+
+    S = attrs["max_seq_length"]
+    chunked = attrs.get("eva_window")
+    if attrs.get("sliding_window") is not None or chunked is not None:
+        S = k_cache.shape[-2]       # the ring's rows; both extents' rows
+    pack = kvl.pack_of(k_cache, S)
+    Dp = k_cache.shape[-1] // pack
+    mesh = getattr(ctx, "mesh", None) if ctx is not None else None
+    seq_deg = _seq_degree(mesh)
+    if not ffk.use_pallas(ctx.config if ctx is not None else None):
+        # (a packed cache: allocated for a kernel now switched off)
+        return "packed cache attended off the Pallas path" if pack > 1 else ""
+    if seq_deg > 1 and S % seq_deg == 0:
+        # the cache's S dim lives sharded over the mesh: the kernel streams
+        # one device's HBM, so this plan attends through the jnp
+        # seq_sharded_attend
+        return "cache sharded over the mesh's 'seq' axis"
+    if not supports_shapes(S, Dp):
+        return f"cache shape S={S} D={Dp} not tileable"
+    if chunked is not None:
+        ns = kvl.chunked_summary_rows(attrs["max_seq_length"],
+                                      attrs["chunk_size"])
+        if not supports_chunked(S, ns, Dp):
+            return (f"chunked stream of {ns} + {S - ns} rows, D={Dp}: the "
+                    "extents are not whole blocks")
+    if Q > 256:
+        return f"query width {Q} > 256"
+    if bias is not None and Q % 8 != 0:
+        # biased (tree) attention DMAs [Q, BS] bias blocks; Mosaic needs
+        # the sublane (Q) dim 8-aligned — unaligned tree widths take the
+        # jnp path (MultiSpecEngine pads its tree so this never triggers)
+        return f"tree width {Q} not 8-aligned"
+    return None
+
+
+def takes_run(attrs, ctx, k_cache, Q: int, A: int) -> bool:
+    """Will ``_attend``'s kernel append a run of ``A`` positions a row of a
+    step ``Q`` wide into this layer's cache? A plain position-major cache
+    (no ring, no chunked stream, no packed D=64 rows) on the kernel path,
+    and a run of up to ``kernels.attention.APPEND_RUN_MOST`` = 8 positions:
+    a decode step's (a verify width, two diffusion blocks), which the
+    kernel's two aligned write-back windows of 16 stored rows hold from any
+    start; a wider step is a prefill chunk."""
+    from flexflow_tpu.kernels.attention import APPEND_RUN_MOST
+
+    return (1 < A <= APPEND_RUN_MOST
+            and attrs.get("sliding_window") is None
+            and attrs.get("eva_window") is None
+            and kvl.pack_of(k_cache, attrs["max_seq_length"]) == 1
+            and _kernel_refusal(attrs, ctx, k_cache, Q) is None)
+
+
 def _attend_on_mesh(attend, mesh, q, k_cache, v_cache, lengths, qpos, bias,
                     alibi, append_kv, rows):
     """``flash_attend`` as a manual region over the whole mesh.
@@ -388,7 +437,8 @@ def _attend_on_mesh(attend, mesh, q, k_cache, v_cache, lengths, qpos, bias,
     in_specs = (heads, cache, cache, P(), P(),
                 None if bias is None else P(),
                 None if alibi is None else P(m),
-                None if append_kv is None else (heads, heads, P()),
+                None if append_kv is None else (
+                    (heads, heads) + (P(),) * (len(append_kv) - 2)),
                 None if rows is None else P())
     return jax.shard_map(
         attend, mesh=mesh, in_specs=in_specs,
@@ -734,6 +784,15 @@ _append_by_slot = jax.jit(append_kv_contiguous,
                           static_argnames=("pack", "ring"))
 
 
+def _note_scatter(new, pack: int):
+    """Count a step's row-granular scatter append (a packed cache has none:
+    its append is the contiguous one)."""
+    if pack == 1:
+        from flexflow_tpu import kernels as ffk
+
+        ffk.record_scatter_append(new.shape[1])
+
+
 def append_and_ref(ctx, attrs, k, v, start_pos, num_tokens, active,
                    slots=None):
     """Append this step's KV and return (k_ref, v_ref, layer_idx) to attend
@@ -748,12 +807,16 @@ def append_and_ref(ctx, attrs, k, v, start_pos, num_tokens, active,
     slots[r] by the exact in-place append of append_kv_contiguous, so a
     prefill step neither scatters nor slices a layer of the stack out.
 
-    The row-granular stacked path is chosen whenever its scalar-unit cost
+    A decode step's short run on a plain cache does not come here where the
+    Pallas path is taken: the attention kernel appends it itself
+    (IncMultiHeadSelfAttention.forward, ``takes_run``). Of what does, the
+    row-granular stacked path is chosen whenever its scalar-unit cost
     (~R*KH*Q index rows) beats the per-layer slice-out/write-back HBM
-    round trip of the windowed path: always for decode (Q == 1), and for
-    wider steps (prefill chunks, tree verify) once the per-layer cache
-    slice is large — at 7B geometry the slice traffic is ~134MB per layer
-    per step and dominated the whole speculation round."""
+    round trip of the windowed path: for one position a row (a tree of one
+    node), a block-diffusion pass off the kernel path, and for wider steps
+    (prefill chunks, tree verify) once the per-layer cache slice is large —
+    at 7B geometry the slice traffic is ~134MB per layer per step and
+    dominated the whole speculation round."""
     ov = getattr(ctx, "kv_override", None)
     idx = attrs.get("cache_layer_idx")
     contiguous = getattr(ctx, "kv_contiguous", False)
@@ -790,6 +853,7 @@ def append_and_ref(ctx, attrs, k, v, start_pos, num_tokens, active,
             vc = append_kv_contiguous(v0, None, v, start_pos, active,
                                       pack=pack)
         else:
+            _note_scatter(k, pack)
             kc = append_kv(k0, k, start_pos, num_tokens, active, pack, ring)
             vc = append_kv(v0, v, start_pos, num_tokens, active, pack, ring)
         write_kv(ctx, attrs, kc, vc)
@@ -817,8 +881,10 @@ def append_and_ref(ctx, attrs, k, v, start_pos, num_tokens, active,
     elif (k.shape[1] == 1 or pack > 1 or ring or chunked
           or "block_length" in attrs):
         # (a packed stack and a ring take every width in place:
-        # append_kv_stacked; so does a block-diffusion layer's pass, a
-        # block's few rows a (request, head) in every decode step)
+        # append_kv_stacked; so does a block-diffusion layer's pass where
+        # the attention kernel does not append it itself, off the Pallas
+        # path: a block's few rows a (request, head), the same cache bits)
+        _note_scatter(k, pack)
         ks = append_kv_stacked(st["k"], idx, k, start_pos, num_tokens,
                                active, pack, ring=ring)
         vs = append_kv_stacked(st["v"], idx, v, start_pos, num_tokens,
@@ -827,6 +893,7 @@ def append_and_ref(ctx, attrs, k, v, start_pos, num_tokens, active,
         # host-stepped wide appends (prefill chunks, host tree verify):
         # drop-exact windowed scatter on the per-layer slice — paid once
         # per prefill, not per speculation round
+        _note_scatter(k, pack)
         kc = append_kv(st["k"][idx], k, start_pos, num_tokens, active)
         vc = append_kv(st["v"][idx], v, start_pos, num_tokens, active)
         ks = st["k"].at[idx].set(kc)
@@ -961,25 +1028,39 @@ class IncMultiHeadSelfAttention(OpImpl):
         append_q = getattr(ctx, "kv_append_q", None)
         eff_q = append_q if (append_q is not None and Q > append_q) else Q
         slots = meta.slots
-        if (eff_q == 1 and slots is None
-                and getattr(ctx, "kv_override", None) is None):
-            # single new real token per row (decode; verify-consistent
-            # wide decode has 1 real + padding tokens): fuse the KV append
-            # into the attention kernel instead of an XLA row scatter
-            idx = attrs.get("cache_layer_idx")
+        idx = attrs.get("cache_layer_idx")
+        fused = False
+        if slots is None and getattr(ctx, "kv_override", None) is None:
             if idx is None:
                 st = ctx.state_in[ctx.layer_name]
                 k0, v0 = st["k_cache"], st["v_cache"]
             else:          # full stacked [L, R, KH, S, D] buffers
                 key, st = _stack(ctx, attrs)
                 k0, v0 = st["k"], st["v"]
+            # a fused engine's wide step (``kv_contiguous``) promises
+            # start_pos + Q <= S and writes its padding: another contract,
+            # and its own append (append_kv_contiguous)
+            fused = eff_q == 1 or (
+                not getattr(ctx, "kv_contiguous", False)
+                and takes_run(attrs, ctx, k0, Q, eff_q))
+        if fused:
+            # a short run of new real tokens per row (decode: one; verify-
+            # consistent wide decode: 1 real + padding tokens; a block-
+            # diffusion pass: a block or two, of which ``num_tokens`` are
+            # real): fuse the KV append into the attention kernel instead
+            # of an XLA row scatter. Off the kernel path only a run of one
+            # comes here: ``_attend`` then scatters it, as append_and_ref
+            # does a wider one.
             S = attrs["max_seq_length"]
             appos = jnp.where(
                 meta.active & (meta.num_tokens > 0) & (meta.start_pos < S),
                 meta.start_pos, -1)
+            run = (k[:, :eff_q], v[:, :eff_q], appos)
+            if eff_q > 1:
+                run += (jnp.minimum(meta.num_tokens, eff_q),)
             out, knew, vnew = _attend(
                 attrs, q, k0, v0, lengths, q_abs, x.dtype, ctx, causal=True,
-                layer_idx=idx, append_kv=(k[:, :1], v[:, :1], appos))
+                layer_idx=idx, append_kv=run)
             if attrs.get("eva_window") is not None:
                 knew, vnew = summarise_decode(
                     attrs, params, knew, vnew, idx, meta.start_pos,

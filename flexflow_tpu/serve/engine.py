@@ -325,9 +325,12 @@ def _diffusion_block(model, compute_dtype, max_steps: int, bd):
 
     Every pass is ONE forward of ``[R, 2B]`` at the true positions, masked
     positions holding the mask token, with the keys and values of every
-    real token written at its position before any row attends (one
-    row-granular scatter a layer's cache, in place:
-    inc_attention.append_and_ref). A row fills its block by DENOISE passes:
+    real token written at its position before the row attends: by the
+    attention kernel itself, a run of B or 2B positions a row merged into
+    the block it streams and written back in place
+    (kernels/attention.flash_attend ``append_kv``; off the kernel path one
+    row-granular scatter a layer's cache, inc_attention.append_and_ref: the
+    same cache bits). A row fills its block by DENOISE passes:
     B real tokens at ``[pos, pos + B)`` (``num_tokens``: the rest of the row
     is routed to no expert, stored nowhere and counted by nothing), which
     see the cache and the block itself both ways

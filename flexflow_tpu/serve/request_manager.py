@@ -488,7 +488,9 @@ class RequestManager:
             return r.cancel_requested or (r.deadline_s
                                           and now >= r.deadline_s)
 
-        if any(expired(r) for r in self.pending):
+        # (a snapshot: the front door appends from its own thread, and a
+        # deque refuses to be iterated while it grows)
+        if any(expired(r) for r in list(self.pending)):
             for _ in range(len(self.pending)):
                 req = self.pending.popleft()
                 if expired(req):

@@ -62,6 +62,26 @@ def test_flash_attend_exports_for_tpu_at_smoke_shapes():
         sds((4, chunk), i32), sds((4,), i32))
 
 
+def test_flash_attend_run_append_exports_for_tpu_at_sdars_pass():
+    """The fused append of a run at SDAR-30B-A3B's pass: 32 rows x 8
+    positions (two blocks of four, a count a row), 32 query and 4 key/value
+    heads of 128 over the bfloat16 stack of 1024 positions (2 layers keep
+    the lowering light)."""
+    from flexflow_tpu.kernels.attention import flash_attend
+
+    L, R, H, KH, S, D, W = 2, 32, 32, 4, 1024, 128, 8
+    bf, i32 = jnp.bfloat16, jnp.int32
+    sds = jax.ShapeDtypeStruct
+    stack = sds((L, R, KH, S, D), bf)
+    _export_tpu(
+        lambda q, k, v, n, qp, kn, vn, ap, cnt: flash_attend(
+            q, k, v, n, qp, append_kv=(kn, vn, ap, cnt), causal=True,
+            layer_idx=1),
+        sds((R, W, H, D), bf), stack, stack, sds((R,), i32),
+        sds((R, W), i32), sds((R, W, KH, D), bf), sds((R, W, KH, D), bf),
+        sds((R,), i32), sds((R,), i32))
+
+
 # (tokens, experts held, router width or None, hidden, expert width, top-k)
 MOE_STEPS = {
     "decode_32_rows": (32, 64, None, 2048, 1024, 8),

@@ -336,6 +336,111 @@ def test_flash_fused_append_stacked_layer():
                                       k_new[r, 0])
 
 
+# A run of positions a row (a block-diffusion pass: two blocks of four, of
+# which ``n`` are real), S = 256 so a stream block is 128 positions.
+#  name: per row (appos, n): n = None: the whole width A; appos < 0: idle
+RUN_CASES = {
+    "starts": [(0, None), (4, None), (8, None), (12, None)],
+    "two_windows": [(12, None), (28, None), (-1, None), (44, None)],
+    "crosses_block": [(124, None), (120, None), (124, 4), (116, None)],
+    "caches_end": [(256 - 8, None), (256 - 4, 4), (256 - 8, 4), (256 - 4,
+                                                                 None)],
+    "real_half": [(0, 4), (4, 4), (8, 4), (124, 4)],
+    "rows_sit_out": [(36, None), (-1, None), (52, 0), (60, 4)],
+}
+
+
+@pytest.mark.parametrize("stacked", [False, True],
+                         ids=["layer", "stack"])
+@pytest.mark.parametrize("A", [4, 8])
+@pytest.mark.parametrize("case", sorted(RUN_CASES))
+def test_flash_fused_append_of_a_run(case, A, stacked):
+    """The fused append of a RUN of up to A positions a row
+    (``append_kv`` with ``k_new [R, A, KH, D]`` and a count): the output is
+    scatter-then-attend's under the block mask, and the returned caches
+    EQUAL ``append_kv_stacked``'s bit for bit: positions of the run past the
+    count, past the cache's end, a row that sits out and every row outside
+    the run keep what they held."""
+    from flexflow_tpu.ops.inc_attention import append_kv_stacked
+
+    R, Q, KH, G, D, S, L, B = 4, 8, 2, 2, 128, 256, 3, 4
+    rng = np.random.RandomState(23)
+    q = jnp.asarray(rng.randn(R, Q, KH * G, D).astype(np.float32))
+    ks = jnp.asarray(rng.randn(L, R, KH, S, D).astype(np.float32))
+    vs = jnp.asarray(rng.randn(L, R, KH, S, D).astype(np.float32))
+    k_new = jnp.asarray(rng.randn(R, A, KH, D).astype(np.float32))
+    v_new = jnp.asarray(rng.randn(R, A, KH, D).astype(np.float32))
+    appos = jnp.asarray([p for p, _ in RUN_CASES[case]], jnp.int32)
+    n = jnp.asarray([A if c is None else min(c, A)
+                     for _, c in RUN_CASES[case]], jnp.int32)
+    live = appos >= 0
+    start = jnp.maximum(appos, 0)
+    # what lands: the scatter drops what lies past the cache's end
+    landed = jnp.where(live, jnp.minimum(n, S - start), 0)
+    lengths = start * live + landed
+    qpos = (start[:, None] + jnp.arange(Q)[None]) // B * B + B - 1
+    k_ref = append_kv_stacked(ks, 1, k_new, start, n, live)
+    v_ref = append_kv_stacked(vs, 1, v_new, start, n, live)
+    ref = reference_attend(q, k_ref[1], v_ref[1], lengths, qpos)
+    if stacked:
+        out, k_out, v_out = fa.flash_attend(
+            q, ks, vs, lengths, qpos, append_kv=(k_new, v_new, appos, n),
+            layer_idx=1, interpret=True)
+    else:
+        out, k_out, v_out = fa.flash_attend(
+            q, ks[1], vs[1], lengths, qpos,
+            append_kv=(k_new, v_new, appos, n), interpret=True)
+        k_ref, v_ref = k_ref[1], v_ref[1]
+    _cmp(ref, out, lengths, 2e-5)
+    np.testing.assert_array_equal(np.asarray(k_out), np.asarray(k_ref))
+    np.testing.assert_array_equal(np.asarray(v_out), np.asarray(v_ref))
+    assert (np.asarray(k_out) != np.asarray(ks if stacked else ks[1])).any() \
+        == bool(landed.any())
+
+
+def test_flash_fused_append_of_a_run_without_a_count_in_bfloat16():
+    """No count: the whole width lands (up to the cache's end). In the
+    cell's dtype, where a write-back window is one packed tile."""
+    from flexflow_tpu.ops.inc_attention import append_kv_stacked
+
+    R, Q, A, KH, D, S = 3, 8, 8, 2, 128, 256
+    q, k, v = _mk(R, Q, 2 * KH, KH, D, S, dtype=jnp.bfloat16, seed=29)
+    rng = np.random.RandomState(31)
+    k_new = jnp.asarray(rng.randn(R, A, KH, D), jnp.bfloat16)
+    v_new = jnp.asarray(rng.randn(R, A, KH, D), jnp.bfloat16)
+    appos = jnp.asarray([124, S - 4, 60], jnp.int32)
+    lengths = jnp.minimum(appos + A, S)
+    qpos = (appos[:, None] + jnp.arange(Q)[None]) // 4 * 4 + 3
+    full = jnp.full((R,), A, jnp.int32)
+    k_ref = append_kv_stacked(k[None], 0, k_new, appos, full, appos >= 0)[0]
+    v_ref = append_kv_stacked(v[None], 0, v_new, appos, full, appos >= 0)[0]
+    out, k_out, v_out = fa.flash_attend(
+        q, k, v, lengths, qpos, append_kv=(k_new, v_new, appos),
+        interpret=True)
+    _cmp(reference_attend(q, k_ref, v_ref, lengths, qpos), out, lengths, 3e-2)
+    np.testing.assert_array_equal(np.asarray(k_out, np.float32),
+                                  np.asarray(k_ref, np.float32))
+    np.testing.assert_array_equal(np.asarray(v_out, np.float32),
+                                  np.asarray(v_ref, np.float32))
+
+
+@pytest.mark.parametrize("layout", ["packed_d64", "ring", "chunked", "wide"])
+def test_flash_fused_append_of_a_run_is_a_plain_caches(layout):
+    """A packed D=64 cache, a ring and a chunked stream keep the appends
+    they have, and a run is at most APPEND_RUN_MOST positions: the kernel
+    says so in one assertion."""
+    D = 64 if layout == "packed_d64" else 128
+    A = fa.APPEND_RUN_MOST + 1 if layout == "wide" else 4
+    q, k, v = _mk(2, 8, 4, 2, D, 256)
+    new = jnp.zeros((2, A, 2, D), jnp.float32)
+    at = jnp.zeros((2,), jnp.int32)
+    kw = {"ring": dict(window=16),
+          "chunked": dict(summaries=at, summary_rows=128)}.get(layout, {})
+    with pytest.raises(AssertionError, match="plain position-major"):
+        flash_attend(q, k, v, at + 4, jnp.zeros((2, 8), jnp.int32),
+                     append_kv=(new, new, at), interpret=True, **kw)
+
+
 #  name: (query heads a key/value head, key/value heads, head dim, ring rows)
 ONE_TOKEN_CASES = {
     "mha_d128": (1, 4, 128, None),          # OLMoE, OPT's verifier
@@ -384,6 +489,82 @@ def test_flash_fused_append_at_one_token_a_row(case):
     # what a row held below its new position is what it holds now
     np.testing.assert_array_equal(k_out[1, :, :300 % rows],
                                   k[1, :, :300 % rows])
+
+
+#  name: (head dim, attrs beside the plain layer's, positions)
+APPEND_LAYOUTS = {
+    "plain": (128, {}, 256),
+    "packed_d64": (64, {}, 256),
+    "ring": (128, {"sliding_window": 16, "max_step_tokens": 16}, 256),
+    "chunked": (128, {"eva_window": 128, "chunk_size": 16}, 2048),
+}
+
+
+@pytest.mark.parametrize("Q", [1, 4])
+@pytest.mark.parametrize("layout", sorted(APPEND_LAYOUTS))
+def test_forward_fuses_the_appends_the_kernel_takes(layout, Q, monkeypatch):
+    """``IncMultiHeadSelfAttention.forward`` on the kernel path
+    (interpreted): one new position a row is appended by the attention
+    kernel on every layout, as ever; a run of four by the kernel on a plain
+    cache and by ``append_and_ref`` (the appends they had) on a packed
+    cache, a ring and a chunked stream; and a fused engine's wide step
+    (``kv_contiguous``) keeps its own append on a plain cache too."""
+    import flexflow_tpu as ff
+    import flexflow_tpu.kernels as ffk
+    from flexflow_tpu.ops import inc_attention as ia
+    from flexflow_tpu.ops.base import OpContext
+    from flexflow_tpu.serve.batch_config import BatchMeta
+
+    monkeypatch.setenv("FF_PALLAS_INTERPRET", "1")
+    D, more, S = APPEND_LAYOUTS[layout]
+    R, H, KH, E = 2, 4, 2, 64
+    attrs = dict(num_q_heads=H, num_kv_heads=KH, head_dim=D, embed_dim=E,
+                 max_requests=R, max_seq_length=S, cache_dtype="float32",
+                 **more)
+    rng = np.random.RandomState(41)
+    params = {w: jnp.asarray(rng.randn(*shape).astype(np.float32) * 0.1)
+              for w, shape in (("wq", (E, H * D)), ("wk", (E, KH * D)),
+                               ("wv", (E, KH * D)), ("wo", (H * D, E)),
+                               ("adaptive_mu_k", (KH, D)),
+                               ("adaptive_phi", (KH, D)))}
+    x = jnp.asarray(rng.randn(R, Q, E).astype(np.float32))
+    start = jnp.asarray([16, 48], jnp.int32)
+    meta = BatchMeta(tokens=jnp.zeros((R, Q), jnp.int32),
+                     positions=start[:, None] + jnp.arange(Q)[None],
+                     start_pos=start, num_tokens=jnp.full((R,), Q, jnp.int32),
+                     active=jnp.ones((R,), bool))
+    sent = []
+    real = ia.append_and_ref
+    monkeypatch.setattr(ia, "append_and_ref",
+                        lambda *a, **k: sent.append(a[2].shape[1])
+                        or real(*a, **k))
+
+    def forward(**flags):
+        ctx = OpContext(layer_name="attn", compute_dtype=jnp.float32,
+                        batch_config=meta, config=ff.FFConfig(num_devices=1),
+                        state_in={"attn": ia._init_kv_state(attrs, None)})
+        for flag, value in flags.items():
+            setattr(ctx, flag, value)
+        del sent[:]
+        ffk.reset_dispatch_stats()
+        (out,) = ia.IncMultiHeadSelfAttention.forward(attrs, params, [x], ctx)
+        assert out.shape == (R, Q, E) and np.isfinite(np.asarray(out)).all()
+        assert not ffk.fallback_counts, ffk.fallback_counts
+        return ctx.state_out["attn"]["k_cache"]
+
+    cache = forward()
+    assert np.asarray(cache).any()
+    if Q == 1 or layout == "plain":
+        assert not sent and ffk.fused_append_counts == {Q: 1}
+        assert not ffk.scatter_append_counts
+    else:   # (a chunked step of 4 < 16 positions pools no whole chunk)
+        assert sent == [Q] and not ffk.fused_append_counts
+        assert ffk.scatter_append_counts == (
+            {} if layout == "packed_d64" else {Q: 1})
+    if Q > 1 and layout == "plain":
+        np.testing.assert_array_equal(
+            np.asarray(forward(kv_contiguous=True)), np.asarray(cache))
+        assert sent == [Q] and not ffk.fused_append_counts
 
 
 @pytest.mark.parametrize("config", [

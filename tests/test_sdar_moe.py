@@ -382,6 +382,40 @@ def test_wide_pass_is_the_two_passes_it_replaces(bench, served, stale):
     assert np.asarray(kv(st_one, 1, 8, 12)).any()
 
 
+def test_passes_through_the_kernels_append_are_the_scatters(monkeypatch):
+    """Decode blocks of passes (rows out of phase; two answers cross
+    position 128, where the kernel's stream block ends) with the attention
+    kernel on (interpreted): the kernel appends each pass's run of one or
+    two blocks itself, in every layer, and no pass scatters; off the kernel
+    path ``append_kv_stacked`` does. The same tokens and the same cache."""
+    from flexflow_tpu import kernels as ffk
+
+    rng = np.random.default_rng(19)
+    prompts = [rng.integers(1, 255, size=n).tolist() for n in (96, 9, 110, 3)]
+    news = (30, 22, 18, 27)
+
+    def served_with():
+        ffk.reset_dispatch_stats()
+        m, _ = _build(max_sequence_length=256)
+        got = _serve(m, prompts, news)
+        return (got, dict(ffk.fused_append_counts),
+                dict(ffk.scatter_append_counts),
+                {x: np.asarray(m.op_state["kv_cache"][x])[..., :32]
+                 for x in "kv"})
+
+    want, fused, scattered, cache = served_with()
+    assert not fused and scattered == {8: 2}        # a trace a layer
+    for key in ("JAX_PLATFORMS",):
+        monkeypatch.setenv(key, os.environ.get(key, ""))
+    monkeypatch.setenv("FF_PALLAS_INTERPRET", "1")
+    got, fused, scattered, kcache = served_with()
+    assert got == want and [len(g) for g in got] == list(news)
+    assert fused == {8: 2} and not scattered and not ffk.fallback_counts
+    for x in "kv":
+        assert np.abs(cache[x]).max() > 0.1
+        np.testing.assert_allclose(kcache[x], cache[x], atol=1e-4, rtol=1e-4)
+
+
 def test_check_folded_commit_tool_rehearses(monkeypatch, capsys):
     """tools/check_folded_commit.py (the on-chip check of the decode
     block's wide pass against the plain reference, which the benchmark's
@@ -396,6 +430,9 @@ def test_check_folded_commit_tool_rehearses(monkeypatch, capsys):
     res = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert res["ok"] and res["routes_ok"] and res["wide_rel_l2"] < res["tol"]
     assert res["stored_rel_l2"] < res["tol_cache"]
+    # the wide pass's run rides in the attention kernel, a trace a layer
+    assert "fused appends of width 8: 2 traces" in res["appends"]
+    assert "scatter appends of width 8" not in res["appends"]
 
 
 # ---------------------------------------------------------------------------

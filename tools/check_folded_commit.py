@@ -25,7 +25,11 @@ carried, its B real tokens first. Compared:
   values the two ways leave at the whole block's positions in every layer
   (two programs that tile their gemms by their own batch: bfloat16 rounding,
   ``TOL_CACHE`` as tools/check_compact_prefill.py's);
-* slot 1's cache past its B real tokens: not written.
+* slot 1's cache past its B real tokens: not written;
+* how the passes' keys and values reached the cache
+  (``flexflow_tpu.kernels.append_summary``): the wide pass's run of 2B
+  positions a row inside the attention kernel, a trace a layer, and no
+  row-granular scatter of that width.
 
 Prints one JSON line; exit 1 if any of it fails. ``--rehearse``: CPU, the
 configuration's tiny rehearsal sizes, interpreted kernels (tier-1 runs it).
@@ -60,6 +64,7 @@ def check(rehearse: bool) -> dict:
 
     from benchmark import run as bench_run
     from benchmark.families import _common as C
+    from flexflow_tpu import kernels as ffk
     from flexflow_tpu.ffconst import InferenceMode, OpType
     from flexflow_tpu.models.sdar_moe import create_sdar_moe_model
     from flexflow_tpu.serve.batch_config import BatchMeta
@@ -74,6 +79,7 @@ def check(rehearse: bool) -> dict:
     family = bench_run.load_module("families", cfg["family"])
     reference = bench_run.load_module("reference", cfg["family"])
     layers = family.REFERENCE_LAYERS
+    ffk.reset_dispatch_stats()
     m = C.build_model(C.ffconfig(cfg, False, max_requests_per_batch=2),
                       create_sdar_moe_model, family._model_cfg(cfg, layers),
                       InferenceMode.INC_DECODING_MODE)
@@ -178,11 +184,17 @@ def check(rehearse: bool) -> dict:
         "stored_max_abs": float(np.abs(
             rows(st_one, 0, chunk, chunk + B)).max()),
         "written_past_real_tokens": beyond,
+        "attention": {"fast_path_traces": ffk.fast_path_count,
+                      "fallback_traces": dict(ffk.fallback_counts)},
+        "appends": ffk.append_summary(),
         "device": jax.devices()[0].device_kind})
     out["ok"] = bool(
         out["routes_ok"] and out["wide_rel_l2"] < tol
         and out["wide_uncarried_rel_l2"] < tol and stored < TOL_CACHE
-        and out["stored_max_abs"] > 0 and beyond == 0.0)
+        and out["stored_max_abs"] > 0 and beyond == 0.0
+        and not ffk.fallback_counts
+        and ffk.fused_append_counts.get(2 * B) == layers
+        and 2 * B not in ffk.scatter_append_counts)
     return out
 
 
