@@ -60,9 +60,9 @@ SMALL = "--small" in sys.argv
 # --smoke / FF_TPU_BENCH_SMOKE=1: CI-sized geometry so the whole bench
 # path (build, warmup, gates, timing, JSON line) runs in minutes on CPU
 SMOKE = "--smoke" in sys.argv or os.environ.get("FF_TPU_BENCH_SMOKE") == "1"
-# --multi-ssm: draft with TWO truncations (2- and 3-layer) through the
-# fused MultiSpecEngine tree path instead of the single-SSM chain engine —
-# the reference's multi-SSM SpecInfer configuration
+# --multi-ssm: draft with TWO truncations (2- and 3-layer) instead of one
+# through the fused MultiSpecEngine tree path — the reference's multi-SSM
+# SpecInfer configuration
 MULTI = "--multi-ssm" in sys.argv
 # --static-spec: disable the adaptive speculation controller
 # (serve/spec_controller.py) for A/B debugging — the DEFAULT is adaptive,
@@ -302,11 +302,11 @@ class AcceptanceMeter:
         self.n_acc = []
 
     def install(self):
-        from flexflow_tpu.serve.engine import MultiSpecEngine, SpecChainEngine
+        from flexflow_tpu.serve.engine import MultiSpecEngine
 
         meter = self
         origs = []
-        for cls in (MultiSpecEngine, SpecChainEngine):
+        for cls in (MultiSpecEngine,):
             orig = cls.run_block
 
             def patched(eng, *args, _orig=orig, **kw):
@@ -885,7 +885,7 @@ def main():
     # Pre-compile the block + prefill programs via short warm runs. Cache
     # garbage from these dummy calls is harmless: every request re-prefills
     # from position 0.
-    from flexflow_tpu.serve.engine import MultiSpecEngine, SpecChainEngine
+    from flexflow_tpu.serve.engine import MultiSpecEngine
     from flexflow_tpu.serve.inference_manager import InferenceManager
 
     llm._inference_manager = ifm = InferenceManager(llm)
@@ -894,16 +894,11 @@ def main():
     tok0 = np.zeros((NUM_REQUESTS,), np.int32)
     pos0 = np.zeros((NUM_REQUESTS,), np.int32)
     act0 = np.ones((NUM_REQUESTS,), bool)
-    # warm whichever engine generate_spec_infer will dispatch to (the
-    # fused tree engine on TPU / multi-SSM; the chain engine off-TPU)
+    # warm the engine generate_spec_infer will dispatch to
     import flexflow_tpu.kernels as ffk
 
-    if MULTI or ffk.use_pallas(llm.config):
-        llm._multi_engine = eng = MultiSpecEngine(llm, ssms, SPEC_DEPTH,
-                                                  max_rounds=SPEC_ROUNDS)
-    else:
-        llm._chain_engine = eng = SpecChainEngine(llm, ssms[0], SPEC_DEPTH,
-                                                  max_rounds=SPEC_ROUNDS)
+    llm._multi_engine = eng = MultiSpecEngine(llm, ssms, SPEC_DEPTH,
+                                              max_rounds=SPEC_ROUNDS)
     # the model is served both ways and the tokens compared: its manager
     # decodes at the engine's verify width from the first block on
     ifm.verified_at(eng.tree_width)
@@ -911,12 +906,9 @@ def main():
     def warmup():
         # one compile each: the block programs take a dynamic trip count
         ifm.decode_block(tok0, pos0, act0, 1)
-        if isinstance(eng, MultiSpecEngine):
-            # the one-token accepted block: (tks, nblk, base)
-            eng.run_block(np.zeros((NUM_REQUESTS, SPEC_DEPTH + 1), np.int32),
-                          np.ones_like(pos0), pos0, act0, 1)
-        else:
-            eng.run_block(tok0, pos0, act0, 1)
+        # the one-token accepted block: (tks, nblk, base)
+        eng.run_block(np.zeros((NUM_REQUESTS, SPEC_DEPTH + 1), np.int32),
+                      np.ones_like(pos0), pos0, act0, 1)
         run_requests(lambda rm: rm.generate_incr_decoding(llm), warm, 4)
         run_requests(lambda rm: rm.generate_spec_infer(
             llm, ssms, spec_depth=SPEC_DEPTH, generation_config=gen_cfg()),

@@ -12,15 +12,18 @@ asked for. The host scheduler reconciles EOS/length truncation after
 reading each block — overshoot work is bounded and the KV caches self-heal
 because positions are recomputed from host state at every call.
 
-Two engines:
+What runs fused:
 * ``decode_block`` (on InferenceManager): n greedy/sampled decode steps per
   call for incremental decoding.
-* ``SpecChainEngine``: the MAX_BEAM_WIDTH=1 speculation path (the reference
-  default, batch_config.h:125) fully fused — draft-chain scan + tree(chain)
-  verification + acceptance + implicit KV commit per round. A chain needs
-  no KV compaction at all: accepted nodes are already contiguous in both
-  caches (the reference needs commit_tokens_kernel only for branchy trees;
-  that path remains in request_manager for multi-SSM).
+* ``MultiSpecEngine``: one greedy chain a draft model, verified as one
+  tree of unmerged branches (one draft: the MAX_BEAM_WIDTH=1 reference
+  default, batch_config.h:125; a single branch needs no KV compaction,
+  its accepted nodes are already contiguous in both caches).
+* ``BeamSpecEngine``: one draft model's beam tree at width > 1.
+
+Both speculation engines drive their rounds through ``_block_impl`` and
+are driven through ``run_block`` below, which states the one contract
+``RequestManager._generate_spec_fused`` reads.
 """
 
 from __future__ import annotations
@@ -141,7 +144,7 @@ def _forward_tokens(model, params, state, tokens, positions, start_pos,
 
 def _adapt_depth_rule(adapt, act_i, n_acc, depth_v, alive, min_depth,
                       max_depth):
-    """Adaptive-mode in-block policy shared by the three fused engines'
+    """Adaptive-mode in-block policy of the fused engines'
     while_loop bodies (a no-op when the host ran the block statically):
 
     * depth adaptation between rounds — grow on a full accept, shrink on
@@ -418,6 +421,160 @@ def _diffusion_block(model, compute_dtype, max_steps: int, bd):
     return block
 
 
+def _block_impl(self, llm_params, llm_state, *rest):
+    """The jitted block of either speculation engine: up to ``n_rounds``
+    of ``self._round`` in one while_loop, each row carried from round to
+    round by its accepted block (tks, nblk, base)."""
+    self._trace_count += 1          # python body == one XLA trace
+    B = len(self.ssms)
+    ssm_ps = [rest[2 * i] for i in range(B)]
+    ssm_states = [rest[2 * i + 1] for i in range(B)]
+    (tks0, nblk0, base0, active, n_rounds, remaining, depth0, min_depth,
+     adaptive) = rest[2 * B:]
+    R = tks0.shape[0]
+    d = self.depth
+    max_seq = self.llm.config.max_sequence_length
+    rng0 = jax.random.fold_in(self._rng_const,
+                              (base0 + nblk0 - 1).sum())
+    # packed [R, max_rounds, d+3]: chain ++ bonus ++ n_acc ++ depth
+    packed0 = jnp.full((R, self.max_rounds, d + 3), 0, jnp.int32)
+    packed0 = packed0.at[:, :, d + 1].set(-1)
+    packed0 = packed0.at[:, :, d + 2].set(-1)
+    # a call starts where a round inside it starts: from each row's
+    # accepted block (tks0, nblk0, base0), handed over by the host, so
+    # round 0's width-(d+1) draft step is the catch-up over the LAST
+    # call's final round too (see run_block)
+    adapt = adaptive > 0
+
+    Tp = self.tree_width
+
+    def live_mask(base, nblk, remaining):
+        r_pos = base + nblk - 1
+        # reserve the PADDED verify width: the contiguous KV append
+        # writes the whole [r_pos, r_pos + Tp) staging window
+        return ((remaining > 0) & (r_pos + Tp <= max_seq - 1))
+
+    def cond(carry):
+        (i, _ls, _ss, _tks, nblk, base, remaining, act, _d, alive,
+         _p) = carry
+        return (i < n_rounds) & jnp.any(
+            act & live_mask(base, nblk, remaining) & alive)
+
+    def body(carry):
+        (i, llm_state, ssm_states, tks, nblk, base, remaining, act,
+         depth_v, alive, packed) = carry
+        act_i = act & live_mask(base, nblk, remaining) & alive
+        (llm_state, ssm_states, blk, new_nblk, new_base, chain, n_acc,
+         bonus) = self._round(
+            llm_params, llm_state, ssm_ps, list(ssm_states), tks, nblk,
+            base, act_i, jax.random.fold_in(rng0, i), depth_v)
+        tks = jnp.where(act_i[:, None], blk, tks)
+        nblk = jnp.where(act_i, new_nblk, nblk)
+        base = jnp.where(act_i, new_base, base)
+        remaining = remaining - jnp.where(act_i, n_acc + 1, 0)
+        row = jnp.concatenate(
+            [chain, bonus[:, None],
+             jnp.where(act_i, n_acc, -1)[:, None],
+             jnp.where(act_i, depth_v, -1)[:, None]], axis=1)
+        packed = jax.lax.dynamic_update_slice(
+            packed, row[:, None, :], (0, i, 0))
+        depth_v, alive = _adapt_depth_rule(adapt, act_i, n_acc,
+                                           depth_v, alive, min_depth,
+                                           d)
+        return (i + 1, llm_state, tuple(ssm_states), tks, nblk, base,
+                remaining, act, depth_v, alive, packed)
+
+    (_, llm_state, ssm_states, _, _, _, _, _, _, _, packed) = \
+        jax.lax.while_loop(
+            cond, body,
+            (jnp.int32(0), llm_state, tuple(ssm_states), tks0, nblk0,
+             base0, remaining, active, depth0, active, packed0))
+    return (llm_state, tuple(ssm_states), packed)
+
+
+def run_block(self, tks: np.ndarray, nblk: np.ndarray, base: np.ndarray,
+              active: np.ndarray, n_rounds: int,
+              remaining: Optional[np.ndarray] = None,
+              depth: Optional[np.ndarray] = None,
+              min_depth: int = 1, trace=None
+              ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """Run up to ``n_rounds`` (<= max_rounds) fused rounds of either
+    speculation engine: the one contract between an engine and whoever
+    drives it. Updates every model's op_state.
+
+    Each row enters with its accepted block: ``tks[r, :nblk[r]]`` are the
+    committed tokens the drafts' caches still lack (1 <= nblk <= depth+1,
+    the first at sequence position ``base[r]``); the last of them is the
+    pending token, whose KV the verifier's cache lacks too. A row the
+    drafts are level with enters with the one-token block ``nblk == 1``.
+    The first draft step of every round is the catch-up over that block,
+    so no prefill call sits between two blocks. A row drafts only while
+    ``engine.tree_width`` positions past its pending token fit in the
+    cache (live_mask) and ``remaining[r]``, its generation budget, is not
+    spent: the device loop exits early once no row can, so one call
+    normally finishes a whole request batch.
+
+    Returns (toks, n_acc, depth_used). toks[r, k] holds round k's [draft
+    path (depth), bonus]: the committed tokens are
+    ``toks[r, k, :n_acc[r, k]]`` plus the verifier's bonus at the FIXED
+    index ``toks[r, k, depth]``; n_acc == -1 marks a round the row sat
+    out. Afterwards the verifier's cache holds everything but the new
+    pending token; the drafts' caches are only good through the LAST
+    round's root (a losing branch holds its own chain, a beam its staged
+    nodes): the gap is the next call's accepted block.
+
+    ``depth[r]`` (None = static: the compiled depth, no in-block
+    adaptation) bounds row r's EFFECTIVE draft depth for the first round
+    — the block is compiled once at the max depth and drafting
+    early-exits at the round's deepest active row (the tree topology and
+    verify width stay static), so a mixed batch runs different depths in
+    one round with no retrace. Between rounds the device grows/shrinks
+    each row's depth (full accept -> +1, zero accept -> -1, clipped to
+    [min_depth, depth]) and once every live row accepts nothing at the
+    floor the block ends (give-up) so the host controller can park the
+    batch; depth_used[r, k] reports the bound each round actually ran
+    under (-1 on idle rounds) so the host can attribute its acceptance
+    observations.
+
+    ``trace`` is the calling scheduler loop's RoundTrace when telemetry
+    is on (None otherwise, and for direct drivers): the block then hands
+    the round its ``sched_commit`` phase the moment its own spans close.
+    """
+    n_rounds = min(int(n_rounds), self.max_rounds)
+    tel = _resolve_tel(self.telemetry)
+    span, ph = _open_block(tel)
+    if remaining is None:
+        remaining = np.full(nblk.shape, np.iinfo(np.int32).max // 2,
+                            np.int32)
+    adaptive = depth is not None
+    if depth is None:
+        depth = np.full(nblk.shape, self.depth, np.int32)
+    depth = np.clip(np.asarray(depth, np.int32), 1, self.depth)
+    args = [self.llm.params, self.llm.op_state]
+    for s in self.ssms:
+        args += [s.params, s.op_state]
+    args += [jnp.asarray(tks, jnp.int32), jnp.asarray(nblk, jnp.int32),
+             jnp.asarray(base, jnp.int32), jnp.asarray(active),
+             jnp.int32(n_rounds), jnp.asarray(remaining, jnp.int32),
+             jnp.asarray(depth),
+             jnp.int32(max(1, min(int(min_depth), self.depth))),
+             jnp.int32(int(adaptive))]
+    if tel is not None:
+        ph = tel.call_phase(ph, "call_launch", "spec_block")
+    t0 = time.perf_counter()
+    llm_state, ssm_states, packed = self._block(*args)
+    self.llm.op_state = llm_state
+    for s, st in zip(self.ssms, ssm_states):
+        s.op_state = st
+    if tel is not None:
+        ph = tel.call_phase(ph, "call_wait", "spec_block")
+    packed = np.asarray(packed)
+    if tel is not None:     # the np readback above is the device fence
+        _report_block(self, tel, span, ph, time.perf_counter() - t0,
+                      packed, n_rounds, trace)
+    return packed[:, :, :-2], packed[:, :, -2], packed[:, :, -1]
+
+
 class MultiSpecEngine:
     """Fully-fused multi-SSM tree speculation: one device call per block.
 
@@ -481,6 +638,14 @@ class MultiSpecEngine:
 
         T = 1 + len(self.ssms) * self.depth
         return round_up(T, SUBLANE)
+
+    @property
+    def room(self) -> int:
+        """Cache positions a row must have free past its tokens for the
+        scheduler to send it here: live_mask's staging window. A looser
+        gate would keep scheduling a row the engine masks dead every
+        round, hanging the loop."""
+        return self.tree_width
 
     def _tree_constants(self, R):
         d, B = self.depth, len(self.ssms)
@@ -658,342 +823,8 @@ class MultiSpecEngine:
         return (llm_state, ssm_states, blk, new_nblk, new_base, best_chain,
                 n_acc, bonus)
 
-    def _block_impl(self, llm_params, llm_state, *rest):
-        self._trace_count += 1          # python body == one XLA trace
-        B = len(self.ssms)
-        ssm_ps = [rest[2 * i] for i in range(B)]
-        ssm_states = [rest[2 * i + 1] for i in range(B)]
-        (tks0, nblk0, base0, active, n_rounds, remaining, depth0, min_depth,
-         adaptive) = rest[2 * B:]
-        R = tks0.shape[0]
-        d = self.depth
-        max_seq = self.llm.config.max_sequence_length
-        rng0 = jax.random.fold_in(self._rng_const,
-                                  (base0 + nblk0 - 1).sum())
-        # packed [R, max_rounds, d+3]: chain ++ bonus ++ n_acc ++ depth
-        packed0 = jnp.full((R, self.max_rounds, d + 3), 0, jnp.int32)
-        packed0 = packed0.at[:, :, d + 1].set(-1)
-        packed0 = packed0.at[:, :, d + 2].set(-1)
-        # a call starts where a round inside it starts: from each row's
-        # accepted block (tks0, nblk0, base0), handed over by the host, so
-        # round 0's width-(d+1) draft step is the catch-up over the LAST
-        # call's final round too (see run_block)
-        adapt = adaptive > 0
-
-        Tp = self.tree_width
-
-        def live_mask(base, nblk, remaining):
-            r_pos = base + nblk - 1
-            # reserve the PADDED verify width: the contiguous KV append
-            # writes the whole [r_pos, r_pos + Tp) staging window
-            return ((remaining > 0) & (r_pos + Tp <= max_seq - 1))
-
-        def cond(carry):
-            (i, _ls, _ss, _tks, nblk, base, remaining, act, _d, alive,
-             _p) = carry
-            return (i < n_rounds) & jnp.any(
-                act & live_mask(base, nblk, remaining) & alive)
-
-        def body(carry):
-            (i, llm_state, ssm_states, tks, nblk, base, remaining, act,
-             depth_v, alive, packed) = carry
-            act_i = act & live_mask(base, nblk, remaining) & alive
-            (llm_state, ssm_states, blk, new_nblk, new_base, chain, n_acc,
-             bonus) = self._round(
-                llm_params, llm_state, ssm_ps, list(ssm_states), tks, nblk,
-                base, act_i, jax.random.fold_in(rng0, i), depth_v)
-            tks = jnp.where(act_i[:, None], blk, tks)
-            nblk = jnp.where(act_i, new_nblk, nblk)
-            base = jnp.where(act_i, new_base, base)
-            remaining = remaining - jnp.where(act_i, n_acc + 1, 0)
-            row = jnp.concatenate(
-                [chain, bonus[:, None],
-                 jnp.where(act_i, n_acc, -1)[:, None],
-                 jnp.where(act_i, depth_v, -1)[:, None]], axis=1)
-            packed = jax.lax.dynamic_update_slice(
-                packed, row[:, None, :], (0, i, 0))
-            depth_v, alive = _adapt_depth_rule(adapt, act_i, n_acc,
-                                               depth_v, alive, min_depth,
-                                               d)
-            return (i + 1, llm_state, tuple(ssm_states), tks, nblk, base,
-                    remaining, act, depth_v, alive, packed)
-
-        (_, llm_state, ssm_states, _, _, _, _, _, _, _, packed) = \
-            jax.lax.while_loop(
-                cond, body,
-                (jnp.int32(0), llm_state, tuple(ssm_states), tks0, nblk0,
-                 base0, remaining, active, depth0, active, packed0))
-        return (llm_state, tuple(ssm_states), packed)
-
-    def run_block(self, tks: np.ndarray, nblk: np.ndarray, base: np.ndarray,
-                  active: np.ndarray, n_rounds: int,
-                  remaining: Optional[np.ndarray] = None,
-                  depth: Optional[np.ndarray] = None,
-                  min_depth: int = 1, trace=None
-                  ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-        """Run up to ``n_rounds`` fused tree rounds. Each row enters with
-        its accepted block: ``tks[r, :nblk[r]]`` are the committed tokens
-        the drafts' caches still lack (1 <= nblk <= depth+1, the first at
-        sequence position ``base[r]``); the last of them is the pending
-        token, whose KV the verifier's cache lacks too. A row the drafts
-        are level with enters with the one-token block ``nblk == 1``.
-        Returns
-        (toks, n_acc, depth_used): toks[r, k] holds round k's [chain
-        tokens (depth), bonus]; the committed tokens are
-        ``toks[r, k, :n_acc[r, k]]`` plus the bonus at the FIXED index
-        ``toks[r, k, depth]``; n_acc == -1 marks an idle round.
-        ``depth``/``min_depth``/``depth_used`` follow the
-        SpecChainEngine.run_block contract (per-row effective depth +
-        give-up, no retrace; the tree topology and verify width stay
-        static — only draft-chain steps early-exit and acceptance caps
-        per row; depth=None = static legacy behavior). ``trace``: see
-        SpecChainEngine.run_block."""
-        n_rounds = min(int(n_rounds), self.max_rounds)
-        tel = _resolve_tel(self.telemetry)
-        span, ph = _open_block(tel)
-        if remaining is None:
-            remaining = np.full(nblk.shape, np.iinfo(np.int32).max // 2,
-                                np.int32)
-        adaptive = depth is not None
-        if depth is None:
-            depth = np.full(nblk.shape, self.depth, np.int32)
-        depth = np.clip(np.asarray(depth, np.int32), 1, self.depth)
-        args = [self.llm.params, self.llm.op_state]
-        for s in self.ssms:
-            args += [s.params, s.op_state]
-        args += [jnp.asarray(tks, jnp.int32), jnp.asarray(nblk, jnp.int32),
-                 jnp.asarray(base, jnp.int32), jnp.asarray(active),
-                 jnp.int32(n_rounds), jnp.asarray(remaining, jnp.int32),
-                 jnp.asarray(depth),
-                 jnp.int32(max(1, min(int(min_depth), self.depth))),
-                 jnp.int32(int(adaptive))]
-        if tel is not None:
-            ph = tel.call_phase(ph, "call_launch", "spec_block")
-        t0 = time.perf_counter()
-        llm_state, ssm_states, packed = self._block(*args)
-        self.llm.op_state = llm_state
-        for s, st in zip(self.ssms, ssm_states):
-            s.op_state = st
-        if tel is not None:
-            ph = tel.call_phase(ph, "call_wait", "spec_block")
-        packed = np.asarray(packed)
-        if tel is not None:     # the np readback above is the device fence
-            _report_block(self, tel, span, ph, time.perf_counter() - t0,
-                          packed, n_rounds, trace)
-        return packed[:, :, :-2], packed[:, :, -2], packed[:, :, -1]
-
-
-class SpecChainEngine:
-    """Fused chain speculation: one device call per block of rounds.
-
-    Per round (all on device): the draft model decodes a greedy chain of
-    ``depth`` tokens (scan of depth+1 steps — the extra step back-fills the
-    draft KV for the accept-all case); the verifier scores the chain in one
-    width-(depth+1) causal pass; acceptance is the longest matching prefix
-    plus the verifier's bonus token. The number of rounds per call is a
-    dynamic scalar bounded by ``max_rounds`` — one compiled program total.
-    """
-
-    def __init__(self, llm, ssm, depth: int = 4, max_rounds: int = 16):
-        self.llm = llm
-        self.ssm = ssm
-        llm.finalize_pipeline()
-        ssm.finalize_pipeline()
-        self.depth = depth
-        self.max_rounds = max_rounds
-        self.telemetry = None   # explicit ServingTelemetry; None -> global
-        self._compute_dtype = jnp.dtype(llm.config.compute_dtype)
-        self._block = jax.jit(self._block_impl, donate_argnums=(1, 3))
-        # jit-cache accounting (see MultiSpecEngine.__init__)
-        self._trace_count = 0
-        self._traces_reported = 0
-        # concrete (created outside any trace: jit closes over it as a const)
-        self._rng_const = jax.random.PRNGKey(llm.config.seed)
-
-    @property
-    def tree_width(self) -> int:
-        """Verify width: the pending token and the chain, one causal pass
-        (a chain needs no bias block, so no sublane padding)."""
-        return self.depth + 1
-
-    def _round(self, llm_params, llm_state, ssm_params, ssm_state, tok, pos,
-               rng, active, depth_r):
-        d = self.depth
-        num = active.astype(jnp.int32)
-        R = tok.shape[0]
-        # the deepest active row's controller depth bounds the draft trip
-        # count this round — one compiled program serves every mixed-depth
-        # batch; shallower rows just stop counting matches at their own
-        # depth (the spec controller's no-retrace contract)
-        d_run = jnp.max(jnp.where(active, depth_r, 1))
-
-        # --- draft chain: d_run+1 steps, last one only back-fills KV ---
-        def draft_cond(carry):
-            return carry[0] < d_run + 1
-
-        def draft_body(carry):
-            i, state, t, p, chain = carry
-            out, state = _forward_tokens(
-                self.ssm, ssm_params, state, t[:, None], p[:, None], p, num,
-                active, jax.random.fold_in(rng, i), self._compute_dtype)
-            nxt = out[:, 0].astype(jnp.int32)
-            chain = jax.lax.dynamic_update_slice(chain, nxt[:, None], (0, i))
-            return i + 1, state, nxt, p + 1, chain
-
-        with jax.named_scope("draft"):
-            (_, ssm_state, _, _, chain) = jax.lax.while_loop(
-                draft_cond, draft_body,
-                (jnp.int32(0), ssm_state, tok, pos,
-                 jnp.zeros((R, d + 1), jnp.int32)))
-            chain = chain[:, :d]                                    # [R, d]
-
-        # --- verify: one causal pass over [pending, chain...] ---
-        # (static width d+1: undrafted tail columns hold zeros whose
-        # staged KV is overwritten by later rounds, exactly like padding)
-        with jax.named_scope("verify"):
-            vtokens = jnp.concatenate([tok[:, None], chain],
-                                      axis=1)                   # [R, d+1]
-            vpos = pos[:, None] + jnp.arange(d + 1)[None, :]
-            out, llm_state = _forward_tokens(
-                self.llm, llm_params, llm_state, vtokens, vpos, pos,
-                num * (d + 1), active, jax.random.fold_in(rng, d + 1),
-                self._compute_dtype)
-            a = out.astype(jnp.int32)                               # [R, d+1]
-
-        # --- greedy acceptance: longest prefix where chain matches ---
-        # (= index of the first mismatch; see MultiSpecEngine on cumprod)
-        # capped per row at the controller depth: positions past depth_r
-        # count as mismatches, so n_acc <= depth_r
-        with jax.named_scope("commit"):
-            match = ((chain == a[:, :d])
-                     & (jnp.arange(d)[None, :] < depth_r[:, None])
-                     ).astype(jnp.int32)
-            n_acc = jnp.argmin(jnp.pad(match, ((0, 0), (0, 1))),
-                               axis=1).astype(jnp.int32)    # [R] in [0,d]
-            bonus = jnp.take_along_axis(a, n_acc[:, None], axis=1)[:, 0]
-            new_tok = bonus.astype(jnp.int32)
-            new_pos = pos + n_acc + 1
-        return llm_state, ssm_state, new_tok, new_pos, a, n_acc
-
-    def _block_impl(self, llm_params, llm_state, ssm_params, ssm_state, tok,
-                    pos, active, n_rounds, remaining, depth0, min_depth,
-                    adaptive):
-        self._trace_count += 1          # python body == one XLA trace
-        R = tok.shape[0]
-        d = self.depth
-        max_seq = self.llm.config.max_sequence_length
-        rng0 = jax.random.fold_in(self._rng_const, pos.sum())
-        # packed output: [R, max_rounds, d+3] = verifier tokens ++ n_acc
-        # ++ effective depth — the host reads ONE buffer per block
-        # (each separate device->host read is a sync). n_acc = -1 marks a
-        # round where the request was
-        # already done (no tokens); depth = -1 likewise.
-        packed0 = jnp.full((R, self.max_rounds, d + 3), 0, jnp.int32)
-        packed0 = packed0.at[:, :, d + 1].set(-1)
-        packed0 = packed0.at[:, :, d + 2].set(-1)
-        adapt = adaptive > 0
-
-        def live_mask(pos, remaining):
-            # a request drafts this round only while it still owes tokens
-            # and a full round of KV slots (pos..pos+d) fits in its cache
-            return active & (remaining > 0) & (pos + d < max_seq)
-
-        def cond(carry):
-            i, _ls, _ss, _t, pos, remaining, _d, alive, _p = carry
-            return (i < n_rounds) & jnp.any(live_mask(pos, remaining)
-                                            & alive)
-
-        def body(carry):
-            (i, llm_state, ssm_state, tok, pos, remaining, depth_v, alive,
-             packed) = carry
-            act_i = live_mask(pos, remaining) & alive
-            llm_state, ssm_state, ntok, npos, a, n_acc = self._round(
-                llm_params, llm_state, ssm_params, ssm_state, tok, pos,
-                jax.random.fold_in(rng0, i), act_i, depth_v)
-            tok = jnp.where(act_i, ntok, tok)
-            pos = jnp.where(act_i, npos, pos)
-            remaining = remaining - jnp.where(act_i, n_acc + 1, 0)
-            row = jnp.concatenate(
-                [a, jnp.where(act_i, n_acc, -1)[:, None],
-                 jnp.where(act_i, depth_v, -1)[:, None]], axis=1)
-            packed = jax.lax.dynamic_update_slice(
-                packed, row[:, None, :], (0, i, 0))
-            depth_v, alive = _adapt_depth_rule(adapt, act_i, n_acc,
-                                               depth_v, alive, min_depth,
-                                               d)
-            return (i + 1, llm_state, ssm_state, tok, pos, remaining,
-                    depth_v, alive, packed)
-
-        (_, llm_state, ssm_state, _, _, _, _, _, packed) = \
-            jax.lax.while_loop(
-                cond, body, (jnp.int32(0), llm_state, ssm_state, tok, pos,
-                             remaining, depth0, active, packed0))
-        return llm_state, ssm_state, packed
-
-    def run_block(self, tok: np.ndarray, pos: np.ndarray, active: np.ndarray,
-                  n_rounds: int,
-                  remaining: Optional[np.ndarray] = None,
-                  depth: Optional[np.ndarray] = None,
-                  min_depth: int = 1, trace=None
-                  ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-        """Run up to ``n_rounds`` (<= max_rounds) rounds; returns
-        (a, n_acc, depth_used).
-
-        a[r, k] is round k's verifier outputs [depth+1]; the committed
-        tokens for slot r in round k are ``a[r, k, :n_acc[r, k] + 1]``;
-        n_acc[r, k] == -1 means the request drafted nothing that round.
-        ``remaining[r]`` is the generation budget per slot — the device
-        loop exits early once every request has drafted its budget (or hit
-        the KV-cache end), so one call normally finishes a whole request
-        batch. Updates both models' op_state.
-
-        ``depth[r]`` (None = static legacy behavior: the compiled depth,
-        no in-block adaptation) bounds row r's EFFECTIVE draft depth for
-        the first round — the block is compiled once at the max depth and
-        drafting early-exits at the round's deepest active row, so a
-        mixed batch runs different depths in one round with no retrace.
-        Between rounds the device grows/shrinks each row's depth (full
-        accept -> +1, zero accept -> -1, clipped to [min_depth, depth])
-        and once every live row accepts nothing at the floor the block
-        ends (give-up) so the host controller can park the batch;
-        depth_used[r, k] reports the bound each round actually ran under
-        (-1 on idle rounds) so the host can attribute its acceptance
-        observations.
-
-        ``trace`` is the calling scheduler loop's RoundTrace when
-        telemetry is on (None otherwise, and for direct drivers): the
-        block then hands the round its ``sched_commit`` phase the moment
-        its own spans close.
-        """
-        n_rounds = min(int(n_rounds), self.max_rounds)
-        tel = _resolve_tel(self.telemetry)
-        span, ph = _open_block(tel)
-        if remaining is None:
-            remaining = np.full(tok.shape, np.iinfo(np.int32).max // 2,
-                                np.int32)
-        adaptive = depth is not None
-        if depth is None:
-            depth = np.full(tok.shape, self.depth, np.int32)
-        depth = np.clip(np.asarray(depth, np.int32), 1, self.depth)
-        min_depth = max(1, min(int(min_depth), self.depth))
-        staged = (jnp.asarray(tok), jnp.asarray(pos), jnp.asarray(active),
-                  jnp.int32(n_rounds),
-                  jnp.asarray(remaining, dtype=jnp.int32),
-                  jnp.asarray(depth), jnp.int32(min_depth),
-                  jnp.int32(int(adaptive)))
-        if tel is not None:
-            ph = tel.call_phase(ph, "call_launch", "spec_block")
-        t0 = time.perf_counter()
-        (self.llm.op_state, self.ssm.op_state, packed) = self._block(
-            self.llm.params, self.llm.op_state, self.ssm.params,
-            self.ssm.op_state, *staged)
-        if tel is not None:
-            ph = tel.call_phase(ph, "call_wait", "spec_block")
-        packed = np.asarray(packed)
-        if tel is not None:     # the np readback above is the device fence
-            _report_block(self, tel, span, ph, time.perf_counter() - t0,
-                          packed, n_rounds, trace)
-        return packed[:, :, :-2], packed[:, :, -2], packed[:, :, -1]
+    _block_impl = _block_impl
+    run_block = run_block
 
 
 class BeamSpecEngine:
@@ -1030,7 +861,7 @@ class BeamSpecEngine:
     def __init__(self, llm, ssm, depth: int = 4, width: int = 2,
                  max_rounds: int = 16):
         self.llm = llm
-        self.ssm = ssm
+        self.ssms = [ssm]
         llm.finalize_pipeline()
         ssm.finalize_pipeline()
         self.depth = depth
@@ -1042,6 +873,10 @@ class BeamSpecEngine:
 
         self.T = 1 + depth * width            # real tree nodes
         self.tree_width = round_up(max(self.T, depth + 1), SUBLANE)
+        # the scheduler's gate (MultiSpecEngine.room): one position more
+        # than live_mask asks, the bound this engine has always been
+        # scheduled under
+        self.room = self.tree_width + 1
         # node depth is a static function of the layout
         nd = np.zeros((self.tree_width,), np.int32)
         for t in range(depth):
@@ -1062,9 +897,11 @@ class BeamSpecEngine:
         par = jnp.take_along_axis(par_flat, idx, axis=1).astype(jnp.int32)
         return cum, tok, par
 
-    def _round(self, llm_params, llm_state, ssm_params, ssm_state, tks,
-               nblk, base, active, rng, depth_r):
+    def _round(self, llm_params, llm_state, ssm_ps, ssm_states, tks, nblk,
+               base, active, rng, depth_r):
         from flexflow_tpu.serve.batch_config import TreeBatchMeta
+
+        (ssm,), (ssm_params,), (ssm_state,) = self.ssms, ssm_ps, ssm_states
 
         d, W, T, Tp = self.depth, self.width, self.T, self.tree_width
         R = tks.shape[0]
@@ -1079,7 +916,7 @@ class BeamSpecEngine:
         num = jnp.where(active, nblk, 0)
         with jax.named_scope("draft"):
             out0, ssm_state = forward_with_meta(
-                self.ssm, ssm_params, ssm_state,
+                ssm, ssm_params, ssm_state,
                 BatchMeta(tokens=tks, positions=pos, start_pos=base,
                           num_tokens=num, active=active),
                 jax.random.fold_in(rng, 0), self._compute_dtype,
@@ -1123,7 +960,7 @@ class BeamSpecEngine:
                 num_nodes=jnp.where(active, 1 + t * W, 0)
                 .astype(jnp.int32), active=active)
             out, ssm_state = forward_with_meta(
-                self.ssm, ssm_params, ssm_state, meta,
+                ssm, ssm_params, ssm_state, meta,
                 jax.random.fold_in(rng, 1 + t), self._compute_dtype,
                 kv_contiguous=True)               # [R, Tp, 2W]
             f0 = 1 + (t - 1) * W
@@ -1205,7 +1042,7 @@ class BeamSpecEngine:
             blk = jnp.where(idx < n_acc[:, None],
                             jnp.pad(chain, ((0, 0), (0, 1))), blk)
             blk = jnp.where(idx == n_acc[:, None], bonus[:, None], blk)
-        return (llm_state, ssm_state, blk, n_acc + 1, r_pos + 1, chain,
+        return (llm_state, [ssm_state], blk, n_acc + 1, r_pos + 1, chain,
                 n_acc, bonus)
 
     def _commit(self, llm_state, path, n_acc, r_pos, active):
@@ -1240,104 +1077,5 @@ class BeamSpecEngine:
         return {**llm_state,
                 "kv_cache": {"k": move(st["k"]), "v": move(st["v"])}}
 
-    def _block_impl(self, llm_params, llm_state, ssm_params, ssm_state,
-                    tok, pos, active, n_rounds, remaining, depth0,
-                    min_depth, adaptive):
-        self._trace_count += 1          # python body == one XLA trace
-        R = tok.shape[0]
-        d = self.depth
-        max_seq = self.llm.config.max_sequence_length
-        Tp = self.tree_width
-        rng0 = jax.random.fold_in(self._rng_const, pos.sum())
-        packed0 = jnp.full((R, self.max_rounds, d + 3), 0, jnp.int32)
-        packed0 = packed0.at[:, :, d + 1].set(-1)
-        packed0 = packed0.at[:, :, d + 2].set(-1)
-        tks0 = jnp.zeros((R, d + 1), jnp.int32).at[:, 0].set(tok)
-        nblk0 = jnp.ones((R,), jnp.int32)
-        adapt = adaptive > 0
-
-        def live_mask(base, nblk, remaining):
-            r_pos = base + nblk - 1
-            return (remaining > 0) & (r_pos + Tp <= max_seq - 1)
-
-        def cond(carry):
-            (i, _ls, _ss, _tks, nblk, base, remaining, act, _d, alive,
-             _p) = carry
-            return (i < n_rounds) & jnp.any(
-                act & live_mask(base, nblk, remaining) & alive)
-
-        def body(carry):
-            (i, llm_state, ssm_state, tks, nblk, base, remaining, act,
-             depth_v, alive, packed) = carry
-            act_i = act & live_mask(base, nblk, remaining) & alive
-            (llm_state, ssm_state, blk, new_nblk, new_base, chain, n_acc,
-             bonus) = self._round(
-                llm_params, llm_state, ssm_params, ssm_state,
-                tks, nblk, base, act_i, jax.random.fold_in(rng0, i),
-                depth_v)
-            tks = jnp.where(act_i[:, None], blk, tks)
-            nblk = jnp.where(act_i, new_nblk, nblk)
-            base = jnp.where(act_i, new_base, base)
-            remaining = remaining - jnp.where(act_i, n_acc + 1, 0)
-            # blk already holds [accepted tokens, bonus at index n_acc] —
-            # the SpecChainEngine packed contract (committed tokens are
-            # row[:n_acc + 1]), so one host driver serves both engines
-            row = jnp.concatenate(
-                [blk, jnp.where(act_i, n_acc, -1)[:, None],
-                 jnp.where(act_i, depth_v, -1)[:, None]], axis=1)
-            packed = jax.lax.dynamic_update_slice(
-                packed, row[:, None, :], (0, i, 0))
-            depth_v, alive = _adapt_depth_rule(adapt, act_i, n_acc,
-                                               depth_v, alive, min_depth,
-                                               d)
-            return (i + 1, llm_state, ssm_state, tks, nblk, base,
-                    remaining, act, depth_v, alive, packed)
-
-        (_, llm_state, ssm_state, _, _, _, _, _, _, _, packed) = \
-            jax.lax.while_loop(
-                cond, body,
-                (jnp.int32(0), llm_state, ssm_state, tks0, nblk0, pos,
-                 remaining, active, depth0, active, packed0))
-        return llm_state, ssm_state, packed
-
-    def run_block(self, tok: np.ndarray, pos: np.ndarray,
-                  active: np.ndarray, n_rounds: int,
-                  remaining: Optional[np.ndarray] = None,
-                  depth: Optional[np.ndarray] = None,
-                  min_depth: int = 1, trace=None
-                  ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-        """Same packed contract as SpecChainEngine.run_block: the committed
-        tokens for slot r in round k are ``a[r, k, :n_acc[r, k] + 1]``
-        (accepted path + bonus); n_acc == -1 marks an idle round;
-        depth_used reports each round's per-row depth bound (beam levels
-        past the round's deepest bound skip their staged tree forward via
-        lax.cond — static layout, no retrace). ``trace``: see
-        SpecChainEngine.run_block."""
-        n_rounds = min(int(n_rounds), self.max_rounds)
-        tel = _resolve_tel(self.telemetry)
-        span, ph = _open_block(tel)
-        if remaining is None:
-            remaining = np.full(tok.shape, np.iinfo(np.int32).max // 2,
-                                np.int32)
-        adaptive = depth is not None
-        if depth is None:
-            depth = np.full(tok.shape, self.depth, np.int32)
-        depth = np.clip(np.asarray(depth, np.int32), 1, self.depth)
-        staged = (jnp.asarray(tok), jnp.asarray(pos), jnp.asarray(active),
-                  jnp.int32(n_rounds), jnp.asarray(remaining, jnp.int32),
-                  jnp.asarray(depth),
-                  jnp.int32(max(1, min(int(min_depth), self.depth))),
-                  jnp.int32(int(adaptive)))
-        if tel is not None:
-            ph = tel.call_phase(ph, "call_launch", "spec_block")
-        t0 = time.perf_counter()
-        (self.llm.op_state, self.ssm.op_state, packed) = self._block(
-            self.llm.params, self.llm.op_state, self.ssm.params,
-            self.ssm.op_state, *staged)
-        if tel is not None:
-            ph = tel.call_phase(ph, "call_wait", "spec_block")
-        packed = np.asarray(packed)
-        if tel is not None:     # the np readback above is the device fence
-            _report_block(self, tel, span, ph, time.perf_counter() - t0,
-                          packed, n_rounds, trace)
-        return packed[:, :, :-2], packed[:, :, -2], packed[:, :, -1]
+    _block_impl = _block_impl
+    run_block = run_block

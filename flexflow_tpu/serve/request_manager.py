@@ -28,9 +28,11 @@ Slot/convention notes:
   row a step, or for such a model a count of tokens a row;
   ``_stage_decode`` and ``_commit_decode`` are the two places that know
   which.
-* Single-chain speculation (one SSM, MAX_BEAM_WIDTH=1 — the reference
-  default) needs no KV commit at all: accepted drafts are already contiguous
-  in the verifier's cache. Multi-SSM token trees use ``commit_tree_kv``.
+* Speculation runs through ONE fused loop (``_generate_spec_fused``, over
+  engine.MultiSpecEngine or engine.BeamSpecEngine) and one host-stepped
+  reference loop (``_generate_spec_tree_host``: ``inference_debugging``
+  dumps and several drafts' merged beams, committing with
+  ``commit_tree_kv``); ``_spec_route`` chooses, by the request alone.
 """
 
 from __future__ import annotations
@@ -1030,11 +1032,10 @@ class RequestManager:
                                  ctrl.take_new_fallbacks())
 
     def _partition_spec(self, ctrl, drafting, rnd, live, roomy, rounds):
-        """Controller partition shared by the two fused scheduler loops
-        (which must stay in sync — see _generate_spec_tree_fused): split
-        the roomy requests into (draftable, parked) by ``drafting`` (the
-        round's ``SpecController.drafting`` set), feed the controller
-        telemetry gauges, and shrink a pure-probe tick to ONE round (one
+        """The fused loop's controller partition: split the roomy
+        requests into (draftable, parked) by ``drafting`` (the round's
+        ``SpecController.drafting`` set), feed the controller telemetry
+        gauges, and shrink a pure-probe tick to ONE round (one
         acceptance sample — minimal probe tax on parked traffic).
         ``rnd`` is the round's RoundTrace (None: telemetry off).
         Returns (draftable, parked, rounds)."""
@@ -1120,17 +1121,13 @@ class RequestManager:
         self._resolve_prefix_cache(generation_config)
         loop, W = self._spec_route(llm, ssms, beam_width)
         self.scheduler_loop = "python:" + loop
-        if loop == "spec_chain":
-            return self._generate_spec_chain(
-                llm, ssms[0], spec_depth=spec_depth, beam_width=W,
-                generation_config=generation_config)
-        if loop == "spec_tree_fused":
-            return self._generate_spec_tree_fused(
-                llm, ssms, spec_depth=spec_depth,
-                generation_config=generation_config)
-        return self._generate_spec_tree_host(llm, ssms,
-                                             spec_depth=spec_depth,
-                                             beam_width=W)
+        if loop == "spec_tree_host":
+            return self._generate_spec_tree_host(llm, ssms,
+                                                 spec_depth=spec_depth,
+                                                 beam_width=W)
+        return self._generate_spec_fused(
+            llm, ssms, loop, W, spec_depth=spec_depth,
+            generation_config=generation_config)
 
     def prepare_spec_infer(self, llm, ssms: List[Any],
                            spec_depth: Optional[int] = None,
@@ -1150,8 +1147,10 @@ class RequestManager:
 
     @staticmethod
     def _spec_route(llm, ssms, beam_width):
-        """``(loop, beam width)``: which of the three speculation loops
-        serves this verifier with these drafts."""
+        """``(loop, beam width)``: the fused loop (``spec_tree_fused``:
+        MultiSpecEngine; ``spec_beam_fused``: BeamSpecEngine) unless
+        ``inference_debugging`` wants per-op dumps or several drafts'
+        beams want merging, which the host-stepped loop does."""
         for m in (llm, *ssms):
             refuse_block_diffusion(m, "speculation (drafting, tree "
                                    "verification and its commit)")
@@ -1166,37 +1165,15 @@ class RequestManager:
                 f"beam_width={W} but the draft models were compiled with "
                 f"max_beam_width={widths}; rebuild the SSMs with the "
                 f"requested width (FFConfig.max_beam_width)")
-        if W > 1:
-            if len(ssms) == 1 and not llm.config.inference_debugging:
-                # single-draft beams run fully fused: the beam tree's NODE
-                # LAYOUT is compile-time static (frontier = the newest W
-                # nodes), so drafting + verify + accept + commit all run
-                # inside one device while_loop (engine.BeamSpecEngine)
-                return "spec_chain", W
-            # multi-SSM beams (merged cross-draft trees) and debug dumps
-            # run the host tree path: frontier nodes step through the
-            # draft as STAGED TREE NODES (no per-beam KV), and the
-            # surviving beam paths merge like extra chains
+        if llm.config.inference_debugging or (W > 1 and len(ssms) > 1):
+            # per-op tensor dumps stay phase-ordered; several drafts' beams
+            # step through each draft as STAGED TREE NODES (no per-beam
+            # KV) and the surviving beam paths merge like extra chains
             return "spec_tree_host", W
-        from flexflow_tpu import kernels as ffk
-
-        if len(ssms) == 1 and not ffk.use_pallas(llm.config):
-            # MAX_BEAM_WIDTH=1 single-draft speculation (the reference
-            # default) fully fused as a chain: no tree merge, no KV
-            # compaction, narrowest verify. Preferred off-TPU, where the
-            # B=1 tree engine's wider (sublane-padded) verify and
-            # catch-up machinery cost more per-op overhead than the
-            # chain's extra KV-backfill draft step saves. On TPU the
-            # weight-bound rounds invert that tradeoff and the fused
-            # tree engine below wins (~12% per round at 7B geometry).
-            return "spec_chain", 1
-        if not llm.config.inference_debugging:
-            # multi-SSM trees also run fully fused (engine.MultiSpecEngine:
-            # all drafts + tree verify + acceptance + KV compaction inside
-            # one device while_loop); the host-stepped path remains for
-            # inference_debugging's per-op tensor dumps.
-            return "spec_tree_fused", 1
-        return "spec_tree_host", 1
+        # everything else runs inside one device while_loop: one chain a
+        # draft verified as one tree, or one draft's beam tree, whose NODE
+        # LAYOUT is compile-time static (frontier = the newest W nodes)
+        return ("spec_beam_fused" if W > 1 else "spec_tree_fused"), W
 
     def _depth_of(self, spec_depth: Optional[int]) -> int:
         return min(spec_depth or self.max_spec_depth, self.max_spec_depth)
@@ -1209,9 +1186,7 @@ class RequestManager:
         blocks of this model (``_fallback_decode``, and incremental decoding
         from here on) take the verify pass's shapes."""
         from flexflow_tpu.kernels.attention import SUBLANE, round_up
-        from flexflow_tpu.serve.engine import (BeamSpecEngine,
-                                               MultiSpecEngine,
-                                               SpecChainEngine)
+        from flexflow_tpu.serve.engine import BeamSpecEngine, MultiSpecEngine
 
         llm_ifm = self._manager_of(llm)
         if loop == "spec_tree_host":
@@ -1220,23 +1195,15 @@ class RequestManager:
                 1 + depth * len(ssms) * beam_width, SUBLANE))
             return None
         rounds = llm.config.spec_rounds_per_call
-        if loop == "spec_tree_fused":
-            attr = "_multi_engine"
-            same = lambda e: e.ssms == list(ssms)
-            make = lambda: MultiSpecEngine(llm, ssms, depth, max_rounds=rounds)
-        elif beam_width > 1:
-            attr = "_beam_engine"
-            same = lambda e: e.ssm is ssms[0] and e.width == beam_width
-            make = lambda: BeamSpecEngine(llm, ssms[0], depth, beam_width,
-                                          max_rounds=rounds)
-        else:
-            attr = "_chain_engine"
-            same = lambda e: e.ssm is ssms[0]
-            make = lambda: SpecChainEngine(llm, ssms[0], depth,
-                                           max_rounds=rounds)
+        attr = "_beam_engine" if beam_width > 1 else "_multi_engine"
         engine = getattr(llm, attr, None)
-        if engine is None or engine.depth != depth or not same(engine):
-            engine = make()
+        if (engine is None or engine.depth != depth
+                or engine.ssms != list(ssms)
+                or getattr(engine, "width", 1) != beam_width):
+            engine = (BeamSpecEngine(llm, ssms[0], depth, beam_width,
+                                     max_rounds=rounds) if beam_width > 1
+                      else MultiSpecEngine(llm, ssms, depth,
+                                           max_rounds=rounds))
             setattr(llm, attr, engine)
         llm_ifm.verified_at(engine.tree_width)
         return engine
@@ -1260,8 +1227,8 @@ class RequestManager:
         This debug path intentionally keeps the historical serial
         drain-prefill-then-decode order and does not consult the
         shared-prefix pool — per-op dumps stay phase-ordered. The
-        throughput loops (incremental, spec-chain, multi-SSM fused)
-        carry the ISSUE 19 interleaving + prefix reuse."""
+        throughput loops (incremental, fused speculation) carry the
+        ISSUE 19 interleaving + prefix reuse."""
         llm_ifm = self._manager_of(llm)
         ssm_ifms = [self._manager_of(ssm) for ssm in ssms]
         cfg = llm.config
@@ -1354,268 +1321,48 @@ class RequestManager:
                     active[slot] = None
         return done
 
-    def _generate_spec_chain(self, llm, ssm,
+    def _generate_spec_fused(self, llm, ssms: List[Any], loop: str,
+                             beam_width: int,
                              spec_depth: Optional[int] = None,
-                             beam_width: int = 1,
                              generation_config: Optional[GenerationConfig]
                              = None) -> List[GenerationResult]:
-        """Single-SSM speculative decoding with a fused engine: the chain
-        engine at beam_width 1, the beam engine (static-layout beam tree
-        drafting, engine.BeamSpecEngine) at width > 1.
+        """Speculative decoding with a fused engine (serve/engine.py:
+        MultiSpecEngine at beam_width 1, BeamSpecEngine for one draft's
+        beams): the one speculation loop that serves traffic.
 
-        Each device call runs SPEC_ROUNDS_PER_CALL full rounds (draft +
-        verify + accept) via serve/engine.py; the host walks the returned
-        (a, n_acc, depth_used) blocks, committing ``a[slot, k, :n_acc+1]``
-        per round and reconciling EOS / length limits (both engines share
-        the packed block contract). With the adaptive controller on
+        Host responsibilities shrink to continuous batching: slot fill,
+        chunked prefill (verifier + every draft), dispatching fused round
+        blocks, and EOS / length reconciliation over the returned rounds.
+        Each device call runs up to SPEC_ROUNDS_PER_CALL full rounds
+        (draft + verify + accept + commit); ``engine.run_block`` states
+        what goes in and what comes back. With the adaptive controller on
         (GenerationConfig.adaptive_spec, the default) each request's
         depth bound comes from its acceptance EWMA, and requests whose
         estimated spec speedup falls below incremental break-even decode
         through ``_fallback_decode`` until a probe round recovers them.
         """
-        llm_ifm, ssm_ifm = self._manager_of(llm), self._manager_of(ssm)
-        cfg = llm.config
-        R = cfg.max_requests_per_batch
-        max_seq = cfg.max_sequence_length
-        depth = self._depth_of(spec_depth)
-        ctrl, gc = self._spec_controller(generation_config, llm, [ssm],
-                                         engine_depth=depth,
-                                         beam_width=beam_width)
-        engine = self._engine_of("spec_chain", llm, [ssm], depth, beam_width)
-        # the beam engine stages a Tp-node tree per round (the chain its
-        # depth + 1 tokens); its live_mask reserves the full window, so the
-        # host must gate at least as strictly or cramped requests would be
-        # rescheduled into an engine that masks them dead every round,
-        # hanging the loop. (NB: named room_needed, not room — the
-        # per-request budget remainder below shadows that name.)
-        room_needed = engine.tree_width
-        shape = self._prefill_shape(cfg)
-        active: List[Optional[Request]] = [None] * R
-        done: List[GenerationResult] = []
-
-        while self.pending or any(a is not None for a in active):
-            tel = self._tel()
-            rnd = (tel.begin_round("spec_chain", R) if tel is not None
-                   else None)
-            self._reap_expired(active, max_seq, done, ctrl)
-            parked_guids = ({req.guid for req in active if req is not None
-                             and ctrl.in_fallback(req.guid)}
-                            if ctrl is not None else ())
-            self._fill_slots(active, max_seq, done, parked_guids)
-            drafting_guids = (None if ctrl is None else ctrl.drafting(
-                req.guid for req in active if req is not None))
-            self._prefix_install(active, (("llm", llm_ifm),
-                                          ("ssm0", ssm_ifm)))
-            if rnd is not None:
-                rnd.admitted(R - active.count(None), len(self.pending))
-            # prompt prefill for both models (same path as incremental);
-            # one bounded chunk per model per round — caught-up slots
-            # draft/decode below in the SAME round (decode-interleaved
-            # chunked prefill, ISSUE 19)
-            rows = self._prefill(llm_ifm, active, shape,
-                                 lambda r: r.cache_depth, tel, rnd)
-            for slot, toks, sp in rows:
-                active[slot].cache_depth = sp + len(toks)
-            # Catching the SSM cache up is only useful if the request can
-            # still draft (a full round of depth+1 KV slots left AND the
-            # controller hasn't parked it on incremental — healing a parked
-            # request's draft cache would be pure waste until its probe
-            # comes due); tail tokens go through the single-step fallback
-            # anyway.
-            drafting = [req if req is not None
-                        and max_seq - len(req.tokens) - 1 >= room_needed
-                        and (ctrl is None or req.guid in drafting_guids)
-                        else None for req in active]
-            ssm_rows = self._prefill(ssm_ifm, drafting, shape,
-                                     lambda r: r.ssm_cache_depth.get(0, 0),
-                                     tel, rnd)
-            for slot, toks, sp in ssm_rows:
-                active[slot].ssm_cache_depth[0] = sp + len(toks)
-            prefilled = bool(rows or ssm_rows)
-            if rnd is not None:
-                for part in filter(None, (rows, ssm_rows)):
-                    rnd.note_cut(self._prefill_kind(active, part))
-            live = [req for req in active
-                    if req is not None and not req.finished]
-            # decode-interleaved chunked prefill: only slots whose
-            # VERIFIER cache is caught up join this round's spec/decode
-            # work; mid-prefill slots wait (their next chunk dispatches
-            # next round) instead of stalling everyone else.
-            ready = [req for req in live
-                     if req.cache_depth == len(req.tokens) - 1]
-            if ready:
-                # speculation must not run past the KV cache end: the verify
-                # pass writes at positions pos..pos+depth each round. A
-                # request can draft only with a full round of KV room (the
-                # prefill loop above only catches its draft cache up in that
-                # case); cramped requests finish through the single-step
-                # path below. The device loop also guards per request and
-                # exits early once every budget is drafted.
-                roomy = [req for req in ready
-                         if max_seq - len(req.tokens) - 1 >= room_needed]
-                cramped = [req for req in ready
-                           if max_seq - len(req.tokens) - 1 < room_needed]
-                # controller partition: parked requests decode through the
-                # fused incremental block (same cost/tokens as plain
-                # incremental) until their probe round recovers them
-                draftable, parked, rounds = self._partition_spec(
-                    ctrl, drafting_guids, rnd, live, roomy,
-                    min(cfg.spec_rounds_per_call, engine.max_rounds))
-                if prefilled:
-                    # prefill still pending somewhere: one spec round,
-                    # then back to the next chunk
-                    rounds = 1
-                # a draftable slot may still have a lagging draft cache
-                # mid-interleave (its SSM chunk dispatched above); it
-                # drafts next round, once healed
-                draftable = [req for req in draftable
-                             if req.ssm_cache_depth.get(0, 0)
-                             == len(req.tokens) - 1]
-                if cramped:
-                    # cache nearly full: finish remaining tokens one by one
-                    # through the non-fused single-step decode path
-                    rows = [(req.slot, req.tokens[-1:], len(req.tokens) - 1)
-                            for req in cramped]
-                    meta = self._meta_from_rows(R, 1, rows)
-                    if rnd is not None:
-                        rnd.phase(None)
-                    t0 = time.perf_counter()
-                    out = llm_ifm.step(meta, tel=tel)
-                    if tel is not None:   # step's np readback = fence
-                        dt = time.perf_counter() - t0
-                        rnd.phase("sched_commit", cramped)
-                        tel.record_decode_block(
-                            dt, 1, len(cramped),
-                            [req.guid for req in cramped], t0)
-                    for slot, _t, sp in rows:
-                        req = active[slot]
-                        req.tokens.append(int(out[slot, 0]))
-                        req.cache_depth = sp + 1
-                        req.ssm_cache_depth[0] = min(
-                            req.ssm_cache_depth.get(0, 0), sp)
-                        self._note_first_token(req)
-                        self._finish_if_done(req, max_seq)
-                if parked:
-                    self._fallback_decode(llm_ifm, parked, R, max_seq, cfg,
-                                          tel, rnd)
-                    for req in parked:
-                        ctrl.note_fallback_block(req.guid)
-                if draftable:
-                    if rnd is not None:
-                        rnd.phase("sched_build")
-                    tok = np.zeros((R,), np.int32)
-                    pos = np.zeros((R,), np.int32)
-                    act = np.zeros((R,), bool)
-                    remaining = np.zeros((R,), np.int32)
-                    depth_vec = None
-                    if ctrl is not None:
-                        depth_vec = np.full((R,), depth, np.int32)
-                    for req in draftable:
-                        assert req.cache_depth == len(req.tokens) - 1
-                        assert req.ssm_cache_depth.get(0) == len(req.tokens) - 1
-                        tok[req.slot] = req.tokens[-1]
-                        pos[req.slot] = len(req.tokens) - 1
-                        act[req.slot] = True
-                        remaining[req.slot] = self._remaining_budget(req,
-                                                                     max_seq)
-                        if ctrl is not None:
-                            depth_vec[req.slot] = ctrl.depth_for(req.guid)
-                    self._tel_tick(tel, draftable, R, max_seq)
-                    # engines are cached on the llm across managers:
-                    # hand THIS manager's explicit telemetry through (a
-                    # None keeps the engine on the process-global one)
-                    engine.telemetry = self.telemetry
-                    if rnd is not None:
-                        rnd.phase(None)
-                    t0 = time.perf_counter()
-                    a, n_acc, d_used = engine.run_block(
-                        tok, pos, act, rounds, remaining, depth=depth_vec,
-                        min_depth=gc.min_spec_depth, trace=rnd)
-                    block_dt = time.perf_counter() - t0
-                    if rnd is not None:
-                        rnd.phase("sched_commit", draftable)
-                    for req in draftable:
-                        round_events = []
-                        observed = []
-                        for k in range(rounds):
-                            n = int(n_acc[req.slot, k])
-                            if n < 0:     # request drafted nothing this round
-                                continue
-                            observed.append((int(d_used[req.slot, k]), n))
-                            new_toks = [int(t)
-                                        for t in a[req.slot, k, : n + 1]]
-                            # trim the accepted chunk at the generation
-                            # budget / EOS — incremental decoding would
-                            # have stopped there (tree-path parity)
-                            room = req.max_new_tokens - req.num_generated
-                            new_toks = new_toks[:max(0, room)]
-                            if (self.eos_token_id is not None
-                                    and self.eos_token_id in new_toks):
-                                new_toks = new_toks[
-                                    :new_toks.index(self.eos_token_id) + 1]
-                            req.tokens.extend(new_toks)
-                            round_events.append((k, n, len(new_toks)))
-                            if self._finish_if_done(req, max_seq):
-                                break
-                        if ctrl is not None:
-                            ctrl.observe_block(req.guid, observed)
-                        self._note_first_token(req)
-                        if tel is not None and round_events:
-                            tel.trace_rounds(req.guid, round_events,
-                                             t0, block_dt, rounds)
-                        d = len(req.tokens) - 1
-                        req.cache_depth = d
-                        req.ssm_cache_depth[0] = d
-            for slot in range(R):
-                req = active[slot]
-                if req is not None and req.finished:
-                    if ctrl is not None:
-                        ctrl.drop(req.guid)
-                    self._prefix_store(req, (("llm", llm_ifm),
-                                             ("ssm0", ssm_ifm)))
-                    done.append(self._collect(req))
-                    active[slot] = None
-            if rnd is not None:
-                rnd.end()
-        return done
-
-    def _generate_spec_tree_fused(self, llm, ssms: List[Any],
-                                  spec_depth: Optional[int] = None,
-                                  generation_config:
-                                  Optional[GenerationConfig] = None
-                                  ) -> List[GenerationResult]:
-        """Multi-SSM tree speculation with the fused MultiSpecEngine.
-
-        Host responsibilities shrink to continuous batching: slot fill,
-        chunked prefill (verifier + every draft), dispatching fused round
-        blocks, and EOS/length reconciliation over the returned rounds —
-        the same division of labor as the single-SSM chain path.
-
-        NOTE: this loop intentionally parallels _generate_spec_chain (the
-        differences are real — per-SSM room/prefill, tree staging needs
-        B*depth+1 KV slots vs depth+1, and the packed-row format differs);
-        a scheduling/EOS fix in one path almost certainly applies to the
-        other — keep them in sync.
-        """
         llm_ifm = self._manager_of(llm)
         ssm_ifms = [self._manager_of(ssm) for ssm in ssms]
+        caches = (("llm", llm_ifm),
+                  *((f"ssm{i}", m) for i, m in enumerate(ssm_ifms)))
         cfg = llm.config
         R = cfg.max_requests_per_batch
         max_seq = cfg.max_sequence_length
         B = len(ssms)
         depth = self._depth_of(spec_depth)
         ctrl, gc = self._spec_controller(generation_config, llm, ssms,
-                                         engine_depth=depth)
-        engine = self._engine_of("spec_tree_fused", llm, ssms, depth, 1)
+                                         engine_depth=depth,
+                                         beam_width=beam_width)
+        engine = self._engine_of(loop, llm, ssms, depth, beam_width)
         shape = self._prefill_shape(cfg)
+        round_name = loop.removesuffix("_fused")    # sched_round's ``loop``
         active: List[Optional[Request]] = [None] * R
         done: List[GenerationResult] = []
-        # a request can draft only with the engine's FULL staging window of
-        # KV room left — derived from the engine itself (its live_mask
-        # reserves the sublane-PADDED verify width; a looser host gate here
-        # would keep scheduling a request the engine masks dead every
-        # round, hanging the loop)
-        room_needed = engine.tree_width
+
+        def has_room(req):
+            """A full staging window of KV room left (engine.room); a
+            cramped request finishes through the single-step path."""
+            return max_seq - len(req.tokens) >= engine.room
 
         def carries_block(req):
             """The drafts all stand at one depth and owe at most one
@@ -1627,7 +1374,7 @@ class RequestManager:
 
         while self.pending or any(a is not None for a in active):
             tel = self._tel()
-            rnd = (tel.begin_round("spec_tree", R) if tel is not None
+            rnd = (tel.begin_round(round_name, R) if tel is not None
                    else None)
             self._reap_expired(active, max_seq, done, ctrl)
             parked_guids = ({req.guid for req in active if req is not None
@@ -1636,14 +1383,12 @@ class RequestManager:
             self._fill_slots(active, max_seq, done, parked_guids)
             drafting_guids = (None if ctrl is None else ctrl.drafting(
                 req.guid for req in active if req is not None))
-            self._prefix_install(
-                active, (("llm", llm_ifm),
-                         *((f"ssm{i}", m)
-                           for i, m in enumerate(ssm_ifms))))
+            self._prefix_install(active, caches)
             if rnd is not None:
                 rnd.admitted(R - active.count(None), len(self.pending))
-            # one bounded prefill chunk per model per round; caught-up
-            # slots spec/decode below in the SAME round (ISSUE 19)
+            # one bounded prefill chunk per model per round (same path as
+            # incremental); caught-up slots spec/decode below in the SAME
+            # round (decode-interleaved chunked prefill, ISSUE 19)
             rows = self._prefill(llm_ifm, active, shape,
                                  lambda r: r.cache_depth, tel, rnd)
             for slot, toks, sp in rows:
@@ -1653,10 +1398,12 @@ class RequestManager:
                 rnd.note_cut("prefill")
             # a row whose drafts owe no more than one accepted block goes
             # to the engine as it is (run_block's first draft step is the
-            # catch-up); only a row that owes more, and can still draft, is
-            # fed in chunks here
+            # catch-up); only a row that owes more, and can still draft
+            # (room for a block, and not parked by the controller: healing
+            # a parked request's draft cache would be pure waste until its
+            # probe comes due), is fed in chunks here
             owing = [req if req is not None and not carries_block(req)
-                     and max_seq - len(req.tokens) >= room_needed
+                     and has_room(req)
                      and (ctrl is None or req.guid in drafting_guids)
                      else None for req in active]
             for i, ifm in enumerate(ssm_ifms):
@@ -1671,27 +1418,34 @@ class RequestManager:
                         rnd.note_cut(self._prefill_kind(active, rows))
             live = [req for req in active
                     if req is not None and not req.finished]
-            # decode-interleaved chunked prefill: mid-prefill slots sit
-            # this round's spec/decode out (chain-path parity)
+            # decode-interleaved chunked prefill: only slots whose
+            # VERIFIER cache is caught up join this round's spec/decode
+            # work; mid-prefill slots wait (their next chunk dispatches
+            # next round) instead of stalling everyone else.
             ready = [req for req in live
                      if req.cache_depth == len(req.tokens) - 1]
             if not ready:
                 if rnd is not None:
                     rnd.end()
                 continue
-            roomy = [req for req in ready
-                     if max_seq - len(req.tokens) >= room_needed]
-            cramped = [req for req in ready
-                       if max_seq - len(req.tokens) < room_needed]
+            roomy = [req for req in ready if has_room(req)]
+            cramped = [req for req in ready if not has_room(req)]
+            # controller partition: parked requests decode through the
+            # fused incremental block (same cost/tokens as plain
+            # incremental) until their probe round recovers them
             draftable, parked, rounds = self._partition_spec(
                 ctrl, drafting_guids, rnd, live, roomy,
                 min(cfg.spec_rounds_per_call, engine.max_rounds))
             if prefilled:
-                rounds = 1      # see chain-path note
+                # prefill still pending somewhere: one spec round, then
+                # back to the next chunk
+                rounds = 1
+            # a draftable slot may still have lagging drafts mid-interleave
+            # (their chunk dispatched above); it drafts once healed
             draftable = [req for req in draftable if carries_block(req)]
             if cramped:
-                # cache nearly full: finish token by token (chain-path
-                # parity; the fused tree needs B*depth+1 staging slots)
+                # cache nearly full: finish remaining tokens one by one
+                # through the non-fused single-step decode path
                 rows = [(req.slot, req.tokens[-1:], len(req.tokens) - 1)
                         for req in cramped]
                 meta = self._meta_from_rows(R, 1, rows)
@@ -1740,7 +1494,10 @@ class RequestManager:
                     if ctrl is not None:
                         depth_vec[req.slot] = ctrl.depth_for(req.guid)
                 self._tel_tick(tel, draftable, R, max_seq)
-                engine.telemetry = self.telemetry   # see chain-path note
+                # engines are cached on the llm across managers: hand THIS
+                # manager's explicit telemetry through (a None keeps the
+                # engine on the process-global one)
+                engine.telemetry = self.telemetry
                 if rnd is not None:
                     rnd.phase(None)
                 t0 = time.perf_counter()
@@ -1756,12 +1513,15 @@ class RequestManager:
                     observed = []
                     for k in range(rounds):
                         n = int(n_acc[req.slot, k])
-                        if n < 0:
+                        if n < 0:         # the row sat this round out
                             continue
                         observed.append((int(d_used[req.slot, k]), n))
                         last_rpos = len(req.tokens) - 1
                         new_toks = ([int(t) for t in toks[req.slot, k, :n]]
                                     + [int(toks[req.slot, k, depth])])
+                        # trim the accepted chunk at the generation budget
+                        # / EOS — incremental decoding would have stopped
+                        # there
                         room = req.max_new_tokens - req.num_generated
                         new_toks = new_toks[:max(0, room)]
                         if (self.eos_token_id is not None
@@ -1785,19 +1545,17 @@ class RequestManager:
                     for i in range(B):
                         # draft caches are only guaranteed correct through
                         # the last round's catch-up position: a losing
-                        # branch's cache holds ITS chain, not the committed
-                        # tokens — the next block is handed the gap as its
-                        # accepted block (carries_block)
+                        # branch's cache holds ITS chain, a beam's its
+                        # staged nodes, not the committed tokens — the next
+                        # block is handed the gap as its accepted block
+                        # (carries_block)
                         req.ssm_cache_depth[i] = min(last_rpos + 1, d)
             for slot in range(R):
                 req = active[slot]
                 if req is not None and req.finished:
                     if ctrl is not None:
                         ctrl.drop(req.guid)
-                    self._prefix_store(
-                        req, (("llm", llm_ifm),
-                              *((f"ssm{i}", m)
-                                for i, m in enumerate(ssm_ifms))))
+                    self._prefix_store(req, caches)
                     done.append(self._collect(req))
                     active[slot] = None
             if rnd is not None:
@@ -1868,7 +1626,7 @@ class RequestManager:
         Correctness-first host loop: each step re-verifies the full
         accumulated tree (~W x the frontier-only FLOPs at depth d) — beams
         are a drafting-quality feature; the throughput paths are the fused
-        chain/tree engines. generate_spec_infer validates that ``width``
+        engines. generate_spec_infer validates that ``width``
         matches every draft's compiled max_beam_width before routing here
         (the packed output layout is fixed at graph-build time).
         """

@@ -37,7 +37,7 @@ an incremental decode step costs — both are weight-stream bound):
   ``max_d E/C < 1`` (with hysteresis margins around 1 so the mode
   cannot flap on boundary noise).
 
-The chosen depth is only a BOUND handed to the engines: all three fused
+The chosen depth is only a BOUND handed to the engines: both fused
 engines (serve/engine.py) compile ONE max-depth program and take a
 per-row depth vector, early-exiting drafting at the round's deepest
 active row and capping acceptance per row — a mixed batch runs

@@ -69,28 +69,40 @@ def bench_round():
         return json.load(f)
 
 
+def _tiny_llama(mode, beam=1):
+    import flexflow_tpu as ff
+    from flexflow_tpu.models.llama import LLAMAConfig, create_llama_model
+
+    tiny = LLAMAConfig(vocab_size=128, hidden_size=64, intermediate_size=128,
+                       num_hidden_layers=2, num_attention_heads=4,
+                       num_key_value_heads=2, max_position_embeddings=128)
+    cfg = ff.FFConfig(max_requests_per_batch=2, max_sequence_length=64,
+                      max_tokens_per_batch=16, seed=0,
+                      kv_cache_dtype="float32", max_beam_width=beam)
+    m = ff.FFModel(cfg)
+    create_llama_model(m, tiny, mode=mode)
+    m.compile(comp_mode=ff.CompMode.COMP_MODE_INFERENCE)
+    return m
+
+
 @pytest.fixture(scope="session")
 def tiny_spec_pair():
     """One TINY llama verify/draft pair shared across the telemetry and
     loadgen test files (tier-1 budget: these files must stay lean, so
     they build models ONCE per session, on the geometry test_serving
     proved out)."""
-    import flexflow_tpu as ff
     from flexflow_tpu.ffconst import InferenceMode
-    from flexflow_tpu.models.llama import LLAMAConfig, create_llama_model
 
-    tiny = LLAMAConfig(vocab_size=128, hidden_size=64, intermediate_size=128,
-                       num_hidden_layers=2, num_attention_heads=4,
-                       num_key_value_heads=2, max_position_embeddings=128)
+    return (_tiny_llama(InferenceMode.TREE_VERIFY_MODE),
+            _tiny_llama(InferenceMode.BEAM_SEARCH_MODE))
 
-    def make(mode):
-        cfg = ff.FFConfig(max_requests_per_batch=2, max_sequence_length=64,
-                          max_tokens_per_batch=16, seed=0,
-                          kv_cache_dtype="float32")
-        m = ff.FFModel(cfg)
-        create_llama_model(m, tiny, mode=mode)
-        m.compile(comp_mode=ff.CompMode.COMP_MODE_INFERENCE)
-        return m
 
-    return (make(InferenceMode.TREE_VERIFY_MODE),
-            make(InferenceMode.BEAM_SEARCH_MODE))
+@pytest.fixture(scope="session")
+def tiny_beam_draft():
+    """``tiny_spec_pair``'s draft compiled at beam width 2, which sends it
+    to the beam engine (``_spec_route`` refuses a width the draft was not
+    compiled with): the same weights, so its best beam is the verifier's
+    own greedy continuation."""
+    from flexflow_tpu.ffconst import InferenceMode
+
+    return _tiny_llama(InferenceMode.BEAM_SEARCH_MODE, beam=2)

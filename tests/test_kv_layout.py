@@ -309,13 +309,10 @@ def _serve(loop):
         results = rm.generate_incr_decoding(llm)
     elif loop == "spec_tree_host":
         results = rm._generate_spec_tree_host(llm, ssms, spec_depth=3)
-    elif loop == "spec_beam_fused":
-        results = rm.generate_spec_infer(llm, ssms, spec_depth=3,
-                                         beam_width=2, generation_config=gc)
-        assert rm.scheduler_loop == "python:spec_chain"     # BeamSpecEngine
     else:
-        results = rm._generate_spec_tree_fused(llm, ssms, spec_depth=3,
-                                               generation_config=gc)
+        results = rm.generate_spec_infer(llm, ssms, spec_depth=3,
+                                         generation_config=gc)
+        assert rm.scheduler_loop == "python:" + loop
     by_prompt = {tuple(r.input_tokens): r.output_tokens for r in results}
     return ([by_prompt[tuple(p)] for p in prompts],
             [m.op_state["kv_cache"]["k"].shape for m in [llm] + ssms])

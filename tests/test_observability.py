@@ -4,7 +4,7 @@ Gates, in dependency order: MetricsRegistry.merge is EXACT against a
 single-registry ground truth (counters, gauges, histograms incl. the
 sliding-window percentiles); the SLO burn-rate monitor fires and clears
 deterministically on a fake clock; the flight recorder's bounded ring
-dumps a parseable incident report; the three fused engines compile
+dumps a parseable incident report; the two fused engines compile
 exactly once under an adaptive-depth mixed batch (retrace counters stay
 zero); a fleet-wide trace_id survives preemption re-queue and crash
 failover token-identically; the seeded failover_run produces the
@@ -232,7 +232,8 @@ def test_flight_recorder_dump_roundtrip(tmp_path):
 # retrace accounting: adaptive mixed batch = ONE compile per engine
 # ---------------------------------------------------------------------------
 
-def test_adaptive_mixed_batch_compiles_once_per_engine(tiny_spec_pair):
+def test_adaptive_mixed_batch_compiles_once_per_engine(tiny_spec_pair,
+                                                       tiny_beam_draft):
     """The fused engines pad their block signatures so an adaptive-depth
     MIXED batch (different prompt lengths, different budgets, per-request
     effective depths) reuses one compile; the retrace counters are how a
@@ -242,6 +243,7 @@ def test_adaptive_mixed_batch_compiles_once_per_engine(tiny_spec_pair):
     from flexflow_tpu.serve.batch_config import GenerationConfig
 
     llm, ssm = tiny_spec_pair
+    beam = tiny_beam_draft                  # width 2: the beam engine
     tel = ServingTelemetry()
     prompts = [[5, 9, 23, 44], [7, 3, 11], [2, 4], [9, 1, 6, 12, 3]]
 
@@ -256,15 +258,15 @@ def test_adaptive_mixed_batch_compiles_once_per_engine(tiny_spec_pair):
     rm = RequestManager(telemetry=tel)
     for i, p in enumerate(prompts):
         rm.register_new_request(p, max_new_tokens=6 + 2 * i)
-    rm.generate_spec_infer(llm, [ssm], spec_depth=3,
+    rm.generate_spec_infer(llm, [beam], spec_depth=3,
                            generation_config=gc())
-    assert llm._chain_engine._trace_count == 1
+    assert llm._beam_engine._trace_count == 1
 
     rm2 = RequestManager(telemetry=tel)
     for p in prompts[:2]:
         rm2.register_new_request(p, max_new_tokens=6)
-    rm2._generate_spec_tree_fused(llm, [ssm], spec_depth=3,
-                                  generation_config=gc())
+    rm2.generate_spec_infer(llm, [ssm], spec_depth=3,
+                            generation_config=gc())
     assert llm._multi_engine._trace_count == 1
 
     # a retrace (total_traces > 1) is the violation; none happened, so
@@ -276,9 +278,9 @@ def test_adaptive_mixed_batch_compiles_once_per_engine(tiny_spec_pair):
     rm3 = RequestManager(telemetry=tel)
     for p in prompts[:3]:
         rm3.register_new_request(p, max_new_tokens=5)
-    rm3.generate_spec_infer(llm, [ssm], spec_depth=3,
+    rm3.generate_spec_infer(llm, [beam], spec_depth=3,
                             generation_config=gc())
-    assert llm._chain_engine._trace_count == 1
+    assert llm._beam_engine._trace_count == 1
     assert tel.registry.get("ffsv_jit_cache_misses_total").value == before
     assert tel.registry.get("ffsv_engine_retraces_total").value == 0
 

@@ -1,8 +1,8 @@
 """Shared-prefix KV cache + decode-interleaved chunked prefill (ISSUE 19, 32):
 radix trie match/insert/evict/refcount math on a fake clock, KV segment
 extract/install roundtrip on both op_state layouts, token identity cold
-vs warm on all three scheduler paths (incremental, spec chain, multi-SSM
-fused) including a preemption re-queue that crosses a pooled prefix,
+vs warm on the incremental loop and on the fused speculation loop under
+both engines, including a preemption re-queue that crosses a pooled prefix,
 eviction-under-pressure never corrupting a live slot, the
 decode-interleaves-with-prefill dispatch order (a round prefills as many
 steps as its decode block pays for, by the two programs' given costs, with
@@ -175,7 +175,7 @@ def test_kv_segment_roundtrip_both_layouts():
 
 
 # ---------------------------------------------------------------------------
-# integration: token identity cold vs warm on the three scheduler paths
+# integration: token identity cold vs warm on the scheduler loops
 # ---------------------------------------------------------------------------
 
 @pytest.fixture(scope="module")
@@ -258,7 +258,10 @@ def test_token_identity_incremental_cold_vs_warm(tiny_incr_model, incr_ref):
     assert all(e.refs == 0 for e in pc._entries)
 
 
-def test_token_identity_spec_chain_and_fused(tiny_spec_pair, tiny_ssm2):
+def test_token_identity_spec_beam_and_tree(tiny_spec_pair, tiny_ssm2,
+                                           tiny_beam_draft):
+    """The fused loop installs and stores a shared prefix in the verifier
+    and in every draft, under either engine."""
     llm, ssm = tiny_spec_pair
     gc = GenerationConfig(prefix_cache=True, prefix_cache_tokens=4096)
 
@@ -283,8 +286,9 @@ def test_token_identity_spec_chain_and_fused(tiny_spec_pair, tiny_ssm2):
         assert warm.results[wa].output_tokens == cold.results[ca].output_tokens
         assert warm.results[wa].status == "ok"
 
-    run_pair([ssm])                 # fused chain engine
-    run_pair([ssm, tiny_ssm2])      # fused multi-SSM tree engine
+    run_pair([tiny_beam_draft])     # one draft's beams: the beam engine
+    run_pair([ssm])                 # one draft's chain: the tree engine
+    run_pair([ssm, tiny_ssm2])      # and two drafts' chains
 
 
 def test_preemption_requeue_crosses_shared_prefix(tiny_incr_model, incr_ref):

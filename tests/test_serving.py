@@ -26,11 +26,11 @@ TINY = LLAMAConfig(vocab_size=128, hidden_size=64, intermediate_size=128,
 
 
 def make_model(mode=InferenceMode.INC_DECODING_MODE, seed=0, max_requests=4,
-               max_seq=64, tp=1):
+               max_seq=64, tp=1, beam=1):
     cfg = ff.FFConfig(max_requests_per_batch=max_requests,
                       max_sequence_length=max_seq, max_tokens_per_batch=16,
                       seed=seed, kv_cache_dtype="float32",
-                      tensor_parallelism_degree=tp)
+                      tensor_parallelism_degree=tp, max_beam_width=beam)
     model = ff.FFModel(cfg)
     create_llama_model(model, TINY, mode=mode)
     model.compile(comp_mode=ff.CompMode.COMP_MODE_INFERENCE)
@@ -53,16 +53,25 @@ def models():
     return get
 
 
+def _draft(models, engine, **kw):
+    """The draft model ``generate_spec_infer`` sends to ``engine``: one
+    compiled at beam width 2 for the beam engine (the route refuses a
+    width the draft was not compiled with), at width 1 for the tree's."""
+    return models(mode=InferenceMode.BEAM_SEARCH_MODE,
+                  beam=2 if engine == "beam" else 1, **kw)
+
+
 def _spec_infer(rm, engine, llm, ssm, spec_depth, monkeypatch):
-    """One draft model through the chain engine (generate_spec_infer's
-    choice where ``use_pallas`` is off, as on the CPU) or through the fused
-    tree engine (its choice on a TPU). The draft is priced at a tenth of
-    the verifier, as a real one is: at the tiny pair's own ratio of 1 the
-    controller parks every request before either engine runs a block."""
+    """One draft model through ``generate_spec_infer``: to the beam engine
+    at the width 2 it was compiled with, to the fused tree engine at
+    width 1, both through the one fused loop. The draft is priced at a
+    tenth of the verifier, as a real one is: at the tiny pair's own ratio
+    of 1 the controller parks every request before either engine runs a
+    block."""
     from flexflow_tpu.serve import engine as engines
     from flexflow_tpu.serve.batch_config import GenerationConfig
 
-    cls = {"chain": engines.SpecChainEngine,
+    cls = {"beam": engines.BeamSpecEngine,
            "tree": engines.MultiSpecEngine}[engine]
     run_block, blocks = cls.run_block, []
 
@@ -71,19 +80,15 @@ def _spec_infer(rm, engine, llm, ssm, spec_depth, monkeypatch):
         return run_block(self, *args, **kwargs)
 
     monkeypatch.setattr(cls, "run_block", counted)
-    gc = GenerationConfig(spec_draft_cost_ratio=0.1)
-    if engine == "chain":
-        out = rm.generate_spec_infer(llm, [ssm], spec_depth=spec_depth,
-                                     generation_config=gc)
-        assert rm.scheduler_loop == "python:spec_chain"
-    else:
-        out = rm._generate_spec_tree_fused(llm, [ssm], spec_depth=spec_depth,
-                                           generation_config=gc)
+    out = rm.generate_spec_infer(
+        llm, [ssm], spec_depth=spec_depth,
+        generation_config=GenerationConfig(spec_draft_cost_ratio=0.1))
+    assert rm.scheduler_loop == f"python:spec_{engine}_fused"
     assert blocks and set(blocks) == {cls}
     return out
 
 
-SPEC_ENGINES = pytest.mark.parametrize("engine", ["chain", "tree"])
+SPEC_ENGINES = pytest.mark.parametrize("engine", ["beam", "tree"])
 
 
 def test_incr_decoding_deterministic():
@@ -195,12 +200,12 @@ def test_decode_width_without_and_against_an_engine(case, monkeypatch):
 
 
 @pytest.mark.parametrize("engine,depth,width", [
-    ("tree", 4, 8), ("tree", 8, 16), ("chain", 4, 5)])
+    ("tree", 4, 8), ("tree", 8, 16), ("beam", 4, 16)])
 def test_decode_width_is_the_verifying_engines(engine, depth, width,
                                                monkeypatch):
     """Served incrementally and then speculatively, one model ends up at
-    its engine's verify width (the fused tree's nodes padded to sublanes,
-    16 past a depth of 7; the chain's depth + 1): the loop that fetches the
+    its engine's verify width (the tree's nodes padded to sublanes: 16
+    past a depth of 7, and for a beam of 2 at depth 4): the loop that fetches the
     engine tells the verifier's manager, the block built at one token a row
     is dropped, ``_fallback_decode`` (every request parks at the tiny
     pair's cost ratio of 1) runs at the engine's width, the span and the
@@ -209,7 +214,8 @@ def test_decode_width_is_the_verifying_engines(engine, depth, width,
     from flexflow_tpu.telemetry import ServingTelemetry
 
     llm = make_model(InferenceMode.TREE_VERIFY_MODE, max_requests=2)
-    ssm = make_model(InferenceMode.BEAM_SEARCH_MODE, max_requests=2)
+    ssm = make_model(InferenceMode.BEAM_SEARCH_MODE, max_requests=2,
+                     beam=2 if engine == "beam" else 1)
     make, built = engines.make_decode_block, []
 
     def counted(model, dtype, steps, width=1):
@@ -217,7 +223,7 @@ def test_decode_width_is_the_verifying_engines(engine, depth, width,
         return make(model, dtype, steps, width=width)
 
     monkeypatch.setattr(engines, "make_decode_block", counted)
-    cls = {"chain": engines.SpecChainEngine,
+    cls = {"beam": engines.BeamSpecEngine,
            "tree": engines.MultiSpecEngine}[engine]
     monkeypatch.setattr(cls, "run_block", lambda *a, **k: pytest.fail(
         "a request drafted: the fallback was to decode every token"))
@@ -232,10 +238,9 @@ def test_decode_width_is_the_verifying_engines(engine, depth, width,
     ifm = llm._inference_manager
     assert ifm.decode_width == 1 and built == [1]
     tel = ServingTelemetry()
-    spec = serve(lambda rm: (rm.generate_spec_infer if engine == "chain"
-                             else rm._generate_spec_tree_fused)(
+    spec = serve(lambda rm: rm.generate_spec_infer(
         llm, [ssm], spec_depth=depth), tel)
-    assert getattr(llm, f"_{'chain' if engine == 'chain' else 'multi'}"
+    assert getattr(llm, f"_{'beam' if engine == 'beam' else 'multi'}"
                    "_engine").tree_width == width
     assert ifm.decode_width == width and built == [1, width]
     assert spec == incr
@@ -246,6 +251,38 @@ def test_decode_width_is_the_verifying_engines(engine, depth, width,
     # from here on incremental decoding takes the verify pass's shapes too
     assert serve(lambda rm: rm.generate_incr_decoding(llm)) == incr
     assert built == [1, width]
+
+
+@pytest.mark.parametrize("pallas", ["off", "on"])
+def test_one_draft_takes_the_tree_engine_on_every_platform(pallas,
+                                                           monkeypatch):
+    """One draft at width 1 goes to MultiSpecEngine through the fused loop
+    whether the Pallas kernels serve the model (as on the chip; here
+    interpreted) or not: the engine the tests run by default is the one
+    the chip runs, and it returns incremental decoding's tokens."""
+    from flexflow_tpu import kernels as ffk
+    from flexflow_tpu.serve.engine import MultiSpecEngine
+
+    if pallas == "on":
+        monkeypatch.setenv("FF_PALLAS_INTERPRET", "1")
+    llm = make_model(InferenceMode.TREE_VERIFY_MODE, max_requests=2,
+                     max_seq=128)
+    ssm = make_model(InferenceMode.BEAM_SEARCH_MODE, max_requests=2,
+                     max_seq=128)
+    assert ffk.use_pallas(llm.config) == (pallas == "on")
+
+    def serve(loop):
+        rm = RequestManager()
+        for p in [[5, 9, 23, 44], [7, 3, 11]]:
+            rm.register_new_request(p, max_new_tokens=6)
+        return rm, {tuple(r.input_tokens): r.output_tokens
+                    for r in loop(rm)}
+
+    rm, spec = serve(lambda rm: rm.generate_spec_infer(llm, [ssm],
+                                                       spec_depth=3))
+    assert rm.scheduler_loop == "python:spec_tree_fused"
+    assert isinstance(llm._multi_engine, MultiSpecEngine)
+    assert spec == serve(lambda rm: rm.generate_incr_decoding(llm))[1]
 
 
 @SPEC_ENGINES
@@ -262,7 +299,7 @@ def test_spec_infer_matches_incr_decoding(models, engine, monkeypatch):
             for r in rm.generate_incr_decoding(incr_model)}
 
     llm = models(mode=InferenceMode.TREE_VERIFY_MODE, seed=0)
-    ssm = models(mode=InferenceMode.BEAM_SEARCH_MODE, seed=0)
+    ssm = _draft(models, engine, seed=0)
     rm2 = RequestManager()
     for p in prompts:
         rm2.register_new_request(p, max_new_tokens=12)
@@ -286,7 +323,7 @@ def test_spec_infer_divergent_ssm_still_correct(models, engine,
             for r in rm.generate_incr_decoding(incr_model)}
 
     llm = models(mode=InferenceMode.TREE_VERIFY_MODE, seed=0)
-    ssm = models(mode=InferenceMode.BEAM_SEARCH_MODE, seed=123)
+    ssm = _draft(models, engine, seed=123)
     rm2 = RequestManager()
     for p in prompts:
         rm2.register_new_request(p, max_new_tokens=10)
@@ -342,14 +379,14 @@ def test_spec_infer_tensor_parallel_matches():
 
 
 @SPEC_ENGINES
-def test_spec_chain_cramped_and_roomy_requests_coexist(models, engine,
-                                                       monkeypatch):
+def test_spec_cramped_and_roomy_requests_coexist(models, engine,
+                                                 monkeypatch):
     """A request whose prompt nearly fills the KV cache (no room to draft a
     full round) must finish via the single-step path while a roomy request
     speculates — without tripping the draft-cache assertions."""
     max_seq = 32
     depth = 4
-    cramped_prompt = list(range(1, 28))       # room = 32-27-1 = 4 < depth+1
+    cramped_prompt = list(range(1, 28))       # room = 32-27 = 5 < a tree's 8
     roomy_prompt = [5, 9, 23]
 
     incr_model = models(seed=0, max_seq=max_seq)
@@ -362,8 +399,7 @@ def test_spec_chain_cramped_and_roomy_requests_coexist(models, engine,
 
     llm = models(mode=InferenceMode.TREE_VERIFY_MODE, seed=0,
                  max_seq=max_seq)
-    ssm = models(mode=InferenceMode.BEAM_SEARCH_MODE, seed=0,
-                 max_seq=max_seq)
+    ssm = _draft(models, engine, seed=0, max_seq=max_seq)
     rm2 = RequestManager()
     rm2.register_new_request(cramped_prompt, max_new_tokens=8)
     rm2.register_new_request(roomy_prompt, max_new_tokens=12)
@@ -386,7 +422,7 @@ def test_spec_infer_eos_and_budget_respected(models, engine, monkeypatch):
     stop_at = incr.output_tokens.index(eos) + 1
 
     llm = models(mode=InferenceMode.TREE_VERIFY_MODE, seed=0)
-    ssm = models(mode=InferenceMode.BEAM_SEARCH_MODE, seed=0)
+    ssm = _draft(models, engine, seed=0)
     rm2 = RequestManager(eos_token_id=eos)
     rm2.register_new_request([5, 9, 23, 44], max_new_tokens=7)
     (spec,) = _spec_infer(rm2, engine, llm, ssm, 4, monkeypatch)
@@ -685,17 +721,18 @@ def test_spec_infer_multi_ssm_draftable_window_terminates():
 
 @pytest.mark.parametrize("adaptive", [False, True],
                          ids=["static", "adaptive"])
-@pytest.mark.parametrize("n_ssm", [1, 2])
-def test_fused_tree_high_acceptance_blocks_carry(n_ssm, adaptive,
-                                                 monkeypatch):
+@pytest.mark.parametrize("n_ssm", [1, 2, "beam"])
+def test_fused_high_acceptance_blocks_carry(n_ssm, adaptive, monkeypatch):
     """A draft that accepts (the verifier's own weights) must keep the fused
-    tree loop in whole blocks: the accepted block of a call's last round is
+    loop in whole blocks: the accepted block of a call's last round is
     handed to the next call, so once a prompt is in no draft ever runs the
     prefill program again and nothing but a prompt prefill holds a block to
     one round. With two drafts the first is divergent: it loses every
-    round, and the carried block is what heals its cache."""
+    round, and the carried block is what heals its cache. ``beam``: one
+    draft at width 2 through the beam engine, whose draft cache holds its
+    staged tree nodes after a round, which the carried block overwrites."""
     from flexflow_tpu.serve.batch_config import GenerationConfig
-    from flexflow_tpu.serve.engine import MultiSpecEngine
+    from flexflow_tpu.serve.engine import BeamSpecEngine, MultiSpecEngine
     from flexflow_tpu.serve.inference_manager import InferenceManager
 
     depth, plen, new = 4, 11, 96
@@ -709,10 +746,12 @@ def test_fused_tree_high_acceptance_blocks_carry(n_ssm, adaptive,
 
     llm = make_model(mode=InferenceMode.TREE_VERIFY_MODE, seed=0, max_seq=128)
     ssms = [make_model(mode=InferenceMode.BEAM_SEARCH_MODE, seed=s,
-                       max_seq=128) for s in ([0] if n_ssm == 1 else [7, 0])]
+                       max_seq=128, beam=2 if n_ssm == "beam" else 1)
+            for s in ([7, 0] if n_ssm == 2 else [0])]
+    engine = BeamSpecEngine if n_ssm == "beam" else MultiSpecEngine
     per_call = llm.config.spec_rounds_per_call
     draft_ends, llm_prefills, blocks = [], [], []
-    orig_step, orig_block = InferenceManager.step, MultiSpecEngine.run_block
+    orig_step, orig_block = InferenceManager.step, engine.run_block
 
     def step_spy(self, meta, *a, **k):
         ends = (meta.start_pos + meta.num_tokens)[meta.active]
@@ -729,11 +768,11 @@ def test_fused_tree_high_acceptance_blocks_carry(n_ssm, adaptive,
         return out
 
     monkeypatch.setattr(InferenceManager, "step", step_spy)
-    monkeypatch.setattr(MultiSpecEngine, "run_block", block_spy)
+    monkeypatch.setattr(engine, "run_block", block_spy)
     rm2 = RequestManager()
     for p in prompts:
         rm2.register_new_request(p, max_new_tokens=new)
-    spec = rm2._generate_spec_tree_fused(
+    spec = rm2.generate_spec_infer(
         llm, ssms, spec_depth=depth,
         # (a cheap draft, as a real one is: the controller's cost model
         # would park a draft the size of its verifier from token one)
@@ -1265,7 +1304,8 @@ def test_default_incr_path():
 # The compact prefill batch (segments x chunk, addressed by slot) against
 # the slot grid it replaced
 # ---------------------------------------------------------------------------
-def _tiny_family(family, mode, seed, R=6, max_seq=63, batch_tokens=16):
+def _tiny_family(family, mode, seed, R=6, max_seq=63, batch_tokens=16,
+                 beam=1):
     """MHA at D=128, multi-query at D=64 (Falcon's geometry), a tiny OLMoE.
     63 positions a slot: a multiple of no chunk, so a prompt's last chunk
     can start within a chunk of the cache's end."""
@@ -1274,7 +1314,7 @@ def _tiny_family(family, mode, seed, R=6, max_seq=63, batch_tokens=16):
 
     cfg = ff.FFConfig(max_requests_per_batch=R, max_sequence_length=max_seq,
                       max_tokens_per_batch=batch_tokens, seed=seed,
-                      kv_cache_dtype="float32")
+                      kv_cache_dtype="float32", max_beam_width=beam)
     m = ff.FFModel(cfg)
     if family == "mha_d128":
         create_llama_model(m, LLAMAConfig(
@@ -1322,11 +1362,19 @@ def _serve_one_way(loop, family, compact, monkeypatch,
         monkeypatch.setattr(RequestManager, "_compact_prefill",
                             staticmethod(lambda ifm: False))
     spec = loop != "incr"
+    if loop == "spec_beam_fused" and family == "mqa_d64":
+        # Falcon's builder has no beam head (models/falcon.py): its one
+        # draft goes to the tree engine, which "spec_tree_fused" below
+        # only runs with two
+        loop = "spec_tree_fused"
+        beam, seeds = 1, (0,)
+    else:
+        beam = 2 if loop == "spec_beam_fused" else 1
+        seeds = {"incr": (), "spec_tree_fused": (0, 5)}.get(loop, (0,))
     llm = _tiny_family(family, InferenceMode.TREE_VERIFY_MODE if spec
                        else InferenceMode.INC_DECODING_MODE, seed=0, **sizes)
     ssms = [_tiny_family(family, InferenceMode.BEAM_SEARCH_MODE, seed=s,
-                         **sizes)
-            for s in {"incr": (), "spec_tree_fused": (0, 5)}.get(loop, (0,))]
+                         beam=beam, **sizes) for s in seeds]
     rng = np.random.RandomState(4)
     prompts = [[int(t) for t in rng.randint(1, 128, size=n)]
                for n in lens]
@@ -1353,12 +1401,14 @@ def _serve_one_way(loop, family, compact, monkeypatch,
 
 
 @pytest.mark.parametrize("family", ["mha_d128", "mqa_d64", "olmoe"])
-@pytest.mark.parametrize("loop", ["incr", "spec_chain", "spec_tree_fused",
-                                  "spec_tree_host"])
+@pytest.mark.parametrize("loop", ["incr", "spec_beam_fused",
+                                  "spec_tree_fused", "spec_tree_host"])
 def test_compact_prefill_matches_slot_grid(loop, family, monkeypatch):
     """The same requests served with the compact [segments x chunk]
     prefill batch and with the slot grid give the same output tokens and
-    equal K/V caches over every written position, in each Python loop."""
+    equal K/V caches over every written position, in each Python loop
+    (the fused one under either engine: one draft's beams, two drafts'
+    chains; Falcon has no beam head, so there one draft's chain)."""
     with monkeypatch.context() as mp:
         outs, kv, draft_kv, seen = _serve_one_way(loop, family, True, mp)
     with monkeypatch.context() as mp:
@@ -1381,8 +1431,8 @@ def test_compact_prefill_matches_slot_grid(loop, family, monkeypatch):
 
 
 @pytest.mark.parametrize("slots", [1, 2])
-@pytest.mark.parametrize("loop", ["incr", "spec_chain", "spec_tree_fused",
-                                  "spec_tree_host"])
+@pytest.mark.parametrize("loop", ["incr", "spec_beam_fused",
+                                  "spec_tree_fused", "spec_tree_host"])
 def test_compact_prefill_chunk_wider_than_cache(loop, slots, monkeypatch):
     """With one or two slots the chunk is the batch's token budget over
     them, which a short max_sequence_length falls under: the by-slot

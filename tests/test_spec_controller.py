@@ -188,15 +188,17 @@ def test_engine_depth_vector_caps_and_adapts(tiny_spec_pair):
     """One compiled block serves a mixed-depth batch: row depths bound
     acceptance per row, the device grows a fully-accepting row's depth
     between rounds, and depth_used reports what each round ran under."""
-    from flexflow_tpu.serve.engine import SpecChainEngine
+    from flexflow_tpu.serve.engine import MultiSpecEngine
 
     llm, ssm = tiny_spec_pair                 # same weights: full accepts
-    eng = SpecChainEngine(llm, ssm, depth=4, max_rounds=8)
-    tok = np.array([5, 5], np.int32)
-    pos = np.zeros((2,), np.int32)
+    eng = MultiSpecEngine(llm, [ssm], depth=4, max_rounds=8)
+    tks = np.zeros((2, 5), np.int32)
+    tks[:, 0] = 5                             # a one-token block a row
+    nblk = np.ones((2,), np.int32)
+    base = np.zeros((2,), np.int32)
     act = np.ones((2,), bool)
     remaining = np.full((2,), 12, np.int32)
-    a, n_acc, d_used = eng.run_block(tok, pos, act, 3, remaining,
+    a, n_acc, d_used = eng.run_block(tks, nblk, base, act, 3, remaining,
                                      depth=np.array([1, 4], np.int32),
                                      min_depth=1)
     assert a.shape[2] == 5 and n_acc.shape == d_used.shape
@@ -246,17 +248,18 @@ def test_give_up_is_the_blocks_not_the_rows():
 # end to end: a zero-acceptance draft must not lose to incremental
 # ---------------------------------------------------------------------------
 
-def _adversarial_ssm():
+def _adversarial_ssm(beam=1):
     """1-layer draft with UNRELATED weights (seed 99): cheap enough that
     the cost model starts out speculating, wrong enough that acceptance
-    is ~zero — the controller must detect and park within a few rounds."""
+    is ~zero — the controller must detect and park within a few rounds.
+    ``beam`` 2: compiled for the beam engine."""
     import flexflow_tpu as ff
     from flexflow_tpu.ffconst import InferenceMode
     from flexflow_tpu.models.llama import LLAMAConfig, create_llama_model
 
     cfg = ff.FFConfig(max_requests_per_batch=2, max_sequence_length=64,
                       max_tokens_per_batch=16, seed=99,
-                      kv_cache_dtype="float32")
+                      kv_cache_dtype="float32", max_beam_width=beam)
     m = ff.FFModel(cfg)
     create_llama_model(
         m,
@@ -334,14 +337,14 @@ def test_zero_acceptance_adversarial_draft_never_loses(tiny_spec_pair):
                       f"({dt_spec:.3f}s vs {dt_incr:.3f}s, informational)")
 
 
-def test_zero_acceptance_fused_tree_path_parks_too(tiny_spec_pair):
-    """The B=1 fused TREE engine (the path the on-TPU bench sweep runs,
-    request_manager._generate_spec_tree_fused) gets the same controller:
-    adversarial draft -> park -> tokens identical to incremental."""
+def test_zero_acceptance_beam_engine_parks_too(tiny_spec_pair):
+    """The beam engine gets the same controller through the same loop
+    (the tree engine is the test above): adversarial draft -> park ->
+    tokens identical to incremental."""
     from flexflow_tpu.telemetry import ServingTelemetry
 
     llm, _good = tiny_spec_pair
-    adv = _adversarial_ssm()
+    adv = _adversarial_ssm(beam=2)
     prompts = [[5, 9, 23, 44], [7, 3, 11]]
 
     rm = RequestManager()
@@ -354,14 +357,16 @@ def test_zero_acceptance_fused_tree_path_parks_too(tiny_spec_pair):
     rm2 = RequestManager(telemetry=tel)
     for p in prompts:
         rm2.register_new_request(p, max_new_tokens=16)
-    res = rm2._generate_spec_tree_fused(llm, [adv])
+    res = rm2.generate_spec_infer(llm, [adv])
+    assert rm2.scheduler_loop == "python:spec_beam_fused"
     assert {tuple(r.input_tokens): r.output_tokens for r in res} == incr
     assert tel.registry.get("ffsv_spec_fallback_total").value >= 2
     assert tel.registry.get("ffsv_spec_rounds_total").value <= 12
 
 
-@pytest.mark.parametrize("engine", ["chain", "tree"])
-def test_parked_row_never_stalls_a_drafting_batch(tiny_spec_pair, engine,
+@pytest.mark.parametrize("engine", ["beam", "tree"])
+def test_parked_row_never_stalls_a_drafting_batch(tiny_spec_pair,
+                                                  tiny_beam_draft, engine,
                                                   monkeypatch):
     """A request the controller holds parked from its first round, beside
     one that drafts: no fallback decode block runs while both are live
@@ -371,6 +376,8 @@ def test_parked_row_never_stalls_a_drafting_batch(tiny_spec_pair, engine,
     import dataclasses
 
     llm, ssm = tiny_spec_pair
+    if engine == "beam":
+        ssm = tiny_beam_draft
     prompts = [[5, 9, 23, 44], [7, 3, 11]]
     rm = RequestManager()
     for p in prompts:
@@ -401,13 +408,10 @@ def test_parked_row_never_stalls_a_drafting_batch(tiny_spec_pair, engine,
         return fallback(self, ifm, reqs, *args, **kwargs)
 
     monkeypatch.setattr(RequestManager, "_fallback_decode", counted)
-    gc = GenerationConfig(spec_draft_cost_ratio=0.1)
-    if engine == "chain":
-        res = rm2.generate_spec_infer(llm, [ssm], generation_config=gc)
-        assert rm2.scheduler_loop == "python:spec_chain"
-    else:
-        res = rm2._generate_spec_tree_fused(llm, [ssm],
-                                            generation_config=gc)
+    res = rm2.generate_spec_infer(
+        llm, [ssm],
+        generation_config=GenerationConfig(spec_draft_cost_ratio=0.1))
+    assert rm2.scheduler_loop == f"python:spec_{engine}_fused"
     out = {tuple(r.input_tokens): r.output_tokens for r in res}
     assert out[tuple(prompts[0])] == incr[tuple(prompts[0])]
     assert out[tuple(prompts[1])][:12] == incr[tuple(prompts[1])]
@@ -465,16 +469,16 @@ def test_generation_config_depth_override(tiny_spec_pair):
     seen = {}
     from flexflow_tpu.serve import request_manager as rmod
 
-    orig = rmod.RequestManager._generate_spec_chain
+    orig = rmod.RequestManager._generate_spec_fused
 
-    def spy(self, llm_, ssm_, spec_depth=None, beam_width=1,
+    def spy(self, llm_, ssms_, loop, beam_width, spec_depth=None,
             generation_config=None):
         seen["depth"] = spec_depth
-        return orig(self, llm_, ssm_, spec_depth=spec_depth,
-                    beam_width=beam_width,
+        return orig(self, llm_, ssms_, loop, beam_width,
+                    spec_depth=spec_depth,
                     generation_config=generation_config)
 
-    rmod.RequestManager._generate_spec_chain = spy
+    rmod.RequestManager._generate_spec_fused = spy
     try:
         rm = RequestManager()
         rm.register_new_request([5, 9], max_new_tokens=4)
@@ -482,5 +486,5 @@ def test_generation_config_depth_override(tiny_spec_pair):
             llm, [ssm], spec_depth=4,
             generation_config=GenerationConfig(spec_depth=2))
     finally:
-        rmod.RequestManager._generate_spec_chain = orig
+        rmod.RequestManager._generate_spec_fused = orig
     assert seen["depth"] == 2

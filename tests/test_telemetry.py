@@ -159,7 +159,9 @@ def test_spec_decode_records_expected_telemetry(tiny_spec_pair, tmp_path):
         assert reg.get("ffsv_batch_occupancy").count > 0
         assert reg.get("ffsv_batch_occupancy").percentile(50) == 1.0
         assert reg.get("ffsv_kv_cache_utilization").count > 0
-        assert reg.get("ffsv_prefill_tokens_total").value == 10  # 5 x 2 models
+        # the verifier's 5; the draft's 3 of the four-token prompt (the
+        # three-token one rides in as its row's first accepted block)
+        assert reg.get("ffsv_prefill_tokens_total").value == 8
         assert reg.get("ffsv_spec_block_seconds").count >= 1
         lat = reg.get("ffsv_per_token_latency_seconds")
         assert lat.count == 2
@@ -231,28 +233,30 @@ def test_disabled_path_records_no_events(tiny_spec_pair):
 LEAVES = ("sched_admit", "sched_build", "sched_commit",
           "call_stage", "call_launch", "call_wait")
 VOCABULARY = LEAVES + ("sched_round", "spec_block")
-LOOPS = ("incr", "spec_chain", "spec_tree")
+LOOPS = ("incr", "spec_beam", "spec_tree")
 
 
 def _serve(loop, llm, ssm, rm):
     """Three requests through two slots on one of the Python scheduler
-    loops: a refill mid-batch, so rounds with and without a prefill."""
+    loops (the fused speculation loop under either engine: ``ssm`` is
+    compiled at beam width 2 for ``spec_beam``): a refill mid-batch, so
+    rounds with and without a prefill."""
     from flexflow_tpu.serve.batch_config import GenerationConfig
 
     for p in [[5, 9, 23, 44], [7, 3, 11], [9, 9, 4, 1, 2]]:
         rm.register_new_request(p, max_new_tokens=8)
     if loop == "incr":
         return rm.generate_incr_decoding(llm)
-    gc = GenerationConfig(adaptive_spec=False)
-    if loop == "spec_chain":
-        return rm._generate_spec_chain(llm, ssm, spec_depth=2,
-                                       generation_config=gc)
-    return rm._generate_spec_tree_fused(llm, [ssm], spec_depth=2,
-                                        generation_config=gc)
+    results = rm.generate_spec_infer(
+        llm, [ssm], spec_depth=2,
+        generation_config=GenerationConfig(adaptive_spec=False))
+    assert rm.scheduler_loop == f"python:{loop}_fused"
+    return results
 
 
 @pytest.mark.parametrize("loop", LOOPS)
-def test_scheduler_round_span_vocabulary(loop, tiny_spec_pair, monkeypatch):
+def test_scheduler_round_span_vocabulary(loop, tiny_spec_pair,
+                                         tiny_beam_draft, monkeypatch):
     """A served batch emits each vocabulary span once per occurrence on
     tid 0; the leaves inside a sched_round do not overlap and cover it;
     spec_block.rounds is what the device ran (the executed columns of
@@ -260,8 +264,10 @@ def test_scheduler_round_span_vocabulary(loop, tiny_spec_pair, monkeypatch):
     from flexflow_tpu.serve import engine as eng
 
     llm, ssm = tiny_spec_pair
+    if loop == "spec_beam":
+        ssm = tiny_beam_draft
     blocks = []                       # (rounds asked, n_acc) per run_block
-    for cls in (eng.SpecChainEngine, eng.MultiSpecEngine):
+    for cls in (eng.BeamSpecEngine, eng.MultiSpecEngine):
         def spy(self, *a, _orig=cls.run_block,
                 _sig=inspect.signature(cls.run_block), **kw):
             out = _orig(self, *a, **kw)
@@ -316,10 +322,10 @@ def test_scheduler_round_span_vocabulary(loop, tiny_spec_pair, monkeypatch):
         assert ev["args"]["rounds"] == ran <= asked
         assert ev["args"]["rounds_asked"] == asked
         assert ev["args"]["rows"] == int((n_acc >= 0).any(axis=1).sum())
-        assert ev["args"]["engine"] == ("SpecChainEngine"
-                                        if loop == "spec_chain"
+        assert ev["args"]["engine"] == ("BeamSpecEngine"
+                                        if loop == "spec_beam"
                                         else "MultiSpecEngine")
-    if loop == "spec_tree":
+    if loop != "incr":
         # the next block is handed the last block's accepted tokens: no
         # catch-up chunk, so only a prompt going in cuts a block short
         assert {r["args"].get("cut") for r in rounds} == {None, "prefill"}
@@ -346,9 +352,10 @@ def test_scheduler_round_span_vocabulary(loop, tiny_spec_pair, monkeypatch):
     assert covered >= 0.9 * sum(r["dur"] for r in rounds)
 
 
-def test_disabled_path_enters_no_annotation(tiny_spec_pair, monkeypatch):
-    """Telemetry off: a batch served on each of the three loops records
-    no event and enters no profiler annotation."""
+def test_disabled_path_enters_no_annotation(tiny_spec_pair,
+                                            tiny_beam_draft, monkeypatch):
+    """Telemetry off: a batch served on each loop, under each engine,
+    records no event and enters no profiler annotation."""
     from flexflow_tpu.telemetry import tracing
 
     llm, ssm = tiny_spec_pair
@@ -362,7 +369,8 @@ def test_disabled_path_enters_no_annotation(tiny_spec_pair, monkeypatch):
     monkeypatch.setattr(tracing, "TraceAnnotation", Counting)
     disable_telemetry()
     for loop in LOOPS:
-        results = _serve(loop, llm, ssm, RequestManager())
+        results = _serve(loop, llm, tiny_beam_draft
+                         if loop == "spec_beam" else ssm, RequestManager())
         assert len(results) == 3
     assert get_telemetry() is None and not entered
     tel = enable_telemetry()
@@ -386,6 +394,7 @@ def _tools_profile_trace():
 
 
 def test_profiler_session_holds_spans_and_clock_mark(tiny_spec_pair,
+                                                     tiny_beam_draft,
                                                      tmp_path):
     """A CPU jax.profiler session around a served batch holds the
     program's batch-level spans on its host plane under their names, and
@@ -395,12 +404,12 @@ def test_profiler_session_holds_spans_and_clock_mark(tiny_spec_pair,
     from flexflow_tpu.utils.profiling import profiler_trace
 
     pt = _tools_profile_trace()
-    llm, ssm = tiny_spec_pair
+    llm, ssm = tiny_spec_pair[0], tiny_beam_draft
     logdir = str(tmp_path / "prof")
     tel = enable_telemetry()
     try:
         with profiler_trace(logdir):
-            _serve("spec_chain", llm, ssm, RequestManager())
+            _serve("spec_beam", llm, ssm, RequestManager())
         events = tel.tracer.events
     finally:
         disable_telemetry()
