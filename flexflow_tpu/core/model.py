@@ -782,16 +782,27 @@ class FFModel:
     def moe_experts(self, input: Tensor, indices: Tensor, weights: Tensor,
                     num_experts: int, expert_width: int,
                     data_type: Optional[DataType] = None, name=None,
-                    held: Optional[Tuple[int, int]] = None):
+                    held: Optional[Tuple[int, int]] = None,
+                    zero_experts: Optional[Tuple[int, int]] = None):
         """The serving path's routed SwiGLU experts (ops/moe.MoeExperts):
         dropless, over the step's real tokens, through the grouped kernel.
         ``indices``/``weights`` are the router's top-k over ``num_experts``.
         ``held`` ``(first, count)``: this chip's share of an expert-parallel
         layer. It holds experts ``[first, first + count)`` of the router's
         ``num_experts`` and computes their part of the result; a pair routed
-        elsewhere is no work here. Without it the layer holds them all."""
+        elsewhere is no work here. Without it the layer holds them all.
+        ``zero_experts`` ``(first, count)``: indices of the router's
+        ``num_experts`` that name no expert anywhere (outside ``held``): a
+        pick of one adds ``weight * input`` and costs no expert's
+        arithmetic."""
         attrs = dict(num_experts=num_experts, expert_width=expert_width,
                      data_type=data_type)
+        if zero_experts is not None:
+            assert held is not None, "say which of the indices are experts"
+            (z0, zn), (h0, hn) = zero_experts, held
+            assert 0 <= z0 and z0 + zn <= num_experts and (
+                h0 + hn <= z0 or z0 + zn <= h0), (held, zero_experts)
+            attrs["zero_experts"] = (int(z0), int(zn))
         if held is not None and tuple(held) != (0, num_experts):
             first, count = held
             assert 0 <= first and first + count <= num_experts, held

@@ -969,6 +969,33 @@ def supports_latent(S: int, width: int, rank: int) -> bool:
 
 # rows of a write-back window: a whole packed tile of a 16-bit cache
 LATENT_APPEND_ROWS = 16
+# the most a call asks of the chip's 128 MiB of VMEM
+LATENT_VMEM_LIMIT = 100 * 1024 * 1024
+
+
+def _latent_vmem_bytes(GQ: int, W: int, rank: int, DB: int, SB: int,
+                       q_itemsize: int, c_itemsize: int) -> int:
+    """VMEM a call over ``GQ`` query rows (heads x tokens) asks for."""
+    return (2 * GQ * (W + rank) * q_itemsize    # q, o double-buffered
+            + GQ * (rank + 2 * LANE) * 4        # acc, m, l
+            + 4 * DB * W * c_itemsize           # the stream's buffers
+            + 6 * GQ * SB * 4                   # scores and their kin
+            + 2 * GQ * rank * 4 + 4 * 1024 * 1024)
+
+
+def latent_head_groups(H: int, Q: int, W: int, rank: int, S: int,
+                       q_itemsize: int = 2, c_itemsize: int = 2) -> int:
+    """In how many groups of heads ``flash_attend_latent`` takes ``Q``
+    query tokens of ``H`` heads a row, so that a group's rows fit VMEM: the
+    fewest that divide ``H``; 1 where all fit (32 heads of 128 tokens of 384
+    lanes); 0 where not even one head does. Of the shapes alone."""
+    DB, SB = _pick_latent_blocks(S)
+    for g in range(1, H + 1):
+        if H % g == 0 and _latent_vmem_bytes(
+                H // g * Q, W, rank, DB, SB, q_itemsize,
+                c_itemsize) <= LATENT_VMEM_LIMIT:
+            return g
+    return 0
 
 
 def _latent_kernel(len_ref, *refs, mode, DB: int, SB: int, rank: int,
@@ -1147,6 +1174,20 @@ def flash_attend_latent(q, cache, lengths, qpos, rows=None, append=None, *,
                                                            cache.shape)
     DB, SB = _pick_latent_blocks(S)
     assert supports_latent(S, W, rank), (S, W, rank)
+    groups = latent_head_groups(H, Q, W, rank, S, q.dtype.itemsize,
+                                cache.dtype.itemsize)
+    assert groups > 0, ("no head of these queries fits VMEM", q.shape)
+    if groups > 1:
+        # the query rows of ALL heads do not fit VMEM beside the stream:
+        # the heads in groups, each a call of its own over the same cache
+        # (64 heads of a 128-token segment of 640 lanes: two calls of 32)
+        assert append is None, "a decode step's rows fit in one call"
+        step = H // groups
+        return jnp.concatenate(
+            [flash_attend_latent(
+                q[:, :, h:h + step], cache, lengths, qpos, rows, rank=rank,
+                qk_scale=qk_scale, out_dtype=out_dtype, layer_idx=layer_idx,
+                interpret=interpret) for h in range(0, H, step)], axis=2)
     GQ = H * Q
     out_dtype = out_dtype or q.dtype
     # [R, Q, H, W] -> [R, H*Q, W], row index h*Q + q
@@ -1158,12 +1199,8 @@ def flash_attend_latent(q, cache, lengths, qpos, rows=None, append=None, *,
     isz = cache.dtype.itemsize
     compiler_params = pltpu.CompilerParams(
         vmem_limit_bytes=int(min(
-            100 * 1024 * 1024,
-            2 * GQ * (W + rank) * q.dtype.itemsize     # q, o double-buffered
-            + GQ * (rank + 2 * LANE) * 4               # acc, m, l
-            + 4 * DB * W * isz                         # the stream's buffers
-            + 6 * GQ * SB * 4                          # scores and their kin
-            + 2 * GQ * rank * 4 + 4 * 1024 * 1024)))
+            LATENT_VMEM_LIMIT,
+            _latent_vmem_bytes(GQ, W, rank, DB, SB, q.dtype.itemsize, isz))))
     cost_estimate = pl.CostEstimate(
         flops=2 * R * GQ * S * (W + rank),
         bytes_accessed=R * S * W * isz,

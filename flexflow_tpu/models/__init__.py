@@ -2,7 +2,7 @@
 
 Capability parity with the reference model zoo (reference inference/models/
 llama.cc, opt.cc, falcon.cc, mpt.cc, starcoder.cc and their Python twins in
-python/flexflow/serve/models/; OLMoE, EXAONE-MoE, Mistral-4 and SDAR-MoE, sparse-expert families, and EvaByte, a byte-level model of chunked attention, are beyond it): each model family is a builder that records
+python/flexflow/serve/models/; OLMoE, EXAONE-MoE, Mistral-4, SDAR-MoE and LongCat-Flash, sparse-expert families, and EvaByte, a byte-level model of chunked attention, are beyond it): each model family is a builder that records
 the decoder graph through the FFModel op-builder surface, plus a HuggingFace
 state-dict name mapping so real checkpoints load. ``FAMILIES`` maps the HF
 ``model_type`` to the family (the reference's ModelType enum +
@@ -16,6 +16,7 @@ from flexflow_tpu.models import evabyte as _evabyte
 from flexflow_tpu.models import exaone_moe as _exaone_moe
 from flexflow_tpu.models import falcon as _falcon
 from flexflow_tpu.models import llama as _llama
+from flexflow_tpu.models import longcat_flash as _longcat_flash
 from flexflow_tpu.models import mistral4 as _mistral4
 from flexflow_tpu.models import mpt as _mpt
 from flexflow_tpu.models import olmoe as _olmoe
@@ -28,6 +29,8 @@ from flexflow_tpu.models.exaone_moe import (ExaoneMoEConfig,
 from flexflow_tpu.models.falcon import FalconConfig, create_falcon_model
 from flexflow_tpu.models.hf_utils import load_hf_state_dict
 from flexflow_tpu.models.llama import LLAMAConfig, create_llama_model
+from flexflow_tpu.models.longcat_flash import (LongcatFlashConfig,
+                                               create_longcat_flash_model)
 from flexflow_tpu.models.mistral4 import (Mistral4Config,
                                           create_mistral4_model)
 from flexflow_tpu.models.mpt import MPTConfig, create_mpt_model
@@ -77,6 +80,10 @@ FAMILIES = {
     "mistral4": ModelFamily("mistral4", Mistral4Config,
                             create_mistral4_model, _mistral4.hf_weight_map,
                             _mistral4.preprocess_hf_state_dict),
+    "longcat_flash": ModelFamily("longcat_flash", LongcatFlashConfig,
+                                 create_longcat_flash_model,
+                                 _longcat_flash.hf_weight_map,
+                                 _longcat_flash.preprocess_hf_state_dict),
     "sdar_moe": ModelFamily("sdar_moe", SDARMoEConfig, create_sdar_moe_model,
                             _sdar_moe.hf_weight_map,
                             _sdar_moe.preprocess_hf_state_dict),
@@ -110,6 +117,7 @@ __all__ = [
     "FAMILIES",
     "FalconConfig",
     "LLAMAConfig",
+    "LongcatFlashConfig",
     "MPTConfig",
     "Mistral4Config",
     "ModelFamily",
@@ -121,6 +129,7 @@ __all__ = [
     "create_exaone_moe_model",
     "create_falcon_model",
     "create_llama_model",
+    "create_longcat_flash_model",
     "create_mistral4_model",
     "create_mpt_model",
     "create_olmoe_model",

@@ -226,8 +226,6 @@ def preprocess_hf_state_dict(sd, config: Mistral4Config):
     ``kv_b_proj`` into its key and value halves, a head apart; permute the
     rope columns of ``q_b_proj`` and ``kv_a_proj_with_mqa`` from the
     checkpoint's adjacent pairing to the op's halves."""
-    from flexflow_tpu.models.hf_utils import _to_numpy
-
     c = config
     for k in [k for k in sd if "vision_tower" in k or "vision_encoder" in k
               or "multi_modal_projector" in k or "patch_merger" in k]:
@@ -235,30 +233,60 @@ def preprocess_hf_state_dict(sd, config: Mistral4Config):
     for k in [k for k in sd if _TEXT in k]:
         sd[k.replace(_TEXT, "")] = sd.pop(k)
     first, count = c.held
-    H, dn, dr, dv = (c.num_attention_heads, c.qk_nope_head_dim,
-                     c.qk_rope_head_dim, c.v_head_dim)
-    rank = c.kv_lora_rank
-    perm = (rope_permutation(dr) if c.rope_interleave else np.arange(dr))
+    perm = (rope_permutation(c.qk_rope_head_dim) if c.rope_interleave
+            else np.arange(c.qk_rope_head_dim))
     for i in range(c.num_hidden_layers):
-        a = f"model.layers.{i}.self_attn"
-        if f"{a}.kv_b_proj.weight" in sd:       # [H * (dn + dv), rank]
-            w = _to_numpy(sd.pop(f"{a}.kv_b_proj.weight")).reshape(
-                H, dn + dv, rank)
-            sd[f"{a}.kv_b_proj.key"] = w[:, :dn].transpose(0, 2, 1)
-            sd[f"{a}.kv_b_proj.value"] = w[:, dn:].transpose(0, 2, 1)
-        if f"{a}.q_b_proj.weight" in sd:        # [H * (dn + dr), q_rank]
-            w = _to_numpy(sd[f"{a}.q_b_proj.weight"])
-            w = w.reshape(H, dn + dr, -1)
-            sd[f"{a}.q_b_proj.weight"] = np.concatenate(
-                [w[:, :dn], w[:, dn:][:, perm]], axis=1).reshape(
-                    H * (dn + dr), -1)
-        if f"{a}.kv_a_proj_with_mqa.weight" in sd:   # [rank + dr, hidden]
-            w = _to_numpy(sd[f"{a}.kv_a_proj_with_mqa.weight"])
-            sd[f"{a}.kv_a_proj_with_mqa.weight"] = np.concatenate(
-                [w[:rank], w[rank:][perm]], axis=0)
+        prepare_latent_attention(sd, f"model.layers.{i}.self_attn", c, perm)
         if i < c.first_k_dense_replace:
             continue
         stack_held_experts(sd, i, c.n_routed_experts, first, count)
+
+
+def prepare_latent_attention(sd, a: str, c, perm, latent_scale: float = 1.0):
+    """One latent attention's entries under the checkpoint prefix ``a``
+    (``c`` has the six MLA sizes): ``kv_b_proj`` split into its key and
+    value halves, a head apart; the rope columns of ``q_b_proj`` and
+    ``kv_a_proj_with_mqa`` reordered by ``perm``; ``kv_a_layernorm`` times
+    ``latent_scale`` (a model that multiplies the normed latent by a
+    constant before ``kv_b_proj``: ``RMSNorm(c) * w * s`` exactly). Also
+    what models/longcat_flash.py prepares, twice a layer."""
+    from flexflow_tpu.models.hf_utils import _to_numpy
+
+    H, dn, dr, dv = (c.num_attention_heads, c.qk_nope_head_dim,
+                     c.qk_rope_head_dim, c.v_head_dim)
+    rank = c.kv_lora_rank
+    if f"{a}.kv_b_proj.weight" in sd:       # [H * (dn + dv), rank]
+        w = _to_numpy(sd.pop(f"{a}.kv_b_proj.weight")).reshape(
+            H, dn + dv, rank)
+        sd[f"{a}.kv_b_proj.key"] = w[:, :dn].transpose(0, 2, 1)
+        sd[f"{a}.kv_b_proj.value"] = w[:, dn:].transpose(0, 2, 1)
+    if f"{a}.q_b_proj.weight" in sd:        # [H * (dn + dr), q_rank]
+        w = _to_numpy(sd[f"{a}.q_b_proj.weight"])
+        w = w.reshape(H, dn + dr, -1)
+        sd[f"{a}.q_b_proj.weight"] = np.concatenate(
+            [w[:, :dn], w[:, dn:][:, perm]], axis=1).reshape(
+                H * (dn + dr), -1)
+    if f"{a}.kv_a_proj_with_mqa.weight" in sd:   # [rank + dr, hidden]
+        w = _to_numpy(sd[f"{a}.kv_a_proj_with_mqa.weight"])
+        sd[f"{a}.kv_a_proj_with_mqa.weight"] = np.concatenate(
+            [w[:rank], w[rank:][perm]], axis=0)
+    if latent_scale != 1.0 and f"{a}.kv_a_layernorm.weight" in sd:
+        sd[f"{a}.kv_a_layernorm.weight"] = _to_numpy(
+            sd[f"{a}.kv_a_layernorm.weight"]) * np.float32(latent_scale)
+
+
+def latent_attention_map(hf: str, ff: str) -> dict:
+    """HF key -> (layer_name, weight_name, transpose?) of one latent
+    attention whose checkpoint prefix is ``hf`` and whose layer is ``ff``,
+    over entries ``prepare_latent_attention`` has prepared."""
+    m = {f"{hf}.{p}.weight": (ff, w, True)
+         for p, w in (("q_a_proj", "wq_a"), ("q_b_proj", "wq_b"),
+                      ("kv_a_proj_with_mqa", "wkv_a"), ("o_proj", "wo"))}
+    m[f"{hf}.q_a_layernorm.weight"] = (ff, "q_norm", False)
+    m[f"{hf}.kv_a_layernorm.weight"] = (ff, "kv_norm", False)
+    m[f"{hf}.kv_b_proj.key"] = (ff, "wk_b", False)
+    m[f"{hf}.kv_b_proj.value"] = (ff, "wv_b", False)
+    return m
 
 
 def hf_weight_map(config: Mistral4Config):
@@ -269,14 +297,7 @@ def hf_weight_map(config: Mistral4Config):
          "lm_head.weight": ("lm_head", "kernel", True)}
     for i in range(config.num_hidden_layers):
         hf, ff = f"model.layers.{i}", f"layers.{i}"
-        a = f"{ff}.self_attn"
-        for p, w in (("q_a_proj", "wq_a"), ("q_b_proj", "wq_b"),
-                     ("kv_a_proj_with_mqa", "wkv_a"), ("o_proj", "wo")):
-            m[f"{hf}.self_attn.{p}.weight"] = (a, w, True)
-        m[f"{hf}.self_attn.q_a_layernorm.weight"] = (a, "q_norm", False)
-        m[f"{hf}.self_attn.kv_a_layernorm.weight"] = (a, "kv_norm", False)
-        m[f"{hf}.self_attn.kv_b_proj.key"] = (a, "wk_b", False)
-        m[f"{hf}.self_attn.kv_b_proj.value"] = (a, "wv_b", False)
+        m.update(latent_attention_map(f"{hf}.self_attn", f"{ff}.self_attn"))
         for p in ("input_layernorm", "post_attention_layernorm"):
             m[f"{hf}.{p}.weight"] = (f"{ff}.{p}", "weight", False)
         dense = i < config.first_k_dense_replace

@@ -136,6 +136,7 @@ def _attend(attrs, q, entry, cache, layer_idx: int, meta, ctx):
     step appends first (``append_latent``)."""
     from flexflow_tpu import kernels as ffk
     from flexflow_tpu.kernels.attention import (flash_attend_latent,
+                                                latent_head_groups,
                                                 reference_attend_latent,
                                                 supports_latent)
 
@@ -150,12 +151,17 @@ def _attend(attrs, q, entry, cache, layer_idx: int, meta, ctx):
     kernel = False
     if ffk.use_pallas(ctx.config if ctx is not None else None):
         kernel = supports_latent(S, cache.shape[-1], rank)
-        if kernel:
-            ffk.record_fast_path()
-        else:
+        if not kernel:
             ffk.record_fallback(
                 f"latent cache S={S} width={cache.shape[-1]} rank={rank} "
                 "not tileable")
+        elif not latent_head_groups(q.shape[2], Q, cache.shape[-1], rank, S,
+                                    q.dtype.itemsize, cache.dtype.itemsize):
+            kernel = False
+            ffk.record_fallback(
+                f"latent queries of {Q} tokens: not one head's rows fit VMEM")
+        else:
+            ffk.record_fast_path()
     if kernel and Q == 1 and rows is None:
         appos = jnp.where(
             meta.active & (meta.num_tokens > 0) & (meta.start_pos < S),

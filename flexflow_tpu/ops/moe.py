@@ -176,6 +176,18 @@ class Experts(OpImpl):
 MOE_COUNTERS = "moe_counters"
 MOE_PHASES = ("decode", "prefill", "verify")
 MOE_FIELDS = ("calls", "tokens", "routed", "touched", "resident")
+# one field more, for a model whose router has picks that are no expert
+# (``zero_experts``): the pairs a phase that cost nothing. No other model's
+# counter row has it
+ZERO_FIELD = "zero"
+
+
+def counter_fields(layers) -> tuple:
+    """The fields of a counter row of a model whose expert layers are
+    ``layers`` (its MOE_EXPERTS layers, or their attrs)."""
+    attrs = [getattr(ly, "attrs", ly) for ly in layers]
+    (zero,) = {a.get("zero_experts") is not None for a in attrs}
+    return MOE_FIELDS + ((ZERO_FIELD,) if zero else ())
 
 
 def init_counters(model):
@@ -191,7 +203,8 @@ def init_counters(model):
     for i, ly in enumerate(layers):
         ly.attrs["counter_row"] = i
     model.op_state[MOE_COUNTERS] = jnp.zeros(
-        (len(layers), E + len(MOE_FIELDS) * len(MOE_PHASES)), jnp.uint32)
+        (len(layers), E + len(counter_fields(layers)) * len(MOE_PHASES)),
+        jnp.uint32)
 
 
 def _step_tokens(ctx, x):
@@ -262,7 +275,15 @@ class MoeExperts(OpImpl):
     ``[first_expert, first_expert + num_experts)`` of that many, the
     indices are the router's over all of them, and the output is the held
     experts' part of the sum (a pair routed elsewhere is no pair: it is
-    neither computed nor counted, and experts count by held index)."""
+    neither computed nor counted, and experts count by held index).
+
+    With ``attrs["zero_experts"]`` ``(first, count)`` the router's indices
+    ``[first, first + count)`` name NO expert on any chip: such a pick adds
+    ``w * x`` where the token lives (the identity, no arithmetic of an
+    expert), so on every chip for its own tokens, like a shared expert and
+    unlike a routed one. It is never a row of the kernel and never
+    ``routed``; the layer's counter row has one field more, ``zero``: those
+    picks a phase."""
 
     op_type = OpType.MOE_EXPERTS
     quant_aware = True
@@ -317,15 +338,26 @@ class MoeExperts(OpImpl):
             y, sizes = run(*flat)
         else:
             y, sizes = _in_chunks(*flat, cap, run, E)
-        y = jnp.pad(y.reshape(R, q, H), ((0, 0), (0, Q - q), (0, 0)))
+        y = y.reshape(R, q, H)
+        zero = attrs.get("zero_experts")
+        if zero is not None:
+            f32 = jnp.float32
+            at_zero = ((idx[:, :q] >= zero[0])
+                       & (idx[:, :q] < zero[0] + zero[1]) & valid[..., None])
+            zw = jnp.sum(jnp.where(at_zero, w[:, :q].astype(f32), 0.0), -1)
+            y = (y.astype(f32)
+                 + x[:, :q].astype(f32) * zw[..., None]).astype(y.dtype)
+        y = jnp.pad(y, ((0, 0), (0, Q - q), (0, 0)))
         row = attrs.get("counter_row")
         if row is not None:
             u32, n = jnp.uint32, len(MOE_PHASES)
-            step = jnp.stack([jnp.uint32(1), jnp.sum(valid, dtype=u32),
-                              jnp.sum(sizes, dtype=u32),
-                              jnp.sum(sizes > 0, dtype=u32),
-                              jnp.uint32(resident)])
-            at = E + n * jnp.arange(len(MOE_FIELDS)) + MOE_PHASES.index(phase)
+            step = [jnp.uint32(1), jnp.sum(valid, dtype=u32),
+                    jnp.sum(sizes, dtype=u32), jnp.sum(sizes > 0, dtype=u32),
+                    jnp.uint32(resident)]
+            if zero is not None:
+                step.append(jnp.sum(at_zero, dtype=u32))
+            step = jnp.stack(step)
+            at = E + n * jnp.arange(len(step)) + MOE_PHASES.index(phase)
             st = ctx.state_out.get(MOE_COUNTERS)
             if st is None:
                 st = ctx.state_in[MOE_COUNTERS]

@@ -73,6 +73,7 @@ ffsv_moe_tokens_total            counter    {phase} real tokens the experts saw
 ffsv_moe_experts_touched         summary    {phase} distinct experts a call read
 ffsv_moe_resident_calls_total    counter    {phase} calls that kept their rows in VMEM
 ffsv_moe_expert_pairs_total      counter    {expert} routed pairs of one expert
+ffsv_moe_zero_pairs_total        counter    {phase} picks that were no expert (w * x)
 ===============================  =========  =================================
 
 A decode block's step is one token a row, or one pass over a row's block
@@ -125,6 +126,9 @@ share of its router's experts (``held``) counts its own: ``expert`` is the
 held index, and a pair routed to an expert held elsewhere is no pair.
 ``phase`` is
 ``decode``, ``prefill`` or ``verify``, fixed when a program is traced.
+``ffsv_moe_zero_pairs_total`` exists only for a model whose router has
+indices that name no expert (``zero_experts``): the picks of those, which
+add ``w * x``, are no ``routed`` pair and touch nothing.
 
 Batch-level spans (``tracing.SpanTracer.begin``/``end``, ``tid`` 0): the
 Python scheduler loops open a ``RoundTrace`` per iteration (``sched_round``
@@ -506,11 +510,15 @@ class ServingTelemetry:
         ``model``'s routed-expert counters off the device. Called once a
         device call (serve/inference_manager.py); a model without such
         counters costs two dict lookups."""
-        from flexflow_tpu.ops.moe import MOE_COUNTERS
+        from flexflow_tpu.ffconst import OpType
+        from flexflow_tpu.ops.moe import MOE_COUNTERS, counter_fields
 
         if (id(model) not in self._watched
                 and MOE_COUNTERS in (model.op_state or {})):
-            self._watched[id(model)] = [weakref.ref(model), 0]
+            self._watched[id(model)] = [
+                weakref.ref(model), 0,
+                counter_fields([ly for ly in model.layers
+                                if ly.op_type == OpType.MOE_EXPERTS])]
         for kind, a in (getattr(model, "attention_kinds", None)
                         or {}).items():     # rings beside full, or latent
             self.registry.gauge(
@@ -610,7 +618,7 @@ class ServingTelemetry:
         return None
 
     def _collect_moe(self):
-        from flexflow_tpu.ops.moe import MOE_FIELDS, MOE_PHASES
+        from flexflow_tpu.ops.moe import MOE_PHASES, ZERO_FIELD
 
         r = self.registry
         for key, watched in list(self._watched.items()):
@@ -626,10 +634,11 @@ class ServingTelemetry:
             # over the layers (the rows)
             gained = (raw - np.uint32(watched[1])).astype(np.int64).sum(0)
             watched[1] = raw
-            n = len(MOE_PHASES)
-            pairs = gained[:-len(MOE_FIELDS) * n]
-            calls, tokens, routed, touched, resident = gained[
-                len(pairs):].reshape(len(MOE_FIELDS), n)
+            n, fields = len(MOE_PHASES), watched[2]
+            pairs = gained[:-len(fields) * n]
+            calls, tokens, routed, touched, resident, *zero = gained[
+                len(pairs):].reshape(len(fields), n)
+            assert len(zero) == (ZERO_FIELD in fields)
             for i, ph in enumerate(MOE_PHASES):
                 lab = f'{{phase="{ph}"}}'
                 r.counter("ffsv_moe_routed_pairs_total" + lab,
@@ -644,6 +653,10 @@ class ServingTelemetry:
                 r.counter("ffsv_moe_resident_calls_total" + lab,
                           "expert-layer calls whose rows the kernel gathered "
                           "and added itself").inc(int(resident[i]))
+                if zero:
+                    r.counter("ffsv_moe_zero_pairs_total" + lab,
+                              "picks of a router index that names no expert"
+                              ).inc(int(zero[0][i]))
             for e, n_pairs in enumerate(pairs):
                 r.counter(f'ffsv_moe_expert_pairs_total{{expert="{e}"}}',
                           "routed pairs of one expert, over layers"

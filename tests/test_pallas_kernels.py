@@ -677,3 +677,83 @@ def test_flash_without_row_map_keeps_its_kernel_arguments():
 
     assert call(None) == (1, 7)
     assert call(jnp.asarray([1, 0], jnp.int32)) == (2, 8)
+
+
+# ---------------------------------------------------------------------------
+# the latent kernel at 64 heads of a 640-lane entry (LongCat-Flash's shape)
+# ---------------------------------------------------------------------------
+
+def _latent_case(R, Q, H, W, rank, S, starts, nums, seed=0):
+    rng = np.random.default_rng(seed)
+    starts, nums = np.array(starts), np.array(nums)
+    lengths = np.where(nums > 0, starts + nums, 0)
+    cache = jnp.asarray(rng.standard_normal((R, 1, S, W)) * 0.5,
+                        jnp.bfloat16)
+    q = jnp.asarray(rng.standard_normal((R, Q, H, W)), jnp.bfloat16)
+    qpos = starts[:, None] + np.arange(Q)[None]
+    return (q, cache, jnp.asarray(lengths, jnp.int32),
+            jnp.asarray(qpos, jnp.int32), starts, nums, lengths)
+
+
+@pytest.mark.parametrize("form", ["decode_append", "prefill_row_map"])
+def test_latent_kernel_at_64_heads_of_640_lanes(form):
+    """64 heads, rank 512, 640 stored lanes (512 + 64 in whole lane tiles):
+    the decode form, one token a row with the fused append, is ONE call of
+    64 query rows; the compact prefill's row map at 128 tokens a segment is
+    8192 query rows, which do not fit the kernel's VMEM beside the stream
+    (108.8 MB of scoped VMEM against 100, by the chip's compiler), so the
+    heads go in two calls of 32 over the same cache. Both against the jnp
+    oracle, on a stack."""
+    H, W, rank, S = 64, 640, 512, 1024
+    if form == "decode_append":
+        R, Q = 3, 1
+        q, cache, lengths, qpos, starts, nums, ln = _latent_case(
+            R, Q, H, W, rank, S, (0, 511, 1023), (1, 0, 1))
+        assert fa.latent_head_groups(H, Q, W, rank, S) == 1
+        at = (np.arange(R), 0, np.maximum(ln - 1, 0))
+        append = (cache[at][:, None, None],
+                  jnp.asarray(np.where(nums > 0, starts, -1), jnp.int32))
+        before = cache.at[at].set(7.0).at[1, 0, 0].set(cache[1, 0, 0])
+        out, c2 = fa.flash_attend_latent(
+            q, jnp.stack([before * 0, before]), lengths, qpos, None, append,
+            rank=rank, qk_scale=0.1, layer_idx=1, interpret=True)
+        np.testing.assert_array_equal(np.asarray(c2[1], np.float32),
+                                      np.asarray(cache, np.float32))
+        assert not np.asarray(c2[0]).any()
+        want = fa.reference_attend_latent(q, cache, lengths, qpos, rank=rank,
+                                          qk_scale=0.1)
+    else:
+        R, Q = 2, 128
+        q, cache, lengths, qpos, starts, nums, ln = _latent_case(
+            R, Q, H, W, rank, S, (896, 100), (128, 37))
+        assert fa.latent_head_groups(H, Q, W, rank, S) == 2
+        assert fa.latent_head_groups(H, Q, W, rank, 8192) == 2
+        rows = jnp.asarray([1, 0], jnp.int32)
+        out = fa.flash_attend_latent(
+            q, jnp.stack([cache * 0, cache]), lengths, qpos, rows,
+            rank=rank, qk_scale=0.1, layer_idx=1, interpret=True)
+        want = fa.reference_attend_latent(q, cache[rows], lengths, qpos,
+                                          rank=rank, qk_scale=0.1)
+    assert out.shape == (R, Q, H, rank)
+    for r in np.nonzero(nums)[0]:
+        np.testing.assert_allclose(
+            np.asarray(out[r, :nums[r]], np.float32),
+            np.asarray(want[r, :nums[r]], np.float32), atol=3e-2)
+
+
+@pytest.mark.parametrize("shape,groups", [
+    # (H, Q, W, rank, S): Mistral-Small-4's prefill segment and decode step
+    ((32, 128, 384, 256, 32768), 1), ((32, 1, 384, 256, 32768), 1),
+    # LongCat-Flash's: the prefill segment in halves, the decode step whole
+    ((64, 128, 640, 512, 8192), 2), ((64, 1, 640, 512, 8192), 1),
+    # a segment no head of which fits: the op falls back and says so
+    ((64, 16384, 640, 512, 8192), 0)])
+def test_latent_head_groups_are_decided_from_the_shapes(shape, groups):
+    """Mistral's shapes take the one call they took (its traced program is
+    the parent's); the groups divide the heads and each fits the budget."""
+    assert fa.latent_head_groups(*shape) == groups
+    H, Q, W, rank, S = shape
+    if groups:
+        DB, SB = fa._pick_latent_blocks(S)
+        assert H % groups == 0 and fa._latent_vmem_bytes(
+            H // groups * Q, W, rank, DB, SB, 2, 2) <= fa.LATENT_VMEM_LIMIT
