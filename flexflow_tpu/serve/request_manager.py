@@ -911,23 +911,24 @@ class RequestManager:
             self._prefix_install(active, (("llm", ifm),))
             if rnd is not None:
                 rnd.admitted(R - active.count(None), len(self.pending))
-            # decode-interleaved chunked prefill (ISSUE 19, 32, 36): a round
-            # dispatches bounded prefill steps (separate calls of the one
-            # program, outputs unused) while a request is still filling,
-            # AND the decode block for the caught-up slots. The two sides
-            # share the round by how many requests each holds (StepCosts:
-            # the loop's own measurement of the two programs): the steps
-            # together cost no more than the block that follows them,
-            # times filling / decoding where the requests still filling
-            # their slots outnumber the rows decoding. One step is always
-            # allowed. So a decoding row waits for prefill at most a
-            # block's time while the decoders are the majority, the
-            # row-seconds stalled never pass the request-seconds the
-            # block holds the fillers off, and a queued short request's
-            # TTFT does not track the longest resident prompt's prefill.
-            # Queued requests without a slot do not count: no prefill step
-            # helps them. With nothing decoding there is nobody to stall:
-            # the round prefills until a request has caught up.
+            # decode-interleaved chunked prefill (ISSUE 19, 32, 36, 48): a
+            # round dispatches bounded prefill steps (separate calls of the
+            # one program, outputs unused) while a request is still
+            # filling, AND the decode block for the caught-up slots. The
+            # round is shared by everyone resident (StepCosts: the loop's
+            # own measurement of the two programs): the steps together cost
+            # no more than the block that follows them, times (decoding +
+            # filling) / decoding. One step is always allowed. So the
+            # row-seconds the decoders are stalled never pass the
+            # request-seconds the block takes from the rows that ride it
+            # and the requests it holds off: at a full batch a decoding
+            # row waits for prefill at most a block's time, a batch that
+            # has emptied earns its refill by how empty it is, and a
+            # queued short request's TTFT does not track the longest
+            # resident prompt's prefill. Queued requests without a slot do
+            # not count: no prefill step helps them. With nothing decoding
+            # there is nobody to stall: the round prefills until a request
+            # has caught up.
             decoding = caught_up()
             allowed = None
             if decoding:
@@ -936,7 +937,8 @@ class RequestManager:
                 allowed = costs.allowance(block_steps(decoding, True),
                                           len(decoding), filling)
                 if tel is not None:
-                    tel.note_round_allowance(allowed)
+                    tel.note_round_allowance(
+                        allowed, costs.weight(len(decoding), filling))
             steps, timed, t0 = 0, False, time.perf_counter()
             while allowed is None or steps < allowed:
                 rows = self._prefill(ifm, active, shape,

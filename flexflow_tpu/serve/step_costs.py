@@ -2,20 +2,29 @@
 incremental loop itself measures them.
 
 ``RequestManager.generate_incr_decoding`` prefills, each scheduler round,
-as many steps as the round's two sides share it by: ``steps x p <= block
-x d x max(1, filling / decoding)``, one step always. Where the decoding
-rows are the majority the steps of one round together may take as long as
-the block that follows them and no longer, so a decoding row waits for
-prefill at most one block's time (PR 32's bound, and the floor). Where
-the requests still filling their slots are the majority, the row-seconds
-the decoders are stalled (``decoding x steps x p``) may reach the
-request-seconds the fillers are held off by the block (``filling x block
-x d``) and no further: a decode block that is cheaper than a prefill step
-(few rows, a latent cache) no longer holds a queue of long prompts to one
-step a round. Both need the two programs' cost on the model being served,
-and nothing states it ahead of time: a prefill step is 1.2 decode steps
-of OLMoE, 2.8 of K-EXAONE, 3.4-3.9 of Falcon, 15 of Mistral-4 at 3 rows
-(PERF.md section 6, PR 32 and 36).
+as many steps as everyone resident pays for: ``steps x p x decoding <=
+block x d x (decoding + filling)``, one step always. The row-seconds a
+round's prefill stalls the rows decoding (``decoding x steps x p``) may
+reach the request-seconds the decode block takes from everyone in a slot:
+the rows that ride it (``decoding x block x d``, PR 32's bound) and the
+requests still filling that it holds off (``filling x block x d``, PR
+36's, which took the larger of the two terms where this is their sum). At
+a full batch (``filling`` 0) the steps of one round together may take as
+long as the block that follows them and no longer, so a decoding row
+waits for prefill at most one block's time; a batch that has emptied
+earns its refill in proportion to how empty it is. The quotient ``block x
+d / p`` falls with every gain in the decode step, and under the larger-of
+rule the batch fell with it, in whole steps, wherever the decoders stayed
+the majority (K-EXAONE's queue after PR 38 and 43: 18 of 32 rows
+decoding, 14 residents outside the block, weight 1). Under the sum, with
+every slot resident and ``k`` steps a row a round needed, the rows settle
+where ``k x rows = quotient x slots / rows``: at the geometric mean of the
+slots and of what the larger-of rule held, moving with the square root of
+the decode step's cost and not with the quotient's floor (PERF.md section
+6, PR 48). Both need the two programs' cost on the model being served, and
+nothing states it ahead of time: a prefill step is 1.2 decode steps of
+OLMoE, 2.8 of K-EXAONE at PR 32 and 4.7 at PR 47, 3.4-3.9 of Falcon, 15 of
+Mistral-4 at 3 rows (PERF.md section 6, PR 32, 36 and 48).
 
 A prefill step is dispatched without a fence and the decode block's
 readback fences both, so a round's wall time does not say which program
@@ -65,18 +74,24 @@ class StepCosts:
         """A decode block of ``steps`` took ``seconds`` on an idle device."""
         self._decode.append(seconds / steps)
 
+    @staticmethod
+    def weight(decoding: int, filling: int) -> float:
+        """Everyone resident over the rows decoding: what a round's decode
+        block is worth in prefill, in blocks."""
+        return (decoding + filling) / decoding
+
     def allowance(self, block_steps: int, decoding: int, filling: int) -> int:
         """The prefill steps a round may take before its decode block of
         ``block_steps`` for ``decoding`` rows, with ``filling`` requests
         in slots still short of their prompts: as many as together cost
-        no more than the block, times ``filling / decoding`` where the
-        fillers are the majority. One always; one alone while either cost
-        is still unknown."""
+        no more than the block, times everyone resident over the rows
+        decoding. One always; one alone while either cost is still
+        unknown."""
         if min(len(self._prefill), len(self._decode)) < self.MIN:
             return 1
         block_s = block_steps * statistics.median(self._decode)
-        weight = max(1.0, filling / decoding)
-        return max(1, int(block_s * weight / statistics.median(self._prefill)))
+        return max(1, int(block_s * self.weight(decoding, filling)
+                          / statistics.median(self._prefill)))
 
 
 class GivenCosts(StepCosts):

@@ -31,6 +31,7 @@ ffsv_prefill_positions_total     counter    positions prefill steps computed
 ffsv_prefill_attended_pairs_total counter   (query, key) pairs prefill attended
 ffsv_round_prefill_steps         histogram  prefill steps a round dispatched
 ffsv_round_prefill_allowance     histogram  prefill steps a round was allowed
+ffsv_round_prefill_weight        histogram  its (decoding + filling) / decoding
 ffsv_spec_rounds_total           counter    speculation rounds executed
 ffsv_decode_steps_total          counter    row-steps of decode blocks
 ffsv_diffusion_row_passes_total  counter    passes block-diffusion rows ran
@@ -382,8 +383,13 @@ class ServingTelemetry:
             "ffsv_round_prefill_allowance",
             "prefill steps StepCosts allowed one round of the incremental "
             "loop that began with a row decoding (the block's worth, times "
-            "filling / decoding where the filling requests outnumber the "
-            "decoding rows)",
+            "ffsv_round_prefill_weight)",
+            buckets=COUNT_BUCKETS)
+        self.round_prefill_weight = r.histogram(
+            "ffsv_round_prefill_weight",
+            "what that round's block was weighed by: the requests in slots "
+            "(decoding + filling) over the rows decoding; 1 at a full "
+            "batch",
             buckets=COUNT_BUCKETS)
         self.spec_rounds = r.counter(
             "ffsv_spec_rounds_total", "speculation rounds executed")
@@ -789,10 +795,12 @@ class ServingTelemetry:
         dispatched before its decode block, none included."""
         self.round_prefill_steps.observe(steps)
 
-    def note_round_allowance(self, allowed: int):
+    def note_round_allowance(self, allowed: int, weight: float):
         """Once per round of the incremental loop that began with a row
-        decoding: the prefill steps the rule allowed it."""
+        decoding: the prefill steps the rule allowed it, and the weight
+        (everyone resident over the rows decoding) it gave the block."""
         self.round_prefill_allowance.observe(allowed)
+        self.round_prefill_weight.observe(weight)
 
     def record_decode_block(self, seconds: float, steps: int, n_live: int,
                             guids=(), t0: Optional[float] = None,

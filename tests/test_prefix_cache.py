@@ -377,9 +377,10 @@ def test_eviction_pressure_keeps_tokens_identical(tiny_incr_model, incr_ref):
 def _interleave_events(model, telemetry: bool):
     """A 60-token prompt (59 to fill: five steps of 2 segments x 8, the
     first shared) beside a 3-token one that decodes 20 tokens in blocks of
-    8, with the two programs' costs GIVEN: a decode block of 8 steps pays
-    for two prefill steps (8 x 0.3 / 1.0). Returns the loop's device calls
-    in order and the two results."""
+    8, with the two programs' costs GIVEN: a decode block of 8 steps for
+    one row, beside one request filling, pays for two prefill steps (8 x
+    0.15 x 2 / 1.0). Returns the loop's device calls in order and the two
+    results."""
     from flexflow_tpu.serve.request_manager import InferenceManager
     from flexflow_tpu.serve.step_costs import GivenCosts
     from flexflow_tpu.telemetry import disable_telemetry, enable_telemetry
@@ -406,7 +407,7 @@ def _interleave_events(model, telemetry: bool):
         return orig_decode(tok, pos, act, block, **kw)
 
     ifm.decode_block = spy_decode
-    ifm.step_costs = GivenCosts(1.0, 0.3)
+    ifm.step_costs = GivenCosts(1.0, 0.15)
     if telemetry:
         enable_telemetry()
     try:

@@ -985,10 +985,9 @@ def test_incr_loop_matches_closed_form(slots, block, steps):
     exactly what it would generate alone (EOS inside a block, a budget
     that ends mid-block, rows refilled from the queue, one-token prompts).
     ``steps``: a queue of eight long prompts through four slots where a
-    decode block pays for that many prefill steps a round, times filling
-    over decoding where the requests still filling outnumber the rows
-    decoding (six slots: up to five to one); whatever the rounds held,
-    every request's tokens are its own."""
+    decode block pays for that many prefill steps a round, times everyone
+    resident over the rows decoding (six slots: up to five filling beside
+    one); whatever the rounds held, every request's tokens are its own."""
     queue = _RULE_PROMPTS if steps is None else _RULE_QUEUE
     max_seq = 24 if steps is None else 80
     cfg = ff.FFConfig(max_requests_per_batch=slots,
@@ -1034,11 +1033,12 @@ def test_incr_loop_matches_closed_form(slots, block, steps):
                and a.decoding + a.filling <= slots for a in asked)
     worth = (steps + 0.5) / block       # of one decode step, in prefill steps
     assert all(a.allowed == max(1, int(
-        a.block * worth * max(1.0, a.filling / a.decoding))) for a in asked)
-    # decoders the majority: what the block pays for, to the letter
-    assert max(a.allowed for a in asked
-               if a.filling <= a.decoding) == steps
-    # fillers the majority: more, and taken (with one step a block too)
+        a.block * worth * (a.decoding + a.filling) / a.decoding))
+        for a in asked)
+    # a full batch: what the block pays for, to the letter
+    assert max(a.allowed for a in asked if not a.filling) == steps
+    # a request filling beside the rows decoding: more, and taken (with one
+    # step a block too)
     most = max(asked, key=lambda a: a.filling / a.decoding)
     assert most.filling / most.decoding == (5 if slots == 6 else 3)
     assert max(a.allowed for a in asked) > steps
@@ -1050,29 +1050,32 @@ def test_incr_loop_fills_its_batch_sooner_with_several_steps_a_round():
     """The same queue, a block that pays for one step against five: the
     four slots all decode after fewer rounds, no token differs,
     ``ffsv_round_prefill_steps`` counts the rounds that took more than one
-    step and ``ffsv_round_prefill_allowance`` what each round that began
-    with a row decoding was allowed. Telemetry waits for each step's output
+    step, ``ffsv_round_prefill_allowance`` what each round that began
+    with a row decoding was allowed and ``ffsv_round_prefill_weight`` what
+    it weighed the block by. Telemetry waits for each step's output
     (the fake has none) and changes nothing of the schedule."""
+    from flexflow_tpu.serve.step_costs import GivenCosts
     from flexflow_tpu.telemetry import disable_telemetry, enable_telemetry
 
     cfg = ff.FFConfig(max_requests_per_batch=4, max_sequence_length=80,
                       max_tokens_per_batch=16, decode_block_steps=4)
 
-    def run(steps):
+    def run(costs):
         rm = RequestManager(eos_token_id=_RULE_EOS)
         for pr in _RULE_QUEUE:
             rm.register_new_request(pr, max_new_tokens=24)
-        ifm = _RuleIFM(costs=_allowing(steps, 4))
+        ifm = _RuleIFM(costs=costs)
         res = rm.generate_incr_decoding(_rule_model(cfg, ifm))
         full = [int(act.sum()) for _, _, act, _ in ifm.decodes].index(4)
         return ({tuple(r.input_tokens): r.output_tokens for r in res},
                 full, ifm.rounds)
 
-    one, full_one, rounds_one = run(0)      # half a step: one, even at 3 to 1
+    # 0.4 of a step: one, even with four resident to one row decoding
+    one, full_one, rounds_one = run(GivenCosts(1.0, 0.1))
     tel = enable_telemetry()
     try:
         before = tel.registry.snapshot()
-        five, full_five, rounds_five = run(5)
+        five, full_five, rounds_five = run(_allowing(5, 4))
         hist = tel.registry.get("ffsv_round_prefill_steps")
         # the benchmark's readers; nothing from a program without them
         from benchmark.layer_metrics import (
@@ -1093,8 +1096,14 @@ def test_incr_loop_fills_its_batch_sooner_with_several_steps_a_round():
         assert allowed.count == len(rounds_five) - 2 == len(allowed._samples)
         assert all(took <= may for took, may
                    in zip(rounds_five[1:], allowed._samples))
-        # 5.5 steps a block, three requests filling to one row decoding
-        assert max(allowed._samples) == 16 > min(allowed._samples) == 5
+        # 5.5 steps a block, three requests filling beside one row
+        assert max(allowed._samples) == 22 > min(allowed._samples) == 5
+        # the weight the block was given, once for each such round: four
+        # residents to one row decoding at most, one at a full batch
+        weight = tel.registry.get("ffsv_round_prefill_weight")
+        assert weight.count == allowed.count == len(weight._samples)
+        assert max(weight._samples) == 4 > min(weight._samples) == 1
+        assert [int(5.5 * w) for w in weight._samples] == allowed._samples
         assert allowance.read(ctx) == allowed.sum / allowed.count
         assert allowance.read({"tel": {"before": {}, "after": {}}}) is None
         assert allowance.read({"tel": None}) is None
@@ -1102,25 +1111,28 @@ def test_incr_loop_fills_its_batch_sooner_with_several_steps_a_round():
         disable_telemetry()
     assert five == one
     assert full_five < full_one, (full_five, full_one)
-    # three filling to one decoding: more than the block's five, and taken
+    # three filling beside one decoding: more than the block's five, and taken
     assert max(rounds_five[1:]) == 8 and max(rounds_one[1:]) <= 2
-    assert run(5)[1:] == (full_five, rounds_five)   # telemetry off: the same
+    # telemetry off: the same
+    assert run(_allowing(5, 4))[1:] == (full_five, rounds_five)
 
 
 @pytest.mark.parametrize("decoding,filling,weight", [
-    (1, 0, 1), (12, 4, 1), (8, 8, 1),       # decoders the majority: PR 32's
-    (4, 12, 3), (3, 13, 13 / 3), (1, 3, 3), (2, 3, 1.5)])
+    (1, 0, 1),                              # a full batch: PR 32's bound
+    (12, 4, 4 / 3), (8, 8, 2), (18, 14, 32 / 18),   # PR 36's rule: weight 1
+    (4, 12, 4), (3, 13, 16 / 3), (1, 3, 4), (2, 3, 2.5)])
 def test_step_costs_are_medians_of_a_few_timed_rounds(decoding, filling,
                                                       weight):
     """No estimate, and so one step a round, until three samples of each
     program are in; then as many steps as together cost no more than the
-    block, times ``filling / decoding`` where the requests filling are the
-    majority, from the medians of the last five samples: a stop of the
-    machine inside one sample moves nothing. Every prefilling round is
-    timed until both estimates stand, then one in eight."""
+    block, times everyone resident over the rows decoding, from the
+    medians of the last five samples: a stop of the machine inside one
+    sample moves nothing. Every prefilling round is timed until both
+    estimates stand, then one in eight."""
     from flexflow_tpu.serve.step_costs import StepCosts
 
     costs = StepCosts()
+    assert costs.weight(decoding, filling) == pytest.approx(weight)
 
     def allowance(block):
         return costs.allowance(block, decoding, filling)
@@ -1143,6 +1155,80 @@ def test_step_costs_are_medians_of_a_few_timed_rounds(decoding, filling,
     assert allowance(16) == int(5.6533 * weight)
 
 
+def test_step_costs_allow_no_less_than_the_larger_of_rule():
+    """Over every (decoding, filling) of 32 slots and quotients on both
+    sides of a step: the sum of the two terms is never below the larger of
+    them (PR 36's rule), and at a full batch it is the block's worth to
+    the letter (PR 32's bound)."""
+    from flexflow_tpu.serve.step_costs import GivenCosts
+
+    more = 0
+    for q in (0.4, 1.0, 3.08, 3.58, 7.5):
+        costs = GivenCosts(1.0, q / 16)
+        for decoding in range(1, 33):
+            assert costs.allowance(16, decoding, 0) == max(1, int(q))
+            for filling in range(33 - decoding):
+                new = costs.allowance(16, decoding, filling)
+                old = max(1, int(q * max(1.0, filling / decoding)))
+                assert new >= old, (q, decoding, filling)
+                more += new > old
+    assert more
+    # K-EXAONE's queue at PR 47, 18 rows decoding and 14 residents
+    # filling: six steps where the quotient alone (3.42) gave three
+    assert GivenCosts(22.5, 4.81).allowance(16, 18, 14) == 6
+
+
+def _serve_on_toy_device(monkeypatch, cfg, queue, new_tokens, decode_s=0.8,
+                         stop_at=None, traced=False):
+    """``queue`` through the incremental loop on a device that runs what
+    it is sent in order: a prefill step 1.0 s, a decode step ``decode_s``,
+    dispatch free; a fence, a readback or a wait on a step's output waits
+    for the device. The loop reads the toy's clock and times the two
+    programs itself. ``stop_at``: the machine stops for 100 s inside that
+    prefill step. Returns the results, the fake manager, and the requests
+    still queued at each decode block."""
+    from flexflow_tpu.serve import request_manager as RM
+    from flexflow_tpu.telemetry import disable_telemetry, enable_telemetry
+
+    host, device = [0.0], [0.0]     # the clock; when the device is free
+
+    def wait(_state=None):
+        host[0] = max(host[0], device[0])
+
+    class Device(_RuleIFM):
+        def step(self, meta, want_output=True, tel=None):
+            super().step(meta, want_output, tel)
+            stop = 100.0 * (len(self.prefills) == stop_at)
+            device[0] = max(host[0], device[0]) + 1.0 + stop
+            # the step's output: ready when the device has run it
+            return types.SimpleNamespace(
+                block_until_ready=lambda end=device[0]: host.__setitem__(
+                    0, max(host[0], end)))
+
+        def decode_block(self, tok, pos, act, block, tel=None,
+                         rnd=None):
+            device[0] = max(host[0], device[0]) + decode_s * block
+            out = super().decode_block(tok, pos, act, block, tel, rnd)
+            wait()
+            return out
+
+    monkeypatch.setattr(RM, "device_fence", wait)
+    monkeypatch.setattr(RM, "time", types.SimpleNamespace(
+        perf_counter=lambda: host[0]))
+    rm = RequestManager(eos_token_id=_RULE_EOS)
+    for pr in queue:
+        rm.register_new_request(pr, max_new_tokens=new_tokens)
+    queued = []
+    ifm = Device(on_decode=lambda i: queued.append(len(rm.pending)))
+    if traced:
+        enable_telemetry()
+    try:
+        res = rm.generate_incr_decoding(_rule_model(cfg, ifm))
+    finally:
+        disable_telemetry()
+    return res, ifm, queued
+
+
 @pytest.mark.parametrize("stop_at", [None, 7])
 def test_incr_loop_times_the_same_rounds_traced_and_untraced(monkeypatch,
                                                              stop_at):
@@ -1155,48 +1241,12 @@ def test_incr_loop_times_the_same_rounds_traced_and_untraced(monkeypatch,
     other either way, so the estimates, and with them the steps of every
     round, are the same traced and untraced. ``stop_at``: the machine stops
     for 100 s inside that prefill step; nothing changes."""
-    from flexflow_tpu.serve import request_manager as RM
-    from flexflow_tpu.telemetry import disable_telemetry, enable_telemetry
-
     cfg = ff.FFConfig(max_requests_per_batch=4, max_sequence_length=80,
                       max_tokens_per_batch=16, decode_block_steps=4)
 
     def run(traced):
-        host, device = [0.0], [0.0]     # the clock; when the device is free
-
-        def wait(_state=None):
-            host[0] = max(host[0], device[0])
-
-        class Device(_RuleIFM):
-            def step(self, meta, want_output=True, tel=None):
-                super().step(meta, want_output, tel)
-                stop = 100.0 * (len(self.prefills) == stop_at)
-                device[0] = max(host[0], device[0]) + 1.0 + stop
-                # the step's output: ready when the device has run it
-                return types.SimpleNamespace(
-                    block_until_ready=lambda end=device[0]: host.__setitem__(
-                        0, max(host[0], end)))
-
-            def decode_block(self, tok, pos, act, block, tel=None,
-                             rnd=None):
-                device[0] = max(host[0], device[0]) + 0.8 * block
-                out = super().decode_block(tok, pos, act, block, tel, rnd)
-                wait()
-                return out
-
-        monkeypatch.setattr(RM, "device_fence", wait)
-        monkeypatch.setattr(RM, "time", types.SimpleNamespace(
-            perf_counter=lambda: host[0]))
-        rm = RequestManager(eos_token_id=_RULE_EOS)
-        for pr in _RULE_QUEUE:
-            rm.register_new_request(pr, max_new_tokens=24)
-        ifm = Device()
-        if traced:
-            enable_telemetry()
-        try:
-            res = rm.generate_incr_decoding(_rule_model(cfg, ifm))
-        finally:
-            disable_telemetry()
+        res, ifm, _ = _serve_on_toy_device(monkeypatch, cfg, _RULE_QUEUE, 24,
+                                           stop_at=stop_at, traced=traced)
         return ({tuple(r.input_tokens): r.output_tokens for r in res},
                 ifm.rounds, list(ifm.step_costs._prefill),
                 list(ifm.step_costs._decode))
@@ -1205,13 +1255,49 @@ def test_incr_loop_times_the_same_rounds_traced_and_untraced(monkeypatch,
     assert plain == traced
     # the first round has nothing decoding; then one step a round until the
     # third sample of each program, then the three that a block of 4 steps
-    # (3.2 s) pays for, and more only where the requests filling outnumber
-    # the rows decoding (late in the run, two to one: four, of six allowed)
+    # (3.2 s) pays for, and more where requests fill beside the rows
+    # decoding (late in the run: four a round)
     assert plain[1][:4] == [6, 1, 1, 3], plain[1]
     assert max(plain[1][1:]) == 4
     assert sorted(plain[2])[:-1] == [1.0] * (len(plain[2]) - 1)
     assert max(plain[2]) == (1.0 if stop_at is None else 101.0)
     assert {round(d, 6) for d in plain[3]} == {0.8}
+
+
+@pytest.mark.parametrize("prompt,new_tokens,slots,holds", [
+    (20, 12, 4, 1.0), (20, 32, 4, 1.0),
+    (40, 8, 8, 0.5 ** 0.5), (48, 8, 8, 0.5 ** 0.5)])
+def test_incr_loop_keeps_its_rows_under_a_cheaper_decode_step(
+        monkeypatch, prompt, new_tokens, slots, holds):
+    """PR 38's fault, on the toy device: a queue that keeps every slot
+    resident, served at a decode step of 0.8 s and of 0.4 s. The block's
+    worth in prefill steps halves (3.2 -> 1.6, floored 3 -> 1), and under
+    the larger-of rule the rows decoding a round went with it wherever the
+    decoders stayed the majority (3.59 -> 2.95 of 4 rows on the first
+    queue, 5.87 -> 3.73 of 8 on the third). The block weighed by everyone
+    resident answers with the steps the emptier batch earns. Prompts of
+    two steps: a freed slot is decoding again a round later at either
+    cost, and not one row is lost. Prompts that ask for more than any
+    block here pays for (1.25 and 1.5 steps a row a round): the rows
+    follow the square root of the cost (steps needed, k x rows = steps
+    allowed, q x slots / rows) and no longer the cost."""
+    cfg = ff.FFConfig(max_requests_per_batch=slots, max_sequence_length=80,
+                      max_tokens_per_batch=16, decode_block_steps=4)
+    queue = [[(7 * i + j) % 50 + 1 for j in range(prompt + i % 3)]
+             for i in range(6 * slots)]
+
+    def rows_decoding(decode_s):
+        res, ifm, queued = _serve_on_toy_device(
+            monkeypatch, cfg, queue, new_tokens, decode_s=decode_s)
+        assert all(r.status == "ok" for r in res)
+        # while requests wait for a slot: every slot holds a resident
+        loaded = [int(act.sum()) for (_, _, act, _), n
+                  in zip(ifm.decodes, queued) if n]
+        assert len(loaded) >= 10
+        return sum(loaded) / len(loaded)
+
+    dear, cheap = rows_decoding(0.8), rows_decoding(0.4)
+    assert cheap >= holds * dear, (dear, cheap)
 
 
 def test_incr_loop_lifecycle():
@@ -1570,10 +1656,10 @@ def test_check_compact_prefill_tool_rehearses(config, rounds, monkeypatch,
     sizes: on the CPU the two programs agree to the bit, and an expert
     model's compact run, sent where the grid run went, overrides no pick
     of its own. ``rounds``: the windowed cut is then served by the
-    scheduler loop, whose rounds take a block's four consecutive steps
-    (256 positions through a ring of 128 rows) while rows decode, and
-    twelve while three requests fill and one row decodes: every token is
-    what one step a round gives, the first the grid run's pick."""
+    scheduler loop, whose second round takes sixteen consecutive steps
+    (a block's four, times four resident to one row decoding: 1024
+    positions through a ring of 128 rows): every token is what one step a
+    round gives, the first the grid run's pick."""
     import json
 
     monkeypatch.syspath_prepend(os.path.dirname(os.path.dirname(
@@ -1593,5 +1679,5 @@ def test_check_compact_prefill_tool_rehearses(config, rounds, monkeypatch,
     if rounds:
         assert res["served_tokens_equal"]
         assert res["served_first_tokens_off_the_grid"] == 0
-        assert res["served_steps_by_round"][:4] == [1, 12, 4, 2]
+        assert res["served_steps_by_round"][:4] == [1, 16, 2, 0]
         assert res["served_positions_a_round_max"] >= 2 * res["ring_rows"][0]

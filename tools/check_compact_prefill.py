@@ -26,8 +26,8 @@ the entry's own values (the latent and the rotated key part).
 ``--rehearse``: CPU, the configuration's tiny rehearsal sizes, interpreted
 kernels. ``--rounds``: the prompts are then also served by the scheduler
 loop, with one prefill step a round and with as many as a decode block has
-steps, times the requests filling over the rows decoding where those are
-more (consecutive compact steps, a ring wrapping inside a round): the
+steps, times everyone resident over the rows decoding (consecutive compact
+steps, a ring wrapping inside a round): the
 tokens must be the same, and how many first tokens differ from the grid
 run's pick is reported (none on the CPU).
 """
@@ -225,9 +225,9 @@ def decode(model, step, prompts, routes: Routes, record: bool):
 def served(model, prompts, new_tokens: int = 24):
     """The prompts through ``RequestManager.generate_incr_decoding`` itself,
     once with one prefill step a scheduler round and once with as many as a
-    decode block's steps, times the requests filling over the rows decoding
-    where those are more (the two programs' costs given, not timed: a
-    prefill step costs one decode step). Returns for each the tokens
+    decode block's steps, times everyone resident over the rows decoding
+    (the two programs' costs given, not timed: a prefill step costs one
+    decode step). Returns for each the tokens
     generated, by prompt, and the prefill steps of every round."""
     import jax
     import jax.numpy as jnp
