@@ -37,16 +37,27 @@ fast_path_count: int = 0
 # row of the scalar unit, R x KH x width of them a cache a layer).
 fused_append_counts: dict = {}
 scatter_append_counts: dict = {}
+# Which form of the latent kernel a trace took, by (form, mode): "block" (a
+# DMA block's scores in one pass, its softmax partitions unrolled: a decode
+# step's few query rows) or "partition" (the partition loop: a prefill
+# segment's thousands), of the shapes alone
+# (``kernels/attention.latent_form``); mode "append" (a decode step, the
+# append fused), "rows" (the compact prefill batch) or "grid" (a slot-grid
+# step). ``latent_summary()`` prints it; read by no metric.
+latent_form_counts: dict = {}
 _warned: set = set()
 
 
-def record_fast_path(append=None):
+def record_fast_path(append=None, latent=None):
     """Count a trace of the attention kernel; ``append``: the width of the
-    run of positions it appends to the cache itself."""
+    run of positions it appends to the cache itself; ``latent``: the (form,
+    mode) of a latent kernel's trace."""
     global fast_path_count
     fast_path_count += 1
     if append is not None:
         fused_append_counts[append] = fused_append_counts.get(append, 0) + 1
+    if latent is not None:
+        latent_form_counts[latent] = latent_form_counts.get(latent, 0) + 1
 
 
 def record_scatter_append(width: int):
@@ -64,6 +75,15 @@ def append_summary() -> str:
                              f"{kind} appends: 0")
     return (part("fused", fused_append_counts) + "; "
             + part("scatter", scatter_append_counts))
+
+
+def latent_summary() -> str:
+    """The latent kernel's traces by form and mode in one line: "latent
+    kernel: block form, append: 8 traces; partition form, rows: 8 traces"."""
+    return "latent kernel: " + ("; ".join(
+        f"{form} form, {mode}: {n} traces"
+        for (form, mode), n in sorted(latent_form_counts.items()))
+        or "0 traces")
 
 
 def record_fallback(reason: str):
@@ -84,6 +104,7 @@ def reset_dispatch_stats():
     fallback_counts.clear()
     fused_append_counts.clear()
     scatter_append_counts.clear()
+    latent_form_counts.clear()
     _warned.clear()
     fast_path_count = 0
     from flexflow_tpu.kernels import moe

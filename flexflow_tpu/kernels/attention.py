@@ -948,9 +948,11 @@ def _pick_latent_blocks(S: int):
     """(DMA block, softmax block) of the latent kernel, in positions, or
     (0, 0) where ``S`` cannot be tiled. An entry is a few hundred bytes, so
     a DMA takes as many positions as divide ``S`` (up to 1024: 0.8 MB at
-    384 lanes) to pay for its fixed cost; the online softmax advances in
-    sub-blocks of that block. As ``_pick_block_s`` states, the softmax
-    partition depends on ``S`` alone, never on the query width."""
+    384 lanes, 1.3 at 640) to pay for its fixed cost; the online softmax
+    advances in sub-blocks of that block, in BOTH forms of the kernel
+    (``latent_form``): the block form scores a DMA block in one pass and
+    still walks it a softmax block at a time. As ``_pick_block_s`` states,
+    the softmax partition depends on ``S`` alone, never on the query width."""
     if S % LANE:
         return 0, 0
     sb = 256 if S % 256 == 0 else 128
@@ -971,15 +973,42 @@ def supports_latent(S: int, width: int, rank: int) -> bool:
 LATENT_APPEND_ROWS = 16
 # the most a call asks of the chip's 128 MiB of VMEM
 LATENT_VMEM_LIMIT = 100 * 1024 * 1024
+# the most a DMA block's float32 scores may take for the block form: 256
+# query rows at 1024 positions. On a v5e the block form beats the
+# partition loop by a third at 16-128 rows, by 7-24% at 256 and by -2..+10%
+# at 512 (PERF.md section 6, PR 49)
+LATENT_BLOCK_SCORES_LIMIT = 1024 * 1024
+
+
+def _scores_fit(GQ: int, DB: int) -> bool:
+    """True iff a DMA block's scores ``[GQ, DB]`` in float32 fit beside the
+    stream."""
+    return GQ * DB * 4 <= LATENT_BLOCK_SCORES_LIMIT
+
+
+def latent_form(GQ: int, S: int) -> str:
+    """Which form of ``_latent_kernel`` a call over ``GQ`` query rows (heads
+    x tokens) of a cache of ``S`` positions takes, of the shapes alone:
+    "block" where a DMA block's scores fit beside the stream (a decode
+    step's 32 or 64 rows: 128 or 256 KB), else "partition" (a prefill
+    segment's 4096 rows: 16 MB)."""
+    return "block" if _scores_fit(GQ, _pick_latent_blocks(S)[0]) else (
+        "partition")
 
 
 def _latent_vmem_bytes(GQ: int, W: int, rank: int, DB: int, SB: int,
                        q_itemsize: int, c_itemsize: int) -> int:
-    """VMEM a call over ``GQ`` query rows (heads x tokens) asks for."""
+    """VMEM a call over ``GQ`` query rows (heads x tokens) asks for: the
+    query and output blocks, the softmax state, the stream's two buffers
+    (and as much again for the block as a value), a partition's scores and
+    their kin and, in the block form, a DMA block's scores and their masked
+    copy."""
+    block = _scores_fit(GQ, DB)
     return (2 * GQ * (W + rank) * q_itemsize    # q, o double-buffered
             + GQ * (rank + 2 * LANE) * 4        # acc, m, l
             + 4 * DB * W * c_itemsize           # the stream's buffers
             + 6 * GQ * SB * 4                   # scores and their kin
+            + 2 * GQ * DB * 4 * block           # a block's scores
             + 2 * GQ * rank * 4 + 4 * 1024 * 1024)
 
 
@@ -998,18 +1027,46 @@ def latent_head_groups(H: int, Q: int, W: int, rank: int, S: int,
     return 0
 
 
-def _latent_kernel(len_ref, *refs, mode, DB: int, SB: int, rank: int,
-                   qk_scale: float, layer_idx):
+def _latent_kernel(len_ref, pipe_ref, *refs, mode, block: bool,
+                   stacked: bool, DB: int, SB: int, rank: int,
+                   qk_scale: float):
     """Program ``r`` attends queries ``q [GQ, W]`` (all heads of all the
     row's query tokens) over cache row ``r``. Every fetched block ``[DB, W]``
-    serves the scores (all ``W`` lanes) and the values (its first ``rank``
-    lanes). ``mode`` "rows": the compact prefill batch's row map, program
-    ``r`` reads cache row ``rows[r]``. ``mode`` "append" (decode): the row's
-    new entry lands at position ``appos[r]`` IN PLACE (the cache is aliased
-    in/out), merged into the streamed block in VMEM and its aligned window
-    written back, as ``_append_kernel`` does for a k/v pair. The
-    cross-program DMA pipeline is ``_stream_attend``'s."""
-    rows_ref = appos_ref = new_ref = asem = None
+    (a row's last one only up to its last partition that holds a valid
+    position: a decode step is bound by the HBM, and bytes not fetched here
+    are bytes XLA's prefetch of the next matrices gets) serves the scores
+    (all ``W`` lanes) and the values (its first ``rank`` lanes), and the
+    online softmax advances through it ``SB`` positions at a time, in one
+    of two forms (``latent_form``, of the shapes alone; the same dot
+    products through the same sequence of partitions, so the same output
+    bit for bit):
+
+    - ``block`` (a decode step's 32 or 64 query rows): the block's scores
+      ``q . c^T`` in ONE pass ``[GQ, DB]``, then its ``DB // SB`` partitions
+      in a loop unrolled at trace time. The cache block is the matmuls'
+      stationary operand, so its arithmetic is a chain of fills and drains
+      of the MXU whatever ``GQ`` is: unrolled, a partition's ``exp`` and
+      value matmul no longer wait for the one before to drain, and a block
+      costs what its bytes cost. A partition with no valid position is
+      masked, not skipped (``NEG_INF`` is finite: ``p`` 0, ``corr`` 1,
+      nothing moves).
+    - partition loop (a prefill segment's 4096 rows, whose block scores
+      would take 16 MB): scores, softmax and values a partition at a time
+      under a loop with a dynamic trip count, only the partitions that hold
+      a valid position.
+
+    ``mode`` "rows": the compact prefill batch's row map, program ``r``
+    reads cache row ``rows[r]``. ``mode`` "append" (decode): the row's new
+    entry lands at position ``appos[r]`` IN PLACE (the cache is aliased
+    in/out), merged into its ``LATENT_APPEND_ROWS``-row window of the
+    streamed block in VMEM, and that window written back, as
+    ``_append_kernel`` does for a k/v pair. The cross-program DMA pipeline
+    is ``_stream_attend``'s, its walk over the rows' lengths done once a
+    call outside (``pipe_ref``: the blocks of the rows before ``r``, the
+    next live row)."""
+    rows_ref = appos_ref = new_ref = asem = layer_ref = None
+    if stacked:
+        layer_ref, *refs = refs
     if mode == "append":
         (appos_ref, q_ref, qp_ref, new_ref, _, o_ref, c_hbm, acc, m, l, cbuf,
          sem, asem) = refs
@@ -1021,38 +1078,46 @@ def _latent_kernel(len_ref, *refs, mode, DB: int, SB: int, rank: int,
     R = len_ref.shape[0]
     length = len_ref[r]
 
-    def nb_of(j):
-        return (len_ref[j] + jnp.asarray(DB - 1, jnp.int32)) // DB
-
     def row_of(j):
         return j if rows_ref is None else rows_ref[j]
 
-    nb = nb_of(r)
+    nb = (length + jnp.asarray(DB - 1, jnp.int32)) // DB
     acc[:] = jnp.zeros_like(acc)
     m[:] = jnp.full_like(m, NEG_INF)
     l[:] = jnp.zeros_like(l)
-    if layer_idx is not None:
-        c_hbm = c_hbm.at[layer_idx]
+    if stacked:
+        c_hbm = c_hbm.at[layer_ref[0]]
+    g0, r_next = pipe_ref[0, r], pipe_ref[1, r]
 
-    def _pipe_scan(j, carry):
-        g0, prev_live, r_next = carry
-        nbj = nb_of(j)
-        g0 = g0 + jnp.where(j < r, nbj, 0)
-        prev_live = prev_live | ((j < r) & (nbj > 0))
-        r_next = jnp.where((j > r) & (nbj > 0) & (r_next == R), j, r_next)
-        return g0, prev_live, r_next
+    def live(j, i):
+        """The partitions of block ``i`` of program ``j``'s row that hold a
+        valid position."""
+        return jnp.minimum((len_ref[j] - i * DB + (SB - 1)) // SB, DB // SB)
 
-    g0, prev_live, r_next = jax.lax.fori_loop(
-        0, R, _pipe_scan,
-        (jnp.int32(0), jnp.asarray(False), jnp.int32(R)))
+    def stream(j, slot, i, start: bool):
+        """Start, or wait for, the copy of block ``i`` of program ``j``'s
+        row: its live partitions alone, in one descriptor (a row's last
+        block is not fetched past its end; a descriptor's size is static,
+        so one branch of a switch a count)."""
+        def copy(k):
+            dma = pltpu.make_async_copy(
+                c_hbm.at[row_of(j), 0, pl.ds(i * DB, k * SB)],
+                cbuf.at[slot, pl.ds(0, k * SB)], sem.at[slot])
+            dma.start() if start else dma.wait()
 
-    def dma(row, slot, i):
-        return pltpu.make_async_copy(
-            c_hbm.at[row, 0, pl.ds(i * DB, DB)], cbuf.at[slot], sem.at[slot])
+        jax.lax.switch(live(j, i) - 1, [functools.partial(copy, k)
+                                        for k in range(1, DB // SB + 1)])
 
-    @pl.when((nb > 0) & jnp.logical_not(prev_live))
+    if block:
+        @pl.when(r == 0)
+        def _():
+            # the block form multiplies a block's dead partitions by p = 0:
+            # what no copy of this call has written must be finite
+            cbuf[:] = jnp.zeros_like(cbuf)
+
+    @pl.when((nb > 0) & (g0 == 0))          # no live row before this one
     def _():
-        dma(row_of(r), g0 % 2, 0).start()
+        stream(r, g0 % 2, 0, True)
 
     GQ = q_ref.shape[-2]
     qp = qp_ref[r]                                  # [GQ] absolute positions
@@ -1061,13 +1126,38 @@ def _latent_kernel(len_ref, *refs, mode, DB: int, SB: int, rank: int,
         p_app = appos_ref[r]
         bp = p_app // DB                  # the block holding the new position
         A = LATENT_APPEND_ROWS
+        # the aligned window around the new position, within the block
+        at = pl.multiple_of(((p_app - bp * DB) // A) * A, A)
 
         def writeback(slot):
-            at = ((p_app - bp * DB) // A) * A       # within the block
             return pltpu.make_async_copy(
-                cbuf.at[slot, pl.ds(pl.multiple_of(at, A), A)],
+                cbuf.at[slot, pl.ds(at, A)],
                 c_hbm.at[r, 0, pl.ds(pl.multiple_of(bp * DB + at, A), A)],
                 asem.at[0])
+
+    def scores(c):
+        return jax.lax.dot_general(
+            q.astype(c.dtype), c,
+            dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)         # [GQ, positions]
+
+    def partition(s, vals, first):
+        """Advance the softmax by ``SB`` positions from ``first``: their raw
+        scores ``s [GQ, SB]`` and values ``vals [SB, rank]``."""
+        s = s * qk_scale
+        s_ids = first + jax.lax.broadcasted_iota(jnp.int32, (GQ, SB), 1)
+        visible = (s_ids <= qp[:, None]) & (s_ids < length)
+        s = jnp.where(visible, s, NEG_INF)
+        m_new = jnp.maximum(m[:], jnp.max(s, axis=-1, keepdims=True))
+        corr = jnp.exp(m[:] - m_new)
+        p = jnp.exp(s - m_new)
+        l[:] = l[:] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        pv = jax.lax.dot_general(
+            p.astype(vals.dtype), vals,
+            dimension_numbers=(((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)     # [GQ, rank]
+        acc[:] = acc[:] * corr + pv
+        m[:] = m_new
 
     def body(i, _):
         slot = (g0 + i) % 2
@@ -1075,53 +1165,39 @@ def _latent_kernel(len_ref, *refs, mode, DB: int, SB: int, rank: int,
 
         @pl.when(i + 1 < nb)
         def _():
-            dma(row_of(r), nxt_slot, i + 1).start()
+            stream(r, nxt_slot, i + 1, True)
 
         @pl.when((i + 1 == nb) & (r_next < R))
         def _():
-            dma(row_of(r_next), nxt_slot, 0).start()
+            stream(r_next, nxt_slot, 0, True)
 
-        dma(row_of(r), slot, i).wait()
+        stream(r, slot, i, False)
         if mode == "append":
             @pl.when(i == bp)
             def _():
-                # merge the new entry into the streamed block (attention
-                # then sees the cache as after the append) and write its
-                # aligned window back; rows before it re-land as they
+                # merge the new entry into its window of the streamed block
+                # (attention then sees the cache as after the append) and
+                # write the window back; rows before it re-land as they
                 # were, rows after it hold nothing valid yet
-                sub_ids = jax.lax.broadcasted_iota(jnp.int32, cbuf.shape[1:],
-                                                   0)
-                cbuf[slot] = jnp.where(sub_ids == p_app - bp * DB,
-                                       new_ref[0], cbuf[slot])
+                ids = jax.lax.broadcasted_iota(jnp.int32, (A, cbuf.shape[-1]),
+                                               0)
+                cbuf[slot, pl.ds(at, A), :] = jnp.where(
+                    ids == p_app - bp * DB - at, new_ref[0],
+                    cbuf[slot, pl.ds(at, A), :])
                 writeback(slot).start()
-        # the sub-blocks of this block that hold a valid position
-        live = jnp.minimum((length - i * DB + (SB - 1)) // SB, DB // SB)
+        if block:
+            c = cbuf[slot]                              # [DB, W]
+            s = scores(c)
+            for j in range(0, DB, SB):
+                partition(s[:, j:j + SB], c[j:j + SB, :rank], i * DB + j)
+        else:
+            def sub(j, _):
+                off = pl.multiple_of(j * SB, SB)
+                c = cbuf[slot, pl.ds(off, SB), :]       # [SB, W]
+                partition(scores(c), c[:, :rank], i * DB + off)
+                return 0
 
-        def sub(j, _):
-            off = pl.multiple_of(j * SB, SB)
-            c = cbuf[slot, pl.ds(off, SB), :]       # [SB, W]
-            s = jax.lax.dot_general(
-                q.astype(c.dtype), c,
-                dimension_numbers=(((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)     # [GQ, SB]
-            s = s * qk_scale
-            s_ids = (i * DB + off
-                     + jax.lax.broadcasted_iota(jnp.int32, (GQ, SB), 1))
-            visible = (s_ids <= qp[:, None]) & (s_ids < length)
-            s = jnp.where(visible, s, NEG_INF)
-            m_new = jnp.maximum(m[:], jnp.max(s, axis=-1, keepdims=True))
-            corr = jnp.exp(m[:] - m_new)
-            p = jnp.exp(s - m_new)
-            l[:] = l[:] * corr + jnp.sum(p, axis=-1, keepdims=True)
-            pv = jax.lax.dot_general(
-                p.astype(c.dtype), c[:, :rank],
-                dimension_numbers=(((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)     # [GQ, rank]
-            acc[:] = acc[:] * corr + pv
-            m[:] = m_new
-            return 0
-
-        jax.lax.fori_loop(0, live, sub, 0)
+            jax.lax.fori_loop(0, live(r, i), sub, 0)
         if mode == "append":
             @pl.when(i == bp)
             def _():
@@ -1134,10 +1210,26 @@ def _latent_kernel(len_ref, *refs, mode, DB: int, SB: int, rank: int,
     o_ref[:] = (acc[:] / jnp.maximum(l[:], 1e-30))[None].astype(o_ref.dtype)
 
 
+def _latent_pipe(lengths, DB: int):
+    """What a program of ``_latent_kernel`` must know of the other rows to
+    take its place in the DMA pipeline, ``[2, R]`` int32: the DMA blocks of
+    the rows before it (the parity of its first slot; 0: it starts the
+    pipeline itself) and the next row after it with a block to fetch (``R``:
+    none). One small fusion a call where every program walked all ``R``
+    lengths on its scalar unit."""
+    R = lengths.shape[0]
+    nb = (lengths + (DB - 1)) // DB
+    j = jnp.arange(R, dtype=jnp.int32)
+    before = j[None, :] < j[:, None]                    # [r, j]: j < r
+    g0 = jnp.sum(jnp.where(before, nb[None, :], 0), axis=1)
+    r_next = jnp.min(jnp.where(before.T & (nb[None, :] > 0), j[None, :], R),
+                     axis=1)
+    return jnp.stack([g0, r_next]).astype(jnp.int32)
+
+
 @functools.partial(
     jax.jit,
-    static_argnames=("rank", "qk_scale", "interpret", "out_dtype",
-                     "layer_idx"))
+    static_argnames=("rank", "qk_scale", "interpret", "out_dtype"))
 def flash_attend_latent(q, cache, lengths, qpos, rows=None, append=None, *,
                         rank: int, qk_scale: float, out_dtype=None,
                         layer_idx=None, interpret=False):
@@ -1148,7 +1240,9 @@ def flash_attend_latent(q, cache, lengths, qpos, rows=None, append=None, *,
                             (ops/kv_layout.latent_query; any scale by
                             position already applied)
     cache    [R, 1, S, W]   the latent cache as stored; or the stack
-                            [L, R, 1, S, W] with ``layer_idx``. One stream:
+                            [L, R, 1, S, W] with ``layer_idx`` (an operand:
+                            the calls of a program's layers share one
+                            trace). One stream:
                             each block is fetched once, scored against
                             whole and its first ``rank`` lanes taken as the
                             values
@@ -1172,6 +1266,7 @@ def flash_attend_latent(q, cache, lengths, qpos, rows=None, append=None, *,
     S = cache.shape[-2]
     assert cache.shape[-1] == W and cache.shape[-3] == 1, (q.shape,
                                                            cache.shape)
+    assert (layer_idx is None) == (cache.ndim == 4), cache.shape
     DB, SB = _pick_latent_blocks(S)
     assert supports_latent(S, W, rank), (S, W, rank)
     groups = latent_head_groups(H, Q, W, rank, S, q.dtype.itemsize,
@@ -1206,8 +1301,15 @@ def flash_attend_latent(q, cache, lengths, qpos, rows=None, append=None, *,
         bytes_accessed=R * S * W * isz,
         transcendentals=R * GQ * S)
     kern = functools.partial(
-        _latent_kernel, mode=mode, DB=DB, SB=SB, rank=rank,
-        qk_scale=float(qk_scale), layer_idx=layer_idx)
+        _latent_kernel, mode=mode, block=_scores_fit(GQ, DB),
+        stacked=layer_idx is not None, DB=DB, SB=SB, rank=rank,
+        qk_scale=float(qk_scale))
+    # scalars every program reads: the lengths, its place in the DMA
+    # pipeline and, on a stack, the layer (an operand, not a constant: the
+    # layers' calls of one program are then one trace of this function)
+    prefetch = [lengths, _latent_pipe(lengths, DB)] + (
+        [] if layer_idx is None
+        else [jnp.asarray(layer_idx, jnp.int32).reshape(1)])
     q_specs = [
         pl.BlockSpec((1, GQ, W), lambda r, *_: (r, 0, 0),
                      memory_space=pltpu.VMEM),                   # qt
@@ -1229,8 +1331,8 @@ def flash_attend_latent(q, cache, lengths, qpos, rows=None, append=None, *,
         return out.reshape(R, H, Q, rank).transpose(0, 2, 1, 3)
 
     if append is None:
-        prefetch = [lengths] + ([] if rows is None
-                                else [rows.astype(jnp.int32)])
+        if rows is not None:
+            prefetch.append(rows.astype(jnp.int32))
         out = pl.pallas_call(
             kern, grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=len(prefetch), grid=(R,),
@@ -1244,17 +1346,18 @@ def flash_attend_latent(q, cache, lengths, qpos, rows=None, append=None, *,
     entry, appos = append
     out, cache = pl.pallas_call(
         kern, grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(R,),
+            num_scalar_prefetch=len(prefetch) + 1, grid=(R,),
             in_specs=q_specs + [
                 pl.BlockSpec((1, 1, W), lambda r, *_: (r, 0, 0),
                              memory_space=pltpu.VMEM), hbm],
             out_specs=(o_spec, hbm),
             scratch_shapes=scratch + [pltpu.SemaphoreType.DMA((1,))]),
         out_shape=(o_shape, jax.ShapeDtypeStruct(cache.shape, cache.dtype)),
-        input_output_aliases={5: 1},        # the cache operand -> output
+        # the cache operand -> output
+        input_output_aliases={len(prefetch) + 4: 1},
         compiler_params=compiler_params, cost_estimate=cost_estimate,
         interpret=interpret, name="flash_attend_latent",
-    )(lengths, appos.astype(jnp.int32), qt, qp_gq,
+    )(*prefetch, appos.astype(jnp.int32), qt, qp_gq,
       entry.reshape(R, 1, W).astype(cache.dtype), cache)
     return post(out), cache
 

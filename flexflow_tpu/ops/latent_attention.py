@@ -136,6 +136,7 @@ def _attend(attrs, q, entry, cache, layer_idx: int, meta, ctx):
     step appends first (``append_latent``)."""
     from flexflow_tpu import kernels as ffk
     from flexflow_tpu.kernels.attention import (flash_attend_latent,
+                                                latent_form,
                                                 latent_head_groups,
                                                 reference_attend_latent,
                                                 supports_latent)
@@ -149,20 +150,25 @@ def _attend(attrs, q, entry, cache, layer_idx: int, meta, ctx):
         qk_scale=attrs["softmax_scale"], layer_idx=layer_idx,
         interpret=ffk.pallas_interpret_forced())
     kernel = False
+    fused = Q == 1 and rows is None     # a decode step: the kernel appends
     if ffk.use_pallas(ctx.config if ctx is not None else None):
         kernel = supports_latent(S, cache.shape[-1], rank)
+        groups = kernel and latent_head_groups(
+            q.shape[2], Q, cache.shape[-1], rank, S, q.dtype.itemsize,
+            cache.dtype.itemsize)
         if not kernel:
             ffk.record_fallback(
                 f"latent cache S={S} width={cache.shape[-1]} rank={rank} "
                 "not tileable")
-        elif not latent_head_groups(q.shape[2], Q, cache.shape[-1], rank, S,
-                                    q.dtype.itemsize, cache.dtype.itemsize):
+        elif not groups:
             kernel = False
             ffk.record_fallback(
                 f"latent queries of {Q} tokens: not one head's rows fit VMEM")
         else:
-            ffk.record_fast_path()
-    if kernel and Q == 1 and rows is None:
+            ffk.record_fast_path(latent=(
+                latent_form(q.shape[2] // groups * Q, S),
+                "append" if fused else "grid" if rows is None else "rows"))
+    if kernel and fused:
         appos = jnp.where(
             meta.active & (meta.num_tokens > 0) & (meta.start_pos < S),
             meta.start_pos, -1)

@@ -125,6 +125,16 @@ def test_program_matches_plain_reference_through_the_latent_caches(
     if how == "interpreted_kernels":
         assert ffk.fast_path_count > 0 and not ffk.fallback_counts
         assert K.fast_path_count > 0 and not K.fallback_counts
+        # the decode step's few query rows take the block form of the
+        # latent kernel, the append fused; at this size a prefill chunk's
+        # rows fit beside the stream too (128 KB of scores a block of 512
+        # positions), where the cell's 4096 rows a group of 32 heads take
+        # the partition loop; one trace a latent cache each
+        from flexflow_tpu.kernels.attention import latent_form
+        assert ffk.latent_form_counts == {("block", "append"): 4,
+                                          ("block", "grid"): 4}
+        assert latent_form(64, 8192) == "block"
+        assert latent_form(64 // 2 * 128, 8192) == "partition"
 
 
 # ---------------------------------------------------------------------------

@@ -12,6 +12,7 @@ four expert shares against the whole layer; the HF weight map; what refuses
 a latent layer; and the series telemetry keeps of the kind.
 """
 
+import functools
 import os
 import sys
 
@@ -26,6 +27,7 @@ from flexflow_tpu.ffconst import InferenceMode
 from flexflow_tpu.kernels import moe as K
 from flexflow_tpu.kernels.attention import (_pick_latent_blocks,
                                             flash_attend_latent,
+                                            latent_form,
                                             reference_attend_latent,
                                             supports_latent)
 from flexflow_tpu.models import FAMILIES
@@ -106,6 +108,27 @@ KERNEL_CASES = {
         (16, (1000, 1016, 40), (16, 16, 3), "twice", False),
     "grid_chunks_across_a_dma_block_edge":
         (16, (1016, 5, 2030), (16, 16, 16), False, False),
+    # the decode step's block form scores a whole DMA block (1024 positions,
+    # four softmax partitions) and masks the partitions past the row's end
+    "decode_append_last_block_of_1_2_and_3_live_partitions":
+        (1, (1124, 1324, 1624), (1, 1, 1), "append", False),
+    "decode_append_last_block_of_4_live_partitions_and_partition_edges":
+        (1, (1900, 255, 256), (1, 1, 1), "append", False),
+    "decode_append_at_positions_1023_and_1024":
+        (1, (1023, 1024, 1022), (1, 1, 1), "append", True),
+    "decode_over_a_row_of_exactly_one_dma_block":
+        (1, (1023, 2047, 511), (1, 1, 1), False, False),
+    "decode_append_hand_off_over_an_idle_row_between_two_live_rows":
+        (1, (1500, 700, 1100), (1, 0, 1), "append", False),
+    "decode_append_after_an_idle_first_row":
+        (1, (5, 1500, 2040), (0, 1, 1), "append", True),
+    # 128 tokens a row of 4 heads are 512 query rows: a block's scores (2
+    # MiB) do not fit beside the stream, so the partition loop serves them,
+    # as it serves a cell's prefill segment
+    "row_map_chunks_ragged_in_the_partition_form":
+        (128, (0, 250, 1300), (128, 0, 77), True, True),
+    "grid_chunks_across_a_dma_block_edge_in_the_partition_form":
+        (128, (960, 5, 1900), (128, 128, 100), False, False),
 }
 
 
@@ -120,6 +143,8 @@ def test_latent_flash_attend_matches_the_oracle(case):
     attended, and an idle row's cache is left as it was."""
     Q, starts, nums, row_map, stacked = KERNEL_CASES[case]
     R, H, W, rank, S = 3, 32 if "32_heads" in case else 4, 256, 128, 2048
+    assert latent_form(H * Q, S) == (
+        "partition" if "partition_form" in case else "block")
     rng = np.random.default_rng(0)
     starts, nums = np.array(starts), np.array(nums)
     lengths = np.where(nums > 0, starts + nums, 0)
@@ -138,7 +163,8 @@ def test_latent_flash_attend_matches_the_oracle(case):
         append = (cache[at][:, None, None], i32(np.where(nums > 0, starts,
                                                          -1)))
         c = cache.at[at].set(7.0)
-        cache = cache.at[1, 0, 0].set(7.0) if nums[1] == 0 else cache
+        for r in np.nonzero(nums == 0)[0]:      # an idle row's stays
+            cache = cache.at[r, 0, 0].set(7.0)
     if stacked:
         c, layer = jnp.stack([c * 0, c]), 1
     out = flash_attend_latent(q, c, i32(lengths), i32(qpos), rows, append,
@@ -158,6 +184,54 @@ def test_latent_flash_attend_matches_the_oracle(case):
         np.testing.assert_allclose(
             np.asarray(out[r, :nums[r]], np.float32),
             np.asarray(want[r, :nums[r]], np.float32), atol=3e-2)
+
+
+def test_block_form_and_partition_form_agree_bit_for_bit():
+    """The same queries over the same cache through both forms of the
+    kernel: one token a row of 4 heads (a block's scores fit beside the
+    stream: the block form) against the same rows as token 0 of a call of
+    128 tokens a row (512 query rows do not: the partition loop). Each
+    score is the same dot product and the softmax advances through the same
+    partitions, so the outputs are equal bit for bit."""
+    R, H, W, rank, S = 3, 4, 256, 128, 2048
+    rng = np.random.default_rng(1)
+    bf = lambda a: jnp.asarray(a, jnp.bfloat16)
+    i32 = lambda a: jnp.asarray(a, jnp.int32)
+    cache = bf(rng.standard_normal((R, 1, S, W)) * 0.5)
+    q = bf(rng.standard_normal((R, 128, H, W)))
+    starts = np.array([1023, 300, 1700])
+    lengths = i32(starts + 1)
+    qpos = starts[:, None] + np.arange(128)[None]
+    assert latent_form(H, S) == "block"
+    assert latent_form(H * 128, S) == "partition"
+    attend = functools.partial(flash_attend_latent, rank=rank, qk_scale=0.1,
+                               interpret=True)
+    narrow = attend(q[:, :1], cache, lengths, i32(qpos[:, :1]))
+    wide = attend(q, cache, lengths, i32(qpos))
+    np.testing.assert_array_equal(np.asarray(narrow, np.float32),
+                                  np.asarray(wide[:, :1], np.float32))
+    # and the decode step's own call, the append fused: the same bits
+    at = (np.arange(R), 0, starts)
+    fused, c2 = attend(q[:, :1], cache.at[at].set(7.0), lengths,
+                       i32(qpos[:, :1]), None,
+                       (cache[at][:, None, None], i32(starts)))
+    np.testing.assert_array_equal(np.asarray(fused, np.float32),
+                                  np.asarray(narrow, np.float32))
+    np.testing.assert_array_equal(np.asarray(c2, np.float32),
+                                  np.asarray(cache, np.float32))
+
+
+def test_the_form_is_decided_from_the_shapes_of_the_call():
+    """Both cells' decode steps take the block form and their prefill
+    segments the partition loop; the rule turns at 256 query rows of a
+    1024-position block (1 MiB of float32 scores)."""
+    assert latent_form(32, 32768) == "block"            # Mistral's decode
+    assert latent_form(32 * 128, 32768) == "partition"  # and its segment
+    assert latent_form(64, 8192) == "block"             # LongCat's decode
+    assert latent_form(32 * 128, 8192) == "partition"   # a group of 32 heads
+    assert latent_form(256, 8192) == "block"
+    assert latent_form(257, 8192) == "partition"
+    assert latent_form(512, 512) == "block"             # a block of 512
 
 
 def test_latent_blocks_depend_on_the_cache_alone():
@@ -361,6 +435,15 @@ def test_the_kernel_serves_the_program_interpreted(bench, monkeypatch):
     np.testing.assert_allclose(ours, np.asarray(ref), rtol=3e-4, atol=3e-4)
     assert ffk.fast_path_count > 0 and not ffk.fallback_counts
     assert K.fast_path_count > 0 and not K.fallback_counts
+    # the decode step's 4 query rows take the block form, the append fused;
+    # a prefill chunk's 64 rows over a block of 512 positions (128 KB of
+    # scores) fit too at this size, where a cell's 4096 rows do not
+    # (test_the_form_is_decided_from_the_shapes_of_the_call); one trace a
+    # layer each
+    assert ffk.latent_form_counts == {("block", "append"): 2,
+                                      ("block", "grid"): 2}
+    assert ffk.latent_summary() == ("latent kernel: block form, append: 2 "
+                                    "traces; block form, grid: 2 traces")
 
 
 def test_the_blocked_reference_is_the_whole_one_on_its_tail(bench):

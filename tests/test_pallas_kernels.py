@@ -695,25 +695,47 @@ def _latent_case(R, Q, H, W, rank, S, starts, nums, seed=0):
             jnp.asarray(qpos, jnp.int32), starts, nums, lengths)
 
 
-@pytest.mark.parametrize("form", ["decode_append", "prefill_row_map"])
+#  form: (S, start positions, real tokens) of a decode step's three rows
+LATENT_64_DECODES = {
+    "decode_append": (1024, (0, 511, 1023), (1, 0, 1)),
+    # the block form scores all 1024 positions of a DMA block and masks the
+    # partitions (256 positions) past the row's end
+    "decode_append_last_block_of_1_2_and_3_live_partitions":
+        (2048, (1100, 1400, 1700), (1, 1, 1)),
+    "decode_append_last_block_of_4_live_partitions":
+        (2048, (2000, 767, 768), (1, 1, 1)),
+    "decode_append_at_positions_1023_and_1024":
+        (2048, (1023, 1024, 2047), (1, 1, 1)),
+    "decode_append_hand_off_over_an_idle_row":
+        (2048, (1500, 900, 1030), (1, 0, 1)),
+}
+
+
+@pytest.mark.parametrize("form",
+                         sorted(LATENT_64_DECODES) + ["prefill_row_map"])
 def test_latent_kernel_at_64_heads_of_640_lanes(form):
     """64 heads, rank 512, 640 stored lanes (512 + 64 in whole lane tiles):
     the decode form, one token a row with the fused append, is ONE call of
-    64 query rows; the compact prefill's row map at 128 tokens a segment is
-    8192 query rows, which do not fit the kernel's VMEM beside the stream
-    (108.8 MB of scoped VMEM against 100, by the chip's compiler), so the
-    heads go in two calls of 32 over the same cache. Both against the jnp
-    oracle, on a stack."""
-    H, W, rank, S = 64, 640, 512, 1024
-    if form == "decode_append":
+    64 query rows in the block form; the compact prefill's row map at 128
+    tokens a segment is 8192 query rows, which do not fit the kernel's VMEM
+    beside the stream (108.8 MB of scoped VMEM against 100, by the chip's
+    compiler), so the heads go in two calls of 32 over the same cache, in
+    the partition form. Both against the jnp oracle, on a stack, the cache
+    after the append compared exactly."""
+    H, W, rank = 64, 640, 512
+    if form in LATENT_64_DECODES:
         R, Q = 3, 1
+        S, starts, nums = LATENT_64_DECODES[form]
         q, cache, lengths, qpos, starts, nums, ln = _latent_case(
-            R, Q, H, W, rank, S, (0, 511, 1023), (1, 0, 1))
+            R, Q, H, W, rank, S, starts, nums)
         assert fa.latent_head_groups(H, Q, W, rank, S) == 1
+        assert fa.latent_form(H * Q, S) == "block"
         at = (np.arange(R), 0, np.maximum(ln - 1, 0))
         append = (cache[at][:, None, None],
                   jnp.asarray(np.where(nums > 0, starts, -1), jnp.int32))
-        before = cache.at[at].set(7.0).at[1, 0, 0].set(cache[1, 0, 0])
+        before = cache.at[at].set(7.0)
+        for r in np.nonzero(nums == 0)[0]:          # an idle row's stays
+            before = before.at[r, 0, 0].set(cache[r, 0, 0])
         out, c2 = fa.flash_attend_latent(
             q, jnp.stack([before * 0, before]), lengths, qpos, None, append,
             rank=rank, qk_scale=0.1, layer_idx=1, interpret=True)
@@ -723,11 +745,12 @@ def test_latent_kernel_at_64_heads_of_640_lanes(form):
         want = fa.reference_attend_latent(q, cache, lengths, qpos, rank=rank,
                                           qk_scale=0.1)
     else:
-        R, Q = 2, 128
+        R, Q, S = 2, 128, 1024
         q, cache, lengths, qpos, starts, nums, ln = _latent_case(
             R, Q, H, W, rank, S, (896, 100), (128, 37))
         assert fa.latent_head_groups(H, Q, W, rank, S) == 2
         assert fa.latent_head_groups(H, Q, W, rank, 8192) == 2
+        assert fa.latent_form(H // 2 * Q, S) == "partition"
         rows = jnp.asarray([1, 0], jnp.int32)
         out = fa.flash_attend_latent(
             q, jnp.stack([cache * 0, cache]), lengths, qpos, rows,

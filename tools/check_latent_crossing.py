@@ -10,8 +10,10 @@ position 0 but the position scale ``s(p)`` is 1 below
 sequence that crosses that position (8320 tokens prefilled in chunks through
 the latent cache, then 4 decoded) and compares the last 256 prefilled and
 the decoded positions' logits with the plain reference in its blocked form
-(``benchmark/families/mistral4.crossing_check``). One JSON line; exit 1 if
-the logits or the routes are outside the family's limits. ``--rehearse``:
+(``benchmark/families/mistral4.crossing_check``). One JSON line (its
+``latent_forms``: which form of the latent kernel each traced program took,
+``flexflow_tpu.kernels.latent_summary()``); exit 1 if the logits or the
+routes are outside the family's limits. ``--rehearse``:
 CPU, the configuration's rehearsal sizes, interpreted kernels.
 """
 
@@ -38,6 +40,7 @@ def main(argv=None) -> int:
         os.environ["FF_PALLAS_INTERPRET"] = "1"
     import jax
 
+    import flexflow_tpu.kernels as ffk
     from benchmark import run as bench_run
     from benchmark.families import _common as C
 
@@ -56,6 +59,7 @@ def main(argv=None) -> int:
         + C.prefill_chunk(cfg))
     res = family.crossing_check(cfg, reference, prefilled)
     res["device"] = jax.devices()[0].device_kind
+    res["latent_forms"] = ffk.latent_summary()
     print(json.dumps(res), flush=True)
     return 0 if res["ok"] else 1
 
