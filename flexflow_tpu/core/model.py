@@ -157,8 +157,12 @@ class FFModel:
               kernel_initializer=None, bias_initializer=None,
               kernel_regularizer=None, keep_f32_logits: bool = False,
               data_type: Optional[DataType] = None,
-              name: Optional[str] = None) -> Tensor:
-        """kernel_regularizer: ("l1"|"l2", coeff) or a list of such pairs —
+              name: Optional[str] = None,
+              tied_to: Optional[str] = None) -> Tensor:
+        """``tied_to``: the name of an ``embedding`` layer whose table this
+        layer multiplies by, transposed, in place of a kernel of its own (a
+        tied head: one array, quantised once, read by both; no bias).
+        kernel_regularizer: ("l1"|"l2", coeff) or a list of such pairs —
         added to the training loss (reference keras regularizers).
         keep_f32_logits: for LM heads feeding argmax/sampling — emit the
         gemm's f32 accumulator instead of rounding to the compute dtype
@@ -177,7 +181,9 @@ class FFModel:
             kernel_initializer=kernel_initializer,
             bias_initializer=bias_initializer,
             keep_f32_logits=keep_f32_logits,
-            kernel_regularizer=_normalize_regularizer(kernel_regularizer)),
+            kernel_regularizer=_normalize_regularizer(kernel_regularizer),
+            # only a tied layer carries the key
+            **({} if tied_to is None else {"tied_to": tied_to})),
             name)
 
     def conv2d(self, input: Tensor, out_channels: int, kernel_h: int,
@@ -436,6 +442,33 @@ class FFModel:
             rope_theta=rope_theta, rope_factor=float(rope_factor),
             pos_scale_beta=float(pos_scale_beta),
             pos_scale_period=int(pos_scale_period), norm_eps=norm_eps,
+            data_type=data_type, kernel_initializer=kernel_initializer,
+            max_requests=self.config.max_requests_per_batch,
+            max_seq_length=self.config.max_sequence_length,
+            use_pallas=self.config.use_pallas,
+            cache_dtype=self.config.kv_cache_dtype), name)
+
+    def inc_cca_attention(self, input: Tensor, embed_dim: int,
+                          num_q_heads: int, num_kv_heads: int, head_dim: int,
+                          rotary_dim: int, rope_theta: float = 10000.0,
+                          data_type: Optional[DataType] = None,
+                          kernel_initializer=None, name=None) -> Tensor:
+        """Attention in a convolved latent for incremental decoding
+        (ops/cca_attention.py, imported here: only a model that has such a
+        layer loads it): ``num_q_heads`` query and ``num_kv_heads`` key/value
+        heads of ``head_dim``, mixed along the sequence before they are
+        normalised, rotated over their first ``rotary_dim`` dims and stored
+        in a plain k/v cache; a slot keeps the row's tail beside it."""
+        from flexflow_tpu.ops import cca_attention  # noqa: F401 (registers)
+
+        assert num_q_heads % num_kv_heads == 0 and num_kv_heads % 2 == 0, (
+            num_q_heads, num_kv_heads)
+        assert 0 < rotary_dim <= head_dim and rotary_dim % 2 == 0, rotary_dim
+        return self._add_layer(OpType.INC_MULTIHEAD_CCA_ATTENTION, [input],
+                               dict(
+            embed_dim=embed_dim, num_q_heads=num_q_heads,
+            num_kv_heads=num_kv_heads, head_dim=head_dim,
+            rotary_dim=int(rotary_dim), rope_theta=float(rope_theta),
             data_type=data_type, kernel_initializer=kernel_initializer,
             max_requests=self.config.max_requests_per_batch,
             max_seq_length=self.config.max_sequence_length,
@@ -890,6 +923,8 @@ class FFModel:
         # compressed form), then int8/int4 dequantizes lazily — all
         # inside the jitted step so XLA overlaps transfer with compute
         lp = params.get(layer.name, {})
+        if "tied_to" in layer.attrs:    # a head on the embedding's table
+            lp = {**lp, "table": params[layer.attrs["tied_to"]]["weight"]}
         if layer.name in offloaded:
             lp = fetch_layer_params(lp, offloaded[layer.name])
         if not impl.quant_aware:
@@ -1327,9 +1362,31 @@ class FFModel:
         """
         from flexflow_tpu.ops.inc_attention import (CHUNKED_STACK,
                                                     FULL_STACK, LATENT_STACK,
-                                                    WINDOW_STACK)
+                                                    TAIL_STACK, WINDOW_STACK)
 
         by_name = {layer.name: layer for layer in self.layers}
+        tails = [n for n, st in self.op_state.items()
+                 if isinstance(st, dict) and "tail" in st]
+        if tails:
+            # layers that carry a row's tail beside a plain k/v cache
+            # (ops/cca_attention.py): the tails are one stack at any depth,
+            # the caches go the way of every plain cache below
+            (shape,) = {self.op_state[n]["tail"].shape for n in tails}
+            (dtype,) = {self.op_state[n]["tail"].dtype for n in tails}
+            for i, n in enumerate(tails):
+                by_name[n].attrs["tail_layer_idx"] = i
+                del self.op_state[n]["tail"]
+            self.op_state[TAIL_STACK] = {
+                "t": jnp.zeros((len(tails),) + shape, dtype)}
+            # what telemetry says of the kind, and what says "this model
+            # carries a tail" (ffsv_kv_cache_bytes{kind="full"|"tail"},
+            # ffsv_attn_positions_read_total{kind="full"},
+            # ffsv_cca_tails_total)
+            self.attention_kinds = {"full": {
+                "layers": len(tails), "window": None,
+                "cache_bytes": sum(2 * self.op_state[n]["k_cache"].nbytes
+                                   for n in tails),
+                "tail_bytes": self.op_state[TAIL_STACK]["t"].nbytes}}
         latent = [n for n, st in self.op_state.items()
                   if isinstance(st, dict) and "c_cache" in st]
         if latent:
@@ -1350,6 +1407,10 @@ class FFModel:
         if latent and names:
             raise NotImplementedError(
                 "latent attention layers beside k/v ones in one model")
+        if tails and len(tails) != len(names):
+            raise NotImplementedError(
+                "attention layers that carry a tail beside others in one "
+                "model")
         rings = [n for n in names
                  if by_name[n].attrs.get("sliding_window") is not None]
         chunked = [n for n in names

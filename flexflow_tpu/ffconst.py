@@ -196,3 +196,6 @@ class OpType(enum.Enum):
     # serving attention over a latent cache (ops/latent_attention.py); last,
     # so that no earlier member's value moved
     INC_MULTIHEAD_LATENT_ATTENTION = enum.auto()
+    # serving attention in a convolved latent, with a tail a slot
+    # (ops/cca_attention.py)
+    INC_MULTIHEAD_CCA_ATTENTION = enum.auto()

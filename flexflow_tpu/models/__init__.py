@@ -2,7 +2,7 @@
 
 Capability parity with the reference model zoo (reference inference/models/
 llama.cc, opt.cc, falcon.cc, mpt.cc, starcoder.cc and their Python twins in
-python/flexflow/serve/models/; OLMoE, EXAONE-MoE, Mistral-4, SDAR-MoE and LongCat-Flash, sparse-expert families, and EvaByte, a byte-level model of chunked attention, are beyond it): each model family is a builder that records
+python/flexflow/serve/models/; OLMoE, EXAONE-MoE, Mistral-4, SDAR-MoE, LongCat-Flash and ZAYA1, sparse-expert families, and EvaByte, a byte-level model of chunked attention, are beyond it): each model family is a builder that records
 the decoder graph through the FFModel op-builder surface, plus a HuggingFace
 state-dict name mapping so real checkpoints load. ``FAMILIES`` maps the HF
 ``model_type`` to the family (the reference's ModelType enum +
@@ -23,6 +23,7 @@ from flexflow_tpu.models import olmoe as _olmoe
 from flexflow_tpu.models import opt as _opt
 from flexflow_tpu.models import sdar_moe as _sdar_moe
 from flexflow_tpu.models import starcoder as _starcoder
+from flexflow_tpu.models import zaya as _zaya
 from flexflow_tpu.models.evabyte import EvaByteConfig, create_evabyte_model
 from flexflow_tpu.models.exaone_moe import (ExaoneMoEConfig,
                                             create_exaone_moe_model)
@@ -39,6 +40,7 @@ from flexflow_tpu.models.opt import OPTConfig, create_opt_model
 from flexflow_tpu.models.sdar_moe import SDARMoEConfig, create_sdar_moe_model
 from flexflow_tpu.models.starcoder import (STARCODERConfig,
                                            create_starcoder_model)
+from flexflow_tpu.models.zaya import ZayaConfig, create_zaya_model
 
 
 @dataclasses.dataclass(frozen=True)
@@ -94,6 +96,11 @@ FAMILIES = {
                                create_starcoder_model,
                                _starcoder.hf_weight_map,
                                _starcoder.preprocess_hf_state_dict),
+    # its attention op (ops/cca_attention.py) is imported by the builder
+    # call that records such a layer, not here
+    "zaya": ModelFamily("zaya", ZayaConfig, create_zaya_model,
+                        _zaya.hf_weight_map,
+                        _zaya.preprocess_hf_state_dict),
 }
 FAMILIES["starcoder"] = FAMILIES["gpt_bigcode"]
 # Legacy HF names for early Falcon checkpoints (tiiuae/falcon-7b pre-rename).
@@ -125,6 +132,7 @@ __all__ = [
     "OPTConfig",
     "SDARMoEConfig",
     "STARCODERConfig",
+    "ZayaConfig",
     "create_evabyte_model",
     "create_exaone_moe_model",
     "create_falcon_model",
@@ -136,6 +144,7 @@ __all__ = [
     "create_opt_model",
     "create_sdar_moe_model",
     "create_starcoder_model",
+    "create_zaya_model",
     "family_for_hf_config",
     "load_hf_state_dict",
 ]

@@ -66,6 +66,25 @@ def apply_rotary(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray
     return x * cos[:, :, None, :] + rotated * sin[:, :, None, :]
 
 
+def apply_partial_rotary(x: jnp.ndarray, positions: jnp.ndarray,
+                         rotary_dim: int, theta: float) -> jnp.ndarray:
+    """x [R, Q, heads, D] rotated over the FIRST ``rotary_dim`` of each
+    head's dims (rotate-half inside them, frequencies over ``rotary_dim``:
+    HF ``partial_rotary_factor``); the rest pass as they are."""
+    cos, sin = rotary_cos_sin(positions, rotary_dim, theta, x.dtype)
+    half, rest = rotary_dim // 2, x.shape[-1] - rotary_dim
+    # one shuffle of the head's dims and full-width tables (cos 1, sin 0
+    # over the dims that pass): the same numbers as rotating a slice and
+    # concatenating, in fewer device operations
+    pad = [(0, 0)] * (cos.ndim - 1) + [(0, rest)]
+    cos = jnp.pad(cos, pad, constant_values=1)[:, :, None, :]
+    sin = jnp.pad(sin, pad)[:, :, None, :]
+    turned = jnp.concatenate(
+        [-x[..., half:rotary_dim], x[..., :half], x[..., rotary_dim:]],
+        axis=-1)
+    return x * cos + turned * sin
+
+
 # ----------------------------------------------------------------------
 # KV cache update (reference update_kv_cache_kernel, inc_mha.cu:376)
 # ----------------------------------------------------------------------
@@ -606,13 +625,18 @@ LATENT_STACK = "kv_cache_latent"
 # one stream: its stack is op_state[CHUNKED_STACK] = {"k", "v"} of
 # [L, R, KH, S / chunk + window, D], whatever the depth.
 CHUNKED_STACK = "kv_cache_chunked"
+# A layer whose queries, keys and values reach back along the sequence
+# (ops/cca_attention.py) keeps, beside its plain k/v cache, a row's TAIL:
+# op_state[TAIL_STACK] = {"t": [L, R, width]}, overwritten by every step.
+TAIL_STACK = "cca_tail"
 
 
 def refuse_windowed(op_state, what: str):
-    """A ring holds a slot's last positions only, by ``p % rows``, and a
-    latent layer one shared entry a position, not a k/v pair: what moves,
-    copies or shards cache positions by their index, a pair at a time,
-    says so."""
+    """A ring holds a slot's last positions only, by ``p % rows``, a latent
+    layer one shared entry a position, not a k/v pair, and a layer that
+    carries a tail keeps state that no cache position holds: what moves,
+    copies, rolls back or shards cache positions by their index, a pair at
+    a time, says so."""
     if WINDOW_STACK in (op_state or {}):
         raise NotImplementedError(
             f"{what} is not supported over a windowed attention layer: its "
@@ -628,6 +652,14 @@ def refuse_windowed(op_state, what: str):
             "cache keeps a window's positions and one summary a chunk of "
             "those before (ops/kv_layout.py), so a position once left "
             "cannot be read back, moved or rolled back")
+    if TAIL_STACK in (op_state or {}):
+        raise NotImplementedError(
+            f"{what} is not supported over an attention layer that carries "
+            "a tail: beside its cache a slot keeps the last positions' "
+            "unmixed latents (ops/cca_attention.py), overwritten every "
+            "step, which no cache position holds, so a rejected draft "
+            "cannot be rolled back and a moved, shared or sharded position "
+            "has no tail to go with it")
 
 
 def refuse_block_diffusion(model, what: str):

@@ -58,6 +58,9 @@ class Linear(OpImpl):
         in_dim = shape[-1]
         out_dim = attrs["out_dim"]
         wdtype = attrs.get("data_type") or dtype
+        if "tied_to" in attrs:      # the embedding's table is its kernel
+            assert not attrs.get("use_bias", True), "a tied head has no bias"
+            return []
         specs = [
             WeightSpec("kernel", (in_dim, out_dim), wdtype,
                        attrs.get("kernel_initializer")
@@ -77,8 +80,16 @@ class Linear(OpImpl):
         from flexflow_tpu.quant import is_quantized, qmatmul
 
         x = inputs[0]
-        kernel = params["kernel"]
         compute_dtype = ctx.compute_dtype or x.dtype
+        if "table" in params:
+            # tied to an embedding (FFModel._apply_layer hands the table
+            # over): x . table^T on the one array, float32 logits
+            from flexflow_tpu.quant import qmatmul_t
+
+            return [qmatmul_t(
+                x, params["table"], compute_dtype,
+                jnp.float32 if attrs.get("keep_f32_logits") else None)]
+        kernel = params["kernel"]
         out_dtype = None
         if attrs.get("keep_f32_logits"):
             # logits heads keep the gemm's f32 ACCUMULATOR instead of

@@ -141,7 +141,9 @@ def is_quantized(leaf) -> bool:
 _QUANT_NAMES = {"kernel", "wq", "wk", "wv", "wo", "weight",
                 "w1", "w2", "w3", "gate", "up", "down",
                 # a latent attention layer's (ops/latent_attention.py)
-                "wq_a", "wq_b", "wkv_a", "wk_b", "wv_b"}
+                "wq_a", "wq_b", "wkv_a", "wk_b", "wv_b",
+                # the joined projection of ops/cca_attention.py
+                "wqkv"}
 # ... and of those, the ones that may be a stack [E, in, out] (ops/moe.py;
 # a latent layer's up-projection halves, a head apart)
 _STACKED_NAMES = {"gate", "up", "down", "wk_b", "wv_b"}
@@ -199,6 +201,28 @@ def qmatmul(x, w, compute_dtype=None, out_dtype=None):
         dimension_numbers=(((x.ndim - 1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
     return (y * w.scale).astype(od)
+
+
+def qmatmul_t(x, table, compute_dtype=None, out_dtype=None):
+    """``x @ table.T`` for a possibly-quantized ``[rows, in]`` table (an
+    embedding's, read as a tied head). Its scale is per COLUMN of the table,
+    the contracted dim here, so it goes onto ``x`` first, which is exact
+    for the same reason ``qmatmul``'s goes onto the result; the gemm reads
+    the int8 payload as stored."""
+    cd = compute_dtype or x.dtype
+    payload = table
+    if is_quantized(table):
+        if table.qtype != "int8":
+            raise NotImplementedError(
+                f"a tied head over an {table.qtype} table: its rows pack in "
+                "pairs along the dim the head keeps")
+        x = x.astype(jnp.float32) * table.scale
+        payload = table.q
+    y = jax.lax.dot_general(
+        x.astype(cd), jnp.asarray(payload).astype(cd),
+        dimension_numbers=(((x.ndim - 1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    return y.astype(out_dtype or cd)
 
 
 def qtake(table, ids):

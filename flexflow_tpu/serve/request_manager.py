@@ -640,6 +640,9 @@ class RequestManager:
         if kinds and "chunked" in kinds:
             tel.note_chunked_prefill(kinds["chunked"],
                                      [(sp, len(chunk)) for _, chunk, sp in rows])
+        if kinds and "tail_bytes" in kinds.get("full", ()):
+            tel.note_prefill_tails([(slot, sp, len(chunk))
+                                    for slot, chunk, sp in rows])
         if rnd is not None:
             rnd.phase(None)
         step = PendingPrefill(tel, [(active[slot].guid, sp, len(chunk))
@@ -728,12 +731,25 @@ class RequestManager:
         each, oldest admission first; with ``consecutive`` the spare
         segments go, in the same order, to those with more still pending,
         as their next chunks. A slot's segments come in ascending order of
-        start_pos. ``window`` (a model of chunked attention layers,
-        ops/kv_layout.py): the positions a slot is given in ONE step lie in
-        one window of that many, since the step appends them all before any
-        of them attends and the next window's would overwrite this one's
-        rows: a segment is cut at the boundary, and what lies beyond waits
-        for the next step."""
+        start_pos, and all of a step's segments are computed in ONE forward.
+        The two rules a step's segments obey for that reason:
+
+        1. ONE WINDOW A STEP for a model of chunked attention layers
+           (``window``; ops/kv_layout.py): the positions a slot is given in
+           one step lie in one window of that many, since the step appends
+           them all before any of them attends and the next window's would
+           overwrite this one's rows: a segment is cut at the boundary, and
+           what lies beyond waits for the next step.
+        2. A TAIL FROM THE STEP OR THE STATE for a model whose attention
+           layers carry one (ops/cca_attention.take_tails): a segment that
+           starts where another segment of the same step, the same slot's,
+           ends takes that segment's end as its tail, any other what the
+           last step left in the slot (zeros at position 0), and the step
+           writes back each slot's last segment's. It costs the scheduler
+           nothing: the op reads it off the rows' own slots, starts and
+           lengths, in the ascending order given here
+           (``ServingTelemetry.note_prefill_tails`` counts the same on the
+           host)."""
         rows, taken, first = [], {}, {}
 
         def pending(req):

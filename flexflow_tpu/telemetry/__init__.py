@@ -75,6 +75,7 @@ ffsv_moe_experts_touched         summary    {phase} distinct experts a call read
 ffsv_moe_resident_calls_total    counter    {phase} calls that kept their rows in VMEM
 ffsv_moe_expert_pairs_total      counter    {expert} routed pairs of one expert
 ffsv_moe_zero_pairs_total        counter    {phase} picks that were no expert (w * x)
+ffsv_cca_tails_total             counter    {phase,source} rows by where their tail came from
 ===============================  =========  =================================
 
 A decode block's step is one token a row, or one pass over a row's block
@@ -107,6 +108,15 @@ chunk of a layer each, prefill and decode) and
 ``ffsv_window_rollovers_total{phase}`` the rows whose position entered a
 new window (``prefill``: a segment that starts one; ``decode``: a step),
 where nothing is moved or launched.
+A model whose attention layers CARRY A TAIL (ops/cca_attention.py: the last
+positions' unmixed latents, a slot's state beside a plain k/v cache, which
+every step overwrites; ``"tail_bytes"`` under ``attention_kinds["full"]``)
+has ``kind="full"`` for its caches, ``ffsv_kv_cache_bytes{kind="tail"}`` for
+the tails, and ``ffsv_cca_tails_total{phase,source}``: a prefill step's
+segments and a decode block's row-steps by where their tail came from, on
+the host from the step's own rows: ``start`` (position 0: zeros), ``step``
+(another segment of the same step, the same slot's, that ends where this
+one starts) or ``state`` (what an earlier step left in the slot).
 ``ffsv_kv_cache_bytes`` is what compile allocated for each kind;
 ``ffsv_attn_positions_read_total`` is what the rows of the decode steps had
 to attend, from the batch's lengths on the host: for each row of each step
@@ -531,6 +541,11 @@ class ServingTelemetry:
                 f'ffsv_kv_cache_bytes{{kind="{kind}"}}',
                 "bytes of the KV caches of one kind of attention layer"
                 ).set(a["cache_bytes"])
+            if "tail_bytes" in a:       # layers that carry a row's tail
+                self.registry.gauge(
+                    'ffsv_kv_cache_bytes{kind="tail"}',
+                    "bytes of the tails kept beside the caches"
+                    ).set(a["tail_bytes"])
 
     def note_attention_reads(self, kinds, lengths, steps: int):
         """A decode block of ``steps`` steps over rows whose caches hold
@@ -543,6 +558,10 @@ class ServingTelemetry:
         at = np.asarray(lengths, np.int64)[:, None] + np.arange(steps)
         if "chunked" in kinds:
             return self._note_chunked_reads(kinds["chunked"], at)
+        if "tail_bytes" in kinds.get("full", ()):
+            # a row-step's tail is the state's, but at position 0
+            self._note_tails("decode", start=int((at == 1).sum()),
+                             state=int((at > 1).sum()))
         for kind, a in kinds.items():
             seen = at if a["window"] is None else np.minimum(at, a["window"])
             self.registry.counter(
@@ -550,6 +569,27 @@ class ServingTelemetry:
                 "layer-positions the decode steps' rows had to attend"
                 ).inc(int(seen.sum()) * a["layers"])
         return {}
+
+    def _note_tails(self, phase: str, **by_source):
+        for source, n in by_source.items():
+            self.registry.counter(
+                f'ffsv_cca_tails_total{{phase="{phase}",source="{source}"}}',
+                "rows of the steps by where their tail came from (a model "
+                "whose attention layers carry one)").inc(n)
+
+    def note_prefill_tails(self, runs):
+        """A prefill step's ``runs`` [(slot, start, tokens)] over a model
+        whose attention layers carry a tail (ops/cca_attention.take_tails
+        decides the same on the device): a run at position 0 starts from
+        zeros, one that another run of the step's, the same slot's, ends in
+        front of takes that run's end, any other what an earlier step left
+        in the slot."""
+        ends = {(slot, sp + n) for slot, sp, n in runs if n}
+        src = ["start" if sp == 0 else
+               "step" if (slot, sp) in ends else "state"
+               for slot, sp, n in runs if n]
+        self._note_tails("prefill", **{s: src.count(s) for s in
+                                       ("start", "step", "state")})
 
     def _chunked_counter(self, name, n, layers=1):
         helps = {
