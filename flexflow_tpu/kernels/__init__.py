@@ -45,19 +45,31 @@ scatter_append_counts: dict = {}
 # append fused), "rows" (the compact prefill batch) or "grid" (a slot-grid
 # step). ``latent_summary()`` prints it; read by no metric.
 latent_form_counts: dict = {}
+# Which form of the k/v kernel's stream a trace took, by (form, mode, DMA
+# block in positions): "block" (a DMA block of several softmax partitions,
+# its scores in one pass and its partitions unrolled: the plain stream where
+# a partition's descriptor is small) or "loop" (a partition a block), of the
+# shapes alone (``kernels/attention.stream_block``); mode "append" (a decode
+# step, its one position a row appended by the kernel), "run" (a run of
+# positions appended), "rows" (the compact prefill batch) or "grid" (a
+# slot-grid step). ``stream_summary()`` prints it; read by no metric.
+stream_form_counts: dict = {}
 _warned: set = set()
 
 
-def record_fast_path(append=None, latent=None):
+def record_fast_path(append=None, latent=None, stream=None):
     """Count a trace of the attention kernel; ``append``: the width of the
     run of positions it appends to the cache itself; ``latent``: the (form,
-    mode) of a latent kernel's trace."""
+    mode) of a latent kernel's trace; ``stream``: the (form, mode, DMA
+    block) of a k/v kernel's."""
     global fast_path_count
     fast_path_count += 1
     if append is not None:
         fused_append_counts[append] = fused_append_counts.get(append, 0) + 1
     if latent is not None:
         latent_form_counts[latent] = latent_form_counts.get(latent, 0) + 1
+    if stream is not None:
+        stream_form_counts[stream] = stream_form_counts.get(stream, 0) + 1
 
 
 def record_scatter_append(width: int):
@@ -86,6 +98,16 @@ def latent_summary() -> str:
         or "0 traces")
 
 
+def stream_summary() -> str:
+    """The k/v kernel's traces by form, mode and DMA block in one line: "k/v
+    kernel: block form of 1024, append: 10 traces; loop form of 128, rows:
+    10 traces"."""
+    return "k/v kernel: " + ("; ".join(
+        f"{form} form of {block}, {mode}: {n} traces"
+        for (form, mode, block), n in sorted(stream_form_counts.items()))
+        or "0 traces")
+
+
 def record_fallback(reason: str):
     """Count (and warn once per reason) a serving-attention jnp fallback."""
     fallback_counts[reason] = fallback_counts.get(reason, 0) + 1
@@ -105,6 +127,7 @@ def reset_dispatch_stats():
     fused_append_counts.clear()
     scatter_append_counts.clear()
     latent_form_counts.clear()
+    stream_form_counts.clear()
     _warned.clear()
     fast_path_count = 0
     from flexflow_tpu.kernels import moe

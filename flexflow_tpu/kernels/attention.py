@@ -108,35 +108,89 @@ def supports_shapes(S: int, D: int) -> bool:
     return _pack_factor(D) > 0 and supports_seq_len(S, D)
 
 
-def _kernel(len_ref,                       # scalar prefetch: [R] int32
-            q_ref, qp_ref, slopes_ref, bias_hbm, k_hbm, v_hbm,
-            o_ref,
-            acc, m, l, kbuf, vbuf, bbuf, sem,
-            *, BS: int, causal: bool, has_bias: bool, has_alibi: bool,
-            qk_scale: float, G: int, Q: int, layer_idx, PACK: int, D: int):
+# The K bytes (and as many V) a DMA block of the plain stream should reach
+# before it stops growing (``stream_block``). The loop form waits for a
+# block, works on it and only then asks for the one after the next, so what
+# a block costs beyond its bytes (the wait's latency, the softmax state's
+# round trip through scratch, a chain of small matmuls that each wait for
+# the one before to drain) is paid once a block whatever its size: at 2
+# key/value heads of 128 in bfloat16 a partition of 128 positions is 64 KB
+# and the loop reads 250 GB/s of the HBM's 819, at 16 heads (512 KB) 580. On
+# a v5e at 2 heads: 352 us a call of 86 MB at 128 positions a block, 236 at
+# 256, 161 at 512, 137 at 1024, 154 at 2048 (PERF.md section 6, PR 51).
+STREAM_BLOCK_TARGET = 512 * 1024
+# The most a DMA block's float32 scores ``KH x GQ x DB x 4`` may take. A
+# row's last block pays for all its partitions, the masked ones too, so
+# where the query rows make the arithmetic the kernel's bound a larger block
+# costs more than its stream saves: at 4 heads x 64 query rows over rows of
+# 350 positions, 134 us a call in the loop, 101 at 256 positions a block (256
+# KB of scores), 125 at 512, 143 at 1024 (the same section). A prefill
+# segment's hundreds of rows a head are over it at any block.
+STREAM_BLOCK_SCORES_LIMIT = 256 * 1024
+# For tools/time_stream_attend.py alone, read when a kernel is traced and
+# never set by the serving path: None, or the half of ``_stream_attend``
+# that a timing keeps: "stream" (every copy, the append's merge and
+# write-back, no scores and no softmax) or "arith" (the arithmetic on
+# whatever the buffers hold: no copy of the stream started or waited for).
+ABLATE = None
+
+
+def stream_block(KH: int, D: int, itemsize: int, GQ: int, S: int) -> int:
+    """The DMA block, in positions, of the PLAIN position-major stream (D
+    fills the lanes; no ring, no chunked stream, no bias) of a call over
+    ``GQ`` query rows a key/value head (group x tokens), of the call's
+    shapes alone. The softmax partition stays ``BS = _pick_block_s(S, D)``
+    whatever the query width (its docstring says why); the DMA block is
+    ``BS`` doubled, while it divides ``S``, until its K descriptor ``KH x DB
+    x D x itemsize`` reaches STREAM_BLOCK_TARGET or its float32 scores would
+    pass STREAM_BLOCK_SCORES_LIMIT. ``DB > BS`` is ``_stream_attend``'s block
+    form (a decode step's few rows over few key/value heads); ``DB == BS``
+    the loop it always was, argument for argument (16 heads and more; a
+    prefill segment's hundreds of rows)."""
+    BS = DB = _pick_block_s(S, D)
+    if BS == 0 or _pack_factor(D) != 1:
+        return BS
+    while (KH * DB * D * itemsize < STREAM_BLOCK_TARGET
+           and S % (2 * DB) == 0
+           and KH * GQ * 2 * DB * 4 <= STREAM_BLOCK_SCORES_LIMIT):
+        DB *= 2
+    return DB
+
+
+def _pipe_of(refs, static):
+    """The block form's one more scalar-prefetched operand behind the
+    lengths, the pipeline's walk (``_latent_pipe``), split off a kernel's
+    operands: (pipe_ref or None, the rest)."""
+    if static["DB"] > static["BS"]:
+        return refs[0], refs[1:]
+    return None, refs
+
+
+def _kernel(len_ref, *refs, **static):     # len_ref: scalar prefetch [R] int32
+    pipe_ref, (q_ref, qp_ref, slopes_ref, bias_hbm, k_hbm, v_hbm, o_ref, acc,
+               m, l, kbuf, vbuf, bbuf, sem) = _pipe_of(refs, static)
     _stream_attend(len_ref, None, q_ref, qp_ref, slopes_ref, None, None,
                    bias_hbm, k_hbm, v_hbm, o_ref, acc, m, l, kbuf, vbuf,
-                   bbuf, sem, None, BS=BS, causal=causal, has_bias=has_bias,
-                   has_alibi=has_alibi, qk_scale=qk_scale, G=G, Q=Q,
-                   layer_idx=layer_idx, PACK=PACK, D=D)
+                   bbuf, sem, None, pipe_ref=pipe_ref, **static)
 
 
-def _rows_kernel(len_ref, rows_ref,        # scalar prefetch: [R] int32 each
-                 q_ref, qp_ref, slopes_ref, bias_hbm, k_hbm, v_hbm,
-                 o_ref,
-                 acc, m, l, kbuf, vbuf, bbuf, sem, **static):
+def _rows_kernel(len_ref, *refs, **static):   # scalar prefetch: [R] int32 each
     """Row-mapped variant (the compact prefill batch): grid program ``r``
     streams cache row ``rows[r]`` instead of row ``r``. The caches stay in
     HBM and are only ever indexed by DMA, so reading another row is a
     different DMA source and nothing is gathered; two programs may read
     the same row (two segments of one slot)."""
+    pipe_ref, (rows_ref, q_ref, qp_ref, slopes_ref, bias_hbm, k_hbm, v_hbm,
+               o_ref, acc, m, l, kbuf, vbuf, bbuf, sem) = _pipe_of(refs,
+                                                                    static)
     _stream_attend(len_ref, None, q_ref, qp_ref, slopes_ref, None, None,
                    bias_hbm, k_hbm, v_hbm, o_ref, acc, m, l, kbuf, vbuf,
-                   bbuf, sem, None, rows_ref=rows_ref, **static)
+                   bbuf, sem, None, rows_ref=rows_ref, pipe_ref=pipe_ref,
+                   **static)
 
 
-def _append_kernel(len_ref, appos_ref,     # scalar prefetch: [R] int32 each
-                   *refs, run: bool = False, **static):
+def _append_kernel(len_ref, *refs,         # scalar prefetch: [R] int32 each
+                   run: bool = False, **static):
     """Decode-step variant: this step's new K/V rows land at cache position
     ``appos[r]`` on IN PLACE (the caches are aliased in/out), fused with the
     attention stream. The new rows are merged into the streamed VMEM block
@@ -161,13 +215,15 @@ def _append_kernel(len_ref, appos_ref,     # scalar prefetch: [R] int32 each
     what it held, bit for bit, the rows of the operand past ``napp``
     included (the scatter's ``mode="drop"``)."""
     napp_ref = None
+    pipe_ref, (appos_ref, *refs) = _pipe_of(refs, static)
     if run:
         napp_ref, *refs = refs
     (q_ref, qp_ref, slopes_ref, knew_ref, vnew_ref, bias_hbm, _, _, o_ref,
      ok_hbm, ov_hbm, acc, m, l, kbuf, vbuf, bbuf, sem, asem) = refs
     _stream_attend(len_ref, appos_ref, q_ref, qp_ref, slopes_ref, knew_ref,
                    vnew_ref, bias_hbm, ok_hbm, ov_hbm, o_ref, acc, m, l,
-                   kbuf, vbuf, bbuf, sem, asem, napp_ref=napp_ref, **static)
+                   kbuf, vbuf, bbuf, sem, asem, napp_ref=napp_ref,
+                   pipe_ref=pipe_ref, **static)
 
 
 def _window_kernel(len_ref, first_ref, *refs, mode, operand="first_ref",
@@ -195,6 +251,21 @@ def _window_kernel(len_ref, first_ref, *refs, mode, operand="first_ref",
                    **{operand: first_ref}, **static)
 
 
+class _Held:
+    """A value behind a ref's ``[:]``: the softmax state as the block form
+    carries it through a DMA block's partitions, read and stored by the
+    lines that read and store the loop form's scratch."""
+
+    def __init__(self, ref):
+        self.v = ref[:]
+
+    def __getitem__(self, _):
+        return self.v
+
+    def __setitem__(self, _, v):
+        self.v = v
+
+
 def _stream_attend(len_ref, appos_ref, q_ref, qp_ref, slopes_ref, knew_ref,
                    vnew_ref, bias_hbm, k_hbm, v_hbm, o_ref,
                    acc, m, l, kbuf, vbuf, bbuf, sem, asem,
@@ -202,7 +273,7 @@ def _stream_attend(len_ref, appos_ref, q_ref, qp_ref, slopes_ref, knew_ref,
                    has_alibi: bool, qk_scale: float, G: int, Q: int,
                    layer_idx, PACK: int, D: int, rows_ref=None,
                    first_ref=None, window=None, nsum_ref=None,
-                   summary_rows=None, napp_ref=None):
+                   summary_rows=None, napp_ref=None, DB: int, pipe_ref=None):
     """Shared stream-attend body.
 
     PACK == 1: one position per 128-lane cache row (D % 128 == 0).
@@ -228,16 +299,38 @@ def _stream_attend(len_ref, appos_ref, q_ref, qp_ref, slopes_ref, knew_ref,
 
     ``napp_ref`` (with ``appos_ref``; a plain cache: none of the above): the
     append is a run of ``napp[r]`` positions, ``_append_kernel``'s ``run``.
+
+    ``DB`` (with ``pipe_ref``; the plain stream: PACK == 1 and none of
+    ``window``, ``summary_rows``, ``has_bias``): the DMA block, ``BS`` or a
+    multiple of it (``stream_block``, of the call's shapes alone). ``DB ==
+    BS`` is the LOOP form: a block is a softmax partition, fetched, scored
+    and folded into ``m``, ``l``, ``acc`` in scratch, one an iteration.
+    ``DB > BS`` is the BLOCK form, the same arithmetic delivered in larger
+    pieces (the same dot products through the same sequence of partitions:
+    the same output bit for bit): one K and one V descriptor a ``DB``
+    positions (a row's last only up to its last partition that holds a
+    valid position), the block's scores ``q . k^T`` in ONE pass ``[KH, GQ,
+    DB]`` and masked in one, then its ``DB // BS`` partitions in a loop
+    unrolled at trace time, the softmax state carried through them as values and stored once
+    a block; a partition with no valid position is masked, not skipped
+    (``NEG_INF`` is finite: ``p`` 0, ``corr`` 1, nothing moves). The fused
+    append, of one position or of a run, merges on its aligned windows of
+    APPEND_WINDOW_ROWS stored rows, and the pipeline's walk over the rows'
+    lengths is done once a call outside (``pipe_ref``, as
+    ``_latent_kernel``'s).
     """
     has_append = appos_ref is not None
     has_run = napp_ref is not None
+    block = DB > BS
+    NP = DB // BS                         # softmax partitions a DMA block
     r = pl.program_id(0)
     R = len_ref.shape[0]
     length = len_ref[r]
-    SB = BS // PACK                       # packed rows per block
+    SB = BS // PACK                       # packed rows per partition
+    RB = DB // PACK                       # and per DMA block
 
     def nb_of(j):                         # blocks program j streams
-        nb = (len_ref[j] + jnp.asarray(BS - 1, jnp.int32)) // BS
+        nb = (len_ref[j] + jnp.asarray(DB - 1, jnp.int32)) // DB
         if first_ref is not None:
             nb = jnp.maximum(nb - first_ref[j], 0)
         if nsum_ref is not None:          # less the blocks between the two
@@ -291,17 +384,43 @@ def _stream_attend(len_ref, appos_ref, q_ref, qp_ref, slopes_ref, knew_ref,
         r_next = jnp.where((j > r) & (nbj > 0) & (r_next == R), j, r_next)
         return g0, prev_live, r_next
 
-    g0, prev_live, r_next = jax.lax.fori_loop(
-        0, R, _pipe_scan,
-        (jnp.int32(0), jnp.asarray(False), jnp.int32(R)))
+    if block:                             # walked once a call, outside
+        g0, r_next = pipe_ref[0, r], pipe_ref[1, r]
+        prev_live = g0 > 0
+    else:
+        g0, prev_live, r_next = jax.lax.fori_loop(
+            0, R, _pipe_scan,
+            (jnp.int32(0), jnp.asarray(False), jnp.int32(R)))
 
-    def dmas(row, slot, i):
+    def live(j, i, ahead=0):
+        """The block form's one more argument of a block's copies: the
+        partitions of block ``i + ahead`` of program ``j``'s row that hold
+        a valid position (a row's last block is not fetched past them)."""
+        if not block:
+            return ()
+        return (jnp.minimum(
+            (len_ref[j] - (i + ahead) * DB + (BS - 1)) // BS, NP),)
+
+    def dmas(row, slot, i, part=None):
+        # ``part`` (first, count): those partitions of the block alone
+        if part is None:
+            def rows():
+                return pl.ds(i * RB, RB)
+
+            def to(buf):
+                return buf.at[slot]
+        else:
+            at = pl.multiple_of(part[0] * SB, SB)
+
+            def rows():
+                return pl.ds(i * RB + at, part[1] * SB)
+
+            def to(buf):
+                return buf.at[slot, :, pl.ds(at, part[1] * SB)]
         yield pltpu.make_async_copy(
-            k_hbm.at[row, :, pl.ds(i * SB, SB)], kbuf.at[slot],
-            sem.at[slot, 0])
+            k_hbm.at[row, :, rows()], to(kbuf), sem.at[slot, 0])
         yield pltpu.make_async_copy(
-            v_hbm.at[row, :, pl.ds(i * SB, SB)], vbuf.at[slot],
-            sem.at[slot, 1])
+            v_hbm.at[row, :, rows()], to(vbuf), sem.at[slot, 1])
         if has_bias:
             if PACK == 1:
                 b_src = bias_hbm.at[row, :, pl.ds(i * BS, BS)]
@@ -310,23 +429,49 @@ def _stream_attend(len_ref, appos_ref, q_ref, qp_ref, slopes_ref, knew_ref,
             yield pltpu.make_async_copy(b_src, bbuf.at[slot],
                                         sem.at[slot, 2])
 
-    def start_dmas(row, slot, i):
-        for d in dmas(row, slot, i):
-            d.start()
+    def each_dma(do, row, slot, i, n=None):
+        """``do`` every copy of a block; with ``n`` (``live``) those of its
+        first ``n`` partitions alone: a whole block in one descriptor a
+        cache, a row's last block a partition a descriptor (a descriptor's
+        size is static, and several of a partition in flight stream as fast
+        as one of a block: PERF.md section 6, PR 51)."""
+        def all_of(part=None):
+            for d in dmas(row, slot, i, part):
+                do(d)
 
-    def wait_dmas(row, slot, i):
-        for d in dmas(row, slot, i):
-            d.wait()
+        def one(p, _):
+            all_of((p, 1))
+
+        if ABLATE == "arith":
+            return
+        if n is None:
+            return all_of()
+        jax.lax.cond(n == NP, all_of,
+                     lambda: jax.lax.fori_loop(0, n, one, None))
+
+    def start_dmas(row, slot, i, *n):
+        each_dma(lambda d: d.start(), row, slot, i, *n)
+
+    def wait_dmas(row, slot, i, *n):
+        each_dma(lambda d: d.wait(), row, slot, i, *n)
+
+    if block:
+        @pl.when(r == 0)
+        def _():
+            # a dead partition of a row's last block is multiplied by p =
+            # 0: what no copy of this call has written must be finite
+            kbuf[:] = jnp.zeros_like(kbuf)
+            vbuf[:] = jnp.zeros_like(vbuf)
 
     @pl.when((nb > 0) & jnp.logical_not(prev_live))
     def _():                              # first live program self-starts
-        start_dmas(row_of(r), g0 % 2, src(r, 0))
+        start_dmas(row_of(r), g0 % 2, src(r, 0), *live(r, 0))
 
     GQ = q_ref.shape[-2]
     qp = qp_ref[r]                                  # [GQ] absolute positions
     if has_append:
         p_app = appos_ref[r]
-    if has_append and not has_run:
+    if has_append and not has_run and not block:
         bp = p_app // BS                  # block holding the new position
         p_row = pr = p_app // PACK        # its global packed row
         if first_ref is not None:         # as the stream counts and stores
@@ -340,9 +485,14 @@ def _stream_attend(len_ref, appos_ref, q_ref, qp_ref, slopes_ref, knew_ref,
             return pr - bp * SB
         return pr % SB
 
-    if has_run:
+    # the append merged a window at a time: a run's, and in the block form
+    # a single position's too (a run of one, in one window; the loop form
+    # selects it over the whole block)
+    by_window = has_run or (block and has_append)
+    if by_window:
         W = APPEND_WINDOW_ROWS
-        n_app = napp_ref[r]               # 0: the row sits out
+        # 0: the row sits out
+        n_app = napp_ref[r] if has_run else (p_app >= 0).astype(jnp.int32)
         p_end = p_app + n_app
         w0 = (p_app // W) * W             # the run's first window
 
@@ -350,16 +500,75 @@ def _stream_attend(len_ref, appos_ref, q_ref, qp_ref, slopes_ref, knew_ref,
         """The windows of this row's run that lie in stream block ``i``
         (a window never straddles a block: whole blocks of whole windows):
         (is it here, its first row in the cache and in the block, its
-        write-backs)."""
-        for t in range(2):
+        write-backs). The block form takes a window only in a partition the
+        loop form would stream (below ``length``): any other is not
+        fetched."""
+        for t in range(2 if has_run else 1):
             wa = pl.multiple_of(w0 + t * W, W)
-            off = pl.multiple_of(wa - i * BS, W)
-            yield ((n_app > 0) & (wa < p_end) & (wa // BS == i), wa, off, [
+            off = pl.multiple_of(wa - i * DB, W)
+            here = (n_app > 0) & (wa < p_end) & (wa // DB == i)
+            if block:
+                here = here & (wa // BS < (length + (BS - 1)) // BS)
+            yield (here, wa, off, [
                 pltpu.make_async_copy(
                     buf.at[slot, :, pl.ds(off, W)],
                     hbm.at[r, :, pl.ds(wa, W)], asem.at[t, c])
                 for c, (buf, hbm) in enumerate(((kbuf, k_hbm),
                                                 (vbuf, v_hbm)))])
+
+    def masked(s, h, i, slot):
+        """Block ``i``'s raw scores ``s [KH, GQ, RB]`` scaled, biased and
+        with ``NEG_INF`` wherever a query does not see the key."""
+        s = s * qk_scale
+        if block:
+            b_abs = i * NP
+        else:
+            b_abs = i if first_ref is None else first_ref[r] + i
+        if nsum_ref is not None:
+            b_abs = src(r, i)
+        s_ids = (b_abs * BS + h
+                 + PACK * jax.lax.broadcasted_iota(jnp.int32, (GQ, RB),
+                                                   1))
+        if has_alibi:
+            dist = (qp[:, None] - s_ids).astype(jnp.float32)
+            s = s - slopes_ref[:, :][:, :, None] * dist[None]
+        if has_bias:
+            b = bbuf[slot] if PACK == 1 else bbuf[slot, h]  # [Q, SB]
+            s = s + jnp.tile(b, (G, 1))[None]   # row g*Q+q <- b[q]
+        if causal:
+            visible = s_ids <= qp[:, None]
+        else:
+            visible = jnp.ones((GQ, RB), dtype=bool)
+        visible = visible & (s_ids < length)
+        if window is not None:
+            visible = visible & (s_ids > qp[:, None] - window)
+        if nsum_ref is not None:
+            visible = visible & ((s_ids < nsum_ref[r])
+                                 | (s_ids >= summary_rows))
+        return jnp.where(visible[None], s, NEG_INF)
+
+    def fold(s, v, h, m, l, acc):
+        """Fold one partition into the softmax state: its masked scores
+        ``s [KH, GQ, SB]`` and its values ``v [KH, SB, D]``."""
+        m_new = jnp.maximum(m[:], jnp.max(s, axis=-1, keepdims=True))
+        corr = jnp.exp(m[:] - m_new)
+        p = jnp.exp(s - m_new)                  # [KH, GQ, SB] f32
+        l[:] = l[:] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        if PACK == 1:
+            v_h = v
+        else:
+            # other half's lanes zeroed so the contraction only picks
+            # up this half's values (their halves' accumulator lanes
+            # are summed outside the kernel)
+            lane = jax.lax.broadcasted_iota(
+                jnp.int32, v.shape, v.ndim - 1)
+            v_h = jnp.where(lane // D == h, v, jnp.zeros_like(v))
+        pv = jax.lax.dot_general(
+            p.astype(v.dtype), v_h,
+            dimension_numbers=(((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)  # [KH, GQ, D|LANE]
+        acc[:] = acc[:] * corr + pv
+        m[:] = m_new
 
     def body(i, _):
         slot = (g0 + i) % 2
@@ -367,14 +576,15 @@ def _stream_attend(len_ref, appos_ref, q_ref, qp_ref, slopes_ref, knew_ref,
 
         @pl.when(i + 1 < nb)
         def _():
-            start_dmas(row_of(r), nxt_slot, src(r, i + 1))
+            start_dmas(row_of(r), nxt_slot, src(r, i + 1), *live(r, i, 1))
 
         @pl.when((i + 1 == nb) & (r_next < R))
         def _():                          # hand off to the next live row
-            start_dmas(row_of(r_next), nxt_slot, src(r_next, 0))
+            start_dmas(row_of(r_next), nxt_slot, src(r_next, 0),
+                       *live(r_next, 0))
 
-        wait_dmas(row_of(r), slot, src(r, i))
-        if has_run:
+        wait_dmas(row_of(r), slot, src(r, i), *live(r, i))
+        if by_window:
             # merge the part of the run that this block holds into the
             # streamed block, a window at a time, and write the window back
             for here, wa, off, copies in run_windows(i, slot):
@@ -389,8 +599,11 @@ def _stream_attend(len_ref, appos_ref, q_ref, qp_ref, slopes_ref, knew_ref,
                             for new_ref, c in zip((knew_ref, vnew_ref), cur))
 
                     at = (slot, slice(None), pl.ds(off, W), slice(None))
-                    kbuf[at], vbuf[at] = jax.lax.fori_loop(
-                        0, n_app, put, (kbuf[at], vbuf[at]))
+                    if has_run:
+                        kbuf[at], vbuf[at] = jax.lax.fori_loop(
+                            0, n_app, put, (kbuf[at], vbuf[at]))
+                    else:
+                        kbuf[at], vbuf[at] = put(0, (kbuf[at], vbuf[at]))
                     for d in copies:
                         d.start()
         elif has_append:
@@ -421,9 +634,11 @@ def _stream_attend(len_ref, appos_ref, q_ref, qp_ref, slopes_ref, knew_ref,
                     v_hbm.at[r, :, pl.ds(pb_abs, SUBLANE)], asem.at[1])
                 wk.start()
                 wv.start()
-        k = kbuf[slot]                    # [KH, SB, D or LANE]
+        k = kbuf[slot]                    # [KH, RB, D or LANE]
         v = vbuf[slot]
-        for h in range(PACK):             # even/odd position halves
+        # the block form carries the state through its partitions as values
+        state = [_Held(x) for x in (m, l, acc)] if block else (m, l, acc)
+        for h in range(PACK if ABLATE != "stream" else 0):   # position halves
             qt_h = q_ref[0] if PACK == 1 else q_ref[0, h]
             # scores[kh, gq, s] = q[kh, gq, :] . k[kh, s, :] — for packed
             # halves q is zero outside lanes [h*D, (h+1)*D), so the full
@@ -431,52 +646,17 @@ def _stream_attend(len_ref, appos_ref, q_ref, qp_ref, slopes_ref, knew_ref,
             s = jax.lax.dot_general(
                 qt_h.astype(k.dtype), k,
                 dimension_numbers=(((2,), (2,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32)     # [KH, GQ, SB]
-            s = s * qk_scale
-            b_abs = i if first_ref is None else first_ref[r] + i
-            if nsum_ref is not None:
-                b_abs = src(r, i)
-            s_ids = (b_abs * BS + h
-                     + PACK * jax.lax.broadcasted_iota(jnp.int32, (GQ, SB),
-                                                       1))
-            if has_alibi:
-                dist = (qp[:, None] - s_ids).astype(jnp.float32)
-                s = s - slopes_ref[:, :][:, :, None] * dist[None]
-            if has_bias:
-                b = bbuf[slot] if PACK == 1 else bbuf[slot, h]  # [Q, SB]
-                s = s + jnp.tile(b, (G, 1))[None]   # row g*Q+q <- b[q]
-            if causal:
-                visible = s_ids <= qp[:, None]
+                preferred_element_type=jnp.float32)     # [KH, GQ, RB]
+            s = masked(s, h, i, slot)
+            if block:
+                for j in range(NP):
+                    at = slice(j * BS, (j + 1) * BS)
+                    fold(s[:, :, at], v[:, at], h, *state)
             else:
-                visible = jnp.ones((GQ, SB), dtype=bool)
-            visible = visible & (s_ids < length)
-            if window is not None:
-                visible = visible & (s_ids > qp[:, None] - window)
-            if nsum_ref is not None:
-                visible = visible & ((s_ids < nsum_ref[r])
-                                     | (s_ids >= summary_rows))
-            s = jnp.where(visible[None], s, NEG_INF)
-
-            m_new = jnp.maximum(m[:], jnp.max(s, axis=-1, keepdims=True))
-            corr = jnp.exp(m[:] - m_new)
-            p = jnp.exp(s - m_new)                  # [KH, GQ, SB] f32
-            l[:] = l[:] * corr + jnp.sum(p, axis=-1, keepdims=True)
-            if PACK == 1:
-                v_h = v
-            else:
-                # other half's lanes zeroed so the contraction only picks
-                # up this half's values (their halves' accumulator lanes
-                # are summed outside the kernel)
-                lane = jax.lax.broadcasted_iota(
-                    jnp.int32, v.shape, v.ndim - 1)
-                v_h = jnp.where(lane // D == h, v, jnp.zeros_like(v))
-            pv = jax.lax.dot_general(
-                p.astype(v.dtype), v_h,
-                dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32)  # [KH, GQ, D|LANE]
-            acc[:] = acc[:] * corr + pv
-            m[:] = m_new
-        if has_run:
+                fold(s, v, h, *state)
+        if block:
+            m[:], l[:], acc[:] = (x.v for x in state)
+        if by_window:
             for here, _, _, copies in run_windows(i, slot):
                 @pl.when(here)
                 def _():                  # as the one-row form below
@@ -585,6 +765,14 @@ def flash_attend(q, k_cache, v_cache, lengths, qpos, bias=None,
     assert BS > 0, f"S={S}/D={D} not tileable by a supported block size"
     SB = BS // PACK
     DL = D if PACK == 1 else LANE         # kernel-side lane width
+    # the DMA block: the partition, or on the plain stream a multiple of it
+    # where a partition's descriptor is small (``_stream_attend``'s block form)
+    DB = BS
+    if (PACK == 1 and window is None and summary_rows is None
+            and bias is None):
+        DB = stream_block(KH, D, k_cache.dtype.itemsize, GQ, S)
+    block = DB > BS
+    RB = DB // PACK                       # stored rows a DMA block
     if qk_scale is None:
         qk_scale = 1.0 / math.sqrt(D)
     out_dtype = out_dtype or q.dtype
@@ -635,12 +823,13 @@ def flash_attend(q, k_cache, v_cache, lengths, qpos, bias=None,
         call_name = {"name": "flash_attend_window"}
 
     cache_dt = k_cache.dtype
-    kv_bytes = 2 * 2 * SB * KH * DL * cache_dt.itemsize
+    kv_bytes = 2 * 2 * RB * KH * DL * cache_dt.itemsize
     compiler_params = pltpu.CompilerParams(
         vmem_limit_bytes=int(min(
             128 * 1024 * 1024,
             8 * (KH * GQ * (DL + 2) * 4 + PACK * KH * GQ * DL * 2
-                 + kv_bytes + 2 * PACK * Q * SB * 4) + 1024 * 1024)),
+                 + kv_bytes + 2 * PACK * Q * SB * 4
+                 + block * KH * GQ * DB * 4) + 1024 * 1024)),
     )
     cost_estimate = pl.CostEstimate(
         flops=4 * R * GQ * KH * D * S,
@@ -668,8 +857,8 @@ def flash_attend(q, k_cache, v_cache, lengths, qpos, bias=None,
         pltpu.VMEM((KH, GQ, DL), jnp.float32),                   # acc
         pltpu.VMEM((KH, GQ, 1), jnp.float32),                    # m
         pltpu.VMEM((KH, GQ, 1), jnp.float32),                    # l
-        pltpu.VMEM((2, KH, SB, DL), cache_dt),                   # k buf
-        pltpu.VMEM((2, KH, SB, DL), cache_dt),                   # v buf
+        pltpu.VMEM((2, KH, RB, DL), cache_dt),                   # k buf
+        pltpu.VMEM((2, KH, RB, DL), cache_dt),                   # v buf
         pltpu.VMEM(bias_buf_shape, jnp.float32),                 # bias buf
         pltpu.SemaphoreType.DMA((2, 3)),
     ]
@@ -684,13 +873,16 @@ def flash_attend(q, k_cache, v_cache, lengths, qpos, bias=None,
         return out.reshape(R, KH, G, Q, D).transpose(0, 3, 1, 2, 4).reshape(
             R, Q, H * D)
 
+    # scalars every program reads behind the lengths: in the block form its
+    # place in the DMA pipeline, walked once a call here
+    pipe = [_latent_pipe(lengths, DB)] if block else []
+    static = dict(BS=BS, causal=causal, has_bias=has_bias,
+                  has_alibi=has_alibi, qk_scale=float(qk_scale), G=G, Q=Q,
+                  layer_idx=layer_idx, PACK=PACK, D=D, DB=DB)
     if append_kv is None:
-        prefetch = [lengths.astype(jnp.int32)] + first
+        prefetch = [lengths.astype(jnp.int32)] + pipe + first
         if rows is not None:
             prefetch.append(rows.astype(jnp.int32))
-        static = dict(BS=BS, causal=causal, has_bias=has_bias,
-                      has_alibi=has_alibi, qk_scale=float(qk_scale), G=G,
-                      Q=Q, layer_idx=layer_idx, PACK=PACK, D=D)
         if summary_rows is not None:
             kern = functools.partial(
                 _window_kernel, mode=None if rows is None else "rows",
@@ -738,9 +930,6 @@ def flash_attend(q, k_cache, v_cache, lengths, qpos, bias=None,
         # tiling the D lanes PACK times gives it the value in every half
         k_new = jnp.concatenate([k_new] * PACK, axis=-1)
         v_new = jnp.concatenate([v_new] * PACK, axis=-1)
-    static = dict(BS=BS, causal=causal, has_bias=has_bias,
-                  has_alibi=has_alibi, qk_scale=float(qk_scale), G=G, Q=Q,
-                  layer_idx=layer_idx, PACK=PACK, D=D)
     if summary_rows is not None:
         kern = functools.partial(_window_kernel, mode="append",
                                  operand="nsum_ref",
@@ -753,15 +942,16 @@ def flash_attend(q, k_cache, v_cache, lengths, qpos, bias=None,
                                  window=window, **static)
     knew_spec = pl.BlockSpec((1, A, KH, DL), lambda r, *_: (r, 0, 0, 0),
                              memory_space=pltpu.VMEM)
-    n_prefetch = 2 + len(first) + len(run)
+    n_prefetch = 2 + len(pipe) + len(first) + len(run)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=n_prefetch, grid=(R,),
         in_specs=qkv_in_specs + [knew_spec, knew_spec] + tail_in_specs,
         out_specs=(o_spec, pl.BlockSpec(memory_space=pl.ANY),
                    pl.BlockSpec(memory_space=pl.ANY)),
-        # a write-back's semaphores: (k, v), a window of a run each
+        # a write-back's semaphores: (k, v), a window of a run each (the
+        # block form's single position is a run of one)
         scratch_shapes=scratch + [
-            pltpu.SemaphoreType.DMA((2, 2) if run else (2,))])
+            pltpu.SemaphoreType.DMA((2, 2) if run or block else (2,))])
     out, k_out, v_out = pl.pallas_call(
         kern, grid_spec=grid_spec,
         out_shape=(jax.ShapeDtypeStruct(
@@ -772,7 +962,8 @@ def flash_attend(q, k_cache, v_cache, lengths, qpos, bias=None,
         input_output_aliases={n_prefetch + 6: 1, n_prefetch + 7: 2},
         compiler_params=compiler_params, cost_estimate=cost_estimate,
         interpret=interpret, **call_name,
-    )(lengths.astype(jnp.int32), *first, appos.astype(jnp.int32), *run, qt,
+    )(lengths.astype(jnp.int32), *pipe, *first, appos.astype(jnp.int32), *run,
+      qt,
       qp_gq, slopes_gq, k_new.astype(cache_dt), v_new.astype(cache_dt),
       bias.astype(jnp.float32), k_cache, v_cache)
     return post(out), k_out, v_out

@@ -299,9 +299,12 @@ def _attend(attrs, q, k_cache, v_cache, lengths, qpos, out_dtype, ctx,
         if refusal:                 # ("": switched off, nothing to report)
             ffk.record_fallback(refusal)
     else:
-        ffk.record_fast_path(
-            append=None if append_kv is None else append_kv[0].shape[1])
         R, H = q.shape[0], q.shape[2]
+        A = None if append_kv is None else append_kv[0].shape[1]
+        ffk.record_fast_path(append=A, stream=_stream_form(
+            q, k_cache, S, Dp, mesh, "rows" if rows is not None else
+            "grid" if A is None else "append" if A == 1 else "run",
+            plain=window is None and chunked is None and bias is None))
         fkv = None
         if append_kv is not None:
             k_new, v_new, *at = append_kv             # [R, A, KH, D] each
@@ -366,6 +369,24 @@ def _attend(attrs, q, k_cache, v_cache, lengths, qpos, out_dtype, ctx,
                  if chunked is not None else
                  None if window is None else kvl.ring_positions(lengths, S)))
     return out if append_kv is None else (out,) + new_caches
+
+
+def _stream_form(q, k_cache, S: int, Dp: int, mesh, mode: str, plain: bool):
+    """The (form, mode, DMA block) ``flash_attend`` takes over this cache,
+    for ``kernels.stream_form_counts``: its own rule
+    (``kernels/attention.stream_block``) on the shapes a chip sees (under
+    tensor parallelism its own heads, as ``_attend_on_mesh`` splits them);
+    a ring, a chunked stream and a biased step keep the loop."""
+    from flexflow_tpu.kernels.attention import _pick_block_s, stream_block
+
+    KH, Q, H = k_cache.shape[-3], q.shape[1], q.shape[2]
+    BS = _pick_block_s(S, Dp)
+    tp = (mesh.shape["model"] if mesh is not None and mesh.devices.size > 1
+          and "model" in mesh.axis_names else 1)
+    DB = BS if not plain else stream_block(
+        KH // tp if KH % tp == 0 else KH, Dp, k_cache.dtype.itemsize,
+        H // KH * Q, S)
+    return ("block" if DB > BS else "loop", mode, DB)
 
 
 def _seq_degree(mesh) -> int:

@@ -976,15 +976,55 @@ class ServingTelemetry:
 # ---------------------------------------------------------------------------
 
 _telemetry: Optional[ServingTelemetry] = None
+_exit_hook = False      # wait_for_profiler_stop registered with atexit
 
 
 def enable_telemetry(trace_path: Optional[str] = None) -> ServingTelemetry:
     """Install (or replace) the global ServingTelemetry and return it."""
-    global _telemetry
+    global _telemetry, _exit_hook
     if _telemetry is not None:
         _telemetry.close()
     _telemetry = ServingTelemetry(trace_path)
+    if not _exit_hook:
+        import atexit
+
+        atexit.register(wait_for_profiler_stop)
+        _exit_hook = True
     return _telemetry
+
+
+def wait_for_profiler_stop(limit_s: float = 600.0) -> int:
+    """At exit of a process that switched telemetry on: join every thread
+    that is still inside ``jax.profiler.stop_trace``, for at most
+    ``limit_s`` seconds; returns how many were. Collecting a session takes
+    30 s and 0.1 ms a device operation (three minutes for 5 s of a step of
+    1400 small operations at 4 ms); a caller that stops its session on a
+    daemon thread and gives up waiting for it (benchmark/run.py joins for
+    120 s) then exits with that thread in the profiler's C++, CPython's
+    finalization ends the thread on its way back into Python, the forced
+    unwind meets a ``catch (...)`` and the process aborts, exit 134, with
+    its work done and printed (PERF.md section 6, PR 51). Costs nothing
+    where no thread is there."""
+    import sys
+    import threading
+    import time
+
+    def inside(frame):
+        while frame is not None:
+            code = frame.f_code
+            if (code.co_name == "stop_trace"
+                    and "profiler" in code.co_filename):
+                return True
+            frame = frame.f_back
+        return False
+
+    deadline = time.monotonic() + limit_s
+    stopping = {ident for ident, frame in sys._current_frames().items()
+                if inside(frame)}
+    for t in threading.enumerate():
+        if t.ident in stopping and t is not threading.current_thread():
+            t.join(max(0.0, deadline - time.monotonic()))
+    return len(stopping)
 
 
 def disable_telemetry():

@@ -452,6 +452,12 @@ def test_ffsv_serving_abi_in_process():
     lib.ffsv_metrics_dump.argtypes = [c.c_char_p]
     libc_m = ctypes.CDLL(None)
     libc_m.free.argtypes = [ctypes.c_void_p]
+    # "disabled" is this process's state, not what a test file before this
+    # one on the same worker left: the switch off, dead fleets collected
+    import gc
+
+    disable_telemetry()
+    gc.collect()
     ptr = lib.ffsv_metrics_dump(b"json")
     assert ptr, lib.ffsv_last_error()
     assert ctypes.string_at(ptr) == b"{}"

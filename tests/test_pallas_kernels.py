@@ -656,12 +656,17 @@ def test_flash_row_map_matches_reference_on_gathered_rows(D, KH, H, stacked):
     _cmp(ref, out, lengths, 2e-5)
 
 
-def test_flash_without_row_map_keeps_its_kernel_arguments():
+@pytest.mark.parametrize("KH,more", [(16, 0), (2, 1)],
+                         ids=["loop_form", "block_form"])
+def test_flash_without_row_map_keeps_its_kernel_arguments(KH, more):
     """Decode blocks, tree verify and the speculation block call
     flash_attend without a map and must get the kernel they always got:
     one scalar-prefetch vector (the lengths) and seven operands. With a
-    map the call carries one more of each."""
-    R, Q, H, KH, D, S = 2, 8, 4, 2, 128, 256
+    map the call carries one more of each. So it is wherever the stream's
+    DMA block is its partition (16 key/value heads: 512 KB a descriptor of
+    128 positions); the block form (2 heads) carries one vector more, its
+    place in the DMA pipeline."""
+    R, Q, H, D, S = 2, 8, 16, 128, 256
     q, k, v = _mk(R, Q, H, KH, D, S)
     lengths = jnp.asarray([20, 9], jnp.int32)
     qpos = jnp.tile(jnp.arange(Q, dtype=jnp.int32)[None], (R, 1))
@@ -675,8 +680,142 @@ def test_flash_without_row_map_keeps_its_kernel_arguments():
         return (eqn.params["grid_mapping"].num_index_operands,
                 len(eqn.invars))
 
-    assert call(None) == (1, 7)
-    assert call(jnp.asarray([1, 0], jnp.int32)) == (2, 8)
+    assert fa.stream_block(KH, D, 4, H // KH * Q, S) == (128, 256)[more]
+    assert call(None) == (1 + more, 7 + more)
+    assert call(jnp.asarray([1, 0], jnp.int32)) == (2 + more, 8 + more)
+
+
+# ---------------------------------------------------------------------------
+# the plain stream's two forms: a DMA block of several softmax partitions,
+# its scores in one pass and its partitions unrolled, against a partition a
+# block (``stream_block`` decides, of the call's shapes alone)
+# ---------------------------------------------------------------------------
+
+# 2 key/value heads of 128 in bfloat16 over 1024 positions, the target
+# brought down to 256 KB: a DMA block of 512 positions, four partitions
+FORMS_TARGET = 2 * 512 * 128 * 2
+#  name: (G, Q, lengths AFTER the step, A (0: no append), counts, mode)
+FORM_CASES = {
+    # ragged rows, one idle, one full
+    "decode_g1": (1, 1, [700, 0, 129, 1024], 1, None, "append"),
+    "decode_g4": (4, 1, [700, 0, 129, 1024], 1, None, "append"),
+    "decode_g8": (8, 1, [700, 0, 129, 1024], 1, None, "append"),
+    # rows that end inside a block's first partition (513, 1) and its last
+    # (500, 1000), on a partition's edge (128, 640) and on a block's (512):
+    # the new position lands in a block's last window (512, 1024) and its
+    # first (513, 1)
+    "decode_edges": (4, 1, [513, 1, 500, 1000, 128, 640, 512, 1024], 1, None,
+                     "append"),
+    "decode_float32": (4, 1, [700, 0, 129, 1024], 1, None, "append32"),
+    "decode_stack": (4, 1, [300, 1000, 5, 0], 1, None, "stack"),
+    # runs that cross a partition (132), a DMA block (516), end the cache
+    "run4": (4, 4, [700, 0, 132, 1024, 516], 4, None, "append"),
+    "run4_counted": (4, 4, [700, 0, 131, 1022, 514], 4, [4, 0, 3, 2, 2],
+                     "append"),
+    "run8": (8, 8, [520, 0, 136, 1024, 8], 8, None, "append"),
+    "run8_counted": (8, 8, [517, 0, 131, 1021, 0], 8, [5, 0, 3, 5, 0],
+                     "append"),
+    "run8_stack": (2, 8, [520, 0, 136, 1024], 8, None, "stack"),
+    # no append: the compact prefill batch's row map, and a slot grid
+    "rows": (4, 8, [700, 100, 129, 1024], 0, None, "rows"),
+    "grid": (4, 8, [700, 0, 129, 1024], 0, None, "grid"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(FORM_CASES))
+def test_the_streams_block_form_equals_its_loop_form_bit_for_bit(
+        case, monkeypatch):
+    """The same arithmetic delivered in larger pieces: the output and both
+    caches after the append, bit for bit, whichever form streams them."""
+    G, Q, lengths, A, counts, mode = FORM_CASES[case]
+    R, KH, D, S = len(lengths), 2, 128, 1024
+    dt = jnp.float32 if mode == "append32" else jnp.bfloat16
+    rng = np.random.RandomState(51)
+    q = jnp.asarray(rng.randn(R, Q, KH * G, D), dt)
+    shape = ((3,) if mode == "stack" else ()) + (R, KH, S, D)
+    k, v = jnp.asarray(rng.randn(*shape), dt), jnp.asarray(rng.randn(*shape),
+                                                           dt)
+    lengths = jnp.asarray(lengths, jnp.int32)
+    kw = {"layer_idx": 1} if mode == "stack" else {}
+    start = lengths - Q
+    if A:
+        n = jnp.asarray([A] * R if counts is None else counts, jnp.int32)
+        start = jnp.where(lengths > 0, lengths - n, -1)
+        kw["append_kv"] = (jnp.asarray(rng.randn(R, A, KH, D), dt),
+                           jnp.asarray(rng.randn(R, A, KH, D), dt), start,
+                           *([] if counts is None else [n]))
+    if mode == "rows":
+        kw["rows"] = jnp.asarray(rng.permutation(R), jnp.int32)
+    qpos = jnp.maximum(start, 0)[:, None] + jnp.arange(Q)[None]
+    target = FORMS_TARGET * (2 if dt == jnp.float32 else 1)
+
+    def run(block_target):
+        monkeypatch.setattr(fa, "STREAM_BLOCK_TARGET", block_target)
+        DB = fa.stream_block(KH, D, jnp.dtype(dt).itemsize, G * Q, S)
+        return DB, jax.tree.leaves(fa.flash_attend.__wrapped__(
+            q, k, v, lengths, qpos.astype(jnp.int32), interpret=True, **kw))
+
+    (DB, block), (BS, loop) = run(target), run(1)
+    assert (DB, BS) == (512, 128)
+    assert len(block) == len(loop) == (3 if A else 1)
+    for a, b in zip(block, loop):
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+    if A:       # and something landed
+        assert (np.asarray(block[1], np.float32)
+                != np.asarray(k, np.float32)).any()
+
+
+#  configuration: (key/value heads, query heads, head dim, positions a slot
+#  or rows of its stream, tokens a row of a decode step, the decode step's
+#  DMA block); a latent cache, another kernel: (query heads, positions)
+CELL_STREAMS = {
+    "zaya1-8b": (2, 8, 128, 16384, 1, 1024),
+    "sdar-30b-a3b": (4, 32, 128, 1024, 8, 256),          # by its scores
+    "k-exaone-236b-a23b": (8, 64, 128, 8192, 1, 256),   # its full layers
+    "olmoe-1b-7b": (16, 16, 128, 1024, 1, 128),
+    "opt-6.7b-spec": (32, 32, 128, 1024, 8, 128),
+    "falcon-7b": (1, 71, 64, 2048, 1, 256),             # packed: outside
+    "evabyte-6.5b": (32, 32, 128, 24576 // 16 + 2048, 1, 128),
+    "mistral-small-4-119b": (32, 32768),
+    "longcat-flash-omni": (64, 8192),
+}
+
+
+def _cells():
+    import json
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        return [(w["name"], w["config"]) for w in json.load(f)["workloads"]]
+
+
+@pytest.mark.parametrize("cell,config", _cells())
+def test_the_stream_block_is_decided_from_the_shapes_of_the_call(cell,
+                                                                  config):
+    """``stream_block`` at each cell's decode and prefill shapes: a DMA block
+    of several partitions where a partition's K descriptor is under the
+    target, doubled until it reaches it (ZAYA1's 2 key/value heads,
+    K-EXAONE's 8) or the block's scores would pass their limit (SDAR's 4
+    heads of 64 query rows); the partition itself at 16 heads and more, on
+    a packed cache and for every prefill segment (128 tokens a row)."""
+    if len(CELL_STREAMS[config]) == 2:      # ``latent_form``'s to decide
+        H, S = CELL_STREAMS[config]
+        assert fa.latent_form(H, S) == "block"
+        assert fa.latent_form(32 * 128, S) == "partition"
+        return
+    KH, H, D, S, Q, want = CELL_STREAMS[config]
+    BS = fa._pick_block_s(S, D)
+    assert BS == (256 if D == 64 else 128)
+    assert fa.stream_block(KH, D, 2, H // KH * Q, S) == want
+    assert (want == BS or KH * want * D * 2 >= fa.STREAM_BLOCK_TARGET
+            or KH * H // KH * Q * 2 * want * 4 > fa.STREAM_BLOCK_SCORES_LIMIT)
+    assert fa.stream_block(KH, D, 2, H // KH, S) >= want     # one token
+    assert fa.stream_block(KH, D, 2, H // KH * 128, S) == BS  # a segment
+    # not from anything else: the same shapes, the same block
+    assert fa.stream_block.__code__.co_varnames[:5] == (
+        "KH", "D", "itemsize", "GQ", "S")
 
 
 # ---------------------------------------------------------------------------
@@ -780,3 +919,84 @@ def test_latent_head_groups_are_decided_from_the_shapes(shape, groups):
         DB, SB = fa._pick_latent_blocks(S)
         assert H % groups == 0 and fa._latent_vmem_bytes(
             H // groups * Q, W, rank, DB, SB, 2, 2) <= fa.LATENT_VMEM_LIMIT
+
+
+@pytest.mark.parametrize("shape,block", [("zaya1", 1024), ("sdar", 256)])
+def test_time_stream_attend_tool_rehearses(shape, block, monkeypatch,
+                                           capsys):
+    """tools/time_stream_attend.py (the kernel alone at a cell's decode
+    shape, by hand on the chip) at its rehearsal sizes, interpreted: every
+    variant runs, the rule's form is the block form there, and one step's
+    outputs and caches are bit-equal between the two forms."""
+    import json
+    import os
+
+    monkeypatch.syspath_prepend(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    monkeypatch.setenv("JAX_PLATFORMS", os.environ.get("JAX_PLATFORMS", ""))
+    from tools import time_stream_attend
+
+    assert time_stream_attend.main(["--rehearse", "--shapes", shape]) == 0
+    res = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert res["ok"] and res["device"] == "cpu"
+    cell = res["shapes"][shape]
+    assert (cell["form"], cell["rule_block"]) == ("block", block)
+    assert cell["forms_bit_equal"] is True
+    assert sorted(cell["us_a_call"]) == [
+        "loop", "loop.arith", "loop.stream", "tree", "tree.arith",
+        "tree.stream"]
+    assert fa.ABLATE is None and fa.stream_block.__name__ == "stream_block"
+
+
+@pytest.fixture(scope="module")
+def one_v5e(request):
+    """A described (not attached) v5e chip to compile for, or a skip."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    mp = pytest.MonkeyPatch()
+    request.addfinalizer(mp.undo)
+    mp.setenv("TPU_LOG_DIR", "disabled")
+    mp.setenv("TPU_ACCELERATOR_TYPE", "v5litepod-4")
+    mp.setenv("TPU_WORKER_HOSTNAMES", "localhost")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # no TPU compiler here, or its lock is held
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+#  a cell's decode call: rows, key/value heads, group, tokens a row (its run
+#  of new positions), positions a slot, the DMA block
+V5E_DECODES = {
+    "zaya1": (16, 2, 4, 1, 16384, 1024),
+    "sdar": (32, 4, 8, 8, 1024, 256),
+    "k-exaone": (32, 8, 8, 1, 8192, 256),
+    "olmoe": (32, 16, 1, 1, 1024, 128),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(V5E_DECODES))
+def test_the_decode_forms_compile_for_a_v5e_at_the_cells_shapes(cell,
+                                                                 one_v5e):
+    """What interpret mode cannot show: Mosaic takes the block form (and the
+    loop) at the published widths, the append fused, on a stack. Compiled
+    for a described chip; nothing runs."""
+    R, KH, G, Q, S, DB = V5E_DECODES[cell]
+    D = 128
+    assert fa.stream_block(KH, D, 2, G * Q, S) == DB
+
+    def aval(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_v5e)
+
+    def step(q, k, v, lengths, qpos, new, at):
+        return fa.flash_attend.__wrapped__(
+            q, k, v, lengths, qpos, append_kv=(new, new, at), layer_idx=1)
+
+    compiled = jax.jit(step, donate_argnums=(1, 2)).lower(
+        aval((R, Q, KH * G, D)), aval((2, R, KH, S, D)),
+        aval((2, R, KH, S, D)), aval((R,), jnp.int32),
+        aval((R, Q), jnp.int32), aval((R, Q, KH, D)),
+        aval((R,), jnp.int32)).compile()
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1

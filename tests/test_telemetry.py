@@ -682,3 +682,50 @@ def test_diffusion_readers_on_hand_made_snapshots():
     assert read["threshold_share"](ctx) == pytest.approx(25.0)
     for bare in ({"tel": None}, {"tel": {"before": {}, "after": {}}}):
         assert all(r(bare) is None for r in read.values())
+
+
+def test_exit_waits_for_a_thread_still_stopping_a_profiler_session(tmp_path):
+    """``wait_for_profiler_stop`` (the exit hook ``enable_telemetry``
+    registers, once): a thread whose stack is inside a profiler's
+    ``stop_trace`` is joined, any other thread is left alone, and with no
+    such thread it returns at once."""
+    import importlib.util
+    import threading
+    import time
+
+    from flexflow_tpu import telemetry as T
+
+    assert T.wait_for_profiler_stop(limit_s=5.0) == 0
+    path = tmp_path / "fake_profiler.py"
+    path.write_text("def stop_trace(inside, done):\n"
+                    "    inside.set()\n"
+                    "    done.wait()\n")
+    spec = importlib.util.spec_from_file_location("fake_profiler", path)
+    fake = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(fake)
+    inside, done, forever = (threading.Event() for _ in range(3))
+    stopper = threading.Thread(target=fake.stop_trace, args=(inside, done),
+                               daemon=True)
+    other = threading.Thread(target=forever.wait, daemon=True)
+    stopper.start(), other.start()
+    assert inside.wait(5.0)
+    threading.Timer(0.3, done.set).start()
+    t = time.monotonic()
+    assert T.wait_for_profiler_stop(limit_s=20.0) == 1
+    assert not stopper.is_alive() and other.is_alive()
+    assert 0.2 < time.monotonic() - t < 10.0
+    forever.set()
+    # a thread that never leaves costs the limit and no more
+    inside.clear(), done.clear()
+    stuck = threading.Thread(target=fake.stop_trace, args=(inside, done),
+                             daemon=True)
+    stuck.start()
+    assert inside.wait(5.0)
+    t = time.monotonic()
+    assert T.wait_for_profiler_stop(limit_s=0.2) == 1 and stuck.is_alive()
+    assert time.monotonic() - t < 5.0
+    done.set()
+    T.enable_telemetry()
+    T.enable_telemetry()
+    T.disable_telemetry()
+    assert T._exit_hook is True

@@ -452,6 +452,40 @@ def test_the_loop_serves_it_and_counts_where_the_tails_came_from(bench):
 # (vii) a synthetic checkpoint under the HF_KEYS names
 # ---------------------------------------------------------------------------
 
+def test_the_decode_block_streams_by_the_block_and_prefill_by_the_loop(
+        bench, monkeypatch):
+    """Served with the kernels interpreted, in the cell's dtype: the decode
+    block's trace takes the k/v kernel's BLOCK form (2 key/value heads: a
+    partition's descriptor is 64 KB, a DMA block is eight of them), the
+    compact prefill step's 256 query rows a key/value head the LOOP (1 MiB
+    of scores a block would be 2 MiB here), one trace a layer each, and the
+    tokens are those of the jnp path."""
+    import flexflow_tpu.kernels as ffk
+
+    def serve():
+        m, _ = _build(tiny=dict(TINY, num_attention_heads=8),
+                      max_sequence_length=2048, max_tokens_per_batch=256,
+                      compute_dtype="bfloat16", kv_cache_dtype="bfloat16")
+        rm = RequestManager()
+        rm.register_new_request([int(t) for t in _tokens(150, seed=51)],
+                                max_new_tokens=6)
+        return [r.output_tokens for r in rm.generate_incr_decoding(m)]
+
+    plain = serve()
+    monkeypatch.setenv("FF_PALLAS_INTERPRET", "1")
+    ffk.reset_dispatch_stats()
+    assert serve() == plain
+    assert not ffk.fallback_counts
+    assert ffk.stream_form_counts == {("block", "append", 1024): 2,
+                                      ("loop", "rows", 128): 2}
+    assert ffk.stream_summary() == (
+        "k/v kernel: block form of 1024, append: 2 traces; loop form of "
+        "128, rows: 2 traces")
+    ffk.reset_dispatch_stats()
+    assert not ffk.stream_form_counts
+    assert ffk.stream_summary() == "k/v kernel: 0 traces"
+
+
 def test_hf_weight_map_loads_a_synthetic_checkpoint_with_one_table(bench):
     """A state dict under the names ``models/zaya.HF_KEYS`` lists (torch
     layouts: ``[out, in]`` Linears, one a projection an expert, Conv1d
