@@ -60,26 +60,32 @@ def _open_block(tel):
             tel.call_phase(None, "call_stage", "spec_block"))
 
 
-def _report_block(engine, tel, span, wait, seconds, packed, n_rounds,
-                  trace):
+def _report_block(engine, tel, span, wait, t0, packed, n_rounds, trace,
+                  behind):
     """Telemetry after one fused block's read-back (the device fence):
     closes the call's ``call_wait`` leaf and its ``spec_block`` span,
     feeds the speculation metrics, and reports compiles the call made.
+    ``t0``: the block's launch. ``behind``: the program of the call the
+    block was launched behind and waited for after its own launch
+    (``prefill``; None: the device had nothing queued), onto the span,
+    which then holds the block's own time and not that call's
+    (``ServingTelemetry.end_spec_block``).
     ``trace`` is the scheduler round's RoundTrace, if a loop drives the
     engine: its ``sched_commit`` phase opens as soon as the spans are
     closed, so the bookkeeping here counts as the round's."""
+    seconds = time.perf_counter() - t0
     tel.call_phase(wait, None)
     name = type(engine).__name__
     ran = packed[:, :, -2] >= 0
-    tel.tracer.end(span, rounds_asked=n_rounds,
-                   rounds=int(ran.any(axis=0).sum()),
-                   rows=int(ran.any(axis=1).sum()),
-                   committed=int((packed[:, :, -2][ran] + 1).sum()),
-                   engine=name)
+    tel.end_spec_block(span, rounds_asked=n_rounds,
+                       rounds=int(ran.any(axis=0).sum()),
+                       rows=int(ran.any(axis=1).sum()),
+                       committed=int((packed[:, :, -2][ran] + 1).sum()),
+                       engine=name, behind=behind)
     if trace is not None:
         trace.phase("sched_commit")
     tel.record_spec_block(seconds, packed[:, :, -2],
-                          depths=packed[:, :, -1])
+                          depths=packed[:, :, -1], t0=t0)
     if engine._trace_count != engine._traces_reported:
         tel.note_retrace(name,
                          engine._trace_count - engine._traces_reported,
@@ -537,8 +543,11 @@ def run_block(self, tks: np.ndarray, nblk: np.ndarray, base: np.ndarray,
     observations.
 
     ``trace`` is the calling scheduler loop's RoundTrace when telemetry
-    is on (None otherwise, and for direct drivers): the block then hands
-    the round its ``sched_commit`` phase the moment its own spans close.
+    is on (None otherwise, and for direct drivers): the block settles the
+    prefill step the round may have pending between its own
+    ``call_launch`` and ``call_wait`` (as InferenceManager.decode_block
+    does), and hands the round its ``sched_commit`` phase the moment its
+    own spans close.
     """
     n_rounds = min(int(n_rounds), self.max_rounds)
     tel = _resolve_tel(self.telemetry)
@@ -567,11 +576,13 @@ def run_block(self, tks: np.ndarray, nblk: np.ndarray, base: np.ndarray,
     for s, st in zip(self.ssms, ssm_states):
         s.op_state = st
     if tel is not None:
-        ph = tel.call_phase(ph, "call_wait", "spec_block")
+        tel.call_phase(ph, None)
+        behind = trace.settle() if trace is not None else None
+        ph = tel.call_phase(None, "call_wait", "spec_block")
     packed = np.asarray(packed)
     if tel is not None:     # the np readback above is the device fence
-        _report_block(self, tel, span, ph, time.perf_counter() - t0,
-                      packed, n_rounds, trace)
+        _report_block(self, tel, span, ph, t0, packed, n_rounds, trace,
+                      behind)
     return packed[:, :, :-2], packed[:, :, -2], packed[:, :, -1]
 
 

@@ -101,7 +101,7 @@ class InferenceManager:
         return forward_with_meta(self.model, params, op_state, meta, rng,
                                  self._compute_dtype)
 
-    def step(self, meta, want_output: bool = True, tel=None):
+    def step(self, meta, want_output: bool = True, tel=None, rnd=None):
         """Run one serving step; threads the model's KV caches through.
 
         Returns the op outputs (token ids [R, Q] for graphs ending in
@@ -115,7 +115,8 @@ class InferenceManager:
         calls have been launched. ``tel`` (a ServingTelemetry; None: no
         spans) records the call's ``call_stage`` / ``call_launch`` /
         ``call_wait`` leaves; an output-free step is program ``prefill``
-        and its wait is its caller's (telemetry.PendingPrefill).
+        and its wait is its caller's (telemetry.PendingPrefill). ``rnd``:
+        as in ``decode_block``, for a step whose output is read.
         """
         ph, prog = None, "step" if want_output else "prefill"
         if tel is not None:
@@ -141,7 +142,10 @@ class InferenceManager:
                 tel.call_phase(ph, None)
             return out
         if tel is not None:
-            ph = tel.call_phase(ph, "call_wait", prog)
+            tel.call_phase(ph, None)
+            if rnd is not None:
+                rnd.settle()
+            ph = tel.call_phase(None, "call_wait", prog)
         out = np.asarray(out)
         if tel is not None:
             tel.call_phase(ph, None)

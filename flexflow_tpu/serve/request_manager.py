@@ -620,19 +620,23 @@ class RequestManager:
             req.first_token_s = time.perf_counter()
 
     def _timed_prefill(self, ifm, meta, tel, rows, active, rnd=None,
-                       lag=False):
+                       model="llm"):
         """One prefill step. Its outputs are discarded (want_output=False
         dispatches asynchronously and is forgotten); with telemetry on a
         wait on the small output its program hands back times the step and
         records its spans and counters (telemetry.PendingPrefill).
 
-        ``rows``/``active`` feed per-request prefill spans. ``rnd`` is
+        ``rows``/``active`` feed per-request prefill spans, which carry
+        ``model`` (``llm``, or ``ssm<i>`` for draft ``i``). ``rnd`` is
         the round's RoundTrace in the loops that have one: the call's
         ``call_*`` leaves take over from the open phase, and
-        ``sched_build`` resumes after them. With ``lag`` the step is
-        waited for once the round's NEXT device call has been launched
-        (the next step, here; InferenceManager.decode_block), so the
-        device has work queued meanwhile, as it has with telemetry off."""
+        ``sched_build`` resumes after them, and the step is waited for
+        once the round's NEXT device call has been launched (the next
+        step, here; InferenceManager.decode_block or step;
+        engine.run_block; failing all, RoundTrace.end), so the device has
+        work queued meanwhile, as it has with telemetry off. Without a
+        round (the host-stepped speculation loop) the step is waited for
+        at once."""
         if tel is None:
             ifm.step(meta, want_output=False)
             return
@@ -647,7 +651,8 @@ class RequestManager:
             rnd.phase(None)
         step = PendingPrefill(tel, [(active[slot].guid, sp, len(chunk))
                                     for slot, chunk, sp in rows],
-                              meta.tokens.size, leaf=rnd is not None)
+                              meta.tokens.size, leaf=rnd is not None,
+                              model=model)
         step.out = ifm.step(meta, want_output=False,
                             tel=tel if rnd is not None else None)
         if rnd is None:
@@ -655,8 +660,6 @@ class RequestManager:
             return
         rnd.settle()            # the step before, now that this is queued
         rnd.pending = step
-        if not lag:
-            rnd.settle()
         rnd.phase("sched_build")
 
     def _tel_tick(self, tel, live, slots: int, max_seq: int):
@@ -785,14 +788,14 @@ class RequestManager:
         return getattr(getattr(ifm, "model", None), "_pp_plan", None) is None
 
     def _prefill(self, ifm, active, shape, depth_of, tel, rnd=None,
-                 lag=False):
+                 model="llm"):
         """One prefill step for ``ifm``'s model: choose the segments
         among ``active`` (None: not a candidate), run them in one
         output-free step, return them (none: nothing is filling). The one
         prefill path of the Python loops; the caller moves its depth marks
-        by the rows returned. The speculation loops call it once a round,
-        the incremental loop as often as StepCosts allows the round and
-        with ``lag`` (_timed_prefill)."""
+        by the rows returned. The speculation loops call it once a model a
+        round, the incremental loop as often as StepCosts allows the round;
+        ``rnd`` and ``model``: _timed_prefill."""
         chunk, segments = shape
         compact = self._compact_prefill(ifm)
         chunked = (getattr(ifm.model, "attention_kinds", None)
@@ -805,7 +808,7 @@ class RequestManager:
             meta = (self._meta_from_segments(segments, chunk, rows)
                     if compact else
                     self._meta_from_rows(len(active), chunk, rows))
-            self._timed_prefill(ifm, meta, tel, rows, active, rnd, lag)
+            self._timed_prefill(ifm, meta, tel, rows, active, rnd, model)
         return rows
 
     # -- a decode block's two ends ------------------------------------------
@@ -958,8 +961,7 @@ class RequestManager:
             steps, timed, t0 = 0, False, time.perf_counter()
             while allowed is None or steps < allowed:
                 rows = self._prefill(ifm, active, shape,
-                                     lambda r: r.cache_depth, tel, rnd,
-                                     lag=True)
+                                     lambda r: r.cache_depth, tel, rnd)
                 if not rows:
                     break
                 if not steps:
@@ -1095,7 +1097,8 @@ class RequestManager:
         if rnd is not None:
             rnd.phase(None)
         t0 = time.perf_counter()
-        toks = llm_ifm.decode_block(R_tok, pos, act, block, tel=tel)
+        toks = llm_ifm.decode_block(R_tok, pos, act, block, tel=tel,
+                                    rnd=rnd)
         if tel is not None:     # decode_block's np readback = fence
             dt = time.perf_counter() - t0
             rnd.phase("sched_commit", reqs)
@@ -1275,7 +1278,7 @@ class RequestManager:
             prefilled = bool(rows)
             for i, ifm in enumerate(ssm_ifms):
                 rows = self._prefill(ifm, active, shape, ssm_depth_of(i),
-                                     tel)
+                                     tel, model=f"ssm{i}")
                 for slot, toks, sp in rows:
                     active[slot].ssm_cache_depth[i] = sp + len(toks)
                 prefilled = prefilled or bool(rows)
@@ -1406,7 +1409,10 @@ class RequestManager:
                 rnd.admitted(R - active.count(None), len(self.pending))
             # one bounded prefill chunk per model per round (same path as
             # incremental); caught-up slots spec/decode below in the SAME
-            # round (decode-interleaved chunked prefill, ISSUE 19)
+            # round (decode-interleaved chunked prefill, ISSUE 19). With
+            # telemetry on each step is waited for behind the round's next
+            # device call (_timed_prefill), so the calls queue as they do
+            # with it off
             rows = self._prefill(llm_ifm, active, shape,
                                  lambda r: r.cache_depth, tel, rnd)
             for slot, toks, sp in rows:
@@ -1427,7 +1433,8 @@ class RequestManager:
             for i, ifm in enumerate(ssm_ifms):
                 rows = self._prefill(
                     ifm, owing, shape,
-                    lambda r, i=i: r.ssm_cache_depth.get(i, 0), tel, rnd)
+                    lambda r, i=i: r.ssm_cache_depth.get(i, 0), tel, rnd,
+                    model=f"ssm{i}")
                 for slot, toks, sp in rows:
                     active[slot].ssm_cache_depth[i] = sp + len(toks)
                 if rows:
@@ -1470,7 +1477,7 @@ class RequestManager:
                 if rnd is not None:
                     rnd.phase(None)
                 t0 = time.perf_counter()
-                out = llm_ifm.step(meta, tel=tel)
+                out = llm_ifm.step(meta, tel=tel, rnd=rnd)
                 if tel is not None:       # step's np readback = fence
                     dt = time.perf_counter() - t0
                     rnd.phase("sched_commit", cramped)

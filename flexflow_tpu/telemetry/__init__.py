@@ -36,7 +36,6 @@ ffsv_spec_rounds_total           counter    speculation rounds executed
 ffsv_decode_steps_total          counter    row-steps of decode blocks
 ffsv_diffusion_row_passes_total  counter    passes block-diffusion rows ran
 ffsv_diffusion_commit_passes_total counter  those that only stored a block
-ffsv_diffusion_folded_commits_total counter blocks stored by a denoise pass
 ffsv_diffusion_tokens_total      counter    {by} positions denoise passes unmasked
 ffsv_acceptance_length           histogram  accepted draft tokens per round
 ffsv_tokens_per_round            histogram  committed tokens per round (+bonus)
@@ -67,8 +66,6 @@ ffsv_kv_cache_bytes              gauge      {kind} bytes of the caches of a kind
 ffsv_attn_positions_read_total   counter    {kind} layer-positions decode read
 ffsv_attn_positions_held_total   counter    layer-positions decode rows held (chunked)
 ffsv_attn_prefill_entries_total  counter    layer-entries prefill segments read (chunked)
-ffsv_chunk_summaries_total       counter    summary rows written (a layer each)
-ffsv_window_rollovers_total      counter    {phase} rows that entered a new window
 ffsv_moe_routed_pairs_total      counter    {phase} (token, expert) pairs run
 ffsv_moe_tokens_total            counter    {phase} real tokens the experts saw
 ffsv_moe_experts_touched         summary    {phase} distinct experts a call read
@@ -85,10 +82,10 @@ denoises, unmasking positions ``by`` ``threshold`` (every pick more
 probable than it) or by ``floor`` (the schedule's most confident, where
 fewer cleared it); the pass that leaves a block whole is when its tokens
 come out, and the row's next pass stores the block in front of the block
-it denoises (``ffsv_diffusion_folded_commits_total``). A row-pass that did
-nothing but store a block is a ``commit_passes``; the decode block runs
-none since it folds them (serve/engine._diffusion_block), and the series
-stays at 0 as the measure of what is left of them.
+it denoises. A row-pass that did nothing but store a block is a
+``commit_passes``; the decode block runs none since it folds them
+(serve/engine._diffusion_block), and the series stays at 0 as the measure
+of what is left of them.
 
 ``kind`` is ``window``, ``full`` or ``latent``: a model with windowed
 attention layers beside full ones keeps a ring a windowed layer and every
@@ -102,12 +99,7 @@ windows before a row's own) and ``kind="chunk_window"`` (its window's
 positions up to its own), both layer-entries of the same bytes;
 ``ffsv_attn_positions_held_total`` is the positions those rows held (what
 a full cache would have read), ``ffsv_attn_prefill_entries_total`` what the
-prefill steps' segments had to read, once a segment;
-``ffsv_chunk_summaries_total`` counts the summary rows written (a whole
-chunk of a layer each, prefill and decode) and
-``ffsv_window_rollovers_total{phase}`` the rows whose position entered a
-new window (``prefill``: a segment that starts one; ``decode``: a step),
-where nothing is moved or launched.
+prefill steps' segments had to read, once a segment.
 A model whose attention layers CARRY A TAIL (ops/cca_attention.py: the last
 positions' unmixed latents, a slot's state beside a plain k/v cache, which
 every step overwrites; ``"tail_bytes"`` under ``attention_kinds["full"]``)
@@ -148,7 +140,10 @@ every device call records ``call_stage`` / ``call_launch`` / ``call_wait``
 through ``ServingTelemetry.call_phase`` (inside a ``spec_block`` span for
 the fused engines), in sequence and never two open. A call's wait may come
 after the next call's launch (``stage k+1, launch k+1, wait k``: a lagged
-prefill step); README "Telemetry" has the table.
+prefill step, in the incremental loop and in the fused speculation loop);
+README "Telemetry" has the tables. A ``prefill`` span says whose cache the
+step filled (``model``: ``llm``, ``ssm<i>``), a ``spec_block`` span what it
+was launched behind (``behind``: ``prefill`` or nothing).
 
 Fleet layer (this package's distributed half): ``fleet.FleetTelemetry``
 keeps one ServingTelemetry per replica (distinct Chrome-trace ``pid``
@@ -169,15 +164,19 @@ AROUND device calls whose results the host has waited for (``np.asarray``
 of the packed block output; for an output-free prefill step a
 ``block_until_ready`` on the small output its program hands back beside the
 donated op_state: ``PendingPrefill``), so a recorded time never measures
-the enqueue alone (utils/profiling.py protocol). The incremental loop
-makes a prefill step's wait only after the round's NEXT device call has
-been launched, so the device is never left with nothing queued for the
-measurement's sake; a span therefore starts where the last recorded call's
-ended if that is later than its own launch (``_own_time``). The spans of
-calls queued behind each other do not overlap, each brackets its own
-call's device time, and ``ffsv_prefill_step_seconds`` observes the span's
-length: a step's service time on a busy device, staging and launch
-included only for a step that found the device idle. The boundary between
+the enqueue alone (utils/profiling.py protocol). Both loops that serve
+traffic (incremental; fused speculation) make a prefill step's wait only
+after the round's NEXT device call has been launched (the next step, the
+decode block, the speculation block), so the device is never left with
+nothing queued for the measurement's sake; a span therefore starts where
+the last recorded call's ended if that is later than its own launch
+(``own_start``: the ``prefill`` and ``decode_block`` spans, the
+``spec_block`` span, the ``decode_round`` spans spread over it). The spans
+of calls queued behind each other do not overlap, each brackets its own
+call's device time, and ``ffsv_prefill_step_seconds`` (and
+``ffsv_decode_block_seconds``, ``ffsv_spec_block_seconds``) observes the
+span's length: a call's service time on a busy device, staging and launch
+included only for a call that found the device idle. The boundary between
 two such spans is the host's return from the earlier call's wait, so it
 is as late as the host is in learning that a call ended (milliseconds on
 a shared host: PERF.md 7 (s)): a round's sum is exact, its split is not.
@@ -220,8 +219,10 @@ class RoundTrace:
     (``ServingTelemetry.call_phase``) take over until the loop names the
     next phase. ``pending`` is the round's prefill step that was launched
     and is not waited for yet (``PendingPrefill``; the incremental loop
-    lags each step's wait by one device call): it never outlives the
-    round. Built only with telemetry on (``begin_round``)."""
+    and the fused speculation loop lag each step's wait by one device
+    call): whoever launches the round's next call settles it between that
+    call's launch and its own wait, and it never outlives the round.
+    Built only with telemetry on (``begin_round``)."""
 
     __slots__ = ("tel", "round", "leaf", "args", "grants0", "reqs",
                  "tokens0", "pending")
@@ -273,16 +274,19 @@ class RoundTrace:
         stands."""
         self.args.setdefault("cut", why)
 
-    def settle(self):
-        """Wait for the round's pending prefill step, if it has one. Its
-        ``call_wait`` is a leaf: no other may be open."""
-        if self.pending is not None:
-            self.pending.settle()
-            self.pending = None
+    def settle(self) -> Optional[str]:
+        """Wait for the round's pending prefill step, if it has one, and
+        say what was waited for (``prefill``; None: nothing was pending).
+        Its ``call_wait`` is a leaf: no other may be open."""
+        if self.pending is None:
+            return None
+        self.pending.settle()
+        self.pending = None
+        return "prefill"
 
     def end(self):
         self.phase(None)
-        self.settle()       # a round that ended without a decode block
+        self.settle()       # a round that ended without a block
         self.tel.tracer.end(self.round, **self.args)
 
 
@@ -291,20 +295,24 @@ class PendingPrefill:
     step's program hands back a small output beside the op state it donates
     onward (``InferenceManager.step``): ``settle`` waits on that output,
     never reads it, and records the step, its spans and its counters
-    together. WHEN is the loop's choice: at once, or after the next device
-    call has been launched so that the device has work queued meanwhile.
+    together. WHEN is the loop's choice: at once (the host-stepped
+    speculation loop, a driver without a ``RoundTrace``), or after the next
+    device call has been launched so that the device has work queued
+    meanwhile (the incremental loop and the fused speculation loop).
     ``leaf``: whether the wait is a ``call_wait`` leaf (a loop with a
-    ``RoundTrace``). Built only with telemetry on; the launch time is taken
-    here, on the clock ``settle`` reads."""
+    ``RoundTrace``). ``model``: whose cache the step fills, onto its spans
+    (``llm``, ``ssm<i>``). Built only with telemetry on; the launch time is
+    taken here, on the clock ``settle`` reads."""
 
-    __slots__ = ("tel", "rows", "positions", "leaf", "t0", "out")
+    __slots__ = ("tel", "rows", "positions", "leaf", "model", "t0", "out")
 
     def __init__(self, tel: "ServingTelemetry", rows, positions: int,
-                 leaf: bool):
+                 leaf: bool, model: str = "llm"):
         self.tel = tel
         self.rows = rows            # [(guid, start_pos, n_tokens)]
         self.positions = positions
         self.leaf = leaf
+        self.model = model
         self.out = None             # the launched step's output, a future
         self.t0 = time.perf_counter()
 
@@ -318,7 +326,8 @@ class PendingPrefill:
         self.out = None
         tel.record_prefill(time.perf_counter() - self.t0,
                            sum(n for _, _, n in self.rows), self.rows,
-                           self.t0, positions=self.positions)
+                           self.t0, positions=self.positions,
+                           model=self.model)
 
 
 class ServingTelemetry:
@@ -417,10 +426,6 @@ class ServingTelemetry:
         r.counter(
             "ffsv_diffusion_commit_passes_total",
             "row-passes that did nothing but store a whole block")
-        self.diffusion_folded_commits = r.counter(
-            "ffsv_diffusion_folded_commits_total",
-            "whole blocks a row stored in the pass that began to denoise "
-            "its next block")
         self.diffusion_tokens = {
             by: r.counter(f'ffsv_diffusion_tokens_total{{by="{by}"}}',
                           "positions denoise passes unmasked: above the "
@@ -591,7 +596,7 @@ class ServingTelemetry:
         self._note_tails("prefill", **{s: src.count(s) for s in
                                        ("start", "step", "state")})
 
-    def _chunked_counter(self, name, n, layers=1):
+    def _chunked_counter(self, name, n, layers):
         helps = {
             'ffsv_attn_positions_read_total{kind="summary"}':
                 "layer-entries the decode steps' rows had to attend",
@@ -600,20 +605,15 @@ class ServingTelemetry:
             "ffsv_attn_positions_held_total":
                 "layer-positions the decode steps' rows held",
             "ffsv_attn_prefill_entries_total":
-                "layer-entries the prefill steps' segments had to attend",
-            "ffsv_chunk_summaries_total":
-                "summary rows written, a layer and a whole chunk each"}
-        self.registry.counter(
-            name, helps.get(name, "rows whose position crossed into a new "
-                            "window of a chunked layer")).inc(int(n) * layers)
+                "layer-entries the prefill steps' segments had to attend"}
+        self.registry.counter(name, helps[name]).inc(int(n) * layers)
 
     def _note_chunked_reads(self, a, at):
         """``at`` [rows, steps]: the positions each row's cache holds after
         each decode step's append, over chunked layers ``a``
         (ops/kv_layout.py): the summaries of the windows before the last
         position's own and that window's positions up to it are what the
-        step read; a last position that ends a chunk wrote a summary row; a
-        last position that begins a window (but the first) crossed into it."""
+        step read."""
         last, W, c = at - 1, a["window"], a["chunk"]
         L = a["layers"]
         summaries = int((last // W * (W // c)).sum())
@@ -623,30 +623,18 @@ class ServingTelemetry:
         self._chunked_counter(
             'ffsv_attn_positions_read_total{kind="chunk_window"}', window, L)
         self._chunked_counter("ffsv_attn_positions_held_total", at.sum(), L)
-        self._chunked_counter("ffsv_chunk_summaries_total",
-                              (at % c == 0).sum(), L)
-        self._chunked_counter(
-            'ffsv_window_rollovers_total{phase="decode"}',
-            ((last % W == 0) & (last > 0)).sum())
         return {"entries": (summaries + window) * L}
 
     def note_chunked_prefill(self, a, runs):
         """A prefill step's ``runs`` [(start, tokens)] over chunked layers
-        ``a``: the whole chunks they hold were summarised, a run that
-        starts a window (but the first) crossed into it, and a run's
-        queries had the summaries before its window and the window up to
-        its last position to read (once a run: the kernel streams them for
-        the segment, not for each query)."""
+        ``a``: a run's queries had the summaries before its window and the
+        window up to its last position to read (once a run: the kernel
+        streams them for the segment, not for each query)."""
         W, c = a["window"], a["chunk"]
         self._chunked_counter(
             "ffsv_attn_prefill_entries_total",
             sum(sp // W * (W // c) + (sp + n - 1) % W + 1
                 for sp, n in runs if n), a["layers"])
-        self._chunked_counter("ffsv_chunk_summaries_total",
-                              sum(n // c for _, n in runs), a["layers"])
-        self._chunked_counter(
-            'ffsv_window_rollovers_total{phase="prefill"}',
-            sum(sp > 0 and sp % W == 0 for sp, _ in runs))
 
     @staticmethod
     def _read_counters(model):
@@ -799,26 +787,35 @@ class ServingTelemetry:
         self.flight.record("failover", guid=guid, replica=replica,
                            target=target, trace_id=trace_id)
 
+    def own_start(self, t0: float, end: float) -> float:
+        """Where the span of a device call that was launched at ``t0`` and
+        waited for until ``end`` starts: no earlier than the last recorded
+        prefill step or decode block ended. A call launched behind another
+        (a lagged prefill step, the block after it) ran on the device from
+        then on, so the spans bracket each its own call's device time and
+        do not overlap."""
+        return max(t0, min(self._call_end, end))
+
     def _own_time(self, seconds: float, t0: Optional[float]):
-        """(start, seconds) of the span of a device call that was launched
-        at ``t0`` (None: ``seconds`` ago) and waited for until ``seconds``
-        later: it starts no earlier than the last such call ended. A call
-        launched behind another (a lagged prefill step, the decode block
-        after it) ran on the device from then on, so the spans bracket each
-        its own call's device time and do not overlap."""
+        """(start, seconds) of the span of a prefill step or decode block
+        launched at ``t0`` (None: ``seconds`` ago) and waited for until
+        ``seconds`` later (``own_start``), which the next such span then
+        starts no earlier than."""
         end = time.perf_counter() if t0 is None else t0 + seconds
-        t0 = max(end - seconds, min(self._call_end, end))
+        t0 = self.own_start(end - seconds, end)
         self._call_end = end
         return t0, end - t0
 
     def record_prefill(self, seconds: float, n_tokens: int, rows=(),
-                       t0: Optional[float] = None, positions: int = 0):
+                       t0: Optional[float] = None, positions: int = 0,
+                       model: str = "llm"):
         """``t0``: the step's launch on ``perf_counter`` (None: it ended
         just now), ``seconds`` from there to the end of its wait; the span
         and the histogram get the step's own time (``_own_time``).
         ``positions``: the batch rows x chunk the step's program computed,
-        real tokens or padding. Counters and spans move together, so a
-        snapshot never counts a step whose span is not out yet."""
+        real tokens or padding. ``model``: onto every copy of the span
+        (tracing.SpanTracer.prefill). Counters and spans move together, so
+        a snapshot never counts a step whose span is not out yet."""
         t0, seconds = self._own_time(seconds, t0)
         self.prefill_seconds.observe(seconds)
         self.prefill_tokens.inc(n_tokens)
@@ -828,7 +825,7 @@ class ServingTelemetry:
         self.prefill_pairs.inc(sum(n * sp + n * (n + 1) // 2
                                    for _, sp, n in rows))
         for guid, start_pos, n in rows:
-            self.tracer.prefill(guid, start_pos, n, t0, seconds)
+            self.tracer.prefill(guid, start_pos, n, t0, seconds, model)
 
     def note_round_prefill(self, steps: int):
         """Once per round of the incremental loop: the prefill steps it
@@ -854,8 +851,7 @@ class ServingTelemetry:
         block-diffusion model's block). ``passes`` (such a model's
         inference_manager.BlockPasses; None: a token a row a step) feeds
         the ``ffsv_diffusion_*`` counters and gives the span ``committed``
-        (tokens the call emitted) and ``folded`` (blocks its passes stored
-        in front of the blocks they denoised). ``reads``: what
+        (tokens the call emitted). ``reads``: what
         ``note_attention_reads`` handed back for the block, onto the span."""
         t0, seconds = self._own_time(seconds, t0)
         self.decode_block_seconds.observe(seconds)
@@ -865,24 +861,36 @@ class ServingTelemetry:
         if passes is not None:
             ran = {k: int(v.sum()) for k, v in passes.stats.items()}
             self.diffusion_row_passes.inc(ran["passes"])
-            self.diffusion_folded_commits.inc(ran["folded"])
             self.diffusion_tokens["threshold"].inc(ran["by_threshold"])
             self.diffusion_tokens["floor"].inc(ran["by_floor"])
-            extra.update(committed=ran["count"], folded=ran["folded"])
+            extra["committed"] = ran["count"]
         for g in guids:
             self.tracer.decode_block(g, steps, t0, seconds, int(n_live),
                                      int(width), **extra)
         self.flight.record("decode_block", seconds=round(seconds, 6),
                            steps=int(steps), n_live=int(n_live))
 
+    def end_spec_block(self, span, **args):
+        """Close a fused block's live ``spec_block`` span over the block's
+        own time (``own_start``): launched behind a prefill step, the block
+        ran on the device only from that step's end, and its
+        ``call_stage`` leaf then lies before the span. A block that found
+        nothing pending keeps the start ``begin`` gave it."""
+        self.tracer.end(span, t0=self.own_start(span[3],
+                                                time.perf_counter()), **args)
+
     def record_spec_block(self, seconds: float, n_acc: np.ndarray,
-                          depths=None):
+                          depths=None, t0: Optional[float] = None):
         """After one fused speculation block (all engines): ``n_acc`` is
         the packed [R, rounds] accepted-length matrix, -1 marking idle
         rounds. Called from engine.run_block, so bench/direct engine
         drivers are instrumented too, not just the RequestManager.
         ``depths`` (same shape, optional) is the per-round EFFECTIVE
-        draft depth the adaptive controller ran each row under."""
+        draft depth the adaptive controller ran each row under. ``t0``:
+        the block's launch, ``seconds`` before the end of its wait; the
+        histogram gets the block's own time (``own_start``)."""
+        if t0 is not None:
+            seconds = t0 + seconds - self.own_start(t0, t0 + seconds)
         self.spec_block_seconds.observe(seconds)
         valid = np.asarray(n_acc).ravel()
         mask = valid >= 0
@@ -934,7 +942,12 @@ class ServingTelemetry:
     def trace_rounds(self, guid: int, committed_per_round, block_t0: float,
                      block_dur: float, rounds_in_block: int):
         """Per-request round events reconciled from a fused block;
-        ``committed_per_round`` is [(round_idx, n_accepted, committed)]."""
+        ``committed_per_round`` is [(round_idx, n_accepted, committed)].
+        The rounds are spread over the block's own time (``own_start``),
+        so none lies over the prefill step the block was launched behind."""
+        end = block_t0 + block_dur
+        block_t0 = self.own_start(block_t0, end)
+        block_dur = end - block_t0
         for k, n, c in committed_per_round:
             self.tracer.decode_round(guid, k, n, c, block_t0, block_dur,
                                      rounds_in_block)

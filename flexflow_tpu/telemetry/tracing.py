@@ -179,13 +179,17 @@ class SpanTracer:
         ann.__enter__()
         return (name, ann, args, time.perf_counter())
 
-    def end(self, span, **args):
+    def end(self, span, t0: Optional[float] = None, **args):
         """Close ``span`` and record it as one complete event; ``args``
-        join those given to ``begin``."""
-        name, ann, args0, t0 = span
-        dur = time.perf_counter() - t0
+        join those given to ``begin``. ``t0``: where the recorded event
+        starts, if later than ``begin`` (a device call's span that had to
+        wait for the call before it: ``ServingTelemetry.own_start``); the
+        profiler's annotation keeps the whole."""
+        name, ann, args0, began = span
+        now = time.perf_counter()
         ann.__exit__(None, None, None)
-        self.emit(name, "X", ts_s=t0, dur_s=dur, **args0, **args)
+        t0 = began if t0 is None else t0
+        self.emit(name, "X", ts_s=t0, dur_s=now - t0, **args0, **args)
 
     def profiler_mark(self) -> str:
         """Tie this tracer's clock to a running ``jax.profiler`` session:
@@ -209,17 +213,20 @@ class SpanTracer:
                   max_new_tokens=max_new_tokens)
 
     def prefill(self, guid: int, start_pos: int, n_tokens: int,
-                ts_s: float, dur_s: float):
+                ts_s: float, dur_s: float, model: str = "llm"):
+        """``model``: whose cache the step filled, ``llm`` (the model that
+        is verified, or decodes alone) or ``ssm<i>`` (draft ``i``). The
+        same on every request's copy of the span."""
         self.emit("prefill", "X", guid, ts_s=ts_s, dur_s=dur_s,
                   request_guid=guid,
-                  start_pos=start_pos, n_tokens=n_tokens)
+                  start_pos=start_pos, n_tokens=n_tokens, model=model)
 
     def decode_block(self, guid: int, steps: int, ts_s: float,
                      dur_s: float, rows: int, width: int = 1, **extra):
         """``rows``: the block's live rows; ``width``: the tokens a row
         each step computed (one of them real; all of them where the model
         fills blocks by diffusion: its block length, a pass computing two
-        blocks a row; its blocks also carry ``committed`` and ``folded``).
+        blocks a row; its blocks also carry ``committed``).
         The same on every request's copy of the span."""
         self.emit("decode_block", "X", guid, ts_s=ts_s, dur_s=dur_s,
                   request_guid=guid, steps=steps, rows=rows, width=width,

@@ -525,7 +525,7 @@ def test_phase_span_readers_on_a_hand_built_trace(metric, capsys):
         assert "rounds asked 2.500" in out and "'catch_up': 1" in out
 
 
-# the four readers this PR adds, on a hand-built traced stretch of 100 ms
+# the four readers of ISSUE 37, on a hand-built traced stretch of 100 ms
 # (microseconds after its start): a round of two lagged prefill steps and a
 # block, a round of one step whose staging found the device idle, and a
 # block that runs over the stretch's end
@@ -567,42 +567,110 @@ _LAG_RECORDS = [{"n_out": 10, "submit": 1.0, "finish": 3.0},
                 {"n_out": 20, "submit": 4.0, "finish": 8.0}]
 
 
-def _lag_ctx(spans):
-    return dict(_hand_ctx(spans, _LAG_BUSY), records=_LAG_RECORDS, w0=2.0,
+# the three readers of ISSUE 52, on a hand-built traced stretch of 100 ms of
+# the fused speculation loop: a prompt round (the verifier's step, the
+# draft's, the block launched behind it), a round whose block is launched
+# alone, and a round whose prefill step runs over the stretch's end
+_SPEC_ROUNDS = [
+    ("sched_round", 1000, 55000, {"loop": "spec_tree", "cut": "prefill"}),
+    ("call_stage", 2000, 3000, {"program": "prefill"}),
+    ("call_launch", 3000, 4000, {"program": "prefill"}),
+    ("call_stage", 5000, 6000, {"program": "prefill"}),
+    ("call_launch", 6000, 7000, {"program": "prefill"}),
+    ("call_wait", 7000, 14000, {"program": "prefill"}),
+    ("prefill", 2000, 14000, {"n_tokens": 8, "model": "llm"}),
+    ("prefill", 2000, 14000, {"n_tokens": 8, "model": "llm"}),  # 2nd request
+    ("call_stage", 15000, 18000, {"program": "spec_block"}),
+    ("call_launch", 18000, 19000, {"program": "spec_block"}),
+    ("call_wait", 19000, 24000, {"program": "prefill"}),
+    ("prefill", 14000, 24000, {"n_tokens": 16, "model": "ssm0"}),
+    ("call_wait", 24000, 50000, {"program": "spec_block"}),
+    ("spec_block", 24000, 50000, {"rounds_asked": 1, "rounds": 1, "rows": 2,
+                                  "behind": "prefill"}),
+    ("sched_round", 60000, 95000, {"loop": "spec_tree"}),
+    ("spec_block", 61000, 92000, {"rounds_asked": 4, "rounds": 3, "rows": 2,
+                                  "behind": None}),
+    ("call_stage", 61000, 63000, {"program": "spec_block"}),
+    ("call_launch", 63000, 64000, {"program": "spec_block"}),
+    ("call_wait", 64000, 92000, {"program": "spec_block"}),
+    ("sched_round", 96000, 105000, {"loop": "spec_tree", "cut": "prefill"}),
+    ("prefill", 97000, 104000, {"n_tokens": 8, "model": "llm"}),
+]
+_SPEC_BUSY = [(3500, 23500), (24000, 49500), (64500, 91500), (98500, 99500)]
+_SPEC_EXPECTED = {
+    "spec_prefill_step_ms": 10.5,           # the draft's step: 9.5
+    # busy inside the two steps of the stretch over the stretch's 73.5 ms
+    "spec_prompt_share": 100 * (10.5 + 9.5) / 73.5,
+    # staged behind the draft's step: nothing; alone: 61-64 ms of a device
+    # that starts at 64.5
+    "spec_stage_idle_ms": (0.0 + 3.0) / 2,
+}
+_SPEC_SAID = {
+    "spec_prefill_step_ms": "ssm0 x1 9.500",
+    "spec_prompt_share": "llm 10.5, ssm0 9.5; rounds with a prefill step 1, "
+                         "without 1",
+    "spec_stage_idle_ms": "behind a prefill step x1 0.000, alone x1 3.000",
+}
+
+
+def _lag_ctx(spans, busy=_LAG_BUSY):
+    return dict(_hand_ctx(spans, busy), records=_LAG_RECORDS, w0=2.0,
                 w1=6.0)
 
 
-@pytest.mark.parametrize("metric", sorted(_LAG_EXPECTED))
+def _without(spans, *keys):
+    return [(n, a, b, {k: v for k, v in args.items() if k not in keys})
+            for n, a, b, args in spans]
+
+
+@pytest.mark.parametrize("metric",
+                         sorted(_LAG_EXPECTED) + sorted(_SPEC_EXPECTED))
 def test_lag_readers_on_a_hand_built_trace(metric, capsys):
     """Known spans, busy intervals and records give known numbers; a
-    program without the source (the parent: no ``rows``; one before ISSUE
-    24: no leaves; an untraced context) gives None."""
+    program without the source (the parent: no ``rows``, or no ``model``
+    and no ``behind``; one before ISSUE 24: no leaves; an untraced context)
+    gives None."""
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     sys.path.insert(0, root)
     try:
         from benchmark.run import load_module
 
         read = load_module("layer_metrics", metric).read
-        assert read(_lag_ctx(_LAG_ROUNDS)) == pytest.approx(_LAG_EXPECTED[metric])
-        parent = [(n, a, b, {k: v for k, v in args.items() if k != "rows"})
-                  for n, a, b, args in _LAG_ROUNDS]
-        bare = [s for s in parent if s[0] == "decode_block"]
-        missing = {"prefill_step_ms": _lag_ctx(bare),
-                   "prefill_stage_idle_ms": _lag_ctx(bare),
-                   "decode_rows_per_block": _lag_ctx(parent),
-                   "traced_output_tok_s": {"records": [], "w0": 2.0,
-                                           "w1": 6.0}}[metric]
-        assert read(missing) is None
-        assert read({"trace": None}) is None
-        if metric != "decode_rows_per_block":   # the parent's spans do
-            assert read(_lag_ctx(parent)) == pytest.approx(
+        if metric in _SPEC_EXPECTED:
+            assert read(_lag_ctx(_SPEC_ROUNDS, _SPEC_BUSY)) == pytest.approx(
+                _SPEC_EXPECTED[metric])
+            parent = _without(_SPEC_ROUNDS, "model", "behind")
+            assert read(_lag_ctx(parent, _SPEC_BUSY)) is None
+            # the incremental loop's spans: a ``model``, no ``spec_block``
+            incr = [(n, a, b, dict(args, model="llm") if n == "prefill"
+                     else args) for n, a, b, args in _LAG_ROUNDS]
+            assert read(_lag_ctx(incr)) == {
+                "spec_prefill_step_ms": pytest.approx(
+                    _LAG_EXPECTED["prefill_step_ms"]),
+                "spec_prompt_share": pytest.approx(100 * 25.0 / 68.0),
+                "spec_stage_idle_ms": None}[metric]
+        else:
+            assert read(_lag_ctx(_LAG_ROUNDS)) == pytest.approx(
                 _LAG_EXPECTED[metric])
+            parent = _without(_LAG_ROUNDS, "rows")
+            bare = [s for s in parent if s[0] == "decode_block"]
+            missing = {"prefill_step_ms": _lag_ctx(bare),
+                       "prefill_stage_idle_ms": _lag_ctx(bare),
+                       "decode_rows_per_block": _lag_ctx(parent),
+                       "traced_output_tok_s": {"records": [], "w0": 2.0,
+                                               "w1": 6.0}}[metric]
+            assert read(missing) is None
+            if metric != "decode_rows_per_block":   # the parent's spans do
+                assert read(_lag_ctx(parent)) == pytest.approx(
+                    _LAG_EXPECTED[metric])
+        assert read({"trace": None}) is None
     finally:
         sys.path.remove(root)
     out = capsys.readouterr().out
     assert all(line.startswith("# ") for line in out.splitlines())
     if metric == "prefill_stage_idle_ms":
         assert "first step x2 2.250, later steps x1 0.000" in out
+    assert _SPEC_SAID.get(metric, "") in out
 
 
 # ---------------------------------------------------------------------------
@@ -643,16 +711,14 @@ def test_diffusion_counters_and_span_attributes_hand_counted():
         value = lambda name: snap[name]["value"]
         assert value("ffsv_diffusion_row_passes_total") == 7 + 4
         assert value("ffsv_diffusion_commit_passes_total") == 0
-        assert value("ffsv_diffusion_folded_commits_total") == 1 + 1
         assert value('ffsv_diffusion_tokens_total{by="floor"}') == 3 + 4 + 4
         assert value('ffsv_diffusion_tokens_total{by="threshold"}') == 0
         assert value("ffsv_decode_steps_total") == 7 + 4
         assert value("ffsv_decode_width") == 4
         blocks = [e["args"] for e in tel.tracer.events
                   if e["name"] == "decode_block"]
-        assert [(b["steps"], b["rows"], b["width"], b["committed"],
-                 b["folded"]) for b in blocks] == [(7, 1, 4, 8, 1),
-                                                   (4, 1, 4, 4, 1)]
+        assert [(b["steps"], b["rows"], b["width"], b["committed"])
+                for b in blocks] == [(7, 1, 4, 8), (4, 1, 4, 4)]
         assert not any("commits" in b for b in blocks)
     finally:
         disable_telemetry()
