@@ -442,7 +442,7 @@ def phase_serving(g: Geometry, tp: int, meter: CompileMeter):
     # draft's own prefill step may compile here
     names = [n for _, n in out["specinfer"]["programs_compiled"]]
     out["specinfer"]["verifier_programs_reused"] = (
-        names.count("jit(_step_impl)") <= 1 and "jit(block)" not in names)
+        names.count("jit(_prefill_impl)") <= 1 and "jit(block)" not in names)
     log(f"  specinfer: {out['specinfer']}")
     out["attention"] = {"fast_path_traces": ffk.fast_path_count,
                         "fallback_traces": dict(ffk.fallback_counts),

@@ -119,7 +119,12 @@ def forward_with_meta(model, params, state, meta, rng, compute_dtype,
     the KV append can skip the padding columns entirely. ``phase``: the
     program that runs the step says what the step is where its width does
     not ("decode": a block-diffusion pass; ops/moe._step_tokens). ``outputs``:
-    the tensors to hand back, as a tuple, in place of the final one.
+    the tensors to hand back, as a tuple, in place of the final one: what
+    no output is computed from is traced and then dropped as dead code,
+    by jit before it lowers and by XLA. That is how the output-free step
+    (InferenceManager._prefill_impl) leaves the graph's tail out: its
+    outputs are the inputs of ``_tail_of(model)``, the last layer's hidden
+    state at every position of the step, so the last layer stays whole.
     ``narrow``: a (layer, fn) whose layer is given ``fn`` of its inputs (a
     pass that is two blocks wide hands the model's tail, the head with it,
     the one block a row reads: ``_diffusion_block``)."""

@@ -64,6 +64,7 @@ class InferenceManager:
         cfg = model.config
         self._compute_dtype = jnp.dtype(cfg.compute_dtype)
         self._step = jax.jit(self._step_impl, donate_argnums=(1,))
+        self._prefill = jax.jit(self._prefill_impl, donate_argnums=(1,))
         self._rng = jax.random.PRNGKey(cfg.seed)
         self._decode_block = None
         self._decode_block_width = 0    # the width _decode_block was built at
@@ -101,18 +102,38 @@ class InferenceManager:
         return forward_with_meta(self.model, params, op_state, meta, rng,
                                  self._compute_dtype)
 
+    def _prefill_impl(self, params, op_state, meta, rng):
+        """The output-free step's program: the graph up to its tail
+        (engine._tail_of: final norm, head, pick), whose inputs it hands
+        back where ``_step_impl`` hands back the pick. A result of a
+        program is something XLA must compute, so a step whose pick nobody
+        reads leaves the head out only as a program of its own. Handed
+        back WHOLE, every position of the step: what a pipeline stage of a
+        deployment hands the next, so the last layer is all there (a slice
+        would let XLA thin it), and what the step's timer waits on."""
+        from flexflow_tpu.serve.engine import _tail_of, forward_with_meta
+
+        return forward_with_meta(self.model, params, op_state, meta, rng,
+                                 self._compute_dtype,
+                                 outputs=_tail_of(self.model).inputs)
+
     def step(self, meta, want_output: bool = True, tel=None, rnd=None):
         """Run one serving step; threads the model's KV caches through.
 
         Returns the op outputs (token ids [R, Q] for graphs ending in
         argmax/sampling). The model's op_state is replaced (old state was
-        donated to the device program). ``want_output=False`` skips the
-        blocking device->host readback — prefill chunks whose outputs are
-        discarded dispatch asynchronously and overlap with the host
-        building the next batch: such a step hands back its output as the
-        device's future, which is not donated onward as the op_state is, so
-        whoever times the step can wait on it (never read it) after later
-        calls have been launched. ``tel`` (a ServingTelemetry; None: no
+        donated to the device program). ``want_output=False`` (a prefill
+        chunk: the scheduler holds a prompt's last token back and the
+        decode block emits the first) runs ``_prefill_impl``, a program
+        without the graph's tail, which writes the caches ``_step_impl``
+        writes, bit for bit, and computes no logits; it skips the blocking
+        device->host readback too, so the step dispatches asynchronously
+        and overlaps with the host building the next batch. Such a step
+        hands back the last layer's hidden state ``[rows, chunk, hidden]``
+        (a tuple of the tail's inputs) as the device's future, which is not
+        donated onward as the op_state is, so whoever times the step can
+        wait on it (never read it) after later calls have been launched.
+        ``tel`` (a ServingTelemetry; None: no
         spans) records the call's ``call_stage`` / ``call_launch`` /
         ``call_wait`` leaves; an output-free step is program ``prefill``
         and its wait is its caller's (telemetry.PendingPrefill). ``rnd``:
@@ -134,8 +155,9 @@ class InferenceManager:
             self._debug_step += 1
         if tel is not None:
             ph = tel.call_phase(ph, "call_launch", prog)
-        out, new_state = self._step(self.model.params, self.model.op_state,
-                                    meta, step_rng)
+        program = self._step if want_output else self._prefill
+        out, new_state = program(self.model.params, self.model.op_state,
+                                 meta, step_rng)
         self.model.op_state = new_state
         if not want_output:
             if tel is not None:

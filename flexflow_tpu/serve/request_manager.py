@@ -621,10 +621,15 @@ class RequestManager:
 
     def _timed_prefill(self, ifm, meta, tel, rows, active, rnd=None,
                        model="llm"):
-        """One prefill step. Its outputs are discarded (want_output=False
-        dispatches asynchronously and is forgotten); with telemetry on a
-        wait on the small output its program hands back times the step and
-        records its spans and counters (telemetry.PendingPrefill).
+        """One prefill step. Nobody reads a pick of it (the scheduler holds
+        a prompt's last token back, ``_held_back``, and the decode block
+        emits the first), so it runs the output-free program, which ends at
+        the last layer's hidden state and computes no logits
+        (InferenceManager.step, ``want_output=False``: dispatched
+        asynchronously and forgotten); with telemetry on a wait on that
+        hidden state, the step's own output, never read and never off the
+        chip, times the step and records its spans and counters
+        (telemetry.PendingPrefill).
 
         ``rows``/``active`` feed per-request prefill spans, which carry
         ``model`` (``llm``, or ``ssm<i>`` for draft ``i``). ``rnd`` is

@@ -162,8 +162,9 @@ contract).
 Timing honesty: block/step timings are recorded by the serving loop
 AROUND device calls whose results the host has waited for (``np.asarray``
 of the packed block output; for an output-free prefill step a
-``block_until_ready`` on the small output its program hands back beside the
-donated op_state: ``PendingPrefill``), so a recorded time never measures
+``block_until_ready`` on the output its program hands back beside the
+donated op_state, the last layer's hidden state, which stays on the
+device: ``PendingPrefill``), so a recorded time never measures
 the enqueue alone (utils/profiling.py protocol). Both loops that serve
 traffic (incremental; fused speculation) make a prefill step's wait only
 after the round's NEXT device call has been launched (the next step, the
@@ -292,8 +293,8 @@ class RoundTrace:
 
 class PendingPrefill:
     """A prefill step between its launch and the wait that times it. The
-    step's program hands back a small output beside the op state it donates
-    onward (``InferenceManager.step``): ``settle`` waits on that output,
+    step's program hands back its last hidden state beside the op state it
+    donates onward (``InferenceManager.step``): ``settle`` waits on that output,
     never reads it, and records the step, its spans and its counters
     together. WHEN is the loop's choice: at once (the host-stepped
     speculation loop, a driver without a ``RoundTrace``), or after the next

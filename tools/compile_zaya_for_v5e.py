@@ -1,6 +1,9 @@
 #!/usr/bin/env python3
 """Compile a cut of ``zaya1-8b`` at the published widths for a DESCRIBED v5e,
-without the chip: the compact prefill step and the decode block.
+without the chip: the compact prefill step, as the loops run it (output-free:
+``InferenceManager._prefill_impl``, which ends at the last layer's hidden
+state) and whole (``_step_impl``: the final norm, the head and the pick
+behind it), and the decode block.
 
     python3 tools/compile_zaya_for_v5e.py [layers, default 2] [--unfenced]
 
@@ -13,7 +16,10 @@ libtpu 0.0.34 segfaulted on the router's four fused gemms until
 ``models/zaya.RouterBarrier`` stood in the middle of them; ``--unfenced``
 compiles the programs WITHOUT that barrier, and a compiler that takes them
 so no longer needs it: ROADMAP R7 (e)), what they keep in memory, how many
-Mosaic calls they hold and whether a large array is copied or transposed
+Mosaic calls and operations they hold (the entry computation's
+instructions and XLA's own count of their arithmetic: what the head costs a
+prefill step is the difference of the two prefill programs, PERF.md section
+6, PR 53) and whether a large array is copied or transposed
 (the tied head reads the embedding's table with no relayout; each layer's
 ``gate`` stack is kept in VMEM, fetched in four async slices: PERF.md
 section 6, PR 50). The optimised HLO lands under ``chiprun_out/``.
@@ -51,7 +57,8 @@ def main(argv=None) -> int:
     from benchmark.run import load_module
     from flexflow_tpu.ffconst import InferenceMode
     from flexflow_tpu.models.zaya import create_zaya_model
-    from flexflow_tpu.serve.engine import forward_with_meta, make_decode_block
+    from flexflow_tpu.serve.engine import make_decode_block
+    from flexflow_tpu.serve.inference_manager import InferenceManager
     from flexflow_tpu.serve.request_manager import RequestManager as RM
 
     if unfenced:
@@ -89,13 +96,14 @@ def main(argv=None) -> int:
     meta = jax.tree.map(aval, RM._meta_from_segments(segments, chunk, [
         (1, [1] * chunk, 0), (1, [1] * chunk, chunk), (2, [1] * 5, 640)]))
     i32 = np.zeros(R, np.int32)
+    rng = aval(np.zeros(2, np.uint32))
+    ifm = InferenceManager(m)
     programs = (
-        ("prefill", jax.jit(
-            lambda p, s, meta: forward_with_meta(m, p, s, meta, None, cdt),
-            donate_argnums=(1,)), (params, state, meta)),
+        ("prefill", ifm._prefill, (params, state, meta, rng)),
+        ("prefill_whole", ifm._step, (params, state, meta, rng)),
         ("decode", make_decode_block(m, cdt, m.config.decode_block_steps),
-         (params, state, aval(i32), aval(i32), aval(i32 > 0),
-          aval(np.zeros(2, np.uint32)), aval(np.int32(0)))))
+         (params, state, aval(i32), aval(i32), aval(i32 > 0), rng,
+          aval(np.int32(0)))))
     os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
     for name, fn, args in programs:
         t = time.time()
@@ -113,6 +121,13 @@ def main(argv=None) -> int:
                                 hit.group(1).split(",")]) > BIG:
                 print("   large", line.strip()[:200])
         print("   Mosaic calls:", text.count('custom_call_target="tpu_custom_call"'))
+        entry = text[text.index("\nENTRY "):]
+        entry = entry[:entry.index("\n}")].splitlines()[2:]
+        cost = compiled.cost_analysis()
+        print(f"   operations: {len(entry)} in the entry computation, "
+              f"{sum(' fusion(' in line for line in entry)} of them fusions; "
+              f"{cost['flops'] / 1e12:.3f} TFLOP and "
+              f"{cost['bytes accessed'] / 1e9:.2f} GB by XLA's count")
     return 0
 
 
