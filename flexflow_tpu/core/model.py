@@ -316,8 +316,8 @@ class FFModel:
                            sliding_window: Optional[int] = None,
                            block_length: Optional[int] = None,
                            eva_window: Optional[int] = None,
-                           chunk_size: Optional[int] = None
-                           ) -> Tensor:
+                           chunk_size: Optional[int] = None,
+                           output_gate: bool = False) -> Tensor:
         if eva_window is not None:
             self._check_chunked(op_type, eva_window, chunk_size,
                                 sliding_window, block_length, position_bias)
@@ -375,7 +375,9 @@ class FFModel:
             # (ops/kv_layout.py ``chunked_*``)
             **({} if eva_window is None else
                {"eva_window": int(eva_window),
-                "chunk_size": int(chunk_size)})),
+                "chunk_size": int(chunk_size)}),
+            # ``W_o (sigmoid(W_g x) * Attn)``: a gate as wide as the heads
+            **({"output_gate": True} if output_gate else {})),
             name)
 
     def _check_chunked(self, op_type, window, chunk, sliding_window,
@@ -475,6 +477,30 @@ class FFModel:
             use_pallas=self.config.use_pallas,
             cache_dtype=self.config.kv_cache_dtype), name)
 
+    def inc_kda_attention(self, input: Tensor, embed_dim: int,
+                          num_heads: int, head_dim: int,
+                          conv_kernel: int = 4, gate_rank: Optional[int] = None,
+                          norm_eps: float = 1e-5,
+                          data_type: Optional[DataType] = None,
+                          kernel_initializer=None, name=None) -> Tensor:
+        """Attention by a gated delta rule with one decay a key channel for
+        incremental decoding (ops/kda_attention.py, imported here: only a
+        model that has such a layer loads it): ``num_heads`` heads whose
+        keys and values are ``head_dim`` wide, a depthwise causal
+        convolution of ``conv_kernel`` taps on q, k and v, the decay and the
+        output gate through ``gate_rank`` (default ``head_dim``). A slot
+        keeps a recurrent state ``[num_heads, head_dim, head_dim]`` and the
+        convolutions' tails, both float32; no cache of positions."""
+        from flexflow_tpu.ops import kda_attention  # noqa: F401 (registers)
+
+        return self._add_layer(OpType.INC_KDA_ATTENTION, [input], dict(
+            embed_dim=embed_dim, num_heads=num_heads, head_dim=head_dim,
+            conv_kernel=int(conv_kernel),
+            gate_rank=int(gate_rank or head_dim), norm_eps=float(norm_eps),
+            data_type=data_type, kernel_initializer=kernel_initializer,
+            max_requests=self.config.max_requests_per_batch,
+            use_pallas=self.config.use_pallas), name)
+
     def inc_multihead_self_attention(self, input: Tensor, embed_dim: int,
                                      num_heads: int, **kw) -> Tensor:
         return self.inc_multiquery_self_attention(input, embed_dim, num_heads,
@@ -495,8 +521,11 @@ class FFModel:
             sliding_window: Optional[int] = None,
             block_length: Optional[int] = None,
             eva_window: Optional[int] = None,
-            chunk_size: Optional[int] = None) -> Tensor:
-        """``eva_window``, ``chunk_size``: a chunked (EVA) layer: a query
+            chunk_size: Optional[int] = None,
+            output_gate: bool = False) -> Tensor:
+        """``output_gate``: the heads' output times ``sigmoid(W_g x)``,
+        elementwise, before the output projection (a weight ``wg``).
+        ``eva_window``, ``chunk_size``: a chunked (EVA) layer: a query
         sees its own window of ``eva_window`` positions exactly and one
         learned summary pair for every ``chunk_size`` positions of the
         windows before, in one softmax, from a cache that keeps both
@@ -514,7 +543,7 @@ class FFModel:
             apply_rotary_embedding, scaling_query, scaling_factor,
             qk_prod_scaling, position_bias, rope_theta, name, qk_norm_eps,
             qk_norm_per_head, sliding_window, block_length, eva_window,
-            chunk_size)
+            chunk_size, output_gate)
 
     def spec_inc_multihead_self_attention(self, input: Tensor, embed_dim: int,
                                           num_heads: int, **kw) -> Tensor:
@@ -1362,9 +1391,47 @@ class FFModel:
         """
         from flexflow_tpu.ops.inc_attention import (CHUNKED_STACK,
                                                     FULL_STACK, LATENT_STACK,
+                                                    RECURRENT_STACK,
                                                     TAIL_STACK, WINDOW_STACK)
 
         by_name = {layer.name: layer for layer in self.layers}
+        recurrent = [n for n, st in self.op_state.items()
+                     if isinstance(st, dict) and "kda_s" in st]
+        if recurrent:
+            # layers that keep a recurrent state and no cache of positions
+            # (ops/kda_attention.py): the states and the convolutions' tails
+            # are one stack each at any depth, beside whatever the model's
+            # other attention layers keep (plain k/v caches, below)
+            stack = {}
+            for member, key in (("s", "kda_s"), ("u", "kda_u")):
+                (shape,) = {self.op_state[n][key].shape for n in recurrent}
+                (dtype,) = {self.op_state[n][key].dtype for n in recurrent}
+                stack[member] = jnp.zeros((len(recurrent),) + shape, dtype)
+            for i, n in enumerate(recurrent):
+                by_name[n].attrs["state_layer_idx"] = i
+                del self.op_state[n]
+            self.op_state[RECURRENT_STACK] = stack
+            plain = [n for n, st in self.op_state.items()
+                     if isinstance(st, dict) and "k_cache" in st]
+            if any(by_name[n].attrs.get(k) is not None for n in plain
+                   for k in ("sliding_window", "eva_window")):
+                raise NotImplementedError(
+                    "layers that keep a recurrent state beside windowed or "
+                    "chunked attention layers in one model")
+            # what telemetry says of the two kinds (ffsv_kv_cache_bytes,
+            # ffsv_attn_positions_read_total{kind="full"},
+            # ffsv_kda_state_steps_total, ffsv_kda_states_total), and what
+            # says "this model keeps a recurrent state"
+            self.attention_kinds = {
+                "full": {"layers": len(plain), "window": None,
+                         "cache_bytes": sum(
+                             2 * self.op_state[n]["k_cache"].nbytes
+                             for n in plain)},
+                "recurrent": {"layers": len(recurrent), "window": None,
+                              "cache_bytes": sum(a.nbytes
+                                                 for a in stack.values()),
+                              "state_bytes": stack["s"].nbytes,
+                              "conv_bytes": stack["u"].nbytes}}
         tails = [n for n, st in self.op_state.items()
                  if isinstance(st, dict) and "tail" in st]
         if tails:
@@ -1432,9 +1499,9 @@ class FFModel:
         elif rings:
             kinds = {FULL_STACK: [n for n in names if n not in rings],
                      WINDOW_STACK: rings}
-        elif len(names) < 2:
+        elif len(names) < 2 and not (names and recurrent):
             return
-        else:
+        else:               # (beside recurrent layers even one is a stack)
             kinds = {FULL_STACK: names}
         if rings:
             # what telemetry says of the two kinds (ffsv_kv_cache_bytes,

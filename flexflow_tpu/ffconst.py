@@ -199,3 +199,6 @@ class OpType(enum.Enum):
     # serving attention in a convolved latent, with a tail a slot
     # (ops/cca_attention.py)
     INC_MULTIHEAD_CCA_ATTENTION = enum.auto()
+    # serving attention by a gated delta rule, a recurrent state a slot
+    # (ops/kda_attention.py)
+    INC_KDA_ATTENTION = enum.auto()

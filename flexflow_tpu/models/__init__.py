@@ -2,7 +2,7 @@
 
 Capability parity with the reference model zoo (reference inference/models/
 llama.cc, opt.cc, falcon.cc, mpt.cc, starcoder.cc and their Python twins in
-python/flexflow/serve/models/; OLMoE, EXAONE-MoE, Mistral-4, SDAR-MoE, LongCat-Flash and ZAYA1, sparse-expert families, and EvaByte, a byte-level model of chunked attention, are beyond it): each model family is a builder that records
+python/flexflow/serve/models/; OLMoE, EXAONE-MoE, Mistral-4, SDAR-MoE, LongCat-Flash, ZAYA1 and Solar-Open2, sparse-expert families, and EvaByte, a byte-level model of chunked attention, are beyond it): each model family is a builder that records
 the decoder graph through the FFModel op-builder surface, plus a HuggingFace
 state-dict name mapping so real checkpoints load. ``FAMILIES`` maps the HF
 ``model_type`` to the family (the reference's ModelType enum +
@@ -22,6 +22,7 @@ from flexflow_tpu.models import mpt as _mpt
 from flexflow_tpu.models import olmoe as _olmoe
 from flexflow_tpu.models import opt as _opt
 from flexflow_tpu.models import sdar_moe as _sdar_moe
+from flexflow_tpu.models import solar_open2 as _solar_open2
 from flexflow_tpu.models import starcoder as _starcoder
 from flexflow_tpu.models import zaya as _zaya
 from flexflow_tpu.models.evabyte import EvaByteConfig, create_evabyte_model
@@ -38,6 +39,8 @@ from flexflow_tpu.models.mpt import MPTConfig, create_mpt_model
 from flexflow_tpu.models.olmoe import OLMoEConfig, create_olmoe_model
 from flexflow_tpu.models.opt import OPTConfig, create_opt_model
 from flexflow_tpu.models.sdar_moe import SDARMoEConfig, create_sdar_moe_model
+from flexflow_tpu.models.solar_open2 import (SolarOpen2Config,
+                                             create_solar_open2_model)
 from flexflow_tpu.models.starcoder import (STARCODERConfig,
                                            create_starcoder_model)
 from flexflow_tpu.models.zaya import ZayaConfig, create_zaya_model
@@ -98,6 +101,11 @@ FAMILIES = {
                                _starcoder.preprocess_hf_state_dict),
     # its attention op (ops/cca_attention.py) is imported by the builder
     # call that records such a layer, not here
+    # (so is this one's ops/kda_attention.py)
+    "solar_open2": ModelFamily("solar_open2", SolarOpen2Config,
+                               create_solar_open2_model,
+                               _solar_open2.hf_weight_map,
+                               _solar_open2.preprocess_hf_state_dict),
     "zaya": ModelFamily("zaya", ZayaConfig, create_zaya_model,
                         _zaya.hf_weight_map,
                         _zaya.preprocess_hf_state_dict),
@@ -132,6 +140,7 @@ __all__ = [
     "OPTConfig",
     "SDARMoEConfig",
     "STARCODERConfig",
+    "SolarOpen2Config",
     "ZayaConfig",
     "create_evabyte_model",
     "create_exaone_moe_model",
@@ -143,6 +152,7 @@ __all__ = [
     "create_olmoe_model",
     "create_opt_model",
     "create_sdar_moe_model",
+    "create_solar_open2_model",
     "create_starcoder_model",
     "create_zaya_model",
     "family_for_hf_config",

@@ -57,7 +57,8 @@ from flexflow_tpu.ffconst import OpType
 from flexflow_tpu.ops.base import OpImpl, register_op
 from flexflow_tpu.ops.inc_attention import (TAIL_STACK, _attend, _init_kv_state,
                                             _stack, append_and_ref,
-                                            apply_partial_rotary, write_kv)
+                                            apply_partial_rotary, carried_rows,
+                                            write_kv)
 
 
 def _dims(attrs):
@@ -151,24 +152,16 @@ def take_tails(stored, slots, start, n, u, v2):
         ext_u, ext_v = with_head(fresh(stored), u, v2)
         ends = jax.vmap(end_of)(ext_u, ext_v, n).astype(stored.dtype)
         return ext_u, ext_v, jnp.where((n > 0)[:, None], ends, stored)
-    # a handful of rows (the step's segments), in order and unrolled: a row
-    # that continues an earlier row of its slot starts from that row's end,
-    # not from the state
-    heads = fresh(stored[slots])
-    runs, ends = [], []
-    for i in range(R):
-        t = heads[i]
-        for j in range(i):
-            t = jnp.where((n[i] > 0) & (n[j] > 0) & (slots[j] == slots[i])
-                          & (start[j] + n[j] == start[i]), ends[j], t)
-        runs.append(with_head(t, u[i], v2[i]))
-        ends.append(end_of(*runs[-1], n[i]))
-    same = (slots[:, None] == slots[None, :]) & (n[None, :] > 0)
-    last = ~jnp.any(same & (start[None, :] > start[:, None]), axis=1)
-    at = jnp.where((n > 0) & last, slots, stored.shape[0])
+    # a handful of rows (the step's segments), in order: a row that
+    # continues an earlier row of its slot starts from that row's end, not
+    # from the state (the rule any carried state obeys: carried_rows)
+    def run(i, t):
+        ext = with_head(t.astype(u.dtype), u[i], v2[i])
+        return ext, end_of(*ext, n[i])
+
+    runs, kept = carried_rows(stored, slots, start, n, run)
     return (jnp.stack([r[0] for r in runs]), jnp.stack([r[1] for r in runs]),
-            stored.at[at].set(jnp.stack(ends).astype(stored.dtype),
-                              mode="drop"))
+            kept)
 
 
 def mix(attrs, params, ext_u, positions):

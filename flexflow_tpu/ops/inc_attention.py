@@ -518,6 +518,10 @@ def _weight_specs(attrs, input_specs):
                        shard_multiples=(D,)),
             WeightSpec("bo", (E,), dt, zero),
         ]
+    if attrs.get("output_gate"):
+        specs.append(WeightSpec("wg", (E, H * D), dt, init,
+                                sharding_dims=(None, "model"),
+                                shard_multiples=(None, D)))
     if attrs.get("qk_norm_eps") is not None:
         from flexflow_tpu.core.initializer import ConstantInitializer
 
@@ -618,9 +622,13 @@ def _init_kv_state(attrs, input_specs):
     }
 
 
-def _project_out(attrs, params, ctx, attn_out):
+def _project_out(attrs, params, ctx, attn_out, x=None):
     from flexflow_tpu.quant import qmatmul
 
+    if "wg" in params:      # ``output_gate``: sigmoid(W_g x) * Attn, then W_o
+        attn_out = attn_out * jax.nn.sigmoid(
+            qmatmul(x, params["wg"]).astype(jnp.float32)).astype(
+                attn_out.dtype).reshape(attn_out.shape)
     out = qmatmul(attn_out, params["wo"])
     if "bo" in params:
         out = out + params["bo"]
@@ -650,14 +658,55 @@ CHUNKED_STACK = "kv_cache_chunked"
 # (ops/cca_attention.py) keeps, beside its plain k/v cache, a row's TAIL:
 # op_state[TAIL_STACK] = {"t": [L, R, width]}, overwritten by every step.
 TAIL_STACK = "cca_tail"
+# A layer that keeps a recurrent state and no cache of positions
+# (ops/kda_attention.py): op_state[RECURRENT_STACK] = {"s": [L, R, H, K, V]
+# float32, "u": [L, R, taps - 1, channels]}, overwritten by every step.
+RECURRENT_STACK = "kda_state"
+
+
+def carried_rows(stored, slots, start, n, run, layer=None):
+    """The rows of a compact prefill step through a state that every step
+    overwrites, in order, and what the step leaves behind: the rules of
+    ``cca_attention.take_tails`` for any state.
+
+    ``stored`` ``[slots, ...]`` (with ``layer``: ``[layers, slots, ...]``,
+    and that layer's entries are read and written); ``slots``, ``start``,
+    ``n`` ``[R]``: each row's slot, first position and real tokens (0: an
+    idle row), rows in ascending order of ``start``; ``run(i, state) ->
+    (out, end)``: row ``i`` from ``state`` to what it yields and the state
+    its last real token leaves. A row starts from zeros at position 0, from
+    the END of an earlier row of the step where that row is the same slot's
+    and ends where this one starts, else from the store; each slot's LAST
+    row's end is written back. Returns ``([out], new stored)``."""
+    R = slots.shape[0]
+    heads = stored[slots] if layer is None else stored[layer, slots]
+    heads = jnp.where((start == 0).reshape((R,) + (1,) * (heads.ndim - 1)),
+                      0, heads)
+    outs, ends = [], []
+    for i in range(R):      # a handful of rows, unrolled
+        t = heads[i]
+        for j in range(i):
+            t = jnp.where((n[i] > 0) & (n[j] > 0) & (slots[j] == slots[i])
+                          & (start[j] + n[j] == start[i]), ends[j], t)
+        out, end = run(i, t)
+        outs.append(out)
+        ends.append(end)
+    same = (slots[:, None] == slots[None, :]) & (n[None, :] > 0)
+    last = ~jnp.any(same & (start[None, :] > start[:, None]), axis=1)
+    at = jnp.where((n > 0) & last, slots,
+                   stored.shape[0 if layer is None else 1])
+    ends = jnp.stack(ends).astype(stored.dtype)
+    where = stored.at[at] if layer is None else stored.at[layer, at]
+    return outs, where.set(ends, mode="drop")
 
 
 def refuse_windowed(op_state, what: str):
     """A ring holds a slot's last positions only, by ``p % rows``, a latent
     layer one shared entry a position, not a k/v pair, and a layer that
-    carries a tail keeps state that no cache position holds: what moves,
-    copies, rolls back or shards cache positions by their index, a pair at
-    a time, says so."""
+    carries a tail or a recurrent state keeps what no cache position holds:
+    what moves, copies, rolls back or shards cache positions by their
+    index, a pair at a time, says so. (Preemption is none of them: the
+    victim is prefilled again from position 0, which rebuilds its state.)"""
     if WINDOW_STACK in (op_state or {}):
         raise NotImplementedError(
             f"{what} is not supported over a windowed attention layer: its "
@@ -681,6 +730,15 @@ def refuse_windowed(op_state, what: str):
             "step, which no cache position holds, so a rejected draft "
             "cannot be rolled back and a moved, shared or sharded position "
             "has no tail to go with it")
+    if RECURRENT_STACK in (op_state or {}):
+        raise NotImplementedError(
+            f"{what} is not supported over an attention layer that keeps a "
+            "recurrent state: a slot holds one state a layer "
+            "(ops/kda_attention.py), the sum of every position so far, "
+            "overwritten every step and kept at no position, so a "
+            "rejected draft cannot be rolled back, a shared prefix has no "
+            "snapshot to start from, a moved position nothing to move, "
+            "and a dividing mesh or a pipeline stage no hand-over for it")
 
 
 def refuse_block_diffusion(model, what: str):
@@ -1122,7 +1180,7 @@ class IncMultiHeadSelfAttention(OpImpl):
                 write_kv(ctx, attrs, knew, vnew)
             else:
                 ctx.state_out[key] = {"k": knew, "v": vnew}
-            return [_project_out(attrs, params, ctx, out)]
+            return [_project_out(attrs, params, ctx, out, x)]
         start = meta.start_pos
         if attrs.get("eva_window") is not None:
             # the whole chunks' pairs to the summary extent first (no query
@@ -1139,7 +1197,7 @@ class IncMultiHeadSelfAttention(OpImpl):
             ctx, attrs, k, v, start, meta.num_tokens, meta.active, slots)
         out = _attend(attrs, q, k_ref, v_ref, lengths, q_abs, x.dtype,
                       ctx, causal=True, layer_idx=layer_idx, rows=slots)
-        return [_project_out(attrs, params, ctx, out)]
+        return [_project_out(attrs, params, ctx, out, x)]
 
 
 @register_op
@@ -1203,7 +1261,7 @@ class TreeIncMultiHeadSelfAttention(OpImpl):
         out = _attend(attrs, q, k_ref, v_ref, lengths, meta.positions,
                       x.dtype, ctx, bias=bias, causal=False,
                       layer_idx=layer_idx)
-        return [_project_out(attrs, params, ctx, out)]
+        return [_project_out(attrs, params, ctx, out, x)]
 
 
 def move_kv(cache, src, dst_start, num, active, pack: int):

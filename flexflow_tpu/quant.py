@@ -143,7 +143,12 @@ _QUANT_NAMES = {"kernel", "wq", "wk", "wv", "wo", "weight",
                 # a latent attention layer's (ops/latent_attention.py)
                 "wq_a", "wq_b", "wkv_a", "wk_b", "wv_b",
                 # the joined projection of ops/cca_attention.py
-                "wqkv"}
+                "wqkv",
+                # a plain attention layer's output gate (``output_gate``)
+                "wg",
+                # ops/kda_attention.py: the joined first halves of its
+                # low-rank gates with beta's projection, and their second
+                "wlow", "wfb", "wgb"}
 # ... and of those, the ones that may be a stack [E, in, out] (ops/moe.py;
 # a latent layer's up-projection halves, a head apart)
 _STACKED_NAMES = {"gate", "up", "down", "wk_b", "wv_b"}

@@ -649,9 +649,9 @@ class RequestManager:
         if kinds and "chunked" in kinds:
             tel.note_chunked_prefill(kinds["chunked"],
                                      [(sp, len(chunk)) for _, chunk, sp in rows])
-        if kinds and "tail_bytes" in kinds.get("full", ()):
-            tel.note_prefill_tails([(slot, sp, len(chunk))
-                                    for slot, chunk, sp in rows])
+        if kinds:       # a tail or a recurrent state: where each came from
+            tel.note_prefill_tails(kinds, [(slot, sp, len(chunk))
+                                           for slot, chunk, sp in rows])
         if rnd is not None:
             rnd.phase(None)
         step = PendingPrefill(tel, [(active[slot].guid, sp, len(chunk))
@@ -748,16 +748,18 @@ class RequestManager:
            them all before any of them attends and the next window's would
            overwrite this one's rows: a segment is cut at the boundary, and
            what lies beyond waits for the next step.
-        2. A TAIL FROM THE STEP OR THE STATE for a model whose attention
-           layers carry one (ops/cca_attention.take_tails): a segment that
+        2. A TAIL OR A STATE FROM THE STEP OR THE STORE for a model whose
+           attention layers carry one (ops/cca_attention.take_tails; a
+           recurrent state: ops/inc_attention.carried_rows): a segment that
            starts where another segment of the same step, the same slot's,
-           ends takes that segment's end as its tail, any other what the
-           last step left in the slot (zeros at position 0), and the step
-           writes back each slot's last segment's. It costs the scheduler
-           nothing: the op reads it off the rows' own slots, starts and
-           lengths, in the ascending order given here
-           (``ServingTelemetry.note_prefill_tails`` counts the same on the
-           host)."""
+           ends takes that segment's END as its tail or state, any other
+           what the last step left in the slot (zeros at position 0), and
+           the step writes back each slot's last segment's. For a tail that
+           is a gather; for a recurrence it ORDERS the rows' work inside the
+           one forward. It costs the scheduler nothing: the op reads it off
+           the rows' own slots, starts and lengths, in the ascending order
+           given here (``ServingTelemetry.note_prefill_tails`` counts the
+           same on the host)."""
         rows, taken, first = [], {}, {}
 
         def pending(req):

@@ -73,6 +73,8 @@ ffsv_moe_resident_calls_total    counter    {phase} calls that kept their rows i
 ffsv_moe_expert_pairs_total      counter    {expert} routed pairs of one expert
 ffsv_moe_zero_pairs_total        counter    {phase} picks that were no expert (w * x)
 ffsv_cca_tails_total             counter    {phase,source} rows by where their tail came from
+ffsv_kda_states_total            counter    {phase,source} rows by where their recurrent state came from
+ffsv_kda_state_steps_total       counter    live rows x recurrent layers x steps of the decode blocks
 ===============================  =========  =================================
 
 A decode block's step is one token a row, or one pass over a row's block
@@ -109,6 +111,15 @@ segments and a decode block's row-steps by where their tail came from, on
 the host from the step's own rows: ``start`` (position 0: zeros), ``step``
 (another segment of the same step, the same slot's, that ends where this
 one starts) or ``state`` (what an earlier step left in the slot).
+A model with layers that keep a RECURRENT STATE (ops/kda_attention.py: a
+slot holds one state a layer, the sum of every position so far, and the
+convolutions' tails; ``attention_kinds["recurrent"]``) has
+``ffsv_kv_cache_bytes{kind="recurrent"}`` (states and tails) beside
+``kind="full"`` (its plain k/v caches, the only kind whose positions are
+read), ``ffsv_kda_states_total{phase,source}``, the twin of
+``ffsv_cca_tails_total`` (``step``: the hand-over of a state inside one
+prefill step), and ``ffsv_kda_state_steps_total``: live rows x recurrent
+layers x steps of the decode blocks, each one state read and written.
 ``ffsv_kv_cache_bytes`` is what compile allocated for each kind;
 ``ffsv_attn_positions_read_total`` is what the rows of the decode steps had
 to attend, from the batch's lengths on the host: for each row of each step
@@ -329,6 +340,18 @@ class PendingPrefill:
                            sum(n for _, _, n in self.rows), self.rows,
                            self.t0, positions=self.positions,
                            model=self.model)
+
+
+def _carried_series(kinds) -> Optional[str]:
+    """The counter of a model whose slots carry something every step
+    overwrites, by ``FFModel.attention_kinds``: a tail beside a cache
+    (ops/cca_attention.py), a recurrent state (ops/kda_attention.py), or
+    None."""
+    if "recurrent" in (kinds or ()):
+        return "ffsv_kda_states_total"
+    if "tail_bytes" in (kinds or {}).get("full", ()):
+        return "ffsv_cca_tails_total"
+    return None
 
 
 class ServingTelemetry:
@@ -564,11 +587,20 @@ class ServingTelemetry:
         at = np.asarray(lengths, np.int64)[:, None] + np.arange(steps)
         if "chunked" in kinds:
             return self._note_chunked_reads(kinds["chunked"], at)
-        if "tail_bytes" in kinds.get("full", ()):
-            # a row-step's tail is the state's, but at position 0
-            self._note_tails("decode", start=int((at == 1).sum()),
+        carried = _carried_series(kinds)
+        if carried:
+            # a row-step's tail (or state) is the stored one, but at
+            # position 0
+            self._note_tails("decode", carried, start=int((at == 1).sum()),
                              state=int((at > 1).sum()))
         for kind, a in kinds.items():
+            if kind == "recurrent":     # reads its state, not positions
+                self.registry.counter(
+                    "ffsv_kda_state_steps_total",
+                    "live rows x recurrent layers x steps of the decode "
+                    "blocks: one state read and written each"
+                    ).inc(int(at.size) * a["layers"])
+                continue
             seen = at if a["window"] is None else np.minimum(at, a["window"])
             self.registry.counter(
                 f'ffsv_attn_positions_read_total{{kind="{kind}"}}',
@@ -576,26 +608,31 @@ class ServingTelemetry:
                 ).inc(int(seen.sum()) * a["layers"])
         return {}
 
-    def _note_tails(self, phase: str, **by_source):
+    def _note_tails(self, phase: str, series: str, **by_source):
         for source, n in by_source.items():
             self.registry.counter(
-                f'ffsv_cca_tails_total{{phase="{phase}",source="{source}"}}',
-                "rows of the steps by where their tail came from (a model "
-                "whose attention layers carry one)").inc(n)
+                f'{series}{{phase="{phase}",source="{source}"}}',
+                "rows of the steps by where their tail or recurrent state "
+                "came from (a model whose attention layers carry one)"
+                ).inc(n)
 
-    def note_prefill_tails(self, runs):
+    def note_prefill_tails(self, kinds, runs):
         """A prefill step's ``runs`` [(slot, start, tokens)] over a model
-        whose attention layers carry a tail (ops/cca_attention.take_tails
-        decides the same on the device): a run at position 0 starts from
-        zeros, one that another run of the step's, the same slot's, ends in
-        front of takes that run's end, any other what an earlier step left
-        in the slot."""
+        whose attention layers carry a tail or a recurrent state (``kinds``:
+        ``FFModel.attention_kinds``; ops/inc_attention.carried_rows and
+        ops/cca_attention.take_tails decide the same on the device): a run
+        at position 0 starts from zeros, one that another run of the
+        step's, the same slot's, ends in front of takes that run's end, any
+        other what an earlier step left in the slot."""
+        series = _carried_series(kinds)
+        if not series:
+            return
         ends = {(slot, sp + n) for slot, sp, n in runs if n}
         src = ["start" if sp == 0 else
                "step" if (slot, sp) in ends else "state"
                for slot, sp, n in runs if n]
-        self._note_tails("prefill", **{s: src.count(s) for s in
-                                       ("start", "step", "state")})
+        self._note_tails("prefill", series, **{
+            s: src.count(s) for s in ("start", "step", "state")})
 
     def _chunked_counter(self, name, n, layers):
         helps = {

@@ -779,6 +779,7 @@ CELL_STREAMS = {
     "evabyte-6.5b": (32, 32, 128, 24576 // 16 + 2048, 1, 128),
     "mistral-small-4-119b": (32, 32768),
     "longcat-flash-omni": (64, 8192),
+    "solar-open2-250b": (8, 64, 128, 16384, 1, 256),    # its GQA layers
 }
 
 
