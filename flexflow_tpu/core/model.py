@@ -1402,6 +1402,8 @@ class FFModel:
             # (ops/kda_attention.py): the states and the convolutions' tails
             # are one stack each at any depth, beside whatever the model's
             # other attention layers keep (plain k/v caches, below)
+            from flexflow_tpu.ops.kda_attention import takes_chunk_kernel
+
             stack = {}
             for member, key in (("s", "kda_s"), ("u", "kda_u")):
                 (shape,) = {self.op_state[n][key].shape for n in recurrent}
@@ -1431,7 +1433,11 @@ class FFModel:
                               "cache_bytes": sum(a.nbytes
                                                  for a in stack.values()),
                               "state_bytes": stack["s"].nbytes,
-                              "conv_bytes": stack["u"].nbytes}}
+                              "conv_bytes": stack["u"].nbytes,
+                              # a prefill step's chunked form is the kernel
+                              # (ffsv_kda_chunk_tokens_total counts then)
+                              "chunk_kernel": takes_chunk_kernel(
+                                  by_name[recurrent[0]].attrs, self.config)}}
         tails = [n for n, st in self.op_state.items()
                  if isinstance(st, dict) and "tail" in st]
         if tails:

@@ -1,8 +1,11 @@
-"""The recurrent form of a gated delta rule with one decay a key channel
-(ops/kda_attention.py): one token a row, a state a row a head.
+"""The two forms of a gated delta rule with one decay a key channel
+(ops/kda_attention.py) as kernels: ``kda_state_step``, the RECURRENT form
+(a decode step: one token a row), and ``kda_chunk``, the CHUNKED form (a
+prefill step: a row's tokens in chunks of 64). A state a row a head.
 
-A row's state is ``S [H, K, V]`` float32 (4.19 MB at 64 heads of 128 x 128).
-A decode step reads ALL of it and writes ALL of it back:
+**The recurrent form.** A row's state is ``S [H, K, V]`` float32 (4.19 MB
+at 64 heads of 128 x 128). A decode step reads ALL of it and writes ALL of
+it back:
 
     S' = exp(g)[:, None] * S           one decay a KEY channel
     d  = beta * (v - S'^T k)
@@ -29,6 +32,35 @@ products with the identity, contracted over both last dims, hand back
 ``[K, heads]`` exactly (three bfloat16 pieces of each value, each one
 value times 1 plus zeros in a float32 accumulator), and a head's column is
 a lane of it.
+
+**The chunked form** (``kda_chunk``; its arithmetic is
+``ops/kda_attention._chunk_parts`` and ``through_chunks``, which stay as the
+jnp fallback and the tests' oracle). The grid is ``head blocks x rows x
+chunks``, a program one chunk of 64 tokens of ``chunk_heads_per_block``
+heads, everything in float32 and every product at ``HIGHEST`` precision:
+
+1. the chunk's cumulative log decay ``G`` (a product with the triangle of
+   ones); inside each 16-token sub-chunk the pairwise decays with the
+   exponent's difference formed first, one earlier token against the eight
+   later ones of a sublane tile a pass, reduced over the key channels along
+   the lanes; between sub-chunks both factors relative to the LATER
+   sub-chunk's start, as matrix products;
+2. ``(I + Diag(beta) A) X = beta [v | k exp(G)]`` by forward substitution:
+   the diagonal blocks' inverses a column at a time with a chunk's four
+   blocks side by side along the lanes, then block by block;
+3. the state's walk ``d = U - W S; o = Qd S + B d; S' = decay * S + Kend^T
+   d`` with ``S`` in a VMEM scratch.
+
+Nothing of 1-2 goes to HBM. The rows are taken a slot after another in
+ascending ``start`` (``chunk_sources``, scalar prefetch), so a row that
+continues the row before it finds that row's end in the scratch; a row at
+position 0 zeroes it, any other loads the stored state (the stack's block,
+aliased in and out as in the recurrent form). A slot's rows name ONE block
+of the output stack, which the pipeline writes back when the walk leaves
+the slot: each slot's LAST row's end. Idle rows come last, name the last
+live program's blocks (nothing is fetched, no state written) and zero their
+own rows of ``o``. A padding position has ``g = 0`` and ``beta = 0`` and
+passes the state as it is.
 """
 
 from __future__ import annotations
@@ -65,6 +97,30 @@ def state_step_bytes(rows: float, H: int, K: int, V: int) -> float:
     return rows * H * (2.0 * K * V + 3 * K + 2 * V + 1) * 4
 
 
+def _identity(K: int):
+    return (jax.lax.broadcasted_iota(jnp.int32, (K, K), 0)
+            == jax.lax.broadcasted_iota(jnp.int32, (K, K), 1)).astype(
+                jnp.bfloat16)
+
+
+def _columns(eye, x):
+    """``x [hb, K]`` float32 -> ``[K, hb]``, exactly: three bfloat16 pieces
+    that add up to the float32 value (8 + 8 + 8 bits of mantissa), each
+    through the identity: one value times 1 plus zeros in a float32
+    accumulator, whatever precision the compiler would give a float32
+    product."""
+    f32 = jnp.float32
+    out, rest = None, x
+    for _ in range(3):
+        piece = rest.astype(jnp.bfloat16)
+        rest = rest - piece.astype(f32)
+        part = jax.lax.dot_general(
+            eye, piece, (((1,), (1,)), ((), ())),
+            preferred_element_type=f32)
+        out = part if out is None else out + part
+    return out
+
+
 def _kernel(lidx_ref, rows_ref, nl_ref, fresh_ref, s_ref, q_ref, k_ref,
             g_ref, v_ref, b_ref, o0_ref, so_ref, o_ref, *, hb: int):
     del lidx_ref, o0_ref            # index maps and aliasing only
@@ -78,25 +134,8 @@ def _kernel(lidx_ref, rows_ref, nl_ref, fresh_ref, s_ref, q_ref, k_ref,
         # a row that starts a request starts from zeros, whatever the slot
         # held
         keep = jnp.where(fresh_ref[rows_ref[i]] != 0, 0.0, 1.0).astype(f32)
-        bf16 = jnp.bfloat16
-        eye = (jax.lax.broadcasted_iota(jnp.int32, (K, K), 0)
-               == jax.lax.broadcasted_iota(jnp.int32, (K, K), 1)).astype(bf16)
-
-        def columns(x):             # [hb, K] -> [K, hb], exactly
-            # three bfloat16 pieces that add up to the float32 value (8 + 8
-            # + 8 bits of mantissa), each through the identity: one value
-            # times 1 plus zeros in a float32 accumulator, whatever
-            # precision the compiler would give a float32 product
-            out, rest = None, x
-            for _ in range(3):
-                piece = rest.astype(bf16)
-                rest = rest - piece.astype(f32)
-                part = jax.lax.dot_general(
-                    eye, piece, (((1,), (1,)), ((), ())),
-                    preferred_element_type=f32)
-                out = part if out is None else out + part
-            return out
-
+        # [hb, K] -> [K, hb], exactly
+        columns = functools.partial(_columns, _identity(K))
         aT = columns(jnp.exp(g_ref[...]) * keep)
         kT, qT = columns(k_ref[...]), columns(q_ref[...])
         for h in range(hb):         # a head's [K, V] tile at a time
@@ -180,3 +219,337 @@ def kda_state_step(state, layer_idx, q, k, g, v, beta, live, fresh,
       v.astype(f32), beta.astype(f32).reshape(R, nhb, hb, 1),
       jnp.zeros((R, H, V), f32))
     return o, new
+
+
+# ----------------------------------------------------------------------
+# the chunked form: a prefill step's rows, chunks of 64 tokens in order
+# ----------------------------------------------------------------------
+
+CHUNK_NAME = "kda_chunk"
+# tokens a chunk and a sub-chunk (ops/kda_attention.CHUNK, SUB)
+CHUNK, SUB = 64, 16
+# heads a program: their chains of small products are what the compiler
+# interleaves; q, k, g, v, o blocks of 64 x 8 x 128 float32 = 256 KB each
+CHUNK_HEADS_PER_BLOCK = 8
+VMEM_LIMIT = 96 * 1024 * 1024
+HIGHEST = jax.lax.Precision.HIGHEST
+# where a row's state comes from (scalar prefetch)
+FROM_ZEROS, FROM_STEP, FROM_STORE = 0, 1, 2
+# traces of the chunked form by (form, rows, tokens a row): "kernel"
+# (``kda_chunk``) or "jnp" (ops/kda_attention.chunked); read by no metric
+chunk_form_counts: dict = {}
+# tools/time_kda_chunk.py's own hook: a part of ``kda_chunk`` left out so
+# that a timing keeps the rest (the results are then wrong): "pairs" (the
+# pairwise decays inside the sub-chunks), "solve" (the forward
+# substitution), "walk" (the state's products), "passes" (every product in
+# one bfloat16 pass)
+ABLATE = None
+
+
+def record_chunk_form(form: str, rows: int, tokens: int):
+    key = (form, rows, tokens)
+    chunk_form_counts[key] = chunk_form_counts.get(key, 0) + 1
+
+
+def chunk_summary() -> str:
+    """The chunked form's traces in one line: "chunked form: kernel, 4 rows
+    of 128: 2 traces"."""
+    return "chunked form: " + ("; ".join(
+        f"{form}, {rows} rows of {tokens}: {n} traces"
+        for (form, rows, tokens), n in sorted(chunk_form_counts.items()))
+        or "0 traces")
+
+
+def chunk_heads_per_block(H: int) -> int:
+    hb = min(CHUNK_HEADS_PER_BLOCK, H)
+    while H % hb:
+        hb -= 1
+    return hb
+
+
+def supports_chunk(H: int, K: int, V: int) -> bool:
+    """Shapes Mosaic takes for ``kda_chunk``: a head's lanes whole tiles."""
+    return K % 128 == 0 and V % 128 == 0
+
+
+def chunk_size(T: int) -> int:
+    """Tokens a chunk for rows of ``T``: ``CHUNK``, or a short row's whole
+    sub-chunks (``ops/kda_attention.chunk_parts``' rule)."""
+    return CHUNK if T >= CHUNK else -(-T // SUB) * SUB
+
+
+def _mm(a, b, dims=(((1,), (0,)), ((), ()))):
+    return jax.lax.dot_general(
+        a, b, dims, precision=None if ABLATE == "passes" else HIGHEST,
+        preferred_element_type=jnp.float32)
+
+
+def _per_head(f, *xs):
+    """``f`` on each head's 2-D slice of ``xs [hb, ., .]``, stacked."""
+    return jnp.stack([f(*(x[h] for x in xs)) for h in range(xs[0].shape[0])])
+
+
+def _chunk_math(q, k, g, v, beta, S, sub: int):
+    """One chunk of ``hb`` heads through the state: ``q, k, g [hb, C, K]``,
+    ``v [hb, C, V]``, ``beta [hb, C, 1]``, ``S [hb, K, V]``, float32. Returns
+    ``(o [hb, C, V], S')``. The arithmetic of ``ops/kda_attention``'s
+    ``_chunk_parts`` and ``through_chunks``, a chunk at a time."""
+    f32 = jnp.float32
+    hb, C, K = k.shape
+    nb = C // sub
+    n = hb * nb
+    iota = jax.lax.broadcasted_iota
+    # 1. the cumulative log decay: a product with the lower triangle of ones
+    tri = (iota(jnp.int32, (C, C), 0) >= iota(jnp.int32, (C, C), 1)).astype(
+        f32)
+    G = _per_head(lambda x: _mm(tri, x), g)                 # [hb, C, K] <= 0
+    # inside a sub-chunk: the exponent's difference first, a column of the
+    # block (one earlier token s against the block's later tokens) a pass.
+    # A column lands in lane s of EVERY group of ``sub`` lanes (the masks
+    # below keep a block's own group), and, for the solve, in all the
+    # lanes of its block's group: ``Lrep[s][h, t, (b, .)] = A_b[t, s]``
+    Gd, gd, kd, qd = (x.reshape(n, sub, K) for x in (G, g, k, q))
+    group = iota(jnp.int32, (1, 1, C), 2) // sub
+    at = iota(jnp.int32, (1, 8, C), 2) % sub
+    A_rows, B_rows, Lrep = [], [], [[] for _ in range(sub)]
+    for lo in range(0, sub, 8):         # eight rows t of every block
+        Gt, kt, qt = (x[:, lo:lo + 8] for x in (Gd, kd, qd))
+        later = iota(jnp.int32, (1, 8, 1), 1) + lo          # t
+        A = jnp.zeros((n, 8, C), f32)
+        B = jnp.zeros((n, 8, C), f32)
+        for s in range(lo + 8 if ABLATE != "pairs" else 0):   # s <= t
+            rel = kd[:, s:s + 1] * jnp.exp(jnp.minimum(
+                Gt - Gd[:, s:s + 1], 0))
+            a = jnp.sum(kt * rel, axis=-1, keepdims=True)   # [n, 8, 1]
+            b = jnp.sum(qt * rel, axis=-1, keepdims=True)
+            A = jnp.where(at == s, a, A)
+            B = jnp.where(at == s, b, B)
+            if s < sub - 1:
+                a = jnp.where(later > s, a, 0).reshape(hb, nb, 8, 1)
+                rep = jnp.zeros((hb, 8, C), f32)
+                for j in range(nb):
+                    rep = jnp.where(group == j, a[:, j], rep)
+                Lrep[s].append(rep)
+        A_rows.append(A), B_rows.append(B)
+        for s in range(lo + 8, sub - 1):    # rows t <= s: nothing
+            Lrep[s].append(jnp.zeros((hb, 8, C), f32))
+    row = iota(jnp.int32, (C, C), 0)
+    col = iota(jnp.int32, (C, C), 1)
+    same = (row // sub) == (col // sub)
+    A = jnp.where(same & (row > col), jnp.concatenate(
+        A_rows, axis=1).reshape(hb, C, C), 0)
+    B = jnp.where(same & (row >= col), jnp.concatenate(
+        B_rows, axis=1).reshape(hb, C, C), 0)
+    # between sub-chunks: both factors relative to the LATER one's start
+    if nb > 1:
+        Gs = Gd[:, :1] - gd[:, :1]                          # [n, 1, K]
+        late = jnp.exp(Gd - Gs)                             # <= 1
+        kq_late = jnp.concatenate(
+            [(kd * late).reshape(hb, nb, sub, K),
+             (qd * late).reshape(hb, nb, sub, K)], axis=2)  # [hb, nb, 2sub, K]
+        Gs = Gs.reshape(hb, nb, 1, K)
+        nt = (((1,), (1,)), ((), ()))
+        Ao, Bo = [jnp.zeros((hb, sub, C), f32)], [jnp.zeros((hb, sub, C), f32)]
+        for i in range(1, nb):
+            early = k * jnp.exp(jnp.minimum(Gs[:, i] - G, 0))      # [hb, C, K]
+            both = _per_head(lambda x, y: _mm(x, y, nt),
+                             kq_late[:, i], early)          # [hb, 2 sub, C]
+            before = iota(jnp.int32, (sub, C), 1) < i * sub
+            Ao.append(jnp.where(before, both[:, :sub], 0))
+            Bo.append(jnp.where(before, both[:, sub:], 0))
+        A = A + jnp.concatenate(Ao, axis=1)
+        B = B + jnp.concatenate(Bo, axis=1)
+    # 2. (I + Diag(beta) A) X = beta [v | k exp(G)] by forward substitution:
+    # the diagonal blocks' inverses a column at a time, a chunk's ``nb``
+    # blocks side by side along the lanes (``T[h, t, (b, j)]``: block b's
+    # row t), then block by block
+    into = jnp.exp(G)
+    L = beta * A
+    beta_b = beta.reshape(hb, nb, sub, 1)
+    beta_rep = jnp.zeros((hb, sub, C), f32)
+    for j in range(nb):
+        beta_rep = jnp.where(group == j, beta_b[:, j], beta_rep)
+    T = jnp.where(iota(jnp.int32, (sub, C), 1) % sub
+                  == iota(jnp.int32, (sub, C), 0), 1.0, 0.0) + jnp.zeros(
+                      (hb, sub, C), f32)
+    for s in range(sub - 1 if ABLATE not in ("solve", "pairs") else 0):
+        T = T - (beta_rep * jnp.concatenate(Lrep[s], axis=1)) * T[:, s:s + 1]
+    rhs = beta * jnp.concatenate([v, k * into], axis=-1)    # [hb, C, V + K]
+    M = rhs.shape[-1]
+    out = []
+    for i in range(nb if ABLATE != "solve" else 0):
+        r = i * sub
+        acc = rhs[:, r:r + sub]
+        if i:
+            sofar = jnp.concatenate(
+                out + [jnp.zeros((hb, C - r, M), f32)], axis=1)
+            acc = acc - _per_head(_mm, L[:, r:r + sub], sofar)
+        # block i's inverse alone in its rows' lanes, times the rows of the
+        # chunk with block i's filled
+        T_i = jnp.where(group[0] == i, T, 0)                # [hb, sub, C]
+        rows = jnp.concatenate(
+            [jnp.zeros((hb, r, M), f32)] * (r > 0) + [acc]
+            + [jnp.zeros((hb, C - r - sub, M), f32)] * (r + sub < C), axis=1)
+        out.append(_per_head(_mm, T_i, rows))
+    X = jnp.concatenate(out, axis=1) if out else rhs
+    Vw = v.shape[-1]
+    U, W = X[..., :Vw], X[..., Vw:]
+    # 3. the state's walk
+    if ABLATE == "walk":
+        return U + W + q * into + B[..., :1], S
+    d = U - _per_head(_mm, W, S)
+    o = _per_head(_mm, q * into, S) + _per_head(_mm, B, d)
+    Kend = k * jnp.exp(G[:, C - 1:] - G)
+    tn = (((0,), (0,)), ((), ()))
+    decay = _columns(_identity(K), into[:, C - 1])          # [K, hb]
+    S = (jnp.stack([decay[:, h:h + 1] for h in range(hb)]) * S
+         + _per_head(lambda x, y: _mm(x, y, tn), Kend, d))
+    return o, S
+
+
+def _chunk_kernel(lidx_ref, walk_ref, slot_ref, src_ref, nl_ref, s_ref,
+                  q_ref, k_ref, g_ref, v_ref, b_ref, so_ref, o_ref, S_ref, *,
+                  hb: int, sub: int):
+    del lidx_ref, walk_ref, slot_ref            # index maps only
+    i, c = pl.program_id(1), pl.program_id(2)
+    nl = nl_ref[0]
+    K, V = s_ref.shape[1:]
+
+    @pl.when(i < nl)
+    def _live():
+        src = src_ref[i]
+
+        @pl.when((c == 0) & (src == FROM_ZEROS))
+        def _():
+            S_ref[...] = jnp.zeros_like(S_ref)
+
+        @pl.when((c == 0) & (src == FROM_STORE))
+        def _():
+            S_ref[...] = s_ref[...]
+
+        # (FROM_STEP: the row before, the same slot's, left its end here)
+        def heads(ref):             # [C, hb, w] -> [hb, C, w]
+            return jnp.stack([ref[:, h, :] for h in range(hb)])
+
+        beta = jnp.stack([b_ref[:, h:h + 1] for h in range(hb)])
+        o, S = _chunk_math(heads(q_ref), heads(k_ref), heads(g_ref),
+                           heads(v_ref), beta, S_ref[...], sub)
+        S_ref[...] = S
+        for h in range(hb):
+            o_ref[:, h, :] = o[h]
+
+        @pl.when(c == pl.num_programs(2) - 1)
+        def _():                    # a slot's LAST row's is what goes back
+            so_ref[...] = S
+
+    @pl.when(i >= nl)
+    def _idle():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when((i == 0) & (nl == 0))
+    def _nobody():
+        # no live row at all: program 0's block of the state is fetched and
+        # written back all the same, so it goes back as it came
+        so_ref[...] = s_ref[...]
+
+
+def chunk_sources(slots, start, n):
+    """The walk of a step's rows through a state that every step overwrites,
+    as scalars: ``slots, start, n [R]`` (a row's slot, first position and
+    real tokens; 0: an idle row). Returns ``(walk, slot_of, src, nl)``: the
+    rows in the order the kernel takes them, the ``nl`` live ones first, a
+    slot after another in ascending ``start``; the slot whose state each
+    names (an idle row: the last live row's, so that nothing moves); and
+    where a live row's state comes from: ``FROM_ZEROS`` at position 0,
+    ``FROM_STEP`` where the row before it in the walk is the same slot's
+    and ends where this one starts, else ``FROM_STORE``. The rule of
+    ``inc_attention.carried_rows``."""
+    R = slots.shape[0]
+    live = n > 0
+    nl = jnp.sum(live.astype(jnp.int32))
+    walk = jnp.lexsort((start, slots, ~live)).astype(jnp.int32)
+    at = jnp.arange(R)
+    before = jnp.roll(walk, 1)
+    step = ((at > 0) & (slots[before] == slots[walk])
+            & (start[before] + n[before] == start[walk]))
+    src = jnp.where(step, FROM_STEP,
+                    jnp.where(start[walk] == 0, FROM_ZEROS, FROM_STORE))
+    slot_of = slots[jnp.where(at < nl, walk, walk[jnp.maximum(nl - 1, 0)])]
+    return walk, slot_of.astype(jnp.int32), src.astype(jnp.int32), nl
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def kda_chunk(state, layer_idx, q, k, g, v, beta, slots, start, n,
+              interpret: bool = False):
+    """A prefill step's rows through the state of layer ``layer_idx``, chunk
+    after chunk.
+
+    ``state`` ``[L, slots, H, K, V]`` float32, updated in place (donate
+    it); ``q, k, g`` ``[R, T, H, K]``, ``v`` ``[R, T, H, V]``, ``beta`` ``[R,
+    T, H]``, float32, a padding position (``t >= n``) with ``g = 0`` and
+    ``beta = 0``; ``slots, start, n`` ``[R]``: a row's slot, first position
+    and real tokens (``chunk_sources``). Returns ``(o [R, T, H, V], the
+    stack)``: each slot's LAST row's end is written back, an idle row's
+    ``o`` is zeros (it reads nothing: its blocks are the last live
+    program's) and no state of its slot is touched."""
+    L, _, H, K, V = state.shape
+    R, T = k.shape[:2]
+    f32 = jnp.float32
+    hb = chunk_heads_per_block(H)
+    nhb = H // hb
+    C = chunk_size(T)
+    pad = -T % C
+    nc = (T + pad) // C
+
+    def rows_of(x):         # [R, T, H, w] -> [R, T + pad, H, w]
+        x = x.astype(f32)
+        return jnp.pad(x, [(0, 0), (0, pad), (0, 0), (0, 0)]) if pad else x
+
+    beta = jnp.pad(beta.astype(f32), [(0, 0), (0, pad), (0, 0)])
+    beta = beta.reshape(R, T + pad, nhb, hb).swapaxes(1, 2)
+    walk, slot_of, src, nl = chunk_sources(slots, start, n)
+    scalars = (jnp.asarray(layer_idx, jnp.int32).reshape(1), walk, slot_of,
+               src, nl.reshape(1))
+
+    def read(i, c, walk, nl):
+        """The (row, chunk) whose inputs program (., i, c) reads: past the
+        last live row, the last live program's, so that nothing moves."""
+        live = i < nl[0]
+        return (walk[jnp.where(live, i, jnp.maximum(nl[0] - 1, 0))],
+                jnp.where(live, c, nc - 1))
+
+    def state_map(h, i, c, lidx, walk, slot_of, src, nl):
+        return lidx[0], slot_of[i], h, 0, 0
+
+    def row_map(h, i, c, lidx, walk, slot_of, src, nl):
+        r, cc = read(i, c, walk, nl)
+        return r, cc, h, 0
+
+    def beta_map(h, i, c, lidx, walk, slot_of, src, nl):
+        r, cc = read(i, c, walk, nl)
+        return r, h, cc, 0
+
+    def out_map(h, i, c, lidx, walk, slot_of, src, nl):
+        return walk[i], c, h, 0
+
+    vec = lambda w: pl.BlockSpec((None, C, hb, w), row_map)  # noqa: E731
+    new, o = pl.pallas_call(
+        functools.partial(_chunk_kernel, hb=hb, sub=SUB),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(scalars), grid=(nhb, R, nc),
+            in_specs=[pl.BlockSpec((None, None, hb, K, V), state_map),
+                      vec(K), vec(K), vec(K), vec(V),
+                      pl.BlockSpec((None, None, C, hb), beta_map)],
+            out_specs=[pl.BlockSpec((None, None, hb, K, V), state_map),
+                       pl.BlockSpec((None, C, hb, V), out_map)],
+            scratch_shapes=[pltpu.VMEM((hb, K, V), f32)]),
+        out_shape=[jax.ShapeDtypeStruct(state.shape, f32),
+                   jax.ShapeDtypeStruct((R, T + pad, H, V), f32)],
+        # operands count the scalars: the stack is operand 5
+        input_output_aliases={len(scalars): 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT),
+        interpret=interpret, name=CHUNK_NAME,
+    )(*scalars, state, rows_of(q), rows_of(k), rows_of(g), rows_of(v), beta)
+    return o[:, :T], new

@@ -23,26 +23,38 @@ slot tokens. One recurrence, two forms:
   ``d = beta (v - S'^T k)``; ``S = S' + k d^T``; ``o = S^T q``. On the
   kernel path ``kernels/linear_attention.kda_state_step`` streams a live
   row's state in and back, in place; ``recurrent_step`` is the same in jnp.
-* CHUNKED (a prefill step): ``chunked`` runs the recurrence over chunks of
-  ``CHUNK`` tokens by matrix products (the WY form of the delta rule: inside
-  a chunk ``d = T (beta v - beta (k * exp(G)) S_0)`` with ``T = (I +
-  Diag(beta) A)^-1`` a unit lower-triangular solve, ``A_ts = sum_c k_t[c]
-  k_s[c] exp(G_t[c] - G_s[c])``, ``G`` the chunk's cumulative log decay),
-  carrying ``S`` from chunk to chunk. ``exp(G_t - G_s)`` is never split into
-  ``exp(G_t) * exp(-G_s)`` across a chunk, which overflows at a strong decay:
-  between sub-chunks of ``SUB`` tokens both factors are taken relative to
-  the LATER sub-chunk's start (both <= 1), and inside one the exponent's
-  difference is formed first.
+* CHUNKED (a prefill step): the recurrence over chunks of ``CHUNK`` tokens
+  by matrix products (the WY form of the delta rule: inside a chunk ``d = T
+  (beta v - beta (k * exp(G)) S_0)`` with ``T = (I + Diag(beta) A)^-1`` a
+  unit lower-triangular solve, ``A_ts = sum_c k_t[c] k_s[c] exp(G_t[c] -
+  G_s[c])``, ``G`` the chunk's cumulative log decay), carrying ``S`` from
+  chunk to chunk. ``exp(G_t - G_s)`` is never split into ``exp(G_t) *
+  exp(-G_s)`` across a chunk, which overflows at a strong decay: between
+  sub-chunks of ``SUB`` tokens both factors are taken relative to the LATER
+  sub-chunk's start (both <= 1), and inside one the exponent's difference
+  is formed first. On the kernel path it is ONE device operation a layer,
+  ``kernels/linear_attention.kda_chunk`` (both callers: the compact batch's
+  segments and the slot grid's rows): a chunk's parts never leave VMEM and
+  the state stays there from chunk to chunk and from a row to the slot's
+  next row. ``chunked`` (``chunk_parts`` then ``through_chunks``) is the
+  same in jnp, some three hundred XLA operations a layer-step: the fallback
+  (no TPU and no interpreter forced, or widths Mosaic does not take) and
+  the tests' oracle.
 
 Padding positions of a row (``t >= n``) have ``g = 0`` and ``beta = 0``: the
 state passes them as it is, and they do not enter the tails.
 
-Where a row's state and tails come from (``inc_attention.carried_rows``, the
-rules of ``cca_attention.take_tails``): zeros where the row starts a request
+Where a row's state and tails come from (the rules of
+``cca_attention.take_tails``): zeros where the row starts a request
 (``start_pos == 0``, whatever the slot held); the END of another row of the
 same step where that row is the same slot's and ends where this one starts
 (the compact prefill batch's consecutive segments, all in one forward); the
 stored state otherwise; and the step writes back each slot's LAST row's.
+The tails (5 KB) go through ``inc_attention.carried_rows``, a gather, selects
+and a scatter; the state (4 MB) on the kernel path through
+``linear_attention.chunk_sources``, the same rule as scalars: the kernel
+takes a slot's rows one after another, so "the row before" is what VMEM
+holds (the jnp path: ``carried_rows`` again).
 
 What stages, moves, shares, rolls back or shards cache positions cannot
 carry the state along: ``inc_attention.refuse_windowed`` refuses them.
@@ -318,6 +330,28 @@ def _gates(attrs, params, x):
     return g, beta, gate.reshape(R, Q, H, V)
 
 
+def kernel_path(attrs, config):
+    """Whether the layer's recurrence runs its Pallas kernels
+    (kernels/linear_attention.py), by what the process can observe: None
+    (the jnp forms), else whether they are interpreted."""
+    from flexflow_tpu import kernels as ffk
+
+    if not (attrs.get("use_pallas", True) and ffk.use_pallas(config)):
+        return None
+    return ffk.pallas_interpret_forced()
+
+
+def takes_chunk_kernel(attrs, config) -> bool:
+    """Whether a prefill step of this layer runs ``kda_chunk`` (else the
+    jnp ``chunked``)."""
+    from flexflow_tpu.kernels import linear_attention as LA
+
+    interpret = kernel_path(attrs, config)
+    H, K, V, _, _ = _dims(attrs)
+    return interpret is not None and (interpret
+                                      or LA.supports_chunk(H, K, V))
+
+
 def _end_of(ext_u, n_i, width: int):
     """The last ``width`` positions before position ``n_i`` of one row's run
     with its tail in front: the tail its last real token leaves."""
@@ -343,7 +377,6 @@ class IncKDAttention(OpImpl):
 
     @staticmethod
     def forward(attrs, params, inputs, ctx):
-        from flexflow_tpu import kernels as ffk
         from flexflow_tpu.kernels import linear_attention as LA
         from flexflow_tpu.ops.norm import _rms_norm
         from flexflow_tpu.quant import qmatmul
@@ -412,13 +445,14 @@ class IncKDAttention(OpImpl):
         v = mixed[:, :, 2]
 
         # 2. the state
+        interpret = kernel_path(attrs, ctx.config)
+        chunk_kernel = takes_chunk_kernel(attrs, ctx.config)
+        if Q > 1 or slots is not None:
+            LA.record_chunk_form("kernel" if chunk_kernel else "jnp", R, Q)
         if slots is None and Q == 1:
             live = n > 0
             args = (q[:, 0], k[:, 0], g[:, 0], v[:, 0], beta[:, 0])
-            use_kernel = attrs.get("use_pallas", True) and ffk.use_pallas(
-                ctx.config)
-            interpret = ffk.pallas_interpret_forced()
-            if use_kernel and (interpret or LA.supports(H, K, V)):
+            if interpret is not None and (interpret or LA.supports(H, K, V)):
                 o, S_all = LA.kda_state_step(S_all, lidx, *args, live,
                                              start == 0, interpret=interpret)
             else:
@@ -426,6 +460,13 @@ class IncKDAttention(OpImpl):
                 o, new = recurrent_step(fresh(old), *args)
                 S_all = S_all.at[lidx].set(wrote(new, old))
             o = o[:, None]
+        elif chunk_kernel:
+            # the rows in the order of their slots and starts, the state in
+            # VMEM from chunk to chunk and from a row to the slot's next
+            o, S_all = LA.kda_chunk(
+                S_all, lidx, q, k, g, v, beta,
+                jnp.arange(R, dtype=jnp.int32) if slots is None else slots,
+                start, n, interpret=interpret)
         elif slots is None:
             old = S_all[lidx]
             o, new = chunked(fresh(old), q, k, g, v, beta)

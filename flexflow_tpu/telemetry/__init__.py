@@ -75,6 +75,7 @@ ffsv_moe_zero_pairs_total        counter    {phase} picks that were no expert (w
 ffsv_cca_tails_total             counter    {phase,source} rows by where their tail came from
 ffsv_kda_states_total            counter    {phase,source} rows by where their recurrent state came from
 ffsv_kda_state_steps_total       counter    live rows x recurrent layers x steps of the decode blocks
+ffsv_kda_chunk_tokens_total      counter    prefill tokens x recurrent layers through the chunked form's kernel
 ===============================  =========  =================================
 
 A decode block's step is one token a row, or one pass over a row's block
@@ -119,7 +120,10 @@ convolutions' tails; ``attention_kinds["recurrent"]``) has
 read), ``ffsv_kda_states_total{phase,source}``, the twin of
 ``ffsv_cca_tails_total`` (``step``: the hand-over of a state inside one
 prefill step), and ``ffsv_kda_state_steps_total``: live rows x recurrent
-layers x steps of the decode blocks, each one state read and written.
+layers x steps of the decode blocks, each one state read and written;
+and, where a prefill step's chunked form runs as the kernel ``kda_chunk``
+(``attention_kinds["recurrent"]["chunk_kernel"]``),
+``ffsv_kda_chunk_tokens_total``: prefill tokens x recurrent layers.
 ``ffsv_kv_cache_bytes`` is what compile allocated for each kind;
 ``ffsv_attn_positions_read_total`` is what the rows of the decode steps had
 to attend, from the batch's lengths on the host: for each row of each step
@@ -633,6 +637,13 @@ class ServingTelemetry:
                for slot, sp, n in runs if n]
         self._note_tails("prefill", series, **{
             s: src.count(s) for s in ("start", "step", "state")})
+        a = kinds.get("recurrent", {})
+        if a.get("chunk_kernel"):
+            self.registry.counter(
+                "ffsv_kda_chunk_tokens_total",
+                "prefill tokens x recurrent layers that went through the "
+                "chunked form's kernel (kda_chunk)"
+                ).inc(sum(n for _, _, n in runs) * a["layers"])
 
     def _chunked_counter(self, name, n, layers):
         helps = {

@@ -121,7 +121,7 @@ def _literal(S0, q, k, g, v, beta):
     return out, S
 
 
-def _draw(decay: str, B=3, T=150, H=2, K=16, seed=0):
+def _draw(decay: str, B=3, T=150, H=2, K=16, seed=0, n=None):
     """A step's q, k, g, v, beta as the op makes them; ``decay``: "mild" or
     "strongest" (every channel at the seeded initialisation's strongest: A
     = 16, dt = 0.1, and a decay input a unit above its bias)."""
@@ -140,7 +140,9 @@ def _draw(decay: str, B=3, T=150, H=2, K=16, seed=0):
         g = -16.0 * np.log1p(np.exp(np.log(np.expm1(0.1))
                                     + rng.uniform(0, 1, (B, T, H, K))))
     S0 = rng.standard_normal((B, H, K, K))
-    n = np.array([T, T - 37, 0][:B])            # whole, ragged, idle
+    if n is None:
+        n = [T, T - 37, 0][:B]                  # whole, ragged, idle
+    n = np.asarray(n)
     real = np.arange(T)[None, :] < n[:, None]
     g = np.where(real[..., None, None], g, 0.0)
     beta = np.where(real[..., None], beta, 0.0)
@@ -181,6 +183,61 @@ def test_chunked_recurrent_and_literal_forms_agree(decay):
     np.testing.assert_allclose(np.asarray(S), want_S, rtol=2e-4, atol=2e-5)
 
 
+def _through_kernel(S0, q, k, g, v, beta, n, slots=None, start=None,
+                    stack=None, layer=1):
+    """``kda_chunk`` interpreted on a stack of two layers whose layer
+    ``layer`` holds ``S0`` in the rows' slots (distinct slots, each row from
+    the store, unless given): ``(o, the new stack, the old stack)``."""
+    from flexflow_tpu.kernels.linear_attention import kda_chunk
+
+    B = q.shape[0]
+    rng = np.random.default_rng(9)
+    if slots is None:
+        slots, start = np.arange(B)[::-1] + 1, np.arange(B) + 7
+    if stack is None:
+        stack = rng.standard_normal((2, B + 2) + S0.shape[1:]).astype(
+            np.float32)
+        stack[layer, slots] = np.float32(S0)
+    f32 = [jnp.asarray(x, jnp.float32) for x in (q, k, g, v, beta)]
+    o, new = kda_chunk(jnp.asarray(stack), layer, *f32,
+                       *(jnp.asarray(x, jnp.int32) for x in (slots, start, n)),
+                       interpret=True)
+    return np.asarray(o), np.asarray(new), stack
+
+
+@pytest.mark.parametrize("decay", ["mild", "strongest"])
+def test_the_chunked_kernel_interpreted(decay):
+    """``kda_chunk`` in interpret mode against the jnp ``chunked`` and the
+    literal float64 recurrence, for the decays the forms' test draws (at
+    the strongest nothing overflows and nothing is NaN): rows of 0, 1, 63,
+    64, 65 and 128 real tokens of 130 (a padding tail in every row, a chunk
+    of nothing but padding in most), each from the store into its own slot;
+    an idle row's output is zeros and its slot's state untouched bit for
+    bit, as every slot no row names and the other layer."""
+    from flexflow_tpu.ops.kda_attention import chunked
+
+    S0, q, k, g, v, beta, n = _draw(decay, B=6, T=130,
+                                    n=[0, 1, 63, 64, 65, 128])
+    if decay == "strongest":
+        assert np.cumsum(g[5, :64], axis=0).min() < -100
+    want_o, want_S = _literal(S0, q, k, g, v, beta)
+    o, new, stack = _through_kernel(S0, q, k, g, v, beta, n)
+    assert np.isfinite(o).all() and np.isfinite(new).all()
+    jo, jS = chunked(*(jnp.asarray(x, jnp.float32)
+                       for x in (S0, q, k, g, v, beta)))
+    slots = np.arange(6)[::-1] + 1
+    for b, n_b in enumerate(n):
+        for want in (want_o, np.asarray(jo)):
+            np.testing.assert_allclose(o[b, :n_b], want[b, :n_b], rtol=2e-4,
+                                       atol=2e-5)
+    for want in (want_S, np.asarray(jS)):
+        np.testing.assert_allclose(new[1, slots[1:]], want[1:], rtol=2e-4,
+                                   atol=2e-5)
+    assert not o[0].any()
+    np.testing.assert_array_equal(new[1, [0, 6, 7]], stack[1, [0, 6, 7]])
+    np.testing.assert_array_equal(new[0], stack[0])
+
+
 @pytest.mark.parametrize("live", [(1, 0, 1, 1, 0), (0, 0, 0, 0, 0),
                                   (1, 1, 1, 1, 1), (0, 0, 0, 0, 1)])
 def test_the_recurrent_kernel_interpreted(live):
@@ -216,15 +273,11 @@ def test_the_recurrent_kernel_interpreted(live):
     np.testing.assert_array_equal(np.asarray(new)[0], np.asarray(stack)[0])
 
 
-def test_the_recurrent_kernel_compiles_for_a_v5e_at_the_cells_shape():
-    """What interpret mode cannot show: Mosaic takes the kernel at the
-    published widths (6 layers x 16 slots x 64 heads of 128 x 128 float32,
-    16 heads a program) on a donated stack. Compiled for a described chip;
-    nothing runs."""
+@pytest.fixture(scope="module")
+def one_chip():
+    """A described v5e chip to compile for; nothing runs on it."""
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
-
-    from flexflow_tpu.kernels import linear_attention as LA
 
     mp = pytest.MonkeyPatch()
     mp.setenv("TPU_LOG_DIR", "disabled")
@@ -236,26 +289,79 @@ def test_the_recurrent_kernel_compiles_for_a_v5e_at_the_cells_shape():
                                                 topology_name="v5e:2x2")
         except Exception as e:  # no TPU compiler here, or its lock is held
             pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-        one = SingleDeviceSharding(topo.devices[0])
-        L, R, H, K = 6, 16, 64, 128
-        assert LA.supports(H, K, K) and LA.heads_per_block(H) == 16
+        yield SingleDeviceSharding(topo.devices[0])
+    finally:
+        mp.undo()
 
-        def aval(shape, dt=jnp.float32):
-            return jax.ShapeDtypeStruct(shape, dt, sharding=one)
 
+def _compiled_for(one_chip, form: str) -> str:
+    """The optimised HLO of one layer's call at the cell's shape (the stack
+    of 6 layers x 16 slots x 64 heads of 128 x 128 float32, donated):
+    "recurrent": a decode step's 16 rows; "chunked": a prefill step's 4
+    rows of 128 tokens."""
+    from flexflow_tpu.kernels import linear_attention as LA
+
+    L, S, H, K = 6, 16, 64, 128
+    assert LA.supports(H, K, K) and LA.heads_per_block(H) == 16
+    assert LA.supports_chunk(H, K, K) and LA.chunk_size(128) == 64
+    from flexflow_tpu.ops import kda_attention
+
+    assert (LA.CHUNK, LA.SUB) == (kda_attention.CHUNK, kda_attention.SUB)
+
+    def aval(shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    if form == "recurrent":
         def step(stack, q, k, g, v, beta, live, fresh):
             return LA.kda_state_step.__wrapped__(stack, 3, q, k, g, v, beta,
                                                  live, fresh)
 
-        compiled = jax.jit(step, donate_argnums=(0,)).lower(
-            aval((L, R, H, K, K)), *[aval((R, H, K))] * 4, aval((R, H)),
-            aval((R,), bool), aval((R,), bool)).compile()
-        text = compiled.as_text()
-        assert text.count('custom_call_target="tpu_custom_call"') == 1
-        # the stack goes through in place: no copy of it anywhere
-        assert "f32[6,16,64,128,128]{4,3,2,1,0} copy(" not in text
-    finally:
-        mp.undo()
+        args = [*[aval((S, H, K))] * 4, aval((S, H)), aval((S,), bool),
+                aval((S,), bool)]
+    else:
+        def step(stack, q, k, g, v, beta, slots, start, n):
+            return LA.kda_chunk.__wrapped__(stack, 3, q, k, g, v, beta,
+                                            slots, start, n)
+
+        args = [*[aval((4, 128, H, K))] * 4, aval((4, 128, H)),
+                *[aval((4,), jnp.int32)] * 3]
+    return jax.jit(step, donate_argnums=(0,)).lower(
+        aval((L, S, H, K, K)), *args).compile().as_text()
+
+
+@pytest.mark.parametrize("form", ["recurrent", "chunked"])
+def test_the_recurrent_kernel_compiles_for_a_v5e_at_the_cells_shape(
+        one_chip, form):
+    """What interpret mode cannot show: Mosaic takes the kernels at the
+    published widths (6 layers x 16 slots x 64 heads of 128 x 128 float32;
+    the recurrent form 16 heads a program, the chunked form a prefill
+    step's 4 rows of 128 tokens) on a donated stack. Compiled for a
+    described chip; nothing runs."""
+    text = _compiled_for(one_chip, form)
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    # the stack goes through in place: no copy of it anywhere
+    assert "f32[6,16,64,128,128]{4,3,2,1,0} copy(" not in text
+
+
+def test_the_two_kernels_device_operations_keep_their_names_apart(one_chip):
+    """The chunked form's device operation is ``kda_chunk`` and nothing in
+    its program is named ``kda_state_step``, the substring by which the
+    benchmark's readers find the RECURRENT kernel
+    (``linear_attn_share``, ``kda_state_hbm_roofline``); and the other way
+    round."""
+    import re
+
+    from flexflow_tpu.kernels import linear_attention as LA
+
+    assert (LA.NAME, LA.CHUNK_NAME) == ("kda_state_step", "kda_chunk")
+    assert LA.NAME not in LA.CHUNK_NAME and LA.CHUNK_NAME not in LA.NAME
+    for form, mine, other in (("chunked", LA.CHUNK_NAME, LA.NAME),
+                              ("recurrent", LA.NAME, LA.CHUNK_NAME)):
+        text = _compiled_for(one_chip, form)
+        (call,) = [line for line in text.splitlines()
+                   if 'custom_call_target="tpu_custom_call"' in line]
+        assert re.match(rf"\s*(ROOT )?%{mine}(\.\d+)? = ", call), call[:80]
+        assert other not in text
 
 
 def test_carried_rows_by_source():
@@ -292,6 +398,67 @@ def test_carried_rows_by_source():
     np.testing.assert_array_equal(kept[0], stored[0])
 
 
+def test_carried_rows_by_source_through_the_chunked_kernel():
+    """The same hand-made step through ``kda_chunk``, whose scalars
+    (``chunk_sources``) stand where ``carried_rows``' selects stood: the
+    row at position 0 starts from zeros over a dirty slot, each
+    continuation from the END of the row before it (kept in VMEM), the
+    fourth from the store; each slot's LAST row's end alone is written
+    back, into the named layer alone, and the idle row's slot and the
+    slot nobody names keep their states bit for bit. Against the jnp path
+    (``carried_rows`` over ``chunked``) and the literal recurrence run
+    segment after segment."""
+    from flexflow_tpu.kernels.linear_attention import (FROM_STEP, FROM_STORE,
+                                                       FROM_ZEROS,
+                                                       chunk_sources)
+    from flexflow_tpu.ops.kda_attention import chunked
+
+    rows = [(2, 0, 3), (2, 3, 1), (2, 4, 2), (1, 7, 3), (0, 5, 0)]
+    # the step's rows in ascending order of start, as _prefill_rows gives
+    # them (the slots' rows interleaved)
+    rows = sorted(rows, key=lambda r: r[1])
+    slots, start, n = (np.asarray(x) for x in zip(*rows))
+    walk, slot_of, src, nl = (np.asarray(x) for x in chunk_sources(
+        *(jnp.asarray(x, jnp.int32) for x in (slots, start, n))))
+    assert nl == 4 and slot_of[:4].tolist() == [1, 2, 2, 2]
+    assert [rows[i][1] for i in walk[:4]] == [7, 0, 3, 4]
+    assert src[:4].tolist() == [FROM_STORE, FROM_ZEROS, FROM_STEP, FROM_STEP]
+    # the idle row comes last and names the last live row's slot: no move
+    assert rows[walk[4]][2] == 0 and slot_of[4] == 2
+    S0, q, k, g, v, beta, n = _draw("mild", B=5, T=3, n=n)
+    rng = np.random.default_rng(4)
+    stack = rng.standard_normal((2, 4) + S0.shape[1:]).astype(np.float32)
+    o, new, _ = _through_kernel(S0, q, k, g, v, beta, n, slots, start,
+                                stack=stack.copy())
+    f32 = [jnp.asarray(x, jnp.float32) for x in (q, k, g, v, beta)]
+
+    def run(i, state):
+        o_i, S = chunked(state[None], *(x[i:i + 1] for x in f32))
+        return o_i[0], S[0]
+
+    outs, kept = carried_rows(jnp.asarray(stack), *(
+        jnp.asarray(x, jnp.int32) for x in (slots, start, n)), run, layer=1)
+    for i, n_i in enumerate(n):
+        np.testing.assert_allclose(o[i, :n_i], np.asarray(outs[i])[:n_i],
+                                   rtol=2e-4, atol=2e-5)
+    np.testing.assert_allclose(new, np.asarray(kept), rtol=2e-4, atol=2e-5)
+    # slot 2: its three rows are one run of six tokens from zeros
+    by_start = {sp: i for i, (_, sp, _) in enumerate(rows)}
+    run2 = [by_start[0], by_start[3], by_start[4]]
+    cat = [np.concatenate([x[i, :n[i]] for i in run2])[None]
+           for x in (q, k, g, v, beta)]
+    want_o, want_S = _literal(np.zeros_like(S0[:1]), *cat)
+    np.testing.assert_allclose(new[1, 2], want_S[0], rtol=2e-4, atol=2e-5)
+    np.testing.assert_allclose(
+        np.concatenate([o[i, :n[i]] for i in run2]), want_o[0], rtol=2e-4,
+        atol=2e-5)
+    _, want_S = _literal(stack[1, 1][None], *(
+        x[by_start[7]][None] for x in (q, k, g, v, beta)))
+    np.testing.assert_allclose(new[1, 1], want_S[0], rtol=2e-4, atol=2e-5)
+    np.testing.assert_array_equal(new[1, [0, 3]], stack[1, [0, 3]])
+    np.testing.assert_array_equal(new[0], stack[0])
+
+
 # ---------------------------------------------------------------------------
 # (b) the program against the plain reference; one prompt four ways
 # ---------------------------------------------------------------------------
@@ -313,7 +480,8 @@ def test_program_matches_plain_reference_through_hand_over_and_decode(bench):
         "recurrent": {"layers": 3, "window": None,
                       "cache_bytes": 3 * 4 * (4 * 16 * 16 + 3 * 192) * 4,
                       "state_bytes": 3 * 4 * 4 * 16 * 16 * 4,
-                      "conv_bytes": 3 * 4 * 3 * 192 * 4}}
+                      "conv_bytes": 3 * 4 * 3 * 192 * 4,
+                      "chunk_kernel": False}}
     assert m.op_state[RECURRENT_STACK]["s"].dtype == jnp.float32
     assert m.op_state[FULL_STACK]["k"].shape[0] == 1
     toks = _tokens(16 + 16 + 11 + 8)
@@ -346,15 +514,29 @@ PLANS = {
 }
 
 
-@pytest.fixture(scope="module")
-def four_ways(bench):
+@pytest.fixture(scope="module", params=["jnp", "kernels"])
+def four_ways(bench, request):
+    """The prompt through each plan, on the jnp path and with the kernels
+    interpreted (``pallas_interpret_forced``: every prefill step through
+    ``kda_chunk``, every one-token step through ``kda_state_step``)."""
+    from flexflow_tpu.kernels import linear_attention as LA
+
     family, _ = bench
     toks = _tokens(53, seed=5)
-    out = {}
-    for name, plan in PLANS.items():
-        m, _ = _build()
-        logits, routes, _ = family.drive(m, toks, plan, slot=1)
-        out[name] = (logits, routes, _state(m, 1), _state(m, 0))
+    out = {"path": request.param}
+    with pytest.MonkeyPatch.context() as mp:
+        if request.param == "kernels":
+            mp.setenv("FF_PALLAS_INTERPRET", "1")
+        LA.chunk_form_counts.clear()
+        for name, plan in PLANS.items():
+            m, _ = _build()
+            assert m.attention_kinds["recurrent"]["chunk_kernel"] == (
+                request.param == "kernels")
+            logits, routes, _ = family.drive(m, toks, plan, slot=1)
+            out[name] = (logits, routes, _state(m, 1), _state(m, 0))
+        forms = {form for form, _, _ in LA.chunk_form_counts}
+        assert forms == {"kernel" if request.param == "kernels" else "jnp"}
+        assert LA.chunk_summary().startswith(f"chunked form: {forms.pop()}, ")
     return out
 
 
@@ -379,19 +561,24 @@ def test_one_prompt_fed_four_ways_gives_the_same_logits_and_states(
 def test_the_slot_grid_prefill_carries_the_state_too(bench, four_ways):
     """A prefill chunk on the slot grid (``slots`` None, a row a slot) takes
     its state from the store like a decode step: the same logits as the
-    compact batch's."""
+    compact batch's (on the kernel path: ``kda_chunk`` over a row a slot)."""
     sys.path.insert(0, ROOT)
     try:
         from benchmark.families._common import program_logits
     finally:
         sys.path.remove(ROOT)
-    m, _ = _build()
-    grid = program_logits(m, _tokens(53, seed=5), 32)   # then 21 decoded
+    with pytest.MonkeyPatch.context() as mp:
+        if four_ways["path"] == "kernels":
+            mp.setenv("FF_PALLAS_INTERPRET", "1")
+        m, _ = _build()
+        grid = program_logits(m, _tokens(53, seed=5), 32)   # then 21 decoded
     assert _rel(grid, four_ways[sorted(PLANS)[0]][0]) < TOL
 
 
 def test_the_kernel_path_serves_the_same_tokens(monkeypatch):
-    """Served with the kernels interpreted: the decode block goes through
+    """Served with the kernels interpreted: a prefill step goes through
+    ``kda_chunk`` (``ffsv_kda_chunk_tokens_total`` counts its tokens, and
+    does not exist on the jnp path), the decode block through
     ``kda_state_step`` and the flash kernel, and in float32 the tokens are
     those of the jnp path (in bfloat16 the two paths round at different
     points and a tiny model's largest logit changes hands, so there the
@@ -399,22 +586,34 @@ def test_the_kernel_path_serves_the_same_tokens(monkeypatch):
     a float32 state)."""
     import flexflow_tpu.kernels as ffk
 
-    def serve(dtype="float32"):
+    def serve(dtype="float32", tel=None):
         m, _ = _build(max_sequence_length=512, compute_dtype=dtype,
-                      kv_cache_dtype=dtype)
+                      kv_cache_dtype=dtype, telemetry=tel is not None)
         assert m.op_state[RECURRENT_STACK]["s"].dtype == jnp.float32
         assert m.op_state[RECURRENT_STACK]["u"].dtype == jnp.float32
         rm = RequestManager()
+        rm.telemetry = tel
         for i, n in enumerate((70, 9)):
             rm.register_new_request([int(t) for t in _tokens(n, seed=50 + i)],
                                     max_new_tokens=6)
         return [r.output_tokens for r in rm.generate_incr_decoding(m)]
 
-    plain = serve()
+    from flexflow_tpu.telemetry import ServingTelemetry
+
+    chunk_tokens = "ffsv_kda_chunk_tokens_total"
+    tel = ServingTelemetry()
+    plain = serve(tel=tel)
+    assert chunk_tokens not in tel.registry.snapshot()
     monkeypatch.setenv("FF_PALLAS_INTERPRET", "1")
     ffk.reset_dispatch_stats()
-    assert serve() == plain
+    tel = ServingTelemetry()
+    assert serve(tel=tel) == plain
     assert not ffk.fallback_counts and ffk.fast_path_count > 0
+    # every prefilled token (a prompt's last goes with the decode block)
+    # through ``kda_chunk`` in each of the three layers
+    snap = tel.registry.snapshot()
+    assert snap[chunk_tokens]["value"] == 3 * (69 + 8) == 3 * snap[
+        "ffsv_prefill_tokens_total"]["value"]
     assert [len(t) for t in serve("bfloat16")] == [6, 6]
 
 
@@ -770,7 +969,7 @@ def test_hf_weight_map_loads_a_synthetic_checkpoint(bench):
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("what", ["arithmetic", "files", "traced_rehearsal",
-                                  "variants_tool"])
+                                  "variants_tool", "chunk_tool"])
 def test_the_cell_its_files_and_the_arithmetic_of_its_bytes(
         bench, what, monkeypatch, capsys):
     family, _ = bench
@@ -828,7 +1027,7 @@ def test_the_cell_its_files_and_the_arithmetic_of_its_bytes(
         mine = {m["name"] for m in b["per_layer"]
                 if CELL in m.get("workloads", ())}
         assert {"kda_state_hbm_roofline", "decode_kda_hbm_roofline",
-                "linear_attn_share", "kv_recurrent_share",
+                "linear_attn_share", "kv_recurrent_share", "kda_chunk_share",
                 "attn_kv_hbm_roofline", "experts_touched",
                 "device_idle"} <= mine
         assert not {"decode_hbm_roofline", "decode_cca_hbm_roofline",
@@ -869,9 +1068,27 @@ def test_the_cell_its_files_and_the_arithmetic_of_its_bytes(
         assert res["state_rel_err"] < 1e-5 < res["state_tol"] < res[
             "wrong_bfloat16_state_state"]
         return
+    if what == "chunk_tool":
+        # tools/time_kda_chunk.py: both forms against the literal recurrence
+        # at both decays, tiny and interpreted (no time is taken here)
+        sys.path.insert(0, os.path.join(ROOT, "tools"))
+        try:
+            import time_kda_chunk
+        finally:
+            sys.path.remove(os.path.join(ROOT, "tools"))
+        assert time_kda_chunk.main(["--rehearse", "--tokens", "70"]) == 0
+        res = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+        assert res["device"] == "cpu" and "kernel" not in res
+        for form in ("jnp", "kernel"):
+            for decay in ("seeded", "strong"):
+                r = res[f"{form}_{decay}_decay"]
+                assert r["finite"] and r["state_max_abs"] < 5e-6, (form, r)
+        return
     from flexflow_tpu import kernels as ffk
+    from flexflow_tpu.kernels import linear_attention as LA
     from flexflow_tpu.kernels import moe as K
 
+    LA.chunk_form_counts.clear()
     ffk.reset_dispatch_stats()      # what the tests before this one traced
     K.reset_dispatch_stats()
     rc = run.main(["--workload", CELL, "--seed", "3000000019", "--seconds",
@@ -881,3 +1098,8 @@ def test_the_cell_its_files_and_the_arithmetic_of_its_bytes(
     assert rc == 0 and last["correct"] and last["rehearsal"], out[-2000:]
     said = [ln for ln in out.splitlines() if "REHEARSAL" in ln][0]
     assert '"kv_recurrent_share"' in said
+    # the rehearsal interprets the kernels: its prefill steps' chunked form
+    # is ``kda_chunk`` (the compact batch's rows and the reference check's
+    # slot grid), and the program counted their tokens
+    assert LA.chunk_form_counts and all(
+        form == "kernel" for form, _, _ in LA.chunk_form_counts)
