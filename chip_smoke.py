@@ -9,7 +9,11 @@ what comes out by the repo's own means:
              ``reference_attend`` at the serving shapes: width-8 decode
              with the fused in-place append on the stacked cache,
              width-8 tree verify with a bias, a prefill chunk, and the
-             packed head_dim-64 decode no served model reaches yet.
+             packed head_dim-64 decode (Falcon's multi-query and
+             Granite-4.0-H's grouped-query layers are served through it;
+             the recurrent kernels ``kda_state_step``, ``kda_chunk`` and
+             ``ssd_state_step`` are not driven here: their checks are
+             ``tools/check_reference_variants.py --config <name>``).
 * serving  — LLaMA-2-7B widths (4096 / 11008 / 32 heads / 128 / 32000),
              int8 weights, bf16 cache of 8 slots x 1024 positions, built
              by FFModel + create_llama_model + compile(INFERENCE) and

@@ -501,6 +501,31 @@ class FFModel:
             max_requests=self.config.max_requests_per_batch,
             use_pallas=self.config.use_pallas), name)
 
+    def inc_ssd_mixer(self, input: Tensor, embed_dim: int, num_heads: int,
+                      head_dim: int, state_dim: int, conv_kernel: int = 4,
+                      norm_eps: float = 1e-5,
+                      data_type: Optional[DataType] = None,
+                      kernel_initializer=None, name=None) -> Tensor:
+        """A state-space mixer (Mamba-2) for incremental decoding
+        (ops/ssd_mixer.py, imported here: only a model that has such a
+        layer loads it): ``num_heads`` heads of ``head_dim`` channels, each
+        with one scalar decay a token, over ONE group of ``state_dim``
+        input and output rows (``B``, ``C``) that all heads share; a
+        depthwise causal convolution of ``conv_kernel`` taps with a bias; a
+        skip term a head; the gate, then one RMSNorm over all ``num_heads *
+        head_dim`` channels. A slot keeps a recurrent state ``[num_heads,
+        head_dim, state_dim]`` and the convolution's tail, both float32; no
+        cache of positions."""
+        from flexflow_tpu.ops import ssd_mixer  # noqa: F401 (registers)
+
+        return self._add_layer(OpType.INC_SSD_MIXER, [input], dict(
+            embed_dim=embed_dim, num_heads=num_heads, head_dim=head_dim,
+            state_dim=state_dim, conv_kernel=int(conv_kernel),
+            norm_eps=float(norm_eps), data_type=data_type,
+            kernel_initializer=kernel_initializer,
+            max_requests=self.config.max_requests_per_batch,
+            use_pallas=self.config.use_pallas), name)
+
     def inc_multihead_self_attention(self, input: Tensor, embed_dim: int,
                                      num_heads: int, **kw) -> Tensor:
         return self.inc_multiquery_self_attention(input, embed_dim, num_heads,
@@ -1389,6 +1414,7 @@ class FFModel:
         (ops/latent_attention.py) keep one stream each: their stack, of any
         depth, is inc_attention.LATENT_STACK.
         """
+        from flexflow_tpu.ops import recurrent as REC
         from flexflow_tpu.ops.inc_attention import (CHUNKED_STACK,
                                                     FULL_STACK, LATENT_STACK,
                                                     RECURRENT_STACK,
@@ -1396,18 +1422,23 @@ class FFModel:
 
         by_name = {layer.name: layer for layer in self.layers}
         recurrent = [n for n, st in self.op_state.items()
-                     if isinstance(st, dict) and "kda_s" in st]
+                     if isinstance(st, dict) and REC.STATE in st]
         if recurrent:
             # layers that keep a recurrent state and no cache of positions
-            # (ops/kda_attention.py): the states and the convolutions' tails
-            # are one stack each at any depth, beside whatever the model's
-            # other attention layers keep (plain k/v caches, below)
-            from flexflow_tpu.ops.kda_attention import takes_chunk_kernel
-
+            # (the contract of ops/recurrent.py, whatever the op): the
+            # states and the convolutions' tails are one stack each at any
+            # depth, beside whatever the model's other attention layers
+            # keep (plain k/v caches, below)
             stack = {}
-            for member, key in (("s", "kda_s"), ("u", "kda_u")):
-                (shape,) = {self.op_state[n][key].shape for n in recurrent}
-                (dtype,) = {self.op_state[n][key].dtype for n in recurrent}
+            for member, key in (("s", REC.STATE), ("u", REC.TAIL)):
+                shapes = {(self.op_state[n][key].shape,
+                           self.op_state[n][key].dtype) for n in recurrent}
+                if len(shapes) != 1:
+                    raise NotImplementedError(
+                        "layers that keep recurrent states of different "
+                        f"shapes in one model ({sorted(map(str, shapes))}): "
+                        "they are served from one stack, a layer an index")
+                ((shape, dtype),) = shapes
                 stack[member] = jnp.zeros((len(recurrent),) + shape, dtype)
             for i, n in enumerate(recurrent):
                 by_name[n].attrs["state_layer_idx"] = i
@@ -1420,10 +1451,11 @@ class FFModel:
                 raise NotImplementedError(
                     "layers that keep a recurrent state beside windowed or "
                     "chunked attention layers in one model")
+            first = by_name[recurrent[0]]
             # what telemetry says of the two kinds (ffsv_kv_cache_bytes,
-            # ffsv_attn_positions_read_total{kind="full"},
-            # ffsv_kda_state_steps_total, ffsv_kda_states_total), and what
-            # says "this model keeps a recurrent state"
+            # ffsv_attn_positions_read_total{kind="full"}, and the three
+            # ffsv_kda_* series, which count this kind whatever the op),
+            # and what says "this model keeps a recurrent state"
             self.attention_kinds = {
                 "full": {"layers": len(plain), "window": None,
                          "cache_bytes": sum(
@@ -1434,10 +1466,12 @@ class FFModel:
                                                  for a in stack.values()),
                               "state_bytes": stack["s"].nbytes,
                               "conv_bytes": stack["u"].nbytes,
-                              # a prefill step's chunked form is the kernel
+                              "op": first.op_type.name,
+                              # a prefill step's chunked form is a kernel
                               # (ffsv_kda_chunk_tokens_total counts then)
-                              "chunk_kernel": takes_chunk_kernel(
-                                  by_name[recurrent[0]].attrs, self.config)}}
+                              "chunk_kernel": get_op_impl(
+                                  first.op_type).takes_chunk_kernel(
+                                      first.attrs, self.config)}}
         tails = [n for n, st in self.op_state.items()
                  if isinstance(st, dict) and "tail" in st]
         if tails:

@@ -202,3 +202,6 @@ class OpType(enum.Enum):
     # serving attention by a gated delta rule, a recurrent state a slot
     # (ops/kda_attention.py)
     INC_KDA_ATTENTION = enum.auto()
+    # a serving state-space mixer (Mamba-2), a recurrent state a slot
+    # (ops/ssd_mixer.py)
+    INC_SSD_MIXER = enum.auto()

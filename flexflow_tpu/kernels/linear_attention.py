@@ -1,7 +1,10 @@
-"""The two forms of a gated delta rule with one decay a key channel
-(ops/kda_attention.py) as kernels: ``kda_state_step``, the RECURRENT form
-(a decode step: one token a row), and ``kda_chunk``, the CHUNKED form (a
-prefill step: a row's tokens in chunks of 64). A state a row a head.
+"""Recurrences that keep a state a row a head, as kernels. The two forms of
+a gated delta rule with one decay a key channel (ops/kda_attention.py):
+``kda_state_step``, the RECURRENT form (a decode step: one token a row), and
+``kda_chunk``, the CHUNKED form (a prefill step: a row's tokens in chunks of
+64); and the recurrent form of a state-space mixer (ops/ssd_mixer.py):
+``ssd_state_step`` (its section below). The two recurrent kernels share
+their scaffolding: ``live_rows_first`` and ``StepMaps``.
 
 **The recurrent form.** A row's state is ``S [H, K, V]`` float32 (4.19 MB
 at 64 heads of 128 x 128). A decode step reads ALL of it and writes ALL of
@@ -78,11 +81,55 @@ HEADS_PER_BLOCK = 16
 NAME = "kda_state_step"
 
 
-def heads_per_block(H: int) -> int:
-    hb = min(HEADS_PER_BLOCK, H)
+def heads_per_block(H: int, most: int = HEADS_PER_BLOCK) -> int:
+    hb = min(most, H)
     while H % hb:
         hb -= 1
     return hb
+
+
+def live_rows_first(live):
+    """The walk of a decode step's rows, as scalars: ``live [R]`` bool ->
+    ``(rows [R] int32, nl)``: the ``nl`` live rows first, in order, and every
+    place past them naming the LAST live row, so that a program there names
+    the block the last live program named and nothing moves."""
+    R = live.shape[0]
+    nl = jnp.sum(live.astype(jnp.int32))
+    order = jnp.argsort(~live, stable=True).astype(jnp.int32)
+    rows = jnp.where(jnp.arange(R) < nl, order, order[jnp.maximum(nl - 1, 0)])
+    return rows, nl
+
+
+class StepMaps:
+    """The index maps of a recurrent kernel's grid ``(rows, head blocks)``
+    over scalars ``(layer, rows, nl, ...)``: program ``(i, h)`` names row
+    ``rows[i]`` and head block ``h``; past the last live row, the last live
+    program's, so that nothing moves."""
+
+    def __init__(self, nhb: int):
+        self.nhb = nhb
+
+    def at(self, i, h, lidx, rows, nl, *_):
+        return rows[i], jnp.where(i < nl[0], h, self.nhb - 1)
+
+    def state(self, i, h, lidx, *s):
+        """Into the stack ``[L, R, H, ., .]``."""
+        r, hh = self.at(i, h, lidx, *s)
+        return lidx[0], r, hh, 0, 0
+
+    def row(self, i, h, *s):
+        """Into a row's vectors ``[R, H, w]``."""
+        r, hh = self.at(i, h, *s)
+        return r, hh, 0
+
+    def head_scalar(self, i, h, *s):
+        """Into a row's scalars a head, ``[R, nhb, hb, 1]``."""
+        r, hh = self.at(i, h, *s)
+        return r, hh, 0, 0
+
+    def shared(self, i, h, *s):
+        """Into a row's vectors that every head shares, ``[R, 1, w]``."""
+        return self.at(i, h, *s)[0], 0, 0
 
 
 def supports(H: int, K: int, V: int) -> bool:
@@ -169,28 +216,11 @@ def kda_state_step(state, layer_idx, q, k, g, v, beta, live, fresh,
     L, R, H, K, V = state.shape
     hb = heads_per_block(H)
     nhb = H // hb
-    nl = jnp.sum(live.astype(jnp.int32))
-    order = jnp.argsort(~live, stable=True).astype(jnp.int32)
-    rows = jnp.where(jnp.arange(R) < nl, order, order[jnp.maximum(nl - 1, 0)])
+    rows, nl = live_rows_first(live)
     scalars = (jnp.asarray(layer_idx, jnp.int32).reshape(1), rows,
                nl.reshape(1), fresh.astype(jnp.int32))
-
-    def at(i, h, lidx, rows, nl, fresh):
-        """The (row, head block) of program (i, h): past the last live row,
-        the last live program's, so that nothing moves."""
-        return rows[i], jnp.where(i < nl[0], h, nhb - 1)
-
-    def state_map(i, h, lidx, *s):
-        r, hh = at(i, h, lidx, *s)
-        return lidx[0], r, hh, 0, 0
-
-    def row_map(i, h, *s):
-        r, hh = at(i, h, *s)
-        return r, hh, 0
-
-    def beta_map(i, h, *s):
-        r, hh = at(i, h, *s)
-        return r, hh, 0, 0
+    maps = StepMaps(nhb)
+    state_map, row_map, beta_map = maps.state, maps.row, maps.head_scalar
 
     vec = lambda w: pl.BlockSpec((None, hb, w), row_map)  # noqa: E731
     f32 = jnp.float32
@@ -553,3 +583,132 @@ def kda_chunk(state, layer_idx, q, k, g, v, beta, slots, start, n,
         interpret=interpret, name=CHUNK_NAME,
     )(*scalars, state, rows_of(q), rows_of(k), rows_of(g), rows_of(v), beta)
     return o[:, :T], new
+
+
+# ----------------------------------------------------------------------
+# a state-space mixer's recurrent form (ops/ssd_mixer.py): one SCALAR
+# decay a head, no delta term, the input and output rows shared by the heads
+# ----------------------------------------------------------------------
+#
+# A row's state is ``S [H, P, N]`` float32 (2.10 MB at 64 heads of 64 x
+# 128), ``N`` along the lanes. A decode step reads all of it and writes all
+# of it back:
+#
+#     S[h] = a[h] * S[h] + dx[h] B^T        dx = dt * x [H, P]; B, C [N]
+#     y[h] = S[h] C
+#
+# (the op adds the skip term ``D[h] x[h]`` to ``y`` in the fusion that
+# gates it). ``kda_state_step`` cannot compute this by any setting of its
+# operands: its write is ``k (beta (v - S'^T k))^T``, and ``beta = 0``
+# switches the write off with the correction. The scaffolding is the same
+# (the stack aliased in and out, the layer an operand, the live rows first,
+# an idle row neither fetched nor written). In a head's ``[P, N]`` tile
+# ``B`` and ``C`` are rows as they come; ``dx`` is wanted as a COLUMN
+# (``_columns``), ``y`` comes out as one (a sum along the lanes), is put in
+# its head's lane of ``[P, heads]`` and handed back through the identity
+# again.
+
+SSD_NAME = "ssd_state_step"
+# heads a program: 32 x 64 x 128 float32 = 1.05 MB a descriptor, as
+# ``kda_state_step``'s 16 x 128 x 128
+SSD_HEADS_PER_BLOCK = 32
+
+
+def ssd_heads_per_block(H: int) -> int:
+    return heads_per_block(H, SSD_HEADS_PER_BLOCK)
+
+
+def supports_ssd(H: int, P: int, N: int) -> bool:
+    """Shapes Mosaic takes: whole 128-lane tiles of ``N``, ``P`` and the
+    head blocks whole 8-sublane tiles (interpreted, any shape goes)."""
+    return N % 128 == 0 and P % 8 == 0 and ssd_heads_per_block(H) % 8 == 0
+
+
+def ssd_step_bytes(rows: float, H: int, P: int, N: int) -> float:
+    """Bytes the kernel must move for ``rows`` live rows of one layer: the
+    state in and out, dx in and y out, the decays, B and C (float32)."""
+    return rows * (H * (2.0 * P * N + 2 * P + 1) + 2 * N) * 4
+
+
+def _ssd_kernel(lidx_ref, rows_ref, nl_ref, fresh_ref, s_ref, dx_ref, a_ref,
+                b_ref, c_ref, y0_ref, so_ref, y_ref, *, hb: int):
+    del lidx_ref, y0_ref            # index maps and aliasing only
+    i = pl.program_id(0)
+    nl = nl_ref[0]
+    f32 = jnp.float32
+
+    @pl.when(i < nl)
+    def _live():
+        P = s_ref.shape[1]
+        # a row that starts a request starts from zeros, whatever the slot
+        # held
+        keep = jnp.where(fresh_ref[rows_ref[i]] != 0, 0.0, 1.0).astype(f32)
+        # [hb, P] -> [P, hb], exactly; the decays as columns too (a head's
+        # scalar over its P sublanes: Mosaic broadcasts along one of lanes
+        # and sublanes at a time)
+        columns = functools.partial(_columns, _identity(P))
+        dxT = columns(dx_ref[...])
+        aT = columns(jnp.broadcast_to(a_ref[...] * keep, (hb, P)))
+        B, C = b_ref[...], c_ref[...]                       # [1, N]
+        lane = jax.lax.broadcasted_iota(jnp.int32, (P, hb), 1)
+        yT = jnp.zeros((P, hb), f32)
+        for h in range(hb):         # a head's [P, N] tile at a time
+            S = s_ref[h] * aT[:, h:h + 1] + dxT[:, h:h + 1] * B
+            so_ref[h] = S
+            yT = jnp.where(lane == h,
+                           jnp.sum(S * C, axis=1, keepdims=True), yT)
+        y_ref[...] = _columns(_identity(hb), yT)            # [hb, P]
+
+    @pl.when((i == 0) & (nl == 0))
+    def _nobody():
+        # no live row at all: program 0's block is fetched and written back
+        # all the same, so it goes back as it came
+        so_ref[...] = s_ref[...]
+        y_ref[...] = jnp.zeros_like(y_ref)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def ssd_state_step(state, layer_idx, dx, a, B, C, live, fresh,
+                   interpret: bool = False):
+    """One token a row through the state of layer ``layer_idx``.
+
+    ``state`` ``[L, R, H, P, N]`` float32, updated in place (donate it);
+    ``dx`` ``[R, H, P]`` (``dt * x``), ``a`` ``[R, H]`` (the decay, in (0,
+    1]), ``B, C`` ``[R, N]``, float32; ``live`` ``[R]`` bool: rows that have
+    a token; ``fresh`` ``[R]`` bool: rows that start from zeros. Returns
+    ``(y [R, H, P] float32, the stack)``; an idle row's ``y`` is zeros and
+    its state untouched."""
+    L, R, H, P, N = state.shape
+    hb = ssd_heads_per_block(H)
+    nhb = H // hb
+    rows, nl = live_rows_first(live)
+    scalars = (jnp.asarray(layer_idx, jnp.int32).reshape(1), rows,
+               nl.reshape(1), fresh.astype(jnp.int32))
+    maps = StepMaps(nhb)
+    f32 = jnp.float32
+    block = pl.BlockSpec((None, None, hb, P, N), maps.state)
+    vec = pl.BlockSpec((None, hb, P), maps.row)
+    shared = pl.BlockSpec((None, 1, N), maps.shared)
+    new, y = pl.pallas_call(
+        functools.partial(_ssd_kernel, hb=hb),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(scalars), grid=(R, nhb),
+            in_specs=[block, vec,
+                      pl.BlockSpec((None, None, hb, 1), maps.head_scalar),
+                      shared, shared, pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=[block, vec]),
+        out_shape=[jax.ShapeDtypeStruct(state.shape, f32),
+                   jax.ShapeDtypeStruct((R, H, P), f32)],
+        # operands count the scalars: the stack is operand 4, the zeros the
+        # idle rows' output keeps operand 9
+        input_output_aliases={len(scalars): 0, len(scalars) + 5: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        cost_estimate=pl.CostEstimate(
+            flops=int(5 * R * H * P * N), transcendentals=0,
+            bytes_accessed=int(ssd_step_bytes(R, H, P, N))),
+        interpret=interpret, name=SSD_NAME,
+    )(*scalars, state, dx.astype(f32), a.astype(f32).reshape(R, nhb, hb, 1),
+      B.astype(f32).reshape(R, 1, N), C.astype(f32).reshape(R, 1, N),
+      jnp.zeros((R, H, P), f32))
+    return y, new

@@ -2,7 +2,7 @@
 
 Capability parity with the reference model zoo (reference inference/models/
 llama.cc, opt.cc, falcon.cc, mpt.cc, starcoder.cc and their Python twins in
-python/flexflow/serve/models/; OLMoE, EXAONE-MoE, Mistral-4, SDAR-MoE, LongCat-Flash, ZAYA1 and Solar-Open2, sparse-expert families, and EvaByte, a byte-level model of chunked attention, are beyond it): each model family is a builder that records
+python/flexflow/serve/models/; OLMoE, EXAONE-MoE, Mistral-4, SDAR-MoE, LongCat-Flash, ZAYA1 and Solar-Open2, sparse-expert families, EvaByte, a byte-level model of chunked attention, and Granite-4.0-H, a hybrid of state-space mixers and attention, are beyond it): each model family is a builder that records
 the decoder graph through the FFModel op-builder surface, plus a HuggingFace
 state-dict name mapping so real checkpoints load. ``FAMILIES`` maps the HF
 ``model_type`` to the family (the reference's ModelType enum +
@@ -15,6 +15,7 @@ from typing import Callable, Optional
 from flexflow_tpu.models import evabyte as _evabyte
 from flexflow_tpu.models import exaone_moe as _exaone_moe
 from flexflow_tpu.models import falcon as _falcon
+from flexflow_tpu.models import granite_hybrid as _granite_hybrid
 from flexflow_tpu.models import llama as _llama
 from flexflow_tpu.models import longcat_flash as _longcat_flash
 from flexflow_tpu.models import mistral4 as _mistral4
@@ -29,6 +30,8 @@ from flexflow_tpu.models.evabyte import EvaByteConfig, create_evabyte_model
 from flexflow_tpu.models.exaone_moe import (ExaoneMoEConfig,
                                             create_exaone_moe_model)
 from flexflow_tpu.models.falcon import FalconConfig, create_falcon_model
+from flexflow_tpu.models.granite_hybrid import (GraniteHybridConfig,
+                                                create_granite_hybrid_model)
 from flexflow_tpu.models.hf_utils import load_hf_state_dict
 from flexflow_tpu.models.llama import LLAMAConfig, create_llama_model
 from flexflow_tpu.models.longcat_flash import (LongcatFlashConfig,
@@ -106,6 +109,11 @@ FAMILIES = {
                                create_solar_open2_model,
                                _solar_open2.hf_weight_map,
                                _solar_open2.preprocess_hf_state_dict),
+    # (and this one's ops/ssd_mixer.py)
+    "granitemoehybrid": ModelFamily("granitemoehybrid", GraniteHybridConfig,
+                                    create_granite_hybrid_model,
+                                    _granite_hybrid.hf_weight_map,
+                                    _granite_hybrid.preprocess_hf_state_dict),
     "zaya": ModelFamily("zaya", ZayaConfig, create_zaya_model,
                         _zaya.hf_weight_map,
                         _zaya.preprocess_hf_state_dict),
@@ -131,6 +139,7 @@ __all__ = [
     "ExaoneMoEConfig",
     "FAMILIES",
     "FalconConfig",
+    "GraniteHybridConfig",
     "LLAMAConfig",
     "LongcatFlashConfig",
     "MPTConfig",
@@ -145,6 +154,7 @@ __all__ = [
     "create_evabyte_model",
     "create_exaone_moe_model",
     "create_falcon_model",
+    "create_granite_hybrid_model",
     "create_llama_model",
     "create_longcat_flash_model",
     "create_mistral4_model",

@@ -658,9 +658,12 @@ CHUNKED_STACK = "kv_cache_chunked"
 # (ops/cca_attention.py) keeps, beside its plain k/v cache, a row's TAIL:
 # op_state[TAIL_STACK] = {"t": [L, R, width]}, overwritten by every step.
 TAIL_STACK = "cca_tail"
-# A layer that keeps a recurrent state and no cache of positions
-# (ops/kda_attention.py): op_state[RECURRENT_STACK] = {"s": [L, R, H, K, V]
-# float32, "u": [L, R, taps - 1, channels]}, overwritten by every step.
+# A layer that keeps a recurrent state and no cache of positions (the
+# contract of ops/recurrent.py; ops/kda_attention.py: a gated delta rule,
+# ops/ssd_mixer.py: a state-space mixer): op_state[RECURRENT_STACK] = {"s":
+# [L, R, ...] float32 (KDA: [H, K, V]; the mixer: [H, P, N]), "u": [L, R,
+# taps - 1, channels]}, overwritten by every step. (The key's "kda" is
+# historical: the benchmark's files read it by this constant.)
 RECURRENT_STACK = "kda_state"
 
 
@@ -733,8 +736,9 @@ def refuse_windowed(op_state, what: str):
     if RECURRENT_STACK in (op_state or {}):
         raise NotImplementedError(
             f"{what} is not supported over an attention layer that keeps a "
-            "recurrent state: a slot holds one state a layer "
-            "(ops/kda_attention.py), the sum of every position so far, "
+            "recurrent state, or a state-space mixer, which does too: a "
+            "slot holds one state a layer (ops/kda_attention.py, "
+            "ops/ssd_mixer.py), the sum of every position so far, "
             "overwritten every step and kept at no position, so a "
             "rejected draft cannot be rolled back, a shared prefix has no "
             "snapshot to start from, a moved position nothing to move, "

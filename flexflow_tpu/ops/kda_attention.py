@@ -14,10 +14,11 @@ that keeps, a row, a RECURRENT STATE and no cache of positions.
     y_t = W_o (RMSNorm_V(o_t[h]) * w_n * sigmoid((W_gb (W_ga x_t))[h]))
 
 What a row carries from step to step, a layer: ``S [H, K, V]`` float32 and
-the last three ``u`` (the convolutions' tails). Both are the op's state,
-``op_state[RECURRENT_STACK] = {"s": [layers, slots, H, K, V], "u": [layers,
-slots, 3, 3 H K]}``, both float32, OVERWRITTEN by every step that gives a
-slot tokens. One recurrence, two forms:
+the last three ``u`` (the convolutions' tails). Both are the op's state, by
+the contract of ops/recurrent.py (which ops/ssd_mixer.py meets too): the
+cache manager stacks them as ``op_state[RECURRENT_STACK] = {"s": [layers,
+slots, H, K, V], "u": [layers, slots, 3, 3 H K]}``, both float32, OVERWRITTEN
+by every step that gives a slot tokens. One recurrence, two forms:
 
 * RECURRENT (a decode step, one token a row): ``S' = exp(g)[:, None] * S``;
   ``d = beta (v - S'^T k)``; ``S = S' + k d^T``; ``o = S^T q``. On the
@@ -44,14 +45,10 @@ slot tokens. One recurrence, two forms:
 Padding positions of a row (``t >= n``) have ``g = 0`` and ``beta = 0``: the
 state passes them as it is, and they do not enter the tails.
 
-Where a row's state and tails come from (the rules of
-``cca_attention.take_tails``): zeros where the row starts a request
-(``start_pos == 0``, whatever the slot held); the END of another row of the
-same step where that row is the same slot's and ends where this one starts
-(the compact prefill batch's consecutive segments, all in one forward); the
-stored state otherwise; and the step writes back each slot's LAST row's.
-The tails (5 KB) go through ``inc_attention.carried_rows``, a gather, selects
-and a scatter; the state (4 MB) on the kernel path through
+Where a row's state and tails come from: the three rules of
+ops/recurrent.py. The tails (5 KB) go through ``recurrent.runs_with_tails``
+(``inc_attention.carried_rows``: a gather, selects and a scatter); the state
+(4 MB) on the kernel path through
 ``linear_attention.chunk_sources``, the same rule as scalars: the kernel
 takes a slot's rows one after another, so "the row before" is what VMEM
 holds (the jnp path: ``carried_rows`` again).
@@ -62,6 +59,7 @@ carry the state along: ``inc_attention.refuse_windowed`` refuses them.
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -71,6 +69,7 @@ from flexflow_tpu.core.initializer import (NormInitializer,
                                            default_kernel_initializer)
 from flexflow_tpu.core.layer import WeightSpec
 from flexflow_tpu.ffconst import DataType, OpType
+from flexflow_tpu.ops import recurrent as REC
 from flexflow_tpu.ops.base import OpImpl, register_op
 from flexflow_tpu.ops.inc_attention import RECURRENT_STACK, carried_rows
 
@@ -85,22 +84,6 @@ def _dims(attrs):
     convolution's kernel, the rank of the two low-rank gates."""
     D = attrs["head_dim"]
     return attrs["num_heads"], D, D, attrs["conv_kernel"], attrs["gate_rank"]
-
-
-class _DecayInitializer:
-    """``A_log`` and ``dt_bias`` as the published layer seeds them: ``A ~
-    U(1, 16)`` a head, ``dt`` log-uniform in [1e-3, 1e-1] a channel,
-    ``dt_bias = softplus^-1(dt)``. ``what``: "A_log" or "dt_bias"."""
-
-    def __init__(self, what: str):
-        self.what = what
-
-    def __call__(self, key, shape, dtype):
-        u = jax.random.uniform(key, shape, jnp.float32)
-        if self.what == "A_log":
-            return jnp.log(1.0 + 15.0 * u).astype(dtype)
-        dt = jnp.exp(math.log(1e-3) + u * (math.log(1e-1) - math.log(1e-3)))
-        return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
 
 
 def _weight_specs(attrs, input_specs):
@@ -121,8 +104,8 @@ def _weight_specs(attrs, input_specs):
         # the depthwise taps, tap j weighs position t - (taps - 1) + j;
         # seeded so that a missing tap is seen
         WeightSpec("conv", (taps, 3 * H * K), dt, NormInitializer(stddev=0.5)),
-        WeightSpec("A_log", (H,), f32, _DecayInitializer("A_log")),
-        WeightSpec("dt_bias", (H * K,), f32, _DecayInitializer("dt_bias")),
+        WeightSpec("A_log", (H,), f32, REC.DecayInitializer("A_log")),
+        WeightSpec("dt_bias", (H * K,), f32, REC.DecayInitializer("dt_bias")),
         WeightSpec("o_norm", (V,), dt, NormInitializer(mean=1.0, stddev=0.02)),
         WeightSpec("wo", (H * V, E), dt, init),
     ]
@@ -134,8 +117,8 @@ def _init_state(attrs, input_specs):
     # both float32 whatever the cache's dtype: what a recurrent layer
     # carries from step to step is summed into everything after it (the
     # tails are 0.3 MB a row a layer beside the state's 4.19)
-    return {"kda_s": jnp.zeros((R, H, K, V), jnp.float32),
-            "kda_u": jnp.zeros((R, taps - 1, 3 * H * K), jnp.float32)}
+    return {REC.STATE: jnp.zeros((R, H, K, V), jnp.float32),
+            REC.TAIL: jnp.zeros((R, taps - 1, 3 * H * K), jnp.float32)}
 
 
 # ----------------------------------------------------------------------
@@ -294,15 +277,6 @@ def chunked(S0, q, k, g, v, beta, chunk: int = CHUNK, sub: int = SUB):
 # the op
 # ----------------------------------------------------------------------
 
-def _conv(params, ext_u, Q: int):
-    """``ext_u [R, Q + taps - 1, C]``, a run with its tail in front -> the
-    mixed, activated ``[R, Q, C]`` in float32."""
-    taps = params["conv"].astype(jnp.float32)
-    eu = ext_u.astype(jnp.float32)
-    mixed = sum(taps[j] * eu[:, j:j + Q] for j in range(taps.shape[0]))
-    return jax.nn.silu(mixed)
-
-
 def _unit(x):
     return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
 
@@ -330,32 +304,15 @@ def _gates(attrs, params, x):
     return g, beta, gate.reshape(R, Q, H, V)
 
 
-def kernel_path(attrs, config):
-    """Whether the layer's recurrence runs its Pallas kernels
-    (kernels/linear_attention.py), by what the process can observe: None
-    (the jnp forms), else whether they are interpreted."""
-    from flexflow_tpu import kernels as ffk
-
-    if not (attrs.get("use_pallas", True) and ffk.use_pallas(config)):
-        return None
-    return ffk.pallas_interpret_forced()
-
-
 def takes_chunk_kernel(attrs, config) -> bool:
     """Whether a prefill step of this layer runs ``kda_chunk`` (else the
     jnp ``chunked``)."""
     from flexflow_tpu.kernels import linear_attention as LA
 
-    interpret = kernel_path(attrs, config)
+    interpret = REC.kernel_path(attrs, config)
     H, K, V, _, _ = _dims(attrs)
     return interpret is not None and (interpret
                                       or LA.supports_chunk(H, K, V))
-
-
-def _end_of(ext_u, n_i, width: int):
-    """The last ``width`` positions before position ``n_i`` of one row's run
-    with its tail in front: the tail its last real token leaves."""
-    return jax.lax.dynamic_slice_in_dim(ext_u, n_i, width, axis=0)
 
 
 @register_op
@@ -374,6 +331,8 @@ class IncKDAttention(OpImpl):
 
     weight_specs = staticmethod(_weight_specs)
     init_state = staticmethod(_init_state)
+    # (the contract of ops/recurrent.py)
+    takes_chunk_kernel = staticmethod(takes_chunk_kernel)
 
     @staticmethod
     def forward(attrs, params, inputs, ctx):
@@ -384,16 +343,8 @@ class IncKDAttention(OpImpl):
         x = inputs[0]
         meta = ctx.batch_config
         assert meta is not None, "serving ops need ctx.batch_config"
-        if (hasattr(meta, "ancestor")
-                or getattr(ctx, "kv_override", None) is not None
-                or getattr(ctx, "kv_append_q", None) is not None):
-            raise NotImplementedError(
-                "an attention layer that keeps a recurrent state is served "
-                "by incremental decoding on one chip, a token a row a "
-                "step: a tree's nodes, a verify-wide decode step and a "
-                "pipeline stage's microbatch would each overwrite a state "
-                "that cannot be rolled back or handed on")
-        H, K, V, taps, _ = _dims(attrs)
+        REC.refuse_staged(meta, ctx)
+        H, K, V, _, _ = _dims(attrs)
         R, Q = x.shape[:2]
         f32 = jnp.float32
         # [R, Q, 3 H K], float32 as the tails that carry it on
@@ -403,49 +354,22 @@ class IncKDAttention(OpImpl):
         real = (jnp.arange(Q)[None, :] < n[:, None])
         g = jnp.where(real[..., None, None], g, 0)
         beta = jnp.where(real[..., None], beta, 0)
-        st = ctx.state_out.get(RECURRENT_STACK) or ctx.state_in[
-            RECURRENT_STACK]
-        S_all, U_all = st["s"], st["u"]
+        S_all, U_all = REC.stack_of(ctx)
         lidx = attrs["state_layer_idx"]
         start, slots = meta.start_pos, meta.slots
-        width = taps - 1
-
-        def with_tail(t, run):
-            return jnp.concatenate([t, run], axis=-2)
-
-        def fresh(t):       # at a request's start nothing came before
-            keep = (start != 0).reshape((-1,) + (1,) * (t.ndim - 1))
-            return jnp.where(keep, t, 0)
-
-        def wrote(new, old):
-            keep = (n > 0).reshape((-1,) + (1,) * (new.ndim - 1))
-            return jnp.where(keep, new.astype(old.dtype), old)
+        fresh = functools.partial(REC.fresh, start=start)
+        wrote = functools.partial(REC.wrote, n=n)
 
         # 1. the convolutions' tails, then q, k, v for every row at once
-        if slots is None:
-            old_u = U_all[lidx]
-            ext_u = with_tail(fresh(old_u), u)
-            if Q == 1:      # the new tail: this token behind the old one's
-                ends = ext_u[:, 1:]
-            else:
-                ends = jax.vmap(lambda e, n_i: _end_of(e, n_i, width))(
-                    ext_u, n)
-            U_all = U_all.at[lidx].set(wrote(ends, old_u))
-        else:
-            def run_of(i, t):
-                ext = with_tail(t, u[i])
-                return ext, _end_of(ext, n[i], width)
-
-            runs, U_all = carried_rows(U_all, slots, start, n, run_of,
-                                       layer=lidx)
-            ext_u = jnp.stack(runs)
-        mixed = _conv(params, ext_u, Q).reshape(R, Q, 3, H, K)
+        ext_u, U_all = REC.runs_with_tails(U_all, lidx, u, start, slots, n)
+        mixed = REC.depthwise_conv(params["conv"], ext_u,
+                                   Q).reshape(R, Q, 3, H, K)
         q = _unit(mixed[:, :, 0]) * (1.0 / math.sqrt(K))
         k = _unit(mixed[:, :, 1])
         v = mixed[:, :, 2]
 
         # 2. the state
-        interpret = kernel_path(attrs, ctx.config)
+        interpret = REC.kernel_path(attrs, ctx.config)
         chunk_kernel = takes_chunk_kernel(attrs, ctx.config)
         if Q > 1 or slots is not None:
             LA.record_chunk_form("kernel" if chunk_kernel else "jnp", R, Q)

@@ -148,7 +148,9 @@ _QUANT_NAMES = {"kernel", "wq", "wk", "wv", "wo", "weight",
                 "wg",
                 # ops/kda_attention.py: the joined first halves of its
                 # low-rank gates with beta's projection, and their second
-                "wlow", "wfb", "wgb"}
+                "wlow", "wfb", "wgb",
+                # ops/ssd_mixer.py: the mixer's two matrices
+                "win", "wout"}
 # ... and of those, the ones that may be a stack [E, in, out] (ops/moe.py;
 # a latent layer's up-projection halves, a head apart)
 _STACKED_NAMES = {"gate", "up", "down", "wk_b", "wv_b"}

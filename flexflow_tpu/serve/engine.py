@@ -285,7 +285,8 @@ PASS_STATS = ("count", "passes", "folded", "by_threshold", "by_floor")
 
 # What a model's tail is made of: the layers that end the graph and work on
 # each position alone (final norm, head), below the pick.
-_POSITION_WISE = (OpType.LINEAR, OpType.RMS_NORM)
+# (a scalar multiple: a head whose logits are scaled, models/granite_hybrid.py)
+_POSITION_WISE = (OpType.LINEAR, OpType.RMS_NORM, OpType.SCALAR_MULTIPLY)
 
 
 def _tail_of(model):

@@ -73,9 +73,9 @@ ffsv_moe_resident_calls_total    counter    {phase} calls that kept their rows i
 ffsv_moe_expert_pairs_total      counter    {expert} routed pairs of one expert
 ffsv_moe_zero_pairs_total        counter    {phase} picks that were no expert (w * x)
 ffsv_cca_tails_total             counter    {phase,source} rows by where their tail came from
-ffsv_kda_states_total            counter    {phase,source} rows by where their recurrent state came from
-ffsv_kda_state_steps_total       counter    live rows x recurrent layers x steps of the decode blocks
-ffsv_kda_chunk_tokens_total      counter    prefill tokens x recurrent layers through the chunked form's kernel
+ffsv_kda_states_total            counter    {phase,source} rows by where their recurrent state came from (any recurrent op)
+ffsv_kda_state_steps_total       counter    live rows x recurrent layers x steps of the decode blocks (any recurrent op)
+ffsv_kda_chunk_tokens_total      counter    prefill tokens x recurrent layers through a chunked form's kernel
 ===============================  =========  =================================
 
 A decode block's step is one token a row, or one pass over a row's block
@@ -112,17 +112,21 @@ segments and a decode block's row-steps by where their tail came from, on
 the host from the step's own rows: ``start`` (position 0: zeros), ``step``
 (another segment of the same step, the same slot's, that ends where this
 one starts) or ``state`` (what an earlier step left in the slot).
-A model with layers that keep a RECURRENT STATE (ops/kda_attention.py: a
-slot holds one state a layer, the sum of every position so far, and the
-convolutions' tails; ``attention_kinds["recurrent"]``) has
+A model with layers that keep a RECURRENT STATE (the contract of
+ops/recurrent.py: a gated delta rule, ops/kda_attention.py, or a state-space
+mixer, ops/ssd_mixer.py; a slot holds one state a layer, the sum of every
+position so far, and the convolutions' tails;
+``attention_kinds["recurrent"]``, which names the op under ``op``; the
+``kda`` in the three series' names is historical, they count the kind
+whatever the op: the benchmark's readers hold the names) has
 ``ffsv_kv_cache_bytes{kind="recurrent"}`` (states and tails) beside
 ``kind="full"`` (its plain k/v caches, the only kind whose positions are
 read), ``ffsv_kda_states_total{phase,source}``, the twin of
 ``ffsv_cca_tails_total`` (``step``: the hand-over of a state inside one
 prefill step), and ``ffsv_kda_state_steps_total``: live rows x recurrent
 layers x steps of the decode blocks, each one state read and written;
-and, where a prefill step's chunked form runs as the kernel ``kda_chunk``
-(``attention_kinds["recurrent"]["chunk_kernel"]``),
+and, where a prefill step's chunked form runs as a kernel (``kda_chunk``;
+the mixer's is plain XLA: ``attention_kinds["recurrent"]["chunk_kernel"]``),
 ``ffsv_kda_chunk_tokens_total``: prefill tokens x recurrent layers.
 ``ffsv_kv_cache_bytes`` is what compile allocated for each kind;
 ``ffsv_attn_positions_read_total`` is what the rows of the decode steps had
@@ -349,8 +353,8 @@ class PendingPrefill:
 def _carried_series(kinds) -> Optional[str]:
     """The counter of a model whose slots carry something every step
     overwrites, by ``FFModel.attention_kinds``: a tail beside a cache
-    (ops/cca_attention.py), a recurrent state (ops/kda_attention.py), or
-    None."""
+    (ops/cca_attention.py), a recurrent state (ops/kda_attention.py,
+    ops/ssd_mixer.py), or None."""
     if "recurrent" in (kinds or ()):
         return "ffsv_kda_states_total"
     if "tail_bytes" in (kinds or {}).get("full", ()):

@@ -780,6 +780,7 @@ CELL_STREAMS = {
     "mistral-small-4-119b": (32, 32768),
     "longcat-flash-omni": (64, 8192),
     "solar-open2-250b": (8, 64, 128, 16384, 1, 256),    # its GQA layers
+    "granite-4.0-h-micro": (8, 32, 64, 8192, 1, 256),   # packed: outside
 }
 
 

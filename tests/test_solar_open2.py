@@ -481,7 +481,7 @@ def test_program_matches_plain_reference_through_hand_over_and_decode(bench):
                       "cache_bytes": 3 * 4 * (4 * 16 * 16 + 3 * 192) * 4,
                       "state_bytes": 3 * 4 * 4 * 16 * 16 * 4,
                       "conv_bytes": 3 * 4 * 3 * 192 * 4,
-                      "chunk_kernel": False}}
+                      "op": "INC_KDA_ATTENTION", "chunk_kernel": False}}
     assert m.op_state[RECURRENT_STACK]["s"].dtype == jnp.float32
     assert m.op_state[FULL_STACK]["k"].shape[0] == 1
     toks = _tokens(16 + 16 + 11 + 8)
