@@ -3,8 +3,12 @@ a gated delta rule with one decay a key channel (ops/kda_attention.py):
 ``kda_state_step``, the RECURRENT form (a decode step: one token a row), and
 ``kda_chunk``, the CHUNKED form (a prefill step: a row's tokens in chunks of
 64); and the recurrent form of a state-space mixer (ops/ssd_mixer.py):
-``ssd_state_step`` (its section below). The two recurrent kernels share
-their scaffolding: ``live_rows_first`` and ``StepMaps``.
+``ssd_state_step`` (its section below: a row's 64 heads one program and
+one descriptor of 2.1 MB, the decay a scalar from SMEM, ``y = S C`` summed
+along the lanes by merging the heads' tiles, a rotation a register, with
+no register reduced alone). The two recurrent kernels share their
+scaffolding, ``live_rows_first`` and ``StepMaps``, and ``_columns``; their
+bodies share no arithmetic.
 
 **The recurrent form.** A row's state is ``S [H, K, V]`` float32 (4.19 MB
 at 64 heads of 128 x 128). A decode step reads ALL of it and writes ALL of
@@ -602,26 +606,62 @@ def kda_chunk(state, layer_idx, q, k, g, v, beta, slots, start, n,
 # operands: its write is ``k (beta (v - S'^T k))^T``, and ``beta = 0``
 # switches the write off with the correction. The scaffolding is the same
 # (the stack aliased in and out, the layer an operand, the live rows first,
-# an idle row neither fetched nor written). In a head's ``[P, N]`` tile
-# ``B`` and ``C`` are rows as they come; ``dx`` is wanted as a COLUMN
-# (``_columns``), ``y`` comes out as one (a sum along the lanes), is put in
-# its head's lane of ``[P, heads]`` and handed back through the identity
-# again.
+# an idle row neither fetched nor written); the body shares ``_columns``
+# and nothing else. A program is ``ssd_heads_per_block`` heads, one
+# descriptor of 2.1 MB at these widths (the whole row's 64 heads); in and
+# out double-buffered 8.4 MB of VMEM, inside the 16 MiB a call has without
+# asking. It states NO limit of its own: XLA keeps the next gemms' weights
+# moving into VMEM while a kernel runs, and with 96 MiB stated here a decode
+# step of Granite-4.0-H's cell took 7.61 ms where it now takes 7.09
+# (PERF.md section 6, PR 57).
+#
+# What the body costs is its cross-lane work (PERF.md section 6, PR 57: the
+# stream alone takes 208 us a layer-step of 32 rows and every multiply and
+# add hides behind it; a lane reduction a register did not):
+#
+# * the decays ``[R, H]`` lie in SMEM: ``a[r, h]`` times the row's
+#   ``keep`` is a SCALAR that multiplies a head's tile as a splat;
+# * ``dx`` is wanted as a COLUMN (``_columns``, once a program) and a
+#   head's column is broadcast along the lanes, one broadcast a register:
+#   inherent to ``N`` on the lanes;
+# * ``y = S C`` sums along the lanes. No register is reduced alone: the
+#   products ``S[h] * C`` of TWO heads are merged into one tile, lane ``l``
+#   taking ``x[l] + x[l - 1]`` of the first head where ``l`` is even and of
+#   the second where it is odd (two selects, one rotation by a lane, one
+#   add); two such tiles the same way two lanes apart, and so on
+#   (``_merge``): after ``log2(m)`` levels lane ``l`` of ONE tile holds the
+#   sum of ``m`` lanes ending at ``l`` for head ``l % m``, at one rotation
+#   a register of state, and ``_fold_lanes`` doubles the windows up to
+#   ``N``. Every lane ``l`` of the ``[P, N]`` tile left then holds
+#   ``y[l % m, p]`` whole: ``y`` as columns, head ``h`` in lane ``h``,
+#   handed back as rows ``[m, P]`` through ``_head_lanes`` (the identity's
+#   first ``m`` rows: ``_columns`` again, exact).
 
 SSD_NAME = "ssd_state_step"
-# heads a program: 32 x 64 x 128 float32 = 1.05 MB a descriptor, as
-# ``kda_state_step``'s 16 x 128 x 128
-SSD_HEADS_PER_BLOCK = 32
+# a program's descriptor: 64 x 64 x 128 float32
+SSD_BLOCK_BYTES = 2 * 1024 * 1024
 
 
-def ssd_heads_per_block(H: int) -> int:
-    return heads_per_block(H, SSD_HEADS_PER_BLOCK)
+def ssd_heads_per_block(H: int, P: int, N: int) -> int:
+    """Heads a program: as many as one descriptor of ``SSD_BLOCK_BYTES``
+    holds, a divisor of ``H``."""
+    return heads_per_block(H, max(SSD_BLOCK_BYTES // (4 * P * N), 1))
+
+
+def ssd_merged_heads(hb: int, N: int) -> int:
+    """Heads whose sums share one tile's lanes: the largest power of two
+    that divides the program's heads and the lanes."""
+    m = 1
+    while hb % (2 * m) == 0 and N % (2 * m) == 0:
+        m *= 2
+    return m
 
 
 def supports_ssd(H: int, P: int, N: int) -> bool:
     """Shapes Mosaic takes: whole 128-lane tiles of ``N``, ``P`` and the
     head blocks whole 8-sublane tiles (interpreted, any shape goes)."""
-    return N % 128 == 0 and P % 8 == 0 and ssd_heads_per_block(H) % 8 == 0
+    return (N % 128 == 0 and P % 8 == 0
+            and ssd_heads_per_block(H, P, N) % 8 == 0)
 
 
 def ssd_step_bytes(rows: float, H: int, P: int, N: int) -> float:
@@ -630,34 +670,72 @@ def ssd_step_bytes(rows: float, H: int, P: int, N: int) -> float:
     return rows * (H * (2.0 * P * N + 2 * P + 1) + 2 * N) * 4
 
 
+def _head_lanes(m: int, N: int):
+    """``[m, N]``: row ``h`` picks lane ``h``."""
+    return (jax.lax.broadcasted_iota(jnp.int32, (m, N), 0)
+            == jax.lax.broadcasted_iota(jnp.int32, (m, N), 1)).astype(
+                jnp.bfloat16)
+
+
+def _merge(x0, x1, sh: int, first):
+    """Two tiles ``[P, N]`` whose lanes hold sums of ``sh`` lanes ending
+    there -> one whose lanes hold sums of ``2 sh``: of ``x0`` where
+    ``first`` (bit ``sh`` of the lane clear), of ``x1`` elsewhere. The
+    rotation is cyclic and ``N`` a multiple of ``2 sh``, so a lane's
+    partner ``l - sh`` has the bit the other way and the same bits below:
+    the same tile's, the same head's."""
+    return jnp.where(first, x0, x1) + pltpu.roll(
+        jnp.where(first, x1, x0), sh, 1)
+
+
+def _fold_lanes(x, span: int):
+    """Lanes that hold sums of ``span`` lanes ending there -> sums of all
+    ``N``: the window doubled while the rest is even, then added up."""
+    N = x.shape[-1]
+    while span < N and (N // span) % 2 == 0:
+        x = x + pltpu.roll(x, span, 1)
+        span *= 2
+    return functools.reduce(lambda u, k: u + pltpu.roll(x, k * span, 1),
+                            range(1, N // span), x)
+
+
 def _ssd_kernel(lidx_ref, rows_ref, nl_ref, fresh_ref, s_ref, dx_ref, a_ref,
                 b_ref, c_ref, y0_ref, so_ref, y_ref, *, hb: int):
     del lidx_ref, y0_ref            # index maps and aliasing only
     i = pl.program_id(0)
+    first_head = pl.program_id(1) * hb
     nl = nl_ref[0]
     f32 = jnp.float32
 
     @pl.when(i < nl)
     def _live():
-        P = s_ref.shape[1]
+        P, N = s_ref.shape[1:]
+        r = rows_ref[i]
         # a row that starts a request starts from zeros, whatever the slot
         # held
-        keep = jnp.where(fresh_ref[rows_ref[i]] != 0, 0.0, 1.0).astype(f32)
-        # [hb, P] -> [P, hb], exactly; the decays as columns too (a head's
-        # scalar over its P sublanes: Mosaic broadcasts along one of lanes
-        # and sublanes at a time)
-        columns = functools.partial(_columns, _identity(P))
-        dxT = columns(dx_ref[...])
-        aT = columns(jnp.broadcast_to(a_ref[...] * keep, (hb, P)))
+        keep = jnp.where(fresh_ref[r] != 0, 0.0, 1.0).astype(f32)
+        dxT = _columns(_identity(P), dx_ref[...])           # [P, hb]
         B, C = b_ref[...], c_ref[...]                       # [1, N]
-        lane = jax.lax.broadcasted_iota(jnp.int32, (P, hb), 1)
-        yT = jnp.zeros((P, hb), f32)
-        for h in range(hb):         # a head's [P, N] tile at a time
-            S = s_ref[h] * aT[:, h:h + 1] + dxT[:, h:h + 1] * B
+        m = ssd_merged_heads(hb, N)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (P, N), 1)
+        first = {sh: (lane & sh) == 0 for sh in
+                 (1 << k for k in range(m.bit_length() - 1))}
+
+        def summed(h, count):
+            """Heads ``h .. h + count``: each head's tile updated and
+            written, their ``S * C`` merged."""
+            if count > 1:
+                sh = count // 2
+                return _merge(summed(h, sh), summed(h + sh, sh), sh,
+                              first[sh])
+            S = (s_ref[h] * (a_ref[r, first_head + h] * keep)
+                 + dxT[:, h:h + 1] * B)
             so_ref[h] = S
-            yT = jnp.where(lane == h,
-                           jnp.sum(S * C, axis=1, keepdims=True), yT)
-        y_ref[...] = _columns(_identity(hb), yT)            # [hb, P]
+            return S * C
+
+        for g in range(0, hb, m):
+            yT = _fold_lanes(summed(g, m), m)       # [P, N], head g + l % m
+            y_ref[g:g + m, :] = _columns(_head_lanes(m, N), yT)
 
     @pl.when((i == 0) & (nl == 0))
     def _nobody():
@@ -679,7 +757,7 @@ def ssd_state_step(state, layer_idx, dx, a, B, C, live, fresh,
     ``(y [R, H, P] float32, the stack)``; an idle row's ``y`` is zeros and
     its state untouched."""
     L, R, H, P, N = state.shape
-    hb = ssd_heads_per_block(H)
+    hb = ssd_heads_per_block(H, P, N)
     nhb = H // hb
     rows, nl = live_rows_first(live)
     scalars = (jnp.asarray(layer_idx, jnp.int32).reshape(1), rows,
@@ -693,8 +771,7 @@ def ssd_state_step(state, layer_idx, dx, a, B, C, live, fresh,
         functools.partial(_ssd_kernel, hb=hb),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(scalars), grid=(R, nhb),
-            in_specs=[block, vec,
-                      pl.BlockSpec((None, None, hb, 1), maps.head_scalar),
+            in_specs=[block, vec, pl.BlockSpec(memory_space=pltpu.SMEM),
                       shared, shared, pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=[block, vec]),
         out_shape=[jax.ShapeDtypeStruct(state.shape, f32),
@@ -705,10 +782,10 @@ def ssd_state_step(state, layer_idx, dx, a, B, C, live, fresh,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         cost_estimate=pl.CostEstimate(
-            flops=int(5 * R * H * P * N), transcendentals=0,
+            flops=int(8 * R * H * P * N), transcendentals=0,
             bytes_accessed=int(ssd_step_bytes(R, H, P, N))),
         interpret=interpret, name=SSD_NAME,
-    )(*scalars, state, dx.astype(f32), a.astype(f32).reshape(R, nhb, hb, 1),
+    )(*scalars, state, dx.astype(f32), a.astype(f32),
       B.astype(f32).reshape(R, 1, N), C.astype(f32).reshape(R, 1, N),
       jnp.zeros((R, H, P), f32))
     return y, new

@@ -165,28 +165,49 @@ def test_chunked_recurrent_and_literal_forms_agree(decay):
                                    rtol=2e-4, atol=2e-4)
 
 
-@pytest.mark.parametrize("live", [(1, 0, 1, 1, 0), (0, 0, 0, 0, 0),
-                                  (1, 1, 1, 1, 1), (0, 0, 0, 0, 1)])
-def test_the_recurrent_kernel_interpreted(live):
-    """``ssd_state_step`` in interpret mode against ``recurrent_step``, on a
-    stack of two layers: a live row's state is updated in place, a fresh
-    row's starts from zeros whatever the slot held, an idle row's state is
-    untouched and its output zeros, the other layer is untouched; with
-    nobody live nothing changes."""
-    from flexflow_tpu.kernels.linear_attention import ssd_state_step
-    from flexflow_tpu.ops.ssd_mixer import recurrent_step
+# the toy shape, and one with what the cell's has and the toy has not: two
+# head blocks a row, ``P = 64`` and ``N = 128``, so the merge of 64 heads'
+# sums, its rotations and the placing of ``y`` run at their real widths;
+# and one whose 24 heads merge in three groups of 8 over 48 lanes (the
+# windows double once and are then added up)
+STEP_SHAPES = {"toy": (2, 5, 8, 16, 32), "two_blocks": (2, 5, 128, 64, 128),
+               "uneven": (2, 5, 24, 8, 48)}
 
-    L, R, H, P, N = 2, 5, 8, 16, 32
-    rng = np.random.default_rng(0)
+
+def _step_inputs(shape, seed=0):
+    L, R, H, P, N = shape
+    rng = np.random.default_rng(seed)
     stack = jnp.asarray(rng.standard_normal((L, R, H, P, N)), jnp.float32)
     dx = jnp.asarray(rng.standard_normal((R, H, P)), jnp.float32)
     a = jnp.asarray(rng.uniform(0.05, 1, (R, H)), jnp.float32)
     B, C = (jnp.asarray(rng.standard_normal((R, N)), jnp.float32)
             for _ in range(2))
+    return stack, dx, a, B, C
+
+
+@pytest.mark.parametrize("shape,live", [
+    ("toy", (1, 0, 1, 1, 0)), ("toy", (0, 0, 0, 0, 0)),
+    ("toy", (1, 1, 1, 1, 1)), ("toy", (0, 0, 0, 0, 1)),
+    ("two_blocks", (1, 0, 1, 1, 0)), ("two_blocks", (0, 0, 0, 0, 0)),
+    ("uneven", (1, 0, 1, 1, 0))])
+def test_the_recurrent_kernel_interpreted(shape, live):
+    """``ssd_state_step`` in interpret mode against ``recurrent_step``, on a
+    stack of two layers: a live row's state is updated in place, a fresh
+    row's starts from zeros whatever the slot held, an idle row's state is
+    untouched and its output zeros, the other layer is untouched; with
+    nobody live nothing changes."""
+    from flexflow_tpu.kernels import linear_attention as LA
+    from flexflow_tpu.ops.ssd_mixer import recurrent_step
+
+    L, R, H, P, N = STEP_SHAPES[shape]
+    hb = LA.ssd_heads_per_block(H, P, N)
+    assert (H // hb, LA.ssd_merged_heads(hb, N)) == {
+        "toy": (1, 8), "two_blocks": (2, 64), "uneven": (1, 8)}[shape]
+    stack, dx, a, B, C = _step_inputs(STEP_SHAPES[shape])
     live = jnp.asarray(live, bool)
     fresh = jnp.asarray([0, 1, 1, 0, 0], bool)
-    y, new = ssd_state_step(stack, 1, dx, a, B, C, live, fresh,
-                            interpret=True)
+    y, new = LA.ssd_state_step(stack, 1, dx, a, B, C, live, fresh,
+                               interpret=True)
     S0 = jnp.where(fresh[:, None, None, None], 0, stack[1])
     want_y, want_S = recurrent_step(S0, dx, a, B, C)
     lv = np.asarray(live)
@@ -198,6 +219,40 @@ def test_the_recurrent_kernel_interpreted(live):
     np.testing.assert_array_equal(np.asarray(new)[1][~lv],
                                   np.asarray(stack)[1][~lv])
     np.testing.assert_array_equal(np.asarray(new)[0], np.asarray(stack)[0])
+
+
+@pytest.mark.parametrize("shape", list(STEP_SHAPES))
+def test_the_recurrent_kernel_passes_a_state_through_bit_for_bit(shape):
+    """A decay of 1 and ``dx = 0`` (what a padding position gives): the
+    state goes back as it came, every bit of it, and ``y = S C``."""
+    from flexflow_tpu.kernels.linear_attention import ssd_state_step
+
+    stack, dx, a, B, C = _step_inputs(STEP_SHAPES[shape], seed=1)
+    R = stack.shape[1]
+    live = jnp.ones((R,), bool)
+    y, new = ssd_state_step(stack, 0, jnp.zeros_like(dx), jnp.ones_like(a),
+                            B, C, live, jnp.zeros((R,), bool),
+                            interpret=True)
+    np.testing.assert_array_equal(np.asarray(new), np.asarray(stack))
+    want = np.einsum("rhpn,rn->rhp", np.asarray(stack[0], np.float64),
+                     np.asarray(C, np.float64))
+    np.testing.assert_allclose(np.asarray(y), want, rtol=1e-5, atol=1e-5)
+
+
+def test_the_step_timing_tool_rehearses(capsys):
+    """tools/time_ssd_step.py (the kernel alone at the cell's shape, and the
+    tool's own copies of its body with a part left out), tiny and
+    interpreted: no time is taken here, and the tool's whole copy gives the
+    module's results bit for bit."""
+    sys.path.insert(0, os.path.join(ROOT, "tools"))
+    try:
+        import time_ssd_step
+    finally:
+        sys.path.remove(os.path.join(ROOT, "tools"))
+    assert time_ssd_step.main(["--rehearse"]) == 0
+    res = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert res["device"] == "cpu" and res["whole_is_module"] is True
+    assert not any(k.endswith("_live4") for k in res)
 
 
 @pytest.fixture(scope="module")
@@ -224,14 +279,19 @@ def one_chip():
 def test_the_recurrent_kernel_compiles_for_a_v5e_at_the_cells_shape(one_chip):
     """What interpret mode cannot show: Mosaic takes ``ssd_state_step`` at
     the published widths (the whole model's 36 layers x 32 slots x 64 heads
-    of 64 x 128 float32, 32 heads a program) on a donated stack, under a
-    device name
-    that the gated delta rule's readers do not match, nor its theirs.
-    Compiled for a described chip; nothing runs."""
+    of 64 x 128 float32; a row's 64 heads one program, the 2.1 MB
+    descriptor that ``ssd_heads_per_block`` gives, and its six levels of
+    merges) on a donated stack, under a device name that the gated delta
+    rule's readers do not match, nor its theirs. Compiled for a described
+    chip; nothing runs."""
     from flexflow_tpu.kernels import linear_attention as LA
 
     L, S, H, P, N = 36, 32, 64, 64, 128
-    assert LA.supports_ssd(H, P, N) and LA.ssd_heads_per_block(H) == 32
+    assert LA.supports_ssd(H, P, N)
+    assert LA.ssd_heads_per_block(H, P, N) == 64 == LA.ssd_merged_heads(64, N)
+    # the rule is the descriptor's bytes: twice the tile, half the heads
+    assert LA.ssd_heads_per_block(H, 2 * P, N) == 32
+    assert LA.ssd_heads_per_block(48, P, N) == 48
     assert LA.SSD_NAME == "ssd_state_step"
     for other in (LA.NAME, LA.CHUNK_NAME):
         assert other not in LA.SSD_NAME and LA.SSD_NAME not in other
