@@ -15,7 +15,6 @@ fwd+bwd accounting (attention score/value matmuls included) — MODEL flops,
 not hardware flops: remat or padding would lower, never raise, the number.
 
 Run directly for the full breakdown: ``python bench_train.py``.
-bench.py folds ``train_mfu`` into its JSON line.
 """
 
 from __future__ import annotations

@@ -76,8 +76,8 @@ def chip_for_device(device=None) -> str:
     """The TPU_CHIPS key for a JAX device (default ``jax.devices()[0]``).
 
     The one place a device is identified for peak numbers: the search's
-    cost model (``FFConfig.tpu_chip=None``), bench_train's MFU and
-    bench.py's roofline all resolve through it. CPU maps to ``cpu-sim``;
+    cost model (``FFConfig.tpu_chip=None``) and bench_train's MFU
+    resolve through it. CPU maps to ``cpu-sim``;
     a device that is not in the table raises rather than borrow another
     chip's peaks."""
     if device is None:

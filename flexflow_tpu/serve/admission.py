@@ -3,10 +3,9 @@
 The submission queue in ``serve/api.py`` used to be unbounded: past the
 saturation knee (the point ``serve/loadgen.py`` can now measure), queue
 depth and tail latency grow without bound and every tenant starves
-together. This module is the bounded front door (ROADMAP item 2,
-robustness half): a pure policy object consulted under the server's
-submission lock, rejecting with a structured 429-style
-:class:`RejectedError` instead of queueing forever.
+together. This module is the bounded front door: a pure policy object
+consulted under the server's submission lock, rejecting with a
+structured 429-style :class:`RejectedError` instead of queueing forever.
 
 Three independent admission checks, all cheap enough for the submit path:
 

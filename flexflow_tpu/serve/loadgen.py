@@ -3,7 +3,7 @@
 PR-12 gave the serving stack its instruments (telemetry counters,
 histograms, span traces); this module is what DRIVES them: arrival-driven
 traffic against the continuous batcher, the regime where the SpecInfer
-paper's claims (and ROADMAP item 2's production front door) actually live.
+paper's claims (and a production front door) actually live.
 Back-to-back batch runs measure peak throughput; only arrival-driven load
 exposes queueing, tail latency, and the saturation knee.
 
@@ -31,7 +31,8 @@ Pieces (all seeded + deterministic where determinism is possible):
   the instrument later scaling PRs (adaptive speculation, prefix-sharing
   KV, chunked prefill) are judged with.
 
-Models built without an HF checkpoint (bench.py, tests, tools/loadtest.py)
+Models built without an HF checkpoint (the benchmark's families, tests,
+tools/loadtest.py)
 wrap their FFModel in :class:`EngineHandle`, a duck-typed stand-in for
 ``serve.api.LLM`` that the background server drives identically.
 """
@@ -154,8 +155,7 @@ def build_schedule(spec: WorkloadSpec, n_requests: int, rate_rps: float,
                    ) -> List[LoadRequest]:
     """Draw a deterministic schedule: arrivals, tenant assignment, prompt
     tokens, and output budgets all come from one seeded RandomState, so
-    the same (spec, n, rate, seed) is byte-identical across runs/hosts —
-    the property the bench-trajectory gate depends on."""
+    the same (spec, n, rate, seed) is byte-identical across runs/hosts."""
     rng = np.random.RandomState(seed)
     if process == "poisson":
         arrivals = poisson_arrivals(rate_rps, n_requests, rng)
@@ -206,7 +206,7 @@ class EngineHandle:
 
     ``serve.api._BackgroundServer`` only touches ``.rm``, ``.ffmodel``
     and ``.ssms`` (each exposing ``.ffmodel``), so models built WITHOUT
-    an HF checkpoint (bench.py's synthetic 7B, the test TINY pair,
+    an HF checkpoint (the benchmark's families, the test TINY pair,
     tools/loadtest.py) get the same submission-queue/continuous-batching
     path the user-facing LLM serves through — one serving front door,
     not a parallel harness."""
@@ -524,8 +524,7 @@ def summarize(records: Sequence[RequestRecord],
         "queue_wait_fraction": round(mean_qw / max(mean_lat, 1e-9), 4),
         # shared-prefix reuse: how many prompt tokens the KV pool served
         # instead of the prefill step, and what was actually prefilled
-        # per request after reuse (the FLOP-savings proxy the
-        # serving_prefix bench gate tracks)
+        # per request after reuse (the FLOP-savings proxy)
         "prefix_hit_tokens_total": sum(r.prefix_hit_tokens for r in served),
         "prefill_tokens_per_request": (round(
             sum(r.prompt_tokens - r.prefix_hit_tokens for r in served)
@@ -619,8 +618,8 @@ def overload_run(handle, spec: WorkloadSpec, knee_rps: float,
                  admission=None, slo_policy=None) -> dict:
     """Drive the engine PAST its measured knee and report how it sheds.
 
-    Offered load is ``multiple`` x ``knee_rps`` (the ISSUE/bench gate
-    runs at >=2x). When ``admission`` (an ``AdmissionPolicy`` or
+    Offered load is ``multiple`` x ``knee_rps`` (``tools/loadtest.py
+    --overload`` runs at 2x). When ``admission`` (an ``AdmissionPolicy`` or
     ``AdmissionController``) is given, the handle's server is restarted
     with it so over-limit submissions reject at the front door instead
     of queueing without bound.

@@ -2,14 +2,14 @@
 failover and measured cold start.
 
 PR 16 made a *single* server overload-safe (admission, timeouts,
-preemption, fault injection); this module is the fleet layer on top
-(ROADMAP item 5). A :class:`ReplicaPool` owns N logical replicas — each
+preemption, fault injection); this module is the fleet layer on top.
+A :class:`ReplicaPool` owns N logical replicas — each
 a full engine handle (its own compiled FFModel, RequestManager and
 ``_BackgroundServer``) — and presents the SAME submission surface as a
 single handle (``.rm`` / ``._server.submit`` / ``start_server`` /
 ``stop_server``), so :class:`~flexflow_tpu.serve.loadgen.LoadRunner`,
-``check_invariants`` and the bench harness drive a fleet exactly the way
-they drive one engine.
+``check_invariants`` and ``tools/loadtest.py`` drive a fleet exactly the
+way they drive one engine.
 
 Design points:
 
@@ -39,7 +39,7 @@ Design points:
   (``models/checkpoint_store.py``) with optional quantize-on-load. The
   build+load+start wall time is recorded per replica as
   ``cold_start_s`` — the number an autoscaler actually pays, reported
-  (not guessed) in the ``serving_fleet`` bench section.
+  (not guessed) by :func:`failover_run` and :func:`spike_run`.
 * **Autoscaling loop.** :func:`spike_run` drives a base->spike traffic
   step through the pool while a queue-depth trigger spins up an extra
   replica mid-spike, and reports the SLO-violation-seconds absorbed
@@ -691,7 +691,7 @@ class ReplicaPool:
 
 
 # ---------------------------------------------------------------------------
-# harnesses: seeded crash chaos + autoscaling spike (bench + tests)
+# harnesses: seeded crash chaos + autoscaling spike (loadtest.py + tests)
 # ---------------------------------------------------------------------------
 
 def failover_run(pool: ReplicaPool, spec: WorkloadSpec, rate_rps: float,
@@ -826,7 +826,7 @@ def spike_run(pool: ReplicaPool, spec: WorkloadSpec, base_rps: float,
                       n_scheduled=n_spike)
     from flexflow_tpu.telemetry.slo import replay_records
     # per-phase alert timelines: the base phase is the steady-state
-    # control (zero alerts is a bench floor), the spike phase may burn
+    # control (zero alerts, or the pager flaps), the spike phase may burn
     slo = {"base": replay_records(base_records, policy=slo_policy).report(),
            "spike": replay_records(spike_records,
                                    policy=slo_policy).report()}

@@ -1,12 +1,11 @@
 """Adaptive speculation controller: spec decoding that never loses to
 incremental decoding.
 
-BENCH_r05's ``bf16_acceptance_sweep`` measured static depth-6/8 drafting
-collapsing to 0.476-0.795x of plain incremental decoding once draft
-acceptance drops (eps 0.2 -> 0.494x): every round still pays ``depth``
-draft forwards plus a full verify pass while committing barely more than
-the bonus token. Under real traffic draft/verifier divergence drifts per
-user and per prompt, so a compiled-in depth is a 2x-slower footgun.
+Drafting at a static depth falls below plain incremental decoding once
+draft acceptance drops: every round still pays ``depth`` draft forwards
+plus a full verify pass while committing barely more than the bonus
+token. Under real traffic draft/verifier divergence drifts per user and
+per prompt, so a compiled-in depth is a footgun.
 
 The fix (SpecDec++-style dynamic candidate length on top of the
 SpecInfer token-tree design, PAPERS.md [3]): track observed acceptance

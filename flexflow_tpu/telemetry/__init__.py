@@ -1,7 +1,7 @@
 """Serving telemetry subsystem: metrics registry + per-request tracing.
 
-The instrumentation seam for the serving stack (ROADMAP items 1/2/5 all
-read from here): ``serve/request_manager.py`` and ``serve/engine.py``
+The instrumentation seam for the serving stack:
+``serve/request_manager.py`` and ``serve/engine.py``
 call the ``ServingTelemetry`` hooks below at block granularity, the
 registry exports Prometheus text / JSON (``serve/api.py`` ``/metrics``,
 ``ffsv_metrics_dump`` in the C ABI), and the tracer writes a

@@ -18,9 +18,9 @@ crash failovers, missed deadlines, and per-request latency/TTFT bounds
 are opt-in classifiers. The serving harnesses
 (``loadgen.overload_run``, ``replica.failover_run``/``spike_run``)
 replay their finished request records through :func:`replay_records` in
-completion order and report the structured alert timeline — fired
-alerts during an injected outage, zero in steady state, is a bench
-floor (tools/bench_trend.py ``serving_fleet`` group).
+completion order and report the structured alert timeline: fired
+alerts during an injected outage, zero in steady state
+(``tests/test_observability.py``).
 
 The monitor also annotates each evaluation with the live windowed
 goodput/latency/TTFT percentiles from a :class:`ServingTelemetry` when

@@ -1,7 +1,8 @@
 """Persistent XLA compile cache for the entry points.
 
-Called by the programs a user runs (``chip_smoke.py``, ``bench.py``,
-``bench_train.py``, ``python -m flexflow_tpu.serve``, ``tools/profile_*``)
+Called by the programs a user runs (``chip_smoke.py``,
+``benchmark/run.py``, ``bench_train.py``, ``python -m flexflow_tpu.serve``,
+the chip tools under ``tools/``)
 before their first compile — never at package import and never by the
 tests. A cold 32-layer serving compile is minutes, and a fresh machine
 starts with nothing compiled.

@@ -3,7 +3,7 @@ spec_infer.py, C++ main inference/spec_infer/spec_infer.cc:274): a verifier
 LLM + small draft SSMs with token-tree verification.
 
 Zero-egress default: random-init verifier whose 2-layer truncation is the
-draft, mirroring bench.py's setup.
+draft.
 """
 
 import os as _os

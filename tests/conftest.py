@@ -56,19 +56,6 @@ def _reset_layer_naming():
     yield
 
 
-@pytest.fixture
-def bench_round():
-    """A fresh copy of the one synthetic bench round the bench_trend
-    self-tests build their histories from (tests/bench_round_fixture.json:
-    only the keys the gate reads; nothing in it was measured)."""
-    import json
-
-    path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                        "bench_round_fixture.json")
-    with open(path) as f:
-        return json.load(f)
-
-
 def _tiny_llama(mode, beam=1):
     import flexflow_tpu as ff
     from flexflow_tpu.models.llama import LLAMAConfig, create_llama_model

@@ -1,7 +1,9 @@
 """Aux subsystem tests: dot export, profiling, inference-debug dumps,
 RecompileState, network simulator (SURVEY §5 parity)."""
 
+import functools
 import os
+import re
 
 import numpy as np
 import pytest
@@ -317,3 +319,86 @@ def test_substitutions_to_dot_tool(tmp_path):
     text = out.read_text()
     assert "digraph substitutions" in text
     assert "fuse_linear_relu" in text
+
+
+# ---------------------------------------------------------------------------
+# documents and sources name only files that exist
+# ---------------------------------------------------------------------------
+
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+_PY = re.compile(r"[\w./*-]+\.py\b")
+_ROOTS = ("tools/", "benchmark/", "tests/", "flexflow_tpu/")
+_HISTORY = ("deleted", "removed", "gone", "went")   # a line may tell it
+
+
+@functools.lru_cache(maxsize=None)
+def _tree():
+    """Relative paths of the checkout's files (hidden and generated
+    directories left out, the two tracked hidden ones kept)."""
+    out = []
+    for top in sorted(os.listdir(_REPO)):
+        if top == "chiprun_out" or (top.startswith(".")
+                                    and top not in (".github", ".claude")):
+            continue
+        if os.path.isfile(os.path.join(_REPO, top)):
+            out.append(top)
+            continue
+        for d, dirs, files in os.walk(os.path.join(_REPO, top)):
+            dirs[:] = [x for x in dirs if x != "__pycache__"]
+            out += [os.path.relpath(os.path.join(d, f), _REPO)
+                    for f in files]
+    return tuple(out)
+
+
+def _document_names(line):
+    """What a line of a document names of this repo's Python files: words
+    under one of ``_ROOTS``, and bare file names."""
+    return [w for w in _PY.findall(line)
+            if w.startswith(_ROOTS) or "/" not in w]
+
+
+def _source_names(line):
+    """What a line of a source file names of the tools and of the
+    top-level benchmark scripts."""
+    return [w for w in _PY.findall(line)
+            if re.fullmatch(r"tools/[\w*-]+\.py|bench\w*\.py", w)]
+
+
+_WHERE = {
+    "README.md": (["README.md"], _document_names),
+    "PAPERS.md": (["PAPERS.md"], _document_names),
+    "tpu-ci.yml": ([".github/workflows/tpu-ci.yml"], _document_names),
+    "SKILL.md": ([".claude/skills/verify/SKILL.md"], _document_names),
+    "flexflow_tpu": (["flexflow_tpu/**/*.py"], _source_names),
+    "tools-tests-inference-top": (["tools/*.py", "tests/*.py",
+                                   "inference/**/*.py", "*.py"],
+                                  _source_names),
+}
+
+
+@pytest.mark.parametrize("where", list(_WHERE))
+def test_names_only_files_that_exist(where):
+    """A command copied from a document, or a tool a comment sends its
+    reader to, is there: every Python file the README, PAPERS.md, the CI
+    workflow and the verify notes name exists, and every ``tools/`` script
+    and top-level ``bench*.py`` the sources name. A bare file name may lie
+    anywhere in the tree; a line that says a file is gone may name it."""
+    import fnmatch
+    import glob
+
+    patterns, names = _WHERE[where]
+    tree = _tree()
+    bare = {os.path.basename(p) for p in tree}
+    files = [p for pat in patterns
+             for p in glob.glob(os.path.join(_REPO, pat), recursive=True)]
+    assert files, patterns
+    stale = []
+    for path in files:
+        with open(path) as f:
+            for n, line in enumerate(f, 1):
+                if any(word in line for word in _HISTORY):
+                    continue
+                stale += [f"{os.path.relpath(path, _REPO)}:{n}: {w}"
+                          for w in names(line)
+                          if not fnmatch.filter(tree if "/" in w else bare, w)]
+    assert not stale, "\n".join(stale)

@@ -6,9 +6,7 @@ fusion bit-for-bit); quantize-on-load from disk matches quantizing the
 same weights in memory; the C-API spec JSON's ``checkpoint_dir`` /
 ``quantize`` keys cold-start an engine; the replica pool survives a
 seeded mid-run crash with token-identical failover and a respawn that
-rejoins from disk; the autoscaler spins a replica up under a spike; and
-the bench-trend gates for the new ``serving_fleet`` section both pass
-good history and catch an injected cold-start regression.
+rejoins from disk; and the autoscaler spins a replica up under a spike.
 
 Kept lean on purpose (tier-1 budget): every engine here is the TINY
 geometry from models/checkpoint_store.TINY_CONFIGS, and the file is
@@ -16,8 +14,6 @@ hoisted to the front of the run by conftest._EARLY_FILES.
 """
 
 import json
-import os
-import sys
 import time
 
 import numpy as np
@@ -27,8 +23,6 @@ from flexflow_tpu.models.checkpoint_store import (
     TINY_CONFIGS, export_hf_state_dict, load_checkpoint,
     load_checkpoint_into, read_checkpoint_config, save_checkpoint,
     save_tiny_checkpoint)
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 PROMPT = [3, 5, 7]
 NEW_TOKENS = 8
@@ -362,51 +356,3 @@ def test_summarize_counts_failovers():
     assert rep["resolved_fraction"] == 1.0
     # the re-dispatch wait shows up as queue wait, not service time
     assert rep["queue_wait_p99_s"] >= 3.0
-
-
-# ---------------------------------------------------------------------------
-# bench_trend: serving_fleet gates
-# ---------------------------------------------------------------------------
-
-def _trend():
-    sys.path.insert(0, os.path.join(REPO, "tools"))
-    try:
-        import bench_trend
-    finally:
-        sys.path.pop(0)
-    return bench_trend
-
-
-def _fleet_round(n, cold_start_s, resolved=1.0):
-    return {"round": n, "file": f"BENCH_r{n:02d}.json", "ok": True,
-            "config": "c1",
-            "parsed": {"value": 100.0,
-                       "serving_fleet": {
-                           "cold_start_s": cold_start_s,
-                           "resolved_fraction": resolved}}}
-
-
-def test_bench_trend_fleet_gates():
-    bt = _trend()
-    assert "serving_fleet.cold_start_s" in bt.LOWER_IS_BETTER
-    assert bt.FLOOR_GROUPS["serving_fleet"][
-        "serving_fleet.resolved_fraction"] == 1.0
-
-    # healthy trajectory (cold start wobbling inside the band) passes
-    ok = [_fleet_round(1, 2.5), _fleet_round(2, 2.2), _fleet_round(3, 2.9)]
-    regressions, lines = bt.check_trajectory(ok)
-    assert regressions == [], "\n".join(lines)
-
-    # injected cold-start regression: 3x the best prior is a structural
-    # slowdown, far outside the +60% wall-clock band — gate must fail
-    bad = ok[:2] + [_fleet_round(3, 6.6)]
-    regressions, _ = bt.check_trajectory(bad)
-    assert any("serving_fleet.cold_start_s" in r and "lower is better" in r
-               for r in regressions)
-
-    # absolute floor: ANY unresolved future under crash chaos fails, even
-    # on a first-of-its-config round with no prior to regress from
-    dropped = [_fleet_round(1, 2.5, resolved=0.93)]
-    regressions, _ = bt.check_trajectory(dropped)
-    assert any("serving_fleet.resolved_fraction" in r and "floor" in r
-               for r in regressions)

@@ -4,15 +4,9 @@ the session-shared tiny spec pair — tier-1 budget).
 Covers the ISSUE-14 acceptance list: seeded Poisson schedules are
 reproducible, goodput/deadline accounting is exact on a hand-built
 record set, sliding-window percentiles match the exact-histogram values
-on retained samples, the end-to-end runner drives the background-server
-submission queue and yields the queue-wait/service decomposition, and
-tools/bench_trend.py passes a steady synthetic trajectory (skipping its
-failed round) while flagging a synthetic 10% throughput regression (the
-gate's own smoke; histories are built from tests/bench_round_fixture.json)."""
-
-import json
-import os
-import sys
+on retained samples, and the end-to-end runner drives the
+background-server submission queue and yields the queue-wait/service
+decomposition."""
 
 import numpy as np
 import pytest
@@ -22,8 +16,6 @@ from flexflow_tpu.serve.loadgen import (EngineHandle, LoadRunner,
                                         WorkloadSpec, build_schedule,
                                         find_knee, format_report, summarize,
                                         sweep)
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 # ---------------------------------------------------------------------------
@@ -175,107 +167,6 @@ def test_load_runner_end_to_end(tiny_spec_pair):
     assert set(rep["per_tenant"]) == {"a", "b"}
     # only 2 batch slots for 6 near-simultaneous arrivals: someone waited
     assert rep["queue_wait_p99_s"] > 0
-
-
-# ---------------------------------------------------------------------------
-# bench_trend gate (the gate itself must not rot)
-# ---------------------------------------------------------------------------
-
-def _trend():
-    sys.path.insert(0, os.path.join(REPO, "tools"))
-    try:
-        import bench_trend
-    finally:
-        sys.path.pop(0)
-    return bench_trend
-
-
-def test_bench_trend_passes_steady_history(tmp_path, bench_round):
-    bt = _trend()
-    for n in (1, 3, 4, 5):                       # a steady trajectory ...
-        (tmp_path / f"BENCH_r{n:02d}.json").write_text(
-            json.dumps({**bench_round, "n": n}))
-    (tmp_path / "BENCH_r02.json").write_text(    # ... with one failed round
-        json.dumps({"n": 2, "rc": 1, "parsed": None}))
-    rounds = bt.load_rounds(str(tmp_path))
-    assert len(rounds) == 5
-    assert not rounds[1]["ok"]                   # failed round is skipped
-    regressions, lines = bt.check_trajectory(rounds)
-    assert regressions == [], "\n".join(lines)
-    # CLI --check smoke: exit code 0 on the steady trajectory
-    assert bt.main(["--check", "--dir", str(tmp_path)]) == 0
-
-
-def test_bench_trend_flags_synthetic_regression(tmp_path, capsys,
-                                                bench_round):
-    bt = _trend()
-    good = bench_round
-    for n in (3, 4, 5):
-        (tmp_path / f"BENCH_r{n:02d}.json").write_text(
-            json.dumps({**good, "n": n}))
-    bad = dict(good)
-    bad["n"] = 6
-    bad["parsed"] = dict(bad["parsed"])
-    bad["parsed"]["value"] = round(bad["parsed"]["value"] * 0.9, 2)
-    (tmp_path / "BENCH_r06.json").write_text(json.dumps(bad))
-    regressions, _ = bt.check_trajectory(bt.load_rounds(str(tmp_path)))
-    assert any(r.startswith("value:") for r in regressions)
-    assert bt.main(["--check", "--dir", str(tmp_path)]) == 1
-    out = capsys.readouterr()
-    assert "BENCH TREND GATE FAILED" in out.err
-    # a serving_load regression is gated the same way once present
-    g5, g6 = dict(good), dict(good)
-    g5["parsed"] = dict(good["parsed"])
-    g5["parsed"]["serving_load"] = {"peak_tokens_per_s": 100.0}
-    g6["n"] = 6
-    g6["parsed"] = dict(good["parsed"])
-    g6["parsed"]["serving_load"] = {"peak_tokens_per_s": 80.0}
-    (tmp_path / "BENCH_r05.json").write_text(json.dumps(g5))
-    (tmp_path / "BENCH_r06.json").write_text(json.dumps(g6))
-    regressions, _ = bt.check_trajectory(bt.load_rounds(str(tmp_path)))
-    assert any("serving_load.peak_tokens_per_s" in r for r in regressions)
-
-    # acceptance-sweep regression (adaptive speculation controller, ROADMAP
-    # item 1): spec re-collapsing below incremental at one damping regime
-    # must fail the gate — the [eps=...] list selector reaches into the
-    # per-eps entries of the bf16_acceptance_sweep list
-    s5, s6 = dict(good), dict(good)
-    s5["parsed"] = dict(good["parsed"])
-    s5["parsed"]["bf16_acceptance_sweep"] = [
-        {"eps": 0.05, "speedup_vs_incr": 1.30},
-        {"eps": 0.2, "speedup_vs_incr": 0.99},
-        {"eps": 1.0, "speedup_vs_incr": 0.97}]
-    s6["n"] = 6
-    s6["parsed"] = dict(good["parsed"])
-    s6["parsed"]["bf16_acceptance_sweep"] = [
-        {"eps": 0.05, "speedup_vs_incr": 1.28},
-        {"eps": 0.2, "speedup_vs_incr": 0.50},      # controller regressed
-        {"eps": 1.0, "speedup_vs_incr": 0.96}]
-    (tmp_path / "BENCH_r05.json").write_text(json.dumps(s5))
-    (tmp_path / "BENCH_r06.json").write_text(json.dumps(s6))
-    regressions, _ = bt.check_trajectory(bt.load_rounds(str(tmp_path)))
-    assert any("bf16_acceptance_sweep[eps=0.2].speedup_vs_incr" in r
-               for r in regressions)
-    assert not any("eps=1.0" in r for r in regressions)   # small drop ok
-
-    # absolute never-lose floor: an adaptive round whose sweep dips below
-    # 0.95 fails even with NO prior sweep to regress from; pre-controller
-    # rounds (no adaptive_spec marker) are never floored retroactively
-    f6 = dict(good)
-    f6["n"] = 7
-    f6["parsed"] = dict(good["parsed"])
-    f6["parsed"]["adaptive_spec"] = True
-    f6["parsed"]["bf16_acceptance_sweep"] = [
-        {"eps": 1.0, "speedup_vs_incr": 0.90}]
-    (tmp_path / "BENCH_r07.json").write_text(json.dumps(f6))
-    regressions, _ = bt.check_trajectory(bt.load_rounds(str(tmp_path)))
-    assert any("below absolute floor" in r and "eps=1.0" in r
-               for r in regressions)
-    f6["parsed"]["bf16_acceptance_sweep"] = [
-        {"eps": 1.0, "speedup_vs_incr": 0.97}]
-    (tmp_path / "BENCH_r07.json").write_text(json.dumps(f6))
-    regressions, _ = bt.check_trajectory(bt.load_rounds(str(tmp_path)))
-    assert not any("below absolute floor" in r for r in regressions)
 
 
 def test_format_report_renders():

@@ -12,9 +12,7 @@ acceptance-criteria artifacts — one stitched Chrome trace with the
 failed-over request's spans under BOTH replicas' pid rows, a pool
 metrics.json whose merged counters equal the sum of the per-replica
 registries, a burn-rate timeline with >= 1 fired alert during the
-outage and zero in steady state, and a parseable flight-recorder JSONL
-— and the bench-trend gates for ``telemetry_overhead`` and the alert
-sanity floors both pass good history and catch injected regressions.
+outage and zero in steady state, and a parseable flight-recorder JSONL.
 
 Kept lean on purpose (tier-1 budget): the session ``tiny_spec_pair``,
 fake clocks everywhere a clock is injectable, and the file is hoisted
@@ -46,12 +44,11 @@ NEW_TOKENS = 8
 def _tools():
     sys.path.insert(0, os.path.join(REPO, "tools"))
     try:
-        import bench_trend
         import profile_trace
         import trace_report
     finally:
         sys.path.pop(0)
-    return bench_trend, trace_report, profile_trace
+    return trace_report, profile_trace
 
 
 # ---------------------------------------------------------------------------
@@ -371,7 +368,7 @@ def test_fleet_observability_acceptance(llama_ckpt, tmp_path):
                                             failover_run)
     from flexflow_tpu.telemetry.fleet import FleetTelemetry
 
-    _, tr, pt = _tools()[0:3]
+    tr, pt = _tools()
     trace_dir = str(tmp_path / "fleet")
     fleet = FleetTelemetry(trace_dir=trace_dir)
     pool = ReplicaPool(checkpoint_replica_factory(llama_ckpt, slots=2,
@@ -381,8 +378,8 @@ def test_fleet_observability_acceptance(llama_ckpt, tmp_path):
                         vocab_size=128,
                         tenants=(TenantSpec("default", 1.0,
                                             deadline_s=2.0),))
-    # harness-scaled thresholds (same rationale as bench.py): one failed
-    # -over request of 10 must page; zero bad can never page
+    # harness-scaled thresholds: one failed-over request of 10 must page;
+    # zero bad can never page
     policy = SLOPolicy(name="obs", fast_burn_threshold=6.0,
                        slow_burn_threshold=3.0)
     pool.start_server()
@@ -515,53 +512,3 @@ def test_capi_metrics_dump_aggregates_fleet():
     assert float(line.split()[-1]) == 7.0
     with pytest.raises(ValueError):
         capi_host.metrics_dump("xml")
-
-
-# ---------------------------------------------------------------------------
-# bench_trend: telemetry_overhead + alert sanity gates
-# ---------------------------------------------------------------------------
-
-def _obs_round(n, overhead=0.02, alerts_overload=1, steady_ok=1.0,
-               cold=2.5):
-    return {"round": n, "file": f"BENCH_r{n:02d}.json", "ok": True,
-            "config": "c1",
-            "parsed": {"value": 100.0,
-                       "serving_fleet": {
-                           "cold_start_s": cold,
-                           "resolved_fraction": 1.0,
-                           "alerts_fired_overload": alerts_overload,
-                           "alerts_steady_ok": steady_ok},
-                       "telemetry_overhead": {"overhead_frac": overhead}}}
-
-
-def test_bench_trend_observability_gates():
-    bt = _tools()[0]
-    assert bt.LOWER_IS_BETTER["telemetry_overhead.overhead_frac"] == 1.0
-    fg = bt.FLOOR_GROUPS["serving_fleet"]
-    assert fg["serving_fleet.alerts_fired_overload"] == 1.0
-    assert fg["serving_fleet.alerts_steady_ok"] == 1.0
-
-    # healthy trajectory: overhead wobbling near the 2% floor passes
-    ok = [_obs_round(1, 0.02), _obs_round(2, 0.03), _obs_round(3, 0.025)]
-    regressions, lines = bt.check_trajectory(ok)
-    assert regressions == [], "\n".join(lines)
-
-    # an unguarded hook landing on the decode hot path: 10x the best
-    # prior tax, far beyond the +100% band — gate must fail
-    bad = ok[:2] + [_obs_round(3, 0.2)]
-    regressions, _ = bt.check_trajectory(bad)
-    assert any("telemetry_overhead.overhead_frac" in r
-               and "lower is better" in r for r in regressions)
-
-    # silent pager: injected outage fired no alert — floor fails even on
-    # a first-of-its-config round
-    mute = [_obs_round(1, alerts_overload=0)]
-    regressions, _ = bt.check_trajectory(mute)
-    assert any("serving_fleet.alerts_fired_overload" in r and "floor" in r
-               for r in regressions)
-
-    # flapping pager: an alert fired in steady state
-    flap = [_obs_round(1, steady_ok=0.0)]
-    regressions, _ = bt.check_trajectory(flap)
-    assert any("serving_fleet.alerts_steady_ok" in r
-               for r in regressions)

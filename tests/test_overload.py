@@ -2,9 +2,9 @@
 fake clock, end-to-end timeouts/cancellation on every scheduler path,
 deadline-aware preemption with re-queue token identity, server fault
 containment + restart, flush-with-timeout shutdown, the chaos harness's
-every-future-resolves invariant, explicit rejected/timed-out accounting
-in summarize(), and the serving_overload absolute floors in the bench
-trend gate.
+every-future-resolves invariant (and tools/faulttest.py, the command
+the README gives for it) and explicit rejected/timed-out accounting in
+summarize().
 
 Budget discipline: pure-math tests dominate; the integration tests share
 the session tiny spec pair plus ONE module-scoped tiny incremental
@@ -166,48 +166,6 @@ def test_summarize_accounts_rejected_and_timed_out():
     rep0 = summarize([rec(0, "rejected", out=0, lat=0.0)], duration_s=1.0)
     assert rep0["achieved_rps"] == 0.0
     assert rep0["latency_p50_s"] == 0.0
-
-
-# ---------------------------------------------------------------------------
-# bench trend gate: serving_overload absolute floors
-# ---------------------------------------------------------------------------
-
-def _trend():
-    sys.path.insert(0, os.path.join(REPO, "tools"))
-    try:
-        import bench_trend
-    finally:
-        sys.path.pop(0)
-    return bench_trend
-
-
-def test_bench_trend_serving_overload_floor(tmp_path, bench_round):
-    bt = _trend()
-    good = bench_round
-    (tmp_path / "BENCH_r05.json").write_text(json.dumps(good))
-    bad = dict(good)
-    bad["n"] = 6
-    bad["parsed"] = dict(good["parsed"])
-    bad["parsed"]["serving_overload"] = {
-        "priority_goodput": 0.90, "resolved_fraction": 1.0}
-    (tmp_path / "BENCH_r06.json").write_text(json.dumps(bad))
-    regressions, _ = bt.check_trajectory(bt.load_rounds(str(tmp_path)))
-    assert any("serving_overload.priority_goodput" in r
-               and "below absolute floor" in r for r in regressions)
-    # a dropped future fails the resolved floor even with goodput fine
-    bad["parsed"]["serving_overload"] = {
-        "priority_goodput": 1.0, "resolved_fraction": 0.97}
-    (tmp_path / "BENCH_r06.json").write_text(json.dumps(bad))
-    regressions, _ = bt.check_trajectory(bt.load_rounds(str(tmp_path)))
-    assert any("serving_overload.resolved_fraction" in r
-               for r in regressions)
-    # passing section gates clean; rounds WITHOUT the section are never
-    # floored retroactively
-    bad["parsed"]["serving_overload"] = {
-        "priority_goodput": 0.97, "resolved_fraction": 1.0}
-    (tmp_path / "BENCH_r06.json").write_text(json.dumps(bad))
-    regressions, _ = bt.check_trajectory(bt.load_rounds(str(tmp_path)))
-    assert not any("serving_overload" in r for r in regressions)
 
 
 # ---------------------------------------------------------------------------
@@ -468,3 +426,18 @@ def test_run_chaos_every_future_resolves(tiny_incr_model):
     assert "unresolved" not in report["statuses"]
     # the seeded plan exercises more than the happy path
     assert set(report["statuses"]) - {"ok"}
+
+
+def test_faulttest_tool_holds_its_invariant(capsys):
+    """tools/faulttest.py as the README gives it, on its default ``tiny``
+    geometry: the model it builds, its injector, the report it prints and
+    the exit status that says the invariant held."""
+    sys.path.insert(0, os.path.join(REPO, "tools"))
+    try:
+        import faulttest
+    finally:
+        sys.path.pop(0)
+    assert faulttest.main(["--requests", "4"]) == 0
+    report = json.loads(capsys.readouterr().out)
+    assert report["problems"] == [] and report["n_requests"] == 4
+    assert report["resolved_fraction"] == 1.0

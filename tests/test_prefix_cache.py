@@ -3,21 +3,16 @@ radix trie match/insert/evict/refcount math on a fake clock, KV segment
 extract/install roundtrip on both op_state layouts, token identity cold
 vs warm on the incremental loop and on the fused speculation loop under
 both engines, including a preemption re-queue that crosses a pooled prefix,
-eviction-under-pressure never corrupting a live slot, the
+eviction-under-pressure never corrupting a live slot, and the
 decode-interleaves-with-prefill dispatch order (a round prefills as many
 steps as its decode block pays for, by the two programs' given costs, with
-telemetry on and off: ISSUE 32), and the serving_prefix absolute floors in
-the bench trend gate.
+telemetry on and off: ISSUE 32).
 
 Budget discipline: pure-math tests dominate; the integration tests share
 the session tiny spec pair plus ONE module-scoped tiny incremental model
 and ONE extra draft (the fused multi-SSM engine needs two distinct
 drafts), and one cold reference run feeds every identity comparison.
 """
-
-import json
-import os
-import sys
 
 import numpy as np
 import pytest
@@ -28,8 +23,6 @@ from flexflow_tpu.serve import prefix_cache as pcm
 from flexflow_tpu.serve.batch_config import GenerationConfig
 from flexflow_tpu.serve.prefix_cache import PrefixCache
 from flexflow_tpu.serve.request_manager import RequestManager
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 # a 12-token "system prompt" three prompts share (vocab 128)
 SHARED = [3, 14, 15, 9, 2, 6, 5, 35, 8, 97, 93, 23]
@@ -452,46 +445,3 @@ def test_telemetry_leaves_the_rounds_as_they_are(tiny_incr_model):
     assert traced[0] == plain[0]
     assert traced[1].output_tokens == plain[1].output_tokens
     assert traced[2].output_tokens == plain[2].output_tokens
-
-
-# ---------------------------------------------------------------------------
-# bench trend gate: serving_prefix absolute floors
-# ---------------------------------------------------------------------------
-
-def _trend():
-    sys.path.insert(0, os.path.join(REPO, "tools"))
-    try:
-        import bench_trend
-    finally:
-        sys.path.pop(0)
-    return bench_trend
-
-
-def test_bench_trend_serving_prefix_floor(tmp_path, bench_round):
-    bt = _trend()
-    good = bench_round
-    (tmp_path / "BENCH_r05.json").write_text(json.dumps(good))
-    bad = dict(good)
-    bad["n"] = 6
-    bad["parsed"] = dict(good["parsed"])
-    # a knee that no longer moves right fails the absolute floor
-    bad["parsed"]["serving_prefix"] = {
-        "knee_ratio": 1.0, "prefix_saved_frac": 0.6}
-    (tmp_path / "BENCH_r06.json").write_text(json.dumps(bad))
-    regressions, _ = bt.check_trajectory(bt.load_rounds(str(tmp_path)))
-    assert any("serving_prefix.knee_ratio" in r
-               and "below absolute floor" in r for r in regressions)
-    # a reuse fraction collapse fails even with the knee fine
-    bad["parsed"]["serving_prefix"] = {
-        "knee_ratio": 4.0, "prefix_saved_frac": 0.1}
-    (tmp_path / "BENCH_r06.json").write_text(json.dumps(bad))
-    regressions, _ = bt.check_trajectory(bt.load_rounds(str(tmp_path)))
-    assert any("serving_prefix.prefix_saved_frac" in r
-               for r in regressions)
-    # healthy values gate clean, and rounds WITHOUT the section (all
-    # committed history before this change) are never floored
-    bad["parsed"]["serving_prefix"] = {
-        "knee_ratio": 4.0, "prefix_saved_frac": 0.6}
-    (tmp_path / "BENCH_r06.json").write_text(json.dumps(bad))
-    regressions, _ = bt.check_trajectory(bt.load_rounds(str(tmp_path)))
-    assert not any("serving_prefix" in r for r in regressions)

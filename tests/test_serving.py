@@ -484,10 +484,10 @@ def test_multi_ssm_spec_host_calls_bounded():
     """Multi-SSM tree speculation must be FUSED: the number of host->device
     dispatches for a whole generation must not scale with drafted tokens
     (the pre-fusion path paid one InferenceManager.step per drafted token
-    per SSM per round and could never beat incremental decoding — the
-    reference CI speed gate compare_speed_spec_infer_incr_decoding,
-    python_inference_tests.sh:57, is asserted wall-clock on the bench
-    harness: ``python bench.py --multi-ssm`` on the real chip)."""
+    per SSM per round and could never beat incremental decoding; what
+    the reference CI speed gate compare_speed_spec_infer_incr_decoding,
+    python_inference_tests.sh:57, asks is measured on the chip by the
+    benchmark's speculation cell, ``opt-6.7b-spec.decode-heavy``)."""
     from flexflow_tpu.serve.engine import MultiSpecEngine
     from flexflow_tpu.serve.inference_manager import InferenceManager
 

@@ -1,5 +1,5 @@
-"""Adaptive speculation controller tests (ROADMAP item 1: spec decoding
-must never lose to plain decoding).
+"""Adaptive speculation controller tests (spec decoding must never lose
+to plain decoding).
 
 Lean by design (tier-1 budget): the policy layer is pure functions
 tested as data-in/data-out; the engine contract runs on the shared

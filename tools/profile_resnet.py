@@ -84,7 +84,7 @@ def make_step(nhwc, use_bn, fwd_only, mm1x1=False, bn_bf16acc=False):
         if mm1x1 and k == 1:
             # 1x1 conv as a matmul over the channel dim: 2-D weights have
             # clean layouts (the 4-D [O,I,1,1] update path pays ms-scale
-            # transpose fusions per weight per step — see profile_trace)
+            # transpose fusions per weight per step in a per-op trace)
             if s != 1:
                 x = (x[:, :, ::s, ::s] if not nhwc else x[:, ::s, ::s, :])
             y = jnp.einsum("nchw,cd->ndhw", x, w) if not nhwc \

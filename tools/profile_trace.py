@@ -1,29 +1,16 @@
-"""Op-level TPU trace profile via jax.profiler.ProfileData.
+"""A serving profiler session's host plane, and the tracer's spans on its clock.
 
-Captures a few training steps (the profile_resnet.py NCHW variant — the
-shipped bench_train configuration's math) under jax.profiler.trace and
-aggregates per-op device time from the xplane, printing the top ops by
-total duration. Answers "where do the ms go" without guessing from
-ablations.
-
-Usage: python tools/profile_trace.py [resnet|decode]
-
-Serving: a profiler session taken with telemetry on
+A ``jax.profiler`` session taken with telemetry on
 (``utils/profiling.profiler_trace``) holds the program's batch-level spans
 on its host plane (``host_annotations``); ``spans_on_profiler_clock`` puts
-the tracer's other spans on the same clock by the marks both hold.
+the tracer's other spans on the same clock by the marks both hold
+(``mark_offset_ns``). The benchmark reduces its own traces with
+``benchmark/lib/trace.py``, which keeps of the host plane only the marks
+``benchmark/run.py`` writes; these three read every event of it by name.
 """
 
 import glob
 import os
-import sys
-import time
-from collections import defaultdict
-
-import numpy as np
-
-sys.path.insert(0, ".")
-sys.path.insert(0, "tools")
 
 
 def _xplane(trace_dir):
@@ -80,96 +67,3 @@ def spans_on_profiler_clock(trace_dir, events):
             out.append((ev["name"], start, start + ev["dur"] * 1e3,
                         ev.get("args", {})))
     return out
-
-
-def aggregate(trace_dir, steps=3, min_pct=0.5):
-    """Aggregate the device plane's "XLA Ops" line: per-op kind totals
-    (fusion-name prefixes) + top individual ops, per step."""
-    import re
-
-    pd = _xplane(trace_dir)
-    totals = defaultdict(float)
-    counts = defaultdict(int)
-    kinds = defaultdict(float)
-    for plane in pd.planes:
-        if not plane.name.startswith("/device:TPU"):
-            continue
-        for line in plane.lines:
-            if line.name != "XLA Ops":
-                continue
-            for ev in line.events:
-                ms = ev.duration_ns / 1e6
-                totals[ev.name] += ms
-                counts[ev.name] += 1
-                kinds[re.sub(r"[.\d]+$", "", ev.name)
-                      .split("(")[0].split(" = ")[0]] += ms
-    if not totals:
-        print("no device XLA Ops captured (tracing unsupported here?)")
-        return
-    grand = sum(totals.values())
-    print(f"device op total {grand:.1f} ms over {steps} steps -> "
-          f"{grand / steps:.1f} ms/step")
-    print("== by kind ==")
-    for k, ms in sorted(kinds.items(), key=lambda kv: -kv[1])[:15]:
-        if 100 * ms / grand < min_pct:
-            break
-        print(f"{ms / steps:9.2f} ms/step {100 * ms / grand:5.1f}%  {k}")
-    print("== top individual ops ==")
-    for n, ms in sorted(totals.items(), key=lambda kv: -kv[1])[:20]:
-        if 100 * ms / grand < min_pct:
-            break
-        print(f"{ms / steps:8.2f} ms/step {100 * ms / grand:5.1f}% "
-              f"x{counts[n] // steps:3d}  {n[:100]}")
-
-
-def run_resnet(trace_dir):
-    import jax
-
-    from profile_resnet import BATCH, IMG, init_params, make_step
-    import jax.numpy as jnp
-
-    rng = np.random.default_rng(0)
-    params, _ = init_params(rng, nhwc=False)
-    params = jax.tree.map(jnp.asarray, params)
-    x = jnp.asarray(rng.standard_normal((BATCH, 3, IMG, IMG)), jnp.bfloat16)
-    y = jnp.asarray(rng.integers(0, 1000, (BATCH, 1)), jnp.int32)
-    step = make_step(False, True, False)
-    loss, params = step(params, x, y)
-    loss, params = step(params, x, y)
-    float(loss)
-    with jax.profiler.trace(trace_dir):
-        for _ in range(3):
-            loss, params = step(params, x, y)
-        float(loss)
-
-
-def run_decode(trace_dir, fusion=True):
-    import jax
-
-    import bench
-    from profile_decode import build
-
-    m, ifm = build(bench.LAYERS, bench, fusion=fusion)
-    R, P = bench.NUM_REQUESTS, bench.PROMPT_LEN
-    tok = np.ones((R,), np.int32)
-    pos = np.full((R,), P, np.int32)
-    act = np.ones((R,), bool)
-    np.asarray(ifm.decode_block(tok, pos, act, 4))
-    with jax.profiler.trace(trace_dir):
-        np.asarray(ifm.decode_block(tok, pos, act, 32))
-
-
-if __name__ == "__main__":
-    from flexflow_tpu.utils.compile_cache import enable_compile_cache
-
-    enable_compile_cache()
-    what = sys.argv[1] if len(sys.argv) > 1 else "resnet"
-    modes = ("resnet", "decode", "decode-nofuse")
-    if what not in modes:
-        raise SystemExit(f"unknown mode {what!r}; pick one of {modes}")
-    trace_dir = f"/tmp/fftrace_{what.replace('-', '_')}_{int(time.time())}"
-    if what.startswith("decode"):
-        run_decode(trace_dir, fusion=(what != "decode-nofuse"))
-    else:
-        run_resnet(trace_dir)
-    aggregate(trace_dir, steps=32 if what.startswith("decode") else 3)
