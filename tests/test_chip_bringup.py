@@ -119,6 +119,27 @@ def test_moe_experts_exports_for_tpu_at_the_cells_shapes(step):
         q(H, inter), q(H, inter), q(inter, H))
 
 
+@pytest.mark.parametrize("shape", [(32, 8, 128), (4, 128, 128)],
+                         ids=["sdar_pass_256_rows", "prefill_512_rows"])
+def test_router_top_k_exports_for_tpu_at_sdars_shapes(shape):
+    """The router's ``TopK`` at SDAR-30B-A3B's shapes, 8 of 128 float32
+    scores for a pass of 32 rows x 8 positions and for a prefill step of
+    4 x 128 tokens: the module for the TPU sorts ``[rows, 128]``, never
+    the three-dimensional scores (75 us a layer there, PR 59)."""
+    from flexflow_tpu.ops.base import OpContext
+    from flexflow_tpu.ops.reduction_ops import TopK
+
+    rows = shape[0] * shape[1]
+    text = jax.export.export(
+        jax.jit(lambda x: TopK.forward({"k": 8}, {}, [x], OpContext())),
+        platforms=["tpu"])(
+            jax.ShapeDtypeStruct(shape, jnp.float32)).mlir_module()
+    sorts = [line for line in text.splitlines()
+             if "mhlo.topk" in line or "stablehlo.sort" in line]
+    assert len(sorts) == 1, sorts
+    assert f"(tensor<{rows}x128xf32>) ->" in sorts[0]
+
+
 def test_interpret_switch_on_a_tpu_backend_raises(monkeypatch):
     from flexflow_tpu import kernels as ffk
 

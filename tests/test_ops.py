@@ -181,6 +181,129 @@ def test_topk_argmax_gather():
     np.testing.assert_allclose(got_vals, want_vals, rtol=1e-6)
 
 
+def _bits(a):
+    return np.asarray(a).tobytes()
+
+
+def _topk_cases():
+    """(scores' shape, k, dtype, kind): the cells' router calls as the op
+    gets them (a decode step's ``[slots, tokens a row, E]``, a prefill
+    step's ``[4, 128, E]``, the frontends' ``[rows, E]`` and ``[E]``) on
+    random scores, then rows of ties, of ``-inf`` and of both zeros."""
+    calls = [((32, 8, 128), 8), ((4, 128, 128), 8), ((32, 1, 64), 8),
+             ((16, 1, 128), 4), ((32, 1, 768), 12), ((16, 1, 17), 1),
+             ((16, 1, 320), 8), ((4, 128, 288), 12), ((2, 4, 8, 16), 4),
+             ((256, 128), 8), ((128,), 8)]
+    cases = [(shape, k, dt, "random") for shape, k in calls
+             for dt in ("float32", "bfloat16")]
+    for dt in ("float32", "bfloat16"):
+        cases += [((32, 8, 128), 8, dt, kind)
+                  for kind in ("ties", "neg_inf", "zeros_and_nan", "grad")]
+        cases += [((5, 1, 17), 12, dt, "ties"), ((5, 8, 16), 12, dt, "neg_inf"),
+                  ((8, 1, 320), 12, dt, "grad")]
+    return cases
+
+
+@pytest.mark.parametrize("shape,k,dtype,kind", _topk_cases(),
+                         ids=lambda v: str(v).replace(" ", ""))
+def test_topk_op_is_lax_top_k_of_the_scores_as_given(shape, k, dtype, kind):
+    """``TopK.forward`` sorts its scores as ``[rows, E]`` (PR 59) and gives
+    what ``jax.lax.top_k`` gives of them as they came, bit for bit: values,
+    indices, order, ties to the lower index, k distinct picks in a row that
+    holds more than ``E - k`` ``-inf``, and the gradient."""
+    from flexflow_tpu.ops.reduction_ops import TopK
+
+    E = shape[-1]
+    rows = int(np.prod(shape[:-1]))
+    rng = np.random.RandomState(rows * 1000 + E * 10 + k)
+    x = rng.randn(rows, E).astype(np.float32)
+    if kind in ("ties", "grad"):
+        x = rng.randint(0, 3, (rows, E)).astype(np.float32)
+        x[0] = 0.25                             # a row of one value
+    elif kind == "neg_inf":
+        x[:, rng.permutation(E)[:E - k + 2]] = -np.inf   # more than E - k
+        x[0] = -np.inf
+    elif kind == "zeros_and_nan":
+        x[:, ::2], x[:, 1::2] = 0.0, -0.0
+        x[1, 5] = x[2, 0] = np.nan
+        x[3, 7], x[3, 9] = np.inf, -np.inf
+    x = jnp.asarray(x.reshape(shape), dtype)
+
+    def op(x):
+        return TopK.forward({"k": k}, {}, [x], OpContext())
+
+    if kind == "grad":
+        w = jnp.arange(1.0, k + 1, dtype=jnp.float32)
+
+        def loss(f):
+            return lambda x: (f(x)[0].astype(jnp.float32) * w).sum()
+
+        assert _bits(jax.grad(loss(op))(x)) == _bits(
+            jax.grad(loss(lambda x: jax.lax.top_k(x, k)))(x))
+        return
+    want_v, want_i = jax.lax.top_k(x, k)
+    got_v, got_i = op(x)
+    assert got_v.shape == got_i.shape == (*shape[:-1], k)
+    assert got_v.dtype == x.dtype and got_i.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(got_i), np.asarray(want_i))
+    assert _bits(got_v) == _bits(want_v)
+
+
+def test_topk_op_sorts_two_dimensions_and_argtopk_is_as_it_was():
+    """A router's ``TopK`` call (SDAR's pass: ``[32, 8, 128]`` scores, k 8)
+    holds ONE ``top_k`` primitive, over ``[256, 128]``: XLA:TPU sorts the
+    leading dimensions one ``[8, 128]`` slab at a time, 75 us there against
+    4.6 (PERF.md section 6, PR 59). Under a mesh too; ``ArgTopK`` over a
+    vocabulary keeps its operand."""
+    from flexflow_tpu.ops.reduction_ops import ArgTopK, TopK
+
+    def top_k_operands(op, shape, ctx=None, k=8):
+        jaxpr = jax.make_jaxpr(lambda x: op.forward(
+            {"k": k}, {}, [x], ctx or OpContext()))(
+                jax.ShapeDtypeStruct(shape, jnp.float32))
+        assert not [e for e in jaxpr.eqns if e.primitive.name == "sort"]
+        return [e.invars[0].aval.shape for e in jaxpr.eqns
+                if e.primitive.name == "top_k"]
+
+    assert top_k_operands(TopK, (32, 8, 128)) == [(256, 128)]
+    assert top_k_operands(TopK, (4, 128, 128)) == [(512, 128)]
+    assert top_k_operands(TopK, (256, 128)) == [(256, 128)]
+    mesh = jax.sharding.Mesh(np.array(jax.devices()[:2]), ("x",))
+    assert top_k_operands(TopK, (32, 8, 128), OpContext(mesh=mesh)) == [
+        (256, 128)]
+    assert top_k_operands(ArgTopK, (32, 50272)) == [(32, 50272)]
+
+
+def test_the_top_k_timing_tool_rehearses(capsys, tmp_path, monkeypatch):
+    """tools/time_top_k.py (``lax.top_k`` on the scores as they come, as
+    ``[rows, E]``, in 128-row chunks, and the op's own), tiny: no time is
+    taken here and no file written, and every form gives ``lax.top_k``'s
+    bits on rows of ties, ``-inf``, ``nan`` and both zeros."""
+    import json
+    import os
+    import sys
+
+    tools = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "tools")
+    sys.path.insert(0, tools)
+    try:
+        import time_top_k
+    finally:
+        sys.path.remove(tools)
+    monkeypatch.setattr(time_top_k, "OUT", str(tmp_path / "out.json"))
+    assert time_top_k.main(["--rehearse"]) == 0
+    assert not os.listdir(tmp_path)
+    res = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert res["device"] == "cpu" and res["all_equal_lax_top_k"] is True
+    forms = set(time_top_k.FORMS)
+    for row in res["table"]:
+        assert {f: row[f] for f in forms} == dict.fromkeys(
+            forms, {"equal": True})
+    with pytest.raises(SystemExit, match="TPU"):
+        time_top_k.main(["--rows", "8", "--experts", "16", "--k", "1",
+                         "--calls", ""])
+
+
 def test_scalar_and_unary_chain():
     x = np.random.RandomState(10).rand(4, 8).astype(np.float32) + 0.5
     model = ff.FFModel(ff.FFConfig(batch_size=4))
