@@ -69,6 +69,73 @@ def _normalize_regularizer(reg):
     return out or None
 
 
+class LoopRegion:
+    """A span of the layer list that runs ``steps`` times over one set of
+    weights inside one device loop (``FFModel.loop_begin`` / ``loop_end``,
+    ops/loop.py): its two end layers, the ``span`` between them, and, once
+    the model is compiled, ``cache_layers``: the span's attention layers,
+    each of which keeps a cache plane a pass."""
+
+    # what a span's stateful layer may be: a plain k/v cache, a plane a pass
+    _PLAIN = (OpType.INC_MULTIHEAD_SELF_ATTENTION,)
+    _REFUSED = {
+        OpType.INC_KDA_ATTENTION: "a layer that keeps a recurrent state",
+        OpType.INC_SSD_MIXER: "a state-space mixer, which keeps a "
+                              "recurrent state",
+        OpType.INC_MULTIHEAD_LATENT_ATTENTION: "a latent attention layer",
+        OpType.INC_MULTIHEAD_CCA_ATTENTION: "an attention layer that "
+                                            "carries a tail",
+        OpType.TREE_INC_MULTIHEAD_SELF_ATTENTION: "tree verification",
+        OpType.SPEC_INC_MULTIHEAD_SELF_ATTENTION: "beam drafting",
+        OpType.MOE_EXPERTS: "a routed-expert layer (its counters are a row "
+                            "a layer, not a row a layer and pass)",
+    }
+
+    def __init__(self, begin: Layer):
+        self.begin, self.end = begin, None
+        self.span: List[Layer] = []
+        self.cache_layers: List[Layer] = []
+        self.inside = frozenset()   # the two ends and the span (``check``)
+
+    @property
+    def steps(self) -> int:
+        return self.begin.attrs["steps"]
+
+    def check(self, model):
+        """Refuse what a region cannot hold: any state but a plain k/v
+        cache a pass, and a value that leaves the span by another way than
+        ``loop_end``."""
+        def refuse(what):
+            raise NotImplementedError(
+                f"{what} inside a loop region is not supported: a pass "
+                "keeps its own plane of a plain k/v stack and nothing else "
+                "(ops/loop.py; a state a layer would be shared by the "
+                "passes, a different result)")
+
+        self.inside = frozenset((self.begin, *self.span, self.end))
+        for ly in self.span:
+            if ly.op_type in self._REFUSED:
+                refuse(self._REFUSED[ly.op_type])
+            if ly.attrs.get("sliding_window") is not None:
+                refuse("a windowed attention layer")
+            if ly.attrs.get("eva_window") is not None:
+                refuse("a chunked attention layer")
+            if "block_length" in ly.attrs:
+                refuse("a block-diffusion layer")
+            if (hasattr(get_op_impl(ly.op_type), "init_state")
+                    and ly.op_type not in self._PLAIN):
+                refuse(f"a {ly.op_type.name} layer, which keeps state")
+        made = {t.tensor_id for ly in self.span for t in ly.outputs}
+        for ly in model.layers:
+            if ly not in self.inside and any(
+                    t.tensor_id in made for t in ly.inputs):
+                raise ValueError(
+                    f"{ly.name} reads a value of a loop region's span: hand "
+                    "it out through loop_end(collect=[...])")
+        self.cache_layers = [ly for ly in self.span
+                             if ly.op_type in self._PLAIN]
+
+
 class FFModel:
     def __init__(self, config: Optional[FFConfig] = None):
         self.config = config or FFConfig()
@@ -105,6 +172,9 @@ class FFModel:
         # pipeline_parallelism_degree > 1; see serve/pipeline_plan.py)
         self._pp_plan = None
         self._pp_segment_fn = None
+        # loop regions, in order (loop_begin / loop_end; ops/loop.py)
+        self._loop_regions: List[LoopRegion] = []
+        self.loop_region: Optional[LoopRegion] = None
 
     # ==================================================================
     # Tensor / layer creation
@@ -251,15 +321,18 @@ class FFModel:
     def rms_norm(self, input: Tensor, eps: float = 1e-6,
                  dim: Optional[int] = None, name: Optional[str] = None,
                  unit_offset: bool = False,
-                 data_type: Optional[DataType] = None) -> Tensor:
+                 data_type: Optional[DataType] = None,
+                 initializer=None) -> Tensor:
         """``unit_offset``: the weight is an offset from one, ``x / rms *
         (1 + g)``; ``data_type``: the output's, where it is not the
-        input's (ops/norm.RMSNorm). Only a model that asks carries the
-        keys."""
+        input's; ``initializer``: the weight's, where it is not ones
+        (ops/norm.RMSNorm). Only a model that asks carries the keys."""
         return self._add_layer(OpType.RMS_NORM, [input], dict(
             eps=eps, dim=dim or input.dims[-1],
             **({"unit_offset": True} if unit_offset else {}),
-            **({} if data_type is None else {"data_type": data_type})), name)
+            **({} if data_type is None else {"data_type": data_type}),
+            **({} if initializer is None else {"initializer": initializer})),
+            name)
 
     def residual_rms_norm(self, input1: Tensor, input2: Tensor,
                           eps: float = 1e-6, dim: Optional[int] = None,
@@ -959,6 +1032,55 @@ class FFModel:
     def allreduce(self, input: Tensor, name=None):
         return self._add_layer(OpType.ALLREDUCE, [input], {}, name)
 
+    # ---- a loop region (ops/loop.py) ----
+    def loop_begin(self, input: Tensor, steps: int, name=None) -> Tensor:
+        """Open a LOOP REGION: the layers recorded from here to
+        ``loop_end`` run ``steps`` times over their one set of weights,
+        inside one device loop. Returns the span's input: ``input`` in the
+        first pass, what the pass before handed on in every later one."""
+        if any(r.end is None for r in self._loop_regions):
+            raise NotImplementedError(
+                "a loop region inside a loop region: close the open one "
+                "(loop_end) first")
+        if int(steps) < 1:
+            raise ValueError(f"a loop region runs at least once, not {steps}")
+        out = self._add_layer(OpType.LOOP_BEGIN, [input],
+                              {"steps": int(steps)}, name)
+        self._loop_regions.append(LoopRegion(self.layers[-1]))
+        return out
+
+    def loop_end(self, input: Tensor, collect: Sequence[Tensor] = (),
+                 name=None) -> List[Tensor]:
+        """Close the open loop region: ``input`` (the shape and dtype of
+        the span's input) is what a pass hands the next. Returns
+        ``[last, *stacks]``: the last pass's ``input``, then every pass's
+        value of each tensor of ``collect``, ``[steps, ...]``."""
+        region = self._loop_regions[-1] if self._loop_regions else None
+        if region is None or region.end is not None:
+            raise ValueError("loop_end without an open loop_begin")
+        begin = region.begin
+        if (input.dims, input.dtype) != (begin.outputs[0].dims,
+                                         begin.outputs[0].dtype):
+            raise ValueError(
+                "a pass hands the next what the span was given: "
+                f"{begin.outputs[0].dims} {begin.outputs[0].dtype}, not "
+                f"{input.dims} {input.dtype}")
+        first = self.layers.index(begin) + 1
+        region.span = self.layers[first:]
+        outs = self._add_layer(OpType.LOOP_END, [input, *collect],
+                               {"steps": begin.attrs["steps"]}, name)
+        region.end = self.layers[-1]
+        return outs if isinstance(outs, list) else [outs]
+
+    def loop_exit(self, states: Tensor, gates: Tensor, threshold: float,
+                  name=None) -> Tensor:
+        """A looped model's exit rule (ops/loop.LoopExit): of every pass's
+        ``states`` [steps, R, Q, E], each token's behind the first pass at
+        which the running sum of its gate's exit probabilities (``gates``
+        [steps, R, Q, 1], logits) reaches ``threshold``, else the last."""
+        return self._add_layer(OpType.LOOP_EXIT, [states, gates],
+                               {"threshold": float(threshold)}, name)
+
     # ==================================================================
     # Graph execution
     # ==================================================================
@@ -1012,7 +1134,12 @@ class FFModel:
         ctx.state_in = state or {}
         ctx.state_out = {}
         plan = getattr(self, "_branch_plan", None)
+        loop = self.loop_region
         for layer in self.layers:
+            if loop is not None and layer in loop.inside:
+                if layer is loop.begin:     # the span, every pass of it
+                    self._run_loop(loop, params, values, ctx)
+                continue
             if narrow is not None and layer is narrow[0]:
                 for t in layer.inputs:
                     values[t.tensor_id] = narrow[1](values[t.tensor_id])
@@ -1036,6 +1163,75 @@ class FFModel:
         new_state = dict(ctx.state_in)
         new_state.update(ctx.state_out)
         return values, new_state
+
+    def _run_loop(self, region, params, values: Dict[int, Any],
+                  ctx: OpContext):
+        """Run a loop region: ONE ``lax.scan`` over the passes whose body
+        is the span, once. It carries the hidden value and the op state
+        (the stacked caches go through it in place, as through the decode
+        block's own loop); the parameters are the body's constants, read
+        once a layer; the pass index reaches the ops as ``ctx.loop_step``.
+        Writes the outputs of the region's end into ``values``."""
+        from flexflow_tpu.ops.loop import count_pass
+
+        begin, end = region.begin, region.end
+        h0 = values[begin.inputs[0].tensor_id]
+        outer_in, outer_out = ctx.state_in, ctx.state_out
+        written = set()
+
+        def one_pass(carry, step):
+            h, state = carry
+            vals = dict(values)         # what the span reads from before it
+            vals[begin.outputs[0].tensor_id] = h
+            ctx.state_in, ctx.state_out, ctx.loop_step = state, {}, step
+            for layer in region.span:
+                self._apply_layer(layer, params, vals, ctx)
+            count_pass(ctx, h, len(region.cache_layers))   # telemetry on
+            new = set(ctx.state_out) - set(state)
+            assert not new, f"a pass may not add op state: {sorted(new)}"
+            written.update(ctx.state_out)
+            out = vals[end.inputs[0].tensor_id]
+            assert (out.shape, out.dtype) == (h.shape, h.dtype), (
+                f"a pass hands on {out.shape} {out.dtype}, the span takes "
+                f"{h.shape} {h.dtype}")
+            return ((out, {**state, **ctx.state_out}),
+                    tuple(vals[t.tensor_id] for t in end.inputs[1:]))
+
+        try:
+            with jax.named_scope(f"loop_region/{begin.name}"):
+                (h, state), stacks = jax.lax.scan(
+                    one_pass, (h0, {**outer_in, **outer_out}),
+                    jnp.arange(region.steps, dtype=jnp.int32))
+        finally:
+            ctx.state_in, ctx.state_out, ctx.loop_step = (outer_in,
+                                                          outer_out, None)
+        ctx.state_out.update({k: state[k] for k in written})
+        for t, v in zip(end.outputs, (h, *stacks)):
+            values[t.tensor_id] = v
+
+    def _note_loop_region(self, split):
+        """``self.loop_region``: the model's LoopRegion, None for every
+        model without one. Refuses here what a region cannot hold or be
+        served through (ops/loop.py)."""
+        from flexflow_tpu.ops.loop import refuse_looped
+
+        self.loop_region = None
+        if not self._loop_regions:
+            return
+        if len(self._loop_regions) > 1 or self._loop_regions[0].end is None:
+            raise NotImplementedError(
+                "one closed loop region a model: "
+                f"{len(self._loop_regions)} were begun")
+        region = self.loop_region = self._loop_regions[0]
+        region.check(self)
+        if self.config.pipeline_parallelism_degree > 1:
+            refuse_looped(self, "a pipeline plan (serve/pipeline_plan.py: a "
+                          "stage is a run of layers used once)")
+        if split:
+            refuse_looped(self, f"a mesh that divides a model ({split})")
+        if self.config.inference_debugging:
+            refuse_looped(self, "inference_debugging (its dump walks the "
+                          "layer list, a layer a step)")
 
     # ==================================================================
     # Compile
@@ -1140,6 +1336,9 @@ class FFModel:
             params[layer.name] = lp
         self.params = params
 
+        split = {a: n for a, n in self.mesh.shape.items()
+                 if a != "data" and n > 1}
+        self._note_loop_region(split)
         self.op_state = {}
         for layer in self.layers:
             impl = get_op_impl(layer.op_type)
@@ -1148,17 +1347,17 @@ class FFModel:
                 self.op_state[layer.name] = impl.init_state(layer.attrs,
                                                             input_specs)
         self._consolidate_kv_caches()
-        split = {a: n for a, n in self.mesh.shape.items()
-                 if a != "data" and n > 1}
         if split:
             from flexflow_tpu.ops.inc_attention import refuse_windowed
 
             refuse_windowed(self.op_state,
                             f"a mesh that divides a model ({split})")
         self._note_block_diffusion(split)
+        from flexflow_tpu.ops.loop import init_counters as init_loop_counters
         from flexflow_tpu.ops.moe import init_counters
 
         init_counters(self)     # routed-expert layers, telemetry on
+        init_loop_counters(self)    # a loop region, likewise
         # --- pipeline-parallel serving plan (reference
         # inference_manager.cc:91-132 layer->stage placement); built after
         # KV consolidation so blocks carry their cache_layer_idx ---
@@ -1539,7 +1738,8 @@ class FFModel:
         elif rings:
             kinds = {FULL_STACK: [n for n in names if n not in rings],
                      WINDOW_STACK: rings}
-        elif len(names) < 2 and not (names and recurrent):
+        elif len(names) < 2 and not (names and (
+                recurrent or self.loop_region is not None)):
             return
         else:               # (beside recurrent layers even one is a stack)
             kinds = {FULL_STACK: names}
@@ -1564,9 +1764,13 @@ class FFModel:
                     raise NotImplementedError(
                         "windowed layers beside full ones need one cache "
                         f"shape a kind; {key} has {sorted(shapes)}")
+                if self.loop_region is not None:
+                    raise NotImplementedError(
+                        "a loop region's attention layers keep their planes "
+                        f"in one stack: one cache shape, not {sorted(shapes)}")
                 return  # heterogeneous caches keep the per-layer layout
-            for i, n in enumerate(names):
-                by_name[n].attrs["cache_layer_idx"] = i
+            planes = self._number_planes(names, by_name)
+            for n in names:
                 if key != FULL_STACK:
                     by_name[n].attrs["cache_stack"] = key
             # A cache starts zeroed, so allocate the stacks instead of
@@ -1577,9 +1781,39 @@ class FFModel:
             # 16).
             for n in names:
                 del self.op_state[n]
-            shape, dtype = (len(names),) + shapes.pop(), dtypes.pop()
+            shape, dtype = (planes,) + shapes.pop(), dtypes.pop()
             self.op_state[key] = {"k": jnp.zeros(shape, dtype),
                                   "v": jnp.zeros(shape, dtype)}
+            if self.loop_region is not None:
+                # what telemetry says of the planes (ffsv_kv_cache_bytes,
+                # ffsv_attn_positions_read_total{kind="full"}): a decode
+                # step reads every pass's plane of every live position
+                self.attention_kinds = {"full": {
+                    "layers": planes, "window": None,
+                    "cache_bytes": 2 * self.op_state[key]["k"].nbytes}}
+
+    def _number_planes(self, names, by_name) -> int:
+        """Give the layers ``names`` of one stack their planes, in order:
+        ``attrs["cache_layer_idx"]``, one a layer; a loop region's span
+        takes ``steps`` planes a layer, pass ``t`` of its layer ``l`` at
+        ``cache_layer_idx + t * attrs["loop_planes"]``
+        (inc_attention.cache_plane). Returns the planes in all."""
+        region = self.loop_region
+        looped = ([ly.name for ly in region.cache_layers]
+                  if region is not None else [])
+        at = 0
+        for n in names:
+            attrs = by_name[n].attrs
+            attrs.pop("loop_planes", None)
+            if n in looped:
+                attrs["cache_layer_idx"] = at + looped.index(n)
+                attrs["loop_planes"] = len(looped)
+                if n == looped[-1]:
+                    at += region.steps * len(looped)
+            else:
+                attrs["cache_layer_idx"] = at
+                at += 1
+        return at
 
     # ==================================================================
     # Training verbs (reference model.cc:2784/2807/2838 + fit)

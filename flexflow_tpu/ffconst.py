@@ -205,3 +205,8 @@ class OpType(enum.Enum):
     # a serving state-space mixer (Mamba-2), a recurrent state a slot
     # (ops/ssd_mixer.py)
     INC_SSD_MIXER = enum.auto()
+    # a loop region's two ends and the gate that reads its passes
+    # (ops/loop.py; FFModel.loop_begin / loop_end / loop_exit)
+    LOOP_BEGIN = enum.auto()
+    LOOP_END = enum.auto()
+    LOOP_EXIT = enum.auto()

@@ -251,6 +251,16 @@ def _window_kernel(len_ref, first_ref, *refs, mode, operand="first_ref",
                    **{operand: first_ref}, **static)
 
 
+def _plane_kernel(plane_ref, *refs, kern):
+    """Any of the kernels above over a stacked cache whose plane is an
+    OPERAND (``flash_attend``'s ``plane``): one more scalar-prefetched
+    operand in front of the lengths, read where a static ``layer_idx`` is
+    baked in. A loop region's pass index is traced (core/model.py), so its
+    layers' planes are; the cache stays in HBM and is only ever indexed by
+    DMA, so another plane is another DMA source and nothing is sliced out."""
+    kern(*refs, layer_idx=plane_ref[0])
+
+
 class _Held:
     """A value behind a ref's ``[:]``: the softmax state as the block form
     carries it through a DMA block's partitions, read and stored by the
@@ -690,7 +700,8 @@ def _stream_attend(len_ref, appos_ref, q_ref, qp_ref, slopes_ref, knew_ref,
     static_argnames=("causal", "qk_scale", "interpret", "out_dtype",
                      "layer_idx", "window", "summary_rows"))
 def flash_attend(q, k_cache, v_cache, lengths, qpos, bias=None,
-                 alibi=None, append_kv=None, rows=None, summaries=None, *,
+                 alibi=None, append_kv=None, rows=None, summaries=None,
+                 plane=None, *,
                  causal=True, qk_scale=None, out_dtype=None, layer_idx=None,
                  interpret=False, window=None, summary_rows=None):
     """Batched KV-cache attention.
@@ -702,7 +713,11 @@ def flash_attend(q, k_cache, v_cache, lengths, qpos, bias=None,
                             D=64 (position p in row p // 2, lanes
                             [(p % 2) * 64, +64): ops/kv_layout.py). Or the
                             whole stacked [L, R, KH, ...] buffer with
-                            ``layer_idx`` selecting the layer to stream.
+                            ``layer_idx`` selecting the layer to stream
+                            (static: a Python int), or with ``plane``, the
+                            same index as an OPERAND (a traced int32 scalar:
+                            a loop region's pass picks the plane, and one
+                            trace serves every pass).
                             Taken and returned as is: no cache operand is
                             reshaped here
     lengths  [R] int32      valid cache extent per request (0 => skip slot)
@@ -753,6 +768,8 @@ def flash_attend(q, k_cache, v_cache, lengths, qpos, bias=None,
     returns  [R, Q, H*D], or (out, k_cache, v_cache) with append_kv
     """
     assert rows is None or append_kv is None, "no fused append by row map"
+    assert plane is None or (layer_idx is None and k_cache.ndim == 5), (
+        "a plane is an operand or static, and indexes a stacked cache")
     R, Q, H, D = q.shape
     PACK = _pack_factor(D)
     assert PACK > 0 and k_cache.shape[-1] == PACK * D, (
@@ -879,8 +896,16 @@ def flash_attend(q, k_cache, v_cache, lengths, qpos, bias=None,
     static = dict(BS=BS, causal=causal, has_bias=has_bias,
                   has_alibi=has_alibi, qk_scale=float(qk_scale), G=G, Q=Q,
                   layer_idx=layer_idx, PACK=PACK, D=D, DB=DB)
+    # a traced plane goes in front of every other prefetched scalar
+    lead = ([] if plane is None
+            else [jnp.asarray(plane, jnp.int32).reshape(1)])
+
+    def planed(kern):
+        return kern if plane is None else functools.partial(_plane_kernel,
+                                                            kern=kern)
+
     if append_kv is None:
-        prefetch = [lengths.astype(jnp.int32)] + pipe + first
+        prefetch = lead + [lengths.astype(jnp.int32)] + pipe + first
         if rows is not None:
             prefetch.append(rows.astype(jnp.int32))
         if summary_rows is not None:
@@ -899,7 +924,7 @@ def flash_attend(q, k_cache, v_cache, lengths, qpos, bias=None,
             in_specs=qkv_in_specs + tail_in_specs,
             out_specs=o_spec, scratch_shapes=scratch)
         out = pl.pallas_call(
-            kern, grid_spec=grid_spec,
+            planed(kern), grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct(
                 (R, KH, GQ, DL),
                 jnp.float32 if PACK > 1 else out_dtype),
@@ -942,7 +967,7 @@ def flash_attend(q, k_cache, v_cache, lengths, qpos, bias=None,
                                  window=window, **static)
     knew_spec = pl.BlockSpec((1, A, KH, DL), lambda r, *_: (r, 0, 0, 0),
                              memory_space=pltpu.VMEM)
-    n_prefetch = 2 + len(pipe) + len(first) + len(run)
+    n_prefetch = len(lead) + 2 + len(pipe) + len(first) + len(run)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=n_prefetch, grid=(R,),
         in_specs=qkv_in_specs + [knew_spec, knew_spec] + tail_in_specs,
@@ -953,7 +978,7 @@ def flash_attend(q, k_cache, v_cache, lengths, qpos, bias=None,
         scratch_shapes=scratch + [
             pltpu.SemaphoreType.DMA((2, 2) if run or block else (2,))])
     out, k_out, v_out = pl.pallas_call(
-        kern, grid_spec=grid_spec,
+        planed(kern), grid_spec=grid_spec,
         out_shape=(jax.ShapeDtypeStruct(
             (R, KH, GQ, DL), jnp.float32 if PACK > 1 else out_dtype),
                    jax.ShapeDtypeStruct(k_cache.shape, k_cache.dtype),
@@ -962,8 +987,8 @@ def flash_attend(q, k_cache, v_cache, lengths, qpos, bias=None,
         input_output_aliases={n_prefetch + 6: 1, n_prefetch + 7: 2},
         compiler_params=compiler_params, cost_estimate=cost_estimate,
         interpret=interpret, **call_name,
-    )(lengths.astype(jnp.int32), *pipe, *first, appos.astype(jnp.int32), *run,
-      qt,
+    )(*lead, lengths.astype(jnp.int32), *pipe, *first,
+      appos.astype(jnp.int32), *run, qt,
       qp_gq, slopes_gq, k_new.astype(cache_dt), v_new.astype(cache_dt),
       bias.astype(jnp.float32), k_cache, v_cache)
     return post(out), k_out, v_out

@@ -2,7 +2,7 @@
 
 Capability parity with the reference model zoo (reference inference/models/
 llama.cc, opt.cc, falcon.cc, mpt.cc, starcoder.cc and their Python twins in
-python/flexflow/serve/models/; OLMoE, EXAONE-MoE, Mistral-4, SDAR-MoE, LongCat-Flash, ZAYA1 and Solar-Open2, sparse-expert families, EvaByte, a byte-level model of chunked attention, and Granite-4.0-H, a hybrid of state-space mixers and attention, are beyond it): each model family is a builder that records
+python/flexflow/serve/models/; OLMoE, EXAONE-MoE, Mistral-4, SDAR-MoE, LongCat-Flash, ZAYA1 and Solar-Open2, sparse-expert families, EvaByte, a byte-level model of chunked attention, and Granite-4.0-H, a hybrid of state-space mixers and attention, and Ouro, a stack of blocks run several times over one set of weights, are beyond it): each model family is a builder that records
 the decoder graph through the FFModel op-builder surface, plus a HuggingFace
 state-dict name mapping so real checkpoints load. ``FAMILIES`` maps the HF
 ``model_type`` to the family (the reference's ModelType enum +
@@ -22,6 +22,7 @@ from flexflow_tpu.models import mistral4 as _mistral4
 from flexflow_tpu.models import mpt as _mpt
 from flexflow_tpu.models import olmoe as _olmoe
 from flexflow_tpu.models import opt as _opt
+from flexflow_tpu.models import ouro as _ouro
 from flexflow_tpu.models import sdar_moe as _sdar_moe
 from flexflow_tpu.models import solar_open2 as _solar_open2
 from flexflow_tpu.models import starcoder as _starcoder
@@ -41,6 +42,7 @@ from flexflow_tpu.models.mistral4 import (Mistral4Config,
 from flexflow_tpu.models.mpt import MPTConfig, create_mpt_model
 from flexflow_tpu.models.olmoe import OLMoEConfig, create_olmoe_model
 from flexflow_tpu.models.opt import OPTConfig, create_opt_model
+from flexflow_tpu.models.ouro import OuroConfig, create_ouro_model
 from flexflow_tpu.models.sdar_moe import SDARMoEConfig, create_sdar_moe_model
 from flexflow_tpu.models.solar_open2 import (SolarOpen2Config,
                                              create_solar_open2_model)
@@ -114,6 +116,9 @@ FAMILIES = {
                                     create_granite_hybrid_model,
                                     _granite_hybrid.hf_weight_map,
                                     _granite_hybrid.preprocess_hf_state_dict),
+    # one stack of blocks run several times: a loop region (ops/loop.py)
+    "ouro": ModelFamily("ouro", OuroConfig, create_ouro_model,
+                        _ouro.hf_weight_map, _ouro.preprocess_hf_state_dict),
     "zaya": ModelFamily("zaya", ZayaConfig, create_zaya_model,
                         _zaya.hf_weight_map,
                         _zaya.preprocess_hf_state_dict),
@@ -147,6 +152,7 @@ __all__ = [
     "ModelFamily",
     "OLMoEConfig",
     "OPTConfig",
+    "OuroConfig",
     "SDARMoEConfig",
     "STARCODERConfig",
     "SolarOpen2Config",
@@ -161,6 +167,7 @@ __all__ = [
     "create_mpt_model",
     "create_olmoe_model",
     "create_opt_model",
+    "create_ouro_model",
     "create_sdar_moe_model",
     "create_solar_open2_model",
     "create_starcoder_model",

@@ -16,6 +16,7 @@ from flexflow_tpu.ops import (  # noqa: F401
     inc_attention,
     latent_attention,
     linear,
+    loop,
     matmul,
     moe,
     norm,

@@ -309,9 +309,13 @@ def _attend(attrs, q, k_cache, v_cache, lengths, qpos, out_dtype, ctx,
         if append_kv is not None:
             k_new, v_new, *at = append_kv             # [R, A, KH, D] each
             fkv = (_pad_d(k_new, Dp), _pad_d(v_new, Dp), *at)
+        # the stack's plane: static, or traced inside a loop region
+        # (``cache_plane``) and then an operand of the kernel
+        which = ("layer_idx" if layer_idx is None
+                 or isinstance(layer_idx, int) else "plane")
         attend = functools.partial(
             flash_attend, causal=causal, qk_scale=scale,
-            out_dtype=out_dtype, layer_idx=layer_idx,
+            out_dtype=out_dtype, **{which: layer_idx},
             interpret=ffk.pallas_interpret_forced(), window=window,
             **({} if chunked is None else
                {"summaries": summaries, "summary_rows": ns}))
@@ -769,6 +773,20 @@ def block_visibility(attrs, q_abs):
     return q_abs if B is None else q_abs // B * B + (B - 1)
 
 
+def cache_plane(ctx, attrs):
+    """The plane of its stack that holds this layer's cache in this step:
+    ``attrs["cache_layer_idx"]`` (a Python int; None: a cache of its own),
+    and inside a loop region (``attrs["loop_planes"]``, the planes a pass;
+    core/model.LoopRegion) the pass's own, ``cache_layer_idx + loop_step *
+    loop_planes``, a TRACED int32 scalar: the kernels take it as an operand
+    and the appends as an index, and no plane is sliced out of the stack."""
+    idx = attrs.get("cache_layer_idx")
+    step = getattr(ctx, "loop_step", None)
+    if idx is None or step is None or "loop_planes" not in attrs:
+        return idx
+    return idx + step * attrs["loop_planes"]
+
+
 def _stack(ctx, attrs):
     """(key, {"k", "v"}) of the stack that holds this layer's cache."""
     key = attrs.get("cache_stack", FULL_STACK)
@@ -779,7 +797,7 @@ def read_kv(ctx, attrs):
     ov = getattr(ctx, "kv_override", None)
     if ov is not None:   # pipeline-parallel block execution: the stage
         return ov        # loop hands this layer its own KV slice directly
-    idx = attrs.get("cache_layer_idx")
+    idx = cache_plane(ctx, attrs)
     if idx is None:
         st = ctx.state_in[ctx.layer_name]
         return st["k_cache"], st["v_cache"]
@@ -791,7 +809,7 @@ def write_kv(ctx, attrs, k_cache, v_cache):
     if getattr(ctx, "kv_override", None) is not None:
         ctx.kv_written = (k_cache, v_cache)
         return
-    idx = attrs.get("cache_layer_idx")
+    idx = cache_plane(ctx, attrs)
     if idx is None:
         ctx.state_out[ctx.layer_name] = {"k_cache": k_cache,
                                          "v_cache": v_cache}
@@ -933,7 +951,7 @@ def append_and_ref(ctx, attrs, k, v, start_pos, num_tokens, active,
     at 7B geometry the slice traffic is ~134MB per layer per step and
     dominated the whole speculation round."""
     ov = getattr(ctx, "kv_override", None)
-    idx = attrs.get("cache_layer_idx")
+    idx = cache_plane(ctx, attrs)
     contiguous = getattr(ctx, "kv_contiguous", False)
     # a windowed layer's ring takes the appends that are exact everywhere
     # (by slot, or the scatter)
@@ -994,11 +1012,13 @@ def append_and_ref(ctx, attrs, k, v, start_pos, num_tokens, active,
         vs = append_kv_contiguous(st["v"], idx, v, start_pos, active,
                                   pack=pack)
     elif (k.shape[1] == 1 or pack > 1 or ring or chunked
-          or "block_length" in attrs):
+          or "block_length" in attrs or not isinstance(idx, int)):
         # (a packed stack and a ring take every width in place:
         # append_kv_stacked; so does a block-diffusion layer's pass where
         # the attention kernel does not append it itself, off the Pallas
-        # path: a block's few rows a (request, head), the same cache bits)
+        # path: a block's few rows a (request, head), the same cache bits;
+        # so does a loop region's pass, whose plane is traced: the branch
+        # below would slice the plane out and copy it back)
         _note_scatter(k, pack)
         ks = append_kv_stacked(st["k"], idx, k, start_pos, num_tokens,
                                active, pack, ring=ring)
@@ -1143,7 +1163,7 @@ class IncMultiHeadSelfAttention(OpImpl):
         append_q = getattr(ctx, "kv_append_q", None)
         eff_q = append_q if (append_q is not None and Q > append_q) else Q
         slots = meta.slots
-        idx = attrs.get("cache_layer_idx")
+        idx = cache_plane(ctx, attrs)
         fused = False
         if slots is None and getattr(ctx, "kv_override", None) is None:
             if idx is None:

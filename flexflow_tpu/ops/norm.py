@@ -165,7 +165,8 @@ class RMSNorm(OpImpl):
             return [WeightSpec("weight", (attrs.get("dim", shape[-1]),),
                                DataType.DT_FLOAT, ZeroInitializer())]
         return [WeightSpec("weight", (attrs.get("dim", shape[-1]),), dtype,
-                           ConstantInitializer(1.0))]
+                           attrs.get("initializer")
+                           or ConstantInitializer(1.0))]
 
     @staticmethod
     def forward(attrs, params, inputs, ctx):

@@ -180,6 +180,16 @@ def _adapt_depth_rule(adapt, act_i, n_acc, depth_v, alive, min_depth,
     return depth_v, alive
 
 
+def serving_jit(config, fn, **kw):
+    """``jax.jit`` for a serving program of a model under ``config``: with
+    the configuration's XLA options where it states any
+    (``FFConfig.compiler_options``), and as plain ``jax.jit`` where not."""
+    options = getattr(config, "compiler_options", None)
+    if options:
+        kw["compiler_options"] = dict(options)
+    return jax.jit(fn, **kw)
+
+
 def make_draft_chain(model, compute_dtype, depth: int):
     """Build a fused greedy draft-chain program for one SSM.
 
@@ -207,7 +217,7 @@ def make_draft_chain(model, compute_dtype, depth: int):
             body, (op_state, tok, pos), jnp.arange(depth))
         return jnp.transpose(toks), op_state                # [R, depth]
 
-    return jax.jit(chain, donate_argnums=(1,))
+    return serving_jit(model.config, chain, donate_argnums=(1,))
 
 
 def make_decode_block(model, compute_dtype, max_steps: int, width: int = 1):
@@ -234,8 +244,9 @@ def make_decode_block(model, compute_dtype, max_steps: int, width: int = 1):
     """
     bd = getattr(model, "block_diffusion", None)
     if bd is not None:
-        return jax.jit(_diffusion_block(model, compute_dtype, max_steps, bd),
-                       donate_argnums=(1,))
+        return serving_jit(
+            model.config, _diffusion_block(model, compute_dtype, max_steps, bd),
+            donate_argnums=(1,))
 
     def block(params, op_state, tok, pos, active, rng, n):
         R = tok.shape[0]
@@ -275,7 +286,7 @@ def make_decode_block(model, compute_dtype, max_steps: int, width: int = 1):
             cond, body, (jnp.int32(0), op_state, tok, pos, out0))
         return out, op_state, tok
 
-    return jax.jit(block, donate_argnums=(1,))
+    return serving_jit(model.config, block, donate_argnums=(1,))
 
 
 # A diffusion block's read-back, one int32 row a slot: the tokens the row
@@ -635,8 +646,8 @@ class MultiSpecEngine:
         self.telemetry = None   # explicit ServingTelemetry; None -> global
         self._compute_dtype = jnp.dtype(llm.config.compute_dtype)
         nssm = len(self.ssms)
-        self._block = jax.jit(
-            self._block_impl,
+        self._block = serving_jit(
+            llm.config, self._block_impl,
             donate_argnums=(1,) + tuple(3 + 2 * i for i in range(nssm)))
         # jit-cache accounting: _block_impl's python body runs ONLY when
         # XLA (re)traces, so _trace_count is the compile count; run_block
@@ -899,7 +910,8 @@ class BeamSpecEngine:
         for t in range(depth):
             nd[1 + t * width: 1 + (t + 1) * width] = t + 1
         self._depth_of = jnp.asarray(nd)
-        self._block = jax.jit(self._block_impl, donate_argnums=(1, 3))
+        self._block = serving_jit(llm.config, self._block_impl,
+                                  donate_argnums=(1, 3))
         # jit-cache accounting (see MultiSpecEngine.__init__)
         self._trace_count = 0
         self._traces_reported = 0

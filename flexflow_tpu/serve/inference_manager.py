@@ -61,10 +61,13 @@ class InferenceManager:
             raise NotImplementedError(
                 "inference_debugging dumps need per-layer params; not "
                 "available with pipeline_parallelism_degree > 1")
+        from flexflow_tpu.serve.engine import serving_jit
+
         cfg = model.config
         self._compute_dtype = jnp.dtype(cfg.compute_dtype)
-        self._step = jax.jit(self._step_impl, donate_argnums=(1,))
-        self._prefill = jax.jit(self._prefill_impl, donate_argnums=(1,))
+        self._step = serving_jit(cfg, self._step_impl, donate_argnums=(1,))
+        self._prefill = serving_jit(cfg, self._prefill_impl,
+                                    donate_argnums=(1,))
         self._rng = jax.random.PRNGKey(cfg.seed)
         self._decode_block = None
         self._decode_block_width = 0    # the width _decode_block was built at

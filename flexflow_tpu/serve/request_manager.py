@@ -60,6 +60,7 @@ from flexflow_tpu.serve.inference_manager import (BlockPasses,
 from flexflow_tpu.serve.step_costs import StepCosts
 from flexflow_tpu.ops.inc_attention import (commit_tree_kv,
                                             refuse_block_diffusion)
+from flexflow_tpu.ops.loop import refuse_looped
 from flexflow_tpu.telemetry import (PendingPrefill, get_telemetry,
                                     mint_trace_id)
 from flexflow_tpu.utils.profiling import device_fence
@@ -1182,6 +1183,8 @@ class RequestManager:
         for m in (llm, *ssms):
             refuse_block_diffusion(m, "speculation (drafting, tree "
                                    "verification and its commit)")
+            refuse_looped(m, "speculation (drafting, tree verification "
+                          "and its commit)")
         widths = [s.config.max_beam_width for s in ssms]
         W = beam_width or max(widths)
         if any(w != W for w in widths):

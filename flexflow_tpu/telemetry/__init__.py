@@ -128,6 +128,15 @@ layers x steps of the decode blocks, each one state read and written;
 and, where a prefill step's chunked form runs as a kernel (``kda_chunk``;
 the mixer's is plain XLA: ``attention_kinds["recurrent"]["chunk_kernel"]``),
 ``ffsv_kda_chunk_tokens_total``: prefill tokens x recurrent layers.
+A model with a LOOP REGION (ops/loop.py: a span of layers run several times
+over one set of weights, a cache plane a pass; ``FFModel.loop_region``) has
+``kind="full"`` over ALL its planes (passes x layers: what a decode step
+reads) and ``ffsv_loop_layer_steps_total{phase}``: the layer-applications
+the steps' real tokens went through, tokens x the span's layers added once
+a pass INSIDE the device loop (``ops/loop.count_pass``, in the op state as
+the routed experts' counters are, fetched with a snapshot), with
+``ffsv_loop_tokens_total{phase}``, those tokens counted beside them: the
+one over the span's layers and the other is the passes a token ran.
 ``ffsv_kv_cache_bytes`` is what compile allocated for each kind;
 ``ffsv_attn_positions_read_total`` is what the rows of the decode steps had
 to attend, from the batch's lengths on the host: for each row of each step
@@ -556,6 +565,9 @@ class ServingTelemetry:
         # id -> [weak reference, the counters' values at the last snapshot]
         self._watched = {}
         r.add_collector(self._collect_moe)
+        # the served model's loop-region counter, if it has one (watch_model)
+        self.loop = None
+        r.add_collector(self._collect_loop)
 
     # -- on-device counters (ops/moe.py) ----------------------------------
     def watch_model(self, model):
@@ -564,6 +576,7 @@ class ServingTelemetry:
         device call (serve/inference_manager.py); a model without such
         counters costs two dict lookups."""
         from flexflow_tpu.ffconst import OpType
+        from flexflow_tpu.ops.loop import LOOP_COUNTERS
         from flexflow_tpu.ops.moe import MOE_COUNTERS, counter_fields
 
         if (id(model) not in self._watched
@@ -572,6 +585,10 @@ class ServingTelemetry:
                 weakref.ref(model), 0,
                 counter_fields([ly for ly in model.layers
                                 if ly.op_type == OpType.MOE_EXPERTS])]
+        if self.loop is None and LOOP_COUNTERS in (model.op_state or {}):
+            # a loop region's counter (ops/loop.py), read off the device
+            # with every snapshot: [weak reference, its values at the last]
+            self.loop = [weakref.ref(model), 0]
         for kind, a in (getattr(model, "attention_kinds", None)
                         or {}).items():     # rings beside full, or latent
             self.registry.gauge(
@@ -690,19 +707,45 @@ class ServingTelemetry:
                 for sp, n in runs if n), a["layers"])
 
     @staticmethod
-    def _read_counters(model):
-        """The counters as numpy, or None. The serving thread donates the
-        op state to every device call and puts the new one in its place:
-        a reader on another thread can catch the old array deleted, and
-        looks again."""
+    def _read_counters(model, key: Optional[str] = None):
+        """The counters under ``key`` (None: the routed experts') as numpy,
+        or None. The serving thread donates the op state to every device
+        call and puts the new one in its place: a reader on another thread
+        can catch the old array deleted, and looks again."""
         from flexflow_tpu.ops.moe import MOE_COUNTERS
 
         for _ in range(8):
             try:
-                return np.asarray(model.op_state[MOE_COUNTERS])
+                return np.asarray(model.op_state[key or MOE_COUNTERS])
             except (RuntimeError, KeyError):
                 time.sleep(0.002)
         return None
+
+    def _collect_loop(self):
+        """``ffsv_loop_layer_steps_total{phase}`` and
+        ``ffsv_loop_tokens_total{phase}``: what the device counted
+        (ops/loop.LOOP_COUNTERS: uint32, wraps) since the last snapshot."""
+        from flexflow_tpu.ops.loop import LOOP_COUNTERS, LOOP_PHASES
+
+        model = self.loop and self.loop[0]()
+        if model is None:
+            return
+        raw = self._read_counters(model, LOOP_COUNTERS)
+        if raw is None:
+            return
+        gained = (raw - np.uint32(self.loop[1])).astype(np.int64)
+        self.loop[1] = raw
+        for phase, (layer_steps, tokens) in zip(LOOP_PHASES, gained):
+            lab = f'{{phase="{phase}"}}'
+            self.registry.counter(
+                "ffsv_loop_layer_steps_total" + lab,
+                "real tokens x the layers of the loop region's span, added "
+                "once a pass on the device: the layer-applications the "
+                "steps' tokens went through").inc(int(layer_steps))
+            self.registry.counter(
+                "ffsv_loop_tokens_total" + lab,
+                "those tokens, counted on the device with them"
+                ).inc(int(tokens))
 
     def _collect_moe(self):
         from flexflow_tpu.ops.moe import MOE_PHASES, ZERO_FIELD

@@ -781,6 +781,7 @@ CELL_STREAMS = {
     "longcat-flash-omni": (64, 8192),
     "solar-open2-250b": (8, 64, 128, 16384, 1, 256),    # its GQA layers
     "granite-4.0-h-micro": (8, 32, 64, 8192, 1, 256),   # packed: outside
+    "ouro-2.6b": (16, 16, 128, 1024, 1, 128),           # OLMoE's shape
 }
 
 
@@ -790,7 +791,8 @@ def _cells():
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "BENCHMARK.json")) as f:
-        return [(w["name"], w["config"]) for w in json.load(f)["workloads"]]
+        cells = json.load(f)["workloads"]
+    return [(w["name"], w["config"]) for w in cells]
 
 
 @pytest.mark.parametrize("cell,config", _cells())
@@ -1002,3 +1004,56 @@ def test_the_decode_forms_compile_for_a_v5e_at_the_cells_shapes(cell,
         aval((R, Q), jnp.int32), aval((R, Q, KH, D)),
         aval((R,), jnp.int32)).compile()
     assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
+
+
+# ---------------------------------------------------------------------------
+# the plane of a stacked cache as an OPERAND (a loop region's pass picks it)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("mode", ["append", "run", "rows", "grid"])
+@pytest.mark.parametrize("KH", [16, 2], ids=["loop_form", "block_form"])
+def test_flash_at_a_traced_plane_is_the_static_one_bit_for_bit(mode, KH):
+    """``flash_attend(plane=)``: the index of the stacked cache as a traced
+    int32 scalar (one more scalar-prefetched operand, read where the static
+    ``layer_idx`` is baked in), at a stack of 2 passes x 3 layers = 6
+    planes, under ``jit`` with the plane an argument: the output and, with
+    ``append_kv``, both caches are those of the static call bit for bit, at
+    every plane; no other plane is written."""
+    R, Q, D, S, L = 3, {"append": 1, "run": 4, "rows": 16, "grid": 8}[mode], \
+        128, 256, 6
+    H = 16
+    rng = np.random.RandomState(60)
+    q = jnp.asarray(rng.randn(R, Q, H, D), jnp.bfloat16)
+    ks = jnp.asarray(rng.randn(L, R, KH, S, D), jnp.bfloat16)
+    vs = jnp.asarray(rng.randn(L, R, KH, S, D), jnp.bfloat16)
+    start = jnp.asarray([130, 0, 7], jnp.int32)
+    live = jnp.asarray([True, False, True])
+    lengths = jnp.where(live, start + Q, 0)
+    qpos = start[:, None] + jnp.arange(Q)[None]
+    kw = {}
+    if mode in ("append", "run"):
+        k_new = jnp.asarray(rng.randn(R, Q, KH, D), jnp.bfloat16)
+        v_new = jnp.asarray(rng.randn(R, Q, KH, D), jnp.bfloat16)
+        at = jnp.where(live, start, -1)
+        kw["append_kv"] = ((k_new, v_new, at) if mode == "append" else
+                           (k_new, v_new, at, jnp.full((R,), Q, jnp.int32)))
+    elif mode == "rows":
+        kw["rows"] = jnp.asarray([2, 0, 2], jnp.int32)
+
+    traced = jax.jit(lambda plane, k, v: fa.flash_attend(
+        q, k, v, lengths, qpos, plane=plane, interpret=True, **kw))
+    for plane in (0, 4, 5):
+        want = fa.flash_attend(q, ks, vs, lengths, qpos, layer_idx=plane,
+                               interpret=True, **kw)
+        got = traced(jnp.int32(plane), ks, vs)
+        for a, b in zip(jax.tree.leaves(want), jax.tree.leaves(got)):
+            assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+        if "append_kv" in kw:
+            others = np.arange(L) != plane
+            assert (np.asarray(got[1], np.float32)[others]
+                    == np.asarray(ks, np.float32)[others]).all()
+            assert (np.asarray(got[1], np.float32)[plane]
+                    != np.asarray(ks, np.float32)[plane]).any()
+    with pytest.raises(AssertionError, match="operand or static"):
+        fa.flash_attend(q, ks, vs, lengths, qpos, plane=jnp.int32(1),
+                        layer_idx=1, interpret=True)
