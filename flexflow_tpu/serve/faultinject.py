@@ -14,7 +14,7 @@ everything else reduces to:
 Pieces:
 
 * :class:`FaultInjector` — wraps a model's ``InferenceManager.step`` /
-  ``decode_block`` with seeded modulo-counter faults: raise
+  ``launch_decode_block`` with seeded modulo-counter faults: raise
   :class:`EngineFault` every ``error_every``-th device call (bounded by
   ``max_errors``) and/or stall ``stall_s`` every ``stall_every``-th.
   Counter-based, not clock-based, so runs replay exactly.
@@ -100,25 +100,27 @@ class FaultInjector:
         ifm = getattr(model, "_inference_manager", None)
         if ifm is None:
             ifm = model._inference_manager = InferenceManager(model)
-        orig_step, orig_decode = ifm.step, ifm.decode_block
+        # a decode block's launch: the end every caller goes through (the
+        # incremental loop's two-ended block, and ``decode_block`` itself)
+        orig_step, orig_decode = ifm.step, ifm.launch_decode_block
 
         def step(*a, **k):
             self._tick()
             return orig_step(*a, **k)
 
-        def decode_block(*a, **k):
+        def launch_decode_block(*a, **k):
             self._tick()
             return orig_decode(*a, **k)
 
         ifm.step = step
-        ifm.decode_block = decode_block
+        ifm.launch_decode_block = launch_decode_block
         self._installed.append((ifm, orig_step, orig_decode))
         return self
 
     def uninstall(self):
         for ifm, orig_step, orig_decode in self._installed:
             ifm.step = orig_step
-            ifm.decode_block = orig_decode
+            ifm.launch_decode_block = orig_decode
         self._installed.clear()
 
 

@@ -51,6 +51,16 @@ class BlockPasses(NamedTuple):
         return self.stats["folded"] * self.block_length
 
 
+class LaunchedBlock(NamedTuple):
+    """A decode block between its two ends
+    (``InferenceManager.launch_decode_block`` / ``read_decode_block``): the
+    packed output, still on the device, and what unpacks it."""
+
+    toks: Any           # int32 [R, ...], the device's future
+    n_steps: int
+    width: int
+
+
 class InferenceManager:
     """Owns the jitted step functions for one FFModel serving graph."""
 
@@ -190,19 +200,33 @@ class InferenceManager:
         the rows' windows ``[R, 2 * decode_width]`` (-1: a masked
         position), ``pos`` the lengths their caches hold, and the return a
         ``BlockPasses`` (engine._diffusion_block).
-        ``tel``: as in ``step`` (program ``decode_block``). ``rnd`` (the
-        caller's telemetry.RoundTrace; None: it has none) may hold a
-        prefill step that was launched before this block and is not waited
-        for yet: its wait comes once the block is queued behind it, between
-        this call's ``call_launch`` and ``call_wait``.
+        The call is its two ends, one after the other: whoever has work
+        to queue behind the running block (the incremental loop: the next
+        round's first prefill step) calls ``launch_decode_block``, then
+        ``read_decode_block``. ``tel``, ``rnd``: as there.
         """
+        return self.read_decode_block(
+            self.launch_decode_block(tok, pos, active, n_steps, tel, rnd),
+            tel)
+
+    def launch_decode_block(self, tok, pos, active, n_steps: int,
+                            tel=None, rnd=None) -> LaunchedBlock:
+        """Stage and dispatch a decode block; nothing is waited for but
+        ``rnd``'s pending prefill step. ``tel``: as in ``step`` (program
+        ``decode_block``; the ``call_wait`` leaf is ``read_decode_block``'s).
+        ``rnd`` (the caller's telemetry.RoundTrace; None: it has none) may
+        hold a prefill step that was launched before this block and is not
+        waited for yet: its wait comes once the block is queued behind it,
+        after this call's ``call_launch``."""
         from flexflow_tpu.serve.engine import make_decode_block
 
         if self.model.config.inference_debugging:
             # debug mode serializes decode into per-step step() calls so
             # every decode token's op tensors are dumped (the fused
             # while_loop body cannot host-dump); same numerics, slower.
-            return self._decode_block_debug(tok, pos, active, n_steps)
+            return LaunchedBlock(
+                self._decode_block_debug(tok, pos, active, n_steps),
+                n_steps, 1)
         width = self.decode_width
         if self._decode_block_width != width:
             self._decode_block = make_decode_block(
@@ -225,6 +249,18 @@ class InferenceManager:
             tel.call_phase(ph, None)
             if rnd is not None:
                 rnd.settle()
+        return LaunchedBlock(toks, n_steps, width)
+
+    def read_decode_block(self, launched: LaunchedBlock, tel=None):
+        """Read a launched block back (the blocking device->host read, the
+        call's ``call_wait`` leaf) and unpack it: what ``decode_block``
+        returns. Calls launched after the block (a prefill step queued
+        behind it) delay nothing: the device runs them in launch order."""
+        toks, n_steps, width = launched
+        if isinstance(toks, np.ndarray):    # the debug path's: read already
+            return toks
+        ph = None
+        if tel is not None:
             ph = tel.call_phase(None, "call_wait", "decode_block")
         toks = np.asarray(toks)
         if tel is not None:

@@ -638,8 +638,9 @@ class RequestManager:
         ``call_*`` leaves take over from the open phase, and
         ``sched_build`` resumes after them, and the step is waited for
         once the round's NEXT device call has been launched (the next
-        step, here; InferenceManager.decode_block or step;
-        engine.run_block; failing all, RoundTrace.end), so the device has
+        step, here; InferenceManager.launch_decode_block or step;
+        engine.run_block; failing all, RoundTrace.end; a lead step's round
+        is the one after its launch), so the device has
         work queued meanwhile, as it has with telemetry off. Without a
         round (the host-stepped speculation loop) the step is waited for
         at once."""
@@ -802,7 +803,8 @@ class RequestManager:
         output-free step, return them (none: nothing is filling). The one
         prefill path of the Python loops; the caller moves its depth marks
         by the rows returned. The speculation loops call it once a model a
-        round, the incremental loop as often as StepCosts allows the round;
+        round, the incremental loop as often as StepCosts allows the round
+        (the round's first behind the block before, where it can);
         ``rnd`` and ``model``: _timed_prefill."""
         chunk, segments = shape
         compact = self._compact_prefill(ifm)
@@ -912,10 +914,13 @@ class RequestManager:
         if costs is None:
             costs = ifm.step_costs = StepCosts()
 
-        def caught_up():
+        def resident():
             return [req for req in active
-                    if req is not None and not req.finished
-                    and req.cache_depth == len(req.tokens) - held_back(req)]
+                    if req is not None and not req.finished]
+
+        def caught_up():
+            return [req for req in resident()
+                    if req.cache_depth == len(req.tokens) - held_back(req)]
 
         def block_steps(live, prefilled: bool) -> int:
             """The decode block's steps for ``live``. Dynamic trip count:
@@ -930,9 +935,35 @@ class RequestManager:
                 min(cfg.decode_block_steps, chunk) if prefilled
                 else cfg.decode_block_steps)
 
+        def prefill_step(tel, rnd) -> int:
+            """One prefill step over whoever is filling, their depths
+            moved on; the steps that made (0: nobody is filling)."""
+            rows = self._prefill(ifm, active, shape,
+                                 lambda r: r.cache_depth, tel, rnd)
+            for slot, chunk_toks, sp in rows:
+                active[slot].cache_depth = sp + len(chunk_toks)
+            return 1 if rows else 0
+
+        # A decode block's read-back waits one launch, as a prefill step's
+        # does (ISSUE 61): while someone resident is still filling, the
+        # NEXT round's first prefill step, its LEAD step, is chosen, staged
+        # and launched behind the running block, before the block is read.
+        # The device runs calls in launch order, so the step starts the
+        # moment the block ends, and the host's learning so, the commit,
+        # the admission and the next build pass while it runs. One step
+        # and no more: the round's allowance is computed from the committed
+        # state. ``lead``: the step the round before launched for this one
+        # (0 or 1), ``led`` whom it brought to a prompt's end, ``handed``
+        # its wait where telemetry is on; ``due``: StepCosts' answer for
+        # the next round that prefills, asked where its lead step would be
+        # launched (None: not asked yet; a round to be timed takes no lead
+        # step and starts on an idle device).
+        lead, led, handed, due = 0, (), None, None
         while self.pending or any(a is not None for a in active):
             tel = self._tel()
-            rnd = tel.begin_round("incr", R) if tel is not None else None
+            rnd = (tel.begin_round("incr", R, handed) if tel is not None
+                   else None)
+            handed = None
             self._reap_expired(active, max_seq, done)
             self._fill_slots(active, max_seq, done)
             self._prefix_install(active, (("llm", ifm),))
@@ -956,24 +987,27 @@ class RequestManager:
             # not count: no prefill step helps them. With nothing decoding
             # there is nobody to stall: the round prefills until a request
             # has caught up.
-            decoding = caught_up()
+            # (the lead step is this round's first: whoever it brought
+            # to the end of a prompt was filling when the round's steps
+            # began, and counts so)
+            decoding = [req for req in caught_up() if req.guid not in led]
             allowed = None
             if decoding:
-                filling = (sum(req is not None and not req.finished
-                               for req in active) - len(decoding))
+                filling = len(resident()) - len(decoding)
                 allowed = costs.allowance(block_steps(decoding, True),
                                           len(decoding), filling)
                 if tel is not None:
                     tel.note_round_allowance(
                         allowed, costs.weight(len(decoding), filling))
-            steps, timed, t0 = 0, False, time.perf_counter()
-            while allowed is None or steps < allowed:
-                rows = self._prefill(ifm, active, shape,
-                                     lambda r: r.cache_depth, tel, rnd)
-                if not rows:
+            steps, timed, t0 = lead, False, time.perf_counter()
+            lead, led = 0, ()
+            while (steps < allowed if allowed is not None
+                   else not (steps and caught_up())):
+                if not prefill_step(tel, rnd):
                     break
                 if not steps:
-                    timed = costs.due()
+                    timed = costs.due() if due is None else due
+                    due = None
                 steps += 1
                 if timed:
                     # a timed round waits for each step before it stages
@@ -986,10 +1020,6 @@ class RequestManager:
                         rnd.phase(None)
                         rnd.settle()
                         rnd.phase("sched_build")
-                for slot, chunk_toks, sp in rows:
-                    active[slot].cache_depth = sp + len(chunk_toks)
-                if allowed is None and caught_up():
-                    break
             if timed:
                 costs.note_prefill(time.perf_counter() - t0, steps)
             if tel is not None:
@@ -1012,8 +1042,23 @@ class RequestManager:
                 if rnd is not None:
                     rnd.phase(None)
                 t0 = time.perf_counter()
-                toks = ifm.decode_block(tok, pos, act, block, tel=tel,
-                                        rnd=rnd)
+                launched = ifm.launch_decode_block(tok, pos, act, block,
+                                                   tel=tel, rnd=rnd)
+                if len(live) < len(resident()):     # someone is filling
+                    if due is None:
+                        due = costs.due()
+                    if not due:
+                        if rnd is not None:
+                            rnd.phase("sched_build")
+                        lead, due = prefill_step(tel, rnd), None
+                        led = ({req.guid for req in caught_up()}
+                               - {req.guid for req in live})
+                        if rnd is not None:
+                            rnd.phase(None)
+                            handed = rnd.hand_on()
+                if tel is not None:
+                    tel.note_round_ahead(bool(lead))
+                toks = ifm.read_decode_block(launched, tel=tel)
                 dt = time.perf_counter() - t0   # the np readback = fence
                 if timed or not steps:  # the device was idle at dispatch
                     costs.note_decode(dt, block)

@@ -31,6 +31,10 @@ readback fences both, so a round's wall time does not say which program
 took it. Every ``EVERY``-th round that prefills is therefore TIMED: the
 loop waits for each of the round's prefill steps before it stages the
 next (a few ms of lost overlap a step), and gets a sample of each cost.
+Any other round's first step is launched behind the decode block before
+it, while that block runs (its LEAD step); ``due`` is asked where that
+step would be launched, and a round that is to be timed takes none, so
+it starts on an idle device.
 Telemetry or not: with it on the wait is on the step's own output and
 with it off a fence of the state, and every other round's steps queue
 behind each other either way, so the traced run has the policy and the
@@ -60,8 +64,9 @@ class StepCosts:
         self._rounds = 0
 
     def due(self) -> bool:
-        """Asked once by each round that prefilled: whether to time it.
-        Every one until both estimates stand, then one in ``EVERY``."""
+        """Asked once for each round that prefills, where its first step
+        is about to be launched: whether to time it. Every one until both
+        estimates stand, then one in ``EVERY``."""
         self._rounds += 1
         return (min(len(self._prefill), len(self._decode)) < self.MIN
                 or self._rounds % self.EVERY == 0)

@@ -32,6 +32,7 @@ ffsv_prefill_attended_pairs_total counter   (query, key) pairs prefill attended
 ffsv_round_prefill_steps         histogram  prefill steps a round dispatched
 ffsv_round_prefill_allowance     histogram  prefill steps a round was allowed
 ffsv_round_prefill_weight        histogram  its (decoding + filling) / decoding
+ffsv_round_prefill_ahead         histogram  1: a step was queued behind the block
 ffsv_spec_rounds_total           counter    speculation rounds executed
 ffsv_decode_steps_total          counter    row-steps of decode blocks
 ffsv_diffusion_row_passes_total  counter    passes block-diffusion rows ran
@@ -168,10 +169,15 @@ every device call records ``call_stage`` / ``call_launch`` / ``call_wait``
 through ``ServingTelemetry.call_phase`` (inside a ``spec_block`` span for
 the fused engines), in sequence and never two open. A call's wait may come
 after the next call's launch (``stage k+1, launch k+1, wait k``: a lagged
-prefill step, in the incremental loop and in the fused speculation loop);
-README "Telemetry" has the tables. A ``prefill`` span says whose cache the
-step filled (``model``: ``llm``, ``ssm<i>``), a ``spec_block`` span what it
-was launched behind (``behind``: ``prefill`` or nothing).
+prefill step, in the incremental loop and in the fused speculation loop;
+the incremental loop's decode block too, where a request is still filling:
+the next round's first step, its LEAD step, is staged and launched between
+the block's ``call_launch`` and its ``call_wait``, and waited for in that
+next round, whose ``RoundTrace`` is handed it); README "Telemetry" has the
+tables. A ``prefill`` span says whose cache the
+step filled (``model``: ``llm``, ``ssm<i>``) and, on a lead step's,
+``ahead``; a ``spec_block`` span what it was launched behind (``behind``:
+``prefill`` or nothing).
 
 Fleet layer (this package's distributed half): ``fleet.FleetTelemetry``
 keeps one ServingTelemetry per replica (distinct Chrome-trace ``pid``
@@ -197,7 +203,9 @@ the enqueue alone (utils/profiling.py protocol). Both loops that serve
 traffic (incremental; fused speculation) make a prefill step's wait only
 after the round's NEXT device call has been launched (the next step, the
 decode block, the speculation block), so the device is never left with
-nothing queued for the measurement's sake; a span therefore starts where
+nothing queued for the measurement's sake; the incremental loop's decode
+block is read back only after the next round's lead step has been launched
+behind it, telemetry or not; a span therefore starts where
 the last recorded call's ended if that is later than its own launch
 (``own_start``: the ``prefill`` and ``decode_block`` spans, the
 ``spec_block`` span, the ``decode_round`` spans spread over it). The spans
@@ -208,7 +216,8 @@ span's length: a call's service time on a busy device, staging and launch
 included only for a call that found the device idle. The boundary between
 two such spans is the host's return from the earlier call's wait, so it
 is as late as the host is in learning that a call ended (milliseconds on
-a shared host: PERF.md 7 (s)): a round's sum is exact, its split is not.
+a shared host: PERF.md 7 (s)): a round's sum is exact, its split is not,
+at either end of a block that has a step queued behind it.
 """
 
 from __future__ import annotations
@@ -250,19 +259,24 @@ class RoundTrace:
     and is not waited for yet (``PendingPrefill``; the incremental loop
     and the fused speculation loop lag each step's wait by one device
     call): whoever launches the round's next call settles it between that
-    call's launch and its own wait, and it never outlives the round.
+    call's launch and its own wait. It is handed on once, by a LEAD step:
+    the incremental loop's step queued behind a round's decode block is the
+    next round's first, and that round's trace is given it (``hand_on``,
+    ``begin_round(pending=)``) and settles it like any other; no other
+    step outlives its round.
     Built only with telemetry on (``begin_round``)."""
 
     __slots__ = ("tel", "round", "leaf", "args", "grants0", "reqs",
                  "tokens0", "pending")
 
-    def __init__(self, tel: "ServingTelemetry", loop: str, slots: int):
+    def __init__(self, tel: "ServingTelemetry", loop: str, slots: int,
+                 pending: Optional["PendingPrefill"] = None):
         self.tel = tel
         self.args = {"loop": loop, "slots": slots}
         self.grants0 = tel.n_grants
         self.reqs = None
         self.tokens0 = 0
-        self.pending = None
+        self.pending = pending
         self.round = tel.tracer.begin("sched_round")
         self.leaf = tel.tracer.begin("sched_admit")
 
@@ -313,6 +327,14 @@ class RoundTrace:
         self.pending = None
         return "prefill"
 
+    def hand_on(self) -> Optional["PendingPrefill"]:
+        """Take the pending step out of the round, for the next round's
+        trace: a lead step, whose ``prefill`` spans say ``ahead``."""
+        step, self.pending = self.pending, None
+        if step is not None:
+            step.ahead = True
+        return step
+
     def end(self):
         self.phase(None)
         self.settle()       # a round that ended without a block
@@ -330,10 +352,12 @@ class PendingPrefill:
     meanwhile (the incremental loop and the fused speculation loop).
     ``leaf``: whether the wait is a ``call_wait`` leaf (a loop with a
     ``RoundTrace``). ``model``: whose cache the step fills, onto its spans
-    (``llm``, ``ssm<i>``). Built only with telemetry on; the launch time is
-    taken here, on the clock ``settle`` reads."""
+    (``llm``, ``ssm<i>``). ``ahead``: a lead step (``RoundTrace.hand_on``).
+    Built only with telemetry on; the launch time is taken here, on the
+    clock ``settle`` reads."""
 
-    __slots__ = ("tel", "rows", "positions", "leaf", "model", "t0", "out")
+    __slots__ = ("tel", "rows", "positions", "leaf", "model", "t0", "out",
+                 "ahead")
 
     def __init__(self, tel: "ServingTelemetry", rows, positions: int,
                  leaf: bool, model: str = "llm"):
@@ -343,6 +367,7 @@ class PendingPrefill:
         self.leaf = leaf
         self.model = model
         self.out = None             # the launched step's output, a future
+        self.ahead = False
         self.t0 = time.perf_counter()
 
     def settle(self):
@@ -356,7 +381,7 @@ class PendingPrefill:
         tel.record_prefill(time.perf_counter() - self.t0,
                            sum(n for _, _, n in self.rows), self.rows,
                            self.t0, positions=self.positions,
-                           model=self.model)
+                           model=self.model, ahead=self.ahead)
 
 
 def _carried_series(kinds) -> Optional[str]:
@@ -451,6 +476,12 @@ class ServingTelemetry:
             "(decoding + filling) over the rows decoding; 1 at a full "
             "batch",
             buckets=COUNT_BUCKETS)
+        self.round_prefill_ahead = r.histogram(
+            "ffsv_round_prefill_ahead",
+            "one observation a decode block of the incremental loop: 1 "
+            "where the next round's first prefill step was launched behind "
+            "the block before its read-back, else 0",
+            buckets=FRACTION_BUCKETS)
         self.spec_rounds = r.counter(
             "ffsv_spec_rounds_total", "speculation rounds executed")
         self.decode_steps = r.counter(
@@ -904,14 +935,16 @@ class ServingTelemetry:
 
     def record_prefill(self, seconds: float, n_tokens: int, rows=(),
                        t0: Optional[float] = None, positions: int = 0,
-                       model: str = "llm"):
+                       model: str = "llm", ahead: bool = False):
         """``t0``: the step's launch on ``perf_counter`` (None: it ended
         just now), ``seconds`` from there to the end of its wait; the span
         and the histogram get the step's own time (``_own_time``).
         ``positions``: the batch rows x chunk the step's program computed,
         real tokens or padding. ``model``: onto every copy of the span
-        (tracing.SpanTracer.prefill). Counters and spans move together, so
-        a snapshot never counts a step whose span is not out yet."""
+        (tracing.SpanTracer.prefill), as ``ahead`` is where it is set (a
+        lead step: launched behind the decode block before it). Counters
+        and spans move together, so a snapshot never counts a step whose
+        span is not out yet."""
         t0, seconds = self._own_time(seconds, t0)
         self.prefill_seconds.observe(seconds)
         self.prefill_tokens.inc(n_tokens)
@@ -920,12 +953,15 @@ class ServingTelemetry:
         # positions up to itself
         self.prefill_pairs.inc(sum(n * sp + n * (n + 1) // 2
                                    for _, sp, n in rows))
+        extra = {"ahead": True} if ahead else {}
         for guid, start_pos, n in rows:
-            self.tracer.prefill(guid, start_pos, n, t0, seconds, model)
+            self.tracer.prefill(guid, start_pos, n, t0, seconds, model,
+                                **extra)
 
     def note_round_prefill(self, steps: int):
-        """Once per round of the incremental loop: the prefill steps it
-        dispatched before its decode block, none included."""
+        """Once per round of the incremental loop: the prefill steps
+        dispatched for it before its decode block, none included; its lead
+        step, launched behind the block before, is its first."""
         self.round_prefill_steps.observe(steps)
 
     def note_round_allowance(self, allowed: int, weight: float):
@@ -934,6 +970,11 @@ class ServingTelemetry:
         (everyone resident over the rows decoding) it gave the block."""
         self.round_prefill_allowance.observe(allowed)
         self.round_prefill_weight.observe(weight)
+
+    def note_round_ahead(self, ahead: bool):
+        """Once per decode block of the incremental loop: whether the next
+        round's first prefill step was launched behind it."""
+        self.round_prefill_ahead.observe(float(ahead))
 
     def record_decode_block(self, seconds: float, steps: int, n_live: int,
                             guids=(), t0: Optional[float] = None,
@@ -1018,10 +1059,12 @@ class ServingTelemetry:
             self.tracer.end(prev)
         return self.tracer.begin(name, program=program) if name else None
 
-    def begin_round(self, loop: str, slots: int) -> "RoundTrace":
+    def begin_round(self, loop: str, slots: int,
+                    pending=None) -> "RoundTrace":
         """Open one scheduler-loop iteration's ``sched_round`` span and
-        its first phase, ``sched_admit``."""
-        return RoundTrace(self, loop, slots)
+        its first phase, ``sched_admit``. ``pending``: the lead step the
+        round before handed on (``RoundTrace.hand_on``)."""
+        return RoundTrace(self, loop, slots, pending)
 
     def note_spec_controller(self, ewma_mean, n_fallback: int,
                              new_fallbacks: int):

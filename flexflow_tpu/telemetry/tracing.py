@@ -213,13 +213,15 @@ class SpanTracer:
                   max_new_tokens=max_new_tokens)
 
     def prefill(self, guid: int, start_pos: int, n_tokens: int,
-                ts_s: float, dur_s: float, model: str = "llm"):
+                ts_s: float, dur_s: float, model: str = "llm", **extra):
         """``model``: whose cache the step filled, ``llm`` (the model that
-        is verified, or decodes alone) or ``ssm<i>`` (draft ``i``). The
-        same on every request's copy of the span."""
+        is verified, or decodes alone) or ``ssm<i>`` (draft ``i``).
+        ``extra``: ``ahead=True`` on a lead step's. The same on every
+        request's copy of the span."""
         self.emit("prefill", "X", guid, ts_s=ts_s, dur_s=dur_s,
                   request_guid=guid,
-                  start_pos=start_pos, n_tokens=n_tokens, model=model)
+                  start_pos=start_pos, n_tokens=n_tokens, model=model,
+                  **extra)
 
     def decode_block(self, guid: int, steps: int, ts_s: float,
                      dur_s: float, rows: int, width: int = 1, **extra):

@@ -915,7 +915,7 @@ def test_the_cell_its_files_and_the_arithmetic_of_its_bytes(
         assert not {"kda_state_hbm_roofline", "decode_kda_hbm_roofline",
                     "linear_attn_share", "kda_chunk_share",
                     "decode_hbm_roofline", "experts_touched"} & mine
-        assert len(mine) == 25
+        assert len(mine) == 26      # PR 61: prefill_ahead_share
         # the configuration file against the catalog, key by key
         with open("/opt/skills/guides/model-configs/architectures.jsonl"
                   ) as f:

@@ -511,7 +511,7 @@ def test_the_cell_rehearses_and_its_traffic_is_the_issues(what, monkeypatch,
         assert cell["chips"] == 1 and cell["traffic"] == "context-reasoning"
         mine = {m["name"] for m in bench_json["per_layer"]
                 if CELL in m.get("workloads", ())}
-        assert len(mine) == 32 and {"zero_expert_share",
+        assert len(mine) == 33 and {"zero_expert_share",
                                     "decode_scmoe_hbm_roofline"} <= mine
         return
     # what the tests before this one traced off the kernels' path is theirs

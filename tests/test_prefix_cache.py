@@ -393,13 +393,13 @@ def _interleave_events(model, telemetry: bool):
     ifm = getattr(model, "_inference_manager", None)
     if ifm is None:
         ifm = model._inference_manager = InferenceManager(model)
-    orig_decode = ifm.decode_block
+    orig_decode = ifm.launch_decode_block
 
     def spy_decode(tok, pos, act, block, **kw):
         events.append("decode")
         return orig_decode(tok, pos, act, block, **kw)
 
-    ifm.decode_block = spy_decode
+    ifm.launch_decode_block = spy_decode
     ifm.step_costs = GivenCosts(1.0, 0.15)
     if telemetry:
         enable_telemetry()
@@ -407,7 +407,7 @@ def _interleave_events(model, telemetry: bool):
         rm.generate_incr_decoding(model)
     finally:
         disable_telemetry()
-        ifm.decode_block = orig_decode
+        ifm.launch_decode_block = orig_decode
         del ifm.step_costs
     return events, rm.results[gl], rm.results[gs]
 

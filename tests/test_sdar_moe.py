@@ -193,18 +193,22 @@ def _watched(m, prompts, news, eos=None):
     (live slots, windows staged, stored lengths staged, steps asked,
     BlockPasses)."""
     ifm, calls = RequestManager._manager_of(m), []
-    run = ifm.decode_block
+    launch, read = ifm.launch_decode_block, ifm.read_decode_block
 
-    def noting(tok, pos, act, n, **kw):
-        out = run(tok, pos, act, n, **kw)
-        calls.append((np.flatnonzero(act), tok.copy(), pos.copy(), n, out))
+    def noting(tok, pos, act, n, **kw):     # the loop's two ends of a block
+        calls.append((np.flatnonzero(act), tok.copy(), pos.copy(), n))
+        return launch(tok, pos, act, n, **kw)
+
+    def handed_back(launched, **kw):
+        out = read(launched, **kw)
+        calls[-1] += (out,)
         return out
 
-    ifm.decode_block = noting
+    ifm.launch_decode_block, ifm.read_decode_block = noting, handed_back
     try:
         return _serve(m, prompts, news, eos), calls
     finally:
-        del ifm.decode_block
+        del ifm.launch_decode_block, ifm.read_decode_block
 
 
 def _whole(win):

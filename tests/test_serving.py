@@ -918,32 +918,40 @@ def _closed_form(prompt, max_new, max_seq, eos=_RULE_EOS):
 
 class _RuleIFM:
     """Stands in for InferenceManager: the next token is ``_rule_next`` of
-    the last token and its position. Records every call; ``on_decode`` runs
-    at the start of each decode block with the call's index. ``costs``: the
+    the last token and its position. Records every call, and in ``order``
+    the launches and reads as they came (``("step", k)``, ``("launch", i)``,
+    ``("read", i)``); ``on_decode`` runs at the start of each decode block
+    with the call's index, ``on_read`` before its read-back. A prefill step
+    counts in the round of the block launched after it: a lead step, sent
+    between a block's launch and its read, in the next. ``costs``: the
     loop's ``step_costs``, given and not timed (None: the loop times the
     fake's calls itself, and has no estimate before its third sample)."""
 
     decode_width = 1                # what the loop stamps its blocks' spans
 
-    def __init__(self, on_decode=None, costs=None):
+    def __init__(self, on_decode=None, costs=None, on_read=None):
         self.prefills = []          # BatchMeta of every prefill step
         self.decodes = []           # (tok, pos, act, block) of every block
         self.rounds = [0]           # prefill steps before each decode block
+        self.order = []
         self.on_decode = on_decode
+        self.on_read = on_read
         self.model = types.SimpleNamespace(op_state=None)   # none to fence
         if costs is not None:
             self.step_costs = costs
 
     def step(self, meta, want_output=True, tel=None):
         assert not want_output
+        self.order.append(("step", len(self.prefills)))
         self.prefills.append(meta)
         self.rounds[-1] += 1
 
-    def decode_block(self, tok, pos, act, block, tel=None, rnd=None):
+    def launch_decode_block(self, tok, pos, act, block, tel=None, rnd=None):
         if rnd is not None:         # telemetry on: the round's last step
             rnd.settle()
         if self.on_decode is not None:
             self.on_decode(len(self.decodes))
+        self.order.append(("launch", len(self.decodes)))
         self.decodes.append((tok.copy(), pos.copy(), act.copy(), block))
         self.rounds.append(0)
         out = np.zeros((tok.shape[0], block), np.int32)
@@ -953,6 +961,24 @@ class _RuleIFM:
             p = p + 1
             out[:, j] = np.where(act, cur, 0)
         return out
+
+    def read_decode_block(self, launched, tel=None):
+        if self.on_read is not None:
+            self.on_read(len(self.decodes) - 1)
+        self.order.append(("read", len(self.decodes) - 1))
+        return launched
+
+    def lead_steps(self):
+        """The blocks (by index) that had a prefill step launched between
+        their launch and their read, and never more than one."""
+        ahead = []
+        for i, (what, k) in enumerate(self.order):
+            if what == "launch":
+                behind = self.order[i + 1:self.order.index(("read", k))]
+                assert all(w == "step" for w, _ in behind) and len(behind) < 2
+                if behind:
+                    ahead.append(k)
+        return ahead
 
 
 def _rule_model(cfg, ifm):
@@ -1205,12 +1231,16 @@ def _serve_on_toy_device(monkeypatch, cfg, queue, new_tokens, decode_s=0.8,
                 block_until_ready=lambda end=device[0]: host.__setitem__(
                     0, max(host[0], end)))
 
-        def decode_block(self, tok, pos, act, block, tel=None,
-                         rnd=None):
+        def launch_decode_block(self, tok, pos, act, block, tel=None,
+                                rnd=None):
             device[0] = max(host[0], device[0]) + decode_s * block
-            out = super().decode_block(tok, pos, act, block, tel, rnd)
-            wait()
-            return out
+            return (super().launch_decode_block(tok, pos, act, block, tel,
+                                                rnd), device[0])
+
+        def read_decode_block(self, launched, tel=None):
+            out, end = launched
+            host[0] = max(host[0], end)     # the block's end, not what
+            return super().read_decode_block(out, tel)  # is queued behind
 
     monkeypatch.setattr(RM, "device_fence", wait)
     monkeypatch.setattr(RM, "time", types.SimpleNamespace(
@@ -1262,6 +1292,126 @@ def test_incr_loop_times_the_same_rounds_traced_and_untraced(monkeypatch,
     assert sorted(plain[2])[:-1] == [1.0] * (len(plain[2]) - 1)
     assert max(plain[2]) == (1.0 if stop_at is None else 101.0)
     assert {round(d, 6) for d in plain[3]} == {0.8}
+
+
+def _asked_costs(every, order):
+    """Costs under which a block of 4 pays for one step, and a ``due``
+    that says yes to every ``every``-th round that asks (0: to none) and
+    keeps each answer beside the last entry of ``order`` (the fake's) when
+    it was asked."""
+    from flexflow_tpu.serve.step_costs import GivenCosts
+
+    class Asked(GivenCosts):
+        def due(self):
+            yes = bool(every) and (len(self.asked) + 1) % every == 0
+            self.asked.append((yes, order[-1] if order else None))
+            return yes
+
+    costs = Asked(1.0, 1.5 / 4)
+    costs.asked = []
+    return costs
+
+
+def _filling(rm):
+    """Whether a request in a slot is short of its prompt (the loop's own
+    marks, read off the manager from a hook of the fake)."""
+    return any(r.slot >= 0 and not r.finished
+               and r.cache_depth < len(r.tokens) - 1
+               for r in rm.inflight.values())
+
+
+@pytest.mark.parametrize("every", [0, 1, 3])
+def test_incr_loop_queues_a_lead_step_behind_a_block(monkeypatch, every):
+    """ISSUE 61: between a decode block's launch and its read the loop
+    launches the next round's first prefill step, one and no more, exactly
+    when a request in a slot is still filling and the coming round is not
+    one StepCosts times (a timed round fences each of its steps, and
+    starts on an idle device); never when everyone resident is decoding.
+    Whatever the rounds held, every request's tokens are its own."""
+    from flexflow_tpu.serve import request_manager as RM
+
+    cfg = ff.FFConfig(max_requests_per_batch=4, max_sequence_length=80,
+                      max_tokens_per_batch=16, decode_block_steps=4)
+    rm = RequestManager(eos_token_id=_RULE_EOS)
+    queue = [[(11 * i + j) % 50 + 1 for j in range(n + i // 8)]
+             for i, n in enumerate(3 * [24, 56, 33, 41, 25, 50, 37, 29])]
+    for i, pr in enumerate(queue):
+        rm.register_new_request(pr, max_new_tokens=6 + i % 9)
+    filling = []
+    ifm = _RuleIFM(on_decode=lambda i: filling.append(_filling(rm)))
+    costs = ifm.step_costs = _asked_costs(every, ifm.order)
+    monkeypatch.setattr(RM, "device_fence",
+                        lambda state: ifm.order.append(("fence", None)))
+    res = rm.generate_incr_decoding(_rule_model(cfg, ifm))
+    assert ({tuple(r.input_tokens): r.output_tokens for r in res}
+            == {tuple(pr): _closed_form(pr, 6 + i % 9, 80)
+                for i, pr in enumerate(queue)})
+    lead = ifm.lead_steps()
+    assert any(filling) and not all(filling)
+    assert all(filling[k] for k in lead)
+    if every == 1:
+        assert not lead
+    # a block with someone filling: a lead step behind it, or else the
+    # round after it is timed, each of its steps fenced
+    for k in (k for k, f in enumerate(filling) if f):
+        seg = ifm.order[ifm.order.index(("launch", k)) + 1:]
+        if ("launch", k + 1) in seg:
+            seg = seg[:seg.index(("launch", k + 1))]
+        steps = sum(what == "step" for what, _ in seg)
+        fences = sum(what == "fence" for what, _ in seg)
+        assert steps >= 1
+        assert fences == (0 if k in lead else steps), (k, seg)
+    # one question a round that prefills, put where its lead step would be
+    # launched: a yes there, and the block has none behind it
+    assert len(costs.asked) == sum(n > 0 for n in ifm.rounds)
+    assert ([k for k, f in enumerate(filling) if f and k not in lead]
+            == [at[1] for yes, at in costs.asked
+                if yes and at and at[0] == "launch"])
+    assert len(lead) == {0: sum(filling), 1: 0, 3: 11}[every] < len(filling)
+
+
+@pytest.mark.parametrize("how", ["cancel", "expire"])
+@pytest.mark.parametrize("victim", ["filling", "decoding"])
+def test_incr_loop_reaps_a_request_between_a_lead_step_and_the_read(
+        victim, how):
+    """A request cancelled, or out of time, after a lead step was launched
+    and before its block is read, whether the step filled it or the block
+    carried it: it resolves so, its slot is refilled, and every other
+    request's tokens are its own."""
+    cfg = ff.FFConfig(max_requests_per_batch=4, max_sequence_length=80,
+                      max_tokens_per_batch=16, decode_block_steps=4)
+    rm = RequestManager(eos_token_id=_RULE_EOS)
+    for i, pr in enumerate(_RULE_QUEUE):
+        rm.register_new_request(pr, max_new_tokens=6 + i)
+    hit = []
+
+    def reap(k):
+        if hit or ifm.order[-1][0] != "step":
+            return
+        meta, (_, _, act, _) = ifm.prefills[-1], ifm.decodes[k]
+        slots = (set(meta.slots[meta.active].tolist())
+                 if victim == "filling" else set(np.flatnonzero(act)))
+        req = min((r for r in rm.inflight.values() if r.slot in slots),
+                  key=lambda r: r.guid)
+        hit.append(req.guid)
+        if how == "cancel":
+            assert rm.cancel(req.guid)
+        else:
+            req.deadline_s = 1e-9           # long past
+
+    ifm = _RuleIFM(costs=_allowing(2, 4), on_read=reap)
+    res = {r.guid: r for r in rm.generate_incr_decoding(_rule_model(cfg, ifm))}
+    assert len(hit) == 1 and len(res) == len(_RULE_QUEUE)
+    gone = res.pop(hit[0])
+    assert gone.status == ("cancelled" if how == "cancel" else "timed_out")
+    full = _closed_form(gone.input_tokens, 13, 80)
+    assert gone.output_tokens == full[:len(gone.output_tokens)] != full
+    assert all(r.status == "ok" for r in res.values())
+    assert ({tuple(r.input_tokens): r.output_tokens for r in res.values()}
+            == {tuple(pr): _closed_form(pr, 6 + i, 80)
+                for i, pr in enumerate(_RULE_QUEUE)
+                if pr != gone.input_tokens})
+    assert ifm.lead_steps()
 
 
 @pytest.mark.parametrize("prompt,new_tokens,slots,holds", [

@@ -240,13 +240,26 @@ def _serve(loop, llm, ssm, rm):
     """Three requests through two slots on one of the Python scheduler
     loops (the fused speculation loop under either engine: ``ssm`` is
     compiled at beam width 2 for ``spec_beam``): a refill mid-batch, so
-    rounds with and without a prefill."""
+    rounds with and without a prefill. ``incr_ahead``: the incremental
+    loop, the first prompt 50 tokens long (four steps) and the two costs
+    given, so that it is still filling when the first blocks are launched
+    and no round is timed: rounds that begin with a lead step (ISSUE 61)."""
     from flexflow_tpu.serve.batch_config import GenerationConfig
+    from flexflow_tpu.serve.step_costs import GivenCosts
 
-    for p in [[5, 9, 23, 44], [7, 3, 11], [9, 9, 4, 1, 2]]:
+    first = ([5, 9, 23, 44] if loop != "incr_ahead"
+             else [(5 * i) % 96 + 1 for i in range(50)])
+    for p in [first, [7, 3, 11], [9, 9, 4, 1, 2]]:
         rm.register_new_request(p, max_new_tokens=8)
     if loop == "incr":
         return rm.generate_incr_decoding(llm)
+    if loop == "incr_ahead":
+        ifm = RequestManager._manager_of(llm)
+        ifm.step_costs = GivenCosts(1.0, 0.3)   # a block of 8: two steps
+        try:
+            return rm.generate_incr_decoding(llm)
+        finally:
+            del ifm.step_costs
     results = rm.generate_spec_infer(
         llm, [ssm], spec_depth=2,
         generation_config=GenerationConfig(adaptive_spec=False))
@@ -254,13 +267,16 @@ def _serve(loop, llm, ssm, rm):
     return results
 
 
-@pytest.mark.parametrize("loop", LOOPS)
+@pytest.mark.parametrize("loop", LOOPS + ("incr_ahead",))
 def test_scheduler_round_span_vocabulary(loop, tiny_spec_pair,
                                          tiny_beam_draft, monkeypatch):
     """A served batch emits each vocabulary span once per occurrence on
     tid 0; the leaves inside a sched_round do not overlap and cover it;
     spec_block.rounds is what the device ran (the executed columns of
-    n_acc) and never more than the rounds asked for."""
+    n_acc) and never more than the rounds asked for. ``incr_ahead``: a
+    lead step's leaves lie between its block's launch and wait, its
+    ``prefill`` span says ``ahead`` and starts where the block's ended, and
+    ``ffsv_round_prefill_ahead`` has one observation a block."""
     from flexflow_tpu.serve import engine as eng
 
     llm, ssm = tiny_spec_pair
@@ -284,9 +300,11 @@ def test_scheduler_round_span_vocabulary(loop, tiny_spec_pair,
         n_decode_calls = reg.get("ffsv_decode_block_seconds").count
         n_prefill_calls = reg.get("ffsv_prefill_step_seconds").count
         n_spec_calls = reg.get("ffsv_spec_block_seconds").count
+        ahead = reg.get("ffsv_round_prefill_ahead")
     finally:
         disable_telemetry()
     assert sorted(len(r.output_tokens) for r in results) == [8, 8, 8]
+    ahead_loop, loop = loop == "incr_ahead", loop.split("_ahead")[0]
 
     batch = [e for e in events if e["ph"] == "X" and e["tid"] == 0]
     assert batch and {e["name"] for e in batch} <= set(VOCABULARY)
@@ -350,6 +368,30 @@ def test_scheduler_round_span_vocabulary(loop, tiny_spec_pair,
         if any(r["ts"] - eps <= e["ts"] < r["ts"] + r["dur"]
                for r in rounds))                 # no leaf outside a round
     assert covered >= 0.9 * sum(r["dur"] for r in rounds)
+
+    # a lead step: one observation a decode block of the incremental loop
+    assert ahead.count == (n_decode_calls if loop == "incr" else 0)
+    calls = [(e["name"][5:], e["args"]["program"]) for e in leaves
+             if e["name"].startswith("call_")]
+    behind = [calls[i + 1:calls.index(("wait", "decode_block"), i)]
+              for i, c in enumerate(calls) if c == ("launch", "decode_block")]
+    step = [("stage", "prefill"), ("launch", "prefill")]
+    # between a block's launch and its wait: the wait of the step before
+    # it, then the lead step's stage and launch, if it has either
+    assert all(b in ([], step, [("wait", "prefill")],
+                     [("wait", "prefill")] + step) for b in behind)
+    led = sum(b[-2:] == step for b in behind)
+    assert ahead.sum == led and (led >= 2 if ahead_loop else led == 0)
+    spans = {n: sorted({(e["ts"], e["ts"] + e["dur"]) for e in per_request
+                        if e["name"] == n and (n == "decode_block"
+                                               or e["args"].get("ahead"))})
+             for n in ("decode_block", "prefill")}
+    assert len(spans["prefill"]) == led
+    for t0, t1 in spans["prefill"]:
+        # its own time: from the end of the block it was queued behind
+        assert any(abs(t0 - b1) <= eps for _, b1 in spans["decode_block"])
+        assert all(t1 <= b0 + eps or b1 <= t0 + eps
+                   for b0, b1 in spans["decode_block"])
 
 
 def test_disabled_path_enters_no_annotation(tiny_spec_pair,
@@ -523,6 +565,34 @@ def test_phase_span_readers_on_a_hand_built_trace(metric, capsys):
     assert all(line.startswith("# ") for line in out.splitlines())
     if metric == "spec_rounds_per_block":
         assert "rounds asked 2.500" in out and "'catch_up': 1" in out
+
+
+@pytest.mark.parametrize("before,after,want", [
+    ((0, 0.0), (40, 34.0), 85.0),       # every block of the window but six
+    ((10, 10.0), (50, 10.0), 0.0),      # blocks, and nobody filling: 0
+    ((7, 3.0), (7, 3.0), None),         # no block in the window
+    (None, (8, 2.0), 25.0),             # the series was born in the window
+    (None, None, None),                 # the parent: no such histogram
+])
+def test_prefill_ahead_share_on_hand_made_snapshots(before, after, want):
+    """ISSUE 61's reader: 100 x the mean of ``ffsv_round_prefill_ahead``
+    over the window (one observation a decode block of the incremental
+    loop, 1 where a lead step was queued behind it); None without the
+    histogram, without a block, or untraced."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, root)
+    try:
+        from benchmark.run import load_module
+
+        read = load_module("layer_metrics", "prefill_ahead_share").read
+    finally:
+        sys.path.remove(root)
+    snap = lambda cs: ({} if cs is None else
+                       {"ffsv_round_prefill_ahead": {"count": cs[0],
+                                                     "sum": cs[1]}})
+    got = read({"tel": {"before": snap(before), "after": snap(after)}})
+    assert got == (want if want is None else pytest.approx(want))
+    assert read({"tel": None}) is None
 
 
 # the four readers of ISSUE 37, on a hand-built traced stretch of 100 ms

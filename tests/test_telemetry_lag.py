@@ -129,7 +129,8 @@ def test_a_prefill_step_is_waited_for_after_the_next_launch(
     its cache's end) before the last step's; a timed round of the
     incremental loop waits for each step before it stages the next; a round
     that launches nothing behind its step waits for it as it ends. Either
-    way: one stage, one launch, one wait a device call."""
+    way: one stage, one launch, one wait a device call (a lead step's wait
+    in the round after its launch)."""
     loop, _, events, _ = lagged
     if timed:
         events = _serve(models, loop, True, _TimedCosts(1.0, 1.0))[1]
@@ -139,8 +140,12 @@ def test_a_prefill_step_is_waited_for_after_the_next_launch(
         assert rounds[0] == [S, L, W] + block + done
         assert rounds[1] == [S, L, W] * 3 + block + done
     elif loop == "incr":
-        assert rounds[0] == [S, L] + block + [W] + done
-        assert rounds[1] == [S, L, S, L, W, S, L, W] + block + [W] + done
+        # ISSUE 61: the long prompt is still filling when a block is
+        # launched, so the next round's first step, its lead step, is
+        # staged and launched behind the block and before the block's
+        # wait, and waited for after that round's next launch
+        assert rounds[0] == [S, L] + block + [W, S, L] + done
+        assert rounds[1] == [S, L, W, S, L, W] + block + [W] + done
     elif loop == "spec_parked":
         step, stepped = _launch_and_wait("step")
         assert rounds[0] == rounds[1] == [S, L] + block + [W] + done
@@ -155,10 +160,10 @@ def test_a_prefill_step_is_waited_for_after_the_next_launch(
             [S, L, S, L, W] + block + [W] + done)
         assert rounds[3] == [S, L] + block + [W] + done
         assert all(r == block + done for r in rounds[4:]) and rounds[4:]
-    for calls in rounds:
-        launched = sum(c[0] == "launch" for c in calls)
-        for leaf in ("stage", "wait"):
-            assert sum(c[0] == leaf for c in calls) == launched
+    for leaf in ("stage", "wait"):
+        for calls in rounds if loop != "incr" or timed else [sum(rounds, [])]:
+            assert (sum(c[0] == leaf for c in calls)
+                    == sum(c[0] == "launch" for c in calls))
 
 
 def test_the_spans_of_a_round_bracket_each_its_own_call(lagged):

@@ -237,7 +237,7 @@ def served(model, prompts, new_tokens: int = 24):
     from flexflow_tpu.serve.step_costs import GivenCosts
 
     ifm = model._inference_manager = InferenceManager(model)
-    step, block = ifm.step, ifm.decode_block
+    step, block = ifm.step, ifm.launch_decode_block
     out = []
     for decode_step_s in (0.0, 1.0):
         model.op_state = jax.tree.map(jnp.zeros_like, model.op_state)
@@ -252,13 +252,13 @@ def served(model, prompts, new_tokens: int = 24):
             rounds.append(0)
             return block(*a, **kw)
 
-        ifm.step, ifm.decode_block = counted_step, counted_block
+        ifm.step, ifm.launch_decode_block = counted_step, counted_block
         rm = RequestManager()
         guids = [rm.register_new_request(list(toks), max_new_tokens=new_tokens)
                  for _, toks in prompts]
         rm.generate_incr_decoding(model)
         out.append(([rm.results[g].output_tokens for g in guids], rounds))
-    ifm.step, ifm.decode_block = step, block
+    ifm.step, ifm.launch_decode_block = step, block
     return out
 
 
